@@ -248,7 +248,7 @@ def make_bnb_megakernel(
     *,
     width: int = 4,
     priority_buckets: Optional[int] = None,
-    capacity: int = 1024,
+    capacity: int = 768,  # fits a v5e's SMEM (Megakernel.check_smem)
     num_values: Optional[int] = None,
     interpret: Optional[bool] = None,
     trace=None,
@@ -344,7 +344,7 @@ def run_bnb(
     *,
     width: int = 4,
     priority_buckets: Optional[int] = None,
-    capacity: int = 1024,
+    capacity: int = 768,
     interpret: Optional[bool] = None,
     trace=None,
     fuel: Optional[int] = None,

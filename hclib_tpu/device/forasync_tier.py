@@ -197,7 +197,15 @@ class Slab:
     lo1, lo2)`` as traced int32 and returns the indexer tuple applied as
     ``data_ref.at[...]`` - scalars for picked leading axes, ``pl.ds``
     for sliced ones. ``shape`` is the (static) slab shape the DMA moves;
-    it must agree with what the indexer selects."""
+    it must agree with what the indexer selects.
+
+    On the chip a window's last two dims must be whole (8, 128) tiles at
+    tile-aligned offsets (wrap a dynamic offset in ``pl.multiple_of`` so
+    the compiler can prove it): Mosaic refuses an unaligned
+    ``memref_slice``, which the interpreter never notices. A loop that
+    needs an unaligned neighbourhood loads the aligned superset and
+    slices the loaded value in ``compute`` (``workloads.stencil_loop``
+    does)."""
 
     def __init__(
         self,

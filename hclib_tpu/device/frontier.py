@@ -106,7 +106,7 @@ __all__ = [
     "PR_DEN",
 ]
 
-# Edge-block width: one VMEM lane row of int32, and the blocked-CSR
+# Edge-block width: one 128-lane row of int32, and the blocked-CSR
 # alignment unit (every vertex's edge run starts on a block boundary).
 EBLOCK = 128
 
@@ -256,7 +256,7 @@ class FrontierKernel:
     scalar-tier kernel (DMA one block in, relax its edges - the
     bit-identity reference arm) and the batched body (all live slots'
     slabs in flight before the first wait, the prospective next batch's
-    slabs prefetched into the other VMEM half during this round's relax
+    slabs prefetched into the other scratch half during this round's relax
     loop, the PR 3 double-buffer protocol) with its ``drain``. One relax
     trace means scalar-vs-batched identity holds by construction - and
     for these kernels the RESULT is additionally schedule-independent
@@ -322,7 +322,11 @@ class FrontierKernel:
     def _relax_block(self, kctx, eslab, wslab, carry, cnt) -> None:
         """The shared relax loop over one loaded edge slab: the single
         arithmetic trace both dispatch spellings run. ``eslab``/``wslab``
-        are zero-arg VMEM readers ``f(e) -> scalar``."""
+        are zero-arg SMEM readers ``f(e) -> scalar``: the relax is a
+        scalar loop that reads edge ``e`` at a dynamic lane, which the
+        TPU allows of SMEM and refuses of VMEM ("cannot statically
+        prove that index ... is a multiple of 128"), so the edge slabs
+        are DMA'd HBM -> SMEM."""
         kctx.ivalues[V_EDGES] = kctx.ivalues[V_EDGES] + cnt
 
         def e_body(e, _):
@@ -337,11 +341,11 @@ class FrontierKernel:
 
     def scalar_scratch(self) -> Dict[str, Any]:
         sc: Dict[str, Any] = {
-            "fr_idx": pltpu.VMEM((EBLOCK,), jnp.int32),
+            "fr_idx": pltpu.SMEM((EBLOCK,), jnp.int32),
             "fr_lsem": pltpu.SemaphoreType.DMA((1,)),
         }
         if self.weighted:
-            sc["fr_wgt"] = pltpu.VMEM((EBLOCK,), jnp.int32)
+            sc["fr_wgt"] = pltpu.SMEM((EBLOCK,), jnp.int32)
         return sc
 
     def scalar_kernel(self, ctx) -> None:
@@ -378,11 +382,11 @@ class FrontierKernel:
             # Double-buffered (leading 2): one half relaxes while the
             # tier's cross-round prefetch streams the next batch's edge
             # slabs into the other.
-            "fr_idx": pltpu.VMEM((2, width, EBLOCK), jnp.int32),
+            "fr_idx": pltpu.SMEM((2, width, EBLOCK), jnp.int32),
             "fr_lsem": pltpu.SemaphoreType.DMA((2, width)),
         }
         if self.weighted:
-            sc["fr_wgt"] = pltpu.VMEM((2, width, EBLOCK), jnp.int32)
+            sc["fr_wgt"] = pltpu.SMEM((2, width, EBLOCK), jnp.int32)
         return sc
 
     def _slot_loads(self, ctx, buf, slot: int, blk, wait: bool) -> None:

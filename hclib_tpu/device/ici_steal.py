@@ -54,7 +54,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import shard_map
 from .descriptor import (
     DESC_WORDS,
     F_CSR_N,
@@ -792,7 +791,7 @@ class ICIStealMegakernel:
             )
 
         nin = 6 + ndata
-        f = shard_map(
+        f = jax.shard_map(
             step,
             mesh=self.mesh,
             in_specs=(P(self.axes),) * nin,

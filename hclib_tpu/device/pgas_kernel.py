@@ -71,7 +71,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import shard_map
 from .descriptor import (
     DESC_WORDS,
     F_A0,
@@ -730,7 +729,7 @@ class PGASMegakernel:
             )
 
         nin = 7 + ndata
-        f = shard_map(
+        f = jax.shard_map(
             step,
             mesh=self.mesh,
             in_specs=(P(self.axis),) * nin,

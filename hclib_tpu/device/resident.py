@@ -117,7 +117,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import shard_map
 from .descriptor import (
     DESC_WORDS,
     F_A0,
@@ -2292,7 +2291,7 @@ class ResidentKernel:
             + (1 if self.mk.trace is not None else 0)
             + ((3 + (1 if self.inject else 0)) if ckpt else 0)
         )
-        f = shard_map(
+        f = jax.shard_map(
             step,
             mesh=self.mesh,
             in_specs=(P(axes),) * nin,

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jaxcompat import shard_map
 from .descriptor import (
     DESC_WORDS,
     F_CSR_N,
@@ -54,6 +53,7 @@ from .megakernel import (
     C_VALLOC,
     Megakernel,
     TS_WORDS,
+    ran_on,
 )
 
 __all__ = [
@@ -162,11 +162,12 @@ def execute_partitions(
         )
     sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
     put = lambda x: jax.device_put(np.ascontiguousarray(x), sh)  # noqa: E731
-    outs = jitted(
+    args = [
         put(tasks), put(succ), put(ring), put(counts), put(ivalues),
         *[put(data[k]) for k in mk.data_specs.keys()],
         *[put(x) for x in extra_inputs],
-    )
+    ]
+    outs = jitted(*args)
     counts_o, iv_o, gcounts = outs[0], outs[1], outs[2]
     nd = len(mk.data_specs)
     data_o = dict(zip(mk.data_specs.keys(), outs[3 : 3 + nd]))
@@ -176,6 +177,10 @@ def execute_partitions(
         "pending": int(g[C_PENDING]),
         "overflow": bool(g[C_OVERFLOW]),
         "per_device_counts": np.asarray(counts_o),
+        **ran_on(counts_o, mk.interpret),
+        # Fewest devices any input is spread over: ndev unless something
+        # sits whole on one device.
+        "input_devices": min(len(a.sharding.device_set) for a in args),
     }
     # Runner-specific trailing outputs (e.g. the resident kernel's
     # per-device fault/abort stats) ride after the data buffers.
@@ -337,7 +342,7 @@ class ShardedMegakernel:
             )
 
         nin = 5 + ndata
-        f = shard_map(
+        f = jax.shard_map(
             step,
             mesh=self.mesh,
             in_specs=(P(self.axis),) * nin,
@@ -529,7 +534,7 @@ class ShardedMegakernel:
             )
 
         nin = 5 + ndata
-        f = shard_map(
+        f = jax.shard_map(
             step,
             mesh=self.mesh,
             in_specs=(P(self.axis),) * nin,

@@ -35,7 +35,7 @@ from typing import Dict
 
 import numpy as np
 
-__all__ = ["forest_steal", "unified_load",
+__all__ = ["forest_steal", "forest_resident", "unified_load",
            "FOREST_STEAL_BENCH", "FOREST_STEAL_QUICK"]
 
 # The benchmark-of-record forest-steal configuration, shared by the
@@ -47,6 +47,36 @@ FOREST_STEAL_BENCH = dict(ndev=8, roots=160, n=12, capacity=4096)
 FOREST_STEAL_QUICK = dict(ndev=8, roots=24, n=9, capacity=1024)
 
 
+def _forest(ndev: int, roots: int, n: int):
+    """The maximally-skewed forest both runners take: ``roots`` fib(``n``)
+    seeds all on device 0 -> (builders, expected tasks, expected value)."""
+    from ..models.fib import fib_seq, task_count
+    from .descriptor import TaskGraphBuilder
+    from .workloads import FIB
+
+    builders = [TaskGraphBuilder() for _ in range(ndev)]
+    for r in range(roots):
+        builders[0].add(FIB, args=[n], out=r)
+    for b in builders:
+        # Symmetric heap: a migrated root writes its out slot on the
+        # THIEF's value buffer, so every device must hold the root
+        # slot range below its row-block region.
+        b.reserve_values(roots)
+    per_call = task_count(n)
+    per_call += (per_call - 1) // 2  # SUM joins
+    return builders, roots * per_call, roots * fib_seq(n)
+
+
+def _check_forest(iv, info, roots, expect_tasks, expect_value) -> int:
+    """Exact totals: executed count, and the out slots summed across the
+    mesh (a migrated root writes its slot on the thief)."""
+    assert info["executed"] == expect_tasks, (info["executed"], expect_tasks)
+    got = int(np.asarray(iv)[:, :roots].sum(dtype=np.int64))
+    assert got == expect_value, (got, expect_value)
+    assert info["pending"] == 0
+    return got
+
+
 def forest_steal(
     ndev: int = 8,
     roots: int = 160,
@@ -55,8 +85,13 @@ def forest_steal(
     window: int = 16,
     capacity: int = 4096,
     batch_width: int = 0,
+    mesh=None,
+    interpret: bool = True,
 ) -> Dict:
-    """Maximally-skewed fib forest through the sharded steal runner.
+    """Maximally-skewed fib forest through the sharded steal runner, on
+    ``mesh`` (default: ``ndev`` virtual CPU devices, which is what the
+    interpreter default wants; a caller with real chips passes its own
+    mesh of ``ndev`` devices and says ``interpret=False``).
 
     ``roots`` fib(``n``) seeds all on device 0; exact checks: the executed
     count equals roots * (FIB nodes + SUM joins) and the out slots sum to
@@ -70,46 +105,29 @@ def forest_steal(
     scalar mesh would, and the returned info carries per-device
     ``tiers`` (occupancy / batch rounds / spills) beside the totals -
     which stay exact and identical to the scalar arm."""
-    from ..models.fib import fib_seq, task_count
     from ..parallel.mesh import cpu_mesh
-    from .descriptor import TaskGraphBuilder
     from .megakernel import VBLOCK
     from .sharded import ShardedMegakernel
     from .workloads import FIB, make_fib_megakernel
 
     mk = make_fib_megakernel(
-        capacity=capacity, interpret=True,
+        capacity=capacity, interpret=interpret,
         num_values=VBLOCK * capacity + max(64, roots),
         batch_width=batch_width or None,
     )
-    smk = ShardedMegakernel(mk, cpu_mesh(ndev, axis_name="q"),
-                            migratable_fns=[FIB])
+    smk = ShardedMegakernel(
+        mk, cpu_mesh(ndev, axis_name="q") if mesh is None else mesh,
+        migratable_fns=[FIB],
+    )
 
-    def build():
-        builders = [TaskGraphBuilder() for _ in range(ndev)]
-        for r in range(roots):
-            builders[0].add(FIB, args=[n], out=r)
-        for b in builders:
-            # Symmetric heap: a migrated root writes its out slot on the
-            # THIEF's value buffer, so every device must hold the root
-            # slot range below its row-block region.
-            b.reserve_values(roots)
-        return builders
-
-    iv, _, info = smk.run(build(), steal=True, quantum=quantum,
-                          window=window)  # compile + warm
+    iv, _, info = smk.run(_forest(ndev, roots, n)[0], steal=True,
+                          quantum=quantum, window=window)  # compile + warm
+    builders, expect_tasks, expect_value = _forest(ndev, roots, n)
     t0 = time.perf_counter()
-    iv, _, info = smk.run(build(), steal=True, quantum=quantum,
+    iv, _, info = smk.run(builders, steal=True, quantum=quantum,
                           window=window)
     dt = time.perf_counter() - t0
-
-    per_call = task_count(n)
-    per_call += (per_call - 1) // 2  # SUM joins
-    expect_tasks = roots * per_call
-    assert info["executed"] == expect_tasks, (info["executed"], expect_tasks)
-    got = int(np.asarray(iv)[:, :roots].sum(dtype=np.int64))
-    assert got == roots * fib_seq(n), (got, roots * fib_seq(n))
-    assert info["pending"] == 0
+    _check_forest(iv, info, roots, expect_tasks, expect_value)
     per_dev = np.asarray(info["per_device_counts"])[:, 5]
     tier_label = f" [batch w={batch_width}]" if batch_width else ""
     info = dict(info)
@@ -143,6 +161,50 @@ def forest_steal(
             mean_occupancy=sum(occ) / len(occ),
             spilled=sum(t["spilled"] for t in tiers),
         )
+    return info
+
+
+def forest_resident(
+    mesh,
+    roots: int = 160,
+    n: int = 12,
+    capacity: int = 640,
+    quantum: int = 256,
+    window: int = 16,
+    interpret: bool = True,
+) -> Dict:
+    """``forest_steal``'s forest through the RESIDENT kernel on ``mesh``:
+    every fib(``n``) root seeded on device 0, link-free roots migrating
+    whole over the in-kernel ICI exchange (``homed=False``), each subtree
+    exploding on its thief. The caller owns the mesh (virtual CPU devices
+    under test, the real chips in ``chip_smoke.py --four-chips``) and
+    states ``interpret``. Same exact checks as ``forest_steal``; the
+    default capacity fits a v5e's SMEM beside the resident scratch."""
+    from .megakernel import VBLOCK
+    from .resident import ResidentKernel
+    from .workloads import FIB, make_fib_megakernel
+
+    ndev = int(np.prod(mesh.devices.shape))
+    mk = make_fib_megakernel(
+        capacity=capacity, interpret=interpret,
+        num_values=VBLOCK * capacity + max(64, roots),
+    )
+    rk = ResidentKernel(
+        mk, mesh, migratable_fns=[FIB], homed=False, window=window,
+    )
+    builders, expect_tasks, expect_value = _forest(ndev, roots, n)
+    t0 = time.perf_counter()
+    iv, _, info = rk.run(builders, quantum=quantum)
+    dt = time.perf_counter() - t0
+    got = _check_forest(iv, info, roots, expect_tasks, expect_value)
+    info = dict(info)
+    info.update(
+        name=f"forest_resident {roots}x fib({n}) on {ndev} devices",
+        seconds=dt,  # first entry: compile included
+        tasks=expect_tasks,
+        value=got,
+        per_device_counts=np.asarray(info["per_device_counts"]).tolist(),
+    )
     return info
 
 

@@ -32,8 +32,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
 from ..models.smithwaterman import GAP, MATCH, MISMATCH
+from .megakernel import resolve_interpret
 
 __all__ = ["sw_scores_pallas"]
 
@@ -83,7 +83,7 @@ def _kernel(n: int, a_ref, b_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def _sw_pallas(a_t, b_t, block_b: int = 512, interpret: bool = False):
+def _sw_pallas(a_t, b_t, block_b: int = 256, interpret: bool = False):
     """a_t (n, B) and b_t (m, B) pre-transposed; returns (1, B) scores.
     B must be a whole number of batch blocks (sw_scores_pallas pads)."""
     n, B = a_t.shape
@@ -109,13 +109,14 @@ def _sw_pallas(a_t, b_t, block_b: int = 512, interpret: bool = False):
     )(a_t, b_t)
 
 
-def sw_scores_pallas(a_batch, b_batch, block_b: int = 512,
+def sw_scores_pallas(a_batch, b_batch, block_b: int = 256,
                      interpret=None) -> np.ndarray:
     """Scores for B pairs: a_batch (B, n) vs b_batch (B, m) -> (B,) i32.
     B is padded to a whole number of batch blocks and n to a multiple of 8
-    (pad symbol -1 matches nothing, so scores are unchanged)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    (pad symbol -1 matches nothing, so scores are unchanged). The default
+    block of 256 pairs is the bench's; 512 at m=1024 needs 16.66 MiB of
+    scoped VMEM and the v5e compiler's default limit is 16 MiB."""
+    interpret = resolve_interpret(interpret)
     a = np.asarray(a_batch, np.int32)
     b = np.asarray(b_batch, np.int32)
     B = a.shape[0]

@@ -339,55 +339,73 @@ MAP_ADD = 7
 
 
 def stencil_loop(H: int, W: int, th: int = 8, tw: int = 128):
-    """2D Jacobi-style stencil over an (H, W) interior held in (H+2, W+2)
-    halo-padded int32 grids ``gin`` -> ``gout``:
+    """2D Jacobi-style stencil over an (H, W) interior, ``gin`` ->
+    ``gout``, both int32:
 
-        gout[i, j] = gin[i, j] + gin[i-1, j] + gin[i+1, j]
-                   + gin[i, j-1] + gin[i, j+1]      (padded coordinates)
+        gout[i, j] = gin[i+1, j+1] + gin[i, j+1] + gin[i+2, j+1]
+                   + gin[i+1, j] + gin[i+1, j+2]
 
-    Returns ``(tile_kernel, bounds, tile)`` for the forasync entry
-    points. Each (th, tw) tile's operand slab is the (th+2, tw+2) window
-    around it - exactly the slab shape the tier's double-buffered
-    prefetch pipeline moves one round early."""
+    ``gin`` carries the interior at ``[1:H+1, 1:W+1]`` inside a zero halo
+    and ``gout`` is the bare (H, W) interior. Returns ``(tile_kernel,
+    bounds, tile)`` for the forasync entry points.
+
+    Every slab window is (8, 128)-tile aligned, which is what the TPU's
+    DMA engine and Mosaic's ``memref_slice`` require of the last two
+    dims (an unaligned (th+2, tw+2) halo window passes the interpreter
+    and is refused by the compiler). So a tile LOADS the aligned
+    (th+8, tw+128) superset that starts at its own corner and slices the
+    halo neighbourhood out of the loaded VALUE, and ``gin`` is allocated
+    (H+8, W+128) so the last tile's superset stays in bounds; the store
+    is the tile itself at its own aligned offset."""
     from .forasync_tier import Slab, TileKernel
 
-    pad = jax.ShapeDtypeStruct((H + 2, W + 2), jnp.int32)
+    if th % 8 or tw % 128:
+        raise ValueError(
+            f"stencil tiles must be whole (8, 128) tiles, got ({th}, {tw})"
+        )
+    gin = jax.ShapeDtypeStruct((H + 8, W + 128), jnp.int32)
+    gout = jax.ShapeDtypeStruct((H, W), jnp.int32)
 
     def compute(ins):
-        v = ins["vin"]
-        c = v[1:-1, 1:-1]
+        v = ins["vin"]  # rows [lo0, lo0+th+8) x cols [lo1, lo1+tw+128)
         return {
             "vout": (
-                c + v[:-2, 1:-1] + v[2:, 1:-1] + v[1:-1, :-2] + v[1:-1, 2:]
+                v[1:th + 1, 1:tw + 1] + v[:th, 1:tw + 1]
+                + v[2:th + 2, 1:tw + 1] + v[1:th + 1, :tw]
+                + v[1:th + 1, 2:tw + 2]
             )
         }
+
+    def corner(a):
+        # Tile corners are multiples of the tile by construction; the
+        # hint lets the compiler prove the window is tile-aligned.
+        return pl.multiple_of(a[1], 8), pl.multiple_of(a[2], 128)
 
     tk = TileKernel(
         loads=[Slab(
             "vin", "gin",
-            # Interior row i lives at padded row i+1: the slab around
-            # interior rows [lo0, lo0+th) is padded rows [lo0, lo0+th+2).
-            lambda a: (pl.ds(a[1], th + 2), pl.ds(a[2], tw + 2)),
-            (th + 2, tw + 2),
+            lambda a: (pl.ds(corner(a)[0], th + 8),
+                       pl.ds(corner(a)[1], tw + 128)),
+            (th + 8, tw + 128),
         )],
         stores=[Slab(
             "vout", "gout",
-            lambda a: (pl.ds(a[1] + 1, th), pl.ds(a[2] + 1, tw)),
+            lambda a: (pl.ds(corner(a)[0], th), pl.ds(corner(a)[1], tw)),
             (th, tw),
         )],
         compute=compute,
-        data_specs={"gin": pad, "gout": pad},
+        data_specs={"gin": gin, "gout": gout},
         name="fa_stencil",
     )
     return tk, [H, W], [th, tw]
 
 
 def stencil_body(gin: np.ndarray, gout: np.ndarray):
-    """Per-index host-forasync body over the padded numpy grids (the
-    host arm of the three-way bit-identity acceptance)."""
+    """Per-index host-forasync body over the numpy grids (the host arm
+    of the three-way bit-identity acceptance)."""
 
     def body(i, j):
-        gout[i + 1, j + 1] = (
+        gout[i, j] = (
             gin[i + 1, j + 1] + gin[i, j + 1] + gin[i + 2, j + 1]
             + gin[i + 1, j] + gin[i + 1, j + 2]
         )
@@ -396,22 +414,25 @@ def stencil_body(gin: np.ndarray, gout: np.ndarray):
 
 
 def stencil_reference(gin: np.ndarray) -> np.ndarray:
-    """Vectorized numpy oracle (padded in -> padded out, halo zero)."""
-    out = np.zeros_like(gin)
-    out[1:-1, 1:-1] = (
-        gin[1:-1, 1:-1] + gin[:-2, 1:-1] + gin[2:, 1:-1]
-        + gin[1:-1, :-2] + gin[1:-1, 2:]
+    """Vectorized numpy oracle (``stencil_data``'s gin -> the (H, W)
+    interior)."""
+    H, W = gin.shape[0] - 8, gin.shape[1] - 128
+    c = gin[1:H + 1, 1:W + 1]
+    return (
+        c + gin[:H, 1:W + 1] + gin[2:H + 2, 1:W + 1]
+        + gin[1:H + 1, :W] + gin[1:H + 1, 2:W + 2]
     )
-    return out
 
 
 def stencil_data(H: int, W: int, seed: int = 0):
-    """Padded (gin, gout) int32 grids; values bounded so the 5-point sum
-    never wraps."""
+    """(gin, gout) int32 grids in ``stencil_loop``'s layout; values
+    bounded so the 5-point sum never wraps."""
     rng = np.random.default_rng(seed)
-    gin = np.zeros((H + 2, W + 2), np.int32)
-    gin[1:-1, 1:-1] = rng.integers(0, 1 << 20, size=(H, W), dtype=np.int32)
-    return gin, np.zeros_like(gin)
+    gin = np.zeros((H + 8, W + 128), np.int32)
+    gin[1:H + 1, 1:W + 1] = rng.integers(
+        0, 1 << 20, size=(H, W), dtype=np.int32
+    )
+    return gin, np.zeros((H, W), np.int32)
 
 
 def map_loop(T: int, th: int = 8, tw: int = 128):

@@ -70,7 +70,7 @@ import numpy as np
 # dispatch, identically on every rank of a committed collective - the one
 # failure class where a joint fallback is safe (see allreduce). Anything
 # raised mid-collective stays fatal.
-from ..jaxcompat import (
+from ..parallel.multihost import (
     is_multiprocess_capability_error as _bulk_capability_error,
 )
 from ..runtime.module import Module
@@ -198,9 +198,7 @@ class ProcWorld:
             import jax
             from jax._src import distributed
 
-            from ..jaxcompat import distributed_is_initialized
-
-            if not distributed_is_initialized():
+            if not jax.distributed.is_initialized():
                 raise RuntimeError(
                     "ProcWorld needs jax.distributed initialized "
                     "(parallel.multihost.init_multihost)"

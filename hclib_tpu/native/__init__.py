@@ -11,6 +11,7 @@ feeding device work.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional, Tuple
@@ -27,6 +28,11 @@ def _csr(paths):
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libhclib_native.so")
+# What the library on disk was built FROM: the digest of _source_digest()
+# at build time. The .so is untracked and travels with copies of the
+# working directory, so file times say nothing about whether it matches
+# the sources beside it.
+_STAMP_PATH = _LIB_PATH + ".built-from"
 _lib = None
 
 
@@ -40,14 +46,32 @@ LOOP1_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_long)
 LOOP2_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_long, ctypes.c_long)
 
 
-def _build() -> None:
+def _source_digest() -> str:
+    """sha256 over the Makefile and every file of src/, names included."""
+    h = hashlib.sha256()
+    src = os.path.join(_DIR, "src")
+    paths = [os.path.join(_DIR, "Makefile")] + [
+        os.path.join(src, f) for f in sorted(os.listdir(src))
+    ]
+    for path in paths:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _build(digest: str) -> None:
     try:
+        # -B: make decides by file times too; the digest already decided.
         subprocess.run(
-            ["make", "-s"], cwd=_DIR, check=True, capture_output=True, text=True
+            ["make", "-s", "-B"], cwd=_DIR, check=True, capture_output=True,
+            text=True,
         )
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         detail = getattr(e, "stderr", "") or str(e)
         raise NativeBuildError(f"native runtime build failed: {detail}") from e
+    with open(_STAMP_PATH, "w") as f:
+        f.write(digest)
 
 
 def load() -> ctypes.CDLL:
@@ -55,12 +79,15 @@ def load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    src_newer = not os.path.exists(_LIB_PATH) or any(
-        os.path.getmtime(os.path.join(_DIR, "src", f)) > os.path.getmtime(_LIB_PATH)
-        for f in os.listdir(os.path.join(_DIR, "src"))
-    )
-    if src_newer:
-        _build()
+    # Stale unless the library was built from exactly these sources:
+    # decided by content (src/* and the Makefile), never by file times.
+    digest = _source_digest()
+    built_from = None
+    if os.path.exists(_LIB_PATH) and os.path.exists(_STAMP_PATH):
+        with open(_STAMP_PATH) as f:
+            built_from = f.read().strip()
+    if built_from != digest:
+        _build(digest)
     lib = ctypes.CDLL(_LIB_PATH)
     lib.hcn_create.restype = ctypes.c_void_p
     lib.hcn_create.argtypes = [ctypes.c_int]

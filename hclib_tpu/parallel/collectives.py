@@ -33,7 +33,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import axis_size
 
 __all__ = [
     "psum", "all_gather", "reduce_scatter", "all_to_all", "ring_permute",
@@ -63,13 +62,13 @@ def all_to_all(x, axis: str, *, split_axis: int = 0, concat_axis: int = 0):
 
 def ring_permute(x, axis: str, shift: int = 1):
     """Rotate shards around the mesh axis (one-sided neighbor exchange)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis, perm)
 
 
 def _check_root(root: int, axis: str) -> None:
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if not (0 <= root < n):
         raise ValueError(f"root {root} out of range for {n}-shard axis {axis!r}")
 
@@ -99,7 +98,7 @@ def exscan(x, axis: str):
     """MPI_Exscan(SUM): shard i receives sum of shards [0, i) - rank 0
     gets zeros. Hillis-Steele doubling in log2(n) ppermute steps; works
     for any axis size (shifts past the edge contribute zero)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     acc = x
     total = jnp.zeros_like(x)
@@ -130,7 +129,7 @@ def ring_allreduce(x, axis: str):
     divisible by the axis size. Matches psum numerically; exists as the
     reference schedule for profiling and for manual compute/comm
     pipelining (interleave chunk FLOPs between steps)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     me = jax.lax.axis_index(axis)

@@ -30,7 +30,6 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ..jaxcompat import distributed_is_initialized, shard_map
 from .mesh import make_mesh
 
 __all__ = [
@@ -48,6 +47,22 @@ __all__ = [
 _initialized = False
 _owns_init = False
 
+
+
+def is_multiprocess_capability_error(e: BaseException) -> bool:
+    """True for errors a backend raises LOCALLY, at dispatch, because it
+    cannot run multiprocess device computations at all (CPU pre-gloo
+    jaxlib). Deterministic on every rank - the one failure class a
+    committed collective may jointly degrade from. Matched by the two
+    SPECIFIC messages of that class (the raw XLA dispatch error and this
+    package's structured wrapper), never by a bare status prefix: an
+    unrelated rank-local UNIMPLEMENTED must stay fatal, or one rank would
+    solo-fallback while its peers sit in the device collective."""
+    msg = str(e)
+    return (
+        "Multiprocess computations aren't implemented" in msg
+        or "bulk device collectives are unavailable" in msg
+    )
 
 def init_multihost(
     coordinator_address: Optional[str] = None,
@@ -69,7 +84,7 @@ def init_multihost(
         return
     import jax
 
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         _initialized = True  # wired by someone else: adopt
         return
     explicit = any(
@@ -182,8 +197,6 @@ def sync_global(tag: int = 0) -> None:
     if is_multihost():
         from jax.experimental import multihost_utils
 
-        from ..jaxcompat import is_multiprocess_capability_error
-
         try:
             multihost_utils.sync_global_devices(f"hclib_tpu_sync_{tag}")
         except Exception as e:
@@ -239,8 +252,6 @@ def bulk_allreduce(arr: np.ndarray, op: str = "sum") -> np.ndarray:
     garr = jax.make_array_from_single_device_arrays(
         (nproc,) + arr.shape, sharding, [local]
     )
-    from ..jaxcompat import is_multiprocess_capability_error
-
     try:
         out = jitted(garr)
     except Exception as e:
@@ -288,7 +299,7 @@ def _local_barrier(devs):
         return jax.lax.psum(v, "all")
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             f, mesh=mesh, in_specs=P("all"), out_specs=P(), check_vma=False
         ),
         out_shardings=NamedSharding(mesh, P()),

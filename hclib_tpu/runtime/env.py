@@ -29,6 +29,7 @@ without a doc row.
 from __future__ import annotations
 
 import os
+import sys
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -43,6 +44,7 @@ __all__ = [
     "env_float",
     "env_str",
     "registry_table",
+    "use_compile_cache",
 ]
 
 
@@ -307,3 +309,28 @@ def registry_table():
         (v.name, v.kind, v.default, v.doc)
         for _, v in sorted(REGISTRY.items())
     ]
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one directory and
+    return it. Where ``JAX_COMPILATION_CACHE_DIR`` is set that is the
+    directory and nothing is changed; where it is not, the cache goes to
+    ``<checkout>/.jax_cache`` - a fixed path, because the path is part
+    of the cache key and a directory that moves never hits. Entry points
+    (``chip_smoke.py``, ``bench.py``, ``__graft_entry__``,
+    ``tools/perf_regression.py``) and ``tests/conftest.py`` call this
+    first; no other code names a cache directory."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)
+            ))),
+            ".jax_cache",
+        )
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = d  # children inherit
+        if "jax" in sys.modules:  # imported before us: env was read
+            sys.modules["jax"].config.update(
+                "jax_compilation_cache_dir", d
+            )
+    return d

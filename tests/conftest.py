@@ -15,16 +15,15 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 # Persistent XLA compilation cache (works for the CPU backend too): the
 # suite's one-time engine compiles (~40-120 s each for the UTS engines and
-# the big interpret kernels) are disk-cached under the repo, so repeated
-# suite runs on one machine skip them (measured 41 s -> 17 s for a single
-# UTS test). Tutorial subprocesses inherit the env. Cold runs are
-# unaffected; the cache directory is gitignored.
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache"),
-)
+# the big interpret kernels) are disk-cached, so repeated suite runs on
+# one machine skip them (measured 41 s -> 17 s for a single UTS test).
+# Tutorial subprocesses inherit the env. Cold runs are unaffected. The
+# directory is the one every entry point uses (runtime/env.py).
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
+
+from hclib_tpu.runtime.env import use_compile_cache  # noqa: E402
+
+use_compile_cache()
 # NOTE: do not be tempted to speed the suite up with non-default
 # InterpretParams (eager DMA / unchecked OOB reads): both variants
 # sporadically deadlock the Mosaic interpreter's io_callback machinery

@@ -19,15 +19,7 @@ import pytest
 
 import hclib_tpu as hc
 from hclib_tpu.device.tracebuf import TR_SCALE, records_of
-from hclib_tpu.jaxcompat import has_mosaic_interpret
 from hclib_tpu.runtime import resilience
-
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs the Mosaic TPU interpret mode (pltpu.InterpretParams, "
-           "jax >= 0.5): the ICI mesh kernels simulate remote DMA + "
-           "semaphores on CPU",
-)
 
 
 # ---------------------------------------------------------- policy, pure
@@ -261,7 +253,6 @@ def _uts_builders(ndev, roots=8):
     return bs
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_autoscale_storm_evacuates_dead_chip_totals_exact():
     """ACCEPTANCE (the storm): an autoscaled UTS mesh scales OUT under
@@ -299,7 +290,6 @@ def test_autoscale_storm_evacuates_dead_chip_totals_exact():
     assert len(recs) == len(info["scale_events"])
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_autoscale_preempt_checkpoints_and_resumes():
     """Preemption of an autoscaled deployment: the notice lands between
@@ -537,7 +527,6 @@ def test_program_cached_probe_reads_process_cache():
         progcache.reset()
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_autoscale_resizes_with_both_shapes_warm_hit_cache():
     """ACCEPTANCE (ISSUE 18): with both mesh shapes pre-warmed by

@@ -28,7 +28,6 @@ from hclib_tpu.device.workloads import (
     device_uts_mk,
     make_uts_megakernel,
 )
-from hclib_tpu.jaxcompat import has_mosaic_interpret
 from hclib_tpu.runtime import resilience
 from hclib_tpu.runtime.checkpoint import (
     CheckpointBundle,
@@ -40,13 +39,6 @@ from hclib_tpu.runtime.checkpoint import (
     snapshot_megakernel,
     snapshot_resident,
     snapshot_stream,
-)
-
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs the Mosaic TPU interpret mode (pltpu.InterpretParams, "
-           "jax >= 0.5): the ICI mesh kernels simulate remote DMA + "
-           "semaphores on CPU",
 )
 
 UTS_KW = dict(max_depth=8, interpret=True)
@@ -707,7 +699,6 @@ def test_bundle_diff():
     assert d3["only_other"] == ["waits"] and not d3["equal"]
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_resident_quiesce_with_pending_waits_roundtrip():
     """ACCEPTANCE (lifted limit #1): a resident mesh with PENDING
@@ -793,7 +784,6 @@ def test_resident_quiesce_with_pending_waits_roundtrip():
     )
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_resident_inject_cursor_survives_reshard():
     """ACCEPTANCE (lifted limit #2): a mid-stream quiesce keeps
@@ -872,7 +862,6 @@ def test_resident_inject_cursor_survives_reshard():
         assert int(np.asarray(iv3)[:, 0].sum()) == want
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_resident_mesh_checkpoint_roundtrip_same_mesh():
     """ACCEPTANCE: quiesce a 4-device resident mesh mid-traversal (the
@@ -903,7 +892,6 @@ def test_resident_mesh_checkpoint_roundtrip_same_mesh():
     assert int(np.asarray(iv_r)[:, 0].sum()) == total
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_resident_mesh_restore_onto_smaller_and_larger_mesh(tmp_path):
     """ACCEPTANCE (elastic resume): a 4-chip checkpoint restores onto 2

@@ -1,0 +1,314 @@
+"""The v5e compiler's verdict on the kernels the chip runs, without a chip.
+
+Interpret mode accepts kernels the TPU compiler refuses: a slice that is
+not aligned to the (8, 128) tiling, a scalar read from VMEM at a dynamic
+lane, a task table that pads past the 1 MiB of SMEM. These tests hand each
+main-path kernel, built ``interpret=False`` at the size ``chip_smoke.py``
+runs it, to the installed TPU compiler for a *described* ``v5e:2x2``
+(``on-chip-measurement`` guide, section 2). Nothing executes, so they say
+nothing about results or speed - ``chip_smoke.py`` on the chip does that.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and every xdist worker imports this file.
+Keep every such test in THIS file.
+"""
+
+import signal
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # libtpu installs a handler that prints a stack trace when this process
+    # is sent SIGTERM, which is how xdist ends its workers and how a suite
+    # cut by its clock ends: the text lands on pytest's progress line.
+    # Python had the default disposition; put it back.
+    signal.signal(signal.SIGTERM, signal.SIG_DFL)
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(arrays, sharding):
+    return [
+        jax.ShapeDtypeStruct(tuple(a.shape), a.dtype, sharding=sharding)
+        for a in arrays
+    ]
+
+
+def _compile_mk(mk, sharding, fuel=1 << 22, builder=None):
+    """Compile ``Megakernel.run``'s program: the bare pallas_call over the
+    shapes ``run`` would stage (as __graft_entry__.entry builds them)."""
+    from hclib_tpu.device.descriptor import TaskGraphBuilder
+
+    assert mk.interpret is False
+    tasks, succ, ring, counts = (builder or TaskGraphBuilder()).finalize(
+        capacity=mk.capacity, succ_capacity=mk.succ_capacity
+    )
+    args = [tasks, succ, ring, counts, np.zeros(mk.num_values, np.int32)]
+    args += list(mk.data_specs.values())
+    if mk.checkpoint:
+        args.append(np.zeros(8, np.int32))
+    return jax.jit(mk._build_raw(fuel)).lower(
+        *_shapes(args, sharding)
+    ).compile()
+
+
+# ---- one builder per kernel; each returns after the compiler accepted it
+
+
+def _fib_scalar(sh):
+    from hclib_tpu.device.workloads import make_fib_megakernel
+
+    _compile_mk(make_fib_megakernel(768, interpret=False), sh)
+
+
+def _fib_batch(sh):
+    from hclib_tpu.device.workloads import make_vfib_megakernel
+
+    _compile_mk(make_vfib_megakernel(max_n=32, interpret=False), sh,
+                fuel=1 << 30)
+
+
+def _uts_t1l(sh):
+    """Through uts_pallas itself, so the shapes are the ones its host
+    seeding derives for T1L; the jitted kernel is swapped for one that
+    compiles the real kernel for the described chip and stops there."""
+    import hclib_tpu.device.uts_pallas as up
+    from hclib_tpu.models.uts import T1L
+
+    class Compiled(Exception):
+        pass
+
+    real = up._uts_dfs_pallas
+
+    def compile_only(*args, **kw):
+        assert kw["interpret"] is False
+        real.lower(*_shapes(args, sh), **kw).compile()
+        raise Compiled
+
+    up._uts_dfs_pallas = compile_only
+    try:
+        with pytest.raises(Compiled):
+            up.uts_pallas(T1L, target_roots=256 * 1024, lanes=(64, 128),
+                          min_idle_div=32, interpret=False)
+    finally:
+        up._uts_dfs_pallas = real
+
+
+def _cholesky_8192(sh):
+    from hclib_tpu.device.cholesky import (
+        build_cholesky_graph, make_cholesky_megakernel,
+    )
+
+    nt = 8192 // 512
+    mk = make_cholesky_megakernel(
+        nt, interpret=False, tile=512, fused_only=True
+    )
+    _compile_mk(mk, sh, builder=build_cholesky_graph(nt))
+
+
+def _sw_fused(sh):
+    from hclib_tpu.device.sw_pallas import _sw_pallas
+
+    a = jax.ShapeDtypeStruct((1024, 1024), jnp.int32, sharding=sh)
+    _sw_pallas.lower(a, a, interpret=False).compile()  # default block_b
+
+
+def _sw_wave(sh):
+    from hclib_tpu.device.smithwaterman import (
+        T, build_sw_wave_graph, make_sw_wave_megakernel,
+    )
+
+    nt = 8192 // T
+    mk = make_sw_wave_megakernel(nt, nt, interpret=False, with_h=False)
+    _compile_mk(mk, sh, builder=build_sw_wave_graph(nt, nt))
+
+
+def _forasync_1d(sh):
+    from hclib_tpu.device.forasync_tier import make_forasync_megakernel
+    from hclib_tpu.device.workloads import map_loop
+
+    tk, _, _ = map_loop(64)
+    _compile_mk(make_forasync_megakernel(tk, width=8, interpret=False), sh)
+
+
+def _forasync_2d(sh):
+    from hclib_tpu.device.forasync_tier import make_forasync_megakernel
+    from hclib_tpu.device.workloads import stencil_loop
+
+    tk, _, _ = stencil_loop(64, 1024)
+    _compile_mk(make_forasync_megakernel(tk, width=8, interpret=False), sh)
+    _compile_mk(make_forasync_megakernel(tk, width=0, interpret=False), sh)
+
+
+def _serve_stream(sh):
+    """chip_smoke's serve phase: three tenants, egress mailbox, telemetry."""
+    from hclib_tpu.device.descriptor import RING_ROW, TaskGraphBuilder
+    from hclib_tpu.device.egress import EGR_WORDS, EgressSpec
+    from hclib_tpu.device.inject import StreamingMegakernel
+    from hclib_tpu.device.megakernel import Megakernel
+    from hclib_tpu.device.telemetry import LAT_BUCKETS, LAT_WORDS
+    from hclib_tpu.device.tenants import TenantSpec, TenantTable
+
+    def respond(ctx):
+        ctx.set_value(0, ctx.value(0) + ctx.arg(0))
+        ctx.set_out(ctx.arg(0) * 3 + 1)
+
+    T, region, cap, depth = 3, 1024, 320, 64
+    table = TenantTable(
+        [TenantSpec(t, weight=w) for t, w in
+         (("gold", 4), ("silver", 2), ("bronze", 1))],
+        region, egress=EgressSpec(depth=depth),
+    )
+    mk = Megakernel(kernels=[("respond", respond)], capacity=cap,
+                    num_values=8, succ_capacity=8, interpret=False)
+    sm = StreamingMegakernel(mk, ring_capacity=T * region, tenants=table,
+                             telemetry=True)
+    tasks, succ, ring0, counts = TaskGraphBuilder().finalize(
+        capacity=cap, succ_capacity=8
+    )
+    z = lambda *s: np.zeros(s, np.int32)  # noqa: E731
+    args = [  # _run_stream's argument order
+        tasks, succ, ring0, counts, z(8), z(sm.ring_capacity, RING_ROW),
+        z(8), z(T, 8), z(depth, EGR_WORDS), z(depth, EGR_WORDS), z(8),
+        z(cap), z(1 + T, LAT_BUCKETS), z(cap, LAT_WORDS),
+    ]
+    sm._build(1 << 10, 64).lower(*_shapes(args, sh)).compile()
+
+
+def _frontier(sh):
+    from hclib_tpu.device.frontier import (
+        _KINDS, Graph, make_frontier_megakernel,
+    )
+    from hclib_tpu.device.workloads import rmat_edges
+
+    g = Graph(*rmat_edges(9, efactor=8, seed=7))
+    _compile_mk(make_frontier_megakernel(
+        _KINDS["sssp"](), g, width=8, capacity=768, interpret=False,
+    ), sh)
+
+
+def _dyngraph(sh):
+    from hclib_tpu.device.dyngraph import DynGraph, make_dyngraph_megakernel
+    from hclib_tpu.device.workloads import rmat_edges
+
+    g = DynGraph(*rmat_edges(7, efactor=8, seed=7), spare_blocks=2,
+                 upd_cap=24)
+    _compile_mk(make_dyngraph_megakernel(
+        "sssp", g, width=8, capacity=768, interpret=False,
+    ), sh)
+
+
+def _bnb(sh):
+    from hclib_tpu.device.bnb import make_bnb_megakernel, make_knapsack
+
+    kp = make_knapsack(16, seed=5)
+    for buckets in (0, 8):  # default capacity: it must fit as shipped
+        _compile_mk(make_bnb_megakernel(
+            kp, width=4, priority_buckets=buckets, interpret=False,
+        ), sh)
+
+
+KERNELS = {
+    f.__name__.lstrip("_"): f
+    for f in (_fib_scalar, _fib_batch, _uts_t1l, _cholesky_8192, _sw_fused,
+              _sw_wave, _forasync_1d, _forasync_2d, _serve_stream,
+              _frontier, _dyngraph, _bnb)
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_v5e_compiler_accepts(kernel, one_chip):
+    KERNELS[kernel](one_chip)
+
+
+def test_v5e_compiler_accepts_resident_kernel_on_four_devices(topo):
+    """chip_smoke.py --four-chips' program: the shard_mapped resident
+    kernel over a mesh of the four described devices, every input sharded
+    over all of them, with the kernel and the termination collective in
+    the compiled module."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hclib_tpu.device.descriptor import TaskGraphBuilder
+    from hclib_tpu.device.megakernel import VBLOCK
+    from hclib_tpu.device.resident import ResidentKernel
+    from hclib_tpu.device.sharded import abort_words, partition_builders
+    from hclib_tpu.device.workloads import FIB, make_fib_megakernel
+
+    ndev, roots, cap = 4, 160, 640  # stress.forest_resident's defaults
+    mesh = Mesh(np.array(topo.devices).reshape(ndev), ("q",))
+    mk = make_fib_megakernel(
+        capacity=cap, interpret=False,
+        num_values=VBLOCK * cap + max(64, roots),
+    )
+    rk = ResidentKernel(mk, mesh, migratable_fns=[FIB], homed=False,
+                        window=16)
+    tasks, succ, ring, counts = partition_builders(
+        mk, ndev, [TaskGraphBuilder() for _ in range(ndev)]
+    )
+    args = [  # ResidentKernel.run's argument order, steal-only build
+        tasks, succ, ring, counts, np.zeros((ndev, mk.num_values), np.int32),
+        np.zeros((ndev, rk.max_waits + 1, 3), np.int32),
+        abort_words(None, ndev),
+    ]
+    compiled = rk._build(256, 1 << 14, None).lower(
+        *_shapes(args, NamedSharding(mesh, P("q")))
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def test_smem_footprint_check_names_the_capacity_that_fits():
+    """The SMEM check that replaces XLA's RESOURCE_EXHAUSTED: against the
+    v5e row of the per-device_kind table it refuses the default
+    capacity=4096 with the largest capacity that fits, accepts the 768
+    the benches use, and a compiled build runs it at construction."""
+    from hclib_tpu.device.megakernel import (
+        DEVICE_TABLE, device_row, smem_bytes,
+    )
+    from hclib_tpu.device.workloads import make_fib_megakernel
+
+    v5e = device_row("TPU v5 lite")
+    assert v5e is DEVICE_TABLE["TPU v5 lite"] and v5e["smem_bytes"] == 1 << 20
+    with pytest.raises(ValueError, match="no row"):
+        device_row("TPU v9 imaginary")
+    # a [capacity, 16] row pads to 128 lanes; 1-D arrays to 128..1024 words
+    assert smem_bytes((768, 16)) == 768 * 512
+    assert smem_bytes((8,)) == 512 and smem_bytes((4016,)) == 16384
+
+    make_fib_megakernel(768, interpret=True).check_smem(v5e)
+    big = make_fib_megakernel(4096, interpret=True)  # interpreter: no check
+    with pytest.raises(ValueError, match=r"largest capacity that fits.* 848;"):
+        big.check_smem(v5e)
+    with pytest.raises(ValueError, match="capacity=4096"):
+        make_fib_megakernel(4096, interpret=False)
+    # 968 is where the v5e compiler itself stops accepting this kernel.
+    make_fib_megakernel(968, interpret=True).check_smem(v5e)
+    with pytest.raises(ValueError, match="fits.* 968;"):
+        make_fib_megakernel(969, interpret=True).check_smem(v5e)

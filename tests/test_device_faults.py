@@ -16,7 +16,6 @@ import pytest
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.inject import StreamingMegakernel
 from hclib_tpu.device.megakernel import Megakernel
-from hclib_tpu.jaxcompat import has_mosaic_interpret
 from hclib_tpu.runtime.resilience import (
     CancelledError,
     CancelScope,
@@ -25,13 +24,6 @@ from hclib_tpu.runtime.resilience import (
 )
 
 pytestmark = pytest.mark.chaos
-
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs the Mosaic TPU interpret mode (pltpu.InterpretParams, "
-           "jax >= 0.5): the ICI mesh kernels simulate remote DMA + "
-           "semaphores on CPU",
-)
 
 BUMP = 0
 
@@ -245,7 +237,6 @@ def test_quarantine_locales_removes_dead_chip_paths():
 # ------------------------------------------------ mesh kernels (interpret)
 
 
-@needs_mosaic
 def test_abort_word_stops_resident_mesh_mid_run():
     """The host abort word stops a running 4-device mesh within one round
     (folded into the termination collective -> lockstep exit), leaving
@@ -261,7 +252,6 @@ def test_abort_word_stops_resident_mesh_mid_run():
     assert all(f["abort_round"] == 0 for f in info["fault_stats"])
 
 
-@needs_mosaic
 def test_abort_word_ici_ring_nonpof2():
     """The non-pof2 ring kernel polls the same abort word (folded into
     its ring allreduce)."""
@@ -280,7 +270,6 @@ def test_abort_word_ici_ring_nonpof2():
     assert info["steal_rounds"] <= 2
 
 
-@needs_mosaic
 def test_dead_chip_rehomes_and_survivors_drain_workload():
     """ACCEPTANCE: seeded dead chip on an 8-device interpret mesh. Every
     device holds work; device 3's scheduler dies at round 2 (wire stays
@@ -330,7 +319,6 @@ def test_dead_chip_rehomes_and_survivors_drain_workload():
     assert (iv2 == iv).all()
 
 
-@needs_mosaic
 def test_dropped_credit_regenerates_and_run_is_exact():
     """ACCEPTANCE (credit half): a dropped steal credit stalls its channel
     for credit_timeout rounds, then the writer regenerates it; the
@@ -353,7 +341,6 @@ def test_dropped_credit_regenerates_and_run_is_exact():
     assert (iv2 == iv).all()
 
 
-@needs_mosaic
 def test_dropped_credit_without_regeneration_raises_stallerror():
     """credit_timeout=0 disables regeneration: the mesh must exit in
     lockstep and raise StallError NAMING the starved channel - never
@@ -366,7 +353,6 @@ def test_dropped_credit_without_regeneration_raises_stallerror():
         rk.run(_skewed(2, 40), quantum=2, max_rounds=4096)
 
 
-@needs_mosaic
 def test_duplicated_credit_tolerated_exactly():
     """A duplicated credit must not corrupt flow control: the surplus is
     absorbed and the exit drain still balances every semaphore."""
@@ -382,7 +368,6 @@ def test_duplicated_credit_tolerated_exactly():
     assert info["fault_stats"][1]["credits_duplicated"] == 1
 
 
-@needs_mosaic
 def test_delayed_xfers_only_slow_the_run():
     """Seeded transfer delays reorder migration but never lose work."""
     ndev, ntasks = 2, 40

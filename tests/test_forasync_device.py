@@ -277,7 +277,7 @@ def _run_mesh(smk, placement, hop_order, quantum=2):
     counts = place_tiles(builders, BOUNDS, TILE, placement)
     stacked = {
         "gin": np.broadcast_to(GIN, (4,) + GIN.shape).copy(),
-        "gout": np.zeros((4,) + GIN.shape, np.int32),
+        "gout": np.zeros((4,) + GOUT0.shape, np.int32),
     }
     _, data, info = smk.run(
         builders, data=stacked, steal=True, quantum=quantum, window=4,
@@ -437,15 +437,7 @@ def test_lane_partial_age_quiet_on_static_tiles():
 
 # --------------------------------------- resident ready-ring seeding
 
-from hclib_tpu.jaxcompat import has_mosaic_interpret  # noqa: E402
 
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs pltpu.InterpretParams (jax >= 0.5)",
-)
-
-
-@needs_mosaic
 def test_resident_ring_seeding_follows_placement():
     """place_tiles seeds the RESIDENT runner's per-device ready rings the
     same way (placement is runner-agnostic data): with stealing disabled

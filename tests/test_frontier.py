@@ -524,15 +524,7 @@ def test_resident_hop_order_validation():
             rk._hop_bits(bad)
 
 
-from hclib_tpu.jaxcompat import has_mosaic_interpret  # noqa: E402
 
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs pltpu.InterpretParams (jax >= 0.5)",
-)
-
-
-@needs_mosaic
 def test_resident_frontier_bfs_with_graph_hop_order():
     """The resident runner consumes frontier descriptors (placement
     seeding is runner-agnostic data) and its XOR exchange takes the

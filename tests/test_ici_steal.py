@@ -106,7 +106,7 @@ def test_ici_steal_race_free_under_detector():
     target = smk._resident if smk._resident is not None else smk
     orig = target._build
 
-    def build_with_detector(quantum, max_rounds):
+    def build_with_detector(*build_args):
         import unittest.mock as m
 
         real = pltpu.InterpretParams
@@ -118,7 +118,7 @@ def test_ici_steal_race_free_under_detector():
             # detection, which needs the async on_wait DMA model.
             lambda **kw: real(detect_races=True),
         ):
-            return orig(quantum, max_rounds)
+            return orig(*build_args)
 
     target._build = build_with_detector
     iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=4)
@@ -203,15 +203,7 @@ def test_ici_steal_non_pof2_legacy_ring():
 
 # ------------------------------------- batched dispatch in the ring (ISSUE 7)
 
-from hclib_tpu.jaxcompat import has_mosaic_interpret  # noqa: E402
 
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs pltpu.InterpretParams (Mosaic TPU interpret mode)",
-)
-
-
-@needs_mosaic
 def test_ici_steal_batch_routed_bump_exact():
     """ISSUE 7 acceptance (ICI arm, pof2): a batch-routed mk through
     ICIStealMegakernel on a pof2 mesh - run() delegates to the resident
@@ -249,7 +241,6 @@ def test_ici_steal_batch_routed_bump_exact():
     assert int((per_dev > 0).sum()) >= 2, per_dev
 
 
-@needs_mosaic
 def test_ici_steal_batch_routed_non_pof2_ring():
     """The 3-device legacy ring (cycling partner + ring termination) runs
     this class's OWN kernel body - the only reachable one (pof2 meshes

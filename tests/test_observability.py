@@ -7,6 +7,7 @@ The reference's instrumentation recorder is stubbed (src/hclib-instrument.c:
 import time
 
 import numpy as np
+import pytest
 
 import hclib_tpu as hc
 from hclib_tpu.runtime.instrument import END, START, load_dump, register_event_type
@@ -128,36 +129,21 @@ def test_stats_format_contains_steals():
     assert executed >= 51
 
 
-def test_windowed_trials_stats_survive_sheared_trials():
-    """Slope-based trials can land nonpositive under clock shear; stats()
-    must exclude them from the pool but still count them in n_trials, and
-    degrade to a 0.0 'all-sheared' summary (never None) when every trial
-    sheared - bench.py formats median/best unconditionally."""
-    from hclib_tpu.runtime.clockprobe import WindowedTrials
+def test_bench_trials_drop_sheared_values():
+    """Slope-based trials can land on the -1.0 sheared sentinel
+    (bench._slope_or_sheared); bench.trials_of must keep them out of the
+    median but count them in n_trials, and a run whose every trial
+    sheared is a failure, not a 0.0 headline."""
+    import bench
 
-    class FakeProbe:
-        best = 50.0
-
-        def sample(self, note=""):
-            return 50.0
-
-        def is_fast(self, v):
-            return v > 40
-
-    wt = WindowedTrials("sheared", probe=FakeProbe(), log_dir=None)
-    for v in (-1.0, -2.0):
-        wt.run(lambda v=v: v)
-    s = wt.stats()
-    assert s["window"] == "all-sheared"
-    assert s["median"] == 0.0 and s["best"] == 0.0
-    assert s["n_trials"] == 2 and s["n_used"] == 0
-
-    wt2 = WindowedTrials("mixed", probe=FakeProbe(), log_dir=None)
-    for v in (5.0, -1.0, 7.0):
-        wt2.run(lambda v=v: v)
-    s2 = wt2.stats()
-    assert s2["median"] == 6.0
-    assert s2["n_trials"] == 3 and s2["n_used"] == 2 and s2["n_fast"] == 2
+    vals = iter((5.0, -1.0, 7.0))
+    s = bench.trials_of("mixed", lambda: next(vals), 3)
+    assert s["median"] == 6.0 and s["best"] == 7.0
+    assert s["n_trials"] == 3 and s["n_used"] == 2 and s["spread"] == 1.4
+    with pytest.raises(RuntimeError, match="sheared"):
+        bench.trials_of("sheared", lambda: -1.0, 2)
+    assert bench._slope_or_sheared(1e-4, 10.0) == -1.0
+    assert bench._slope_or_sheared(0.5, 10.0) == 20.0
 
 
 def test_event_log_external_lane_counts_non_worker_records(tmp_path):

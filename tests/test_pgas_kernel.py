@@ -257,7 +257,7 @@ def test_pgas_race_free_under_detector():
     target = pg._resident if pg._resident is not None else pg
     orig = target._build
 
-    def build_with_detector(quantum, max_rounds):
+    def build_with_detector(*build_args):
         import unittest.mock as m
 
         real = pltpu.InterpretParams
@@ -268,7 +268,7 @@ def test_pgas_race_free_under_detector():
             # race-detection semantics (same in test_resident/test_ici).
             lambda **kw: real(detect_races=True),
         ):
-            return orig(quantum, max_rounds)
+            return orig(*build_args)
 
     target._build = build_with_detector
     builders = [TaskGraphBuilder() for _ in range(ndev)]
@@ -315,15 +315,7 @@ def test_pgas_compiles_and_runs_on_tpu():
 
 # --------------------------------- batched dispatch under PGAS/AM (ISSUE 7)
 
-from hclib_tpu.jaxcompat import has_mosaic_interpret  # noqa: E402
 
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs pltpu.InterpretParams (Mosaic TPU interpret mode)",
-)
-
-
-@needs_mosaic
 def test_pgas_batch_routed_am_bumps_exact():
     """ISSUE 7 acceptance (PGAS arm): AM-delivered BUMP tasks fire through
     the batched same-kind tier - the lane scratch binds positionally at

@@ -117,7 +117,7 @@ def test_migration_race_free_under_detector():
     )
     orig = rk._build
 
-    def build_with_detector(quantum, max_rounds):
+    def build_with_detector(*build_args):
         import unittest.mock as m
 
         real = pltpu.InterpretParams
@@ -128,7 +128,7 @@ def test_migration_race_free_under_detector():
             # detection, which needs the async on_wait DMA model.
             lambda **kw: real(detect_races=True),
         ):
-            return orig(quantum, max_rounds)
+            return orig(*build_args)
 
     rk._build = build_with_detector
     builders = [TaskGraphBuilder() for _ in range(ndev)]
@@ -602,13 +602,6 @@ def test_resident_volume_stress_on_tpu():
 
 # ------------------------------------- batched dispatch on the mesh (ISSUE 7)
 
-from hclib_tpu.jaxcompat import has_mosaic_interpret  # noqa: E402
-
-needs_mosaic = pytest.mark.skipif(
-    not has_mosaic_interpret(),
-    reason="needs pltpu.InterpretParams (Mosaic TPU interpret mode)",
-)
-
 
 def _batched_fib_rk(ndev, batch_width=0, capacity=160, trace=None,
                     window=8):
@@ -627,7 +620,6 @@ def _batched_fib_rk(ndev, batch_width=0, capacity=160, trace=None,
     return rk, mk
 
 
-@needs_mosaic
 def test_mesh_batch_fib_matches_scalar_resident():
     """ISSUE 7 acceptance (resident arm): the batch-routed skewed fib
     mesh - homed migration, remote completions, the full round loop -
@@ -658,7 +650,6 @@ def test_mesh_batch_fib_matches_scalar_resident():
     assert int((per_dev > 0).sum()) >= 2, per_dev
 
 
-@needs_mosaic
 def test_mesh_batch_trace_reconciles_with_tstats():
     """Mesh TR_FIRE_BATCH records (the ROADMAP lane-firing-policy
     detector, now live on the mesh): per device, the flight-recorder
@@ -684,7 +675,6 @@ def test_mesh_batch_trace_reconciles_with_tstats():
         assert int(takes) == tiers[d]["batch_tasks"]
 
 
-@needs_mosaic
 @pytest.mark.chaos
 def test_mesh_batch_checkpoint_reshard_4_to_2():
     """Checkpoint/reshard with lanes ACTIVE: a batch-routed UTS mesh

@@ -1038,15 +1038,7 @@ def test_resident_mesh_tenancy_construction_and_off_path():
                        [TenantSpec("x")], 2, 16))
 
 
-needs_mosaic = pytest.mark.skipif(
-    not __import__(
-        "hclib_tpu.jaxcompat", fromlist=["has_mosaic_interpret"]
-    ).has_mosaic_interpret(),
-    reason="needs the Mosaic TPU interpret mode (jax >= 0.5)",
-)
 
-
-@needs_mosaic
 @pytest.mark.chaos
 def test_resident_mesh_tenant_wrr_and_quiesce_reshard():
     """DEVICE ACCEPTANCE (mesh half): the in-kernel WRR tenant poll on

@@ -342,13 +342,9 @@ def test_tracering_normalization_and_decode_validation():
 @pytest.mark.chaos
 def test_resident_mesh_trace_rings():
     """2-device resident run with the recorder on: per-device rings with
-    round records, reconciled against info (needs Mosaic interpret)."""
+    round records, reconciled against info."""
     import jax
     from jax.sharding import Mesh
-    from hclib_tpu.jaxcompat import has_mosaic_interpret
-
-    if not has_mosaic_interpret():
-        pytest.skip("needs pltpu.InterpretParams (Mosaic interpret mode)")
     from hclib_tpu.device.resident import ResidentKernel
     from hclib_tpu.device.workloads import (  # noqa: F401
         FIB,

@@ -65,7 +65,6 @@ def test_uts_pallas_t1xxl_exact_on_tpu():
 
     r = uts_pallas(
         T1XXL, target_roots=1024 * 1024, lanes=(64, 128), min_idle_div=32,
-        timing_reps=1,  # counts only; skip the best-of-3 rate protocol
     )
     assert r["nodes"] == 4_230_646_601
     assert r["leaves"] == 3_384_495_738

@@ -288,10 +288,6 @@ def scenario_procworld_crash(seed: int, scale: str) -> dict:
 # --------------------------------------------- device-mesh chaos (ISSUE 2)
 
 def _mesh_prereq():
-    from hclib_tpu.jaxcompat import has_mosaic_interpret
-
-    if not has_mosaic_interpret():
-        return "no Mosaic TPU interpret mode (needs jax >= 0.5)"
     import jax
 
     if len(jax.devices("cpu")) < 8:

@@ -95,19 +95,16 @@ def _suite(quick: bool) -> List[Tuple[str, Callable[[], dict]]]:
 
 def _device_suite(trials: int) -> List[Tuple[str, Callable[[], float], str]]:
     """TPU device engines: (name, fn -> rate, unit). Each fn measures its
-    own steady-state rate (slope harness, bench.py); --trials scales the
-    throttle-window spreading (1 = quick smoke, no sleeps)."""
+    own steady-state rate (slope harness, bench.py), compiled
+    (interpret=False stated in bench.py); --trials is the trial count."""
     import bench as b
 
-    spread = 8.0 if trials > 1 else 0.0
     return [
         ("device-fib-scalar", b.bench_device_fib, "tasks/s"),
         ("device-fib-batch", b.bench_device_vfib, "tasks/s"),
         (
             "device-cholesky",
-            lambda: b.bench_device_cholesky(
-                trials=max(1, trials), spread_seconds=spread
-            ) * 1e9,
+            lambda: b.bench_device_cholesky(trials=max(1, trials)) * 1e9,
             "FLOP/s",
         ),
         ("device-sw", lambda: b.bench_device_sw() * 1e9, "CUPS"),
@@ -115,9 +112,7 @@ def _device_suite(trials: int) -> List[Tuple[str, Callable[[], float], str]]:
             # The batched same-kind dispatch tier's flagship workload: the
             # wave-DAG SW chunks grouped + prefetched by the scheduler.
             "device-sw-wave",
-            lambda: b.bench_device_sw_wave(
-                trials=max(1, trials), spread_seconds=spread
-            ) * 1e9,
+            lambda: b.bench_device_sw_wave(trials=max(1, trials)) * 1e9,
             "CUPS",
         ),
         (
@@ -1116,11 +1111,10 @@ def main(argv=None) -> int:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8"
             ).strip()
-        os.environ.setdefault(
-            "JAX_COMPILATION_CACHE_DIR",
-            os.path.join(os.path.dirname(__file__), "..", ".jax_cache"),
-        )
         os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
+        from hclib_tpu.runtime.env import use_compile_cache
+
+        use_compile_cache()
 
     wanted = {a for a in args.apps.split(",") if a}
     prev = _latest_log(args.log_dir, args.quick)
@@ -1436,37 +1430,36 @@ def main(argv=None) -> int:
             print(line, flush=True)
 
     if args.device:
-        import jax
+        from hclib_tpu.device.megakernel import require_tpu
+        from hclib_tpu.runtime.env import use_compile_cache
 
-        if jax.default_backend() != "tpu":
-            print("--device: no TPU attached, skipping device suite",
-                  file=sys.stderr)
-        else:
-            for name, fn, unit in _device_suite(args.trials):
-                if wanted and name not in wanted:
+        use_compile_cache()
+        print(f"--device: {require_tpu()}", file=sys.stderr)  # or raise
+        for name, fn, unit in _device_suite(args.trials):
+            if wanted and name not in wanted:
+                continue
+            try:
+                val = fn()
+                if val is None:  # dependent entry whose producer
+                    print(f"{name:20s} SKIPPED (no data)",  # didn't run
+                          file=sys.stderr)
                     continue
-                try:
-                    val = fn()
-                    if val is None:  # dependent entry whose producer
-                        print(f"{name:20s} SKIPPED (no data)",  # didn't run
-                              file=sys.stderr)
-                        continue
-                    rate = float(val)
-                except Exception as e:  # one engine must not sink the log
-                    print(f"{name:20s} FAILED: {e}", file=sys.stderr)
-                    failures.append(f"{name}: failed ({e})")
-                    continue
-                results[name] = {"rate": rate, "unit": unit}
-                line = f"{name:20s} rate {rate:14.3e} {unit}"
-                if name in prev and "rate" in prev[name]:
-                    ratio = rate / prev[name]["rate"]
-                    line += f"  vs prev {ratio:5.2f}x"
-                    if ratio < 1 - args.tolerance:
-                        failures.append(
-                            f"{name}: {1/ratio:.2f}x slower than previous log"
-                        )
-                        line += "  REGRESSED"
-                print(line, flush=True)
+                rate = float(val)
+            except Exception as e:  # one engine must not sink the log
+                print(f"{name:20s} FAILED: {e}", file=sys.stderr)
+                failures.append(f"{name}: failed ({e})")
+                continue
+            results[name] = {"rate": rate, "unit": unit}
+            line = f"{name:20s} rate {rate:14.3e} {unit}"
+            if name in prev and "rate" in prev[name]:
+                ratio = rate / prev[name]["rate"]
+                line += f"  vs prev {ratio:5.2f}x"
+                if ratio < 1 - args.tolerance:
+                    failures.append(
+                        f"{name}: {1/ratio:.2f}x slower than previous log"
+                    )
+                    line += "  REGRESSED"
+            print(line, flush=True)
 
     ts = int(time.time())
     if args.multichip:

@@ -98,13 +98,7 @@ def part_two_events_and_telemetry() -> None:
 
 def part_three_autoscaled_mesh() -> None:
     """The real loop: a 2-device UTS mesh scales in on its idle tail,
-    totals exact across the resize. Needs the Mosaic interpret mode."""
-    from hclib_tpu.jaxcompat import has_mosaic_interpret
-
-    if not has_mosaic_interpret():
-        print("  (skipped: the resident mesh needs the Mosaic TPU "
-              "interpret mode, jax >= 0.5)")
-        return
+    totals exact across the resize."""
     import numpy as np
 
     from hclib_tpu.device.descriptor import TaskGraphBuilder
