@@ -379,11 +379,14 @@ class ResidentKernel:
             }
         else:
             self.migratable = {int(f): () for f in migratable_fns}
-        if self.migratable and self.homed:
-            # The scheduler must maintain descriptor home-link words on
-            # spawn/continuation transfer (plain megakernels skip these
-            # scalar writes - see Megakernel.tracks_home).
-            mk.tracks_home = True
+        # The scheduler must maintain descriptor home-link words on
+        # spawn/continuation transfer (plain megakernels skip these
+        # scalar writes - see Megakernel.tracks_home). homed=False too:
+        # export eligibility (migrate-once), the import fix-up and
+        # CheckpointBundle.reshard all read F_HOME, and a row spawned
+        # into a never-staged table slot would otherwise carry whatever
+        # the buffer held there.
+        mk.tracks_home = True
         # A claimed kernel id outside the table would silently never
         # migrate (the whitelist is a per-kind mask) - refuse
         # unconditionally, verifier on or off.

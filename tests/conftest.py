@@ -30,22 +30,49 @@ use_compile_cache()
 # on 1-vCPU hosts (see megakernel.interpret_mode).
 
 import faulthandler  # noqa: E402
+import sys  # noqa: E402
 
 import pytest  # noqa: E402
+
+# A device thread of the Mosaic interpreter that waits for a remote DMA
+# spins in pure Python (jax's Semaphore.wait with has_tasks=True), so it
+# gives the GIL up only when the switch interval forces it to, and every
+# kernel load, store and semaphore operation of the other device threads
+# (one io_callback each) first waits that interval out. At CPython's
+# default of 5 ms a 2-device fib(6) mesh run of 7.5 k callbacks took
+# 50.0 s; at 0.1 ms it takes 8.2 s with the same rounds and per-device
+# counts (ISSUE 27, measured on this host).
+sys.setswitchinterval(1e-4)
 
 # Stack dumps must BYPASS pytest's stderr capture (captured output dies
 # with the os._exit the watchdog fires), so they go to an on-disk log
 # next to this file; the handle stays open for the whole session.
+# Appended to: the worker xdist starts in a dead one's place would
+# otherwise empty the log of the stacks it was started over.
 _WEDGE_LOG = open(
     os.path.join(os.path.dirname(os.path.abspath(__file__)),
                  ".wedge_traceback.log"),
-    "w",
+    "a",
 )
+
+
+# The one time limit a test has (seconds), and what a test gives a child
+# process it waits for (tutorial lessons, apps): less, so that the child
+# is reaped by its test and not orphaned by the watchdog's exit.
+WEDGE_SECONDS = 240
+CHILD_SECONDS = WEDGE_SECONDS - 30
 
 
 @pytest.fixture(autouse=True)
 def _wedge_watchdog():
-    """Hard per-test ceiling (15 min; the slowest test is ~2 min loaded).
+    """Hard per-test ceiling: WEDGE_SECONDS, four times what the slowest
+    test should take.
+
+    Measured by ISSUE 27 with the driver's command (six workers on 8
+    cores): under the 900 s limit this replaces the slowest tests took
+    328 s to over 900 s, and a worker killed by the limit under `--dist
+    loadfile` hangs the session until the outer clock cuts it; after the
+    cuts the slowest tests take about a minute (table in CHANGES.md).
 
     The Mosaic interpreter's io_callback machinery can SPORADICALLY wedge
     on 1-vCPU hosts even with the strict default InterpretParams (device
@@ -55,7 +82,9 @@ def _wedge_watchdog():
     faulthandler's timer CAN: it dumps every thread's stack (to
     tests/.wedge_traceback.log, see above) and exits, so a wedged run
     fails loudly with evidence instead of hanging forever."""
-    faulthandler.dump_traceback_later(900, exit=True, file=_WEDGE_LOG)
+    faulthandler.dump_traceback_later(
+        WEDGE_SECONDS, exit=True, file=_WEDGE_LOG
+    )
     yield
     faulthandler.cancel_dump_traceback_later()
 
@@ -78,8 +107,6 @@ def _clean_modules():
 def timeline_mod():
     """Import tools/timeline.py (shared by the observability tests so the
     sys.path dance lives in ONE place)."""
-    import sys
-
     tools = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"
     )
@@ -89,3 +116,87 @@ def timeline_mod():
     finally:
         sys.path.remove(tools)
     return timeline
+
+
+def fib_exec_count(n):
+    """Descriptors the scalar tier executes for fib(n): every FIB node
+    plus one SUM continuation per internal node (``task_count`` counts
+    FIB calls only). Shared by the resident-mesh test files."""
+    from hclib_tpu.models.fib import task_count
+
+    t = task_count(n)
+    return t + (t - 1) // 2
+
+
+BUMP = 0  # bump_kernel's id in bump_mk's one-entry kernel table
+
+
+def bump_kernel(ctx):
+    """The kernel most runner tests share: add the argument to value slot
+    0, so a run's total is the sum of what was submitted (per device; the
+    host sums across devices)."""
+    ctx.set_value(0, ctx.value(0) + ctx.arg(0))
+
+
+def bump_mk(capacity=128, num_values=4, **kw):
+    """A Megakernel whose one kernel is ``bump_kernel``."""
+    from hclib_tpu.device.megakernel import Megakernel
+
+    kw.setdefault("interpret", True)
+    return Megakernel(
+        kernels=[("bump", bump_kernel)], capacity=capacity,
+        num_values=num_values, succ_capacity=8, **kw,
+    )
+
+
+def skewed_builders(ndev, ntasks, dev=0):
+    """bump(1) .. bump(ntasks) all on device ``dev``'s queue; the other
+    devices start empty."""
+    from hclib_tpu.device.descriptor import TaskGraphBuilder
+
+    builders = [TaskGraphBuilder() for _ in range(ndev)]
+    for i in range(ntasks):
+        builders[dev].add(BUMP, args=[i + 1])
+    return builders
+
+
+def seed_builder():
+    """One bump(1000): the graph a stream test starts its kernel on."""
+    from hclib_tpu.device.descriptor import TaskGraphBuilder
+
+    b = TaskGraphBuilder()
+    b.add(BUMP, args=[1000])
+    return b
+
+
+def uts_mesh_rk(ndev, max_depth, fault_plan=None, **mk_kw):
+    """A checkpoint-enabled UTS megakernel on an ``ndev``-device resident
+    mesh. homed=False: UTS rows are link-free (count-accumulate only), so
+    whole-row migration suffices - and it keeps the quiesced state
+    proxy-free, which is what makes N -> M re-homing legal (reshard
+    refuses linked rows)."""
+    from hclib_tpu.device.resident import ResidentKernel
+    from hclib_tpu.device.workloads import UTS_NODE, make_uts_megakernel
+    from hclib_tpu.parallel.mesh import cpu_mesh
+
+    mk_kw.setdefault("checkpoint", True)
+    mk = make_uts_megakernel(max_depth=max_depth, interpret=True, **mk_kw)
+    return ResidentKernel(
+        mk, cpu_mesh(ndev, axis_name="q"), migratable_fns=[UTS_NODE],
+        window=4, homed=False, fault_plan=fault_plan,
+    )
+
+
+def uts_mesh_builders(ndev, per_dev=1, depth=0):
+    """UTS roots 1 .. ndev * per_dev at ``depth``, ``per_dev`` to each of
+    ``ndev`` builders in order (a root at the kernel's max_depth is a
+    leaf)."""
+    from hclib_tpu.device.descriptor import TaskGraphBuilder
+    from hclib_tpu.device.workloads import UTS_NODE
+
+    builders = [TaskGraphBuilder() for _ in range(ndev)]
+    for d in range(ndev):
+        for r in range(per_dev):
+            builders[d].add(UTS_NODE, args=[d * per_dev + r + 1, depth])
+    return builders
+

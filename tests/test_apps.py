@@ -9,6 +9,7 @@ import subprocess
 import sys
 
 import numpy as np
+from conftest import CHILD_SECONDS
 
 import hclib_tpu as hc
 from hclib_tpu.models import fft, nqueens, sort
@@ -75,7 +76,7 @@ def test_perf_regression_harness_quick(tmp_path):
         [sys.executable, "tools/perf_regression.py", "--quick", "--trials", "1",
          "--log-dir", str(tmp_path),
          "--apps", "fib,nqueens,qsort,cilksort,fft,fib-ddt"],
-        capture_output=True, text=True, timeout=300,
+        capture_output=True, text=True, timeout=CHILD_SECONDS,
     )
     assert out.returncode == 0, out.stderr
     assert "fib" in out.stdout and "log written" in out.stdout
@@ -83,7 +84,7 @@ def test_perf_regression_harness_quick(tmp_path):
     out2 = subprocess.run(
         [sys.executable, "tools/perf_regression.py", "--quick", "--trials", "1",
          "--log-dir", str(tmp_path), "--tolerance", "1000", "--apps", "fib"],
-        capture_output=True, text=True, timeout=300,
+        capture_output=True, text=True, timeout=CHILD_SECONDS,
     )
     assert out2.returncode == 0, out2.stderr
     assert "vs prev" in out2.stdout

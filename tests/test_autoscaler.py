@@ -1,15 +1,12 @@
 """Elastic autoscaling (ISSUE 6): the metrics-driven quiesce -> reshard
--> resume control loop.
+-> resume control loop, the host-only half.
 
 The policy half is PURE (observation in, decision out) and is tested
 headless - hysteresis, cooldown, the no-flap guarantee, and the
 evacuation fast path need no mesh and no Mosaic. The control loop's
 telemetry (typed ScaleEvents -> MetricsRegistry + TR_SCALE host ring ->
-Perfetto) is host-only too. The end-to-end mesh runs (scale out under
-backlog, dead-chip evacuation mid-stream, preemption checkpoint of an
-autoscaled deployment, totals bit-identical to an uninterrupted run)
-need the Mosaic interpret mode and ride the chaos marker like the other
-mesh tests.
+Perfetto) and the program-cache probe are host-only too. The end-to-end
+mesh runs are in test_autoscaler_mesh.py.
 """
 
 import threading
@@ -19,7 +16,6 @@ import pytest
 
 import hclib_tpu as hc
 from hclib_tpu.device.tracebuf import TR_SCALE, records_of
-from hclib_tpu.runtime import resilience
 
 
 # ---------------------------------------------------------- policy, pure
@@ -216,125 +212,6 @@ def test_autoscaler_off_path_is_inert():
     assert n1 == n2 and i1["executed"] == i2["executed"]
 
 
-# ------------------------------------------------------- mesh end-to-end
-
-
-def _uts_kernel_factory(depth, dead_on_4=None, seed=0):
-    from hclib_tpu.device.resident import ResidentKernel
-    from hclib_tpu.device.workloads import UTS_NODE, make_uts_megakernel
-    from hclib_tpu.parallel.mesh import cpu_mesh
-
-    def make_kernel(ndev):
-        plan = None
-        if dead_on_4 is not None and ndev == 4:
-            plan = hc.DeviceFaultPlan(
-                seed=seed, dead_device=dead_on_4, dead_round=2,
-                heartbeat_timeout=2,
-            )
-        mk = make_uts_megakernel(seed=19 + seed, max_depth=depth,
-                                 interpret=True, checkpoint=True)
-        return ResidentKernel(
-            mk, cpu_mesh(ndev, axis_name="q"),
-            migratable_fns=[UTS_NODE], window=4, homed=False,
-            fault_plan=plan,
-        )
-
-    return make_kernel
-
-
-def _uts_builders(ndev, roots=8):
-    from hclib_tpu.device.descriptor import TaskGraphBuilder
-    from hclib_tpu.device.workloads import UTS_NODE
-
-    bs = [TaskGraphBuilder() for _ in range(ndev)]
-    for d in range(ndev):
-        for r in range(roots):
-            bs[d].add(UTS_NODE, args=[d * roots + r + 1, 0])
-    return bs
-
-
-@pytest.mark.chaos
-def test_autoscale_storm_evacuates_dead_chip_totals_exact():
-    """ACCEPTANCE (the storm): an autoscaled UTS mesh scales OUT under
-    seeded backlog, the dead chip on the 4-device mesh is quarantined
-    and EVACUATED mid-stream, the idle tail scales IN - >= 3 typed
-    ScaleEvents including the evacuation - and the final totals are
-    bit-identical to an uninterrupted fault-free run (zero task loss)."""
-    make_kernel = _uts_kernel_factory(6, dead_on_4=3)
-    iv_f, _, info_f = _uts_kernel_factory(6)(2).run(
-        _uts_builders(2), quantum=8, max_rounds=1 << 14,
-    )
-    total = int(np.asarray(iv_f)[:, 0].sum())
-
-    reg = hc.MetricsRegistry()
-    asc = hc.Autoscaler(
-        make_kernel,
-        hc.AutoscalerPolicy(min_devices=1, max_devices=4,
-                            scale_out_backlog=4.0, scale_in_backlog=1.0,
-                            hysteresis=1, cooldown=1),
-        slice_rounds=8, metrics=reg,
-    )
-    iv, _, info = asc.run(_uts_builders(2), quantum=8)
-    assert info["pending"] == 0
-    assert int(np.asarray(iv)[:, 0].sum()) == total
-    assert info["executed"] == info_f["executed"]
-    kinds = [e["kind"] for e in info["scale_events"]]
-    assert len(info["scale_events"]) >= 3, kinds
-    assert "evacuate" in kinds, kinds
-    ev = next(e for e in info["scale_events"] if e["kind"] == "evacuate")
-    assert ev["from_ndev"] == 4 and ev["to_ndev"] == 2
-    assert ev["resize_latency_s"] is not None
-    snap = reg.snapshot()["metrics"]
-    assert snap["autoscale.evacuate.count"] >= 1.0
-    recs = records_of(asc.trace_info(), TR_SCALE)
-    assert len(recs) == len(info["scale_events"])
-
-
-@pytest.mark.chaos
-def test_autoscale_preempt_checkpoints_and_resumes():
-    """Preemption of an autoscaled deployment: the notice lands between
-    slices, the controller checkpoints (bundle on disk) and stops; a
-    fresh Autoscaler continues from the bundle and the totals are
-    exact."""
-    import os
-    import tempfile
-
-    make_kernel = _uts_kernel_factory(6, seed=1)
-    iv_f, _, info_f = make_kernel(2).run(
-        _uts_builders(2), quantum=8, max_rounds=1 << 14,
-    )
-    total = int(np.asarray(iv_f)[:, 0].sum())
-
-    resilience.reset_preempt()
-    ckdir = tempfile.mkdtemp(prefix="hclib-autoscale-")
-    asc = hc.Autoscaler(
-        make_kernel,
-        hc.AutoscalerPolicy(min_devices=1, max_devices=2,
-                            scale_out_backlog=1e9,
-                            scale_in_backlog=0.0, hysteresis=1),
-        slice_rounds=4, checkpoint_dir=ckdir,
-    )
-    try:
-        resilience.fire_preempt("test preemption")
-        iv, _, info = asc.run(_uts_builders(2), quantum=2)
-    finally:
-        resilience.reset_preempt()
-    assert info.get("preempted") is True
-    assert info["pending"] > 0  # genuinely mid-graph
-    assert os.path.isdir(info["bundle_path"])
-    assert [e["kind"] for e in info["scale_events"]][-1] == "checkpoint"
-
-    asc2 = hc.Autoscaler(make_kernel, hc.AutoscalerPolicy(
-        min_devices=1, max_devices=2, scale_out_backlog=1e9,
-        scale_in_backlog=0.0, hysteresis=1,
-    ), slice_rounds=1 << 12)
-    iv2, _, info2 = asc2.run(resume_bundle=info["bundle_path"],
-                             quantum=8)
-    assert info2["pending"] == 0
-    assert int(np.asarray(iv2)[:, 0].sum()) == total
-    assert info2["executed"] == info_f["executed"]
-
-
 # ------------------- tenant/deadline-aware policy (ISSUE 13), pure
 
 
@@ -523,50 +400,5 @@ def test_program_cached_probe_reads_process_cache():
         assert stats["hit"] is False
         assert rk().program_cached(quantum=8) is True
         assert rk().program_cached(quantum=16) is False
-    finally:
-        progcache.reset()
-
-
-@pytest.mark.chaos
-def test_autoscale_resizes_with_both_shapes_warm_hit_cache():
-    """ACCEPTANCE (ISSUE 18): with both mesh shapes pre-warmed by
-    content-identical kernels, every controller resize reports
-    cache_hit=True and the whole autoscaled run performs ZERO new
-    trace/lower work (the process-wide miss counter does not move)."""
-    from hclib_tpu.runtime import progcache
-
-    make_kernel = _uts_kernel_factory(6)
-    progcache.reset()
-    try:
-        # Pre-warm BOTH shapes with fresh instances (their private jit
-        # tables die with them; only the process cache carries over).
-        for ndev in (2, 4):
-            make_kernel(ndev).run(
-                _uts_builders(ndev), quantum=8, max_rounds=1 << 14,
-            )
-        warm = progcache.cache_stats()
-        assert warm["misses"] >= 2 and warm["entries"] >= 2
-
-        asc = hc.Autoscaler(
-            make_kernel,
-            hc.AutoscalerPolicy(min_devices=1, max_devices=4,
-                                scale_out_backlog=4.0,
-                                scale_in_backlog=1.0,
-                                hysteresis=1, cooldown=1),
-            slice_rounds=8,
-        )
-        iv, _, info = asc.run(_uts_builders(2), quantum=8)
-        assert info["pending"] == 0
-        resizes = [
-            e for e in info["scale_events"]
-            if e["from_ndev"] != e["to_ndev"]
-        ]
-        assert resizes, info["scale_events"]
-        assert all(e["cache_hit"] is True for e in resizes), resizes
-        # Zero rebuilds anywhere in the run: every slice's program came
-        # from the registry (hits moved, misses did not).
-        after = progcache.cache_stats()
-        assert after["misses"] == warm["misses"]
-        assert after["hits"] > warm["hits"]
     finally:
         progcache.reset()

@@ -18,6 +18,7 @@ import sys
 
 import numpy as np
 import pytest
+from conftest import CHILD_SECONDS
 
 from hclib_tpu.analysis.model import certify_claim, certify_dyngraph_schedule
 from hclib_tpu.analysis.races import check_splice
@@ -317,7 +318,7 @@ def _offpath_hash(extra: str) -> str:
     ))
     out = subprocess.run(
         [sys.executable, "-c", _OFFPATH_SCRIPT.format(extra=extra)],
-        capture_output=True, text=True, env=env, timeout=300,
+        capture_output=True, text=True, env=env, timeout=CHILD_SECONDS,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     return out.stdout.strip().splitlines()[-1]

@@ -10,6 +10,7 @@ Reference counterpart: thief-side deque CAS across cores
 import jax
 import numpy as np
 import pytest
+from conftest import bump_kernel, bump_mk, skewed_builders
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.ici_steal import ICIStealMegakernel
@@ -19,37 +20,16 @@ from hclib_tpu.parallel.mesh import cpu_mesh
 BUMP = 0
 
 
-def _bump_kernel(ctx):
-    ctx.set_value(0, ctx.value(0) + ctx.arg(0))
-
-
-def _make_mk(capacity=256):
-    return Megakernel(
-        kernels=[("bump", _bump_kernel)],
-        capacity=capacity,
-        num_values=4,
-        succ_capacity=8,
-        interpret=True,
-    )
-
-
-def _skewed(ndev, ntasks):
-    builders = [TaskGraphBuilder() for _ in range(ndev)]
-    for i in range(ntasks):
-        builders[0].add(BUMP, args=[i + 1])
-    return builders
-
-
 def test_ici_steal_rebalances_skewed_load():
     # (8-device spread coverage lives in the hypercube test below and the
     # resident skewed-fib test; 4 devices keep this one's semantics at a
     # quarter of the interpret cost.)
     ndev, ntasks = 4, 28
     smk = ICIStealMegakernel(
-        _make_mk(capacity=64), cpu_mesh(ndev, axis_name="queues"),
+        bump_mk(64), cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=8,
     )
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=8)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=8)
     assert info["pending"] == 0
     assert info["executed"] == ntasks
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
@@ -60,10 +40,10 @@ def test_ici_steal_rebalances_skewed_load():
 def test_ici_steal_two_devices_exact():
     ndev, ntasks = 2, 16
     smk = ICIStealMegakernel(
-        _make_mk(capacity=64), cpu_mesh(ndev, axis_name="queues"),
+        bump_mk(64), cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=8,
     )
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=8)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=8)
     assert info["pending"] == 0
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
     assert info["per_device_counts"][1, 5] > 0  # work actually migrated
@@ -98,7 +78,7 @@ def test_ici_steal_race_free_under_detector():
 
     ndev, ntasks = 2, 12
     smk = ICIStealMegakernel(
-        _make_mk(), cpu_mesh(ndev, axis_name="queues"),
+        bump_mk(256), cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=4,
     )
     # Rebuild with the race detector on (pof2 meshes delegate to the
@@ -121,7 +101,7 @@ def test_ici_steal_race_free_under_detector():
             return orig(*build_args)
 
     target._build = build_with_detector
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=4)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=4)
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
 
 
@@ -133,12 +113,12 @@ def test_ici_steal_compiles_and_runs_on_tpu():
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("queues",))
     mk = Megakernel(
-        kernels=[("bump", _bump_kernel)],
+        kernels=[("bump", bump_kernel)],
         capacity=256, num_values=4, succ_capacity=8, interpret=False,
     )
     smk = ICIStealMegakernel(mesh=mesh, mk=mk, migratable_fns=[BUMP])
     ntasks = 100
-    iv, _, info = smk.run(_skewed(1, ntasks), quantum=16)
+    iv, _, info = smk.run(skewed_builders(1, ntasks), quantum=16)
     assert info["pending"] == 0
     assert int(iv[0, 0]) == ntasks * (ntasks + 1) // 2
 
@@ -150,10 +130,10 @@ def test_ici_steal_hypercube_spreads_max_skew_fast():
     round, vs. one fixed window to a single partner per round)."""
     ndev, ntasks = 8, 48
     smk = ICIStealMegakernel(
-        _make_mk(capacity=128), cpu_mesh(ndev, axis_name="queues"),
+        bump_mk(128), cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=16,
     )
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=8)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=8)
     assert info["pending"] == 0
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
     per_dev = info["per_device_counts"][:, 5]
@@ -173,7 +153,7 @@ def test_ici_steal_2d_mesh_exact():
     mesh = make_mesh((2, 2), ("r", "c"), cpus[:4])
     ntasks = 20
     smk = ICIStealMegakernel(
-        _make_mk(capacity=64), mesh, migratable_fns=[BUMP], window=8,
+        bump_mk(64), mesh, migratable_fns=[BUMP], window=8,
     )
     builders = [TaskGraphBuilder() for _ in range(4)]
     for i in range(ntasks):
@@ -191,10 +171,10 @@ def test_ici_steal_non_pof2_legacy_ring():
     stay exact."""
     ndev, ntasks = 3, 18
     smk = ICIStealMegakernel(
-        _make_mk(), cpu_mesh(ndev, axis_name="queues"),
+        bump_mk(256), cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=8,
     )
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=4)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=4)
     assert info["pending"] == 0
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
     per_dev = info["per_device_counts"][:, 5]
@@ -215,19 +195,12 @@ def test_ici_steal_batch_routed_bump_exact():
     from hclib_tpu.device.workloads import batch_of
 
     ndev, ntasks = 4, 28
-    mk = Megakernel(
-        kernels=[("bump", _bump_kernel)],
-        capacity=64,
-        num_values=4,
-        succ_capacity=8,
-        interpret=True,
-        route={"bump": batch_of(_bump_kernel, width=4)},
-    )
+    mk = bump_mk(64, route={"bump": batch_of(bump_kernel, width=4)})
     smk = ICIStealMegakernel(
         mk, cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=8,
     )
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=8)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=8)
     assert info["pending"] == 0
     assert info["executed"] == ntasks
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
@@ -249,19 +222,12 @@ def test_ici_steal_batch_routed_non_pof2_ring():
     from hclib_tpu.device.workloads import batch_of
 
     ndev, ntasks = 3, 18
-    mk = Megakernel(
-        kernels=[("bump", _bump_kernel)],
-        capacity=64,
-        num_values=4,
-        succ_capacity=8,
-        interpret=True,
-        route={"bump": batch_of(_bump_kernel, width=4)},
-    )
+    mk = bump_mk(64, route={"bump": batch_of(bump_kernel, width=4)})
     smk = ICIStealMegakernel(
         mk, cpu_mesh(ndev, axis_name="queues"),
         migratable_fns=[BUMP], window=8,
     )
-    iv, _, info = smk.run(_skewed(ndev, ntasks), quantum=4)
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), quantum=4)
     assert info["pending"] == 0
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
     tiers = info["tiers"]

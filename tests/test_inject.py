@@ -8,24 +8,13 @@ import time
 
 import jax
 import pytest
+from conftest import bump_mk
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.inject import StreamingMegakernel
-from hclib_tpu.device.megakernel import Megakernel
 from hclib_tpu.device.workloads import FIB, make_fib_megakernel
 
 BUMP = 0
-
-
-def _bump_kernel(ctx):
-    ctx.set_value(0, ctx.value(0) + ctx.arg(0))
-
-
-def _bump_mk(interpret=True):
-    return Megakernel(
-        kernels=[("bump", _bump_kernel)],
-        capacity=128, num_values=4, succ_capacity=8, interpret=interpret,
-    )
 
 
 def fib(n):
@@ -44,7 +33,7 @@ def tree_tasks(n):
 def test_ring_rows_discovered_by_in_kernel_poll():
     """Injected rows are NEVER staged with the graph - they can only enter
     through the in-kernel ring poll; exact totals prove that path."""
-    sm = StreamingMegakernel(_bump_mk(), ring_capacity=64)
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=64)
     b = TaskGraphBuilder()
     b.add(BUMP, args=[1000])
     for i in range(20):
@@ -86,7 +75,7 @@ def test_concurrent_feeder_thread():
 
 
 def test_inject_after_close_raises():
-    sm = StreamingMegakernel(_bump_mk(), ring_capacity=8)
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=8)
     sm.close()
     with pytest.raises(RuntimeError):
         sm.inject(BUMP, args=[1])
@@ -95,7 +84,7 @@ def test_inject_after_close_raises():
 @pytest.mark.skipif(jax.default_backend() != "tpu", reason="needs TPU")
 def test_streaming_on_tpu():
     """The ring poll + install path through real Mosaic lowering."""
-    sm = StreamingMegakernel(_bump_mk(interpret=False), ring_capacity=64)
+    sm = StreamingMegakernel(bump_mk(interpret=False), ring_capacity=64)
     b = TaskGraphBuilder()
     b.add(BUMP, args=[7])
     for i in range(10):

@@ -8,6 +8,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import timeline_mod as _timeline
 
 import hclib_tpu as hc
 from hclib_tpu.runtime.instrument import END, START, load_dump, register_event_type
@@ -182,12 +183,6 @@ def test_watchdog_stall_event_lands_in_external_lane(tmp_path, caplog):
     # (writing worker 0's lock-free buffer from another thread was a
     # race).
     assert rt.event_log.external_records >= 1
-
-
-def _timeline():
-    from conftest import timeline_mod
-
-    return timeline_mod()
 
 
 def test_spans_from_events_empty_and_open_paths(tmp_path):

@@ -12,6 +12,7 @@ from types import SimpleNamespace
 
 import numpy as np
 import pytest
+from conftest import CHILD_SECONDS
 
 import hclib_tpu as hc
 from hclib_tpu.models import fib, uts
@@ -428,7 +429,7 @@ def _run_soak(extra):
         + extra,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=280,
+        capture_output=True, text=True, timeout=CHILD_SECONDS,
     )
 
 

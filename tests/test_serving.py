@@ -19,8 +19,8 @@ import os
 
 import numpy as np
 import pytest
+from conftest import bump_mk, seed_builder
 
-from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.egress import (
     EC_CONSUMED,
     EC_PARK_COUNT,
@@ -43,26 +43,9 @@ from hclib_tpu.device.egress import (
     normalize_egress,
 )
 from hclib_tpu.device.inject import StreamingMegakernel
-from hclib_tpu.device.megakernel import Megakernel
 from hclib_tpu.device.tenants import MeshTenantTable, TenantSpec, TenantTable
 
 BUMP = 0
-
-
-def _bump_mk(checkpoint=False):
-    def bump(ctx):
-        ctx.set_value(0, ctx.value(0) + ctx.arg(0))
-
-    return Megakernel(
-        kernels=[("bump", bump)], capacity=128, num_values=4,
-        succ_capacity=8, interpret=True, checkpoint=checkpoint,
-    )
-
-
-def _seed_builder():
-    b = TaskGraphBuilder()
-    b.add(BUMP, args=[1000])
-    return b
 
 
 def _table(specs=None, region=16, egress=None, clock=None):
@@ -308,7 +291,7 @@ def test_stream_serve_futures_resolve_with_parking():
         [TenantSpec("gold", weight=4), TenantSpec("silver")],
         egress=EgressSpec(depth=4),
     )
-    sm = StreamingMegakernel(_bump_mk(), ring_capacity=32, tenants=table)
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=32, tenants=table)
     futs = []
     for i in range(8):
         adm = sm.submit("gold", BUMP, args=[i + 1])
@@ -317,7 +300,7 @@ def test_stream_serve_futures_resolve_with_parking():
     for _ in range(4):
         futs.append(sm.submit("silver", BUMP, args=[100]).future)
     sm.close()
-    iv, info = sm.run_stream(_seed_builder())
+    iv, info = sm.run_stream(seed_builder())
     assert int(iv[0]) == 1000 + sum(range(1, 9)) + 400
     for f in futs:
         assert isinstance(f.result(timeout=2.0), int)
@@ -335,11 +318,11 @@ def test_stream_quiesce_preempts_then_reattaches_across_resume():
     ledgers."""
     t1 = _table([TenantSpec("x"), TenantSpec("y")], region=32,
                 egress=EgressSpec(depth=64))
-    sm = StreamingMegakernel(_bump_mk(checkpoint=True),
+    sm = StreamingMegakernel(bump_mk(checkpoint=True),
                              ring_capacity=64, tenants=t1)
     futs = [sm.submit("x", BUMP, args=[1]).future for _ in range(10)]
     sm.quiesce(after_executed=3)
-    _, info = sm.run_stream(_seed_builder())
+    _, info = sm.run_stream(seed_builder())
     assert info["quiesced"] and "etok" in info["state"]
     assert {f.state for f in futs} <= {"RESULT", "PREEMPTED"}
     tokens = []
@@ -354,7 +337,7 @@ def test_stream_quiesce_preempts_then_reattaches_across_resume():
     assert c1["ok"] and c1["preempted"] == len(tokens)
     t2 = _table([TenantSpec("x"), TenantSpec("y")], region=32,
                 egress=EgressSpec(depth=64))
-    sm2 = StreamingMegakernel(_bump_mk(checkpoint=True),
+    sm2 = StreamingMegakernel(bump_mk(checkpoint=True),
                               ring_capacity=64, tenants=t2)
     sm2.close()
     iv2, _ = sm2.run_stream(resume_state=info["state"])
@@ -377,17 +360,17 @@ def test_resume_onto_tiny_mailbox_reseeds_inflight_credit():
         return _table([TenantSpec("x"), TenantSpec("y")], region=32,
                       egress=EgressSpec(depth=4))
 
-    sm = StreamingMegakernel(_bump_mk(checkpoint=True),
+    sm = StreamingMegakernel(bump_mk(checkpoint=True),
                              ring_capacity=64, tenants=table())
     futs = [sm.submit("x" if i % 2 else "y", BUMP, args=[i + 1]).future
             for i in range(14)]
     sm.quiesce(after_executed=4)
-    _, info = sm.run_stream(_seed_builder())
+    _, info = sm.run_stream(seed_builder())
     assert info["quiesced"]
     tokens = [f.resume_token for f in futs if f.state == "PREEMPTED"]
     assert len(tokens) > 4, "need more adopted tokens than the depth"
     t2 = table()
-    sm2 = StreamingMegakernel(_bump_mk(checkpoint=True),
+    sm2 = StreamingMegakernel(bump_mk(checkpoint=True),
                               ring_capacity=64, tenants=t2)
     sm2.close()
     iv2, _ = sm2.run_stream(resume_state=info["state"])
@@ -404,11 +387,11 @@ def test_stream_abort_poisons_outstanding_futures():
     in the mailbox resolve, every other outstanding future poisons
     (typed raise, no hang)."""
     t = _table(egress=EgressSpec(depth=64), region=32)
-    sm = StreamingMegakernel(_bump_mk(), ring_capacity=32, tenants=t)
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=32, tenants=t)
     futs = [sm.submit("a", BUMP, args=[1]).future for _ in range(5)]
     sm.abort("client disconnect")
     with pytest.raises(Exception, match="abort"):
-        sm.run_stream(_seed_builder())
+        sm.run_stream(seed_builder())
     for f in futs:
         assert f.state in ("RESULT", "POISONED")
         if f.state == "POISONED":
@@ -423,7 +406,7 @@ def test_stream_abort_poisons_outstanding_futures():
 
 def _lower_text(sm):
     mk = sm.mk
-    tasks, succ, ready, counts = _seed_builder().finalize(
+    tasks, succ, ready, counts = seed_builder().finalize(
         capacity=mk.capacity, succ_capacity=mk.succ_capacity
     )
     args = [
@@ -452,12 +435,12 @@ def test_off_path_builds_compile_zero_egress_words(monkeypatch):
     build lowers cleanly and differs (the words exist only on-path)."""
     monkeypatch.delenv("HCLIB_TPU_EGRESS_DEPTH", raising=False)
     base = _lower_text(
-        StreamingMegakernel(_bump_mk(), ring_capacity=32, tenants=["a"])
+        StreamingMegakernel(bump_mk(), ring_capacity=32, tenants=["a"])
     )
     monkeypatch.setenv("HCLIB_TPU_EGRESS_DEPTH", "64")
     off = _lower_text(
         StreamingMegakernel(
-            _bump_mk(), ring_capacity=32,
+            bump_mk(), ring_capacity=32,
             tenants=TenantTable([TenantSpec("a")], 32,
                                 clock=lambda: 0.0, egress=False),
         )
@@ -465,7 +448,7 @@ def test_off_path_builds_compile_zero_egress_words(monkeypatch):
     assert off == base
     on = _lower_text(
         StreamingMegakernel(
-            _bump_mk(), ring_capacity=32,
+            bump_mk(), ring_capacity=32,
             tenants=TenantTable([TenantSpec("a")], 32,
                                 clock=lambda: 0.0,
                                 egress=EgressSpec(depth=8)),

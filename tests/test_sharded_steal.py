@@ -5,6 +5,7 @@ mesh)."""
 import jax
 import numpy as np
 import pytest
+from conftest import bump_kernel, bump_mk, skewed_builders
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.megakernel import Megakernel
@@ -14,36 +15,12 @@ from hclib_tpu.parallel.mesh import cpu_mesh
 BUMP = 0
 
 
-def _bump_kernel(ctx):
-    # Location-independent counter task: accumulate arg0 into value slot 0
-    # (per device; the host sums across devices).
-    ctx.set_value(0, ctx.value(0) + ctx.arg(0))
-
-
-def _make_mk(capacity=512):
-    return Megakernel(
-        kernels=[("bump", _bump_kernel)],
-        capacity=capacity,
-        num_values=4,
-        succ_capacity=8,
-        interpret=True,
-    )
-
-
-def _skewed_builders(ndev, ntasks):
-    """All work lands on device 0's queue; the rest start empty."""
-    builders = [TaskGraphBuilder() for _ in range(ndev)]
-    for i in range(ntasks):
-        builders[0].add(BUMP, args=[i + 1])
-    return builders
-
-
 def test_steal_rebalances_skewed_load():
     ndev, ntasks = 8, 200
     mesh = cpu_mesh(ndev, axis_name="queues")
-    smk = ShardedMegakernel(_make_mk(), mesh, migratable_fns=[BUMP])
+    smk = ShardedMegakernel(bump_mk(512), mesh, migratable_fns=[BUMP])
     iv, _, info = smk.run(
-        _skewed_builders(ndev, ntasks), steal=True, quantum=8, window=16
+        skewed_builders(ndev, ntasks), steal=True, quantum=8, window=16
     )
     assert info["pending"] == 0
     assert info["executed"] == ntasks
@@ -59,8 +36,8 @@ def test_steal_rebalances_skewed_load():
 def test_no_steal_keeps_static_partition():
     ndev, ntasks = 8, 64
     mesh = cpu_mesh(ndev, axis_name="queues")
-    smk = ShardedMegakernel(_make_mk(), mesh, migratable_fns=[BUMP])
-    iv, _, info = smk.run(_skewed_builders(ndev, ntasks), steal=False)
+    smk = ShardedMegakernel(bump_mk(512), mesh, migratable_fns=[BUMP])
+    iv, _, info = smk.run(skewed_builders(ndev, ntasks), steal=False)
     per_dev = info["per_device_counts"][:, 5]
     assert int(per_dev[0]) == ntasks  # everything ran where it was placed
     assert int(iv[0, 0]) == ntasks * (ntasks + 1) // 2
@@ -69,7 +46,7 @@ def test_no_steal_keeps_static_partition():
 def test_steal_with_balanced_load_still_correct():
     ndev, ntasks = 4, 120
     mesh = cpu_mesh(ndev, axis_name="queues")
-    smk = ShardedMegakernel(_make_mk(), mesh, migratable_fns=[BUMP])
+    smk = ShardedMegakernel(bump_mk(512), mesh, migratable_fns=[BUMP])
     builders = [TaskGraphBuilder() for _ in range(ndev)]
     for i in range(ntasks):
         builders[i % ndev].add(BUMP, args=[1])
@@ -164,7 +141,7 @@ def test_non_migratable_head_does_not_block_export():
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = Megakernel(
         kernels=[("stay", lambda ctx: ctx.set_value(1, ctx.value(1) + 1)),
-                 ("bump", _bump_kernel)],
+                 ("bump", bump_kernel)],
         capacity=512, num_values=4, succ_capacity=8, interpret=True,
     )
     smk = ShardedMegakernel(mk, mesh, migratable_fns=[1])  # bump only
@@ -203,7 +180,7 @@ def test_steal_heavy_run_reuses_rows_everywhere():
     ndev, ntasks = 8, 600
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = Megakernel(
-        kernels=[("spawner", _spawner_kernel), ("bump", _bump_kernel)],
+        kernels=[("spawner", _spawner_kernel), ("bump", bump_kernel)],
         capacity=64, num_values=4, succ_capacity=8, interpret=True,
     )
     smk = ShardedMegakernel(mk, mesh, migratable_fns=[1])

@@ -22,6 +22,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import bump_mk, seed_builder, timeline_mod as _timeline
 
 import hclib_tpu as hc
 from hclib_tpu.device.descriptor import (
@@ -29,11 +30,9 @@ from hclib_tpu.device.descriptor import (
     TEN_ADMIT_ROUND,
     TEN_ID,
     TEN_TOKEN,
-    TaskGraphBuilder,
 )
 from hclib_tpu.device.egress import EGR_WORDS, EgressSpec, HostMailbox
 from hclib_tpu.device.inject import StreamingMegakernel
-from hclib_tpu.device.megakernel import Megakernel
 from hclib_tpu.device.telemetry import (
     LAT_BUCKETS,
     LAT_WORDS,
@@ -59,22 +58,6 @@ from hclib_tpu.runtime.slo import SloEstimator, parse_windows
 BUMP = 0
 
 
-def _bump_mk(checkpoint=False):
-    def bump(ctx):
-        ctx.set_value(0, ctx.value(0) + ctx.arg(0))
-
-    return Megakernel(
-        kernels=[("bump", bump)], capacity=128, num_values=4,
-        succ_capacity=8, interpret=True, checkpoint=checkpoint,
-    )
-
-
-def _seed_builder():
-    b = TaskGraphBuilder()
-    b.add(BUMP, args=[1000])
-    return b
-
-
 def _table(specs=None, region=32, depth=64):
     return TenantTable(
         specs or [TenantSpec("a", queue_capacity=64),
@@ -85,7 +68,7 @@ def _table(specs=None, region=32, depth=64):
 
 def _stream(checkpoint=False, telemetry=True, **kw):
     return StreamingMegakernel(
-        _bump_mk(checkpoint=checkpoint), ring_capacity=64,
+        bump_mk(checkpoint=checkpoint), ring_capacity=64,
         tenants=_table(**kw), telemetry=telemetry,
     )
 
@@ -181,11 +164,11 @@ def test_telemetry_requires_egress_stream():
     telemetry build without an egress-enabled tenant stream is a
     loud construction error, not a silent no-op."""
     with pytest.raises(ValueError, match="egress"):
-        StreamingMegakernel(_bump_mk(), ring_capacity=32,
+        StreamingMegakernel(bump_mk(), ring_capacity=32,
                             telemetry=True)
     with pytest.raises(ValueError, match="egress"):
         StreamingMegakernel(
-            _bump_mk(), ring_capacity=32,
+            bump_mk(), ring_capacity=32,
             tenants=TenantTable([TenantSpec("a")], 16,
                                 clock=lambda: 0.0),
             telemetry=True,
@@ -194,7 +177,7 @@ def test_telemetry_requires_egress_stream():
 
 def _lower_text(sm):
     mk = sm.mk
-    tasks, succ, ready, counts = _seed_builder().finalize(
+    tasks, succ, ready, counts = seed_builder().finalize(
         capacity=mk.capacity, succ_capacity=mk.succ_capacity
     )
     args = [
@@ -245,7 +228,7 @@ def test_device_histograms_reconcile_with_spans_and_ledger():
         assert adm
         futs[tid].append(adm.future)
     sm.close()
-    iv, info = sm.run_stream(_seed_builder())
+    iv, info = sm.run_stream(seed_builder())
     assert int(iv[0]) == 1000 + 12
     snap = sm.telemetry_snapshot()
     assert snap is not None and snap["entries"] >= 1
@@ -278,7 +261,7 @@ def test_device_quantiles_within_one_bucket_of_exact_stamps():
     for i in range(16):
         assert sm.submit(i % 2, BUMP, args=[1])
     sm.close()
-    sm.run_stream(_seed_builder(), max_rounds=8)
+    sm.run_stream(seed_builder(), max_rounds=8)
     blk = TelemetryBlock(sm.telemetry_snapshot()["tele"])
     deltas = sorted(
         fire - admit
@@ -303,7 +286,7 @@ def test_live_stream_scraped_midrun_two_monotone_snapshots():
     sm.close()
     poller = TelemetryPoller(sm.telemetry_snapshot,
                              interval_s=0.001).start()
-    sm.run_stream(_seed_builder(), max_rounds=4)
+    sm.run_stream(seed_builder(), max_rounds=4)
     midrun = len(poller.snapshots)
     poller.stop(final_poll=True)
     assert midrun >= 2, "poller never caught the stream mid-run"
@@ -332,7 +315,7 @@ def test_quiesce_resume_conserves_histograms():
     t1 = sm.tenants
     futs = [sm.submit("a", BUMP, args=[1]).future for _ in range(8)]
     sm.quiesce(after_executed=3)
-    _, info = sm.run_stream(_seed_builder())
+    _, info = sm.run_stream(seed_builder())
     assert info["quiesced"]
     state = info["state"]
     assert "tele" in state and "tlat" in state
@@ -609,12 +592,6 @@ class _FakeFuture:
         self.token = token
         self.t_submit = t_submit
         self.t_done = t_done
-
-
-def _timeline():
-    from conftest import timeline_mod
-
-    return timeline_mod()
 
 
 def test_request_flow_events_join_host_and_device_stamps():

@@ -12,6 +12,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import bump_mk, seed_builder
 
 from hclib_tpu.device.descriptor import (
     RING_ROW,
@@ -20,7 +21,6 @@ from hclib_tpu.device.descriptor import (
     TaskGraphBuilder,
 )
 from hclib_tpu.device.inject import StreamingMegakernel
-from hclib_tpu.device.megakernel import Megakernel
 from hclib_tpu.device.tenants import (
     ADMIT_ACCEPTED,
     ADMIT_QUEUED,
@@ -460,7 +460,7 @@ def test_export_resume_conserves_per_tenant_counts():
 def test_submit_wait_true_blocks_through_transient_rejection():
     """submit(wait=True) converts a dry token bucket into a bounded
     blocking wait; terminal rejections (quarantine) return immediately."""
-    mk = _bump_mk()
+    mk = bump_mk()
     sm = StreamingMegakernel(
         mk, ring_capacity=32,
         tenants=[TenantSpec("a", rate=50.0, burst=1.0)],
@@ -478,7 +478,7 @@ def test_submit_wait_true_blocks_through_transient_rejection():
     assert time.monotonic() - t0 < 1.0  # terminal: no blocking
     # Wait respects the submission's own deadline.
     sm2 = StreamingMegakernel(
-        _bump_mk(), ring_capacity=32,
+        bump_mk(), ring_capacity=32,
         tenants=[TenantSpec("b", rate=0.01, burst=1.0)],
     )
     sm2.submit("b", BUMP, args=[1])
@@ -568,7 +568,7 @@ def test_submit_wait_timeout_is_wall_clock_bounded():
     clock (whose token bucket therefore never refills) must yield a
     bounded 'rate' rejection, not an unbounded spin."""
     sm = StreamingMegakernel(
-        _bump_mk(), ring_capacity=32,
+        bump_mk(), ring_capacity=32,
         tenants=TenantTable(
             [TenantSpec("a", rate=10.0, burst=1.0)], 32,
             clock=lambda: 0.0,
@@ -584,29 +584,12 @@ def test_submit_wait_timeout_is_wall_clock_bounded():
 # -------------------------------------------------------------- device
 
 
-def _bump_mk(checkpoint=False, trace=None):
-    def bump(ctx):
-        ctx.set_value(0, ctx.value(0) + ctx.arg(0))
-
-    return Megakernel(
-        kernels=[("bump", bump)], capacity=128, num_values=4,
-        succ_capacity=8, interpret=True, checkpoint=checkpoint,
-        trace=trace,
-    )
-
-
-def _seed_builder():
-    b = TaskGraphBuilder()
-    b.add(BUMP, args=[1000])
-    return b
-
-
 def test_stream_wrr_exact_totals_and_stats_fold():
     """DEVICE: a 3-lane weighted stream executes every admitted task
     exactly once (value algebra proves it) and stats_dict names each
     tenant's counters - the StallError-names-the-tenant satellite."""
     sm = StreamingMegakernel(
-        _bump_mk(), ring_capacity=96,
+        bump_mk(), ring_capacity=96,
         tenants=[TenantSpec("gold", weight=4), TenantSpec("silver",
                  weight=2), TenantSpec("bronze")],
     )
@@ -616,7 +599,7 @@ def test_stream_wrr_exact_totals_and_stats_fold():
             sm.submit(tid, BUMP, args=[k + 1])
             expect += k + 1
     sm.close()
-    iv, info = sm.run_stream(_seed_builder())
+    iv, info = sm.run_stream(seed_builder())
     assert int(iv[0]) == expect
     ten = info["tenants"]
     assert ten["gold"]["completed"] == 6
@@ -631,11 +614,11 @@ def test_stream_wrr_exact_totals_and_stats_fold():
     assert late.rejected and late.reason == "closed"
     # inject() sugar routes through the default (first) lane.
     sm2 = StreamingMegakernel(
-        _bump_mk(), ring_capacity=32, tenants=2,
+        bump_mk(), ring_capacity=32, tenants=2,
     )
     sm2.inject(BUMP, args=[7])
     sm2.close()
-    iv2, info2 = sm2.run_stream(_seed_builder())
+    iv2, info2 = sm2.run_stream(seed_builder())
     assert int(iv2[0]) == 1007
     assert info2["tenants"]["t0"]["completed"] == 1
 
@@ -648,7 +631,7 @@ def test_stream_greedy_and_poisoned_tenants_isolated():
         raise RuntimeError("boom")
 
     sm = StreamingMegakernel(
-        _bump_mk(), ring_capacity=96,
+        bump_mk(), ring_capacity=96,
         tenants=[
             TenantSpec("bad", validator=poison, poison_throttle=1,
                        poison_quarantine=2),
@@ -674,7 +657,7 @@ def test_stream_greedy_and_poisoned_tenants_isolated():
         assert sm.submit("victim", BUMP, args=[100])
         expect += 100
     sm.close()
-    iv, info = sm.run_stream(_seed_builder())
+    iv, info = sm.run_stream(seed_builder())
     assert int(iv[0]) == expect      # no poison row ever executed
     ten = info["tenants"]
     assert ten["victim"]["completed"] == 12
@@ -691,7 +674,7 @@ def test_stream_tenant_quiesce_resume_conserves_counts():
     final value is bit-identical to an uninterrupted run."""
     def fresh(n=64):
         return StreamingMegakernel(
-            _bump_mk(checkpoint=True), ring_capacity=n,
+            bump_mk(checkpoint=True), ring_capacity=n,
             tenants=["x", "y", "z"],
         )
 
@@ -703,7 +686,7 @@ def test_stream_tenant_quiesce_resume_conserves_counts():
         for _ in range(n):
             sm.submit(tid, BUMP, args=[i + 1])
     sm.quiesce(after_executed=4)
-    iv, info = sm.run_stream(_seed_builder())
+    iv, info = sm.run_stream(seed_builder())
     assert info["quiesced"] is True
     st = info["state"]
     res = per_tenant_ring_counts(st["ring_rows"])
@@ -718,13 +701,13 @@ def test_stream_tenant_quiesce_resume_conserves_counts():
     bundle = snapshot_stream(sm, info)
     assert bundle.meta["tenants"] == ["x", "y", "z"]
     reordered = StreamingMegakernel(
-        _bump_mk(checkpoint=True), ring_capacity=64,
+        bump_mk(checkpoint=True), ring_capacity=64,
         tenants=["y", "x", "z"],
     )
     with pytest.raises(CheckpointError, match="roster"):
         restore_stream(bundle, reordered)
     plain = StreamingMegakernel(
-        _bump_mk(checkpoint=True), ring_capacity=64,
+        bump_mk(checkpoint=True), ring_capacity=64,
     )
     with pytest.raises(CheckpointError, match="roster"):
         restore_stream(bundle, plain)
@@ -741,7 +724,7 @@ def test_stream_tenant_quiesce_resume_conserves_counts():
         for _ in range(n):
             sm3.submit(tid, BUMP, args=[i + 1])
     sm3.close()
-    iv3, _ = sm3.run_stream(_seed_builder())
+    iv3, _ = sm3.run_stream(seed_builder())
     assert int(iv3[0]) == int(iv2[0])
 
 
@@ -1008,20 +991,20 @@ def test_resident_mesh_tenancy_construction_and_off_path():
     from hclib_tpu.parallel.mesh import cpu_mesh
 
     rk_off = ResidentKernel(
-        _bump_mk(checkpoint=True), cpu_mesh(2, axis_name="q"),
+        bump_mk(checkpoint=True), cpu_mesh(2, axis_name="q"),
         inject=True,
     )
     assert rk_off.T == 0 and rk_off.tenant_specs is None
     assert rk_off.region_rows == 0
     rk = ResidentKernel(
-        _bump_mk(checkpoint=True), cpu_mesh(2, axis_name="q"),
+        bump_mk(checkpoint=True), cpu_mesh(2, axis_name="q"),
         inject=True, tenants=["x", "y", "z"], ring_capacity=96,
     )
     assert rk.T == 3
     assert rk.ring_capacity == rk.T * rk.region_rows
     assert rk.region_rows % 8 == 0
     with pytest.raises(ValueError, match="inject=True"):
-        ResidentKernel(_bump_mk(), cpu_mesh(2, axis_name="q"),
+        ResidentKernel(bump_mk(), cpu_mesh(2, axis_name="q"),
                        tenants=2)
     builders = [TaskGraphBuilder() for _ in range(2)]
     # Rows enter only through the table on a tenant mesh.
@@ -1059,7 +1042,7 @@ def test_resident_mesh_tenant_wrr_and_quiesce_reshard():
 
     def make(ndev):
         return ResidentKernel(
-            _bump_mk(checkpoint=True), cpu_mesh(ndev, axis_name="q"),
+            bump_mk(checkpoint=True), cpu_mesh(ndev, axis_name="q"),
             migratable_fns=[BUMP], homed=False, window=4, inject=True,
             tenants=specs(), ring_capacity=96,
         )

@@ -11,18 +11,13 @@ import json
 
 import numpy as np
 import pytest
+from conftest import timeline_mod as _timeline
 from jax.experimental import pallas as pl
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.megakernel import BatchSpec, Megakernel
 from hclib_tpu.device import tracebuf as tb
 from hclib_tpu.runtime.resilience import StallError
-
-
-def _timeline():
-    from conftest import timeline_mod
-
-    return timeline_mod()
 
 
 DOUBLE, NEG = 0, 1

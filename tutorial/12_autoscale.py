@@ -107,7 +107,7 @@ def part_three_autoscaled_mesh() -> None:
     from hclib_tpu.parallel.mesh import cpu_mesh
 
     def make_kernel(ndev):
-        mk = make_uts_megakernel(max_depth=6, interpret=True,
+        mk = make_uts_megakernel(max_depth=5, interpret=True,
                                  checkpoint=True)
         return ResidentKernel(
             mk, cpu_mesh(ndev, axis_name="q"),
