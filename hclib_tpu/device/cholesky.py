@@ -31,6 +31,7 @@ FLOP/s) / (probe/3) - bench.py prints both.
 
 from __future__ import annotations
 
+import contextlib
 import functools as _ft
 import time
 from typing import Optional, Tuple
@@ -40,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.profiler import TraceAnnotation
 
 from ..ops.tiles import (
     dma_copy as _dma,
@@ -476,6 +478,30 @@ def cholesky_buffers(a: np.ndarray, nt: int, tile: int = T) -> dict:
     }
 
 
+@_ft.partial(jax.jit, static_argnums=1)
+def _tiles_on_device(x, ts: int):
+    """``_to_tiles``' layout, made where the matrix already is:
+    (n, n) -> (nt, nt, ts, ts)."""
+    nt = x.shape[0] // ts
+    return x.reshape(nt, ts, nt, ts).swapaxes(1, 2)
+
+
+@jax.jit
+def _tril_on_device(tiles):
+    """``np.tril(_from_tiles(tiles))`` before the download:
+    (nt, nt, ts, ts) -> (n, n), +0.0 above the diagonal."""
+    nt, _, ts, _ = tiles.shape
+    return jnp.tril(tiles.swapaxes(1, 2).reshape(nt * ts, nt * ts))
+
+
+def _layout_device(mk: Megakernel):
+    """Where the two layout functions run: ``Megakernel._execute``'s rule,
+    so an interpreter run on a machine with a chip stays on the host CPU."""
+    if mk.interpret:
+        return jax.default_device(jax.devices("cpu")[0])
+    return contextlib.nullcontext()
+
+
 def device_cholesky(
     a: np.ndarray,
     interpret: Optional[bool] = None,
@@ -484,7 +510,21 @@ def device_cholesky(
     fused_trsm: bool = True,
     batch_updrow: bool = True,
 ) -> Tuple[np.ndarray, dict]:
-    """Factor SPD ``a`` ((nt*tile)^2) on-device; returns (L, info)."""
+    """Factor SPD ``a`` ((nt*tile)^2) on-device; returns (L, info).
+
+    What a call costs: one contiguous upload of ``a`` (as it is when it is
+    C-contiguous float32; anything else pays one host pass,
+    ``np.ascontiguousarray(a, np.float32)``, first), one kernel, and one
+    contiguous download of ``L``. The tile layout going in and the
+    un-tiling and ``tril`` coming out are two small jitted functions that
+    run on the device around the kernel. ``L`` is C-contiguous float32
+    with exactly 0.0 above the diagonal, and it is what ``np.asarray`` of
+    a device array gives: possibly READ-ONLY, so copy it before writing
+    into it. ``a`` is only read.
+
+    Three profiler spans split the call for a traced run:
+    ``bench:chol.upload`` (to the tiled buffer ready on the device),
+    ``bench:chol.run`` (``Megakernel.run``) and ``bench:chol.download``."""
     n = a.shape[0]
     if n % tile != 0:
         raise ValueError(f"matrix size must be a multiple of {tile}")
@@ -494,10 +534,24 @@ def device_cholesky(
             nt, interpret, tile=tile, batch_updrow=batch_updrow
         )
     b = build_cholesky_graph(nt, fused_trsm=fused_trsm)
+    # No pass and no copy for a C-contiguous float32 matrix.
+    a = np.ascontiguousarray(a, dtype=np.float32)
     t0 = time.perf_counter()
-    _, data, info = mk.run(b, data=cholesky_buffers(a, nt, tile))
+    with TraceAnnotation("bench:chol.upload"), _layout_device(mk):
+        # Nothing is donated: on the CPU backend device_put may alias the
+        # caller's memory, and the (n, n) buffer is freed as soon as the
+        # tiling has run anyway, since nothing else refers to it.
+        data = {
+            "tiles": _tiles_on_device(jax.device_put(a), tile),
+            "linvsp": jnp.zeros((nt, 2, tile, tile), jnp.bfloat16),
+            "lsp": jnp.zeros((nt, nt, 2, tile, tile), jnp.bfloat16),
+        }
+        data["tiles"].block_until_ready()
+    with TraceAnnotation("bench:chol.run"):
+        _, data, info = mk.run(b, data=data)
     dt = time.perf_counter() - t0
-    L = np.tril(_from_tiles(data["tiles"], nt, tile))
+    with TraceAnnotation("bench:chol.download"), _layout_device(mk):
+        L = np.asarray(_tril_on_device(data["tiles"]))
     info = dict(info)
     info["seconds"] = dt
     info["gflops"] = (n**3 / 3.0) / dt / 1e9

@@ -48,6 +48,79 @@ def test_device_cholesky_interpret_blocked_potrf():
     assert info["executed"] == 4
 
 
+def _strided_view(a32):
+    wide = np.zeros((a32.shape[0], 2 * a32.shape[1]), np.float32)
+    wide[:, ::2] = a32
+    return wide[:, ::2]
+
+
+# Every form rounds to the same C-contiguous float32 matrix.
+INPUT_FORMS = {
+    "float32": lambda a64: a64.astype(np.float32),
+    "float64": lambda a64: a64,
+    "fortran": lambda a64: np.asfortranarray(a64.astype(np.float32)),
+    "view": lambda a64: _strided_view(a64.astype(np.float32)),
+}
+
+
+@pytest.fixture(scope="module", params=[(256, 128), (512, 256)],
+                ids=["n256-t128", "n512-t256"])
+def parent_path(request):
+    """(a64, mk, tile, L) with L from the host-staged path device_cholesky
+    took before its layout moved to the device: numpy tiles in, numpy
+    tiles out, np.tril."""
+    from hclib_tpu.device.cholesky import (
+        _from_tiles, cholesky_buffers, make_cholesky_megakernel,
+    )
+
+    n, tile = request.param
+    nt = n // tile
+    a64 = make_spd(n, seed=n)
+    mk = make_cholesky_megakernel(nt, interpret=True, tile=tile)
+    a32 = np.ascontiguousarray(a64, np.float32)
+    _, data, _ = mk.run(
+        build_cholesky_graph(nt), data=cholesky_buffers(a32, nt, tile)
+    )
+    return a64, mk, tile, np.tril(_from_tiles(data["tiles"], nt, tile))
+
+
+@pytest.mark.parametrize("form", INPUT_FORMS)
+def test_device_cholesky_layout_on_device(parent_path, form):
+    """The tile layout, the cast and the tril now run on the device: L is
+    bit-identical to the host-staged path's whatever dtype and layout the
+    caller's matrix has, and the caller's matrix is only read."""
+    a64, mk, tile, L_parent = parent_path
+    a = INPUT_FORMS[form](a64)
+    before = a.copy()
+    L, info = device_cholesky(a, mk=mk, tile=tile)
+    assert np.array_equal(L, L_parent)
+    assert L.dtype == np.float32 and L.flags.c_contiguous
+    upper = L[np.triu_indices_from(L, 1)]
+    assert not upper.any() and not np.signbit(upper).any()  # +0.0
+    assert info["executed"] == 4  # nt = 2: 2 POTRF, 1 TRSMCOL, 1 UPDROW
+    assert np.array_equal(a, before)
+
+
+def test_device_cholesky_layout_compiles_once_per_shape(parent_path):
+    """The two layout functions are jitted per shape, not per call or per
+    Megakernel: a second call, and one through a fresh Megakernel of the
+    same shape, add nothing to their caches."""
+    from hclib_tpu.device import cholesky
+    from hclib_tpu.device.cholesky import make_cholesky_megakernel
+
+    a64, mk, tile, _ = parent_path
+    a = a64.astype(np.float32)
+    fns = (cholesky._tiles_on_device, cholesky._tril_on_device)
+    device_cholesky(a, mk=mk, tile=tile)
+    warm = [f._cache_size() for f in fns]
+    assert min(warm) >= 1
+    device_cholesky(a, mk=mk, tile=tile)
+    mk2 = make_cholesky_megakernel(a.shape[0] // tile, interpret=True,
+                                   tile=tile)
+    device_cholesky(a, mk=mk2, tile=tile)
+    assert [f._cache_size() for f in fns] == warm
+
+
 def test_device_sw_interpret_multi_tile():
     a, b = random_seq(256, 3), random_seq(384, 4)
     score, h, info = device_sw(a, b, interpret=True)
