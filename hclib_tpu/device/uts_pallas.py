@@ -39,18 +39,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.profiler import TraceAnnotation
 
-
-from ..models.uts import FIXED, UTSParams
-from .megakernel import ran_on, resolve_interpret
+from ..models.uts import UTSParams
+from .megakernel import resolve_interpret
 from .uts_vec import (
     LANES,
     PAD_QUANTUM,
-    _host_seed,
-    _timed_best,
+    _engine_shape,
+    _launch_once,
+    _seeded,
     apply_claim,
-    child_thresholds,
-    depth_cap,
     inrow_threshold_table,
     make_traversal,
     padded_threshold_table,
@@ -137,7 +136,7 @@ def _dfs_kernel(
     tab_ref,  # VMEM (K, 128): in-row threshold table ((1,128) dummy when
     # the shape is depth-independent - kernels cannot capture constants)
     nodes_ref, leaves_ref, maxd_ref,  # VMEM lanes, outputs
-    ctl_ref,  # SMEM (2,): steps, unfinished
+    ctl_ref,  # SMEM (3,): steps, unfinished, refill rounds
     wstate, wcount, sems,  # scratch: (5, winrows, 128), (winrows, 128), DMA
 ) -> None:
     rows, cols = lanes
@@ -190,12 +189,13 @@ def _dfs_kernel(
         S, lanes, thresholds, gen_mx, min_idle, max_steps, refill, R,
         inrow_table=tab_ref[...] if thresholds is None else None,
     )
-    sp, next_root, nodes, leaves, maxd, steps = run()
+    sp, next_root, nodes, leaves, maxd, steps, refills = run()
     nodes_ref[...] = nodes
     leaves_ref[...] = leaves
     maxd_ref[...] = maxd
     ctl_ref[0] = steps
     ctl_ref[1] = (jnp.any(sp >= 0) | (next_root < R)).astype(jnp.int32)
+    ctl_ref[2] = refills
 
 
 @functools.partial(
@@ -233,7 +233,7 @@ def _uts_dfs_pallas(
             jax.ShapeDtypeStruct(lanes, i32),  # nodes
             jax.ShapeDtypeStruct(lanes, i32),  # leaves
             jax.ShapeDtypeStruct(lanes, i32),  # maxd
-            jax.ShapeDtypeStruct((2,), i32),   # steps, unfinished
+            jax.ShapeDtypeStruct((3,), i32),   # steps, unfinished, refills
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -250,6 +250,7 @@ def _uts_dfs_pallas(
             pltpu.VMEM((winrows, cols), i32),
             pltpu.SemaphoreType.DMA((6,)),
         ],
+        name="uts_dfs",  # the kernel's name in a profiler trace
         interpret=interpret,  # bool: the fast XLA-backed interpreter
         # (InterpretParams would select the slow Mosaic one - only
         # remote-DMA/semaphore kernels need that; see megakernel.py)
@@ -272,6 +273,7 @@ def _uts_dfs_pallas(
         maxd,
         ctl[0],
         ctl[1] != 0,
+        ctl[2],
     )
 
 
@@ -286,7 +288,6 @@ def uts_pallas(
     depth_bound: Optional[int] = None,
     vmem_limit_bytes: int = 100 * 2**20,
     stack_pad: Optional[int] = None,
-    timing_reps: int = 1,
     table_cols: Optional[int] = None,
 ) -> dict:
     """uts_vec with the whole traversal fused into one Pallas kernel; same
@@ -301,122 +302,76 @@ def uts_pallas(
     run fails loudly if the tree actually reaches it. The scoped-vmem
     budget defaults to 100 MiB (sized for v5e's 128 MiB physical VMEM);
     pass a smaller ``vmem_limit_bytes`` on TPU generations with less
-    (mirrors Megakernel.vmem_limit_bytes)."""
+    (mirrors Megakernel.vmem_limit_bytes).
+
+    One call is one traversal: one seeding, one upload, one launch of the
+    kernel, one readback. ``device_seconds`` is that launch, and the first
+    launch of a shape compiles: a caller that wants a rate calls twice."""
     if lanes[1] != 128:
         raise ValueError("uts_pallas lanes must be (rows, 128)")
-    import time
-
     interpret = resolve_interpret(interpret)
-    t_seed = time.perf_counter()
-    host_nodes, host_leaves, host_maxd, d0, roots_state, roots_count = (
-        _host_seed(params, target_roots)
-    )
-    seed_seconds = time.perf_counter() - t_seed
-    result = {
-        "host_seed_nodes": host_nodes,
-        "roots": 0 if roots_count is None else int(roots_count.shape[0]),
-        "seed_seconds": seed_seconds,
-    }
+    seed, result = _seeded(params, target_roots)
+    d0, roots_state, roots_count = seed[3:]
     if roots_count is None:
-        result.update(
-            nodes=host_nodes, leaves=host_leaves, max_depth=host_maxd, steps=0
-        )
         return result
     if max_steps is None:
         max_steps = (1 << 31) - 1
     rows, cols = lanes
     nlanes = rows * cols
-    R = int(roots_count.shape[0])
-    # Pad so any aligned window [align_down(next_root), +nlanes+ALIGN) is in
-    # bounds (next_root <= R), then lay out as (Rrows, 128) for row-block
-    # DMA. PAD_QUANTUM (a multiple of ALIGN) keeps trees with different
-    # root counts on one padded shape, sharing one compiled kernel (R is
-    # a runtime scalar; only the padded shape is static).
-    rpad = -(-(R + nlanes + ALIGN) // PAD_QUANTUM) * PAD_QUANTUM
-    pstate = np.zeros((5, rpad), np.int32)
-    pstate[:, :R] = roots_state.astype(np.int32)
-    pcount = np.zeros(rpad, np.int32)
-    pcount[:R] = roots_count
-    # Shape -> (thresholds, stack height, depth cap) exactly as uts_vec.
-    derived = depth_cap(params)
-    if derived is None:  # EXPDEC: caller-chosen bound, validated below
-        cap = depth_bound if depth_bound is not None else 8 * params.gen_mx
-        bounded = True
-    elif depth_bound is not None and depth_bound < derived:
-        cap = depth_bound
-        bounded = True
-    else:
-        cap = derived
-        bounded = False
-    if params.shape == FIXED and not bounded:
-        thr = tuple(int(t) for t in child_thresholds(params.b0))
-        stack_size = max(1, params.gen_mx - d0)
-        tabnp = np.zeros((1, cols), np.int32)  # unused dummy input
-    else:
-        # Runtime-table path: the padded in-row table is a kernel INPUT,
-        # so all depth-varying trees with one padded shape + stack height
-        # share a single compiled kernel (see padded_threshold_table).
-        thr = None
-        stack_size = max(1, (cap - d0) if bounded else (cap - 1 - d0))
-        # max_rows = cols - 1: the in-row gather clips depth to column
-        # cols - 1 and needs that column to stay -1 padding, so the row
-        # quantization must not round past it (restores depth caps up to
-        # cols - 2 = 126 that the plain 16-row round-up would reject).
-        # table_cols (like stack_pad) opts into a shared width class so
-        # different trees reuse one compiled engine.
-        tabnp = inrow_threshold_table(
-            padded_threshold_table(
-                params, cap, max_rows=cols - 1, min_cols=table_cols
-            ),
-            cols,
+    with TraceAnnotation("bench:uts.stage"):
+        R = int(roots_count.shape[0])
+        # Pad so any aligned window [align_down(next_root), +nlanes+ALIGN)
+        # is in bounds (next_root <= R), then lay out as (Rrows, 128) for
+        # row-block DMA. PAD_QUANTUM (a multiple of ALIGN) keeps trees
+        # with different root counts on one padded shape, sharing one
+        # compiled kernel (R is a runtime scalar; only the padded shape is
+        # static).
+        rpad = -(-(R + nlanes + ALIGN) // PAD_QUANTUM) * PAD_QUANTUM
+        pstate = np.zeros((5, rpad), np.int32)
+        pstate[:, :R] = roots_state.astype(np.int32)
+        pcount = np.zeros(rpad, np.int32)
+        pcount[:R] = roots_count
+        thr, stack_size, cap, bounded = _engine_shape(
+            params, d0, depth_bound, stack_pad
         )
-    if stack_pad is not None:
-        # Opt-in compile sharing across tree shapes (taller stacks cost
-        # select/store work per step; the perf path keeps tight heights).
-        stack_size = max(stack_size, int(stack_pad))
-    args = (
-        jnp.asarray(pstate.reshape(5, rpad // cols, cols)),
-        jnp.asarray(pcount.reshape(rpad // cols, cols)),
-        jnp.asarray(np.array([R, d0, params.gen_mx], np.int32)),
-        jnp.asarray(tabnp),
-    )
-    kw = dict(
-        stack_size=stack_size,
-        thresholds=thr,
-        max_steps=max_steps,
-        lanes=tuple(lanes),
-        min_idle_div=min_idle_div,
-        interpret=interpret,  # bool: the fast XLA-backed interpreter
-        # (InterpretParams would select the slow Mosaic one - only
-        # remote-DMA/semaphore kernels need that; see megakernel.py)
-        vmem_limit_bytes=vmem_limit_bytes,
-    )
-    if device is not None:
-        args = tuple(jax.device_put(a, device) for a in args)
-    # One warm execution (the compile), then ``timing_reps`` timed ones
-    # of the same compiled kernel on the same staged args.
-    (nodes, leaves, maxd, steps, unfinished), dev_nodes, dt = _timed_best(
-        lambda: _uts_dfs_pallas(*args, **kw), timing_reps
-    )
-    if bool(unfinished):
-        raise RuntimeError(f"uts_pallas ran out of steps ({max_steps})")
-    if bounded and int(np.asarray(maxd).max()) >= cap:
-        raise RuntimeError(
-            f"tree reached the depth bound ({cap}): counts beyond it are "
-            "truncated - rerun with a larger depth_bound"
+        if thr is not None:
+            tabnp = np.zeros((1, cols), np.int32)  # unused dummy input
+        else:
+            # The padded in-row table is a kernel INPUT. max_rows =
+            # cols - 1: the in-row gather clips depth to column cols - 1
+            # and needs that column to stay -1 padding, so the row
+            # quantization must not round past it (restores depth caps up
+            # to cols - 2 = 126 that the plain 16-row round-up would
+            # reject). table_cols (like stack_pad) opts into a shared
+            # width class so different trees reuse one compiled engine.
+            tabnp = inrow_threshold_table(
+                padded_threshold_table(
+                    params, cap, max_rows=cols - 1, min_cols=table_cols
+                ),
+                cols,
+            )
+        args = (
+            jnp.asarray(pstate.reshape(5, rpad // cols, cols)),
+            jnp.asarray(pcount.reshape(rpad // cols, cols)),
+            jnp.asarray(np.array([R, d0, params.gen_mx], np.int32)),
+            jnp.asarray(tabnp),
         )
-    result.update(
-        nodes=host_nodes + dev_nodes,
-        leaves=host_leaves + int(np.asarray(leaves).sum(dtype=np.int64)),
-        max_depth=max(host_maxd, int(np.asarray(maxd).max())),
-        steps=int(steps),
-        device_nodes=dev_nodes,
-        device_seconds=dt,
-        nodes_per_sec=dev_nodes / dt if dt > 0 else float("inf"),
-        lane_efficiency=dev_nodes / (int(steps) * nlanes) if steps else 0.0,
-        **ran_on(nodes, interpret),
+        kw = dict(
+            stack_size=stack_size,
+            thresholds=thr,
+            max_steps=max_steps,
+            lanes=tuple(lanes),
+            min_idle_div=min_idle_div,
+            interpret=interpret,
+            vmem_limit_bytes=vmem_limit_bytes,
+        )
+        if device is not None:
+            args = tuple(jax.device_put(a, device) for a in args)
+        jax.block_until_ready(args)  # the upload belongs to this span
+    return _launch_once(
+        "uts_pallas", lambda: _uts_dfs_pallas(*args, **kw), result, seed,
+        nlanes, max_steps, cap if bounded else None, interpret,
     )
-    return result
 
 
 if __name__ == "__main__":  # pragma: no cover

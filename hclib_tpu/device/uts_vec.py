@@ -28,9 +28,9 @@ operation vectorized across (rows, 128) VPU planes:
   floor(log(1-r/2^31)/log(1-p)) >= k}, and the device counts children as
   #(r >= t_k) with pure int32 compares. Leaf children are counted without
   being pushed (80% of canonical-tree nodes are leaves).
-- The host BFS seed is itself vectorized: the same SHA-1 block function runs
-  on numpy arrays over whole frontier levels, so seeding hundreds of
-  thousands of subtree roots costs well under a second.
+- The host BFS seed is itself vectorized: the same SHA-1 runs in place on
+  numpy arrays over whole frontier levels (ops.sha1.sha1_children_np), so
+  seeding hundreds of thousands of subtree roots costs well under a second.
 
 Supports every GEO shape: FIXED (canonical T1/T1L/T1XL/T3) on the
 depth-independent threshold fast path, LINEAR/CYCLIC (canonical T5/T2) and
@@ -52,9 +52,12 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.uts import CYCLIC, FIXED, LINEAR, UTSParams, _branching
-from ..ops.sha1 import sha1_block as _sha1_block, sha1_child as _sha1_child
+from ..ops.sha1 import (
+    sha1_block as _sha1_block, sha1_child as _sha1_child, sha1_children_np,
+)
 from .megakernel import ran_on
 
 __all__ = [
@@ -371,7 +374,8 @@ def make_traversal(
     starved (or nothing is left to claim). ``refill(sp, next_root, st0,
     ch0, cn0, dp0)`` is the only engine-specific part (XLA gather here vs
     in-kernel DMA + matmul gather in uts_pallas). Returns run() ->
-    (sp, next_root, nodes, leaves, maxd, steps)."""
+    (sp, next_root, nodes, leaves, maxd, steps, refills); ``refills`` is
+    the number of outer rounds, each of which claimed roots once."""
     step = make_dfs_step(S, lanes, thresholds, gen_mx, inrow_table, table)
 
     def inner_cond(carry):
@@ -394,11 +398,12 @@ def make_traversal(
         return sp, nodes, leaves, maxd, st, ch, cn, dp, steps + 1, avail
 
     def outer_cond(carry):
-        sp, next_root, nodes, leaves, maxd, st, ch, cn, dp, steps = carry
+        sp, next_root, *_rest, steps, _refills = carry
         return (jnp.any(sp >= 0) | (next_root < R)) & (steps < max_steps)
 
     def outer_body(carry):
-        sp, next_root, nodes, leaves, maxd, st, ch, cn, dp, steps = carry
+        (sp, next_root, nodes, leaves, maxd, st, ch, cn, dp, steps,
+         refills) = carry
         sp, next_root, st0, ch0, cn0, dp0 = refill(
             sp, next_root, st[0], ch[0], cn[0], dp[0]
         )
@@ -412,7 +417,8 @@ def make_traversal(
         (
             sp, nodes, leaves, maxd, st, ch, cn, dp, steps, _,
         ) = jax.lax.while_loop(inner_cond, inner_body, inner)
-        return sp, next_root, nodes, leaves, maxd, st, ch, cn, dp, steps
+        return (sp, next_root, nodes, leaves, maxd, st, ch, cn, dp, steps,
+                refills + 1)
 
     def run():
         zeros = jnp.zeros(lanes, jnp.int32)
@@ -423,34 +429,110 @@ def make_traversal(
         dp0 = tuple(zeros for _ in range(S))
         carry = (
             jnp.full(lanes, -1, jnp.int32), jnp.int32(0), zeros, zeros,
-            zeros, st0, ch0, cn0, dp0, jnp.int32(0),
+            zeros, st0, ch0, cn0, dp0, jnp.int32(0), jnp.int32(0),
         )
-        (sp, next_root, nodes, leaves, maxd, *_rest, steps) = (
+        (sp, next_root, nodes, leaves, maxd, *_rest, steps, refills) = (
             jax.lax.while_loop(outer_cond, outer_body, carry)
         )
-        return sp, next_root, nodes, leaves, maxd, steps
+        return sp, next_root, nodes, leaves, maxd, steps, refills
 
     return run
 
 
-def _timed_best(run, reps: int):
-    """Warm once (the first call compiles), then return (outputs,
-    dev_nodes, best_dt) over ``reps`` timed executions of ``run`` - the
-    same compiled kernel on the same staged args. Each timed region ends
-    in the host-side int64 sum of the per-lane node plane, which both
-    engines need anyway and which cannot complete before the device
-    has."""
-    outs = run()
-    jax.block_until_ready(outs)  # the warm run's tail stays out of rep 1
-    dt = None
-    dev_nodes = 0
-    for _ in range(max(1, int(reps))):
+def _engine_shape(params: UTSParams, d0: int, depth_bound, stack_pad):
+    """Tree shape -> (thresholds, stack height, depth cap, bounded), for
+    both engines. ``thresholds`` is the static tuple of the FIXED fast
+    path, or None: the per-depth table (rows up to ``cap``) is then a
+    runtime INPUT, so all trees with one padded table shape + stack height
+    share a compile. ``bounded`` says the cap was chosen, not derived from
+    the shape, and the run must be validated against it."""
+    derived = depth_cap(params)
+    if derived is None:  # EXPDEC: caller-chosen bound
+        cap = depth_bound if depth_bound is not None else 8 * params.gen_mx
+        bounded = True
+    elif depth_bound is not None and depth_bound < derived:
+        # An explicit bound below the shape's own cap shrinks the stack
+        # for known-shallow trees - and gets the same loud validation.
+        cap = depth_bound
+        bounded = True
+    else:
+        cap = derived
+        bounded = False
+    if params.shape == FIXED and not bounded:
+        thr = tuple(int(t) for t in child_thresholds(params.b0))
+        stack_size = max(1, params.gen_mx - d0)
+    else:
+        thr = None
+        # Pushed frames hold non-leaf nodes only; for shapes whose cap is
+        # exact the deepest non-leaf sits at cap-2, so the tight height is
+        # cap-1-d0 (every extra level costs select/store work per step).
+        stack_size = max(1, (cap - d0) if bounded else (cap - 1 - d0))
+    if stack_pad is not None:
+        # Opt-in: pad the stack so differently-shaped trees share one
+        # compiled engine (taller stacks cost select/store work per step,
+        # so the perf path keeps the tight height).
+        stack_size = max(stack_size, int(stack_pad))
+    return thr, stack_size, cap, bounded
+
+
+def _seeded(params: UTSParams, target_roots: int):
+    """``_host_seed`` inside its span: (its tuple, the result dict so far -
+    complete when the host consumed the whole tree)."""
+    with TraceAnnotation("bench:uts.seed"):
         t0 = time.perf_counter()
-        outs = run()
-        dev_nodes = int(np.asarray(outs[0]).sum(dtype=np.int64))
-        d = time.perf_counter() - t0
-        dt = d if dt is None else min(dt, d)
-    return outs, dev_nodes, dt
+        seed = _host_seed(params, target_roots)
+        seed_seconds = time.perf_counter() - t0
+    host_nodes, host_leaves, host_maxd, _, _, roots_count = seed
+    result = {
+        "host_seed_nodes": host_nodes,
+        "roots": 0 if roots_count is None else int(roots_count.shape[0]),
+        "seed_seconds": seed_seconds,
+    }
+    if roots_count is None:
+        result.update(
+            nodes=host_nodes, leaves=host_leaves, max_depth=host_maxd,
+            steps=0,
+        )
+    return seed, result
+
+
+def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
+    """One launch of the engine ``run`` and its outputs into the result
+    dict. ``device_seconds`` is that launch, waited for; a caller that
+    wants a rate warms first (the first launch of a shape compiles) and
+    times a second call. Totals are summed here in int64 (see the engines'
+    return comment); ``cap`` is the depth bound to validate, None where
+    the shape's own cap is exact."""
+    with TraceAnnotation("bench:uts.run"):
+        t0 = time.perf_counter()
+        outs = jax.block_until_ready(run())
+        dt = time.perf_counter() - t0
+    host_nodes, host_leaves, host_maxd = seed[:3]
+    nodes, leaves, maxd, steps, unfinished, refills = outs
+    with TraceAnnotation("bench:uts.readback"):
+        if bool(unfinished):
+            raise RuntimeError(f"{who} ran out of steps ({max_steps})")
+        deepest = int(np.asarray(maxd).max())
+        if cap is not None and deepest >= cap:
+            raise RuntimeError(
+                f"tree reached the depth bound ({cap}): counts beyond it "
+                "are truncated - rerun with a larger depth_bound"
+            )
+        dev_nodes = int(np.asarray(nodes).sum(dtype=np.int64))
+        steps = int(steps)
+        result.update(
+            nodes=host_nodes + dev_nodes,
+            leaves=host_leaves + int(np.asarray(leaves).sum(dtype=np.int64)),
+            max_depth=max(host_maxd, deepest),
+            steps=steps,
+            refills=int(refills),
+            device_nodes=dev_nodes,
+            device_seconds=dt,
+            nodes_per_sec=dev_nodes / dt if dt > 0 else float("inf"),
+            lane_efficiency=dev_nodes / (steps * nlanes) if steps else 0.0,
+            **ran_on(nodes, interpret),
+        )
+    return result
 
 
 def padded_threshold_table(
@@ -535,7 +617,7 @@ def _uts_dfs(
         S, lanes, thresholds, gen_mx, refill_min_idle, max_steps, refill, R,
         table=tab if thresholds is None else None,
     )
-    sp, next_root, nodes, leaves, maxd, steps = run()
+    sp, next_root, nodes, leaves, maxd, steps, refills = run()
     return (
         # Per-lane planes, not totals: totals are summed on the host in
         # int64 so trees beyond 2^31 total nodes (T1XXL's 4.23B) count
@@ -545,6 +627,7 @@ def _uts_dfs(
         maxd,
         steps,
         jnp.any(sp >= 0) | (next_root < R),
+        refills,
     )
 
 
@@ -555,16 +638,15 @@ def _host_seed(params: UTSParams, target_roots: int):
     roots_count (R,) i32). Roots all sit at depth d0 and have count >= 1;
     leaf frontier nodes are counted host-side.
     """
-    def counts_of(state5, depth: int) -> np.ndarray:
+    def counts_of(state4, depth: int) -> np.ndarray:
         # Per-level thresholds from the depth's branching factor: one code
-        # path covers FIXED and every depth-varying shape exactly.
+        # path covers FIXED and every depth-varying shape exactly. The
+        # thresholds ascend, so #{k : r >= t_k} is r's insertion point.
         ts = np.asarray(
             _thresholds_for_b(_branching(params, depth)), np.int32
         )
-        if ts.size == 0:
-            return np.zeros(state5[0].shape, np.int32)
-        r = (state5[4] & np.uint32(0x7FFFFFFF)).astype(np.int32)
-        return (r[:, None] >= ts[None, :]).sum(axis=1, dtype=np.int32)
+        r = (state4 & np.uint32(0x7FFFFFFF)).astype(np.int32)
+        return np.searchsorted(ts, r, side="right").astype(np.int32)
 
     # Root state: SHA1(16 zero bytes || BE32(seed)) per the UTS spec
     # (models/uts.py root_state).
@@ -575,19 +657,19 @@ def _host_seed(params: UTSParams, target_roots: int):
         *[np.zeros(1, np.uint32) for _ in range(9)],
         np.full(1, 20 * 8, np.uint32),
     ]
-    state5 = list(_sha1_block(w16, np))
+    state = np.stack(_sha1_block(w16, np))  # (5, n): one level's states
 
     host_nodes = 0
     host_leaves = 0
     host_maxd = 0
     depth = 0
     while True:
-        n = state5[0].shape[0]
-        counts = counts_of(state5, depth)
+        n = state.shape[1]
+        counts = counts_of(state[4], depth)
         host_nodes += n
         host_maxd = max(host_maxd, depth) if n else host_maxd
         nonleaf = counts > 0
-        host_leaves += int((~nonleaf).sum())
+        host_leaves += n - int(np.count_nonzero(nonleaf))
         total = int(counts.sum())
         if total == 0:
             return host_nodes, host_leaves, host_maxd, depth, None, None
@@ -598,22 +680,19 @@ def _host_seed(params: UTSParams, target_roots: int):
             # are claimed (and balanced over lanes) early and the drain tail
             # is short - classic longest-processing-time scheduling. Totals
             # are order-independent; only steps/lane-efficiency change.
-            rs = [s[nonleaf] for s in state5]
-            rc = counts[nonleaf]
-            order = np.argsort(-rc, kind="stable")
-            rs = [s[order] for s in rs]
-            rc = rc[order]
+            roots = np.flatnonzero(nonleaf)
+            roots = roots[np.argsort(-counts[roots], kind="stable")]
             return (
                 host_nodes, host_leaves, host_maxd, depth,
-                np.stack(rs).astype(np.uint32), rc.astype(np.int32),
+                state[:, roots], counts[roots],
             )
-        # Expand the whole level at once.
-        parent = np.repeat(np.arange(n), counts)
-        starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
-        rank = (np.arange(total) - starts[parent]).astype(np.uint32)
-        state5 = list(
-            _sha1_child([s[parent] for s in state5], rank, np)
-        )
+        # Expand the whole level at once. int32 indices: on the chip's
+        # host a freshly mapped page costs about 12 us (PERF.md, PR 29).
+        parent = np.repeat(np.arange(n, dtype=np.int32), counts)
+        first = (np.cumsum(counts) - counts).astype(np.int32)
+        rank = np.arange(total, dtype=np.int32)
+        rank -= first[parent]
+        state = sha1_children_np(state, parent, rank.view(np.uint32))
         depth += 1
 
 
@@ -626,7 +705,6 @@ def uts_vec(
     min_idle_div: int = 8,
     depth_bound: Optional[int] = None,
     stack_pad: Optional[int] = None,
-    timing_reps: int = 1,
     table_cols: Optional[int] = None,
 ) -> dict:
     """Run UTS with the vectorized DFS engine; returns counts + timing info.
@@ -639,107 +717,56 @@ def uts_vec(
     threshold fast path; LINEAR/CYCLIC get exact per-depth threshold
     tables with a shape-derived depth cap; EXPDEC (whose branching decays
     but never reaches zero) uses ``depth_bound`` (default 8*gen_mx) and
-    the run fails loudly if the tree actually reaches the bound."""
-    import time
+    the run fails loudly if the tree actually reaches the bound.
 
-    t_seed = time.perf_counter()
-    host_nodes, host_leaves, host_maxd, d0, roots_state, roots_count = (
-        _host_seed(params, target_roots)
-    )
-    seed_seconds = time.perf_counter() - t_seed
-    result = {
-        "host_seed_nodes": host_nodes,
-        "roots": 0 if roots_count is None else int(roots_count.shape[0]),
-        "seed_seconds": seed_seconds,
-    }
+    One call is one traversal: one seeding, one upload, one launch, one
+    readback. ``device_seconds`` is that launch, and the first launch of a
+    shape compiles: a caller that wants a rate calls twice."""
+    seed, result = _seeded(params, target_roots)
+    d0, roots_state, roots_count = seed[3:]
     if roots_count is None:
-        result.update(
-            nodes=host_nodes, leaves=host_leaves, max_depth=host_maxd, steps=0
-        )
         return result
     if max_steps is None:
         max_steps = (1 << 31) - 1
-    # Pad to PAD_QUANTUM (>= R + nlanes): the refill window dynamic_slice
-    # never runs off the end, and trees with different root counts land
-    # on the SAME padded shape, sharing one compiled engine.
     nlanes = lanes[0] * lanes[1]
-    R = int(roots_count.shape[0])
-    padn = -(-(R + nlanes) // PAD_QUANTUM) * PAD_QUANTUM
-    pstate = np.zeros((5, padn), np.uint32)
-    pstate[:, :R] = roots_state
-    pcount = np.zeros(padn, np.int32)
-    pcount[:R] = roots_count
-    args = (jnp.asarray(pstate), jnp.asarray(pcount))
-    derived = depth_cap(params)
-    if derived is None:  # EXPDEC: caller-chosen bound, validated below
-        cap = depth_bound if depth_bound is not None else 8 * params.gen_mx
-        bounded = True
-    elif depth_bound is not None and depth_bound < derived:
-        # An explicit bound below the shape's own cap shrinks the stack
-        # for known-shallow trees - and gets the same loud validation.
-        cap = depth_bound
-        bounded = True
-    else:
-        cap = derived
-        bounded = False
-    if params.shape == FIXED and not bounded:
-        thr = tuple(int(t) for t in child_thresholds(params.b0))
-        stack_size = max(1, params.gen_mx - d0)
-        tabnp = np.zeros((1, 1), np.int32)  # unused dummy input
-    else:
-        # Runtime-table path: values are an input, so all trees with the
-        # same padded table shape + stack height share one compile.
-        thr = None
-        # table_cols (like stack_pad) opts into a shared width class.
-        tabnp = padded_threshold_table(params, cap, min_cols=table_cols)
-        # Pushed frames hold non-leaf nodes only; for shapes whose cap is
-        # exact the deepest non-leaf sits at cap-2, so the tight height is
-        # cap-1-d0 (every extra level costs select/store work per step).
-        stack_size = max(
-            1, (cap - d0) if bounded else (cap - 1 - d0)
+    with TraceAnnotation("bench:uts.stage"):
+        # Pad to PAD_QUANTUM (>= R + nlanes): the refill window
+        # dynamic_slice never runs off the end, and trees with different
+        # root counts land on the SAME padded shape, sharing one compiled
+        # engine.
+        R = int(roots_count.shape[0])
+        padn = -(-(R + nlanes) // PAD_QUANTUM) * PAD_QUANTUM
+        pstate = np.zeros((5, padn), np.uint32)
+        pstate[:, :R] = roots_state
+        pcount = np.zeros(padn, np.int32)
+        pcount[:R] = roots_count
+        thr, stack_size, cap, bounded = _engine_shape(
+            params, d0, depth_bound, stack_pad
         )
-    if stack_pad is not None:
-        # Opt-in: pad the stack so differently-shaped trees share one
-        # compiled engine (taller stacks cost select/store work per step,
-        # so the perf path keeps the tight height).
-        stack_size = max(stack_size, int(stack_pad))
-    args = args + (
-        jnp.asarray(tabnp), jnp.int32(params.gen_mx), jnp.int32(d0),
-        jnp.int32(R),
-    )
-    kw = dict(
-        stack_size=stack_size,
-        thresholds=thr,
-        max_steps=max_steps,
-        lanes=tuple(lanes),
-        min_idle_div=min_idle_div,
-    )
-    if device is not None:
-        args = tuple(jax.device_put(a, device) for a in args)
-    (nodes, leaves, maxd, steps, unfinished), dev_nodes, dt = _timed_best(
-        lambda: _uts_dfs(*args, **kw), timing_reps
-    )
-    if bool(unfinished):
-        raise RuntimeError(f"uts_vec ran out of steps ({max_steps})")
-    if bounded and int(np.asarray(maxd).max()) >= cap:
-        raise RuntimeError(
-            f"tree reached the depth bound ({cap}): counts beyond it are "
-            "truncated - rerun with a larger depth_bound"
+        if thr is not None:
+            tabnp = np.zeros((1, 1), np.int32)  # unused dummy input
+        else:
+            # table_cols (like stack_pad) opts into a shared width class.
+            tabnp = padded_threshold_table(params, cap, min_cols=table_cols)
+        args = (
+            jnp.asarray(pstate), jnp.asarray(pcount), jnp.asarray(tabnp),
+            jnp.int32(params.gen_mx), jnp.int32(d0), jnp.int32(R),
         )
-    nlanes = lanes[0] * lanes[1]
-    result.update(
-        nodes=host_nodes + dev_nodes,
-        leaves=host_leaves + int(np.asarray(leaves).sum(dtype=np.int64)),
-        max_depth=max(host_maxd, int(np.asarray(maxd).max())),
-        steps=int(steps),
-        device_nodes=dev_nodes,
-        device_seconds=dt,
-        nodes_per_sec=dev_nodes / dt if dt > 0 else float("inf"),
-        lane_efficiency=dev_nodes / (int(steps) * nlanes) if steps else 0.0,
-        # uts_vec is plain XLA: it has no interpreter to fall back to.
-        **ran_on(nodes, False),
+        kw = dict(
+            stack_size=stack_size,
+            thresholds=thr,
+            max_steps=max_steps,
+            lanes=tuple(lanes),
+            min_idle_div=min_idle_div,
+        )
+        if device is not None:
+            args = tuple(jax.device_put(a, device) for a in args)
+        jax.block_until_ready(args)  # the upload belongs to this span
+    # uts_vec is plain XLA: it has no interpreter to fall back to.
+    return _launch_once(
+        "uts_vec", lambda: _uts_dfs(*args, **kw), result, seed, nlanes,
+        max_steps, cap if bounded else None, False,
     )
-    return result
 
 
 if __name__ == "__main__":  # pragma: no cover
