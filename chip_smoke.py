@@ -113,7 +113,7 @@ def phase_uts(dev: dict, seed: int) -> None:
         interpret=False,
     ))
     assert r["nodes"] == T1L_NODES, r["nodes"]
-    # run_s is the whole second call (host seeding, upload, one launch,
+    # run_s is the whole second call (seeding on host and chip, one launch,
     # readback); device_s is that call's one launch of the kernel.
     emit("uts", dev, tree="T1L", lanes=list(lanes), target_roots=roots,
          nodes=r["nodes"], leaves=r["leaves"], max_depth=r["max_depth"],

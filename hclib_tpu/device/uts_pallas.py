@@ -15,7 +15,7 @@ runs EVERYTHING on-core in one kernel launch:
   * flat cumsum over the starved mask -> two triangular MXU matmuls (exact:
     counts <= nlanes << 2^24 in f32);
   * the root-window DMA -> a 1024-aligned dynamic row-block copy from HBM
-    (roots are laid out (rows, 128) host-side; the residual offset folds
+    (the seeding lays the roots out (rows, 128); the residual offset folds
     into the gather indices);
   * the monotone claim gather -> same-shape ``take_along_axis`` passes
     (Mosaic's only gather form): claim ranks are a prefix sum, so each
@@ -45,7 +45,6 @@ from ..models.uts import UTSParams
 from .megakernel import resolve_interpret
 from .uts_vec import (
     LANES,
-    PAD_QUANTUM,
     _engine_shape,
     _launch_once,
     _seeded,
@@ -291,7 +290,9 @@ def uts_pallas(
     table_cols: Optional[int] = None,
 ) -> dict:
     """uts_vec with the whole traversal fused into one Pallas kernel; same
-    exact counts, same host seeding, same result dict.
+    exact counts, same seeding (``uts_vec._seeded``: the tree's top on the
+    host, its larger levels and the roots' sort and layout on the device),
+    same result dict.
 
     All GEO shapes run fused: FIXED on the depth-independent threshold
     fast path; LINEAR/CYCLIC (canonical T5/T2) and EXPDEC via the same
@@ -304,33 +305,28 @@ def uts_pallas(
     pass a smaller ``vmem_limit_bytes`` on TPU generations with less
     (mirrors Megakernel.vmem_limit_bytes).
 
-    One call is one traversal: one seeding, one upload, one launch of the
-    kernel, one readback. ``device_seconds`` is that launch, and the first
-    launch of a shape compiles: a caller that wants a rate calls twice."""
+    One call is one traversal: one seeding, one launch of the kernel, one
+    readback. ``device_seconds`` is that launch, and the first launch of a
+    shape compiles: a caller that wants a rate calls twice."""
     if lanes[1] != 128:
         raise ValueError("uts_pallas lanes must be (rows, 128)")
     interpret = resolve_interpret(interpret)
-    seed, result = _seeded(params, target_roots)
-    d0, roots_state, roots_count = seed[3:]
-    if roots_count is None:
-        return result
     if max_steps is None:
         max_steps = (1 << 31) - 1
     rows, cols = lanes
     nlanes = rows * cols
+    # Padded so any aligned window [align_down(next_root), +nlanes+ALIGN)
+    # is in bounds (next_root <= R), laid out as (Rrows, 128) for row-block
+    # DMA. PAD_QUANTUM (a multiple of ALIGN) keeps trees with different
+    # root counts on one padded shape, sharing one compiled kernel (R is a
+    # runtime scalar; only the padded shape is static).
+    seed, roots, result = _seeded(
+        params, target_roots, device, nlanes + ALIGN, True
+    )
+    if roots is None:
+        return result
+    d0 = seed[2]
     with TraceAnnotation("bench:uts.stage"):
-        R = int(roots_count.shape[0])
-        # Pad so any aligned window [align_down(next_root), +nlanes+ALIGN)
-        # is in bounds (next_root <= R), then lay out as (Rrows, 128) for
-        # row-block DMA. PAD_QUANTUM (a multiple of ALIGN) keeps trees
-        # with different root counts on one padded shape, sharing one
-        # compiled kernel (R is a runtime scalar; only the padded shape is
-        # static).
-        rpad = -(-(R + nlanes + ALIGN) // PAD_QUANTUM) * PAD_QUANTUM
-        pstate = np.zeros((5, rpad), np.int32)
-        pstate[:, :R] = roots_state.astype(np.int32)
-        pcount = np.zeros(rpad, np.int32)
-        pcount[:R] = roots_count
         thr, stack_size, cap, bounded = _engine_shape(
             params, d0, depth_bound, stack_pad
         )
@@ -350,12 +346,11 @@ def uts_pallas(
                 ),
                 cols,
             )
-        args = (
-            jnp.asarray(pstate.reshape(5, rpad // cols, cols)),
-            jnp.asarray(pcount.reshape(rpad // cols, cols)),
-            jnp.asarray(np.array([R, d0, params.gen_mx], np.int32)),
-            jnp.asarray(tabnp),
-        )
+        args = roots + jax.block_until_ready(jax.device_put(
+            (np.array([result["roots"], d0, params.gen_mx], np.int32),
+             tabnp),
+            device,
+        ))  # the upload belongs to this span
         kw = dict(
             stack_size=stack_size,
             thresholds=thr,
@@ -365,9 +360,6 @@ def uts_pallas(
             interpret=interpret,
             vmem_limit_bytes=vmem_limit_bytes,
         )
-        if device is not None:
-            args = tuple(jax.device_put(a, device) for a in args)
-        jax.block_until_ready(args)  # the upload belongs to this span
     return _launch_once(
         "uts_pallas", lambda: _uts_dfs_pallas(*args, **kw), result, seed,
         nlanes, max_steps, cap if bounded else None, interpret,

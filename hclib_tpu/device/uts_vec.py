@@ -15,9 +15,9 @@ operation vectorized across (rows, 128) VPU planes:
   stack frame expandable, so every active step performs an expansion - the
   classic DFS pop-the-exhausted-frame steps, ~20% of all steps on canonical
   trees, are eliminated.
-- **Dynamic load balancing via a shared root queue**: the host seeds a flat
-  array of subtree roots (all at one BFS depth d0); every step, lanes whose
-  stack emptied claim the next unclaimed roots with a prefix-sum over the
+- **Dynamic load balancing via a shared root queue**: the seeding leaves a
+  flat array of subtree roots (all at one BFS depth d0); every step, lanes
+  whose stack emptied claim the next unclaimed roots with a prefix-sum over the
   done mask + a gather from the root arrays. Imbalance is therefore bounded
   by the size of a single subtree instead of the sum of a lane's static
   deal - this is the work-stealing idea of the reference scheduler
@@ -28,9 +28,12 @@ operation vectorized across (rows, 128) VPU planes:
   floor(log(1-r/2^31)/log(1-p)) >= k}, and the device counts children as
   #(r >= t_k) with pure int32 compares. Leaf children are counted without
   being pushed (80% of canonical-tree nodes are leaves).
-- The host BFS seed is itself vectorized: the same SHA-1 runs in place on
-  numpy arrays over whole frontier levels (ops.sha1.sha1_children_np), so
-  seeding hundreds of thousands of subtree roots costs well under a second.
+- The BFS seeding is itself vectorized, a whole frontier level at a time:
+  the small levels at the root in place on numpy arrays
+  (ops.sha1.sha1_children_np), every level from SEED_CHIP_FROM nodes on as
+  one jitted expansion on the device (uts_seed_expand), which also sorts,
+  pads and lays out the roots (uts_seed_roots), so they never visit the
+  host: T1L's 239,628 roots are ready 25 ms after the call (PERF.md).
 
 Supports every GEO shape: FIXED (canonical T1/T1L/T1XL/T3) on the
 depth-independent threshold fast path, LINEAR/CYCLIC (canonical T5/T2) and
@@ -47,17 +50,17 @@ from __future__ import annotations
 import functools
 import math
 import time
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ..models.uts import CYCLIC, FIXED, LINEAR, UTSParams, _branching
-from ..ops.sha1 import (
-    sha1_block as _sha1_block, sha1_child as _sha1_child, sha1_children_np,
+from ..models.uts import (
+    CYCLIC, FIXED, LINEAR, UTSParams, _branching, root_state,
 )
+from ..ops.sha1 import sha1_child as _sha1_child, sha1_children_np
 from .megakernel import ran_on
 
 __all__ = [
@@ -79,11 +82,13 @@ MAX_CHILDREN = 100
 PAD_QUANTUM = 4096
 
 
-def _thresholds_for_b(b_i: float) -> List[int]:
+@functools.lru_cache(maxsize=None)
+def _thresholds_for_b(b_i: float) -> Tuple[int, ...]:
     """Integer thresholds for the geometric child count at branching b_i:
-    count(r) = #{k : r >= t_k}. Exact w.r.t. the f64 scalar formula."""
+    count(r) = #{k : r >= t_k}, ascending. Exact w.r.t. the f64 scalar
+    formula; 3 k logarithms a row, so a row is computed once."""
     if b_i <= 0.0:
-        return []
+        return ()
     p = 1.0 / (1.0 + b_i)
     logq = math.log(1.0 - p)
 
@@ -106,7 +111,7 @@ def _thresholds_for_b(b_i: float) -> List[int]:
             else:
                 lo = mid + 1
         ts.append(lo)
-    return ts
+    return tuple(ts)
 
 
 def child_thresholds(b0: float) -> np.ndarray:
@@ -475,25 +480,39 @@ def _engine_shape(params: UTSParams, d0: int, depth_bound, stack_pad):
     return thr, stack_size, cap, bounded
 
 
-def _seeded(params: UTSParams, target_roots: int):
-    """``_host_seed`` inside its span: (its tuple, the result dict so far -
-    complete when the host consumed the whole tree)."""
+def _seeded(params: UTSParams, target_roots: int, device, slack: int,
+            planes: bool):
+    """The whole seeding inside its span, which ends when the padded roots
+    are ready on the device: ``(seed, roots, result)``. ``seed`` is the
+    exact (nodes, leaves, d0) of levels 0 to d0, ``roots`` the engine's
+    first two arguments (``_padded_roots``; None when the seeding consumed
+    the whole tree, and the result dict is then complete). ``slack`` is
+    what the engine's refill window may read beyond the last root."""
     with TraceAnnotation("bench:uts.seed"):
         t0 = time.perf_counter()
-        seed = _host_seed(params, target_roots)
-        seed_seconds = time.perf_counter() - t0
-    host_nodes, host_leaves, host_maxd, _, _, roots_count = seed
-    result = {
-        "host_seed_nodes": host_nodes,
-        "roots": 0 if roots_count is None else int(roots_count.shape[0]),
-        "seed_seconds": seed_seconds,
-    }
-    if roots_count is None:
-        result.update(
-            nodes=host_nodes, leaves=host_leaves, max_depth=host_maxd,
-            steps=0,
+        nodes, leaves, d0, frontier, chip_levels, chip_nodes = _seed_top(
+            params, target_roots, device
         )
-    return seed, result
+        roots, R = None, 0
+        if frontier is not None:
+            R = frontier.n - frontier.leaves
+            padn = -(-(R + slack) // PAD_QUANTUM) * PAD_QUANTUM
+            side = "host" if _on_host(frontier) else "chip"
+            with TraceAnnotation(f"bench:uts.seed.{side}"):
+                roots = jax.block_until_ready(
+                    _padded_roots(frontier, padn, planes, device)
+                )
+        seed_seconds = time.perf_counter() - t0
+    result = {
+        "host_seed_nodes": nodes,
+        "roots": R,
+        "seed_seconds": seed_seconds,
+        "seed_levels_on_chip": chip_levels,
+        "seed_nodes_on_chip": chip_nodes,
+    }
+    if roots is None:
+        result.update(nodes=nodes, leaves=leaves, max_depth=d0, steps=0)
+    return (nodes, leaves, d0), roots, result
 
 
 def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
@@ -507,7 +526,7 @@ def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
         t0 = time.perf_counter()
         outs = jax.block_until_ready(run())
         dt = time.perf_counter() - t0
-    host_nodes, host_leaves, host_maxd = seed[:3]
+    host_nodes, host_leaves, d0 = seed
     nodes, leaves, maxd, steps, unfinished, refills = outs
     with TraceAnnotation("bench:uts.readback"):
         if bool(unfinished):
@@ -523,7 +542,7 @@ def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
         result.update(
             nodes=host_nodes + dev_nodes,
             leaves=host_leaves + int(np.asarray(leaves).sum(dtype=np.int64)),
-            max_depth=max(host_maxd, deepest),
+            max_depth=max(d0, deepest),
             steps=steps,
             refills=int(refills),
             device_nodes=dev_nodes,
@@ -631,69 +650,265 @@ def _uts_dfs(
     )
 
 
-def _host_seed(params: UTSParams, target_roots: int):
-    """Vectorized BFS of the tree top with numpy SHA-1 over whole levels.
+# The seeding: the tree's top, breadth first, a whole level at a time. A
+# level of at least SEED_CHIP_FROM nodes is expanded on the device, and so
+# is every level after it; the smaller ones above it in numpy on the host.
+# The crossover is read from the input (the level's size), not set by a
+# caller. On the v5e's host a numpy level costs 1.2 ms at 74 nodes and
+# below (1.4 k numpy calls), 1.6 at 300, 2.2 at 1,179, 5.3 at 4,562; a
+# device level 1.0-1.4 ms up to 25 k children (a launch and a read of three
+# scalars), 2.0 at 75 k, 5.7 at 300 k. With the crossover at 256, 1,024 or
+# 2,048 the whole T1L seeding takes 24.4-26.6 ms, from the root 25.6-27.4;
+# each shape more costs a new process half a second (tracing 1.4 k
+# operations of SHA-1, and the cache's load), so it is the largest of them
+# (PERF.md, PR 30).
+SEED_CHIP_FROM = 2048
+# Static capacities of a device level's arrays: 2^k and 3 * 2^(k-1), from
+# 2,048 to 12 M. A level takes the smallest rung that holds it, whole, so
+# trees of like size share the compiled expansions (T1L: three of them and
+# one hand-over) and a level pays for at most 1.5 times its size.
+SEED_RUNGS = tuple(m << s for s in range(10, 23) for m in (2, 3))
 
-    Returns (host_nodes, host_leaves, host_maxd, d0, roots_state (5,R) u32,
-    roots_count (R,) i32). Roots all sit at depth d0 and have count >= 1;
-    leaf frontier nodes are counted host-side.
-    """
-    def counts_of(state4, depth: int) -> np.ndarray:
-        # Per-level thresholds from the depth's branching factor: one code
-        # path covers FIXED and every depth-varying shape exactly. The
-        # thresholds ascend, so #{k : r >= t_k} is r's insertion point.
-        ts = np.asarray(
-            _thresholds_for_b(_branching(params, depth)), np.int32
+
+class _Level(NamedTuple):
+    """One breadth-first level. On the host ``state`` (5, n) u32 and
+    ``counts`` (n,) i32 are numpy arrays; on the device ``state`` is five
+    (C,) u32 arrays and ``counts`` (C,) i32, at a rung C >= n, with
+    ``counts`` 0 beyond n. The host loop reads only the three exact
+    scalars."""
+
+    state: object
+    counts: object
+    n: int  # nodes
+    leaves: int  # those of them with no child
+    total: int  # their children in all
+
+
+def _on_host(level: _Level) -> bool:
+    return isinstance(level.counts, np.ndarray)
+
+
+def _rung(n: int) -> int:
+    for cap in SEED_RUNGS:
+        if n <= cap:
+            return cap
+    raise ValueError(
+        f"a level of {n} nodes is beyond the seeding's largest capacity "
+        f"({SEED_RUNGS[-1]}): lower target_roots"
+    )
+
+
+def _level_thresholds(params: UTSParams, depth: int) -> Tuple[int, ...]:
+    """The ascending thresholds of a node AT ``depth``: one code path
+    covers FIXED and every depth-varying shape exactly."""
+    return _thresholds_for_b(_branching(params, depth))
+
+
+def _host_level(params: UTSParams, state: np.ndarray, depth: int) -> _Level:
+    # The thresholds ascend, so #{k : r >= t_k} is r's insertion point.
+    ts = np.asarray(_level_thresholds(params, depth), np.int32)
+    r = (state[4] & np.uint32(0x7FFFFFFF)).astype(np.int32)
+    counts = np.searchsorted(ts, r, side="right").astype(np.int32)
+    n = state.shape[1]
+    return _Level(
+        state, counts, n, n - int(np.count_nonzero(counts)),
+        int(counts.sum()),
+    )
+
+
+def _expand_host(level: _Level) -> np.ndarray:
+    """The states of the level's children, (5, total). int32 indices: on
+    the chip's host a freshly mapped page costs about 12 us (PERF.md, PR
+    29)."""
+    counts = level.counts
+    parent = np.repeat(np.arange(level.n, dtype=np.int32), counts)
+    first = (np.cumsum(counts) - counts).astype(np.int32)
+    rank = np.arange(level.total, dtype=np.int32)
+    rank -= first[parent]
+    return sha1_children_np(level.state, parent, rank.view(np.uint32))
+
+
+def _running_sum(x):
+    """Inclusive running sum of a (C,) i32 array, C a multiple of 1,024:
+    within rows of 1,024, then over the rows' totals. (A flat ``cumsum``
+    over 2^19 words takes the TPU compiler 22 s; this form 0.3 s, and runs
+    as fast: PERF.md, PR 30.)"""
+    rows = x.reshape(-1, 1024)
+    ends = jnp.cumsum(jnp.sum(rows, axis=1))
+    before = jnp.concatenate([jnp.zeros(1, x.dtype), ends[:-1]])
+    return (jnp.cumsum(rows, axis=1) + before[:, None]).reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+def uts_seed_expand(state, counts, thr, *, cap: int):
+    """``_expand_host`` and the next ``_host_level`` on the device. In: a
+    level's states, five (C_in,) u32, and child counts (C_in,) i32, 0
+    beyond its real size, and the children's thresholds (MAX_CHILDREN,)
+    i32, ascending and -1 padded. Out: the children's states, five (cap,)
+    u32 (five arrays, not one stacked: XLA's CPU backend fuses a stack with
+    the hash above it and then runs 2,048 hashes in 170 s), their own child
+    counts (cap,) i32, 0 beyond the real size, and the scalars (children,
+    leaves among them, grandchildren) i32."""
+    cum = _running_sum(counts)
+    first = cum - counts
+    total = cum[-1]
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    # The device form of np.repeat. Every parent marks the slot of its first
+    # child, a childless one the slot its successor marks too, so the
+    # running sum of the marks steps over it: the parent index is monotone.
+    marks = jnp.zeros(cap, jnp.int32).at[first].add(
+        1, indices_are_sorted=True, mode="drop"
+    )
+    parent = _running_sum(marks) - 1
+    # One gather for all a child needs of its parent: on the v5e a gather
+    # costs 4.5 ns an index whether it fetches one word or eight, and five
+    # separate ones in a jit 23 ns (PERF.md, PR 30).
+    of_parent = jnp.take(
+        jnp.stack([*state, first.astype(jnp.uint32)]), parent, axis=1
+    )
+    rank = slot - of_parent[5].astype(jnp.int32)
+    child = _sha1_child(list(of_parent[:5]), rank, jnp)
+    live = slot < total
+    r = (child[4] & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+    ccounts = jnp.sum(
+        (thr[:, None] >= 0) & (r[None, :] >= thr[:, None]), axis=0,
+        dtype=jnp.int32,
+    )
+    ccounts = jnp.where(live, ccounts, 0)
+    leaves = jnp.sum(live & (ccounts == 0), dtype=jnp.int32)
+    scalars = jnp.stack([total, leaves, jnp.sum(ccounts)])
+    return child, ccounts, scalars
+
+
+@functools.partial(jax.jit, static_argnames=("padn", "planes"))
+def uts_seed_roots(state, counts, *, padn: int, planes: bool):
+    """The hand-over on the device: the level's non-leaf nodes in LPT order
+    (descending child count, ties in frontier order, as ``np.argsort(-counts,
+    kind="stable")`` gives), zero padded to ``padn``, in the engine's
+    layout (``_padded_roots``). A counting sort, since a count is one of
+    MAX_CHILDREN values: a node's place is where its count's class starts,
+    plus the class's nodes in the rows of 1,024 before its own, plus those
+    before it in its row. (XLA's own sort takes the TPU compiler 68 s at
+    this size: PERF.md, PR 30.)"""
+    row = counts.reshape(-1, 1024)
+    i = jnp.arange(1024, dtype=jnp.int32)
+    in_row = jnp.sum(
+        (row[:, None, :] == row[:, :, None]) & (i[None, :] < i[:, None]),
+        axis=2, dtype=jnp.int32,
+    )
+
+    def place_class(t, carry):
+        dest, start = carry
+        mine = row == MAX_CHILDREN - t  # the biggest counts first
+        per_row = jnp.sum(mine, axis=1, dtype=jnp.int32)
+        ends = jnp.cumsum(per_row)
+        dest = jnp.where(mine, start + (ends - per_row)[:, None], dest)
+        return dest, start + ends[-1]
+
+    # A leaf matches no class: it gets a place of its own beyond padn, and
+    # the scatter drops it. The scatter carries one word a node, its own
+    # slot; one gather then brings states and counts (a scatter of the six
+    # words themselves costs six times as much on the v5e, or 12 s of
+    # compiling as one 2-D scatter).
+    dest, R = jax.lax.fori_loop(
+        0, MAX_CHILDREN, place_class, (jnp.zeros_like(row), jnp.int32(0))
+    )
+    slot = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    dest = jnp.where(counts > 0, (dest + in_row).reshape(-1), padn + slot)
+    source = jnp.zeros(padn, jnp.int32).at[dest].set(
+        slot, unique_indices=True, mode="drop"
+    )
+    out = jnp.take(
+        jnp.stack([*state, counts.astype(jnp.uint32)]), source, axis=1
+    )
+    out = jnp.where(jnp.arange(padn, dtype=jnp.int32) < R, out, 0)
+    pstate, pcount = out[:5], out[5].astype(jnp.int32)
+    if planes:
+        pstate = jax.lax.bitcast_convert_type(pstate, jnp.int32)
+        return (pstate.reshape(5, -1, LANES[1]),
+                pcount.reshape(-1, LANES[1]))
+    return pstate, pcount
+
+
+def _padded_roots(frontier: _Level, padn: int, planes: bool, device):
+    """The engine's root arrays on the device, from a frontier on either
+    side: the non-leaf nodes in LPT order - biggest child counts first, so
+    the large subtrees are claimed (and balanced over lanes) early and the
+    drain tail is short; totals are order-independent, only steps and lane
+    efficiency change - zero padded to ``padn``, as (5, padn) u32 and
+    (padn,) i32, or with ``planes`` as (5, padn/128, 128) i32 (u32 bits)
+    and (padn/128, 128) i32 for the Pallas engine's row-block DMA."""
+    state, counts = frontier.state, frontier.counts
+    if not _on_host(frontier):
+        return uts_seed_roots(state, counts, padn=padn, planes=planes)
+    roots = np.flatnonzero(counts)
+    roots = roots[np.argsort(-counts[roots], kind="stable")]
+    pstate = np.zeros((5, padn), np.uint32)
+    pstate[:, : roots.size] = state[:, roots]
+    pcount = np.zeros(padn, np.int32)
+    pcount[: roots.size] = counts[roots]
+    if planes:
+        pstate = pstate.view(np.int32).reshape(5, -1, LANES[1])
+        pcount = pcount.reshape(-1, LANES[1])
+    return jax.device_put((pstate, pcount), device)
+
+
+def _expand_chip(params: UTSParams, level: _Level, depth: int, device,
+                 thr_on_chip: dict) -> _Level:
+    """The level's children, at ``depth``, as a device level: one launch of
+    ``uts_seed_expand`` and one read of its three scalars. A level still on
+    the host goes up first, at its rung. ``thr_on_chip`` keeps the
+    threshold rows already uploaded (a FIXED tree has one)."""
+    state, counts = level.state, level.counts
+    if _on_host(level):
+        pad = (0, _rung(level.n) - level.n)
+        state, counts = jax.device_put(
+            (tuple(np.pad(s, pad) for s in state), np.pad(counts, pad)),
+            device,
         )
-        r = (state4 & np.uint32(0x7FFFFFFF)).astype(np.int32)
-        return np.searchsorted(ts, r, side="right").astype(np.int32)
+    ts = _level_thresholds(params, depth)
+    if ts not in thr_on_chip:
+        row = np.full(MAX_CHILDREN, -1, np.int32)
+        row[: len(ts)] = ts
+        thr_on_chip[ts] = jax.device_put(row, device)
+    state, counts, scalars = uts_seed_expand(
+        state, counts, thr_on_chip[ts], cap=_rung(level.total)
+    )
+    children, leaves, grandchildren = (int(x) for x in np.asarray(scalars))
+    assert children == level.total, (children, level.total)
+    return _Level(state, counts, children, leaves, grandchildren)
 
-    # Root state: SHA1(16 zero bytes || BE32(seed)) per the UTS spec
-    # (models/uts.py root_state).
-    seed_words = [np.zeros(1, np.uint32) for _ in range(4)]
-    seed_words.append(np.full(1, params.root_seed, np.uint32))
-    w16 = seed_words + [
-        np.full(1, 0x80000000, np.uint32),
-        *[np.zeros(1, np.uint32) for _ in range(9)],
-        np.full(1, 20 * 8, np.uint32),
-    ]
-    state = np.stack(_sha1_block(w16, np))  # (5, n): one level's states
 
-    host_nodes = 0
-    host_leaves = 0
-    host_maxd = 0
-    depth = 0
+def _seed_top(params: UTSParams, target_roots: int, device):
+    """Breadth-first expansion of the tree's top, a whole level at a time,
+    to the first level of at least ``target_roots`` nodes.
+
+    Returns (nodes, leaves, d0, frontier, chip_levels, chip_nodes): the
+    exact counts of levels 0 to d0, all of them, wherever they were hashed;
+    the frontier level at depth d0 (None when the tree ended there), whose
+    non-leaf nodes are the engine's roots; how many levels the device
+    expanded and how many nodes it hashed for them."""
+    root = np.frombuffer(root_state(params.root_seed), ">u4")
+    level = _host_level(params, root.astype(np.uint32)[:, None], 0)
+    nodes = leaves = depth = chip_levels = chip_nodes = 0
+    thr_on_chip: dict = {}
     while True:
-        n = state.shape[1]
-        counts = counts_of(state[4], depth)
-        host_nodes += n
-        host_maxd = max(host_maxd, depth) if n else host_maxd
-        nonleaf = counts > 0
-        host_leaves += n - int(np.count_nonzero(nonleaf))
-        total = int(counts.sum())
-        if total == 0:
-            return host_nodes, host_leaves, host_maxd, depth, None, None
-        if n >= target_roots:
-            # Hand the non-leaf frontier to the device. Frontier leaves were
-            # already counted above; roots themselves were counted as nodes.
-            # LPT order: biggest child counts first, so the large subtrees
-            # are claimed (and balanced over lanes) early and the drain tail
-            # is short - classic longest-processing-time scheduling. Totals
-            # are order-independent; only steps/lane-efficiency change.
-            roots = np.flatnonzero(nonleaf)
-            roots = roots[np.argsort(-counts[roots], kind="stable")]
-            return (
-                host_nodes, host_leaves, host_maxd, depth,
-                state[:, roots], counts[roots],
-            )
-        # Expand the whole level at once. int32 indices: on the chip's
-        # host a freshly mapped page costs about 12 us (PERF.md, PR 29).
-        parent = np.repeat(np.arange(n, dtype=np.int32), counts)
-        first = (np.cumsum(counts) - counts).astype(np.int32)
-        rank = np.arange(total, dtype=np.int32)
-        rank -= first[parent]
-        state = sha1_children_np(state, parent, rank.view(np.uint32))
+        # Frontier leaves are counted here; the roots themselves as nodes.
+        nodes += level.n
+        leaves += level.leaves
+        if level.total == 0:
+            return nodes, leaves, depth, None, chip_levels, chip_nodes
+        if level.n >= target_roots:
+            return nodes, leaves, depth, level, chip_levels, chip_nodes
         depth += 1
+        if _on_host(level) and level.n < SEED_CHIP_FROM:
+            with TraceAnnotation("bench:uts.seed.host"):
+                level = _host_level(params, _expand_host(level), depth)
+            continue
+        with TraceAnnotation("bench:uts.seed.chip"):
+            level = _expand_chip(params, level, depth, device, thr_on_chip)
+        chip_levels += 1
+        chip_nodes += level.n
 
 
 def uts_vec(
@@ -709,9 +924,10 @@ def uts_vec(
 ) -> dict:
     """Run UTS with the vectorized DFS engine; returns counts + timing info.
 
-    The host BFS-expands the tree top until >= target_roots frontier nodes
-    (counting that part itself), then the device traverses the subtrees,
-    lanes claiming roots from the shared queue as they drain.
+    The seeding BFS-expands the tree top until >= target_roots frontier
+    nodes (its small levels on the host, the others on the device, counting
+    that part exactly), then the engine traverses the subtrees, lanes
+    claiming roots from the shared queue as they drain.
 
     All GEO shapes are supported: FIXED uses the depth-independent
     threshold fast path; LINEAR/CYCLIC get exact per-depth threshold
@@ -719,27 +935,23 @@ def uts_vec(
     but never reaches zero) uses ``depth_bound`` (default 8*gen_mx) and
     the run fails loudly if the tree actually reaches the bound.
 
-    One call is one traversal: one seeding, one upload, one launch, one
-    readback. ``device_seconds`` is that launch, and the first launch of a
-    shape compiles: a caller that wants a rate calls twice."""
-    seed, result = _seeded(params, target_roots)
-    d0, roots_state, roots_count = seed[3:]
-    if roots_count is None:
-        return result
+    One call is one traversal: one seeding, one launch, one readback.
+    ``device_seconds`` is that launch, and the first launch of a shape
+    compiles (as the seeding's expansions do): a caller that wants a rate
+    calls twice. ``host_seed_nodes`` counts levels 0 to d0 whole, wherever
+    they were hashed; ``seed_levels_on_chip`` / ``seed_nodes_on_chip`` say
+    how much of that the device did."""
     if max_steps is None:
         max_steps = (1 << 31) - 1
     nlanes = lanes[0] * lanes[1]
+    # Padded to PAD_QUANTUM (>= R + nlanes): the refill window
+    # dynamic_slice never runs off the end, and trees with different root
+    # counts land on the SAME padded shape, sharing one compiled engine.
+    seed, roots, result = _seeded(params, target_roots, device, nlanes, False)
+    if roots is None:
+        return result
+    d0 = seed[2]
     with TraceAnnotation("bench:uts.stage"):
-        # Pad to PAD_QUANTUM (>= R + nlanes): the refill window
-        # dynamic_slice never runs off the end, and trees with different
-        # root counts land on the SAME padded shape, sharing one compiled
-        # engine.
-        R = int(roots_count.shape[0])
-        padn = -(-(R + nlanes) // PAD_QUANTUM) * PAD_QUANTUM
-        pstate = np.zeros((5, padn), np.uint32)
-        pstate[:, :R] = roots_state
-        pcount = np.zeros(padn, np.int32)
-        pcount[:R] = roots_count
         thr, stack_size, cap, bounded = _engine_shape(
             params, d0, depth_bound, stack_pad
         )
@@ -748,10 +960,11 @@ def uts_vec(
         else:
             # table_cols (like stack_pad) opts into a shared width class.
             tabnp = padded_threshold_table(params, cap, min_cols=table_cols)
-        args = (
-            jnp.asarray(pstate), jnp.asarray(pcount), jnp.asarray(tabnp),
-            jnp.int32(params.gen_mx), jnp.int32(d0), jnp.int32(R),
-        )
+        args = roots + jax.block_until_ready(jax.device_put(
+            (tabnp, np.int32(params.gen_mx), np.int32(d0),
+             np.int32(result["roots"])),
+            device,
+        ))  # the upload belongs to this span
         kw = dict(
             stack_size=stack_size,
             thresholds=thr,
@@ -759,9 +972,6 @@ def uts_vec(
             lanes=tuple(lanes),
             min_idle_div=min_idle_div,
         )
-        if device is not None:
-            args = tuple(jax.device_put(a, device) for a in args)
-        jax.block_until_ready(args)  # the upload belongs to this span
     # uts_vec is plain XLA: it has no interpreter to fall back to.
     return _launch_once(
         "uts_vec", lambda: _uts_dfs(*args, **kw), result, seed, nlanes,

@@ -5,8 +5,9 @@ compose (the layer the package docstring calls ``hclib_tpu.ops``).
   masked rank-1 Cholesky factorization, Newton-Schulz triangular inverse)
   and the DMA start/wait helper used by megakernel task kernels.
 - ``sha1``: the FIPS-180-1 compression function vectorized over arrays of
-  any shape, generic over numpy (host seeding) and jnp (device planes) -
-  the UTS splittable RNG.
+  any shape, generic over numpy (the seeding's small levels on the host)
+  and jnp (its larger levels and the engines' planes on the device) - the
+  UTS splittable RNG.
 - ``scan``: decay-cummax, the log-depth solution of recurrences
   c[j] = max(t[j], c[j-1] - g) used by the Smith-Waterman row sweep.
 """

@@ -1,11 +1,13 @@
 """FIPS-180-1 SHA-1 compression, vectorized over arrays of any shape.
 
 The UTS splittable RNG (one hash per tree node). Generic over the array
-module: ``xp=jnp`` hashes device planes inside the vectorized DFS;
-``xp=numpy`` hashes whole BFS frontier levels during host seeding
-(hclib_tpu/device/uts_vec.py); ``sha1_children_np`` is that host hash
-without a temporary. A scalar single-block variant lives in the native
-runtime (hclib_tpu/native/src/sha1.hpp).
+module: ``xp=jnp`` hashes device planes inside the vectorized DFS and
+whole BFS frontier levels in the seeding's device expansion
+(hclib_tpu/device/uts_vec.py: uts_seed_expand); ``xp=numpy`` is the same
+on the host, and ``sha1_children_np`` is that host hash without a
+temporary, for the seeding's small levels at the root. A scalar
+single-block variant lives in the native runtime
+(hclib_tpu/native/src/sha1.hpp).
 """
 
 from __future__ import annotations
@@ -85,7 +87,8 @@ def sha1_children_np(state: np.ndarray, parent: np.ndarray,
 
     Every operation writes into one of 23 rows allocated once (``out=``),
     where the generic form above allocates a fresh n-word temporary for
-    each of its 1.4k operations. At the 300 k nodes of a seeding level
+    each of its 1.4k operations. At the 300 k nodes of a seeding level (on
+    the host until PR 30; levels that size now go to the device)
     those are 1.2 MB blocks that the allocator maps and unmaps one by one,
     and in a process with the TPU runtime's threads that cost differed
     between processes by half. A whole level at once, not L2-sized chunks:

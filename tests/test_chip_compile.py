@@ -55,10 +55,12 @@ def one_chip(topo):
 
 
 def _shapes(arrays, sharding):
-    return [
-        jax.ShapeDtypeStruct(tuple(a.shape), a.dtype, sharding=sharding)
-        for a in arrays
-    ]
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            tuple(a.shape), a.dtype, sharding=sharding
+        ),
+        list(arrays),
+    )
 
 
 def _compile_mk(mk, sharding, fuel=1 << 22, builder=None):
@@ -96,29 +98,48 @@ def _fib_batch(sh):
 
 
 def _uts_t1l(sh):
-    """Through uts_pallas itself, so the shapes are the ones its host
-    seeding derives for T1L; the jitted kernel is swapped for one that
-    compiles the real kernel for the described chip and stops there."""
+    """Through uts_pallas itself, so the shapes are the ones its seeding
+    derives for T1L. The seeding's jits run here on the CPU, and each
+    shape they are called with is first compiled for the described chip;
+    the jitted kernel is swapped for one that compiles the real kernel for
+    the described chip and stops there."""
     import hclib_tpu.device.uts_pallas as up
+    import hclib_tpu.device.uts_vec as uv
     from hclib_tpu.models.uts import T1L
 
     class Compiled(Exception):
         pass
 
-    real = up._uts_dfs_pallas
+    real = up._uts_dfs_pallas, uv.uts_seed_expand, uv.uts_seed_roots
+    seeding = []
 
     def compile_only(*args, **kw):
         assert kw["interpret"] is False
-        real.lower(*_shapes(args, sh), **kw).compile()
+        real[0].lower(*_shapes(args, sh), **kw).compile()
         raise Compiled
 
+    def compiled_too(jitted):
+        def run(*args, **kw):
+            jitted.lower(*_shapes(args, sh), **kw).compile()
+            seeding.append((jitted.__name__, args[1].shape[0], kw))
+            return jitted(*args, **kw)
+
+        return run
+
     up._uts_dfs_pallas = compile_only
+    uv.uts_seed_expand = compiled_too(real[1])
+    uv.uts_seed_roots = compiled_too(real[2])
     try:
         with pytest.raises(Compiled):
             up.uts_pallas(T1L, target_roots=256 * 1024, lanes=(64, 128),
                           min_idle_div=32, interpret=False)
     finally:
-        up._uts_dfs_pallas = real
+        up._uts_dfs_pallas, uv.uts_seed_expand, uv.uts_seed_roots = real
+    # the device expanded T1L's lower levels and handed the roots over
+    assert [name for name, _, _ in seeding][-2:] == [
+        "uts_seed_expand", "uts_seed_roots"
+    ], seeding
+    assert seeding[-1][1] >= 300_125  # level 9, whole
 
 
 def _cholesky_8192(sh):
