@@ -1066,17 +1066,16 @@ class StreamingMegakernel:
         aliases = {0: 0, 2: 1, 3: 2, 4: 3}
         for i in range(ndata):
             aliases[7 + i] = 5 + i
-        from .megakernel import VBLOCK
-
         return jax.jit(pl.pallas_call(
             functools.partial(self._kernel, quantum, max_rounds, mk.trace),
             out_shape=out_shape,
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=list(mk.scratch_specs.values())
+            # free, vfree: the stream kernel embeds the core without the
+            # batched tier (_make_core refuses a batch-routed mk).
+            + mk.core_scratch()[:2]
             + [
-                pltpu.SMEM((mk.capacity + 1,), jnp.int32),
-                pltpu.SMEM((mk.num_values // VBLOCK + 1,), jnp.int32),
                 pltpu.SMEM((8,), jnp.int32),  # ctl staging
                 pltpu.SMEM((8, RING_ROW), jnp.int32),  # row staging (8-row chunks)
                 pltpu.SemaphoreType.DMA((2,)),

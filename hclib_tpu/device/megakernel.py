@@ -320,7 +320,7 @@ C_EXECUTED = 5
 C_OVERFLOW = 6
 # Slot 7 is time-shared: during a kernel entry it is C_VBASE (first value
 # slot above the host-preset range, set by stage()); AFTER a multi-device
-# steal loop finishes, the runners (device/sharded.py, device/ici_steal.py)
+# steal loop finishes, the runners (device/sharded.py, device/resident.py)
 # overwrite it with their round count for the host to read.
 C_ROUNDS = 7
 C_VBASE = 7
@@ -1154,13 +1154,9 @@ class Megakernel:
             (cap, DESC_WORDS), (cap,), (8,), (self.num_values,),
         ]
         one = [(self.succ_capacity,)]  # succ is input-only
-        one += [(cap + 1,), (self.num_values // VBLOCK + 1,)]  # free stacks
+        one += [s.shape for s in self.core_scratch(cap)]
         if self.batch_specs:
-            one += [
-                (TS_WORDS,),
-                (self.lane_scratch_rows, cap),
-                (self.lane_scratch_rows, LS_WORDS),
-            ]
+            one.append((TS_WORDS,))
         if self.checkpoint:
             one += [(8,), (8,)]  # qstat out, qbuf
         if self.trace is not None:
@@ -1206,11 +1202,28 @@ class Megakernel:
     def lane_scratch_rows(self) -> int:
         """Rows of the batched-tier lane/lstate SMEM scratch: one ring
         per routed kind, times ``priority_buckets`` bucket rings per
-        kind when the priority tier is armed. Every embedder that
-        allocates the scratch (this class's _build_raw, the sharded/
-        resident/ici/pgas runners) sizes it from here so the bucket
-        layout cannot drift per runner."""
+        kind when the priority tier is armed. ``core_scratch`` sizes the
+        scratch from here for every embedder of the core (this class's
+        _build_raw, which ShardedMegakernel re-enters, and
+        ResidentKernel), so the bucket layout cannot drift per runner."""
         return len(self.batch_specs) * (self.priority_buckets or 1)
+
+    def core_scratch(self, capacity: Optional[int] = None) -> list:
+        """The scheduler core's own SMEM scratch at ``capacity`` rows
+        (default: this build's), in ``_make_core``'s order: the two free
+        stacks (``free``, ``vfree``), then - for a batch-routed build -
+        the batched tier's ``lanes`` and ``lstate``. The embedders
+        (_build_raw, StreamingMegakernel._build, ResidentKernel._build)
+        splice these into their scratch lists and ``smem_footprint``
+        charges the same shapes, so the core's layout is declared once."""
+        cap = self.capacity if capacity is None else int(capacity)
+        shapes = [(cap + 1,), (self.num_values // VBLOCK + 1,)]
+        if self.batch_specs:
+            shapes += [
+                (self.lane_scratch_rows, cap),
+                (self.lane_scratch_rows, LS_WORDS),
+            ]
+        return [pltpu.SMEM(s, jnp.int32) for s in shapes]
 
     def describe(self) -> Dict[str, Any]:
         """Whole-program description of this megakernel's kernel table:
@@ -1296,15 +1309,14 @@ class Megakernel:
         ``stage()`` (copy host state into the mutable windows), and
         ``sched(fuel)`` (pop/dispatch/complete until the ready ring drains
         or ``fuel`` tasks have run since this call). Used by this class's
-        own kernel body and by kernels that embed the scheduler next to
-        other phases (the in-kernel ICI steal runner, device/ici_steal.py;
-        the one-sided PGAS runner, device/pgas_kernel.py - whose
+        own kernel body and by the two kernels that embed the scheduler
+        next to other phases: the streaming front door, device/inject.py,
+        and the resident mesh runner, device/resident.py - whose
         ``ctx_hook`` attaches its put/am/wait-until ops to each task's
-        KernelContext before dispatch; the unified resident runner,
-        device/resident.py - whose ``complete_hook(idx)`` runs at the top
-        of every completion to forward migrated tasks' results home, and
-        whose ``value_limit`` caps dynamic value allocation below the
-        region it reserves for migration result slots).
+        KernelContext before dispatch, whose ``complete_hook(idx)`` runs at
+        the top of every completion to forward migrated tasks' results
+        home, and whose ``value_limit`` caps dynamic value allocation below
+        the region it reserves for migration result slots.
 
         ``quiesce_hook(executed_since_entry)`` - when given - is evaluated
         once per scheduling round and returns a traced bool; a True makes
@@ -1326,9 +1338,9 @@ class Megakernel:
         capacity = self.capacity
         num_values = value_limit if value_limit is not None else self.num_values
         # Batched same-kind dispatch tier: requires the per-kind lane
-        # scratch. Every runner that embeds this core (Megakernel's own
-        # build, the sharded steal loop, resident/ici/pgas) allocates and
-        # passes it; the lane discipline is steal-round-RE-ENTRANT - sched()
+        # scratch. Megakernel's own build (which the sharded steal loop
+        # re-enters) and the resident runner allocate it (core_scratch)
+        # and pass it; the lane discipline is steal-round-RE-ENTRANT - sched()
         # unconditionally spills unrun lane entries back to the ready ring
         # at every exit (the fuel/quiesce path below), so between sched
         # calls the ring is the ONLY live structure and the steal/export/
@@ -1344,9 +1356,8 @@ class Megakernel:
                 f"batch-routed kernels ({routed}) "
                 "need the batched dispatch tier's lane scratch "
                 "(lanes/lstate/tstats): pass it through _make_core like "
-                "Megakernel._build and the multi-device runners "
-                "(sharded/resident/ici/pgas) do, or drop the BatchSpec "
-                "routes for this embedding"
+                "Megakernel._build_raw and ResidentKernel do, or drop the "
+                "BatchSpec routes for this embedding"
             )
         use_batch = lanes is not None and len(self.batch_specs) > 0
         nbatch = len(self.batch_specs) if use_batch else 0
@@ -1685,7 +1696,7 @@ class Megakernel:
                         # LIFO on the owner side (newest first, depth-first,
                         # small live sets); the head side is the
                         # steal/export side (device/sharded.py,
-                        # device/ici_steal.py) - the Chase-Lev split of the
+                        # device/resident.py) - the Chase-Lev split of the
                         # reference deque (src/hclib-deque.c).
                         idx = ready[(tail - 1) % capacity]
                         counts[C_TAIL] = tail - 1
@@ -2328,22 +2339,7 @@ class Megakernel:
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=list(self.scratch_specs.values())
-            + [
-                pltpu.SMEM((self.capacity + 1,), jnp.int32),
-                pltpu.SMEM((self.num_values // VBLOCK + 1,), jnp.int32),
-            ]
-            + (
-                [
-                    pltpu.SMEM(
-                        (self.lane_scratch_rows, self.capacity), jnp.int32
-                    ),
-                    pltpu.SMEM(
-                        (self.lane_scratch_rows, LS_WORDS), jnp.int32
-                    ),
-                ]
-                if nbatch
-                else []
-            )
+            + self.core_scratch()
             + (
                 [
                     pltpu.SMEM((8,), jnp.int32),  # qbuf (quiesce staging)

@@ -5,11 +5,13 @@ injection - with **general migration of dependency-bearing tasks**.
 This is the device-side analogue of the reference's module architecture,
 where every module adds locales to a SINGLE scheduler instead of spawning a
 private runtime (/root/reference/inc/hclib-module.h:79-97,
-src/hclib-runtime.c:294-317). Round 3 shipped three disjoint wrappers
-around ``Megakernel`` (ici_steal / pgas_kernel / inject); this module is
-their composition: one kernel per device that steals, puts, AMs, waits,
-and polls an injection ring in the same round loop. The older wrappers
-remain as narrower configurations (see their module docstrings).
+src/hclib-runtime.c:294-317): one kernel per device that steals, puts,
+AMs, waits, and polls an injection ring in the same round loop. It is the
+only runner whose kernel lives across chips; the narrower runners it
+replaced are configurations of it - steal-only whole-row migration is
+``steal=True, homed=False``, one-sided PGAS alone is ``steal=False`` plus
+``channels``. (``ShardedMegakernel`` re-enters the single-chip kernel from
+XLA every round; ``StreamingMegakernel`` is one chip.)
 
 **General task migration** (the round-3 gap: only successor-free
 whitelisted rows could move). The reference thief takes ANY task out of a
@@ -58,30 +60,58 @@ owner's value slots; RC_GRANT releases the next waiter's row - the
 device translation of the reference chaining lock requests through
 promises.
 
-**Termination and flow control.** Counting protocol as in
-device/pgas_kernel.py (Mattern-style: exit when global pending == 0,
-outboxes and injection rings empty, and messages sent == received), but
-the per-round stat exchange is re-designed for pod scale (round-3 weak
-item #8): instead of ring-allreducing an O(ndev^2) send matrix, each
-round runs log2(ndev) paired XOR hops that (1) recursive-double the five
+**Termination and flow control.** A counting protocol, Mattern-style:
+senders count messages per (target, channel); every round the counts are
+exchanged, so each device learns exactly how many messages were directed
+at it and *consumes* exactly that many arrival-semaphore signals with
+matching ``wait_recv`` descriptors - blocking, but only for messages
+already launched, never speculative, and data is read only after its
+semaphore count is consumed, so no torn payload is ever observed. The
+kernel exits when global pending == 0, outboxes and injection rings are
+empty, and messages sent == received: an in-flight message therefore
+always blocks exit, and every semaphore is drained to zero at kernel
+exit. The per-round stat exchange is built for pod scale: each round
+runs log2(ndev) paired XOR hops that (1) recursive-double the five
 scalar sums, and (2) route the per-destination send counts with the
 hypercube XOR all-to-all (slot p of device v ends holding the count from
 source v^p) - payload O(ndev + ndev*nchan) words per hop, O(ndev log
-ndev) per round. The same hops carry the backlog-equalizing steal
-exchange of device/ici_steal.py, so termination, stealing, and message
-accounting ride one credited lockstep schedule.
+ndev) per round, where a ring allreduce of the send matrix would carry
+O(ndev^2).
+
+The same hops carry the backlog-equalizing steal exchange: at each hop
+the pair sends (mine - theirs)/2 rows, ``window``-capped, by remote-DMAing
+descriptor rows straight between SMEM task tables, importing before the
+next hop so received work diffuses further the same round. Every (hop,
+sub-channel) inbox is 1-deep with a fixed writer: the receiver signals
+that writer's REGULAR *credit* semaphore after consuming, and the writer
+waits a credit before its next-round write, so an inbox is never
+overwritten before it is consumed, without any global barrier. Receive
+DMA semaphores are per-hop: a device two hops ahead may deliver early,
+and a shared receive semaphore would hand its signal to a wait for a
+different hop's message. All devices execute the identical hop schedule,
+so every semaphore wait has a matching signal by construction (lockstep
+SPMD, no dynamic handshakes to deadlock on). Termination, stealing, and
+message accounting ride this one credited lockstep schedule.
+
+Active messages need no credit round-trips: device s owns inbox row
+``inbox[s, :]`` on every target (``am_window`` slots, cycled). A receiver
+drains everything a round's counts announced during that round, and the
+next round's fold completes only after every device finished that drain,
+so a sender that launches at most ``am_window // 2`` AMs per target per
+round can never overwrite an unconsumed slot; the rest wait in a local
+outbox drained by the round loop.
 
 Arrival correctness: every (source, channel) pair has its OWN DMA
 semaphore (``am_sems[src]``, ``chan_sems[src, chan]``), and receivers
-wait exactly the announced per-source count before reading - closing a
-latent aliasing hazard in the shared-semaphore drain of the round-3 PGAS
-kernel, where an early next-round arrival from a fast device could
-satisfy a wait for a slower device's still-in-flight message.
+wait exactly the announced per-source count before reading - with one
+semaphore shared between sources, an early next-round arrival from a
+fast device could satisfy a wait for a slower device's still-in-flight
+message.
 
 Meshes: 1D, 2D, or 3D (v4/v5p slices are 3D tori), power-of-two per axis
 (TPU slices are pof2 per axis); multi-axis hops decompose into per-axis
-transfers exactly as in ici_steal (row-major flattening, low XOR bits =
-minor axis, so each hypercube hop flips exactly one mesh coordinate).
+transfers (row-major flattening, low XOR bits = minor axis, so each
+hypercube hop flips exactly one mesh coordinate).
 Tested on 8-device 1D, 4x2, and 2x2x2 interpret meshes (including under
 the Mosaic race detector) and compiled/run on the real 1-device TPU
 (self-loop AMs, atomics, locks).
@@ -152,7 +182,6 @@ from .megakernel import (
     fault_mix,
     interpret_mode,
     C_EXECUTED,
-    LS_WORDS,
     OVF_LOCKQ,
     OVF_OUTBOX,
     OVF_WAITS,
@@ -295,7 +324,11 @@ class ResidentKernel:
     (dependency-bearing rows included, via the home-link protocol), or a
     dict ``{fn_id: (value_arg_index, ...)}`` naming which arg words of
     that kernel are value-slot references to dereference at export.
-    ``channels``: as PGASMegakernel - ``{name: (data_buffer, rows)}``.
+    ``channels``: ``{name: (data_buffer, rows)}`` - every put on a channel
+    moves exactly that many leading-axis rows of the named ``data_specs``
+    buffer (the static-shape contract that lets receivers consume arrival
+    semaphores with matching descriptors); ``chan_id[name]`` is the index
+    kernels use.
     ``inject=True`` adds a per-device host injection ring (rows published
     before entry are discovered by the in-kernel poll).
 
@@ -355,8 +388,9 @@ class ResidentKernel:
         for d in dims:
             if d & (d - 1):
                 raise ValueError(
-                    f"mesh axes must be power-of-two, got {dims} (non-pof2 "
-                    "1D meshes: use ICIStealMegakernel / PGASMegakernel)"
+                    f"mesh axes must be power-of-two, got {dims} (the "
+                    "hypercube hop schedule is pof2-only: drop each axis "
+                    "to the next power of two below it)"
                 )
         if am_window < 2:
             raise ValueError("am_window must be >= 2")
@@ -367,10 +401,9 @@ class ResidentKernel:
         self.ndev = int(np.prod(dims))
         self.nh = self.ndev.bit_length() - 1  # log2 hops (0 for 1 device)
         self.steal = bool(steal)
-        # homed=False restricts migration to the round-3 semantics (only
-        # link-free rows move, whole; no proxies, no result forwarding, no
-        # value-slot reservation) - the configuration the legacy
-        # ICIStealMegakernel wrapper delegates to.
+        # homed=False restricts migration to link-free rows, which move
+        # whole (no proxies, no result forwarding, no value-slot
+        # reservation): the steal-only configuration.
         self.homed = bool(homed)
         if isinstance(migratable_fns, dict):
             self.migratable: Dict[int, Tuple[int, ...]] = {
@@ -571,7 +604,7 @@ class ResidentKernel:
 
         return probe(self.mk, self._cache_variant(key))
 
-    # -- mesh addressing (as ici_steal) --
+    # -- mesh addressing --
 
     def _flat_me(self):
         # Row-major flattening over the mesh axes; with pof2 dims the XOR
@@ -2154,9 +2187,10 @@ class ResidentKernel:
         aliases = {0: 0, 2: 1, 3: 2, 4: 3}
         for i in range(ndata):
             aliases[5 + i] = 4 + i
+        free, vfree, *lane_scratch = mk.core_scratch()
         scratch = list(mk.scratch_specs.values()) + [
-            pltpu.SMEM((mk.capacity + 1,), jnp.int32),  # free
-            pltpu.SMEM((mk.num_values // VBLOCK + 1,), jnp.int32),  # vfree
+            free,
+            vfree,
             pltpu.SMEM((self.scan,), jnp.int32),  # candbuf
             pltpu.SMEM((W + 1, DESC_WORDS), jnp.int32),  # sendbuf
             pltpu.SMEM((self.S,), jnp.int32),  # statacc
@@ -2201,14 +2235,10 @@ class ResidentKernel:
             pltpu.SMEM((8,), jnp.int32),  # abuf (abort-word staging)
             pltpu.SemaphoreType.DMA((1,)),  # asem
         ]
-        if mk.batch_specs:
-            # Batched dispatch tier lane scratch (lanes + lane state);
-            # re-entrant across sched() entries via the spill discipline.
-            nb = mk.lane_scratch_rows  # kinds x priority buckets
-            scratch += [
-                pltpu.SMEM((nb, mk.capacity), jnp.int32),  # lanes
-                pltpu.SMEM((nb, LS_WORDS), jnp.int32),  # lstate
-            ]
+        # Batched dispatch tier lane scratch (lanes + lane state, none
+        # unless batch-routed); re-entrant across sched() entries via the
+        # spill discipline.
+        scratch += lane_scratch
         if self.plan is not None:
             nhk = max(1, nh)
             scratch += [
@@ -2320,8 +2350,10 @@ class ResidentKernel:
     ):
         """Execute all partitions fully on-device.
 
-        ``waits[d]``: host-declared wait-sets (chan_id, need, task_index),
-        as PGASMegakernel. ``inject_rows[d]``: descriptor tuples
+        ``waits[d]``: host-declared wait-sets (chan_id, need, task_index)
+        for device d - the named task gains one extra dependency,
+        satisfied when ``need`` messages have landed on the channel.
+        ``inject_rows[d]``: descriptor tuples
         ``(fn, args[, out[, tenant_lane]])`` - or prebuilt RING_ROW
         numpy rows (``tenants.build_row``) - published on device d's
         injection ring before entry (requires ``inject=True``); the

@@ -238,7 +238,7 @@ class ShardedMegakernel:
             else:
                 raise ValueError(
                     "ShardedMegakernel does not support the trace ring; "
-                    "use ResidentKernel/ICIStealMegakernel tracing or "
+                    "use ResidentKernel tracing or "
                     "build the Megakernel with trace=None"
                 )
         # Checkpoint quiesce cannot ride this runner either: the appended

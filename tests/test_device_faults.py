@@ -186,17 +186,6 @@ def test_plan_requires_steal_and_valid_dead_device():
         )
 
 
-def test_nonpof2_mesh_rejects_fault_plan():
-    from hclib_tpu.device.ici_steal import ICIStealMegakernel
-    from hclib_tpu.parallel.mesh import cpu_mesh
-
-    with pytest.raises(ValueError, match="power-of-two"):
-        ICIStealMegakernel(
-            _bump_mk(), cpu_mesh(3, axis_name="d"), migratable_fns=[BUMP],
-            fault_plan=DeviceFaultPlan(drop_credit_rate=0.1),
-        )
-
-
 def test_quarantine_locales_removes_dead_chip_paths():
     from hclib_tpu.parallel.mesh import (
         cpu_mesh, mesh_locality_graph, quarantine_locales,
@@ -233,24 +222,6 @@ def test_abort_word_stops_resident_mesh_mid_run():
     assert info["rounds"] <= 2  # bounded abort latency, surfaced below
     assert info["pending"] > 0
     assert all(f["abort_round"] == 0 for f in info["fault_stats"])
-
-
-def test_abort_word_ici_ring_nonpof2():
-    """The non-pof2 ring kernel polls the same abort word (folded into
-    its ring allreduce)."""
-    from hclib_tpu.device.ici_steal import ICIStealMegakernel
-    from hclib_tpu.parallel.mesh import cpu_mesh
-
-    sk = ICIStealMegakernel(
-        _bump_mk(), cpu_mesh(3, axis_name="d"), migratable_fns=[BUMP],
-        window=4,
-    )
-    iv, _, info = sk.run(
-        _skewed(3, 30), quantum=2, abort=True, max_rounds=256,
-    )
-    assert info["aborted"]
-    assert info["pending"] > 0
-    assert info["steal_rounds"] <= 2
 
 
 def test_dead_chip_rehomes_and_survivors_drain_workload():

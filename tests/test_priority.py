@@ -423,3 +423,28 @@ def test_bucket_gauges_ride_metrics():
     # device 0, bucket 0 on this single-device run.
     assert "prio.bucket_occupancy.0.0" in m
     assert m["prio.trace.fire_bucket"] == t["batch_rounds"]
+
+
+# ------------------------------------------------------------- SMEM model
+
+
+def test_smem_model_charges_the_core_scratch_it_declares(monkeypatch):
+    """``core_scratch`` is the one declaration of the scheduler core's
+    scratch (free stacks; lanes and lstate over kinds x buckets):
+    ``smem_footprint`` charges exactly those words for it, at this
+    build's capacity and at another."""
+    from hclib_tpu.device.megakernel import LS_WORDS, VBLOCK, smem_bytes
+
+    mk = _seq_mk(4, lambda arg: arg(0) // 2)
+    assert mk.lane_scratch_rows == 4  # one routed kind x four buckets
+    for cap in (mk.capacity, 40):
+        shapes = [s.shape for s in mk.core_scratch(cap)]
+        assert shapes == [
+            (cap + 1,), (mk.num_values // VBLOCK + 1,), (4, cap),
+            (4, LS_WORDS),
+        ]
+        whole = mk.smem_footprint(cap)
+        with monkeypatch.context() as m:
+            m.setattr(mk, "core_scratch", lambda capacity=None: [])
+            rest = mk.smem_footprint(cap)
+        assert whole - rest == sum(map(smem_bytes, shapes))

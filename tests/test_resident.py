@@ -188,3 +188,35 @@ def test_successor_free_rows_still_migrate_whole():
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
     per_dev = info["per_device_counts"][:, 5]
     assert int((per_dev > 0).sum()) >= 3, per_dev
+
+
+@pytest.mark.parametrize("dims", [(3,), (3, 2)])
+def test_non_power_of_two_mesh_is_refused(dims):
+    """The hypercube hop schedule needs every axis a power of two; the
+    refusal says so, and says what to do instead."""
+    names = ("r", "c")[: len(dims)]
+    n = math.prod(dims)
+    mesh = make_mesh(dims, names, jax.devices("cpu")[:n])
+    with pytest.raises(ValueError, match=r"power-of-two.*next power of two"):
+        ResidentKernel(bump_mk(32), mesh, migratable_fns=[0])
+
+
+def test_scheduler_core_has_exactly_three_embedders():
+    """``Megakernel._make_core`` is embedded by hand (scratch, ref order,
+    hooks), so every caller repeats every change to the core. Three
+    kernels do: the single-chip one, the stream's, the mesh's. A fourth
+    caller fails here and has to say why it is not one of those."""
+    import pathlib
+    import re
+
+    import hclib_tpu
+
+    root = pathlib.Path(hclib_tpu.__file__).parent
+    callers = sorted(
+        str(p.relative_to(root))
+        for p in root.rglob("*.py")
+        if re.search(r"\._make_core\(", p.read_text())
+    )
+    assert callers == [
+        "device/inject.py", "device/megakernel.py", "device/resident.py",
+    ]

@@ -1,5 +1,6 @@
-"""One-sided device PGAS (device/pgas_kernel.py): put / AM / wait-until on
-data between resident schedulers, on simulated multi-device meshes (Mosaic
+"""One-sided device PGAS: ResidentKernel in its PGAS-only configuration
+(``steal=False`` plus channels): put / AM / wait-until on data between
+resident schedulers, on simulated multi-device meshes (Mosaic
 TPU interpret mode emulates the remote DMAs + semaphores) plus a TPU-gated
 1-device compile.
 
@@ -15,7 +16,7 @@ import pytest
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.megakernel import Megakernel
-from hclib_tpu.device.pgas_kernel import PGASMegakernel
+from hclib_tpu.device.resident import ResidentKernel
 from hclib_tpu.parallel.mesh import cpu_mesh
 
 ROWS = 16
@@ -101,8 +102,9 @@ def test_put_wakes_parked_consumer_across_devices():
     ndev = 4
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = _mk(ndev=ndev, capacity=128)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)}
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
     )
     builders = [TaskGraphBuilder() for _ in range(ndev)]
     waits = [[] for _ in range(ndev)]
@@ -131,8 +133,9 @@ def test_am_targets_specific_device_mid_run():
     ndev = 4
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = _mk(ndev=ndev, capacity=128)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)},
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
         # am_window 2 < the 4 messages each sender queues, so the
         # outbox's capped-head carry-over path actually runs.
         am_window=2,
@@ -169,8 +172,9 @@ def test_get_composes_am_and_reply_put():
     ndev = 4
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = _mk(ndev=ndev)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)}
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
     )
     GET_ROW = 3  # fetch row 3 of each owner
     REQUEST = 5  # appended below after the 5 base kernels
@@ -206,8 +210,9 @@ def test_wait_until_device_side_spawn():
     ndev = 2
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = _mk(ndev=ndev)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)}
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
     )
 
     SPAWNER = 5
@@ -238,8 +243,9 @@ def test_pgas_race_free_under_detector():
     ndev = 2
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = _mk(ndev=ndev)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)},
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
         am_window=4,
     )
 
@@ -252,10 +258,7 @@ def test_pgas_race_free_under_detector():
 
     mk.kernel_names.append("send_all")
     mk.kernel_fns.append(send_all)
-    # pof2 meshes delegate to the resident kernel: patch the build that
-    # will actually run.
-    target = pg._resident if pg._resident is not None else pg
-    orig = target._build
+    orig = pg._build
 
     def build_with_detector(*build_args):
         import unittest.mock as m
@@ -265,12 +268,13 @@ def test_pgas_race_free_under_detector():
             pltpu, "InterpretParams",
             # Ignore kwargs: if interpret_mode() ever grows non-default
             # InterpretParams variants, they must not silently alter
-            # race-detection semantics (same in test_resident/test_ici).
+            # race-detection semantics (same in test_resident and
+            # test_resident_steal).
             lambda **kw: real(detect_races=True),
         ):
             return orig(*build_args)
 
-    target._build = build_with_detector
+    pg._build = build_with_detector
     builders = [TaskGraphBuilder() for _ in range(ndev)]
     for d in range(ndev):
         builders[d].add(SEND)
@@ -290,8 +294,9 @@ def test_pgas_compiles_and_runs_on_tpu():
 
     mesh = Mesh(np.array(mesh_devs), ("queues",))
     mk = _mk(interpret=False, ndev=1)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)}
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
     )
 
     SPAWNER = 5
@@ -318,19 +323,19 @@ def test_pgas_compiles_and_runs_on_tpu():
 
 def test_pgas_batch_routed_am_bumps_exact():
     """ISSUE 7 acceptance (PGAS arm): AM-delivered BUMP tasks fire through
-    the batched same-kind tier - the lane scratch binds positionally at
-    the end of the PGAS body's 23-ref scratch tail, so this is the
-    coverage that a _build edit misplacing lanes/lstate/tstats fails
-    loudly. Every device AMs a BUMP at every other device; batched
-    delivery must land the exact all-senders sum on each device (slot_ctx
-    carries the pgas ctx_hook, so a batched AM task behaves exactly like
-    scalar dispatch), and tier counters reconcile with the executed
-    count."""
+    the batched same-kind tier - the lane scratch binds positionally
+    inside the resident kernel's scratch tail, so this is the coverage
+    that a _build edit misplacing lanes/lstate/tstats fails loudly.
+    Every device AMs a BUMP at every other device; batched delivery must
+    land the exact all-senders sum on each device (slot_ctx carries the
+    pgas ctx_hook, so a batched AM task behaves exactly like scalar
+    dispatch), and tier counters reconcile with the executed count."""
     ndev = 4
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = _mk(ndev=ndev, capacity=128, batch_width=4)
-    pg = PGASMegakernel(
-        mk, mesh, channels={"c0": ("heap", 1), "reply": ("heap", 1)},
+    pg = ResidentKernel(
+        mk, mesh, steal=False,
+        channels={"c0": ("heap", 1), "reply": ("heap", 1)},
         am_window=2,
     )
 

@@ -78,8 +78,8 @@ print("procworld: 2 processes exchanged put/get + allreduce")
 # -- 2. in-kernel ICI steal on a simulated 2-device mesh -----------------
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
-from hclib_tpu.device.ici_steal import ICIStealMegakernel
 from hclib_tpu.device.megakernel import Megakernel
+from hclib_tpu.device.resident import ResidentKernel
 from hclib_tpu.parallel.mesh import cpu_mesh
 
 BUMP = 0
@@ -92,7 +92,9 @@ def bump(ctx):
 mesh = cpu_mesh(2, axis_name="queues")
 mk = Megakernel(kernels=[("bump", bump)], capacity=128, num_values=4,
                 succ_capacity=8, interpret=True)
-smk = ICIStealMegakernel(mk, mesh, migratable_fns=[BUMP], window=8)
+# Steal-only: successor-free BUMP rows migrate whole (homed=False), no PGAS.
+smk = ResidentKernel(mk, mesh, steal=True, migratable_fns=[BUMP],
+                     homed=False, window=8)
 builders = [TaskGraphBuilder() for _ in range(2)]
 for i in range(16):
     builders[0].add(BUMP, args=[i + 1])  # all work lands on device 0
@@ -101,6 +103,6 @@ assert int(iv[:, 0].sum()) == 16 * 17 // 2
 per_dev = info["per_device_counts"][:, 5]
 assert per_dev[1] > 0, "device 1 stole nothing"
 print(f"ici steal: skewed load executed as {per_dev.tolist()} across devices "
-      f"in {info['steal_rounds']} resident rounds")
+      f"in {info['rounds']} resident rounds")
 
 print("lesson 8 OK")
