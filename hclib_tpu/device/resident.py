@@ -290,6 +290,8 @@ FS_QUIESCE_ROUND = 10  # round the folded quiesce word was observed (-1)
 FS_TEN_EXPIRED = 11 # tenant-tagged ring rows I dropped expired (the
                     # mesh half of deadline admission: the host marks
                     # TEN_EXPIRED on published rows, the poll skips them)
+FS_EXPORTED = 12    # rows I put on the wire in the steal exchange
+FS_IMPORTED = 13    # rows I installed from the steal exchange's inboxes
 FS_WORDS = 16
 
 
@@ -313,6 +315,8 @@ def decode_fault_stats(row) -> Dict[str, Any]:
         "heartbeat": row[FS_HB],
         "quiesce_round": row[FS_QUIESCE_ROUND],
         "tenant_expired": row[FS_TEN_EXPIRED],
+        "steal_exported": row[FS_EXPORTED],
+        "steal_imported": row[FS_IMPORTED],
     }
 
 
@@ -1208,10 +1212,12 @@ class ResidentKernel:
             # Homed exports stay pending at home (the proxy); only
             # whole-row exports hand their pending count to the thief.
             counts[C_PENDING] = counts[C_PENDING] - nwhole
+            fstats[FS_EXPORTED] = fstats[FS_EXPORTED] + nsend
             return nsend
 
         def import_rows(box):
             n = box[W, 0]
+            fstats[FS_IMPORTED] = fstats[FS_IMPORTED] + n
 
             def one(i, _):
                 install_fixed(lambda w: box[i, w])
@@ -2261,6 +2267,7 @@ class ResidentKernel:
             scratch_shapes=scratch,
             input_output_aliases=aliases,
             interpret=interpret_mode() if mk.interpret else False,
+            name="resident_mesh",  # the kernel's name in a profiler trace
         )
         axes = self.axes
 
@@ -2371,7 +2378,10 @@ class ResidentKernel:
         through this driver the word is uploaded at entry.
         ``info['fault_stats']`` carries
         each device's FS_* trace (abort round, credits dropped/regenerated/
-        duplicated, quarantine mask, re-homed rows, heartbeat).
+        duplicated, quarantine mask, re-homed rows, heartbeat), and
+        ``info['steal']`` the rows each device exported and imported over
+        the steal exchange (``{"exported": [...], "imported": [...]}``,
+        one entry a device; the sums are equal when nothing was lost).
 
         Checkpoint (``mk`` built with ``checkpoint=True``): ``quiesce``
         is the host quiesce word - truthy stops the mesh at its next
@@ -2675,6 +2685,10 @@ class ResidentKernel:
         frows = tail[-1]
         fs = [decode_fault_stats(frows[d]) for d in range(ndev)]
         info["fault_stats"] = fs
+        info["steal"] = {
+            "exported": [f["steal_exported"] for f in fs],
+            "imported": [f["steal_imported"] for f in fs],
+        }
         info["aborted"] = any(f["abort_round"] >= 0 for f in fs)
         if self.T:
             # The stacked tctl echo (lane cursors + cumulative install/

@@ -30,6 +30,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .descriptor import (
@@ -133,63 +134,78 @@ def execute_partitions(
     builder partitioning and preset widening entirely - the arrays are a
     quiesced run's exported state, already consistent. ``keep_inputs``
     surfaces the uploaded input arrays as ``info['inputs']`` (the
-    checkpoint path needs the succ CSR, which is input-only)."""
-    if state is not None:
-        tasks = np.asarray(state["tasks"]).copy()
-        succ = np.asarray(state["succ"]).copy()
-        ring = np.asarray(state["ready"]).copy()
-        counts = np.asarray(state["counts"]).copy()
-        ivalues = np.asarray(state["ivalues"]).copy()
-    else:
-        tasks, succ, ring, counts = partition_builders(mk, ndev, builders)
-        if ivalues is None:
-            ivalues = np.zeros((ndev, mk.num_values), np.int32)
+    checkpoint path needs the succ CSR, which is input-only).
+
+    The four phases are ``bench:mesh.*`` spans in a profiler trace:
+    ``partition``, ``upload``, ``run`` (the launch and the wait for it:
+    the first output read to the host) and ``readback`` (the rest)."""
+    with TraceAnnotation("bench:mesh.partition"):
+        if state is not None:
+            tasks = np.asarray(state["tasks"]).copy()
+            succ = np.asarray(state["succ"]).copy()
+            ring = np.asarray(state["ready"]).copy()
+            counts = np.asarray(state["counts"]).copy()
+            ivalues = np.asarray(state["ivalues"]).copy()
         else:
-            ivalues = np.asarray(ivalues)
-            for d in range(ndev):
-                mk.widen_value_alloc(counts[d], ivalues[d])
-    # Mutate AFTER preset widening: runners that symmetrize or validate
-    # the per-device value_alloc (ResidentKernel's symmetric-heap layout
-    # and migration result-slot check) must see the final values.
-    if mutate is not None:
-        mutate(tasks, succ, ring, counts)
-    for c in counts:
-        mk.check_row_values(int(c[C_VALLOC]))
-    data = dict(data or {})
-    if set(data.keys()) != set(mk.data_specs.keys()):
-        raise ValueError(
-            f"data buffers {sorted(data)} != declared {sorted(mk.data_specs)}"
-        )
+            tasks, succ, ring, counts = partition_builders(
+                mk, ndev, builders
+            )
+            if ivalues is None:
+                ivalues = np.zeros((ndev, mk.num_values), np.int32)
+            else:
+                ivalues = np.asarray(ivalues)
+                for d in range(ndev):
+                    mk.widen_value_alloc(counts[d], ivalues[d])
+        # Mutate AFTER preset widening: runners that symmetrize or
+        # validate the per-device value_alloc (ResidentKernel's
+        # symmetric-heap layout and migration result-slot check) must see
+        # the final values.
+        if mutate is not None:
+            mutate(tasks, succ, ring, counts)
+        for c in counts:
+            mk.check_row_values(int(c[C_VALLOC]))
+        data = dict(data or {})
+        if set(data.keys()) != set(mk.data_specs.keys()):
+            raise ValueError(
+                f"data buffers {sorted(data)} != declared "
+                f"{sorted(mk.data_specs)}"
+            )
     sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
     put = lambda x: jax.device_put(np.ascontiguousarray(x), sh)  # noqa: E731
-    args = [
-        put(tasks), put(succ), put(ring), put(counts), put(ivalues),
-        *[put(data[k]) for k in mk.data_specs.keys()],
-        *[put(x) for x in extra_inputs],
-    ]
-    outs = jitted(*args)
-    counts_o, iv_o, gcounts = outs[0], outs[1], outs[2]
-    nd = len(mk.data_specs)
-    data_o = dict(zip(mk.data_specs.keys(), outs[3 : 3 + nd]))
-    g = np.asarray(gcounts)[0]  # identical on every row
-    info = {
-        "executed": int(g[C_EXECUTED]),
-        "pending": int(g[C_PENDING]),
-        "overflow": bool(g[C_OVERFLOW]),
-        "per_device_counts": np.asarray(counts_o),
-        **ran_on(counts_o, mk.interpret),
-        # Fewest devices any input is spread over: ndev unless something
-        # sits whole on one device.
-        "input_devices": min(len(a.sharding.device_set) for a in args),
-    }
-    # Runner-specific trailing outputs (e.g. the resident kernel's
-    # per-device fault/abort stats) ride after the data buffers.
-    info["extra_outputs"] = [np.asarray(x) for x in outs[3 + nd :]]
-    if keep_inputs:
-        info["inputs"] = {"succ": succ}
-    if with_rounds:
-        info["steal_rounds"] = int(np.asarray(counts_o)[0][C_ROUNDS])
-    return np.asarray(iv_o), data_o, info
+    with TraceAnnotation("bench:mesh.upload"):
+        args = [
+            put(tasks), put(succ), put(ring), put(counts), put(ivalues),
+            *[put(data[k]) for k in mk.data_specs.keys()],
+            *[put(x) for x in extra_inputs],
+        ]
+    with TraceAnnotation("bench:mesh.run"):
+        outs = jitted(*args)
+        counts_o, iv_o, gcounts = outs[0], outs[1], outs[2]
+        g = np.asarray(gcounts)[0]  # the wait; identical on every row
+    with TraceAnnotation("bench:mesh.readback"):
+        nd = len(mk.data_specs)
+        data_o = dict(zip(mk.data_specs.keys(), outs[3 : 3 + nd]))
+        info = {
+            "executed": int(g[C_EXECUTED]),
+            "pending": int(g[C_PENDING]),
+            "overflow": bool(g[C_OVERFLOW]),
+            "per_device_counts": np.asarray(counts_o),
+            **ran_on(counts_o, mk.interpret),
+            # Fewest devices any input is spread over: ndev unless
+            # something sits whole on one device.
+            "input_devices": min(
+                len(a.sharding.device_set) for a in args
+            ),
+        }
+        # Runner-specific trailing outputs (e.g. the resident kernel's
+        # per-device fault/abort stats) ride after the data buffers.
+        info["extra_outputs"] = [np.asarray(x) for x in outs[3 + nd :]]
+        if keep_inputs:
+            info["inputs"] = {"succ": succ}
+        if with_rounds:
+            info["steal_rounds"] = int(np.asarray(counts_o)[0][C_ROUNDS])
+        iv_host = np.asarray(iv_o)
+    return iv_host, data_o, info
 
 
 class ShardedMegakernel:

@@ -308,6 +308,9 @@ def test_v5e_compiler_accepts_resident_kernel_on_four_devices(topo):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    # the name a profiler trace gives the kernel (benchmarks/metrics/
+    # mesh_round_us.json finds it by this name)
+    assert "resident_mesh" in text
 
 
 def test_smem_footprint_check_names_the_capacity_that_fits():

@@ -57,6 +57,9 @@ def test_resident_steal_two_devices_exact():
     assert info["pending"] == 0
     assert int(iv[:, 0].sum()) == ntasks * (ntasks + 1) // 2
     assert info["per_device_counts"][1, 5] > 0  # work actually migrated
+    steal = info["steal"]  # and the exchange counted what it moved
+    assert sum(steal["exported"]) == sum(steal["imported"]) > 0
+    assert steal["imported"][1] > 0
 
 
 def test_resident_steal_dependency_graphs_stay_home():
