@@ -256,7 +256,18 @@ class Future:
     """One submitted request's handle. States: PENDING then exactly one
     of RESULT | EXPIRED | POISONED | PREEMPTED(resume_token) - the
     degradation ladder. Thread-safe: the driving loop resolves, any
-    thread may ``result()``/``wait()``."""
+    thread may ``result()``/``wait()``.
+
+    A future has a ``threading.Event`` only once somebody waits for it:
+    the first ``wait()`` / ``result()`` that finds it PENDING makes the
+    Event under the owning :class:`FutureTable`'s lock, the lock every
+    terminal transition holds around ``_finish``, so state and Event
+    are read and written under one lock and no wake-up is lost. A
+    future that is terminal before anyone waits, or whose client only
+    reads ``state`` / ``value`` / ``t_done`` after the stream returned,
+    never builds one (``FutureTable.waited`` counts those that did).
+    ``fn``, ``slot`` and ``token`` are stored as handed in: the ledger
+    passes Python ints."""
 
     __slots__ = (
         "token", "tenant", "fn", "slot", "state", "value", "reason",
@@ -264,30 +275,33 @@ class Future:
     )
 
     def __init__(self, table: "FutureTable", token: int, tenant: str,
-                 fn: int, slot: int) -> None:
-        self.token = int(token)
+                 fn: int, slot: int, t_submit: float) -> None:
+        self.token = token
         self.tenant = tenant
-        self.fn = int(fn)
-        self.slot = int(slot)
+        self.fn = fn
+        self.slot = slot
         self.state = PENDING
         self.value: Optional[int] = None
         self.reason: Optional[str] = None
         self.resume_token = None
-        self.t_submit = table._clock()
+        self.t_submit = t_submit
         self.t_done: Optional[float] = None
-        self._event = threading.Event()
+        self._event: Optional[threading.Event] = None
         self._table = table
 
-    # -- driver side (FutureTable only) --
+    # -- driver side (FutureTable only; its lock is held) --
 
     def _finish(self, state: str, value=None, reason=None,
                 resume_token=None) -> None:
-        self.state = state
         self.value = value
         self.reason = reason
         self.resume_token = resume_token
         self.t_done = self._table._clock()
-        self._event.set()
+        # Last: a reader that skips the lock on a terminal ``state``
+        # finds the fields above already written.
+        self.state = state
+        if self._event is not None:
+            self._event.set()
 
     # -- client side --
 
@@ -298,18 +312,26 @@ class Future:
         """Block (bounded-backoff poll) until terminal; True if done."""
         if self.state != PENDING:
             return True
-        backoff = self._table.backoff_s
+        table = self._table
+        with table._lock:
+            if self.state != PENDING:
+                return True
+            event = self._event
+            if event is None:
+                event = self._event = threading.Event()
+                table.waited += 1
+        backoff = table.backoff_s
         deadline = None if timeout is None else (
             time.monotonic() + float(timeout)
         )
         step = min(0.0005, backoff)
-        while not self._event.is_set():
+        while not event.is_set():
             if deadline is not None:
                 left = deadline - time.monotonic()
                 if left <= 0:
-                    return self._event.is_set()
+                    return event.is_set()
                 step = min(step, left)
-            self._event.wait(step)
+            event.wait(step)
             step = min(step * 2, backoff)
         return True
 
@@ -367,6 +389,13 @@ class FutureTable:
     cursor consumes each row once, so in correct operation this never
     fires - the tests force it to prove it would).
 
+    The table's lock is also what makes a future's lazy Event safe: a
+    terminal transition finishes the future under it, and the first
+    waiter on a PENDING future builds the Event under it
+    (:meth:`Future.wait`). ``waited`` counts the futures that ever
+    built one; ``stats_dict()`` shows it, ``conservation()`` does not
+    certify it.
+
     Across a checkpoint cut the ledger hands over: ``preempt_all()``
     turns every live future PREEMPTED (terminal for ``result()``) and
     ``export_tokens()`` / ``adopt_tokens()`` move the still-pending
@@ -398,10 +427,22 @@ class FutureTable:
         self.poisoned = 0
         self.preempted = 0
         self.reattached = 0
+        # Futures that ever built an Event: somebody waited on them
+        # while they were PENDING (Future.wait). Telemetry only, not
+        # part of the identity conservation() certifies.
+        self.waited = 0
 
     # -- submit side --
 
     def create(self, tenant: str, fn: int, slot: int) -> Future:
+        """Open one ledger entry: a fresh token and its PENDING future."""
+        return self._create(tenant, int(fn), int(slot), self._clock())
+
+    def _create(self, tenant: str, fn: int, slot: int,
+                t_submit: float) -> Future:
+        """``create`` for the admission routine (device/tenants.py),
+        which hands in Python ints and the clock reading it already
+        took, and may hold its table's admission lock."""
         with self._lock:
             token = self._next
             if token >= TOKEN_LIMIT:
@@ -410,11 +451,11 @@ class FutureTable:
                     "submits per serving session): roll over to a fresh "
                     "table"
                 )
-            self._next += 1
-            fut = Future(self, token, tenant, fn, slot)
+            self._next = token + 1
+            fut = Future(self, token, tenant, fn, slot, t_submit)
             self._live[token] = fut
             self.submitted += 1
-            return fut
+        return fut
 
     # -- terminal transitions (driver side) --
 
@@ -548,7 +589,8 @@ class FutureTable:
             token = int(token)
             meta = self._unattached.pop(token, None)
             if meta is not None:
-                fut = Future(self, token, meta[0], meta[1], meta[2])
+                fut = Future(self, token, meta[0], meta[1], meta[2],
+                             self._clock())
                 self._live[token] = fut
                 self.reattached += 1
                 return fut
@@ -556,7 +598,8 @@ class FutureTable:
             if early is not None:
                 # The residue row retired before the client re-attached:
                 # hand back an already-terminal future.
-                fut = Future(self, token, str(tenant), int(fn), int(slot))
+                fut = Future(self, token, str(tenant), int(fn), int(slot),
+                             self._clock())
                 fut._finish(early[0], value=early[1], reason=early[2])
                 self.reattached += 1
                 return fut
@@ -599,6 +642,7 @@ class FutureTable:
     def stats_dict(self) -> Dict[str, Any]:
         d = self.conservation()
         d["backoff_s"] = self.backoff_s
+        d["waited"] = self.waited
         return d
 
 

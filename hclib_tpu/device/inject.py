@@ -157,6 +157,7 @@ from .tenants import (
     TC_WEIGHT,
     Admission,
     TenantTable,
+    _request_row,
     build_row,
     normalize_tenants,
 )
@@ -483,24 +484,35 @@ class StreamingMegakernel:
         queue) - into a blocking wait with bounded exponential backoff,
         up to ``wait_timeout_s`` or the submission's own deadline.
         Terminal rejections (ring budget, quarantine, cancellation,
-        expiry, closed stream) return immediately either way."""
+        expiry, closed stream) return immediately either way.
+
+        The stream builds the request's row and the table keeps that
+        array: a caller who has a row of its own hands it to
+        ``TenantTable.admit``, which copies it. On an egress-enabled
+        table the verdict carries ``.future``; it has no
+        ``threading.Event`` until somebody waits on it while it is
+        pending (device/egress.py, ``Future``)."""
         if self.tenants is None:
             raise ValueError(
                 "submit() needs tenant lanes: build the stream with "
                 "tenants= (or set HCLIB_TPU_TENANTS)"
             )
         table = self.tenants
-        table._lane(tenant)  # unknown tenants raise KeyError up front
-        row = build_row(fn, args, out, succ0, succ1)
-        deadline_at = table.resolve_deadline(
-            tenant, deadline_s, cancel_scope
-        )
-        with self._lock:
-            closed = self._closed
-        if closed:
-            return table.record_reject(tenant, "closed")
+        lane = table._lane(tenant)  # unknown tenants raise KeyError up front
+        fn, out = int(fn), int(out)
+        row = _request_row(fn, args, out, succ0, succ1)
+        now = table.clock()
+        deadline_at = table._deadline(lane, now, deadline_s, cancel_scope)
+        # A plain read: a lock taken only to read the flag, and dropped
+        # before acting on it, would order nothing - a submit racing
+        # close() / abort() lands before or after it either way. The
+        # race that matters (the drained exit) is closed by the TABLE'S
+        # flag, read under the table's lock in the admission routine.
+        if self._closed:
+            return table._reject(lane, "closed")
         if not wait:
-            return table.admit(tenant, row, deadline_at, cancel_scope)
+            return table._admit(lane, now, deadline_at, cancel_scope,
+                                True, row, fn, out)
         # The timeout is a WALL-clock bound: an injected table clock
         # (deterministic tests) governs admission/deadline semantics but
         # must not be able to make "bounded wait" unbounded - a frozen
@@ -509,23 +521,21 @@ class StreamingMegakernel:
         t_end = time.monotonic() + float(wait_timeout_s)
         backoff = 0.0005
         while True:
-            adm = table.admit(
-                tenant, row, deadline_at, cancel_scope,
-                record_reject=False,
-            )
+            adm = table._admit(lane, now, deadline_at, cancel_scope,
+                               False, row, fn, out)
             if adm:
                 return adm
             if adm.reason not in ("rate", "backlog"):
-                return table.record_reject(tenant, adm.reason)
+                return table._reject(lane, adm.reason)
             if deadline_at is not None and table.clock() >= deadline_at:
-                return table.record_reject(tenant, "expired")
+                return table._reject(lane, "expired")
             if time.monotonic() >= t_end:
-                return table.record_reject(tenant, adm.reason)
-            with self._lock:
-                if self._closed:
-                    return table.record_reject(tenant, "closed")
+                return table._reject(lane, adm.reason)
+            if self._closed:
+                return table._reject(lane, "closed")
             time.sleep(backoff)
             backoff = min(backoff * 2, 0.05)
+            now = table.clock()
         assert False, "unreachable"
 
     def close(self) -> None:

@@ -350,6 +350,40 @@ def build_row(fn: int, args: Sequence[int] = (), out: int = 0,
     return row
 
 
+def _request_row(fn: int, args: Sequence[int], out: int,
+                 succ0: int, succ1: int) -> np.ndarray:
+    """The row of one ``submit()``: :func:`build_row`'s words for values
+    that are ints already, each stored once into a fresh array that the
+    admission routine then owns (it stamps the tenant words into the
+    same array and queues it: no copy, no read-back). One store a
+    non-zero word: on numpy 2.0 a scalar store of a Python int costs a
+    fifth of a slice store, so eight of them beat three slices."""
+    if len(args) > NUM_ARGS:
+        raise ValueError(f"at most {NUM_ARGS} args per descriptor")
+    row = np.zeros(RING_ROW, np.int32)
+    row[F_FN] = fn
+    row[F_SUCC0] = succ0
+    row[F_SUCC1] = succ1
+    i = F_A0
+    for a in args:
+        row[i] = a
+        i += 1
+    row[F_OUT] = out
+    row[F_HOME] = NO_TASK
+    return row
+
+
+def _own_row(row: np.ndarray) -> np.ndarray:
+    """A private copy of a CALLER'S row (``admit``, ``submit_row``):
+    what the caller does to its array afterwards must not change what
+    ``pump`` publishes. The transport words only the export stamps are
+    cleared."""
+    r = np.array(row, np.int32).reshape(RING_ROW)
+    r[TEN_EXPIRED] = 0
+    r[TEN_DEADLINE_MS] = 0  # stamped only at checkpoint export
+    return r
+
+
 class _Pending:
     """One admitted row in flight on the host side."""
 
@@ -357,7 +391,7 @@ class _Pending:
                  "token")
 
     def __init__(self, row: np.ndarray, deadline_at: Optional[float],
-                 t_submit: float) -> None:
+                 t_submit: float, token: int) -> None:
         self.row = row
         self.deadline_at = deadline_at
         self.t_submit = t_submit
@@ -366,7 +400,7 @@ class _Pending:
         # Submit token of a tracked request (rides the row's TEN_TOKEN
         # word; 0 = untracked). Zeroed once its future reached a
         # terminal state host-side, so each token resolves exactly once.
-        self.token = int(row[TEN_TOKEN])
+        self.token = token
 
 
 def _remaining_ms(deadline_at: Optional[float], now: float) -> int:
@@ -387,7 +421,7 @@ def _readmit_pending(row: np.ndarray, now: float) -> "_Pending":
     ms = int(r[TEN_DEADLINE_MS])
     r[TEN_DEADLINE_MS] = 0
     deadline_at = (now + ms / 1000.0) if ms > 0 else None
-    return _Pending(r, deadline_at, now)
+    return _Pending(r, deadline_at, now, int(r[TEN_TOKEN]))
 
 
 class _Lane:
@@ -561,8 +595,12 @@ class TenantTable:
         """The absolute admission deadline for one submit: explicit
         ``deadline_s`` wins, else the nearest CancelScope deadline, else
         the tenant's default ``deadline_s``."""
-        lane = self._lane(tenant)
-        now = self.clock()
+        return self._deadline(self._lane(tenant), self.clock(),
+                              deadline_s, cancel_scope)
+
+    def _deadline(self, lane: _Lane, now: float,
+                  deadline_s: Optional[float],
+                  cancel_scope: Optional[CancelScope]) -> Optional[float]:
         if deadline_s is not None:
             return now + float(deadline_s)
         if cancel_scope is not None:
@@ -580,81 +618,98 @@ class TenantTable:
             return now + lane.spec.deadline_s
         return None
 
+    def _admit(self, lane: _Lane, now: float,
+               deadline_at: Optional[float],
+               cancel_scope: Optional[CancelScope], record_reject: bool,
+               row: np.ndarray, fn: int, out: int) -> Admission:
+        """THE admission routine: every ``submit`` / ``admit`` /
+        ``submit_row`` of the front door ends here, with its lane
+        resolved, its one clock reading ``now`` (the deadline check,
+        the latency stamp and the future's ``t_submit`` share it) and a
+        ``row`` the table may keep (``fn`` / ``out`` are its F_FN /
+        F_OUT words as Python ints). Gates run cheapest-first -
+        quarantined, cancelled, expired, closed, ring, backlog, rate -
+        and a rate token is taken only when every cheaper gate passed.
+        The table's lock is taken once; the ledger's own lock
+        (``FutureTable._create``) nests inside it."""
+        spec = lane.spec
+        if lane.quarantined is not None:
+            reason = "quarantined"
+        elif lane.scope.cancelled() or (
+            cancel_scope is not None and cancel_scope.cancelled()
+        ):
+            reason = "cancelled"
+        elif deadline_at is not None and now >= deadline_at:
+            reason = "expired"
+        else:
+            reason = None
+        with self._lock:
+            if reason is None:
+                queued = len(lane.queue)
+                if self._closed:
+                    reason = "closed"
+                # Ring lifetime budget: the region is a linear append
+                # log per stream (device/inject.py), so published +
+                # queued rows may never exceed it - rejecting here keeps
+                # QUEUED an eventual-service promise instead of a silent
+                # wedge.
+                elif lane.published + queued >= self.region_rows:
+                    reason = "ring"
+                elif queued >= spec.queue_capacity:
+                    reason = "backlog"
+                elif lane.bucket is not None and not lane.bucket.try_take(1):
+                    reason = "rate"
+            if reason is not None:
+                lane.rejected += record_reject
+                return Admission(ADMIT_REJECTED, spec.id, reason)
+            over = (
+                spec.max_in_flight is not None
+                and queued + lane.published - lane.consumed
+                >= spec.max_in_flight
+            )
+            fut = None
+            token = 0
+            if self.futures is not None:
+                # Token minted only after every admission gate passed:
+                # a rejected submit never enters the conservation ledger.
+                fut = self.futures._create(spec.id, fn, out, now)
+                token = fut.token
+            row[TEN_ID] = lane.idx
+            row[TEN_TOKEN] = token
+            lane.queue.append(_Pending(row, deadline_at, now, token))
+            index = lane.accepted
+            lane.accepted = index + 1
+        # (status, tenant, reason, index, device, future), positional:
+        # keywords cost this call half as much again.
+        return Admission(ADMIT_QUEUED if over else ADMIT_ACCEPTED, spec.id,
+                         None, index, None, fut)
+
+    def _reject(self, lane: _Lane, reason: str) -> Admission:
+        with self._lock:
+            lane.rejected += 1
+        return Admission(ADMIT_REJECTED, lane.spec.id, reason)
+
     def admit(self, tenant: Union[str, int], row: np.ndarray,
               deadline_at: Optional[float] = None,
               cancel_scope: Optional[CancelScope] = None,
               record_reject: bool = True) -> Admission:
-        """Non-blocking admission of one prepared ring row. Checks run
-        cheapest-first and quota checks only consume a rate token when
-        every cheaper gate already passed."""
+        """Non-blocking admission of one prepared ring row. The row
+        stays the CALLER'S: the table queues a copy, so mutating it
+        after the call changes nothing that ``pump`` publishes. Checks
+        run cheapest-first and quota checks only consume a rate token
+        when every cheaper gate already passed (``_admit``)."""
         lane = self._lane(tenant)
-        tid = lane.spec.id
-        now = self.clock()
-
-        def reject(reason: str) -> Admission:
-            if record_reject:
-                with self._lock:
-                    lane.rejected += 1
-            return Admission(ADMIT_REJECTED, tid, reason)
-
-        if lane.quarantined is not None:
-            return reject("quarantined")
-        if lane.scope.cancelled() or (
-            cancel_scope is not None and cancel_scope.cancelled()
-        ):
-            return reject("cancelled")
-        if deadline_at is not None and now >= deadline_at:
-            return reject("expired")
-        with self._lock:
-            if self._closed:
-                lane.rejected += record_reject
-                return Admission(ADMIT_REJECTED, tid, "closed")
-            # Ring lifetime budget: the region is a linear append log per
-            # stream (device/inject.py), so published + queued rows may
-            # never exceed it - rejecting here keeps QUEUED an eventual-
-            # service promise instead of a silent wedge.
-            if lane.published + len(lane.queue) >= self.region_rows:
-                lane.rejected += record_reject
-                return Admission(ADMIT_REJECTED, tid, "ring")
-            if len(lane.queue) >= lane.spec.queue_capacity:
-                lane.rejected += record_reject
-                return Admission(ADMIT_REJECTED, tid, "backlog")
-            if lane.bucket is not None and not lane.bucket.try_take(1):
-                lane.rejected += record_reject
-                return Admission(ADMIT_REJECTED, tid, "rate")
-            over = (
-                lane.spec.max_in_flight is not None
-                and lane.backlog >= lane.spec.max_in_flight
-            )
-            r = np.array(row, np.int32).reshape(RING_ROW)
-            r[TEN_ID] = lane.idx
-            r[TEN_EXPIRED] = 0
-            r[TEN_DEADLINE_MS] = 0  # stamped only at checkpoint export
-            fut = None
-            if self.futures is not None:
-                # Token minted only after every admission gate passed:
-                # a rejected submit never enters the conservation ledger.
-                fut = self.futures.create(
-                    tid, int(r[F_FN]), int(r[F_OUT])
-                )
-                r[TEN_TOKEN] = fut.token
-            else:
-                r[TEN_TOKEN] = 0
-            lane.queue.append(_Pending(r, deadline_at, now))
-            lane.accepted += 1
-            return Admission(
-                ADMIT_QUEUED if over else ADMIT_ACCEPTED, tid,
-                index=lane.accepted - 1, future=fut,
-            )
+        r = _own_row(row)
+        return self._admit(
+            lane, self.clock(), deadline_at, cancel_scope, record_reject,
+            r, int(r[F_FN]), int(r[F_OUT]),
+        )
 
     def record_reject(self, tenant: Union[str, int], reason: str) -> (
             Admission):
         """Count a terminal rejection decided by an outer wait loop
         (submit(wait=True) probes with record_reject=False)."""
-        lane = self._lane(tenant)
-        with self._lock:
-            lane.rejected += 1
-        return Admission(ADMIT_REJECTED, lane.spec.id, reason)
+        return self._reject(self._lane(tenant), reason)
 
     def submit(self, tenant: Union[str, int], fn: int,
                args: Sequence[int] = (), out: int = 0,
@@ -663,14 +718,20 @@ class TenantTable:
                cancel_scope: Optional[CancelScope] = None) -> Admission:
         """Build, deadline-resolve, and admit one request in a single
         call - the serving-loop face (mirrors MeshTenantTable.submit).
-        On an egress-enabled table the returned Admission carries
-        ``.future``, whose ``result(timeout=)`` rides the completion
-        mailbox to exactly one terminal rung of the degradation ladder:
+        The table builds the row itself and owns it: each word is
+        written once, nothing is copied. On an egress-enabled table the
+        returned Admission carries ``.future``, whose
+        ``result(timeout=)`` rides the completion mailbox to exactly
+        one terminal rung of the degradation ladder:
         RESULT | EXPIRED | POISONED | PREEMPTED(resume_token)."""
-        row = build_row(fn, args, out, succ0, succ1)
-        deadline_at = self.resolve_deadline(tenant, deadline_s,
-                                            cancel_scope)
-        return self.admit(tenant, row, deadline_at, cancel_scope)
+        lane = self._lane(tenant)
+        fn, out = int(fn), int(out)
+        row = _request_row(fn, args, out, succ0, succ1)
+        now = self.clock()
+        return self._admit(
+            lane, now, self._deadline(lane, now, deadline_s, cancel_scope),
+            cancel_scope, True, row, fn, out,
+        )
 
     def reattach(self, resume_token):
         """Re-attach a PREEMPTED future across a checkpoint cut: feed
@@ -1371,23 +1432,39 @@ class MeshTenantTable:
                deadline_s: Optional[float] = None,
                cancel_scope: Optional[CancelScope] = None,
                device: Optional[int] = None) -> Admission:
-        """Admit one task into the mesh: build the row, resolve the
-        deadline (explicit > scope chain > lane default), route, and
-        return the typed verdict (``.device`` names the landing)."""
-        row = build_row(fn, args, out, succ0, succ1)
-        deadline_at = self.resolve_deadline(tenant, deadline_s,
-                                            cancel_scope)
-        return self.submit_row(tenant, row, deadline_at, cancel_scope,
-                               device=device)
+        """Admit one task into the mesh: build the row (the mesh owns
+        it, nothing is copied), resolve the deadline (explicit > scope
+        chain > lane default), route, and return the typed verdict
+        (``.device`` names the landing)."""
+        i = self._idx(tenant)
+        fn, out = int(fn), int(out)
+        row = _request_row(fn, args, out, succ0, succ1)
+        now = self.clock()
+        deadline_at = self.tables[0]._deadline(
+            self.tables[0]._lanes[i], now, deadline_s, cancel_scope
+        )
+        return self._route(i, now, row, fn, out, deadline_at,
+                           cancel_scope, device)
 
     def submit_row(self, tenant: Union[str, int], row: np.ndarray,
                    deadline_at: Optional[float] = None,
                    cancel_scope: Optional[CancelScope] = None,
                    device: Optional[int] = None) -> Admission:
         """Route one prepared row to a device and admit it there. The
-        routed replica's ``admit`` is the single-device ladder verbatim;
-        routing only picks WHICH replica decides."""
+        row stays the CALLER'S (a copy is queued). The routed replica's
+        admission routine is the single-device ladder verbatim; routing
+        only picks WHICH replica decides."""
         i = self._idx(tenant)
+        r = _own_row(row)
+        return self._route(i, self.clock(), r, int(r[F_FN]),
+                           int(r[F_OUT]), deadline_at, cancel_scope, device)
+
+    def _route(self, i: int, now: float, row: np.ndarray, fn: int,
+               out: int, deadline_at: Optional[float],
+               cancel_scope: Optional[CancelScope],
+               device: Optional[int]) -> Admission:
+        """Pick the device for lane ``i``'s row (which the mesh owns)
+        and hand it to that replica's ``TenantTable._admit``."""
         tid = self.specs[i].id
         # Terminal gates FIRST, mirroring the single-device ladder's
         # cheapest-first order (quarantine/cancel flags are mesh-uniform
@@ -1395,17 +1472,17 @@ class MeshTenantTable:
         # doomed submission never burns a mesh-wide rate token.
         lane0 = self.tables[0]._lanes[i]
         if lane0.quarantined is not None:
-            adm = self.tables[0].record_reject(tid, "quarantined")
+            adm = self.tables[0]._reject(lane0, "quarantined")
             adm.device = 0
             return adm
         if lane0.scope.cancelled() or (
             cancel_scope is not None and cancel_scope.cancelled()
         ):
-            adm = self.tables[0].record_reject(tid, "cancelled")
+            adm = self.tables[0]._reject(lane0, "cancelled")
             adm.device = 0
             return adm
-        if deadline_at is not None and self.clock() >= deadline_at:
-            adm = self.tables[0].record_reject(tid, "expired")
+        if deadline_at is not None and now >= deadline_at:
+            adm = self.tables[0]._reject(lane0, "expired")
             adm.device = 0
             return adm
         if device is not None:
@@ -1437,20 +1514,21 @@ class MeshTenantTable:
             target = d
             break
         if target is None:
-            adm = self.tables[order[0]].record_reject(tid, last_reason)
+            table = self.tables[order[0]]
+            adm = table._reject(table._lanes[i], last_reason)
             adm.device = order[0]
             return adm
+        table = self.tables[target]
         bucket = self._buckets[tid]
         if bucket is not None:
             with self._lock:
                 ok = bucket.try_take(1)
             if not ok:
-                adm = self.tables[target].record_reject(tid, "rate")
+                adm = table._reject(table._lanes[i], "rate")
                 adm.device = target
                 return adm
-        adm = self.tables[target].admit(
-            tenant, row, deadline_at, cancel_scope
-        )
+        adm = table._admit(table._lanes[i], now, deadline_at,
+                           cancel_scope, True, row, fn, out)
         adm.device = target
         return adm
 

@@ -16,6 +16,9 @@ egress-off build lowers to the exact text an env-free build lowers to,
 even with the egress env knobs set."""
 
 import os
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -96,6 +99,165 @@ def test_double_resolution_is_impossible():
         g.result()
     cons = ft.conservation()
     assert cons["ok"] and cons["resolved"] == 1 and cons["expired"] == 1
+
+
+# ---- the lazy Event (ISSUE 34): a future pays for a waiter only when
+# one comes
+
+
+def test_a_future_builds_an_event_only_for_a_waiter_who_finds_it_pending():
+    """``waited`` is the futures waited on while PENDING, each once; a
+    future resolved before anyone waits never builds an Event, and a
+    timed wait on a pending one returns False in time."""
+    ft = FutureTable(backoff_s=0.002)
+    futs = [ft.create("a", BUMP, i) for i in range(40)]
+    assert all(f._event is None for f in futs)
+    assert ft.stats_dict()["waited"] == 0
+    for f in futs[:7]:
+        t0 = time.monotonic()
+        assert f.wait(0.01) is False
+        assert time.monotonic() - t0 < 1.0
+        assert f.wait(0.0) is False  # a second wait: the same Event
+    assert ft.stats_dict()["waited"] == 7
+    assert "waited" not in ft.conservation()  # telemetry, not the ledger
+    for i, f in enumerate(futs):
+        ft.resolve(f.token, 3 * i + 1)
+    for i, f in enumerate(futs):
+        assert f.wait() and f.wait(0.0) and f.result() == 3 * i + 1
+    assert [f._event is not None for f in futs] == [True] * 7 + [False] * 33
+    assert all(f._event.is_set() for f in futs[:7])
+    d = ft.stats_dict()
+    assert d["waited"] == 7 and d["ok"] and d["resolved"] == 40
+
+
+def test_waiters_before_during_and_after_resolution_all_wake():
+    """Some hundreds of futures, eighteen client threads (more than
+    cores) that start waiting before the resolver, beside it and after
+    it is done, under a short switch interval: no result() hangs, every
+    value is right, and every Event that was built was built for a
+    waiter (``waited`` counts them all, and no more than the futures
+    there are)."""
+    ft = FutureTable(backoff_s=0.001)
+    n = 360
+    futs = [ft.create("a", BUMP, 0) for _ in range(n)]
+    got = {}
+    errors = []
+
+    def client(k, delay):
+        try:
+            time.sleep(delay)
+            mine = futs[k::18]
+            if k % 2:  # odd clients walk theirs backwards
+                mine = mine[::-1]
+            for f in mine:
+                got[f.token] = f.result(timeout=20.0)
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    def resolver():
+        time.sleep(0.02)
+        for i, f in enumerate(futs):
+            ft.resolve(f.token, 3 * f.token + 1)
+            if i % 60 == 59:
+                time.sleep(0.005)
+
+    # clients 0-5 wait first, 6-11 start beside the resolver, 12-17
+    # only after every future is terminal
+    delays = [0.0] * 6 + [0.02 + 0.004 * i for i in range(6)] + [0.0] * 6
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        early = [threading.Thread(target=client, args=(k, delays[k]))
+                 for k in range(12)]
+        res = threading.Thread(target=resolver)
+        for t in early:
+            t.start()
+        res.start()
+        res.join(30.0)
+        assert not res.is_alive()
+        waited_then = ft.stats_dict()["waited"]
+        late = [threading.Thread(target=client, args=(k, 0.0))
+                for k in range(12, 18)]
+        for t in late:
+            t.start()
+        for t in early + late:
+            t.join(30.0)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors
+    assert got == {f.token: 3 * f.token + 1 for f in futs}
+    d = ft.stats_dict()
+    # the late clients found every future terminal: not one Event more
+    assert d["waited"] == waited_then
+    assert 1 <= d["waited"] <= 12 * (n // 18)
+    assert d["waited"] == sum(f._event is not None for f in futs)
+    assert all(f._event is None for k in range(12, 18) for f in futs[k::18])
+    assert d["ok"] and d["resolved"] == n and d["pending"] == 0
+
+
+@pytest.mark.parametrize(
+    "how", ["resolve", "expire", "poison", "poison_all", "preempt_all",
+            "reattach"])
+def test_every_terminal_transition_wakes_a_waiter_with_its_rung(how):
+    """A client blocked in result() on a PENDING future (so its Event
+    exists) is woken by each way a future ends, with that way's typed
+    answer; ``reattach`` hands out a future that is waited on, and woken,
+    like any other."""
+    ft = FutureTable(backoff_s=0.5)  # a lost wake-up would cost 0.5 s
+    f = ft.create("a", BUMP, 2)
+    if how == "reattach":
+        (rt,) = ft.preempt_all()
+        with pytest.raises(FuturePreempted):
+            f.result(timeout=1.0)
+        assert f._event is None  # terminal before anyone waited
+        f = ft.reattach(rt)
+        assert f.state == "PENDING" and f._event is None
+    out = []
+
+    def client():
+        try:
+            out.append(("value", f.result(timeout=20.0)))
+        except Exception as e:  # noqa: BLE001 - the rung under test
+            out.append(("raised", e))
+
+    t = threading.Thread(target=client)
+    t.start()
+    deadline = time.monotonic() + 10.0
+    while f._event is None and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert f._event is not None and ft.stats_dict()["waited"] == 1
+    t0 = time.monotonic()
+    if how in ("resolve", "reattach"):
+        ft.resolve(f.token, 99)
+    elif how == "expire":
+        ft.expire(f.token, "deadline")
+    elif how == "poison":
+        ft.poison(f.token, "quarantined")
+    elif how == "poison_all":
+        assert ft.poison_all("aborted") == 1
+    else:
+        assert len(ft.preempt_all()) == 1
+    t.join(10.0)
+    assert not t.is_alive()
+    (kind, what), = out
+    want = {"resolve": 99, "reattach": 99, "expire": FutureExpired,
+            "poison": FuturePoisoned, "poison_all": FuturePoisoned,
+            "preempt_all": FuturePreempted}[how]
+    if kind == "value":
+        assert what == want
+    else:
+        assert type(what) is want
+    assert f.done() and f.t_done is not None and f.t_done >= f.t_submit
+    if how == "reattach":
+        # The early rung: a token that ends before its client comes back
+        # yields a future that is born terminal and never builds one.
+        g = ft.create("a", BUMP, 3)
+        (rt,) = ft.preempt_all()
+        ft.resolve(g.token, 5)
+        h = ft.reattach(rt)
+        assert h.result(timeout=1.0) == 5 and h._event is None
+    assert ft.stats_dict()["waited"] == 1
 
 
 def test_cancelled_scope_futures_poison_not_hang():
@@ -308,6 +470,9 @@ def test_stream_serve_futures_resolve_with_parking():
     cons = table.futures.conservation()
     assert cons["ok"] and cons["resolved"] == 12, cons
     assert sm.stats_dict()["egress"]["resolved"] == 12
+    # The client came after the stream: no future ever built an Event.
+    assert sm.stats_dict()["egress"]["waited"] == 0
+    assert all(f._event is None for f in futs)
 
 
 def test_stream_quiesce_preempts_then_reattaches_across_resume():

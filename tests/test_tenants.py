@@ -8,6 +8,8 @@ submission sequence. Device tests drive the real interpret-mode
 streaming kernel: exact per-tenant totals, isolation under a poisoned +
 greedy mix, and quiesce -> resume -> reshard conservation."""
 
+import gc
+import threading
 import time
 
 import numpy as np
@@ -15,11 +17,22 @@ import pytest
 from conftest import bump_mk, seed_builder
 
 from hclib_tpu.device.descriptor import (
+    F_A0,
+    F_DEP,
+    F_FN,
+    F_HOME,
+    F_OUT,
+    F_SUCC0,
+    F_SUCC1,
+    NO_TASK,
     RING_ROW,
+    TEN_DEADLINE_MS,
     TEN_EXPIRED,
     TEN_ID,
+    TEN_TOKEN,
     TaskGraphBuilder,
 )
+from hclib_tpu.device.egress import EgressSpec
 from hclib_tpu.device.inject import StreamingMegakernel
 from hclib_tpu.device.tenants import (
     ADMIT_ACCEPTED,
@@ -30,6 +43,7 @@ from hclib_tpu.device.tenants import (
     TC_PAUSE,
     TC_TAIL,
     TC_WEIGHT,
+    MeshTenantTable,
     TenantSpec,
     TenantTable,
     TokenBucket,
@@ -579,6 +593,265 @@ def test_submit_wait_timeout_is_wall_clock_bounded():
     adm = sm.submit("a", BUMP, args=[2], wait=True, wait_timeout_s=0.3)
     assert adm.rejected and adm.reason == "rate"
     assert time.monotonic() - t0 < 5.0
+
+
+# ---- one-pass admission (ISSUE 34): the row, the gates, who owns a row
+
+
+def _oracle_row(fn, args, out, succ0, succ1, lane_idx, token):
+    """The published row as the parent (6b75638) constructed it:
+    ``build_row``'s body, then ``admit``'s copy and stamps. Kept here
+    word for word as the oracle of the one-pass construction."""
+    row = np.zeros(RING_ROW, np.int32)
+    row[F_FN] = int(fn)
+    row[F_DEP] = 0
+    row[F_SUCC0] = int(succ0)
+    row[F_SUCC1] = int(succ1)
+    for i, a in enumerate(args):
+        row[F_A0 + i] = int(a)
+    row[F_OUT] = int(out)
+    row[F_HOME] = NO_TASK
+    r = np.array(row, np.int32).reshape(RING_ROW)
+    r[TEN_ID] = lane_idx
+    r[TEN_EXPIRED] = 0
+    r[TEN_DEADLINE_MS] = 0
+    r[TEN_TOKEN] = token
+    return r
+
+
+_ROSTER = [("gold", 4), ("silver", 2), ("bronze", 1)]  # serve-3tenant's
+
+
+def _front(kind, egress):
+    """(submit callable, pump-able table) of each face of the front
+    door that builds a request's row itself."""
+    specs = [TenantSpec(t, weight=w) for t, w in _ROSTER]
+    eg = EgressSpec(depth=8) if egress else False
+    if kind == "mesh":
+        mesh = MeshTenantTable(specs, 1, 16, egress=eg)
+        return mesh.submit, mesh.tables[0]
+    table = TenantTable(specs, 16, egress=eg)
+    if kind == "table":
+        return table.submit, table
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=3 * 16,
+                             tenants=table)
+    return sm.submit, table
+
+
+@pytest.mark.parametrize("by", ["id", "index"])
+@pytest.mark.parametrize("kind", ["stream", "table", "mesh"])
+@pytest.mark.parametrize("nargs", range(7))
+def test_submit_publishes_the_parents_row_word_for_word(nargs, kind, by):
+    """0-6 arguments, an out slot, successor words, a tenant named by id
+    and by index, with a token and without: what ``pump`` publishes is
+    the parent's ``build_row`` + stamp, every one of the 256 words."""
+    for egress in (True, False):
+        submit, table = _front(kind, egress)
+        want = {i: [] for i in range(len(_ROSTER))}
+        token = 0
+        for lane_idx, (tid, _) in enumerate(_ROSTER):
+            for k, (out, s0, s1) in enumerate(
+                [(0, NO_TASK, NO_TASK), (3, 5, NO_TASK), (1, 2, 7)]
+            ):
+                args = [1000 * lane_idx + 10 * k + j - 3
+                        for j in range(nargs)]
+                adm = submit(lane_idx if by == "index" else tid, BUMP,
+                             args=args, out=out, succ0=s0, succ1=s1)
+                assert adm.accepted and adm.tenant == tid
+                assert adm.index == k
+                token += 1
+                if egress:
+                    assert adm.future.token == token
+                    assert (adm.future.fn, adm.future.slot) == (BUMP, out)
+                else:
+                    assert adm.future is None
+                want[lane_idx].append(_oracle_row(
+                    BUMP, args, out, s0, s1, lane_idx,
+                    token if egress else 0,
+                ))
+        ring = np.zeros((3 * 16, RING_ROW), np.int32)
+        table.pump(ring)
+        for lane_idx, rows in want.items():
+            got = ring[lane_idx * 16: lane_idx * 16 + len(rows)]
+            np.testing.assert_array_equal(got, np.stack(rows))
+            assert not ring[lane_idx * 16 + len(rows)].any()
+    with pytest.raises(ValueError, match="at most 6 args"):
+        submit("gold", BUMP, args=list(range(7)))
+    with pytest.raises(KeyError):
+        submit("nobody", BUMP)
+
+
+_GATES = ["quarantined", "cancelled", "expired", "closed", "ring",
+          "backlog", "rate"]
+
+
+def _gated(first, via):
+    """A one-lane front door on which gate ``first`` and EVERY later
+    gate of the documented order would refuse, and no earlier one; the
+    call that tries it. Returns (call, table, lane)."""
+    clock = FakeClock()
+    k = _GATES.index(first)
+    table = TenantTable(
+        [TenantSpec("a", rate=1.0, burst=2.0,
+                    queue_capacity=2 if k <= 5 else 8)], 8,
+        clock=clock,
+    )
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=8, tenants=table)
+    for i in range(2):  # the bucket is dry now, and the backlog full
+        assert table.submit("a", BUMP, args=[i])
+    lane = table._lanes[0]
+    if k <= 4:
+        lane.published = 6  # 6 published + 2 queued fill the region
+    if k <= 3:
+        table._closed = True
+    scope = CancelScope()
+    if k <= 1:
+        scope.cancel("test")
+    if k <= 0:
+        lane.quarantined = "test"
+    late = k <= 2
+    if via == "admit":
+        def call(**kw):
+            return table.admit(
+                "a", _row(), cancel_scope=scope,
+                deadline_at=clock() - 1.0 if late else None, **kw)
+    else:
+        def call(**kw):
+            return (sm if via == "stream" else table).submit(
+                "a", BUMP, args=[9], cancel_scope=scope,
+                deadline_s=-1.0 if late else None, **kw)
+    return call, table
+
+
+@pytest.mark.parametrize("via", ["stream", "table", "admit"])
+@pytest.mark.parametrize("first", _GATES)
+def test_gates_refuse_in_the_documented_order(first, via):
+    """quarantined, cancelled, expired, closed, ring, backlog, rate:
+    with every later gate shut too, the earliest names the rejection,
+    it is counted once, and nothing is admitted."""
+    call, table = _gated(first, via)
+    adm = call()
+    assert adm.rejected and adm.reason == first and adm.future is None
+    assert adm.tenant == "a" and not adm
+    s = table.stats()["a"]
+    assert s["rejected"] == 1 and s["accepted"] == 2 and s["queued"] == 2
+    if via == "admit":  # a wait loop's probe is not a rejection yet
+        assert call(record_reject=False).reason == first
+        assert table.stats()["a"]["rejected"] == 1
+
+
+@pytest.mark.parametrize("via", ["stream", "table", "admit"])
+@pytest.mark.parametrize("full", ["ring", "backlog"])
+def test_rate_token_is_kept_when_a_cheaper_gate_refused(full, via):
+    clock = FakeClock()
+    table = TenantTable(
+        [TenantSpec("a", rate=1.0, burst=8.0, queue_capacity=2)], 8,
+        clock=clock,
+    )
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=8, tenants=table)
+    for i in range(2):
+        assert table.submit("a", BUMP, args=[i])
+    lane = table._lanes[0]
+    if full == "ring":
+        lane.published = 6
+    tokens = lane.bucket._tokens
+    assert tokens == 6.0
+    for _ in range(3):
+        if via == "admit":
+            adm = table.admit("a", _row())
+        else:
+            adm = (sm if via == "stream" else table).submit("a", BUMP)
+        assert adm.rejected and adm.reason == full
+    assert lane.bucket._tokens == tokens
+    assert table.stats()["a"]["rejected"] == 3
+
+
+@pytest.mark.parametrize("via", ["admit", "submit_row"])
+def test_a_callers_row_is_copied_at_admission(via):
+    """``admit`` / ``submit_row`` take a row the CALLER owns: what the
+    caller does to it afterwards does not reach the ring."""
+    specs = [TenantSpec("a"), TenantSpec("b")]
+    if via == "admit":
+        table = TenantTable(specs, 8, egress=EgressSpec(depth=4))
+        admit = table.admit
+    else:
+        mesh = MeshTenantTable(specs, 1, 8, egress=EgressSpec(depth=4))
+        table, admit = mesh.tables[0], mesh.submit_row
+    row = build_row(BUMP, [11, 22], out=2, succ0=4)
+    row[TEN_EXPIRED] = 1        # transport words of an older life
+    row[TEN_DEADLINE_MS] = 77
+    keep = row.copy()
+    adm = admit("b", row)
+    assert adm.accepted and adm.future.token == 1
+    assert (adm.future.fn, adm.future.slot) == (BUMP, 2)
+    np.testing.assert_array_equal(row, keep)  # the caller's is untouched
+    row[:] = -5
+    ring = np.zeros((16, RING_ROW), np.int32)
+    table.pump(ring)
+    np.testing.assert_array_equal(
+        ring[8], _oracle_row(BUMP, [11, 22], 2, 4, NO_TASK, 1, 1))
+
+
+@pytest.mark.parametrize("reason", ["rate", "backlog"])
+def test_submit_wait_true_turns_a_transient_refusal_into_a_wait(reason):
+    """``wait=True``: a dry bucket waits for its refill, a full backlog
+    for the pump; the probes that were refused meanwhile are not counted
+    as rejections, and the wait is bounded."""
+    spec = (TenantSpec("a", rate=20.0, burst=1.0) if reason == "rate"
+            else TenantSpec("a", queue_capacity=1))
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=8, tenants=[spec])
+    table = sm.tenants
+    assert sm.submit("a", BUMP, args=[1]).accepted
+    refused = sm.submit("a", BUMP, args=[2])
+    assert refused.rejected and refused.reason == reason
+    pumper = None
+    if reason == "backlog":
+        ring = np.zeros((8, RING_ROW), np.int32)
+        pumper = threading.Timer(0.05, table.pump, args=(ring,))
+        pumper.start()
+    t0 = time.monotonic()
+    adm = sm.submit("a", BUMP, args=[3], wait=True, wait_timeout_s=5.0)
+    waited = time.monotonic() - t0
+    if pumper is not None:
+        pumper.join(5.0)
+        assert not pumper.is_alive()
+    assert adm.accepted and adm.index == 1
+    assert 0.001 < waited < 2.0
+    s = table.stats()["a"]
+    assert s["accepted"] == 2 and s["rejected"] == 1  # only the plain one
+
+
+def test_submit_allocates_a_third_of_the_parents_objects():
+    """A count, never a time: the collector's young-generation counter
+    (tracked allocations less deallocations; it only rises while the
+    collector is off) over 1,000 ``submit()``s on serve-3tenant's
+    roster, futures and verdicts kept. The parent (6b75638) read 9,007:
+    Future, Event, its dict, Condition, its dict, two bound lock
+    methods, the waiter deque, _Pending, Admission, and more. A future
+    without an Event leaves Future, _Pending, Admission."""
+    specs = [TenantSpec(t, weight=w) for t, w in _ROSTER]
+    table = TenantTable(specs, 1024, egress=EgressSpec(depth=64))
+    sm = StreamingMegakernel(bump_mk(), ring_capacity=3 * 1024,
+                             tenants=table)
+    kept = [None] * 1000
+    was_on = gc.isenabled()
+    gc.collect()
+    gc.disable()
+    try:
+        before = gc.get_count()[0]
+        for i in range(1000):
+            kept[i] = sm.submit(_ROSTER[i % 3][0], BUMP, args=[i], out=1)
+        rise = gc.get_count()[0] - before
+    finally:
+        if was_on:
+            gc.enable()
+    assert all(a.accepted for a in kept)
+    assert rise <= 9007 // 2, rise
+    assert 2500 <= rise, rise  # the count counts: three objects a request
+    assert all(a.future._event is None for a in kept)
+    assert not any(isinstance(o, threading.Event)
+                   for a in kept for o in gc.get_referents(a.future))
+    assert sm.stats_dict()["egress"]["waited"] == 0
 
 
 # -------------------------------------------------------------- device
