@@ -150,11 +150,12 @@ def phase_cholesky(dev: dict, seed: int) -> None:
          run_s=run_s, **ran_compiled(info))
 
 
-def sw_scores_numpy(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Best local-alignment score of each pair a[k] (n) vs b[k] (m), by
-    the row recurrence in plain numpy - independent of every engine under
-    test. The in-row gap chain h[j] = max(t[j], h[j-1] - GAP) is solved
-    with a running maximum of t[j] + j*GAP."""
+def sw_numpy(a: np.ndarray, b: np.ndarray):
+    """``(best, last_row, last_col)`` of each pair a[k] (n) vs b[k] (m):
+    the best local-alignment score (B), H's last row (B, m) and H's last
+    column (B, n), by the row recurrence in plain numpy - independent of
+    every engine under test. The in-row gap chain h[j] = max(t[j],
+    h[j-1] - GAP) is solved with a running maximum of t[j] + j*GAP."""
     from hclib_tpu.models.smithwaterman import GAP, MATCH, MISMATCH
 
     B, m = b.shape
@@ -162,13 +163,15 @@ def sw_scores_numpy(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     prev = np.zeros((B, m), np.int32)
     diag = np.zeros((B, m), np.int32)
     best = np.zeros(B, np.int32)
+    last_col = np.zeros(a.shape, np.int32)
     for i in range(a.shape[1]):
         s = np.where(b == a[:, i:i + 1], MATCH, MISMATCH).astype(np.int32)
         diag[:, 1:] = prev[:, :-1]
         t = np.maximum(np.maximum(diag + s, prev - GAP), 0)
         prev = np.maximum.accumulate(t + ramp, axis=1) - ramp
+        last_col[:, i] = prev[:, -1]
         np.maximum(best, prev.max(axis=1), out=best)
-    return best
+    return best, prev, last_col
 
 
 def phase_sw(dev: dict, seed: int) -> None:
@@ -184,7 +187,7 @@ def phase_sw(dev: dict, seed: int) -> None:
     got, compile_s, run_s = twice(
         lambda: sw_scores_pallas(A, Bs, interpret=False)
     )
-    want = sw_scores_numpy(A, Bs)
+    want = sw_numpy(A, Bs)[0]
     assert got.shape == (B,) and np.array_equal(got, want), (
         int((got != want).sum()), "pairs differ"
     )
@@ -201,8 +204,12 @@ def phase_sw(dev: dict, seed: int) -> None:
     (score, _, info), compile_s, run_s = twice(
         lambda: device_sw_wave(a, b, interpret=False, with_h=False)
     )
-    want = int(sw_scores_numpy(a[None], b[None])[0])
+    want, row, col = (x[0] for x in sw_numpy(a[None], b[None]))
     assert score == want, (score, want)
+    # What the benchmark cell sw-wave-8192 holds too: every tile feeds
+    # H's last row and column, the score only the best path's.
+    assert np.array_equal(info["last_row"], row), "last row differs"
+    assert np.array_equal(info["last_col"], col), "last column differs"
     emit("sw", dev, engine="wave-dag", n=n, m=m, score=score,
          tasks=info["executed"],
          batch_occupancy=round(info["tiers"]["batch_occupancy"], 3),

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.profiler import TraceAnnotation
 
 from ..models.smithwaterman import GAP, MATCH, MISMATCH
 from .descriptor import TaskGraphBuilder
@@ -558,6 +559,28 @@ def sw_wave_buffers(a: np.ndarray, b: np.ndarray) -> dict:
     }
 
 
+def _sw_result(n: int, m: int, ivalues, out: dict, info: dict, dt: float):
+    """The tail the three engines share: ``(score, H or None, info)`` from
+    what ``mk.run`` brought back. Besides the run's own keys ``info`` holds
+    ``last_row`` (H[n-1, :], ``m`` values: the bottom rows the last tile
+    row published to ``bot``) and ``last_col`` (H[:, m-1], ``n`` values:
+    the right columns the last tile column published to ``right``): every
+    tile feeds them through the recurrence, so with ``with_h=False`` a
+    caller still gets more than one integer to check."""
+    with TraceAnnotation("bench:sw.readback"):
+        h = (
+            np.asarray(out["htiles"]).swapaxes(1, 2).reshape(n, m)
+            if "htiles" in out
+            else None
+        )
+        info = dict(info)
+        info["seconds"] = dt
+        info["cells_per_sec"] = n * m / dt
+        info["last_row"] = np.asarray(out["bot"])[n // T - 1].reshape(m)
+        info["last_col"] = np.asarray(out["right"])[:, m // T - 1].reshape(n)
+        return int(ivalues[0]), h, info
+
+
 def device_sw_wave(
     a: np.ndarray,
     b: np.ndarray,
@@ -568,30 +591,28 @@ def device_sw_wave(
     """Tiled SW where each task is a WAVE CHUNK (up to WAVE_R tiles of one
     anti-diagonal batched over VPU sublanes); dependencies chain
     anti-diagonals. Same results as device_sw, ~WAVE_R x the vector-unit
-    utilization once diagonals are wide."""
+    utilization once diagonals are wide.
+
+    Four profiler spans split the call for a traced run: ``bench:sw.build``
+    (the graph), ``bench:sw.stage`` (the host buffers), ``bench:sw.run``
+    (``Megakernel.run``) and ``bench:sw.readback`` (``_sw_result``)."""
     n, m = len(a), len(b)
     if n % T or m % T:
         raise ValueError(f"sequence lengths must be multiples of {T}")
     nt_i, nt_j = n // T, m // T
     if mk is None:
         mk = make_sw_wave_megakernel(nt_i, nt_j, interpret, with_h=with_h)
-    builder = build_sw_wave_graph(nt_i, nt_j)
-    i32 = np.int32
-    data = sw_wave_buffers(a, b)
-    if "htiles" in mk.data_specs:
-        data["htiles"] = np.zeros((nt_i, nt_j, T, T), i32)
+    with TraceAnnotation("bench:sw.build"):
+        builder = build_sw_wave_graph(nt_i, nt_j)
+    with TraceAnnotation("bench:sw.stage"):
+        data = sw_wave_buffers(a, b)
+        if "htiles" in mk.data_specs:
+            data["htiles"] = np.zeros((nt_i, nt_j, T, T), np.int32)
     t0 = time.perf_counter()
-    ivalues, out, info = mk.run(builder, data=data)
+    with TraceAnnotation("bench:sw.run"):
+        ivalues, out, info = mk.run(builder, data=data)
     dt = time.perf_counter() - t0
-    h = (
-        np.asarray(out["htiles"]).swapaxes(1, 2).reshape(n, m)
-        if "htiles" in out
-        else None
-    )
-    info = dict(info)
-    info["seconds"] = dt
-    info["cells_per_sec"] = n * m / dt
-    return int(ivalues[0]), h, info
+    return _sw_result(n, m, ivalues, out, info, dt)
 
 
 def device_sw_batched(
@@ -623,15 +644,7 @@ def device_sw_batched(
     t0 = time.perf_counter()
     ivalues, out, info = mk.run(builder, data=data)
     dt = time.perf_counter() - t0
-    h = (
-        np.asarray(out["htiles"]).swapaxes(1, 2).reshape(n, m)
-        if "htiles" in out
-        else None
-    )
-    info = dict(info)
-    info["seconds"] = dt
-    info["cells_per_sec"] = n * m / dt
-    return int(ivalues[0]), h, info
+    return _sw_result(n, m, ivalues, out, info, dt)
 
 
 def make_sw_megakernel(
@@ -709,12 +722,4 @@ def device_sw(
     t0 = time.perf_counter()
     ivalues, out, info = mk.run(builder, data=data)
     dt = time.perf_counter() - t0
-    h = (
-        np.asarray(out["htiles"]).swapaxes(1, 2).reshape(n, m)
-        if "htiles" in out
-        else None
-    )
-    info = dict(info)
-    info["seconds"] = dt
-    info["cells_per_sec"] = n * m / dt
-    return int(ivalues[0]), h, info
+    return _sw_result(n, m, ivalues, out, info, dt)
