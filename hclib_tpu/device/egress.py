@@ -62,7 +62,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -384,10 +384,16 @@ class FutureTable:
     ladder, and certifies conservation.
 
     Exactly-once is structural: a token is live exactly until its ONE
-    terminal transition; a second ``resolve``/``expire``/``poison`` of
-    the same token raises :class:`EgressProtocolError` (the mailbox
-    cursor consumes each row once, so in correct operation this never
-    fires - the tests force it to prove it would).
+    terminal transition, and every transition - ``resolve`` /
+    ``expire`` / ``poison`` one token at a time, ``resolve_many`` a
+    whole entry boundary's rows under one taking of the lock - goes
+    through the one routine ``_settle``. A second transition of the
+    same token, or one of a token this table never held, raises
+    :class:`EgressProtocolError` (the mailbox cursor consumes each row
+    once, so in correct operation this never fires - the tests force
+    it to prove it would). A batch settles in row order: the rows
+    before an offender are terminal and counted when the error
+    surfaces, the offender and the rows after it are untouched.
 
     The table's lock is also what makes a future's lazy Event safe: a
     terminal transition finishes the future under it, and the first
@@ -459,16 +465,23 @@ class FutureTable:
 
     # -- terminal transitions (driver side) --
 
-    def _take(self, token: int, what: str):
-        """Pop a pending token (live future OR unattached adoption); a
-        token already terminal or never issued is a protocol violation."""
-        token = int(token)
+    def _settle(self, token: int, what: str, state: str, value=None,
+                reason=None) -> None:
+        """THE place a token becomes terminal (the lock is held; ``token``
+        is a Python int): a live future finishes - its value stored, its
+        own clock reading taken, then its state flipped and its waiter
+        woken - and an unattached adoption lands in ``_early`` for the
+        ``reattach`` that follows. A token already terminal or never
+        issued is a protocol violation and changes nothing."""
         fut = self._live.pop(token, None)
         if fut is not None:
-            return fut, None
-        meta = self._unattached.pop(token, None)
-        if meta is not None:
-            return None, meta
+            self._terminal[token] = state
+            fut._finish(state, value=value, reason=reason)
+            return
+        if self._unattached.pop(token, None) is not None:
+            self._terminal[token] = state
+            self._early[token] = (state, value, reason)
+            return
         if token in self._terminal:
             raise EgressProtocolError(
                 f"double resolution: token {token} already "
@@ -480,32 +493,41 @@ class FutureTable:
             "or adopted it"
         )
 
-    def _terminate(self, token: int, what: str, state: str, value=None,
-                   reason=None, resume_token=None) -> None:
-        with self._lock:
-            fut, meta = self._take(token, what)
-            self._terminal[int(token)] = state
-            if fut is not None:
-                fut._finish(state, value=value, reason=reason,
-                            resume_token=resume_token)
-            else:
-                self._early[int(token)] = (state, value, reason)
-            if state == RESULT:
-                self.resolved += 1
-            elif state == EXPIRED:
-                self.expired += 1
-            elif state == POISONED:
-                self.poisoned += 1
-
     def resolve(self, token: int, value: int) -> None:
         """A mailbox row for ``token`` was consumed: terminal RESULT."""
-        self._terminate(token, "resolve", RESULT, value=int(value))
+        with self._lock:
+            self._settle(int(token), "resolve", RESULT, int(value))
+            self.resolved += 1
+
+    def resolve_many(self, tokens: Sequence[int],
+                     values: Sequence[int]) -> int:
+        """One entry boundary's mailbox rows: what a loop of ``resolve``
+        over ``zip(tokens, values)`` does, under ONE taking of the lock
+        (both are Python ints, ``ndarray.tolist()``'s). Row order; every
+        future still reads the clock for itself as it finishes, so none
+        is dated before its value was stored. At an offender (a token
+        resolved twice, or never issued) the rows before it are terminal
+        and counted, and the error surfaces. Returns rows resolved."""
+        n = 0
+        settle = self._settle
+        with self._lock:
+            try:
+                for token, value in zip(tokens, values):
+                    settle(token, "resolve", RESULT, value)
+                    n += 1
+            finally:
+                self.resolved += n
+        return n
 
     def expire(self, token: int, reason: str = "deadline") -> None:
-        self._terminate(token, "expire", EXPIRED, reason=reason)
+        with self._lock:
+            self._settle(int(token), "expire", EXPIRED, reason=reason)
+            self.expired += 1
 
     def poison(self, token: int, reason: str = "quarantined") -> None:
-        self._terminate(token, "poison", POISONED, reason=reason)
+        with self._lock:
+            self._settle(int(token), "poison", POISONED, reason=reason)
+            self.poisoned += 1
 
     def poison_all(self, reason: str = "stream aborted") -> int:
         """The abort rung: every pending token - live futures AND

@@ -68,8 +68,16 @@ int32 slab and what it reads (counts, their echoes, the trace row)
 comes back as one: ``_build_entry`` wraps the kernel in the jit that
 splits and joins them, ``_entry_layout`` names the blocks. The loop
 sleeps ``poll_interval_s`` only when an entry left it nothing to do.
-``info['stream']`` / ``stats_dict()['stream']`` count entries, host-link
-calls each way, ring uploads and idle sleeps.
+What the host does at a boundary it does once an entry over arrays, not
+once a request over objects: the mailbox and the park ring settle
+through ONE call of the ledger (``_drain_egress`` ->
+``FutureTable.resolve_many``), the lane cursors' advance retires its
+rows in one pass (``TenantTable.absorb``) and a backlog goes to the ring
+by one store (``TenantTable.pump``); the two halves run under the spans
+``bench:stream.pump`` (up to the entry) and ``bench:stream.settle``
+(after it). ``info['stream']`` / ``stats_dict()['stream']`` count
+entries, host-link calls each way, ring uploads, idle sleeps, and the
+rows settled in how many bulk calls.
 """
 
 from __future__ import annotations
@@ -84,6 +92,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.profiler import TraceAnnotation
 
 from ..runtime import resilience
 from ..runtime.resilience import CancelledError, StallError
@@ -145,7 +154,7 @@ from .telemetry import (
     TG_PARKED,
     TG_RETIRES,
     TG_ROUNDS,
-    unpack_spans,
+    unpack_spans_rows,
 )
 from .tenants import (
     TC_CONSUMED,
@@ -318,10 +327,12 @@ class StreamingMegakernel:
             "last_quiesce_latency_s": None,
             # The entry boundary of the newest run_stream (ISSUE 32):
             # entries, host-link calls each way, ring uploads, idle
-            # polls slept - info["stream"] at the exits is this block.
+            # polls slept, and the mailbox rows the boundaries settled
+            # (``settled``, through ``settle_batches`` bulk calls of
+            # the ledger) - info["stream"] at the exits is this block.
             "stream": dict.fromkeys(
                 ("entries", "uploads", "downloads", "ring_uploads",
-                 "idle_sleeps"), 0,
+                 "idle_sleeps", "settled", "settle_batches"), 0,
             ),
         }
 
@@ -1239,8 +1250,10 @@ class StreamingMegakernel:
         not move because it waits on rows nobody has injected yet); with
         a backlog it goes straight to the next pump. ``info['stream']``
         (also ``stats_dict()['stream']``, live) counts ``entries``,
-        ``uploads`` / ``downloads`` (host-link calls), ``ring_uploads``
-        and ``idle_sleeps``.
+        ``uploads`` / ``downloads`` (host-link calls), ``ring_uploads``,
+        ``idle_sleeps``, and ``settled`` / ``settle_batches``: the
+        mailbox rows resolved at the boundaries and the bulk calls of
+        the ledger that resolved them (one an entry that had any).
 
         Resilience: ``deadline_s`` bounds the whole stream - past it the
         ring is closed and a structured ``StallError`` raises instead of
@@ -1301,49 +1314,56 @@ class StreamingMegakernel:
     def _drain_egress(table, egr, park, ectl, spans=None) -> int:
         """Consume the completion mailbox AND the park ring at an entry
         boundary (this driver IS the poller), resolving each row's
-        future exactly once. Mutates the arrays in place: consumed
-        mailbox slots re-zero and EC_CONSUMED catches up to EC_WRITE;
-        parked rows resolve directly (they never occupied a mailbox
-        slot) and the park ring empties. Draining both regions here is
-        what makes a full mailbox unable to wedge quiesce or the
-        drained exit. ``spans`` (telemetry builds): a dict collecting
-        ``token -> (admit, install, fire)`` absolute rounds decoded off
-        the EGR span words. Returns rows consumed."""
-        futures = table.futures
+        future exactly once. The boundary settles in bulk: the consumed
+        mailbox slots (``EC_CONSUMED .. EC_WRITE``, modulo the depth)
+        and the counted park slots are taken as index arrays, their
+        status words checked at once, their tokens and values handed to
+        the ledger in ONE call (``FutureTable.resolve_many``: one lock,
+        row order, mailbox before park), and the slots re-zeroed by one
+        indexed store each. Mutates the arrays in place: EC_CONSUMED
+        catches up to EC_WRITE; parked rows resolve directly (they
+        never occupied a mailbox slot) and the park ring empties.
+        Draining both regions here is what makes a full mailbox unable
+        to wedge quiesce or the drained exit. ``spans`` (telemetry
+        builds): a dict collecting ``token -> (admit, install, fire)``
+        absolute rounds decoded off the EGR span words.
 
-        def _one(row):
-            if spans is not None:
-                spans[int(row[EGR_TOKEN])] = unpack_spans(
-                    row[EGR_T_ADMIT], row[EGR_T_SPANS]
-                )[:3]
-            futures.resolve(int(row[EGR_TOKEN]), int(row[EGR_VALUE]))
-            row[:] = 0
-
-        depth = egr.shape[0]
-        n = 0
-        consumed = int(ectl[EC_CONSUMED])
-        while consumed < int(ectl[EC_WRITE]):
-            row = egr[consumed % depth]
-            if int(row[EGR_STATUS]) != EGR_OK:
-                raise EgressProtocolError(
-                    f"mailbox slot {consumed % depth} consumed twice or "
-                    f"never published (status {int(row[EGR_STATUS])})"
-                )
-            _one(row)
-            consumed += 1
-            n += 1
-        ectl[EC_CONSUMED] = consumed
+        Errors surface in row order, as the row-at-a-time specification
+        (``egress.HostMailbox.drain``) gives them: a slot whose status
+        is not ``EGR_OK`` raises ``EgressProtocolError`` naming it with
+        the rows before it resolved and re-zeroed; a token the ledger
+        refuses raises out of ``resolve_many`` with the rows before it
+        resolved and nothing re-zeroed. The cursors move only on a clean
+        drain. Returns rows consumed."""
+        depth, cap = egr.shape[0], park.shape[0]
+        consumed, write = int(ectl[EC_CONSUMED]), int(ectl[EC_WRITE])
         head, cnt = int(ectl[EC_PARK_HEAD]), int(ectl[EC_PARK_COUNT])
-        cap = park.shape[0]
-        for k in range(cnt):
-            row = park[(head + k) % cap]
-            if int(row[EGR_STATUS]) != EGR_OK:
+        mslots = np.arange(consumed, write) % depth
+        pslots = (head + np.arange(cnt)) % cap
+        rows = np.concatenate([egr[mslots], park[pslots]])
+        bad = np.flatnonzero(rows[:, EGR_STATUS] != EGR_OK)
+        n = int(bad[0]) if bad.size else len(rows)
+        tokens = rows[:n, EGR_TOKEN].tolist()
+        table.futures.resolve_many(tokens, rows[:n, EGR_VALUE].tolist())
+        if spans is not None:
+            spans.update(zip(tokens, zip(*(
+                a.tolist() for a in unpack_spans_rows(
+                    rows[:n, EGR_T_ADMIT], rows[:n, EGR_T_SPANS])
+            ))))
+        egr[mslots[:n]] = 0
+        park[pslots[:max(0, n - len(mslots))]] = 0
+        if bad.size:
+            status = int(rows[n, EGR_STATUS])
+            if n < len(mslots):
                 raise EgressProtocolError(
-                    f"park slot {(head + k) % cap} empty but counted "
-                    f"(status {int(row[EGR_STATUS])})"
+                    f"mailbox slot {int(mslots[n])} consumed twice or "
+                    f"never published (status {status})"
                 )
-            _one(row)
-            n += 1
+            raise EgressProtocolError(
+                f"park slot {int(pslots[n - len(mslots)])} empty but "
+                f"counted (status {status})"
+            )
+        ectl[EC_CONSUMED] = write
         ectl[EC_PARK_HEAD] = 0
         ectl[EC_PARK_COUNT] = 0
         return n
@@ -1613,6 +1633,18 @@ class StreamingMegakernel:
             link["downloads"] += 1
             return jax.device_get([dev[n] for n in names])
 
+        def settle(egr, park, ectl) -> None:
+            """One boundary's mailbox and park ring through the ledger
+            in bulk, counted; the decoded spans land under the lock
+            once."""
+            sp = {} if self.telemetry else None
+            n = self._drain_egress(table, egr, park, ectl, spans=sp)
+            link["settled"] += n
+            link["settle_batches"] += n > 0
+            if sp:
+                with self._lock:
+                    self._spans.update(sp)
+
         ring_seen = 0
         # Flight recorder: each entry resets the ring, so the LAST entry's
         # records surface in info - bracketed by THAT entry's own epoch
@@ -1651,14 +1683,7 @@ class StreamingMegakernel:
                     # resolve RESULT; every other outstanding future
                     # poisons - clients get a typed terminal state, not
                     # a hang.
-                    sp = {} if self.telemetry else None
-                    self._drain_egress(
-                        table, got["egr"], got["park"], got["ectl"],
-                        spans=sp,
-                    )
-                    if sp:
-                        with self._lock:
-                            self._spans.update(sp)
+                    settle(got["egr"], got["park"], got["ectl"])
                     table.futures.poison_all(
                         f"stream aborted: {abort_reason}"
                     )
@@ -1683,93 +1708,88 @@ class StreamingMegakernel:
                     f"(injected={injected}, closed={closed})",
                     stats=self.stats_dict(),
                 )
-            for row in rows:
-                if injected >= self.ring_capacity:
-                    raise RuntimeError(
-                        f"injection ring exhausted ({self.ring_capacity} "
-                        "rows per stream)"
-                    )
-                ring[injected] = row
-                injected += 1
-            if rows:
-                dev["ring"] = ring
-            if table is not None:
-                # Tenant lanes: the pump expires/publishes the host
-                # backlogs into the per-lane ring regions and builds the
-                # tctl block this entry uploads; the plain tail is unused.
-                if self.telemetry:
-                    # Admit-round feedback: rows published by THIS pump
-                    # are stamped with the round gauge the last entry
-                    # echoed - ring-wait time is inside the measured
-                    # admission->retire span.
-                    table.set_admit_round(int(host["tele"][0, TG_ROUNDS]))
-                host["tctl"] = table.pump(ring)
-                if table.ring_writes != ring_seen:
-                    ring_seen = table.ring_writes
+            with TraceAnnotation("bench:stream.pump"):
+                for row in rows:
+                    if injected >= self.ring_capacity:
+                        raise RuntimeError(
+                            f"injection ring exhausted ({self.ring_capacity} "
+                            "rows per stream)"
+                        )
+                    ring[injected] = row
+                    injected += 1
+                if rows:
                     dev["ring"] = ring
-                injected = table.total_published()
-                ctl[0] = 0
-            ctl[1] = 1 if closed else 0
-            if quiesce_after is not None:
-                # Publish the quiesce word + threshold: the kernel
-                # observes it inside its round loop once the executed
-                # count passes the threshold and exits with its state.
-                ctl[5] = 1
-                ctl[6] = quiesce_after
-            if table is None:
-                ctl[0] = injected
+                if table is not None:
+                    # Tenant lanes: the pump expires/publishes the host
+                    # backlogs into the per-lane ring regions and builds the
+                    # tctl block this entry uploads; the plain tail is unused.
+                    if self.telemetry:
+                        # Admit-round feedback: rows published by THIS pump
+                        # are stamped with the round gauge the last entry
+                        # echoed - ring-wait time is inside the measured
+                        # admission->retire span.
+                        table.set_admit_round(int(host["tele"][0, TG_ROUNDS]))
+                    host["tctl"] = table.pump(ring)
+                    if table.ring_writes != ring_seen:
+                        ring_seen = table.ring_writes
+                        dev["ring"] = ring
+                    injected = table.total_published()
+                    ctl[0] = 0
+                ctl[1] = 1 if closed else 0
+                if quiesce_after is not None:
+                    # Publish the quiesce word + threshold: the kernel
+                    # observes it inside its round loop once the executed
+                    # count passes the threshold and exits with its state.
+                    ctl[5] = 1
+                    ctl[6] = quiesce_after
+                if table is None:
+                    ctl[0] = injected
             executed0 = int(counts_np[C_EXECUTED])
             entry_t0_ns = time.monotonic_ns()
             got = enter()
-            counts_np, ctl_o = got["counts"], got["ctl"]
-            if mk.trace is not None:
-                trace_row = got["trace"]
-                entry_t1_ns = time.monotonic_ns()
-            if table is not None:
-                # Fold the lane-cursor echo back: consume cursors advance
-                # (freeing in-flight budget), cumulative install/expire/
-                # sweep counters refresh, admission latencies record.
-                table.absorb(got["tctl"])
-            if egspec is not None:
-                # Drain the mailbox AND the park ring at the entry
-                # boundary, resolving futures - both always empty when
-                # the loop reaches the quiesce/drained-exit checks
-                # below, so a full mailbox can never wedge either.
-                for n in ("egr", "park", "ectl"):
-                    host[n] = got[n]
-                sp = {} if self.telemetry else None
-                self._drain_egress(
-                    table, host["egr"], host["park"], host["ectl"],
-                    spans=sp,
-                )
-                if sp:
+            with TraceAnnotation("bench:stream.settle"):
+                counts_np, ctl_o = got["counts"], got["ctl"]
+                if mk.trace is not None:
+                    trace_row = got["trace"]
+                    entry_t1_ns = time.monotonic_ns()
+                if table is not None:
+                    # Fold the lane-cursor echo back: consume cursors advance
+                    # (freeing in-flight budget), cumulative install/expire/
+                    # sweep counters refresh, admission latencies record.
+                    table.absorb(got["tctl"])
+                if egspec is not None:
+                    # Drain the mailbox AND the park ring at the entry
+                    # boundary, resolving futures - both always empty when
+                    # the loop reaches the quiesce/drained-exit checks
+                    # below, so a full mailbox can never wedge either.
+                    for n in ("egr", "park", "ectl"):
+                        host[n] = got[n]
+                    settle(host["egr"], host["park"], host["ectl"])
+                if self.telemetry:
+                    # Absorb the echoed histogram/gauge block and publish a
+                    # coherent snapshot for mid-run scrapers (the stamp table
+                    # stays on the chip). The epoch bracket pairs this
+                    # entry's host wall clock with the round-gauge delta so
+                    # rounds convert to ns without any on-device clock.
+                    tele_np = host["tele"] = got["tele"]
+                    tele_np[0, TG_ENTRIES] += 1
+                    t1_ns = time.monotonic_ns()
+                    rounds = int(tele_np[0, TG_ROUNDS])
+                    bracket.accumulate(
+                        entry_t0_ns, t1_ns, rounds - prev_rounds
+                    )
+                    prev_rounds = rounds
                     with self._lock:
-                        self._spans.update(sp)
-            if self.telemetry:
-                # Absorb the echoed histogram/gauge block and publish a
-                # coherent snapshot for mid-run scrapers (the stamp table
-                # stays on the chip). The epoch bracket pairs this
-                # entry's host wall clock with the round-gauge delta so
-                # rounds convert to ns without any on-device clock.
-                tele_np = host["tele"] = got["tele"]
-                tele_np[0, TG_ENTRIES] += 1
-                t1_ns = time.monotonic_ns()
-                rounds = int(tele_np[0, TG_ROUNDS])
-                bracket.accumulate(
-                    entry_t0_ns, t1_ns, rounds - prev_rounds
-                )
-                prev_rounds = rounds
-                with self._lock:
-                    self._tele_seq += 1
-                    self._tele_snapshot = {
-                        "seq": self._tele_seq,
-                        "tele": tele_np.copy(),
-                        "rounds": rounds,
-                        "entries": int(tele_np[0, TG_ENTRIES]),
-                        "ns_per_round": bracket.ns_per_round(),
-                        "t0_ns": entry_t0_ns,
-                        "t1_ns": t1_ns,
-                    }
+                        self._tele_seq += 1
+                        self._tele_snapshot = {
+                            "seq": self._tele_seq,
+                            "tele": tele_np.copy(),
+                            "rounds": rounds,
+                            "entries": int(tele_np[0, TG_ENTRIES]),
+                            "ns_per_round": bracket.ns_per_round(),
+                            "t0_ns": entry_t0_ns,
+                            "t1_ns": t1_ns,
+                        }
             ctl[2] = ctl_o[2]  # device-consumed cursor persists
             if bool(counts_np[C_OVERFLOW]):
                 raise RuntimeError("streaming megakernel overflow")

@@ -65,6 +65,7 @@ __all__ = [
     "bucket_of",
     "bucket_edges",
     "unpack_spans",
+    "unpack_spans_rows",
     "hist_fold_reference",
     "quantile_from_hist",
     "TelemetryBlock",
@@ -134,6 +135,18 @@ def unpack_spans(admit: int, spans: int) -> Tuple[int, int, int, int]:
     install = admit + (spans & 0xFFFF)
     fire = install + ((spans >> 16) & 0xFFFF)
     return admit, install, fire, fire
+
+
+def unpack_spans_rows(admit, spans) -> Tuple[np.ndarray, np.ndarray,
+                                              np.ndarray]:
+    """:func:`unpack_spans` over whole EGR_T_ADMIT / EGR_T_SPANS columns
+    (int32 arrays): the absolute rounds ``(admit, install, fire)`` as
+    int64 arrays, word for word what the scalar decode gives a row."""
+    admit = np.asarray(admit).astype(np.int64)
+    spans = np.asarray(spans).astype(np.int64) & 0xFFFFFFFF
+    install = admit + (spans & 0xFFFF)
+    fire = install + ((spans >> 16) & 0xFFFF)
+    return admit, install, fire
 
 
 def hist_fold_reference(

@@ -83,6 +83,7 @@ the Perfetto timeline (tools/timeline.py).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -429,7 +430,7 @@ class _Lane:
         "spec", "idx", "scope", "bucket", "queue", "pub_meta",
         "published", "consumed", "dev_expired", "dev_dropped", "installed",
         "accepted", "rejected", "expired_host", "poisoned", "dropped",
-        "throttled", "quarantined", "latencies",
+        "throttled", "quarantined", "latencies", "timed",
     )
 
     def __init__(self, spec: TenantSpec, idx: int, parent_scope,
@@ -443,6 +444,11 @@ class _Lane:
         )
         self.queue: deque = deque()
         self.pub_meta: deque = deque()
+        # Rows of pub_meta that carry a deadline (marked expired or
+        # not): kept where rows are published, consumed and exported,
+        # so pump() walks pub_meta for lapsed deadlines, and absorb()
+        # takes its rows one by one, only in a lane that has any.
+        self.timed = 0
         self.published = 0
         self.consumed = 0
         self.dev_expired = 0
@@ -835,7 +841,13 @@ class TenantTable:
         drops expired host-queued rows, marks expired published rows for
         the device poll to drop, publishes backlog into each lane's ring
         region up to its in-flight budget, and returns the (T, 8) tctl
-        array the entry uploads."""
+        array the entry uploads. What it does it decides from what the
+        lane holds: published rows are walked for lapsed deadlines only
+        in a lane that has a deadline-bearing one (``_Lane.timed``),
+        and a backlog's head run that needs no look at each row (no
+        deadline, no validator) goes to the ring by one store
+        (``_publish_run_locked``); every other row takes the row-by-row
+        loop."""
         now = self.clock()
         T = len(self._lanes)
         tctl = np.zeros((T, 8), np.int32)
@@ -867,8 +879,10 @@ class TenantTable:
                     lane.dropped += len(lane.queue)
                     lane.queue.clear()
                 # Expire published-but-unconsumed rows: mark the ring row
-                # so the device poll drops it (lazily, counted).
-                for p in lane.pub_meta:
+                # so the device poll drops it (lazily, counted). A lane
+                # none of whose published rows has a deadline is not
+                # walked.
+                for p in lane.pub_meta if lane.timed else ():
                     if (
                         not p.marked
                         and p.deadline_at is not None
@@ -887,6 +901,15 @@ class TenantTable:
                     self.region_rows if spec.max_in_flight is None
                     else spec.max_in_flight
                 )
+                if (
+                    spec.validator is None
+                    and ring.flags.c_contiguous
+                    and not lane.paused()
+                ):
+                    self._publish_run_locked(lane, ring, min(
+                        self.region_rows - lane.published,
+                        cap - lane.in_flight,
+                    ))
                 while (
                     lane.queue
                     and lane.published < self.region_rows
@@ -918,6 +941,7 @@ class TenantTable:
                         )
                     p.index = lane.published
                     lane.pub_meta.append(p)
+                    lane.timed += p.deadline_at is not None
                     lane.published += 1
                     self.ring_writes += 1
                 tctl[lane.idx, TC_TAIL] = lane.published
@@ -929,6 +953,37 @@ class TenantTable:
                 tctl[lane.idx, TC_EXPIRED] = lane.dev_expired
                 tctl[lane.idx, TC_INSTALLED] = lane.installed
         return tctl
+
+    def _publish_run_locked(self, lane: _Lane, ring: np.ndarray,
+                            room: int) -> None:
+        """Publish the head run of the lane's backlog that needs no
+        look at each row - no deadline (the lane has no validator, the
+        caller saw) - up to ``room`` rows, by ONE store of the rows,
+        joined, into the lane's region (a view of a ring that is
+        contiguous, the caller saw too). The admit-round stamp goes
+        onto the slice's zero words only, as row by row: residue
+        re-published after a checkpoint cut keeps its original
+        admission round. A row with a deadline ends the run; ``pump``'s
+        row-by-row loop takes the backlog from there."""
+        run = list(itertools.takewhile(
+            lambda p: p.deadline_at is None,
+            itertools.islice(lane.queue, max(0, room)),
+        ))
+        if not run:
+            return
+        lo = lane.idx * self.region_rows + lane.published
+        block = ring[lo:lo + len(run)]
+        np.concatenate([p.row for p in run], out=block.reshape(-1))
+        if self._admit_round:
+            stamps = block[:, TEN_ADMIT_ROUND]
+            stamps[stamps == 0] = self._admit_round
+        for _ in run:
+            lane.queue.popleft()
+        for index, p in enumerate(run, lane.published):
+            p.index = index
+        lane.pub_meta.extend(run)
+        lane.published += len(run)
+        self.ring_writes += len(run)
 
     def _validate(self, lane: _Lane, p: _Pending) -> bool:
         """Run the lane's validator with IMMEDIATE retries per its
@@ -974,17 +1029,30 @@ class TenantTable:
         consume cursors, record admission-to-install latencies, and
         refresh the cumulative device counters. A paused lane's consume
         advance is the device SWEEP (quarantine/cancel drain): those
-        rows count as dropped, never as install latencies."""
+        rows count as dropped, never as install latencies. A lane that
+        was not swept and holds no deadline-bearing row retires the
+        cursor's advance in one pass; a swept lane, or one in which a
+        row may be marked expired, goes row by row."""
         now = self.clock()
         tctl_out = np.asarray(tctl_out)
         with self._lock:
             for lane in self._lanes:
                 swept = int(tctl_out[lane.idx, TC_PAUSE]) != 0
                 new_consumed = int(tctl_out[lane.idx, TC_CONSUMED])
-                while lane.pub_meta and lane.pub_meta[0].index < (
-                    new_consumed
-                ):
-                    p = lane.pub_meta.popleft()
+                pub = lane.pub_meta
+                if not swept and not lane.timed:
+                    # The cursor's advance says how many rows leave,
+                    # and none of them can be marked (no row of the
+                    # lane has a deadline): one pass, one extend.
+                    gone = min(new_consumed - lane.consumed, len(pub))
+                    lane.latencies.extend([
+                        now - pub.popleft().t_submit for _ in range(gone)
+                    ])
+                # Row by row where a row may be marked or was swept
+                # (after the pass above nothing is left to take).
+                while pub and pub[0].index < new_consumed:
+                    p = pub.popleft()
+                    lane.timed -= p.deadline_at is not None
                     if not p.marked and not swept:
                         lane.latencies.append(now - p.t_submit)
                     elif swept and self.futures is not None and p.token:
@@ -1072,6 +1140,7 @@ class TenantTable:
                 for p in lane.pub_meta:
                     carry(lane, p, ring[base + p.index])
                 lane.pub_meta.clear()
+                lane.timed = 0
                 for p in lane.queue:
                     carry(lane, p, p.row)
                 lane.queue.clear()
@@ -1154,6 +1223,7 @@ class TenantTable:
                 i = lane.idx
                 lane.queue.clear()
                 lane.pub_meta.clear()
+                lane.timed = 0
                 lane.published = 0
                 lane.consumed = 0
                 lane.dev_expired = int(tctl[i, TC_EXPIRED])

@@ -128,6 +128,19 @@ def fib_exec_count(n):
     return t + (t - 1) // 2
 
 
+class Tick:
+    """A ledger clock that advances a step a reading: two futures
+    finished from one reading would share a ``t_done``, and ``t_done``
+    orders futures as they were resolved."""
+
+    def __init__(self, t: float = 100.0) -> None:
+        self.t = t
+
+    def __call__(self) -> float:
+        self.t += 1.0
+        return self.t
+
+
 BUMP = 0  # bump_kernel's id in bump_mk's one-entry kernel table
 
 

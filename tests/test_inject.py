@@ -5,13 +5,30 @@ Reference counterpart: materializing work on a running runtime from outside
 
 import threading
 import time
+import types
 
 import jax
+import numpy as np
 import pytest
-from conftest import KINDS, bump_mk, front_door, send
+from conftest import KINDS, Tick, bump_mk, front_door, send
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
+from hclib_tpu.device.egress import (
+    EC_CONSUMED,
+    EC_PARK_COUNT,
+    EC_PARK_HEAD,
+    EC_WRITE,
+    EGR_STATUS,
+    EGR_T_ADMIT,
+    EGR_T_SPANS,
+    EGR_TOKEN,
+    EgressProtocolError,
+    EgressSpec,
+    FutureTable,
+    HostMailbox,
+)
 from hclib_tpu.device.inject import StreamingMegakernel
+from hclib_tpu.device.telemetry import unpack_spans, unpack_spans_rows
 from hclib_tpu.device.workloads import FIB, make_fib_megakernel
 
 BUMP = 0
@@ -97,7 +114,8 @@ def test_streaming_on_tpu():
 
 # ---- the entry boundary (ISSUE 32): what crosses the host link an entry
 
-LINK_KEYS = {"entries", "uploads", "downloads", "ring_uploads", "idle_sleeps"}
+LINK_KEYS = {"entries", "uploads", "downloads", "ring_uploads", "idle_sleeps",
+             "settled", "settle_batches"}
 
 
 def counting_pumps(table):
@@ -138,6 +156,11 @@ def test_closed_burst_one_upload_one_download_an_entry(kind):
     assert link["idle_sleeps"] == 0
     assert link["uploads"] <= 2 * link["entries"] + 1
     assert link["downloads"] <= 2 * link["entries"] + 1
+    # The boundaries settle the mailbox in bulk: every request through
+    # one ledger call an entry that resolved any (egress builds only).
+    assert link["settled"] == (n if kind in ("egress", "telemetry") else 0)
+    assert link["settle_batches"] <= link["entries"]
+    assert (link["settle_batches"] >= 3) == (link["settled"] > 0)
     if table is None:
         assert link["ring_uploads"] == 1
         return
@@ -183,3 +206,153 @@ def test_open_stream_sleeps_while_idle_and_picks_late_rows_up(kind):
     # once more only if the loop woke while the producer was sending).
     assert 2 <= link["ring_uploads"] <= 1 + 6
     assert [f.state for f in late] == ["RESULT"] * len(late)
+
+
+# ---- the boundary's bulk settle (ISSUE 37) against the row-at-a-time
+# specification of the mailbox (egress.HostMailbox / egress_reference)
+
+
+def _published(case):
+    """A depth-4 mailbox with a 4-row park ring in the state ``case``
+    names, its rows published by ``egress_reference`` and its cursors
+    moved by ``HostMailbox.drain`` / ``flush``; two twin ledgers that
+    both saw what was drained on the way. Returns (box, the ledger the
+    specification resolves into, the ledger the driver does, the
+    driver's futures by token)."""
+    spec, ours = FutureTable(), FutureTable(clock=Tick())
+    for i in range(9):
+        spec.create("a", BUMP, i)
+    futs = {f.token: f for f in (ours.create("a", BUMP, i)
+                                 for i in range(9))}
+    box = HostMailbox(EgressSpec(depth=4), park_cap=4)
+    rows = [(t, t % 2, BUMP, t, 100 + 7 * t) for t in range(1, 10)]
+
+    def drain(limit):
+        for t, v in box.drain(futures=spec, limit=limit,
+                              include_parked=False):
+            ours.resolve(t, v)
+
+    if case == "straight":          # slots 0, 1
+        box.publish(rows[:2])
+    elif case == "wraps":           # consumed 3: slots 3, 0, 1
+        box.publish(rows[:3])
+        drain(3)
+        box.publish(rows[3:6])
+    elif case == "empty":           # consumed == write == 3
+        box.publish(rows[:3])
+        drain(3)
+    elif case == "parked":          # mailbox full, park slots 0, 1, 2
+        box.publish(rows[:7])
+    elif case == "park_head":       # mailbox 2,3,0,1; park head 2, wraps
+        box.publish(rows[:7])
+        drain(2)
+        assert box.flush() == 2
+        box.publish(rows[7:9])
+        assert int(box.ectl[EC_PARK_HEAD]) == 2 and box.parked() == 3
+    # Telemetry words, as a telemetry build's rows carry them: the
+    # packed deltas of every row have the sign bit set.
+    for blk in (box.egr, box.park):
+        live = blk[:, EGR_TOKEN] != 0
+        blk[live, EGR_T_ADMIT] = 1000 + blk[live, EGR_TOKEN]
+        blk[live, EGR_T_SPANS] = (
+            (0x9000 + blk[live, EGR_TOKEN]) << 16 | 0x21
+        ).astype(np.uint32).view(np.int32)
+    return box, spec, ours, futs
+
+
+DRAINS = {"straight": 2, "wraps": 3, "empty": 0, "parked": 7,
+          "park_head": 7}
+
+
+@pytest.mark.parametrize("case", sorted(DRAINS))
+def test_drain_egress_equals_the_mailbox_specification(case):
+    """``_drain_egress`` settles a boundary in bulk; the row-at-a-time
+    ``HostMailbox.drain`` over the same arrays resolves the same tokens
+    to the same values in the same order, and both leave the mailbox
+    and the park ring empty and re-zeroed."""
+    box, spec, ours, futs = _published(case)
+    egr, park, ectl = box.egr.copy(), box.park.copy(), box.ectl.copy()
+    held = np.concatenate([egr, park])
+    held = held[held[:, EGR_TOKEN] != 0]
+    early = {t for t, f in futs.items() if f.done()}
+    spans = {}
+    n = StreamingMegakernel._drain_egress(
+        types.SimpleNamespace(futures=ours), egr, park, ectl, spans=spans)
+    pairs = box.drain(futures=spec)
+    assert n == len(pairs) == len(held) == DRAINS[case]
+    settled = sorted((f for t, f in futs.items()
+                      if f.done() and t not in early),
+                     key=lambda f: f.t_done)
+    assert [(f.token, f.value) for f in settled] == pairs
+    assert all(f.state == "RESULT" for f in settled)
+    assert ours.conservation() == spec.conservation()
+    assert not egr.any() and not park.any()
+    assert not box.egr.any() and not box.park.any()
+    assert int(ectl[EC_CONSUMED]) == int(ectl[EC_WRITE])
+    assert int(ectl[EC_PARK_COUNT]) == int(ectl[EC_PARK_HEAD]) == 0
+    assert box.occupancy() == box.parked() == 0
+    assert spans == {
+        int(r[EGR_TOKEN]): unpack_spans(r[EGR_T_ADMIT], r[EGR_T_SPANS])[:3]
+        for r in held
+    }
+    assert all(a < b < c for a, b, c in spans.values())
+
+
+@pytest.mark.parametrize("where", ["mailbox", "park"])
+def test_drain_egress_names_the_first_slot_that_is_not_ok(where):
+    """A consumed slot whose status is not ``EGR_OK`` raises, as the
+    specification does, naming the slot; the rows before it are
+    resolved and re-zeroed, the offender and the cursors are left."""
+    box, spec, ours, futs = _published("park_head")
+    blk, slot, first = (
+        (box.egr, 3, [3]) if where == "mailbox"
+        else (box.park, 3, [3, 4, 5, 6, 7])
+    )
+    blk[slot, EGR_STATUS] = 0
+    egr, park, ectl = box.egr.copy(), box.park.copy(), box.ectl.copy()
+    with pytest.raises(EgressProtocolError, match=f"{where} slot {slot} "):
+        StreamingMegakernel._drain_egress(
+            types.SimpleNamespace(futures=ours), egr, park, ectl)
+    assert sorted(t for t, f in futs.items() if f.done()) == [1, 2] + first
+    assert np.array_equal(ectl, box.ectl)
+    assert (egr, park)[where == "park"][slot, EGR_TOKEN] == first[-1] + 1
+    assert np.count_nonzero(np.concatenate([egr, park])[:, EGR_TOKEN]) == (
+        7 - len(first))
+    cons = ours.conservation()
+    assert cons["ok"] and cons["resolved"] == 2 + len(first)
+    if where == "mailbox":
+        with pytest.raises(EgressProtocolError, match="consumed twice"):
+            box.drain(futures=spec)
+        assert spec.conservation() == cons
+
+
+def test_drain_egress_lets_the_ledgers_refusal_through():
+    """A row whose token the ledger already settled raises out of the
+    bulk call with the rows before it resolved; nothing is re-zeroed
+    and no cursor moves."""
+    box, spec, ours, futs = _published("parked")
+    ours.resolve(3, 0)
+    egr, park, ectl = box.egr.copy(), box.park.copy(), box.ectl.copy()
+    with pytest.raises(EgressProtocolError, match="token 3 already"):
+        StreamingMegakernel._drain_egress(
+            types.SimpleNamespace(futures=ours), egr, park, ectl)
+    assert [futs[t].state for t in (1, 2, 4)] == [
+        "RESULT", "RESULT", "PENDING"]
+    assert np.array_equal(egr, box.egr) and np.array_equal(ectl, box.ectl)
+    assert ours.conservation()["ok"]
+
+
+def test_vector_span_decode_equals_unpack_spans_word_for_word():
+    """``unpack_spans_rows`` over whole columns is ``unpack_spans`` of
+    each row: random words, the sign bit of either set, the extremes."""
+    rng = np.random.default_rng(37)
+    words = rng.integers(-2**31, 2**31, (2, 4096)).astype(np.int32)
+    edge = np.array([0, 1, -1, 2**31 - 1, -2**31, 0xFFFF, 0x10000],
+                    np.int32)
+    admit = np.concatenate([words[0], edge, edge[::-1]])
+    spans = np.concatenate([words[1], edge, edge])
+    got = [a.tolist() for a in unpack_spans_rows(admit, spans)]
+    assert list(zip(*got)) == [
+        unpack_spans(a, s)[:3] for a, s in zip(admit, spans)
+    ]
+    assert (spans < 0).any() and (admit < 0).any()

@@ -22,7 +22,7 @@ import time
 
 import numpy as np
 import pytest
-from conftest import bump_mk, seed_builder
+from conftest import Tick, bump_mk, seed_builder
 
 from hclib_tpu.device.egress import (
     EC_CONSUMED,
@@ -99,6 +99,113 @@ def test_double_resolution_is_impossible():
         g.result()
     cons = ft.conservation()
     assert cons["ok"] and cons["resolved"] == 1 and cons["expired"] == 1
+
+
+# ---- the bulk settle (ISSUE 37): resolve_many is a loop of resolve
+# under one taking of the lock
+
+
+def _ledger(kind):
+    """A ledger holding 12 pending tokens of the given kind: live
+    futures, tokens adopted from a predecessor and not yet reattached,
+    or six of each (adopted 1-6, live 7-12). Returns (ledger, live
+    futures by token, resume tokens by token)."""
+    ft = FutureTable(clock=Tick())
+    live, resume = {}, {}
+    if kind != "live":
+        old = FutureTable()
+        gone = [old.create("a", BUMP, i) for i in range(
+            12 if kind == "adopted" else 6)]
+        resume = {rt[2]: rt for rt in old.preempt_all()}
+        assert sorted(resume) == [f.token for f in gone]
+        ft.adopt_tokens(old.export_tokens())
+    if kind != "adopted":
+        for i in range(12 if kind == "live" else 6):
+            f = ft.create("b", BUMP, i)
+            live[f.token] = f
+    assert ft.pending() == 12
+    return ft, live, resume
+
+
+def _told(ft, live, resume):
+    """What a client can learn of every token: its future's state,
+    value and reason (an adopted one through reattach)."""
+    futs = dict(live)
+    futs.update((t, ft.reattach(rt)) for t, rt in resume.items())
+    return {t: (f.state, f.value, f.reason) for t, f in futs.items()}
+
+
+@pytest.mark.parametrize("kind", ["live", "adopted", "mix"])
+def test_resolve_many_equals_a_loop_of_resolve(kind):
+    """The same rows through ``resolve_many`` and through ``resolve``
+    one by one leave two ledgers a client cannot tell apart: live
+    futures RESULT with their values, adopted-and-unattached tokens
+    early-terminal for the ``reattach`` that follows, counters and
+    conservation equal."""
+    order = [5, 12, 1, 8, 3, 10, 7, 2, 11, 4, 9, 6]
+    values = [31 * t + 1 for t in order]
+    one, live1, resume1 = _ledger(kind)
+    for t, v in zip(order, values):
+        one.resolve(t, v)
+    many, live2, resume2 = _ledger(kind)
+    assert many.resolve_many(order, values) == 12
+    assert many.conservation() == one.conservation()
+    assert many.conservation()["resolved"] == 12 and not many.pending()
+    assert sorted(many._early) == sorted(one._early) == sorted(resume1)
+    told = _told(many, live2, resume2)
+    assert told == _told(one, live1, resume1)
+    assert told == {t: ("RESULT", 31 * t + 1, None) for t in order}
+    assert many.resolve_many([], []) == 0
+
+
+@pytest.mark.parametrize("offender", ["double", "unknown"])
+def test_resolve_many_refuses_in_row_order(offender):
+    """A token resolved twice, or never issued, raises where a loop of
+    ``resolve`` would: the rows before it are RESULT and counted, the
+    rows after it still pending, the ledger conserving; the rest then
+    settles."""
+    ft, live, _ = _ledger("live")
+    bad = 3 if offender == "double" else 999_999
+    rows = [1, 2, 3, bad, 4, 5]
+    with pytest.raises(
+        EgressProtocolError,
+        match="already RESULT" if offender == "double" else "unknown",
+    ):
+        ft.resolve_many(rows, [10 * t for t in rows])
+    assert [live[t].state for t in (1, 2, 3)] == ["RESULT"] * 3
+    assert [live[t].value for t in (1, 2, 3)] == [10, 20, 30]
+    assert all(live[t].state == "PENDING" for t in range(4, 13))
+    cons = ft.conservation()
+    assert cons["ok"] and cons["resolved"] == 3 and cons["pending"] == 9
+    assert ft.resolve_many(list(range(4, 13)), [0] * 9) == 9
+    cons = ft.conservation()
+    assert cons["ok"] and cons["resolved"] == 12 and not cons["pending"]
+
+
+def test_resolve_many_wakes_who_waits_and_dates_each_future_itself():
+    """A client blocked in ``result()`` wakes from the bulk call; every
+    future's ``t_done`` is a clock reading of its own, taken inside the
+    call: not before the batch began, not after it returned, in row
+    order."""
+    ft = FutureTable(backoff_s=0.001)
+    futs = [ft.create("a", BUMP, i) for i in range(200)]
+    got = []
+    waiter = threading.Thread(
+        target=lambda: got.append(futs[150].result(timeout=60.0)))
+    waiter.start()
+    while ft.stats_dict()["waited"] < 1:
+        time.sleep(0.001)
+    t0 = time.monotonic()
+    ft.resolve_many([f.token for f in futs], [7 * f.token for f in futs])
+    t1 = time.monotonic()
+    waiter.join(60.0)
+    assert not waiter.is_alive() and got == [7 * futs[150].token]
+    done = [f.t_done for f in futs]
+    assert t0 <= done[0] and done[-1] <= t1 and done == sorted(done)
+    # Under a clock that moves a reading, no two futures share one.
+    ft, live, _ = _ledger("live")
+    ft.resolve_many(sorted(live), [0] * 12)
+    assert len({f.t_done for f in live.values()}) == 12
 
 
 # ---- the lazy Event (ISSUE 34): a future pays for a waiter only when

@@ -26,6 +26,7 @@ from hclib_tpu.device.descriptor import (
     F_SUCC1,
     NO_TASK,
     RING_ROW,
+    TEN_ADMIT_ROUND,
     TEN_DEADLINE_MS,
     TEN_EXPIRED,
     TEN_ID,
@@ -39,6 +40,7 @@ from hclib_tpu.device.tenants import (
     ADMIT_QUEUED,
     TC_CONSUMED,
     TC_DROPPED,
+    TC_EXPIRED,
     TC_INSTALLED,
     TC_PAUSE,
     TC_TAIL,
@@ -245,6 +247,265 @@ def test_deadline_admission_reject_drop_and_ring_mark():
     assert s["expired"] == 8     # 4 device-dropped + 4 host-dropped
     assert s["rejected"] == 1    # the at-admission one
     assert s["accepted"] == s["completed"] + s["expired"]
+
+
+# ---- the boundary in bulk (ISSUE 37): pump publishes a run by one
+# store and absorb takes the cursor's advance in one pass, to the word
+# what the row-by-row paths that are still there do
+
+
+def _twins(**lane):
+    """Two egress tables on one clock that a script drives alike:
+    ``bulk``'s lanes have no validator, so a backlog's head run goes to
+    the ring by one store; ``rows``' validator accepts every row, which
+    makes ``pump`` take each through its row-by-row loop."""
+    clock = FakeClock()
+    bulk, rows = (
+        TenantTable(
+            [TenantSpec("a", weight=2, validator=v, **lane),
+             TenantSpec("b", validator=v, **lane)],
+            16, clock=clock, egress=EgressSpec(depth=4),
+        )
+        for v in (None, lambda row: None)
+    )
+    return clock, bulk, rows
+
+
+def _lanes_alike(bulk, rows):
+    """Every word a later pump, absorb, export or client can see."""
+    assert bulk.stats() == rows.stats()
+    assert bulk.ring_writes == rows.ring_writes
+    assert bulk.futures.conservation() == rows.futures.conservation()
+    for x, y in zip(bulk._lanes, rows._lanes):
+        assert [p.index for p in x.pub_meta] == [p.index for p in y.pub_meta]
+        assert [p.index for p in x.pub_meta] == list(
+            range(x.consumed, x.published))
+        assert [p.token for p in x.queue] == [p.token for p in y.queue]
+        assert list(x.latencies) == list(y.latencies)
+        assert x.timed == y.timed == sum(
+            p.deadline_at is not None for p in x.pub_meta)
+
+
+def _script_run(clock, t, ring):
+    """Backlogs without a deadline, two lanes, an admit round to stamp."""
+    for i in range(10):
+        assert t.submit("ab"[i % 3 == 0], BUMP, args=[i + 1], out=i)
+        clock.advance(0.25)
+    t.set_admit_round(7)
+    return [t.pump(ring)]
+
+
+def _script_deadline_on_some(clock, t, ring):
+    """A deadline on rows 4 and 6 of lane a: the run ends at row 4;
+    once the deadline lapses the published rows are marked on the ring,
+    their tokens expire once, each mark counts a ring write, and a
+    later pump marks nothing again."""
+    futs = []
+    for i in range(8):
+        adm = t.submit("a", BUMP, args=[i + 1],
+                       deadline_s=5.0 if i in (4, 6) else None)
+        futs.append(adm.future)
+        clock.advance(0.25)
+    assert t.submit("b", BUMP, args=[99])
+    out = [t.pump(ring)]
+    assert t._lanes[0].timed == 2 and t._lanes[1].timed == 0
+    wrote = t.ring_writes
+    clock.advance(10.0)
+    out.append(t.pump(ring))
+    assert t.ring_writes == wrote + 2
+    assert [i for i in range(16) if ring[i, TEN_EXPIRED]] == [4, 6]
+    assert [f.state for f in futs] == [
+        "EXPIRED" if i in (4, 6) else "PENDING" for i in range(8)]
+    out.append(t.pump(ring))
+    assert t.ring_writes == wrote + 2 and t.stats()["a"]["expired"] == 0
+    echo = out[-1].copy()   # the device drops the marked two, takes all
+    echo[0, TC_CONSUMED], echo[0, TC_INSTALLED] = 8, 6
+    echo[0, TC_EXPIRED] = 2
+    t.absorb(echo)
+    assert t._lanes[0].timed == 0 and len(t._lanes[0].latencies) == 6
+    assert t.stats()["a"]["expired"] == 2
+    return out
+
+
+def _script_budget_below_backlog(clock, t, ring):
+    """``max_in_flight`` 3 under a backlog of 8: three go, the cursor's
+    echo frees two, two more go."""
+    for i in range(8):
+        assert t.submit("a", BUMP, args=[i + 1])
+    out = [t.pump(ring)]
+    assert t.stats()["a"]["published"] == 3
+    echo = out[0].copy()
+    echo[0, TC_CONSUMED] = echo[0, TC_INSTALLED] = 2
+    clock.advance(1.0)
+    t.absorb(echo)
+    out.append(t.pump(ring))
+    assert t.stats()["a"]["published"] == 5
+    assert t.stats()["a"]["queued"] == 3
+    return out
+
+
+def _script_stamped_residue(clock, t, ring):
+    """Residue of a checkpoint cut re-enters with its admit round
+    stamped (3): the pump's stamp (9) lands on the zero words only."""
+    for i in range(4):
+        row = build_row(BUMP, [i + 1])
+        row[TEN_ID] = 0
+        row[TEN_ADMIT_ROUND] = 3 if i % 2 else 0
+        t.readmit("a", row)
+    assert t.submit("a", BUMP, args=[5])
+    t.set_admit_round(9)
+    out = [t.pump(ring)]
+    assert ring[:6, TEN_ADMIT_ROUND].tolist() == [9, 3, 9, 3, 9, 0]
+    return out
+
+
+def _script_no_round(clock, t, ring):
+    """Telemetry off (admit round 0): no word is stamped."""
+    for i in range(5):
+        assert t.submit("b", BUMP, args=[i + 1])
+    out = [t.pump(ring)]
+    assert not ring[:, TEN_ADMIT_ROUND].any()
+    return out
+
+
+SCRIPTS = {
+    "run": (_script_run, {}),
+    "deadline_on_some": (_script_deadline_on_some, {}),
+    "budget_below_backlog": (_script_budget_below_backlog,
+                             {"max_in_flight": 3}),
+    "stamped_residue": (_script_stamped_residue, {}),
+    "no_round": (_script_no_round, {}),
+}
+
+
+@pytest.mark.parametrize("script", sorted(SCRIPTS))
+def test_pump_and_absorb_in_bulk_equal_the_row_by_row_paths(script):
+    fn, lane = SCRIPTS[script]
+    clock, bulk, rows = _twins(**lane)
+    rings = [np.zeros((32, RING_ROW), np.int32) for _ in range(2)]
+    t0 = clock()
+    blocks = []
+    for t, ring in zip((bulk, rows), rings):
+        clock.t = t0
+        blocks.append(fn(clock, t, ring))
+    assert np.array_equal(rings[0], rings[1])
+    assert rings[0].any()
+    for x, y in zip(*blocks):
+        assert np.array_equal(x, y)
+    _lanes_alike(bulk, rows)
+    # ... and to the end: the device takes everything, the cursors'
+    # echo retires every published row on both.
+    for t, ring in zip((bulk, rows), rings):
+        echo = t.pump(ring)
+        echo[:, TC_CONSUMED] = echo[:, TC_TAIL]
+        t.absorb(echo)
+        assert not any(lane.pub_meta or lane.timed for lane in t._lanes)
+    _lanes_alike(bulk, rows)
+
+
+def test_pump_takes_a_ring_it_cannot_view_flat_row_by_row():
+    """The one-store publish writes through a flat view of the lane's
+    region; a ring that is not contiguous gets the same words row by
+    row."""
+    clock, bulk, rows = _twins()
+    wide = np.zeros((32, 2 * RING_ROW), np.int32)
+    rings = [wide[:, :RING_ROW], np.zeros((32, RING_ROW), np.int32)]
+    assert not rings[0].flags.c_contiguous
+    for t, ring in zip((bulk, rows), rings):
+        _script_run(clock, t, ring)
+    assert np.array_equal(rings[0], rings[1]) and rings[0].any()
+    assert not wide[:, RING_ROW:].any()
+
+
+def test_pump_with_a_validator_publishes_what_it_passes():
+    """A lane with a validator never takes the one-store path: the rows
+    it refuses poison their futures and leave no gap on the ring."""
+    def odd_only(row):
+        if row[F_A0] % 2 == 0:
+            raise ValueError("even")
+
+    t = TenantTable(
+        [TenantSpec("a", validator=odd_only, poison_throttle=99,
+                    poison_quarantine=99)],
+        16, clock=FakeClock(), egress=EgressSpec(depth=4),
+    )
+    futs = [t.submit("a", BUMP, args=[i]).future for i in range(1, 9)]
+    ring = np.zeros((16, RING_ROW), np.int32)
+    tctl = t.pump(ring)
+    assert tctl[0, TC_TAIL] == 4 and t.ring_writes == 4
+    assert ring[:5, F_A0].tolist() == [1, 3, 5, 7, 0]
+    assert [f.state for f in futs] == ["PENDING", "POISONED"] * 4
+    s = t.stats()["a"]
+    assert s["dropped"] == s["poisoned"] == 4 and s["published"] == 4
+
+
+ABSORBS = ["advance", "no_move", "marked", "swept"]
+
+
+@pytest.mark.parametrize("case", ABSORBS)
+def test_absorb_by_the_cursors_advance(case):
+    """``absorb`` retires ``new_consumed - consumed`` published rows in
+    one pass and records their latencies in one call; a lane with a
+    marked row, or one the device swept, still goes row by row: the
+    marked row records no latency, the swept rows' futures poison."""
+    clock = FakeClock()
+    t = TenantTable([TenantSpec("a"), TenantSpec("b")], 16, clock=clock,
+                    egress=EgressSpec(depth=4))
+    ring = np.zeros((32, RING_ROW), np.int32)
+    futs, sent = [], []
+    for i in range(6):
+        sent.append(clock())
+        futs.append(t.submit(
+            "a", BUMP, args=[i + 1],
+            deadline_s=4.0 if case == "marked" and i == 1 else None,
+        ).future)
+        clock.advance(0.5)
+    if case == "swept":
+        t.pump(ring)
+        t.quarantine("a", "test")
+    elif case == "marked":
+        t.pump(ring)
+        clock.advance(2.0)          # row 1's deadline lapses on the ring
+    tctl = t.pump(ring)
+    lane = t._lanes[0]
+    assert tctl[0, TC_TAIL] == 6 and len(lane.pub_meta) == 6
+    echo = tctl.copy()
+    moved = 0 if case == "no_move" else 4
+    echo[0, TC_CONSUMED] = moved
+    echo[0, TC_INSTALLED] = {"marked": 3, "swept": 0}.get(case, moved)
+    if case == "marked":
+        echo[0, TC_EXPIRED] = 1
+        assert ring[1, TEN_EXPIRED] == 1 and futs[1].state == "EXPIRED"
+        assert lane.timed == 1
+    if case == "swept":
+        assert tctl[0, TC_PAUSE] == 1
+        echo[0, TC_DROPPED] = 4
+    clock.advance(0.125)
+    now = clock()
+    t.absorb(echo)
+    assert lane.consumed == moved and len(lane.pub_meta) == 6 - moved
+    assert [p.index for p in lane.pub_meta] == list(range(moved, 6))
+    assert lane.timed == 0
+    want = {
+        "advance": [now - s for s in sent[:4]],
+        "no_move": [],
+        "marked": [now - sent[i] for i in (0, 2, 3)],
+        "swept": [],
+    }[case]
+    assert list(lane.latencies) == want
+    states = [f.state for f in futs]
+    if case == "swept":
+        assert states == ["POISONED"] * 4 + ["PENDING"] * 2
+        assert futs[0].reason == "swept (lane paused)"
+        assert t.stats()["a"]["dropped"] == 4
+    elif case == "marked":
+        assert states == ["PENDING", "EXPIRED"] + ["PENDING"] * 4
+        assert t.stats()["a"]["expired"] == 1
+    else:
+        assert states == ["PENDING"] * 6
+    assert t.futures.conservation()["ok"]
+    t.absorb(echo)                  # the same echo again moves nothing
+    assert list(lane.latencies) == want and lane.consumed == moved
 
 
 def test_cancel_scope_deadline_chain_feeds_admission():
