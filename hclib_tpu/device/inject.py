@@ -140,6 +140,7 @@ from .megakernel import (
     C_TAIL,
     C_VALLOC,
     Megakernel,
+    _split,
     _target_row,
     ran_on,
     smem_bytes,
@@ -198,16 +199,6 @@ class _EntryLayout(NamedTuple):
     down: Dict[str, Tuple[int, ...]]
     kept: List[str]
     stays: List[str]
-
-
-def _split(slab, shapes: Dict[str, Tuple[int, ...]]) -> Dict[str, Any]:
-    """The named blocks of a flat slab (numpy or traced), in order."""
-    blocks, off = {}, 0
-    for n, shape in shapes.items():
-        size = int(np.prod(shape))
-        blocks[n] = slab[off : off + size].reshape(shape)
-        off += size
-    return blocks
 
 
 class StreamingMegakernel:

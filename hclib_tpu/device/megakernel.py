@@ -23,7 +23,9 @@ from __future__ import annotations
 
 import functools
 import types
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -131,6 +133,65 @@ def require_tpu() -> Dict[str, Any]:
             "on the chip only; tests and tutorials say interpret=True."
         )
     return rec
+
+
+# A host int32 data buffer of ``Megakernel.run`` rides the call's one
+# upload slab while it is smaller than this, and crosses as an array of
+# its own from here up (``_rides`` is the rule). Measured once on a v5e's
+# host (PR 39, p50 of 150 round trips of a 130 KB slab, one buffer, one
+# small program and one small read; buffer riding / alone): 256 KiB 1.38
+# / 1.57 ms, 1 MiB 1.58 / 1.69, 2 MiB 1.96 / 1.92, 4 MiB 2.48 / 2.11. An
+# array of its own costs 0.23-0.25 ms to send whatever its size and the
+# join 0.14 ms a MiB, so riding wins by 0.1-0.2 ms up to 1 MiB, ties at
+# 2 MiB (device_sw_wave with its two 2 MiB buffers riding read 41.33 ms
+# a call against 41.14 alone) and loses from there.
+SLAB_RIDE_BYTES = 1 << 20
+
+
+def _split(slab, shapes: Dict[str, Tuple[int, ...]]) -> Dict[str, Any]:
+    """The named blocks of a flat slab (numpy or traced), in order."""
+    blocks, off = {}, 0
+    for n, shape in shapes.items():
+        size = int(np.prod(shape))
+        blocks[n] = slab[off : off + size].reshape(shape)
+        off += size
+    return blocks
+
+
+def _join(blocks: Dict[str, Any], shapes: Dict[str, Tuple[int, ...]]):
+    """``_split``'s inverse on the host: the named blocks as one flat
+    int32 slab. A block of another shape than its name's would shift
+    every block behind it, so it is refused here."""
+    parts = []
+    for n, shape in shapes.items():
+        a = np.asarray(blocks[n], np.int32)
+        if a.shape != tuple(shape):
+            raise ValueError(
+                f"{n}: shape {a.shape} != the build's {tuple(shape)}"
+            )
+        parts.append(a.reshape(-1))
+    return np.concatenate(parts)
+
+
+def _rides(buf) -> bool:
+    """Whether a data buffer of ``Megakernel.run`` rides the call's one
+    upload slab: it is on the host, int32, and smaller than
+    ``SLAB_RIDE_BYTES``. Read off the buffer alone."""
+    if isinstance(buf, jax.Array):
+        return False
+    a = np.asarray(buf)
+    return a.dtype == np.int32 and a.nbytes < SLAB_RIDE_BYTES
+
+
+class _ExecLayout(NamedTuple):
+    """``Megakernel._exec_layout``: block names by how they cross."""
+
+    ins: List[str]
+    outs: List[str]
+    up: Dict[str, Tuple[int, ...]]
+    alone: List[str]
+    down: Dict[str, Tuple[int, ...]]
+    stays: List[str]
 
 
 def ran_on(out, interpret: bool) -> Dict[str, Any]:
@@ -1104,7 +1165,9 @@ class Megakernel:
         # scheduler then maintains descriptor home-link words on spawn and
         # continuation transfer (dead writes otherwise - skipped).
         self.tracks_home = False
-        self._jitted: Dict[int, Any] = {}  # fuel -> compiled call
+        # (fuel, stage_all_values, the data buffers that ride the slab)
+        # -> the program _build_exec made for them
+        self._jitted: Dict[Any, Any] = {}
         # Last shared_build stats ({hit, build_s, cache_lookup_s}) for
         # this instance's most recent program build - surfaced as
         # info['program_cache'] (and the tiers timing gauges) so every
@@ -1113,9 +1176,6 @@ class Megakernel:
         # Last run()'s info dict (incl. the batched-tier counters), for
         # stats_dict() consumers that don't thread the return value.
         self._last_info: Optional[Dict[str, Any]] = None
-        # Packs counts + ivalues (+ tier stats) into one array: one
-        # device->host fetch per run instead of one per output.
-        self._packer = jax.jit(lambda *a: jnp.concatenate(a))
         if not self.interpret:
             # A compiled build must fit the chip's SMEM; refuse here,
             # naming the capacity that fits, not inside XLA.
@@ -2365,6 +2425,73 @@ class Megakernel:
             ),
         )
 
+    def _exec_layout(self, riding: Sequence[str] = ()) -> "_ExecLayout":
+        """How each block of ``_build_raw``'s signature crosses in a
+        ``run`` / ``resume`` (``ins`` / ``outs`` name its arguments and
+        results in order; a data buffer ``k`` is ``data:k`` both sides):
+
+        - ``up``: name -> shape of what goes up as ONE int32 slab, in
+          this order: the scheduler's own blocks, the quiesce words of a
+          checkpoint build, and the data buffers named in ``riding``.
+        - ``alone``: the other data buffers, arguments of their own.
+        - ``down``: name -> shape of what the host reads after every
+          run (counts, the values, and the tier, quiesce and trace rows
+          of the builds that have them); comes back as ONE array.
+        - ``stays``: the outputs that stay on the chip."""
+        data = ["data:" + k for k in self.data_specs]
+        ins = ["tasks", "succ", "ready", "counts", "ivalues"] + data
+        outs = ["tasks", "ready", "counts", "ivalues"] + data
+        up = {
+            "tasks": (self.capacity, DESC_WORDS),
+            "succ": (self.succ_capacity,),
+            "ready": (self.capacity,),
+            "counts": (8,),
+            "ivalues": (self.num_values,),
+        }
+        down = {"counts": (8,), "ivalues": (self.num_values,)}
+        if self.batch_specs:
+            outs.append("tstats")
+            down["tstats"] = (TS_WORDS,)
+        if self.checkpoint:
+            ins.append("qctl")
+            up["qctl"] = (8,)
+            outs.append("qstat")
+            down["qstat"] = (8,)
+        if self.trace is not None:
+            outs.append("trace")
+            down["trace"] = self.trace.out_shape().shape
+        up.update(
+            (n, tuple(self.data_specs[n[5:]].shape))
+            for n in data if n in riding
+        )
+        alone = [n for n in ins if n not in up]
+        return _ExecLayout(
+            ins, outs, up, alone, down, ["tasks", "ready"] + data
+        )
+
+    def _build_exec(self, fuel: int, stage_all_values: bool, lay):
+        """The program one ``run`` / ``resume`` runs: ``_build_raw``'s
+        kernel inside a jitted wrapper that splits the host's slab into
+        the blocks of ``lay.up`` and joins those of ``lay.down`` into
+        the one array the host reads, so a call is one transfer each way
+        beside the buffers that cross alone. Called as
+        ``program(slab, *alone)``; returns ``(packed, *stays)``."""
+        kernel = self._build_raw(fuel, stage_all_values=stage_all_values)
+
+        # Named for the trace's sake: a Pallas kernel shows under the
+        # outermost jit's name, and the benchmark's kernel metrics find
+        # ``%tpu_custom_call.N``, what a bare ``jit(pallas_call)`` gave
+        # (inject.py:_build_entry, the same).
+        def tpu_custom_call(slab, *alone):
+            blocks = {**dict(zip(lay.alone, alone)), **_split(slab, lay.up)}
+            res = dict(zip(lay.outs, kernel(*[blocks[n] for n in lay.ins])))
+            return (
+                jnp.concatenate([res[n].reshape(-1) for n in lay.down]),
+                *[res[n] for n in lay.stays],
+            )
+
+        return jax.jit(tpu_custom_call)
+
     def _build(self, fuel: int, reps: int = 1):
         from ..runtime.progcache import shared_build
 
@@ -2463,7 +2590,24 @@ class Megakernel:
         state: the run comes back with ``info['quiesced']=True`` and
         ``info['state']`` (the resumable scheduler snapshot - feed it to
         ``resume()`` or ``runtime.checkpoint.snapshot_megakernel``)
-        instead of raising StallError on the pending remainder."""
+        instead of raising StallError on the pending remainder.
+
+        What crosses the host link (``resume`` alike): ONE int32 slab up
+        (the task table, successors, ready ring, counts, values, the
+        quiesce words of a checkpoint build, and every ``data`` buffer
+        that is on the host, int32 and smaller than ``SLAB_RIDE_BYTES``),
+        one upload more for each other host buffer (a large or a
+        non-int32 one), nothing for a buffer that is a ``jax.Array``
+        already; ONE packed int32 array down (counts, values, and the
+        tier, quiesce and trace rows of the builds that have them), its
+        host copy started behind the launch. The data outputs stay on
+        the chip as ``jax.Array``s until the caller reads them. The rule
+        reads only the buffer (host or device, dtype, bytes); a layout
+        seen for the first time builds its own program.
+        ``info['staging']`` counts it: ``uploads`` (the slab counted
+        once), ``slab_words``, ``slab_blocks`` (the names that rode it,
+        a data buffer ``k`` as ``data:k``), ``downloads`` (1; 2 where a
+        quiesced run also pulled its state)."""
         with span("mk.finalize"):
             tasks, succ, ring, counts = builder.finalize(
                 capacity=self.capacity, succ_capacity=self.succ_capacity
@@ -2516,7 +2660,28 @@ class Megakernel:
                 "quiesce= needs Megakernel(checkpoint=True): the quiesce "
                 "word is compiled into the round loop only then"
             )
-        key = (fuel, bool(stage_all_values))
+        # What crosses, crosses once (ISSUE 39). The scheduler's blocks
+        # and the small int32 buffers the caller holds on the host go up
+        # as ONE slab; a buffer already on the chip is passed through, a
+        # large or non-int32 host buffer gets an upload of its own
+        # (``_rides`` reads the rule off each buffer).
+        host = {
+            "tasks": tasks, "succ": succ, "ready": ring, "counts": counts,
+            "ivalues": ivalues,
+        }
+        if self.checkpoint:
+            host["qctl"] = self.quiesce_words(quiesce)
+        alone = {
+            "data:" + k: data[k]
+            for k in self.data_specs if not _rides(data[k])
+        }
+        riding = tuple(
+            "data:" + k for k in self.data_specs if "data:" + k not in alone
+        )
+        host.update((n, data[n[5:]]) for n in riding)
+        # A caller may hand the same buffer from the host once and from
+        # the chip the next time: the layout is part of the program.
+        key = (fuel, bool(stage_all_values), riding)
         first_build = key not in self._jitted
         if first_build:
             # Process-wide program cache (runtime/progcache.py): a
@@ -2527,13 +2692,13 @@ class Megakernel:
             # runs on one instance never pay fingerprinting).
             from ..runtime.progcache import shared_build
 
-            self._jitted[key], self._pc_stats = shared_build(
+            lay = self._exec_layout(riding)
+            fn, self._pc_stats = shared_build(
                 self, ("megakernel-exec",) + key,
-                lambda: jax.jit(
-                    self._build_raw(fuel, stage_all_values=stage_all_values)
-                ),
+                lambda: self._build_exec(fuel, stage_all_values, lay),
             )
-        jitted = self._jitted[key]
+            self._jitted[key] = fn, lay
+        jitted, lay = self._jitted[key]
         import contextlib
 
         # An interpret-mode kernel is plain JAX ops plus host callbacks.
@@ -2548,41 +2713,37 @@ class Megakernel:
         )
         import time as _time
 
-        with span("mk.upload"):
-            args = [
-                jnp.asarray(tasks),
-                jnp.asarray(succ),
-                jnp.asarray(ring),
-                jnp.asarray(counts),
-                jnp.asarray(ivalues),
-                *[jnp.asarray(data[k]) for k in self.data_specs.keys()],
-            ]
-            if self.checkpoint:
-                args.append(jnp.asarray(self.quiesce_words(quiesce)))
-        # Epoch bracket for the flight recorder (the clockprobe trick):
-        # monotonic_ns before launch and after readback are the host wall
-        # clock the trace's round-indexed records interpolate into - the
-        # same clock runtime/instrument.py stamps host events with, so
-        # device rounds and host spans share one Perfetto timeline.
-        t0_ns = _time.monotonic_ns()
-        with span("mk.launch"), cm:
-            outs = jitted(*args)
-        ndata = len(self.data_specs)
-        tasks_out, ready_out, counts_out, ivalues_out = outs[:4]
-        data_out = dict(zip(self.data_specs.keys(), outs[4 : 4 + ndata]))
-        packs = [counts_out, ivalues_out]
-        off_out = 4 + ndata
-        if self.batch_specs:
-            packs.append(outs[off_out])
-            off_out += 1
-        if self.checkpoint:
-            packs.append(outs[off_out])
-            off_out += 1
-        if self.trace is not None:
-            packs.append(outs[off_out])
+        with cm:
+            with span("mk.upload"):
+                slab = _join(host, lay.up)
+                args = [jnp.asarray(slab)] + [
+                    d if isinstance(d, jax.Array) else jnp.asarray(d)
+                    for d in alone.values()
+                ]
+            # Epoch bracket for the flight recorder (the clockprobe
+            # trick): monotonic_ns before launch and after readback are
+            # the host wall clock the trace's round-indexed records
+            # interpolate into - the same clock runtime/instrument.py
+            # stamps host events with, so device rounds and host spans
+            # share one Perfetto timeline.
+            t0_ns = _time.monotonic_ns()
+            with span("mk.launch"):
+                packed_dev, tasks_out, ready_out, *rest = jitted(*args)
+                # The host's copy of what it reads starts behind the
+                # launch; mk.wait only waits for it.
+                packed_dev.copy_to_host_async()
+        data_out = dict(zip(self.data_specs, rest))  # lay.stays' order
         with span("mk.wait"):  # the kernel runs inside this span
-            packed = np.asarray(self._packer(*packs))
+            packed = np.asarray(packed_dev)
         t1_ns = _time.monotonic_ns()
+        staging = {
+            "uploads": 1 + sum(
+                not isinstance(d, jax.Array) for d in alone.values()
+            ),
+            "slab_words": int(slab.size),
+            "slab_blocks": list(lay.up),
+            "downloads": 1,
+        }
         if first_build and self._pc_stats is not None:
             if not self._pc_stats["hit"]:
                 # jax.jit is lazy: the trace/lower/compile this cache
@@ -2599,7 +2760,8 @@ class Megakernel:
             "allocated": int(counts_np[C_ALLOC]),
             "value_alloc": int(counts_np[C_VALLOC]),
             "overflow": bool(counts_np[C_OVERFLOW]),
-            **ran_on(counts_out, self.interpret),
+            "staging": staging,
+            **ran_on(packed_dev, self.interpret),
         }
         if self._pc_stats is not None:
             # How this run's program was obtained (the build that
@@ -2670,13 +2832,17 @@ class Megakernel:
             # CheckpointBundle) needs to relaunch mid-graph. succ is
             # input-only (never mutated on device), so the input array IS
             # its live value.
+            staging["downloads"] += 1
+            tasks_np, ready_np, data_np = jax.device_get(
+                (tasks_out, ready_out, data_out)
+            )
             info["state"] = {
-                "tasks": np.asarray(tasks_out),
+                "tasks": tasks_np,
                 "succ": np.asarray(succ),
-                "ready": np.asarray(ready_out),
+                "ready": ready_np,
                 "counts": counts_np.copy(),
                 "ivalues": ivalues_np.copy(),
-                "data": {k: np.asarray(v) for k, v in data_out.items()},
+                "data": data_np,
             }
         self._last_info = info
         if info["overflow"]:

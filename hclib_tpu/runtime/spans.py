@@ -25,10 +25,11 @@ STAGES = (
     "chol.download",  # the un-tiling / tril jit and np.asarray of L
     # Megakernel.run / resume (device/megakernel.py), one of each a call
     "mk.finalize",  # run() only: builder.finalize, default ivalues, checks
-    "mk.upload",  # jnp.asarray of every argument (and the quiesce words)
+    "mk.upload",  # the slab joined and sent, and the buffers that cross
+                  # alone
     "mk.launch",  # the jitted program called, until the call returns
-    "mk.wait",  # the packer and np.asarray: blocked on the kernel, then
-                # the packed counters down; the kernel runs inside it
+    "mk.wait",  # blocked on the kernel, then the packed counters, whose
+                # copy started behind the launch; the kernel runs inside
     # execute_partitions (device/sharded.py), one of each a mesh run
     "mesh.partition",  # partition_builders and the per-device staging
     "mesh.upload",  # the sharded device_puts

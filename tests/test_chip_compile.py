@@ -63,20 +63,22 @@ def _shapes(arrays, sharding):
     )
 
 
-def _compile_mk(mk, sharding, fuel=1 << 22, builder=None):
-    """Compile ``Megakernel.run``'s program: the bare pallas_call over the
-    shapes ``run`` would stage (as __graft_entry__.entry builds them)."""
-    from hclib_tpu.device.descriptor import TaskGraphBuilder
+def _compile_mk(mk, sharding, fuel=1 << 22):
+    """Compile ``Megakernel.run``'s program: the kernel inside the wrapper
+    that splits the upload slab and packs what the host reads, laid out
+    as ``run`` lays it out for a caller whose buffers are all on the host
+    (the small int32 ones ride the slab, the others cross alone)."""
+    from hclib_tpu.device.megakernel import SLAB_RIDE_BYTES
 
     assert mk.interpret is False
-    tasks, succ, ring, counts = (builder or TaskGraphBuilder()).finalize(
-        capacity=mk.capacity, succ_capacity=mk.succ_capacity
-    )
-    args = [tasks, succ, ring, counts, np.zeros(mk.num_values, np.int32)]
-    args += list(mk.data_specs.values())
-    if mk.checkpoint:
-        args.append(np.zeros(8, np.int32))
-    return jax.jit(mk._build_raw(fuel)).lower(
+    lay = mk._exec_layout([
+        "data:" + k for k, s in mk.data_specs.items()
+        if s.dtype == jnp.int32 and 4 * np.prod(s.shape) < SLAB_RIDE_BYTES
+    ])
+    words = sum(int(np.prod(s)) for s in lay.up.values())
+    args = [jax.ShapeDtypeStruct((words,), jnp.int32)]
+    args += [mk.data_specs[n[5:]] for n in lay.alone]
+    return mk._build_exec(fuel, False, lay).lower(
         *_shapes(args, sharding)
     ).compile()
 
@@ -143,15 +145,13 @@ def _uts_t1l(sh):
 
 
 def _cholesky_8192(sh):
-    from hclib_tpu.device.cholesky import (
-        build_cholesky_graph, make_cholesky_megakernel,
-    )
+    from hclib_tpu.device.cholesky import make_cholesky_megakernel
 
     nt = 8192 // 512
     mk = make_cholesky_megakernel(
         nt, interpret=False, tile=512, fused_only=True
     )
-    _compile_mk(mk, sh, builder=build_cholesky_graph(nt))
+    _compile_mk(mk, sh)
 
 
 def _sw_fused(sh):
@@ -162,13 +162,11 @@ def _sw_fused(sh):
 
 
 def _sw_wave(sh):
-    from hclib_tpu.device.smithwaterman import (
-        T, build_sw_wave_graph, make_sw_wave_megakernel,
-    )
+    from hclib_tpu.device.smithwaterman import T, make_sw_wave_megakernel
 
     nt = 8192 // T
     mk = make_sw_wave_megakernel(nt, nt, interpret=False, with_h=False)
-    _compile_mk(mk, sh, builder=build_sw_wave_graph(nt, nt))
+    _compile_mk(mk, sh)
 
 
 def _forasync_1d(sh):
