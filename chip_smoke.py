@@ -262,6 +262,28 @@ def phase_forasync(dev: dict, seed: int) -> None:
          tile=list(tile), tiles=tiles, equal_to_numpy=True,
          compile_s=compile_s, run_s=run_s, **ran_compiled(info))
 
+    # The same loop with its tiles made on the device: one range
+    # descriptor, 63 splits on the scalar tier, the same lane and body.
+    def loop2d_recursive():
+        return hc.forasync(
+            tk, bounds, tile=tile, mode=hc.RECURSIVE, place="device",
+            width=8, interpret=False,
+            data={"gin": gin, "gout": gout.copy()},
+        )
+
+    (data, info), compile_s, run_s = twice(loop2d_recursive)
+    assert np.array_equal(np.asarray(data["gout"]), want)
+    assert info["executed"] == 2 * tiles - 1, info["executed"]
+    fa, tiers = info["forasync"], info["tiers"]
+    assert (tiers["batch_tasks"], tiers["scalar_tasks"]) == (
+        tiles, tiles - 1), tiers
+    assert fa["live_rows_max"] < fa["capacity"] <= tiles, fa
+    emit("forasync", dev, loop="2d-stencil-recursive", interior=[H, W],
+         tile=list(tile), tiles=tiles, splits=fa["splits"],
+         live_rows_max=fa["live_rows_max"], capacity=fa["capacity"],
+         equal_to_numpy=True, compile_s=compile_s, run_s=run_s,
+         **ran_compiled(info))
+
 
 def phase_serve(dev: dict, seed: int) -> None:
     from hclib_tpu.device.descriptor import TaskGraphBuilder

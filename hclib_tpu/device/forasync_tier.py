@@ -42,16 +42,33 @@ writes its executed tiles into its own output copy, and the host sums
 the per-device outputs (each tile executes exactly once mesh-wide, and
 output buffers are required to start zero).
 
-Device-path constraints (both explicit ``ValueError``\\ s):
+Two modes, the reference's two (src/hclib.c:158-416). FLAT stages one
+descriptor a tile on the host, so the task table holds every tile.
+RECURSIVE stages ONE range descriptor and makes the tiles on the device:
+a split kind on the scalar tier halves the widest dimension that is
+still more than one tile long, at a tile boundary (the one nearest the
+midpoint, so every piece is whole tiles and a power-of-two tile count
+splits exactly where the reference does), and spawns its two halves
+until a half is one tile - the same ``[flat, lo0, lo1, lo2]`` descriptor
+FLAT stages, through the same batch lane and the same body. The lane
+fires as soon as it holds two batches (``BatchSpec.fire_at``), so the
+splitter is paced by the tiles' consumer and the table holds the LIVE
+set - two batches of tiles and a split a level of the recursion - however
+many tiles the loop has (``info["forasync"]["live_rows_max"]``).
+
+Device-path constraints (explicit ``ValueError``\\ s):
 
 - bounds must divide exactly by the tile (slab shapes are static; the
-  reference's ragged last tile would need dynamic DMA sizes), and
-- ``mode=FLAT`` only (recursive splitting produces unaligned piece
-  shapes; RECURSIVE remains a host-tier mode).
+  reference's ragged last tile would need dynamic DMA sizes);
+- a FLAT loop needs a table row a tile: one with more tiles than the
+  table takes is refused, naming RECURSIVE;
+- RECURSIVE runs on one device (a placement seeds per-device rings from
+  flat tiles).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -62,9 +79,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..runtime.env import env_int
+from ..runtime.forasync import FLAT, RECURSIVE
 from ..runtime.locality import MeshPlacement, resolve_placement
+from ..runtime.spans import span
 from .descriptor import TaskGraphBuilder
-from .megakernel import BatchSpec, Megakernel, _batch_stub
+from .megakernel import BatchSpec, Megakernel, SmemError, _batch_stub
 
 __all__ = [
     "Slab",
@@ -75,12 +94,16 @@ __all__ = [
     "place_tiles",
     "make_forasync_megakernel",
     "run_forasync_device",
+    "seed_root",
+    "split_plan",
     "FA_TILE",
+    "FA_SPLIT",
 ]
 
-# The tile kernel's table index: the tier builds single-kind megakernels
-# (one loop body per kernel), so the id is fixed.
+# The tile kernel's table index: the tier builds one loop body per
+# kernel, so the id is fixed. A RECURSIVE build adds the split kind.
 FA_TILE = 0
+FA_SPLIT = 1
 
 
 # ------------------------------------------------------------- tiling math
@@ -185,6 +208,108 @@ def place_tiles(
         builders[d].add(fn, args=tile_args(dims, tile_dims, counts, flat))
         out[d] += 1
     return out
+
+
+# ------------------------------------------------------ recursive split
+
+
+def _widest(extents: Sequence[int], tile_dims: Sequence[int]) -> int:
+    """The dimension a piece of ``extents`` tiles splits: the longest (in
+    loop indices) that is more than one tile, the first of equals, as
+    runtime/forasync.py's ``_spawn_recursive`` picks it; -1 for a tile."""
+    wdim, widest = -1, 0
+    for d, (n, t) in enumerate(zip(extents, tile_dims)):
+        if n > 1 and n * t > widest:
+            wdim, widest = d, n * t
+    return wdim
+
+
+def split_plan(bounds: Sequence, tile: Sequence) -> Dict[str, int]:
+    """What RECURSIVE mode does to ``(bounds, tile)``, on the host:
+    ``tiles``, ``splits`` (a binary tree's inner nodes: tiles - 1) and
+    ``depth`` (splits from the root to the deepest tile). The ring pops
+    newest first, so the live set is one pending half a level of that
+    depth, the piece in hand with its two halves, and what the lane
+    holds."""
+    _, tile_dims, counts, total = tile_grid(bounds, tile)
+
+    def depth(ext) -> int:
+        d = _widest(ext, tile_dims)
+        if d < 0:
+            return 0
+        big = list(ext)
+        big[d] = ext[d] - ext[d] // 2
+        return 1 + depth(big)
+
+    return {"tiles": total, "splits": total - 1, "depth": depth(counts)}
+
+
+def _split_kernel(dims, tile_dims, counts) -> Callable:
+    """The split kind of one tile space. A range descriptor's args are
+    its piece in TILE units, ``[lo0, hi0, lo1, hi1, lo2, hi2]`` (unused
+    dimensions ``[0, 1]``); each half goes back on the ring as a range,
+    or - one tile long everywhere - as that tile's FLAT descriptor."""
+    nd = len(dims)
+    los = [lo for lo, _ in dims] + [0] * (3 - nd)
+    tds = list(tile_dims) + [1] * (3 - nd)
+    cts = list(counts) + [1] * (3 - nd)
+
+    def kernel(ctx) -> None:
+        lo = [ctx.arg(2 * d) for d in range(3)]
+        hi = [ctx.arg(2 * d + 1) for d in range(3)]
+        n = [h - l for l, h in zip(lo, hi)]
+        # The widest over-tile dimension, the first of equals.
+        wdim, widest = jnp.int32(0), jnp.int32(0)
+        for d in range(nd):
+            ext = jnp.where(n[d] > 1, n[d] * tds[d], 0)
+            wdim = jnp.where(ext > widest, d, wdim)
+            widest = jnp.maximum(ext, widest)
+        mid = [
+            jnp.where(wdim == d, lo[d] + n[d] // 2, hi[d])
+            for d in range(3)
+        ]
+        # The upper half first: the ring pops newest first, so the lower
+        # half splits next and tiles reach the lane in ascending order
+        # along every dimension.
+        for upper in (True, False):
+            plo = [
+                jnp.where((wdim == d) & upper, mid[d], lo[d])
+                for d in range(3)
+            ]
+            phi = [
+                jnp.where((wdim == d) & jnp.logical_not(upper), mid[d],
+                          hi[d])
+                for d in range(3)
+            ]
+            leaf = functools.reduce(
+                jnp.logical_and, [h - l == 1 for l, h in zip(plo, phi)]
+            )
+            flat = (plo[0] * cts[1] + plo[1]) * cts[2] + plo[2]
+            tile = [flat] + [
+                los[d] + plo[d] * tds[d] if d < nd else 0
+                for d in range(3)
+            ]
+            rng = [plo[0], phi[0], plo[1], phi[1], plo[2], phi[2]]
+            ctx.spawn(
+                jnp.where(leaf, FA_TILE, FA_SPLIT),
+                [jnp.where(leaf, a, b)
+                 for a, b in zip(tile + [0, 0], rng)],
+            )
+
+    return kernel
+
+
+def seed_root(builder: TaskGraphBuilder, bounds: Sequence,
+              tile: Sequence) -> int:
+    """RECURSIVE's one host descriptor: the whole space as a range (or,
+    for a loop of one tile, that tile). Returns the tile count."""
+    dims, tile_dims, counts, total = tile_grid(bounds, tile)
+    if total == 1:
+        builder.add(FA_TILE, args=tile_args(dims, tile_dims, counts, 0))
+    else:
+        cts = list(counts) + [1] * (3 - len(counts))
+        builder.add(FA_SPLIT, args=[0, cts[0], 0, cts[1], 0, cts[2]])
+    return total
 
 
 # ---------------------------------------------------------- slab pipeline
@@ -406,28 +531,61 @@ class TileKernel:
 # ------------------------------------------------------------ megakernel
 
 
+# What the compiler grants a kernel's VMEM unasked on every supported
+# chip (the v5e's scoped default); a loop's slabs may ask for more.
+VMEM_DEFAULT_BYTES = 16 << 20
+
+
+def _vmem_bytes(tk: TileKernel, scratch: Dict[str, Any]) -> int:
+    """The kernel's VMEM limit, from its slabs: the scratch the tier
+    declared, and room for ``compute``'s values of one tile (a load slab
+    and the shifted views the body cuts from it, a store slab), which the
+    compiler keeps in VMEM too. Never under the default."""
+    held = sum(
+        math.prod(sp.shape) * jnp.dtype(sp.dtype).itemsize
+        for sp in scratch.values()
+        if getattr(sp, "memory_space", None) == pltpu.VMEM
+    )
+    one = sum(
+        math.prod(sl.shape) * jnp.dtype(tk._dtype(sl)).itemsize
+        for sl in tk.loads + tk.stores
+    )
+    return max(VMEM_DEFAULT_BYTES, held + 4 * one + (4 << 20))
+
+
 def make_forasync_megakernel(
     tk: TileKernel,
     *,
     width: int = 0,
     prefetch: bool = True,
-    capacity: int = 256,
+    capacity: Optional[int] = None,
     interpret: Optional[bool] = None,
     trace=None,
     checkpoint: Optional[bool] = None,
     quiesce_stride: Optional[int] = None,
     verify: Optional[bool] = None,
+    space: Optional[Tuple[Sequence, Sequence]] = None,
 ) -> Megakernel:
     """Build the loop's megakernel. ``width=0`` is the scalar-dispatch
     arm (one tile per ``lax.switch`` round - the bit-identity reference);
     ``width>0`` routes the tile kind through the batch lanes, with the
-    double-buffered operand prefetch on by default."""
+    double-buffered operand prefetch on by default. ``space=(bounds,
+    tile)`` makes it a RECURSIVE build of that tile space: the split
+    kind beside the tile kind, a lane that fires at two batches, and by
+    default a table of the live set (``split_plan``: a pending half a
+    level, the piece in hand with its halves, the lane), at least 64
+    rows; a FLAT build's default is 256."""
+    if capacity is None:
+        capacity = 256 if space is None else max(
+            64, split_plan(*space)["depth"] + 2 * width + 8
+        )
     if width:
         spec = BatchSpec(
             tk.batch_body,
             width=width,
             prefetch=prefetch,
             drain=tk.batch_drain if prefetch else None,
+            fire_at=2 * width if space is not None else None,
         )
         kernels = [(tk.name, _batch_stub)]
         route = {tk.name: spec}
@@ -436,6 +594,9 @@ def make_forasync_megakernel(
         kernels = [(tk.name, tk.scalar_kernel)]
         route = None
         scratch = tk.scalar_scratch()
+    if space is not None:
+        dims, tile_dims, counts, _ = tile_grid(*space)
+        kernels.append(("fa_split", _split_kernel(dims, tile_dims, counts)))
     mk = Megakernel(
         kernels=kernels,
         route=route,
@@ -445,11 +606,16 @@ def make_forasync_megakernel(
         num_values=16,
         succ_capacity=8,
         interpret=interpret,
+        vmem_limit_bytes=_vmem_bytes(tk, scratch),
         trace=trace,
         checkpoint=checkpoint,
         quiesce_stride=quiesce_stride,
         verify=verify,
+        read_only=[k for k in tk.data_specs if k not in tk.out_names],
     )
+    # The tile space a RECURSIVE build splits, as tile_grid normalises
+    # it; None for a FLAT build, which takes any.
+    mk.fa_space = None if space is None else (dims, tile_dims)
     # Schedule-independence claim: tiles write disjoint slabs, so any
     # pop order yields one output state. The tile SPACE isn't known
     # until a run names (bounds, tile) - run_forasync_device completes
@@ -496,9 +662,23 @@ def run_forasync_device(
     trace=None,
     fuel: int = 1 << 22,
     mk: Optional[Megakernel] = None,
+    mode: str = FLAT,
 ) -> Tuple[Dict[str, np.ndarray], Dict]:
     """Run one forasync tile loop on the device tier to completion;
     returns ``(data_out, info)``.
+
+    ``mode=FLAT`` stages a descriptor a tile; ``mode=RECURSIVE`` stages
+    the one range and lets the device split it (module docstring), so the
+    default table is sized to the live set, not to the tile count.
+    ``info["forasync"]`` (and the kernel's ``stats_dict()``) says which:
+    ``mode``, ``tiles``, ``splits``, ``capacity`` and ``live_rows_max``,
+    the table's high-water mark by the kernel's own ``allocated``.
+
+    A ``data`` buffer may be a numpy array (uploaded; the caller's array
+    is untouched) or a ``jax.Array`` that stays on the chip: one the
+    ``TileKernel`` only loads is read where it lies and is still the
+    caller's, one it stores to (``tk.out_names``) is consumed and comes
+    back in ``data_out`` (``Megakernel.run``'s ownership rule).
 
     Single device when ``placement`` is None. With a placement (and an
     optional ``mesh``; defaults to a CPU mesh sized by the placement),
@@ -510,7 +690,18 @@ def run_forasync_device(
     ``info['placement_counts']`` carries the seeded per-device counts."""
     w = _default_width() if width is None else int(width)
     dims, tile_dims, tcounts, total = tile_grid(bounds, tile)
-    cap = capacity or max(64, total + 8)
+    if set(data) != set(tk.data_specs):
+        raise ValueError(
+            f"data buffers {sorted(data)} != declared {sorted(tk.data_specs)}"
+        )
+    recursive = mode == RECURSIVE
+    if recursive and placement is not None:
+        raise ValueError(
+            "mode=RECURSIVE runs on one device: a placement seeds the "
+            "per-device rings from flat tiles (use mode=FLAT on a mesh)"
+        )
+    # A RECURSIVE table is sized by the build, to the live set.
+    cap = capacity or (None if recursive else max(64, total + 8))
     if mk is not None and getattr(mk, "verify", False) or (
         mk is None and _verify_default()
     ):
@@ -540,11 +731,34 @@ def run_forasync_device(
                 f"(its tile kind is "
                 f"{f'batch-routed at width {mk_width}' if mk_width else 'scalar-dispatched'})"
             )
+        want = (dims, tile_dims) if recursive else None
+        if getattr(mk, "fa_space", None) != want:
+            raise ValueError(
+                f"mode={mode!r} over {want} disagrees with the prebuilt "
+                f"megakernel, built for {getattr(mk, 'fa_space', None)} "
+                "(make_forasync_megakernel's space=; None is a FLAT build)"
+            )
         kernel = mk
     else:
-        kernel = make_forasync_megakernel(
-            tk, width=w, prefetch=prefetch, capacity=cap,
-            interpret=interpret, trace=trace,
+        try:
+            kernel = make_forasync_megakernel(
+                tk, width=w, prefetch=prefetch, capacity=cap,
+                interpret=interpret, trace=trace,
+                space=(bounds, tile) if recursive else None,
+            )
+        except SmemError as e:
+            if recursive:
+                raise
+            raise ValueError(
+                f"mode=FLAT stages one descriptor a tile and this loop has "
+                f"{total}: {e} mode=RECURSIVE makes the tiles on the device "
+                "and needs a table of its live set only."
+            ) from e
+    if placement is None and not recursive and total > kernel.capacity:
+        raise ValueError(
+            f"mode=FLAT stages one descriptor a tile: {total} tiles do not "
+            f"fit a table of {kernel.capacity} rows. mode=RECURSIVE makes "
+            "the tiles on the device and needs a table of its live set only."
         )
     # Complete the schedule-independence claim with the concrete tile
     # space this run names (make_forasync_megakernel stamps it
@@ -561,9 +775,20 @@ def run_forasync_device(
         if (claim[2], claim[3]) != (nb, nt):
             kernel.si_claim = ("tile", claim[1], nb, nt)
     if placement is None:
-        b = TaskGraphBuilder()
-        seed_tiles(b, bounds, tile)
-        _, data_out, info = kernel.run(b, data=dict(data), fuel=fuel)
+        with span("fa.seed"):
+            b = TaskGraphBuilder()
+            (seed_root if recursive else seed_tiles)(b, bounds, tile)
+        with span("fa.run"):
+            _, data_out, info = kernel.run(b, data=dict(data), fuel=fuel)
+        # info is the dict stats_dict() copies: the loop's own counters
+        # ride both.
+        info["forasync"] = {
+            "mode": mode,
+            "tiles": total,
+            "splits": total - 1 if recursive else 0,
+            "capacity": kernel.capacity,
+            "live_rows_max": info["allocated"],
+        }
         return data_out, info
 
     p = resolve_placement(placement)

@@ -72,7 +72,7 @@ __all__ = [
     "KernelContext", "BatchContext", "BatchSpec", "Megakernel", "VBLOCK",
     "decode_overflow", "interpret_mode", "fault_mix",
     "DEVICE_TABLE", "device_row", "device_record", "require_tpu",
-    "resolve_interpret", "ran_on", "smem_bytes",
+    "resolve_interpret", "ran_on", "smem_bytes", "SmemError",
 ]
 
 # Facts of each supported chip, keyed by ``jax.Device.device_kind``. Peaks
@@ -192,6 +192,7 @@ class _ExecLayout(NamedTuple):
     alone: List[str]
     down: Dict[str, Tuple[int, ...]]
     stays: List[str]
+    donated: Tuple[int, ...]
 
 
 def ran_on(out, interpret: bool) -> Dict[str, Any]:
@@ -211,6 +212,11 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return bool(interpret)
+
+
+class SmemError(ValueError):
+    """A build whose scheduler state does not fit the chip's SMEM
+    (``Megakernel.check_smem``)."""
 
 
 def smem_bytes(shape: Sequence[int]) -> int:
@@ -755,13 +761,27 @@ class BatchSpec:
     in-flight prefetch of ``ctx.prefetched`` descriptors - the scheduler
     calls it before spilling unrun lane entries at exit so no DMA outlives
     its consumer.
+
+    ``fire_at=N`` (N >= width) is for a kind whose descriptors are MADE on
+    the device by scalar tasks that keep the ready ring hot (forasync's
+    RECURSIVE splitter): the lane fires as soon as it holds N entries,
+    whatever the ring holds, instead of only at a drained ring - the
+    producer is paced by its consumer, so the live set stays N rows plus
+    the producer's own depth however many descriptors the loop makes.
+    ``2 * width`` fires full batches with a full batch queued behind each
+    for the prefetch. None compiles nothing: ring-drain-first, as before.
     """
 
     def __init__(self, body, width: int = 8, prefetch: bool = False,
                  drain=None, priority=None,
-                 verify_suppress: Sequence[str] = ()) -> None:
+                 verify_suppress: Sequence[str] = (),
+                 fire_at: Optional[int] = None) -> None:
         if width < 1:
             raise ValueError(f"batch width must be >= 1, got {width}")
+        if fire_at is not None and fire_at < width:
+            raise ValueError(
+                f"fire_at must be >= width ({width}), got {fire_at}"
+            )
         if prefetch and drain is None:
             raise ValueError(
                 "prefetch=True requires a drain(ctx) callback: the "
@@ -778,6 +798,7 @@ class BatchSpec:
         self.prefetch = bool(prefetch)
         self.drain = drain
         self.priority = priority
+        self.fire_at = None if fire_at is None else int(fire_at)
         # Per-rule opt-outs for the build-time verifier (hclib_tpu.
         # analysis): a spec whose body DELIBERATELY violates a checked
         # contract (e.g. intentionally-shared value slots) annotates the
@@ -996,6 +1017,7 @@ class Megakernel:
         priority_buckets: Optional[int] = None,
         verify: Optional[bool] = None,
         verify_suppress: Sequence[str] = (),
+        read_only: Sequence[str] = (),
     ) -> None:
         interpret = resolve_interpret(interpret)
         # Device flight recorder (device/tracebuf.py): ``trace`` is None
@@ -1087,6 +1109,14 @@ class Megakernel:
                 f"(the static bucket-ring set), got {priority_buckets}"
             )
         self.priority_buckets = priority_buckets
+        if priority_buckets and any(
+            _is_batch_spec(s) and s.fire_at for s in (route or {}).values()
+        ):
+            raise ValueError(
+                "BatchSpec(fire_at=) has no spelling under priority_buckets: "
+                "a bucketed kind fires its lowest non-empty bucket at a "
+                "drained ring"
+            )
         # Dispatch-tier routing: ``route`` maps a kernel NAME to the spec
         # of a non-scalar dispatch tier for that task family. Two tiers:
         #
@@ -1148,6 +1178,20 @@ class Megakernel:
             for i, (_, fn) in enumerate(routed)
         ]
         self.data_specs = dict(data_specs or {})
+        # Data buffers no kernel of this table writes: ``run`` / ``resume``
+        # hand them to the kernel as plain inputs, read where they lie
+        # (no alias, no output, no copy), and the caller keeps them. Every
+        # other buffer is an output: aliased in and out, and donated
+        # where the caller handed it in on the device (the ownership rule
+        # in ``run``'s docstring). The embedders that wrap
+        # ``_build_raw`` themselves (sharded, resident) alias every buffer
+        # as before.
+        self.read_only = tuple(k for k in self.data_specs if k in read_only)
+        if set(read_only) - set(self.data_specs):
+            raise ValueError(
+                f"read_only names undeclared buffers: "
+                f"{sorted(set(read_only) - set(self.data_specs))}"
+            )
         self.scratch_specs = dict(scratch_specs or {})
         self.capacity = capacity
         self.num_values = num_values
@@ -1250,7 +1294,7 @@ class Megakernel:
                 lo = mid
             else:
                 hi = mid - 1
-        raise ValueError(
+        raise SmemError(
             f"Megakernel(capacity={self.capacity}) needs {need} B of SMEM "
             f"and the chip offers {budget} B: every [capacity, {DESC_WORDS}] "
             f"task-table row pads to 128 lanes (512 B) in an input and an "
@@ -1881,6 +1925,13 @@ class Megakernel:
                                 eligible = (
                                     avails[base] > 0
                                 ) & jnp.logical_not(ring_work)
+                                if spec.fire_at:
+                                    # A lane its producer filled fires
+                                    # over a hot ring (BatchSpec.fire_at).
+                                    eligible = eligible | (
+                                        avails[base]
+                                        >= jnp.int32(spec.fire_at)
+                                    )
                         elif phase == "starved":
                             # Lowest-bucket starved ring of this kind
                             # (deterministic; any starved ring fires
@@ -2199,19 +2250,21 @@ class Megakernel:
 
     def _kernel(
         self, fuel: int, reps: int, stage_all_values: bool, trace, ckpt,
-        qstride, *refs
+        qstride, inputs, *refs
     ) -> None:
-        # ``trace``/``ckpt``/``qstride`` are the TraceRing / checkpoint
-        # flag / quiesce poll stride captured when _build_raw fixed the
-        # output tree - NOT self.trace: pallas kernels trace lazily
-        # (first call), so reading mutable instance state here could
-        # disagree with the already-built out_shape and shift every ref
-        # slice.
+        # ``trace``/``ckpt``/``qstride``/``inputs`` are the TraceRing /
+        # checkpoint flag / quiesce poll stride / input-only data buffers
+        # captured when _build_raw fixed the output tree - NOT self.trace:
+        # pallas kernels trace lazily (first call), so reading mutable
+        # instance state here could disagree with the already-built
+        # out_shape and shift every ref slice.
         ndata = len(self.data_specs)
+        written = [k for k in self.data_specs if k not in inputs]
         nbatch = len(self.batch_specs)
         ntrace = 1 if trace is not None else 0
         n_in = 5 + ndata + (1 if ckpt else 0)  # qctl rides last
-        n_out = 4 + ndata + (1 if nbatch else 0) + (1 if ckpt else 0) + ntrace
+        n_out = (4 + len(written) + (1 if nbatch else 0)
+                 + (1 if ckpt else 0) + ntrace)
         in_refs = refs[:n_in]
         out_refs = refs[n_in : n_in + n_out]
         tail = list(refs[n_in + n_out :])
@@ -2227,11 +2280,13 @@ class Megakernel:
         tasks_in, succ, ready_in, counts_in, ivalues_in = in_refs[:5]
         qctl = in_refs[5 + ndata] if ckpt else None
         tasks, ready, counts, ivalues = out_refs[:4]
-        data = dict(zip(self.data_specs.keys(), out_refs[4 : 4 + ndata]))
-        tstats = out_refs[4 + ndata] if nbatch else None
-        qstat = (
-            out_refs[4 + ndata + (1 if nbatch else 0)] if ckpt else None
-        )
+        # An input-only buffer is its input ref; a written one its
+        # (aliased) output window.
+        data = dict(zip(self.data_specs, in_refs[5 : 5 + ndata]))
+        data.update(zip(written, out_refs[4 : 4 + len(written)]))
+        after = 4 + len(written)  # the appended outputs start here
+        tstats = out_refs[after] if nbatch else None
+        qstat = out_refs[after + (1 if nbatch else 0)] if ckpt else None
         tracer = (
             Tracer(out_refs[n_out - 1], trace.capacity)
             if ntrace
@@ -2341,12 +2396,16 @@ class Megakernel:
             )
 
     def _build_raw(
-        self, fuel: int, reps: int = 1, stage_all_values: bool = False
+        self, fuel: int, reps: int = 1, stage_all_values: bool = False,
+        inputs: Sequence[str] = (),
     ):
         """The bare pallas_call (for embedding under shard_map; re-entrant
         callers must pass stage_all_values=True so value slots above
-        value_alloc survive between entries)."""
+        value_alloc survive between entries). The data buffers named in
+        ``inputs`` are arguments only: no output, no alias (``_build_exec``
+        passes ``read_only``; the embedders alias every buffer)."""
         ndata = len(self.data_specs)
+        written = [k for k in self.data_specs if k not in inputs]
         nbatch = len(self.batch_specs)
         ckpt = self.checkpoint
         smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
@@ -2360,7 +2419,7 @@ class Megakernel:
         )
         out_specs = tuple(
             [smem(), smem(), smem(), smem()]
-            + [anyspace() for _ in range(ndata)]
+            + [anyspace() for _ in written]
             # Batched-tier counters ride out as one extra SMEM word row
             # APPENDED after the data outputs, so every existing consumer's
             # positional indexing is untouched.
@@ -2372,7 +2431,8 @@ class Megakernel:
             + ([smem()] if self.trace is not None else [])
         )
         data_shapes = [
-            jax.ShapeDtypeStruct(s.shape, s.dtype) for s in self.data_specs.values()
+            jax.ShapeDtypeStruct(s.shape, s.dtype)
+            for s in map(self.data_specs.get, written)
         ]
         out_shape = tuple(
             [
@@ -2389,12 +2449,12 @@ class Megakernel:
         # inputs: tasks(0) succ(1) ready(2) counts(3) ivalues(4) data(5..)
         # outputs: tasks(0) ready(1) counts(2) ivalues(3) data(4..) [tstats]
         aliases = {0: 0, 2: 1, 3: 2, 4: 3}
-        for i in range(ndata):
-            aliases[5 + i] = 4 + i
+        for o, k in enumerate(written):
+            aliases[5 + list(self.data_specs).index(k)] = 4 + o
         return pl.pallas_call(
             functools.partial(
                 self._kernel, fuel, reps, stage_all_values, self.trace,
-                ckpt, self.quiesce_stride,
+                ckpt, self.quiesce_stride, tuple(inputs),
             ),
             out_shape=out_shape,
             in_specs=in_specs,
@@ -2425,7 +2485,8 @@ class Megakernel:
             ),
         )
 
-    def _exec_layout(self, riding: Sequence[str] = ()) -> "_ExecLayout":
+    def _exec_layout(self, riding: Sequence[str] = (),
+                     given: Sequence[str] = ()) -> "_ExecLayout":
         """How each block of ``_build_raw``'s signature crosses in a
         ``run`` / ``resume`` (``ins`` / ``outs`` name its arguments and
         results in order; a data buffer ``k`` is ``data:k`` both sides):
@@ -2437,10 +2498,18 @@ class Megakernel:
         - ``down``: name -> shape of what the host reads after every
           run (counts, the values, and the tier, quiesce and trace rows
           of the builds that have them); comes back as ONE array.
-        - ``stays``: the outputs that stay on the chip."""
+        - ``stays``: what the program returns that stays on the chip:
+          the table, the ring, every written data buffer, and a
+          ``read_only`` one that rode the slab (its block of it).
+        - ``donated``: the program's argument numbers it consumes: the
+          written data buffers named in ``given``, the ones the caller
+          handed in as ``jax.Array``s."""
         data = ["data:" + k for k in self.data_specs]
+        kept = ["data:" + k for k in self.read_only]
         ins = ["tasks", "succ", "ready", "counts", "ivalues"] + data
-        outs = ["tasks", "ready", "counts", "ivalues"] + data
+        outs = ["tasks", "ready", "counts", "ivalues"] + [
+            n for n in data if n not in kept
+        ]
         up = {
             "tasks": (self.capacity, DESC_WORDS),
             "succ": (self.succ_capacity,),
@@ -2466,7 +2535,11 @@ class Megakernel:
         )
         alone = [n for n in ins if n not in up]
         return _ExecLayout(
-            ins, outs, up, alone, down, ["tasks", "ready"] + data
+            ins, outs, up, alone, down,
+            ["tasks", "ready"]
+            + [n for n in data if n not in kept or n in up],
+            tuple(1 + i for i, n in enumerate(alone)
+                  if n in given and n not in kept),
         )
 
     def _build_exec(self, fuel: int, stage_all_values: bool, lay):
@@ -2476,7 +2549,9 @@ class Megakernel:
         the one array the host reads, so a call is one transfer each way
         beside the buffers that cross alone. Called as
         ``program(slab, *alone)``; returns ``(packed, *stays)``."""
-        kernel = self._build_raw(fuel, stage_all_values=stage_all_values)
+        kernel = self._build_raw(
+            fuel, stage_all_values=stage_all_values, inputs=self.read_only
+        )
 
         # Named for the trace's sake: a Pallas kernel shows under the
         # outermost jit's name, and the benchmark's kernel metrics find
@@ -2485,12 +2560,17 @@ class Megakernel:
         def tpu_custom_call(slab, *alone):
             blocks = {**dict(zip(lay.alone, alone)), **_split(slab, lay.up)}
             res = dict(zip(lay.outs, kernel(*[blocks[n] for n in lay.ins])))
+            res = {**blocks, **res}  # a read-only block is its input
             return (
                 jnp.concatenate([res[n].reshape(-1) for n in lay.down]),
                 *[res[n] for n in lay.stays],
             )
 
-        return jax.jit(tpu_custom_call)
+        # Donated, a written buffer is updated where it lies; kept, XLA
+        # copies it whole in front of the kernel to honour the alias (and
+        # may keep a small one in faster memory meanwhile, which is why a
+        # buffer this call uploaded itself is left to XLA, as it was).
+        return jax.jit(tpu_custom_call, donate_argnums=lay.donated)
 
     def _build(self, fuel: int, reps: int = 1):
         from ..runtime.progcache import shared_build
@@ -2604,6 +2684,17 @@ class Megakernel:
         the chip as ``jax.Array``s until the caller reads them. The rule
         reads only the buffer (host or device, dtype, bytes); a layout
         seen for the first time builds its own program.
+
+        Who owns a ``data`` buffer that arrives as a ``jax.Array``
+        (``resume`` alike): a buffer the build declared ``read_only`` is
+        read where it lies, is still the caller's afterwards, and comes
+        back in the data dict as the same array. Every other one is
+        CONSUMED: it is donated to the program, the kernel writes it in
+        place (no whole-buffer copy on the way in), the array the caller
+        handed in is deleted, and its contents come back under the same
+        name in the data dict. A host (numpy) buffer is uploaded as
+        before, the caller's array never touched and the upload not
+        donated: host callers run the program they always ran.
         ``info['staging']`` counts it: ``uploads`` (the slab counted
         once), ``slab_words``, ``slab_blocks`` (the names that rode it,
         a data buffer ``k`` as ``data:k``), ``downloads`` (1; 2 where a
@@ -2679,9 +2770,13 @@ class Megakernel:
             "data:" + k for k in self.data_specs if "data:" + k not in alone
         )
         host.update((n, data[n[5:]]) for n in riding)
+        given = tuple(
+            n for n, d in alone.items()
+            if isinstance(d, jax.Array) and n[5:] not in self.read_only
+        )
         # A caller may hand the same buffer from the host once and from
         # the chip the next time: the layout is part of the program.
-        key = (fuel, bool(stage_all_values), riding)
+        key = (fuel, bool(stage_all_values), riding, given)
         first_build = key not in self._jitted
         if first_build:
             # Process-wide program cache (runtime/progcache.py): a
@@ -2692,7 +2787,7 @@ class Megakernel:
             # runs on one instance never pay fingerprinting).
             from ..runtime.progcache import shared_build
 
-            lay = self._exec_layout(riding)
+            lay = self._exec_layout(riding, given)
             fn, self._pc_stats = shared_build(
                 self, ("megakernel-exec",) + key,
                 lambda: self._build_exec(fuel, stage_all_values, lay),
@@ -2732,7 +2827,11 @@ class Megakernel:
                 # The host's copy of what it reads starts behind the
                 # launch; mk.wait only waits for it.
                 packed_dev.copy_to_host_async()
-        data_out = dict(zip(self.data_specs, rest))  # lay.stays' order
+        # A read-only buffer that crossed alone is the array that went
+        # in; every other one is what the program returned.
+        held = {**dict(zip(lay.alone, args[1:])),
+                **dict(zip(lay.stays[2:], rest))}
+        data_out = {k: held["data:" + k] for k in self.data_specs}
         with span("mk.wait"):  # the kernel runs inside this span
             packed = np.asarray(packed_dev)
         t1_ns = _time.monotonic_ns()

@@ -23,8 +23,12 @@ same-kind dispatch lanes instead of spawning host tasks
 (device/forasync_tier.py): the body is then a ``TileKernel`` slab pipeline,
 ``dist_func`` doubles as the mesh placement (dist-func callable or JSON
 placement descriptor resolved against ``locality_graphs/``), and the call
-returns ``(data_out, info)``. The device tier is FLAT-mode only and
-requires tiles that divide the bounds exactly (slab shapes are static).
+returns ``(data_out, info)``. Both modes run there: FLAT stages one
+descriptor a tile from the host; RECURSIVE stages one range descriptor and
+a split kind on the device halves it, at tile boundaries, down to the same
+tiles, so a loop of more tiles than the task table has rows still runs.
+The device tier requires tiles that divide the bounds exactly (slab shapes
+are static).
 """
 
 from __future__ import annotations
@@ -198,7 +202,8 @@ def forasync(
     ``place="device"`` runs the loop on the TPU megakernel's batch-lane
     tier instead (see module docstring): ``fn`` must be a
     ``device.forasync_tier.TileKernel``, ``tile`` is required,
-    ``dist_func`` doubles as the mesh placement, and extra keywords
+    ``dist_func`` doubles as the mesh placement, ``mode=RECURSIVE`` makes
+    the tiles on the device from one range descriptor, and extra keywords
     (``data=``, ``width=``, ``mesh=``, ...) forward to
     ``run_forasync_device``, whose ``(data_out, info)`` is returned.
     """
@@ -207,12 +212,6 @@ def forasync(
     if place not in (None, "host", "device"):
         raise ValueError(f"unknown forasync place {place!r}")
     if place == "device":
-        if mode != FLAT:
-            raise ValueError(
-                "place='device' supports mode=FLAT only: recursive "
-                "splitting produces unaligned piece shapes, and device "
-                "slab DMAs are static-shaped"
-            )
         if tile is None:
             raise ValueError(
                 "place='device' needs an explicit tile= (auto-tile is a "
@@ -227,7 +226,7 @@ def forasync(
         from ..device.forasync_tier import run_forasync_device
 
         return run_forasync_device(
-            fn, bounds, tile, placement=dist_func, **device_kw
+            fn, bounds, tile, placement=dist_func, mode=mode, **device_kw
         )
     if device_kw:
         raise TypeError(

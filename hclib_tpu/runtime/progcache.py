@@ -295,7 +295,7 @@ def megakernel_fingerprint(mk) -> str:
         bool(mk.interpret), bool(mk.uses_row_values),
         mk.vmem_limit_bytes, bool(mk.tracks_home),
         bool(mk.checkpoint), getattr(mk, "quiesce_stride", 1),
-        mk.lane_max_age, mk.priority_buckets,
+        mk.lane_max_age, mk.priority_buckets, mk.read_only,
     ))
     tr = mk.trace
     fp.add(None if tr is None

@@ -48,6 +48,9 @@ STAGES = (
     "sw.stage",  # sw_wave_buffers: the host buffers
     "sw.run",  # Megakernel.run: the four mk.* stages nest inside
     "sw.readback",  # _sw_result: the boundary buffers (and H) read
+    # run_forasync_device (device/forasync_tier.py), one of each a loop
+    "fa.seed",  # the builder and its root range (RECURSIVE) or its tiles
+    "fa.run",  # Megakernel.run: the four mk.* stages nest inside
     # uts_vec / uts_pallas (device/uts_vec.py, device/uts_pallas.py)
     "uts.seed",  # the whole seeding, until the padded roots are ready
     "uts.seed.host",  # inside it: a level in numpy, the roots' hand-over

@@ -63,24 +63,45 @@ def _shapes(arrays, sharding):
     )
 
 
-def _compile_mk(mk, sharding, fuel=1 << 22):
+def _compile_mk(mk, sharding, fuel=1 << 22, on_device=()):
     """Compile ``Megakernel.run``'s program: the kernel inside the wrapper
     that splits the upload slab and packs what the host reads, laid out
-    as ``run`` lays it out for a caller whose buffers are all on the host
-    (the small int32 ones ride the slab, the others cross alone)."""
+    as ``run`` lays it out for a caller whose buffers are on the host (the
+    small int32 ones ride the slab, the others cross alone) but for those
+    named in ``on_device``, which it hands in as ``jax.Array``s."""
     from hclib_tpu.device.megakernel import SLAB_RIDE_BYTES
 
     assert mk.interpret is False
     lay = mk._exec_layout([
         "data:" + k for k, s in mk.data_specs.items()
         if s.dtype == jnp.int32 and 4 * np.prod(s.shape) < SLAB_RIDE_BYTES
-    ])
+        and k not in on_device
+    ], ["data:" + k for k in on_device])
     words = sum(int(np.prod(s)) for s in lay.up.values())
     args = [jax.ShapeDtypeStruct((words,), jnp.int32)]
     args += [mk.data_specs[n[5:]] for n in lay.alone]
     return mk._build_exec(fuel, False, lay).lower(
         *_shapes(args, sharding)
     ).compile()
+
+
+def _whole_copies(compiled, mk):
+    """The compiled program's ``copy`` / ``copy-start`` instructions that
+    produce a whole data buffer of ``mk`` of a MiB or more: what XLA puts
+    in front of the kernel for an aliased buffer that was not donated."""
+    import re
+
+    big = {
+        "[" + ",".join(map(str, s.shape)) + "]"
+        for s in mk.data_specs.values()
+        if np.prod(s.shape) * jnp.dtype(s.dtype).itemsize >= 1 << 20
+    }
+    assert big
+    return [
+        line.strip()[:120] for line in compiled.as_text().splitlines()
+        if re.search(r"= \(?\w+(\[[\d,]*\])\S* copy(-start)?\(", line)
+        and re.search(r"= \(?\w+(\[[\d,]*\])", line).group(1) in big
+    ]
 
 
 # ---- one builder per kernel; each returns after the compiler accepted it
@@ -151,7 +172,12 @@ def _cholesky_8192(sh):
     mk = make_cholesky_megakernel(
         nt, interpret=False, tile=512, fused_only=True
     )
-    _compile_mk(mk, sh)
+    # device_cholesky hands its three buffers in on the device and they
+    # are written, so donated: no copy of one (the parent of PR 40 had
+    # two, 1.6 ms each on the chip). From the host, they are XLA's.
+    on_device = _compile_mk(mk, sh, on_device=list(mk.data_specs))
+    assert _whole_copies(on_device, mk) == []
+    assert len(_whole_copies(_compile_mk(mk, sh), mk)) == 2
 
 
 def _sw_fused(sh):
@@ -184,6 +210,31 @@ def _forasync_2d(sh):
     tk, _, _ = stencil_loop(64, 1024)
     _compile_mk(make_forasync_megakernel(tk, width=8, interpret=False), sh)
     _compile_mk(make_forasync_megakernel(tk, width=0, interpret=False), sh)
+
+
+def _forasync_hbm(sh):
+    """The cell forasync-2d-hbm's program (benchmarks/configs/
+    forasync-stencil.json): RECURSIVE over 32768 x 32768 in (256, 1024)
+    tiles at width 8, its 8.6 GB of grids on the chip. The table is the
+    live set's, the VMEM limit the slabs', ``gin`` an input only and
+    ``gout`` donated: no temporary and no copy of either."""
+    from hclib_tpu.device.forasync_tier import make_forasync_megakernel
+    from hclib_tpu.device.workloads import stencil_loop
+
+    tk, bounds, tile = stencil_loop(32768, 32768, 256, 1024)
+    mk = make_forasync_megakernel(tk, width=8, interpret=False,
+                                  space=(bounds, tile))
+    assert mk.capacity == 64 and mk.read_only == ("gin",)
+    assert 27 << 20 < mk.vmem_limit_bytes < 64 << 20
+    compiled = _compile_mk(mk, sh, on_device=["gin", "gout"])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * 32768 * 32768
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert _whole_copies(compiled, mk) == []
+    # chip_smoke's RECURSIVE arm
+    tk, bounds, tile = stencil_loop(64, 1024)
+    _compile_mk(make_forasync_megakernel(
+        tk, width=8, interpret=False, space=(bounds, tile)), sh)
 
 
 def _serve_stream(sh):
@@ -262,7 +313,8 @@ def _bnb(sh):
 KERNELS = {
     f.__name__.lstrip("_"): f
     for f in (_fib_scalar, _fib_batch, _uts_t1l, _cholesky_8192, _sw_fused,
-              _sw_wave, _forasync_1d, _forasync_2d, _serve_stream,
+              _sw_wave, _forasync_1d, _forasync_2d, _forasync_hbm,
+              _serve_stream,
               _frontier, _dyngraph, _bnb)
 }
 

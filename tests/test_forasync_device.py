@@ -17,11 +17,16 @@ from jax.experimental import pallas as pl
 import hclib_tpu as hc
 from hclib_tpu.device.descriptor import F_A0, TaskGraphBuilder
 from hclib_tpu.device.forasync_tier import (
+    FA_SPLIT,
     FA_TILE,
+    Slab,
+    TileKernel,
     make_forasync_megakernel,
     place_tiles,
     run_forasync_device,
+    seed_root,
     seed_tiles,
+    split_plan,
     tile_args,
     tile_grid,
 )
@@ -86,9 +91,11 @@ def test_tile_grid_math():
 
 
 def test_place_arguments_validated():
-    with pytest.raises(ValueError, match="mode=FLAT"):
-        hc.forasync(TK, BOUNDS, tile=TILE, mode=hc.RECURSIVE,
-                    place="device")
+    # RECURSIVE runs on the device now (the tests below); ragged bounds
+    # are still refused there, in either mode.
+    with pytest.raises(ValueError, match="divide the bounds exactly"):
+        hc.forasync(TK, [H + 4, W], tile=TILE, mode=hc.RECURSIVE,
+                    place="device", data={"gin": GIN, "gout": GOUT0})
     with pytest.raises(ValueError, match="explicit tile"):
         hc.forasync(TK, BOUNDS, place="device")
     with pytest.raises(ValueError, match="unknown forasync place"):
@@ -462,3 +469,228 @@ def test_resident_ring_seeding_follows_placement():
     per_dev = np.asarray(info["per_device_counts"])[:, C_EXECUTED]
     assert per_dev.tolist() == counts
     assert int(np.asarray(iv)[:, 0].sum()) == 12
+
+
+# ------------------------------- RECURSIVE: tiles made on the device
+
+
+def _stencil(h, w):
+    tk, bounds, tile = stencil_loop(h, w)
+    gin, gout = stencil_data(h, w, seed=h)
+    return (tk, bounds, tile, {"gin": gin, "gout": gout}, "gout",
+            stencil_reference(gin))
+
+
+def _map(t):
+    tk, bounds, tile = map_loop(t)
+    vin, vout = map_data(t, seed=t)
+    return (tk, bounds, tile, {"vin": vin, "vout": vout}, "vout",
+            map_reference(vin))
+
+
+def _cube():
+    """A 3-D loop: block (i, j, k) of a (4, 24, 256) grid in (2, 8, 128)
+    tiles, 2 x 3 x 2 of them, out = in + 1."""
+    import jax
+    import jax.numpy as jnp
+
+    shape, tile = (4, 24, 256), (2, 8, 128)
+    spec = jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def window(a):
+        return tuple(pl.ds(a[1 + d], tile[d]) for d in range(3))
+
+    tk = TileKernel(
+        loads=[Slab("cin", "cin", window, tile)],
+        stores=[Slab("cout", "cout", window, tile)],
+        compute=lambda ins: {"cout": ins["cin"] + 1},
+        data_specs={"cin": spec, "cout": spec}, name="fa_cube",
+    )
+    cin = np.arange(np.prod(shape), dtype=np.int32).reshape(shape)
+    return (tk, list(shape), list(tile),
+            {"cin": cin, "cout": np.zeros(shape, np.int32)}, "cout", cin + 1)
+
+
+LOOPS = {
+    "stencil-2x4": lambda: _stencil(16, 512),  # power-of-two tile counts
+    "stencil-3x3": lambda: _stencil(24, 384),
+    "map-16": lambda: _map(16),
+    "map-9": lambda: _map(9),
+    "cube-2x3x2": _cube,
+}
+
+
+@pytest.mark.parametrize("loop", sorted(LOOPS))
+def test_recursive_flat_and_numpy_bit_identical(loop):
+    tk, bounds, tile, data, out, want = LOOPS[loop]()
+    tiles = tile_grid(bounds, tile)[3]
+    got = {}
+    for mode in (hc.FLAT, hc.RECURSIVE):
+        d, info = hc.forasync(
+            tk, bounds, tile=tile, mode=mode, place="device", width=4,
+            data={k: v.copy() for k, v in data.items()},
+        )
+        got[mode] = np.asarray(d[out])
+        splits = tiles - 1 if mode == hc.RECURSIVE else 0
+        assert info["executed"] == tiles + splits and info["pending"] == 0
+        t = info["tiers"]
+        assert (t["batch_tasks"], t["scalar_tasks"]) == (tiles, splits)
+        assert info["forasync"] == {
+            "mode": mode, "tiles": tiles, "splits": splits,
+            "capacity": info["forasync"]["capacity"],
+            "live_rows_max": info["allocated"],
+        }
+        assert not info["overflow"]
+        assert info["allocated"] < info["forasync"]["capacity"]
+    assert np.array_equal(got[hc.FLAT], want)
+    assert np.array_equal(got[hc.RECURSIVE], want)
+
+
+def test_recursive_scalar_arm_and_one_tile_loop():
+    # width=0: the leaves go through lax.switch like the splits.
+    tk, bounds, tile, data, out, want = _stencil(16, 512)
+    d, info = hc.forasync(tk, bounds, tile=tile, mode=hc.RECURSIVE,
+                          place="device", width=0, data=dict(data))
+    assert np.array_equal(np.asarray(d[out]), want)
+    assert info["executed"] == 2 * TOTAL - 1 and "tiers" not in info
+    # A loop of one tile has nothing to split: the root IS the tile.
+    b = TaskGraphBuilder()
+    assert seed_root(b, [8, 128], [8, 128]) == 1
+    assert int(b.finalize()[0][0][0]) == FA_TILE  # F_FN of row 0
+    b = TaskGraphBuilder()
+    assert seed_root(b, BOUNDS, TILE) == TOTAL
+    row = b.finalize()[0][0]
+    assert int(row[0]) == FA_SPLIT
+    assert [int(x) for x in row[F_A0:F_A0 + 6]] == [0, 2, 0, 4, 0, 1]
+
+
+def test_split_plan_counts():
+    assert split_plan([32768, 32768], [256, 1024]) == {
+        "tiles": 4096, "splits": 4095, "depth": 12}
+    assert split_plan([24, 384], [8, 128]) == {
+        "tiles": 9, "splits": 8, "depth": 4}
+    assert split_plan([8 * 1024], [8]) == {
+        "tiles": 1024, "splits": 1023, "depth": 10}
+    assert split_plan([8, 128], [8, 128])["depth"] == 0
+
+
+def test_256_tiles_through_a_table_of_64_rows():
+    """Four times as many tiles as table rows: FLAT says so and names
+    RECURSIVE, which completes with the table a quarter full."""
+    tk, bounds, tile, data, out, want = _map(256)
+    with pytest.raises(ValueError, match="mode=RECURSIVE"):
+        hc.forasync(tk, bounds, tile=tile, place="device", width=8,
+                    capacity=64, data=dict(data))
+    # A compiled build is refused at construction, by the SMEM a row a
+    # tile would take (nothing is compiled or run for this).
+    big = map_loop(2000)
+    with pytest.raises(ValueError, match="2000.*SMEM.*mode=RECURSIVE"):
+        hc.forasync(big[0], big[1], tile=big[2], place="device", width=8,
+                    interpret=False, data=dict(zip(("vin", "vout"),
+                                                   map_data(2000))))
+    d, info = hc.forasync(tk, bounds, tile=tile, mode=hc.RECURSIVE,
+                          place="device", width=8, capacity=64,
+                          data=dict(data))
+    assert np.array_equal(np.asarray(d[out]), want)
+    assert not info["overflow"] and info["pending"] == 0
+    fa, t = info["forasync"], info["tiers"]
+    assert fa["capacity"] == 64 and fa["tiles"] == 256
+    assert fa["live_rows_max"] == info["allocated"] < 32
+    assert info["executed"] == 511
+    assert (t["batch_tasks"], t["scalar_tasks"]) == (256, 255)
+    # The lane fired at two batches, so every round was full and every
+    # round but the first found its operands in flight.
+    assert t["batch_rounds"] == t["full_rounds"] == 32
+    assert t["prefetch_hits"] == 256 - 8 and t["age_fires"] == 0
+    # The default table is sized to the live set, not to the tile count.
+    assert make_forasync_megakernel(
+        tk, width=8, interpret=True, space=(bounds, tile)).capacity == 64
+
+
+def test_prebuilt_kernel_owns_its_mode_and_space():
+    mk = make_forasync_megakernel(TK, width=4, interpret=True,
+                                  space=(BOUNDS, TILE))
+    assert mk.fa_space == tile_grid(BOUNDS, TILE)[:2]
+    assert mk.read_only == ("gin",)
+    data = {"gin": GIN, "gout": GOUT0.copy()}
+    with pytest.raises(ValueError, match="disagrees with the prebuilt"):
+        run_forasync_device(TK, BOUNDS, TILE, data, mk=mk)  # FLAT
+    with pytest.raises(ValueError, match="disagrees with the prebuilt"):
+        run_forasync_device(TK, [32, 512], TILE, data, mk=mk,
+                            mode=hc.RECURSIVE)
+    with pytest.raises(ValueError, match="one device"):
+        run_forasync_device(TK, BOUNDS, TILE, data, mode=hc.RECURSIVE,
+                            placement=MeshPlacement(2, policy="block"))
+    d, info = run_forasync_device(TK, BOUNDS, TILE, data, mk=mk,
+                                  mode=hc.RECURSIVE)
+    assert np.array_equal(np.asarray(d["gout"]), REF)
+    assert mk.stats_dict()["forasync"] == info["forasync"]
+
+
+def test_recursive_checkpoint_mid_loop_resume_bit_identical():
+    """The split rows are ordinary rows: a cut taken while ranges and
+    tiles are both pending resumes to the uninterrupted grid."""
+    mk = make_forasync_megakernel(
+        TK, width=4, capacity=64, interpret=True, checkpoint=True,
+        space=(BOUNDS, TILE),
+    )
+
+    def root():
+        b = TaskGraphBuilder()
+        seed_root(b, BOUNDS, TILE)
+        return b
+
+    _, full, info = mk.run(root(), data={"gin": GIN, "gout": GOUT0.copy()})
+    assert np.array_equal(np.asarray(full["gout"]), REF)
+    assert info["executed"] == 2 * TOTAL - 1
+    _, _, q = mk.run(root(), data={"gin": GIN, "gout": GOUT0.copy()},
+                     quiesce=5)
+    assert q["quiesced"] and q["pending"] > 0
+    state = q["state"]
+    counts = state["counts"]
+    head, tail = int(counts[C_HEAD]), int(counts[C_TAIL])
+    rows = [int(state["ready"][i % mk.capacity]) for i in range(head, tail)]
+    kinds = sorted(int(state["tasks"][r][0]) for r in rows)
+    # Everything pending is on the ring (the lane spilled), both kinds.
+    assert len(rows) == q["pending"] and set(kinds) == {FA_TILE, FA_SPLIT}
+    _, data_r, info_r = mk.resume(state)
+    assert info_r["pending"] == 0
+    assert info_r["executed"] == 2 * TOTAL - 1
+    assert np.array_equal(np.asarray(data_r["gout"]), REF)
+
+
+# --------------------------------- operands that live on the device
+
+
+@pytest.mark.parametrize("mode", [hc.FLAT, hc.RECURSIVE])
+def test_device_operands_are_kept_or_consumed(mode):
+    """Megakernel.run's ownership rule through the entry point: a
+    ``jax.Array`` the tile kernel only loads is read where it lies and is
+    still the caller's; one it stores to is consumed and comes back."""
+    import jax.numpy as jnp
+
+    gin, gout = jnp.array(GIN), jnp.array(GOUT0)
+    d, info = hc.forasync(TK, BOUNDS, tile=TILE, mode=mode, place="device",
+                          width=4, data={"gin": gin, "gout": gout})
+    assert d["gin"] is gin and not gin.is_deleted()
+    assert np.array_equal(np.asarray(gin), GIN)
+    assert gout.is_deleted() and d["gout"] is not gout
+    assert np.array_equal(np.asarray(d["gout"]), REF)
+    # What came back goes in again (a sweep a time step).
+    d2, _ = hc.forasync(TK, BOUNDS, tile=TILE, mode=mode, place="device",
+                        width=4, data=d)
+    assert d2["gin"] is gin and d["gout"].is_deleted()
+    assert np.array_equal(np.asarray(d2["gout"]), REF)
+    # Both buffers crossed alone, so the slab held the scheduler only.
+    assert info["staging"]["uploads"] == 1
+    assert not [b for b in info["staging"]["slab_blocks"] if ":" in b]
+
+
+def test_numpy_operands_are_untouched():
+    gin, gout = GIN.copy(), GOUT0.copy()
+    d, _ = hc.forasync(TK, BOUNDS, tile=TILE, mode=hc.RECURSIVE,
+                       place="device", width=4,
+                       data={"gin": gin, "gout": gout})
+    assert np.array_equal(gin, GIN) and np.array_equal(gout, GOUT0)
+    assert np.array_equal(np.asarray(d["gout"]), REF)
+    assert np.array_equal(np.asarray(d["gin"]), GIN)

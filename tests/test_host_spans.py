@@ -26,6 +26,7 @@ from hclib_tpu.runtime import spans  # noqa: E402
 PACKAGE = os.path.join(ROOT, "hclib_tpu")
 HELPER = os.path.join(PACKAGE, "runtime", "spans.py")
 SERVE, CHOL, SW = "serve-burst-3072", "cholesky-8192", "sw-wave-8192"
+FA = "forasync-2d-hbm"  # joined the four mk_* metrics' cells in PR 40
 MK = ["bench:mk.finalize", "bench:mk.upload", "bench:mk.launch",
       "bench:mk.wait"]
 ENTRY = ["bench:stream.pump", "bench:stream.launch", "bench:stream.wait",
@@ -104,7 +105,7 @@ def test_spans_land_nested_in_a_profile_the_benchmark_reads(tmp_path):
 
 
 def test_the_table_names_every_stage_once():
-    assert len(set(spans.STAGES)) == len(spans.STAGES) == 27
+    assert len(set(spans.STAGES)) == len(spans.STAGES) == 29
     assert all(re.fullmatch(r"[a-z]+(\.[a-z]+)+", s) for s in spans.STAGES)
 
 
@@ -153,6 +154,29 @@ def test_a_run_opens_its_four_spans_in_order_and_resume_three(recorded):
     iv, _, done = mk.resume(cut["state"])
     assert int(iv[0]) == 55 and done["executed"] == info["executed"]
     assert recorded == MK[1:]  # the state is final: nothing to finalize
+
+
+def test_a_device_forasync_opens_its_two_spans_around_the_run(recorded):
+    """``fa.seed`` (the builder and its root range or its tiles), then
+    ``fa.run`` with ``Megakernel.run``'s four inside, in either mode."""
+    import numpy as np
+
+    import hclib_tpu as hc
+    from hclib_tpu.device.workloads import (
+        stencil_data, stencil_loop, stencil_reference,
+    )
+
+    tk, bounds, tile = stencil_loop(16, 256)
+    gin, gout = stencil_data(16, 256)
+    for mode in (hc.FLAT, hc.RECURSIVE):
+        del recorded[:]
+        out, info = hc.forasync(
+            tk, bounds, tile=tile, mode=mode, place="device", width=2,
+            interpret=True, data={"gin": gin, "gout": gout.copy()})
+        assert recorded == ["bench:fa.seed", "bench:fa.run"] + MK
+        assert np.array_equal(np.asarray(out["gout"]),
+                              stencil_reference(gin))
+        assert info["forasync"]["mode"] == mode
 
 
 # ---------------------------------------------- the stream's entry loop
@@ -248,7 +272,7 @@ def test_an_open_stream_names_its_idle_sleep(recorded):
 FRONT = {"source": "program_span", "layer": "front door",
          "moves": "req_per_s", "workloads": [SERVE]}
 STAGING = {"layer": "host staging", "moves": "solve_ms",
-           "workloads": [CHOL, SW]}
+           "workloads": [CHOL, SW, FA]}
 # name: (reducer, args, unit, the rest of the entry, value on HOST below)
 METRICS = {
     "launch_us": ("span_mean",

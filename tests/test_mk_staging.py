@@ -55,7 +55,7 @@ def _addx_kernel(ctx):
     out.wait()
 
 
-def _mk(build: str, residency: str) -> Megakernel:
+def _mk(build: str, residency: str, read_only=("a", "x")) -> Megakernel:
     xdt = jnp.float32 if residency == "host_float" else jnp.int32
     xtiles = BIG if residency == "host_large" else NT
     small = jax.ShapeDtypeStruct((NT,) + TILE, jnp.int32)
@@ -82,6 +82,7 @@ def _mk(build: str, residency: str) -> Megakernel:
         checkpoint=build == "checkpoint",
         trace=64 if build == "traced" else None,
         interpret=True,
+        read_only=read_only,  # the addx kernel writes c alone
     )
 
 
@@ -102,6 +103,26 @@ def _data(mk: Megakernel, residency: str) -> dict:
         "x": jnp.asarray(x) if residency == "on_device" else x,
         "c": np.zeros((NT,) + TILE, np.int32),
     }
+
+
+def test_a_written_buffer_on_the_device_is_consumed_and_comes_back():
+    """``run``'s ownership rule (ISSUE 40): a ``jax.Array`` the build did
+    not declare ``read_only`` is donated, so the caller's array is deleted
+    and its contents come back under its name; a numpy buffer is never
+    touched, and a declared one is never consumed."""
+    mk = _mk("scalar", "on_device", read_only=("a",))
+    assert mk.read_only == ("a",)
+    data = _data(mk, "on_device")
+    x, c = data["x"], jnp.asarray(data["c"])
+    x_np, a_np = np.array(x), data["a"].copy()  # copies: a view pins x
+    _, out, info = mk.run(_graph(), data={**data, "c": c})
+    assert x.is_deleted() and c.is_deleted()
+    assert np.array_equal(np.asarray(out["x"]), x_np)
+    assert np.array_equal(np.asarray(out["c"]), a_np + x_np[:NT])
+    assert np.array_equal(data["a"], a_np)  # the host's, untouched
+    assert info["staging"]["uploads"] == 1  # the slab alone
+    with pytest.raises(ValueError, match="undeclared buffers"):
+        _mk("scalar", "on_device", read_only=("a", "nope"))
 
 
 def _staging(mk: Megakernel, data: dict) -> dict:
@@ -160,6 +181,9 @@ def test_same_answers_however_the_buffers_cross(build, residency):
     for k in ("a", "x"):
         assert np.array_equal(np.asarray(out[k]), np.asarray(data[k]))
         assert np.asarray(out[k]).dtype == mk.data_specs[k].dtype
+    if residency == "on_device" and build != "checkpoint":
+        # read where it lies, and still the caller's (ISSUE 40)
+        assert out["x"] is data["x"] and not data["x"].is_deleted()
     if build == "batch":
         t = info["tiers"]
         assert (t["batch_tasks"], t["routed"]) == (FIB_TASKS, FIB_TASKS)

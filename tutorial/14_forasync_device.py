@@ -15,6 +15,16 @@ lowers the same construct onto the DEVICE (device/forasync_tier.py):
   slabs - the tier derives the scalar-dispatch kernel, the batched body,
   and its prefetch drain from that one declaration, which is why the
   two device spellings are bit-identical by construction.
+- **RECURSIVE makes the tiles on the device.** FLAT stages one
+  descriptor a tile from the host, so the task table must hold every
+  tile - and a table row costs a KiB of the chip's 1 MiB of SMEM.
+  ``mode=hc.RECURSIVE`` stages ONE range descriptor instead: a split kind
+  on the scalar tier halves the widest dimension at a tile boundary and
+  spawns its halves until a half is one tile, which goes through the same
+  lane and the same body. The lane fires as soon as it holds two batches,
+  so the splitter is paced by its consumer and the table holds the live
+  set (two batches of tiles, a range a level of the recursion) however
+  many tiles the loop has: 256 tiles run through 64 rows below.
 - **Placement is data, not code.** On a mesh, a JSON placement
   descriptor (or a classic dist func) resolved against
   ``locality_graphs/*.json`` maps each flat tile to a device, seeding
@@ -115,6 +125,32 @@ def part_two_map_loop():
           f"{info['tiers']['batch_occupancy']:.2f}")
 
 
+def part_two_b_recursive():
+    """More tiles than table rows: FLAT refuses and names RECURSIVE,
+    which makes the 256 tiles on the device through a table of 64."""
+    T = 256
+    tk, bounds, tile = map_loop(T)
+    vin, vout = map_data(T)
+    try:
+        hc.forasync(tk, bounds, tile=tile, place="device", capacity=64,
+                    data={"vin": vin, "vout": vout.copy()}, width=8)
+    except ValueError as e:
+        assert "mode=RECURSIVE" in str(e)
+    else:
+        raise AssertionError("256 tiles were staged into 64 rows")
+    d, info = hc.forasync(
+        tk, bounds, tile=tile, mode=hc.RECURSIVE, place="device",
+        capacity=64, data={"vin": vin, "vout": vout.copy()}, width=8,
+    )
+    assert np.array_equal(np.asarray(d["vout"]), map_reference(vin))
+    fa, t = info["forasync"], info["tiers"]
+    assert fa["live_rows_max"] < fa["capacity"] == 64 < fa["tiles"]
+    print(f"  recursive: {fa['tiles']} tiles from {fa['splits']} splits "
+          f"through {fa['capacity']} rows, {fa['live_rows_max']} live at "
+          f"most; {t['batch_rounds']} rounds, occupancy "
+          f"{t['batch_occupancy']:.2f}, {t['prefetch_hits']} prefetch hits")
+
+
 def part_three_mesh_placement():
     """Placement as data: a JSON descriptor seeds the per-device ready
     rings; the machine graph orders the steal scan; a deliberately
@@ -154,6 +190,8 @@ if __name__ == "__main__":
     part_one_host_vs_device()
     print("map loop:")
     part_two_map_loop()
+    print("recursive splitting on the device:")
+    part_two_b_recursive()
     print("mesh placement + stealing:")
     part_three_mesh_placement()
     print("lesson 14 OK")
