@@ -505,6 +505,12 @@ def _make_recording_contexts():
             self._shim_trace.continuations += 1
             super().take_continuation(new_idx)
 
+        def become(self, fn, dep_count) -> None:
+            # The same fork-join as take_continuation, on the task's own
+            # row: its links stay behind for the continuation.
+            self._shim_trace.continuations += 1
+            super().become(fn, dep_count)
+
     class RecordingBatchContext(BatchContext):
         _shim_trace: BodyTrace = None
 
@@ -551,6 +557,7 @@ def _make_recording_contexts():
                 ctx.ivalues, ctx.data, ctx.scratch, ctx._capacity,
                 ctx._free, ctx._num_values, ctx._vfree,
                 ctx._uses_row_values, ctx._tracks_home,
+                rearm=ctx._rearm, slot=ctx._slot,
             )
             rec._shim_trace = self._shim_trace
             rec._shim_slot = int(s)
@@ -611,7 +618,9 @@ def _synth_tasks(fid: int, width: int, nxt: int) -> np.ndarray:
 
 
 def _core_refs(tasks: np.ndarray):
-    from ..device.megakernel import C_ALLOC, C_PENDING, C_VALLOC, C_VBASE
+    from ..device.megakernel import (
+        C_ALLOC, C_PENDING, C_VALLOC, C_VBASE, RA_MARK, _Rearm,
+    )
 
     t = FakeRef("smem:tasks", "smem", backing=tasks)
     succ = FakeRef("smem:succ", "smem", (64,))
@@ -625,7 +634,8 @@ def _core_refs(tasks: np.ndarray):
     ivalues = FakeRef("smem:ivalues", "smem", (64,))
     free = FakeRef("smem:free", "smem", (_CAPACITY + 1,))
     vfree = FakeRef("smem:vfree", "smem", (_CAPACITY + 1,))
-    return t, succ, ready, counts, ivalues, free, vfree
+    rearm = _Rearm(FakeRef("smem:rearm", "smem", (RA_MARK + _CAPACITY,)))
+    return t, succ, ready, counts, ivalues, free, vfree, rearm
 
 
 class _BigValues:
@@ -671,7 +681,7 @@ def run_batch_body(spec, fid: int, data_specs, scratch_specs, *,
         _make_recording_contexts()
     )
     trace = BodyTrace()
-    tasks, succ, ready, counts, ivalues, free, vfree = (
+    tasks, succ, ready, counts, ivalues, free, vfree, rearm = (
         _core_refs(_synth_tasks(fid, spec.width, prefetch_count))
     )
     data, scratch = _fake_env(data_specs, scratch_specs)
@@ -681,7 +691,7 @@ def run_batch_body(spec, fid: int, data_specs, scratch_specs, *,
     )
     kctx = RecordingKernelContext(
         0, tasks, succ, ready, counts, _BigValues(), data, scratch,
-        _CAPACITY, free, 1 << 22, vfree, False, False,
+        _CAPACITY, free, 1 << 22, vfree, False, False, rearm=rearm,
     )
     kctx._shim_trace = trace
     bctx = RecordingBatchContext(
@@ -702,7 +712,7 @@ def run_drain(spec, fid: int, data_specs, scratch_specs, *,
         _make_recording_contexts()
     )
     trace = BodyTrace()
-    tasks, succ, ready, counts, ivalues, free, vfree = (
+    tasks, succ, ready, counts, ivalues, free, vfree, rearm = (
         _core_refs(_synth_tasks(fid, spec.width, prefetched))
     )
     data, scratch = _fake_env(data_specs, scratch_specs)
@@ -713,6 +723,7 @@ def run_drain(spec, fid: int, data_specs, scratch_specs, *,
     kctx = RecordingKernelContext(
         spec.width, tasks, succ, ready, counts, _BigValues(), data,
         scratch, _CAPACITY, free, 1 << 22, vfree, False, False,
+        rearm=rearm,
     )
     kctx._shim_trace = trace
     # head = width: the drained prefetch targets the rows BEHIND the
@@ -735,7 +746,7 @@ def run_scalar_kernel(fn, data_specs, scratch_specs,
     the trace's spawns/continuations drive classification."""
     RecordingKernelContext, _ = _make_recording_contexts()
     trace = BodyTrace()
-    tasks, succ, ready, counts, ivalues, free, vfree = (
+    tasks, succ, ready, counts, ivalues, free, vfree, rearm = (
         _core_refs(_synth_tasks(0, 1, 0))
     )
     for i in range(6):
@@ -746,7 +757,7 @@ def run_scalar_kernel(fn, data_specs, scratch_specs,
     data, scratch = _fake_env(data_specs, scratch_specs)
     ctx = RecordingKernelContext(
         0, tasks, succ, ready, counts, _BigValues(), data, scratch,
-        _CAPACITY, free, 1 << 22, vfree, False, False,
+        _CAPACITY, free, 1 << 22, vfree, False, False, rearm=rearm,
     )
     ctx._shim_trace = trace
     ctx._shim_slot = 0

@@ -38,6 +38,20 @@ Static DAGs (Cholesky, Smith-Waterman) are built host-side with
 ``TaskGraphBuilder``; dynamic tasks (fib, UTS) are allocated on-device by
 kernels via ``KernelContext.spawn``.
 
+A *re-armed row* (``KernelContext.become``) is a fork-join task that turned
+into its own continuation where it lies: its handler rewrote F_FN (the
+continuation's kind) and F_DEP (the children it waits for, above zero) and
+nothing else, so F_SUCC0/1, the CSR pair, F_OUT, the args it did not set
+again, its row-owned value block and a traveling copy's F_HOME/F_HROW all
+pass to the continuation without a word moved. From the end of that
+dispatch it is an ordinary pending row: off the ring until its children
+count F_DEP down, exported by a checkpoint cut and kept home by the steal
+filters like any dependent row, retired (hook, successors, tombstone) when
+the continuation completes. ``take_continuation`` is still the call when
+the continuation must be ANOTHER row: one that outlives this task's
+completion hook firing now (an egress token retired at the fork, not at
+the join), or a join spawned by someone else.
+
 Injection-ring row extension (multi-tenant ingress, device/tenants.py):
 ring rows are padded to 256 words (``RING_ROW``, device/inject.py) so any
 row offset DMA-aligns, and the pad words directly above the descriptor

@@ -571,7 +571,7 @@ class StreamingMegakernel:
         rest = refs[n_in + n_out :]
         nscratch = len(mk.scratch_specs)
         scratch_refs = rest[:nscratch]
-        free, vfree, ctlbuf, rowbuf, isem = rest[nscratch:]
+        free, vfree, rearm, ctlbuf, rowbuf, isem = rest[nscratch:]
         tasks_in, succ, ready_in, counts_in, ivalues_in = in_refs[:5]
         ring, ctl_in = in_refs[5], in_refs[6]
         tctl_in = in_refs[7 + ndata] if nten else None
@@ -706,7 +706,7 @@ class StreamingMegakernel:
 
         core = mk._make_core(
             succ, tasks, ready, counts, ivalues, data, scratch, free, vfree,
-            tasks_in, ready_in, counts_in, ivalues_in, True,
+            rearm, tasks_in, ready_in, counts_in, ivalues_in, True,
             tracer=tr if tr.enabled else None,
             complete_hook=egress_complete if negr else None,
             fire_hook=tele_fire if ntele else None,
@@ -1134,9 +1134,9 @@ class StreamingMegakernel:
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=list(mk.scratch_specs.values())
-            # free, vfree: the stream kernel embeds the core without the
-            # batched tier (_make_core refuses a batch-routed mk).
-            + mk.core_scratch()[:2]
+            # free, vfree, rearm: the stream kernel embeds the core without
+            # the batched tier (_make_core refuses a batch-routed mk).
+            + mk.core_scratch()[:3]
             + [
                 pltpu.SMEM((8,), jnp.int32),  # ctl staging
                 pltpu.SMEM((8, RING_ROW), jnp.int32),  # row staging (8-row chunks)

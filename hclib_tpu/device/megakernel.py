@@ -305,9 +305,10 @@ OVF_WAITS = 16   # resident wait table
 OVF_LOCKQ = 32   # resident lock FIFO
 OVF_PROMISE = 64  # on-device promise wait spun out its bounded budget
 
-# Batched-dispatch tier statistics (the 8-word tstats output a batch-routed
-# megakernel appends after its data outputs; surfaced as info['tiers'] /
-# Megakernel.stats_dict()). All counters reset at every kernel entry, so with
+# Dispatch tier statistics (the TS_WORDS-word tstats output a megakernel
+# appends after its data outputs; a batch-routed build's are surfaced as
+# info['tiers'] / Megakernel.stats_dict(), TS_BECAME by every build as
+# info['became']). All counters reset at every kernel entry, so with
 # reps > 1 they describe the LAST rep - per-graph numbers, which is what
 # occupancy tracking wants.
 TS_BATCH_ROUNDS = 0   # batch rounds fired
@@ -333,7 +334,18 @@ TS_INVERSIONS = 11    # bucket-order inversions: age-guard fires that
                       # way a higher bucket fires first; bounded noise
                       # is healthy, a large count means the age knob is
                       # fighting the priority order)
-TS_WORDS = 12
+TS_BECAME = 12        # dispatches that ended RE-ARMED (ctx.become: the row
+                      # stayed, as its own continuation); written by every
+                      # build, batch-routed or not
+TS_WORDS = 13
+
+# Re-arm words (SMEM scratch of RA_MARK + widest-batch words, the third of
+# ``core_scratch``): what ``KernelContext.become`` leaves for the
+# ``complete()`` of the same dispatch, at static offsets. A mark is set and
+# cleared inside one dispatch, so no round boundary, export or checkpoint
+# cut ever sees one set.
+RA_BECAME = 0  # re-armed dispatches since stage() (rides out as TS_BECAME)
+RA_MARK = 1    # + the dispatch's slot (0 on the scalar tier): re-armed
 
 # Priority-bucket dispatch tier (ISSUE 15): ``priority_buckets=B`` layers
 # B bucket rings over every per-kind batch lane - pop lowest-nonempty-
@@ -394,13 +406,25 @@ C_ROUNDS = 7
 C_VBASE = 7
 
 
+class _Rearm:
+    """One scheduler core's re-arm words (``RA_*``) and, while the core is
+    traced, whether a handler traced so far calls ``become``: each
+    dispatch site traces its bodies before their ``complete()``, so a table
+    in which nothing re-arms compiles the completion it always had."""
+
+    def __init__(self, ref) -> None:
+        self.ref = ref
+        self.used = False
+
+
 class KernelContext:
     """Facilities exposed to device task kernels (the device analogue of the
     worker-state + spawn API the reference hands to tasks)."""
 
     def __init__(self, idx, tasks, succ, ready, counts, ivalues, data,
                  scratch, capacity, free, num_values, vfree,
-                 uses_row_values=False, tracks_home=False):
+                 uses_row_values=False, tracks_home=False,
+                 rearm=None, slot=0):
         self.idx = idx  # this task's descriptor index
         self._tasks = tasks
         self._succ = succ
@@ -423,6 +447,11 @@ class KernelContext:
         # (ResidentKernel sets Megakernel.tracks_home; plain megakernels
         # skip the dead scalar writes - the cost unit on this tier).
         self._tracks_home = tracks_home
+        # The core's re-arm words (a ``_Rearm``) and this dispatch's slot
+        # in them: 0 under scalar dispatch, the batch slot under
+        # ``BatchContext.slot_ctx`` (``become``).
+        self._rearm = rearm
+        self._slot = slot
 
     # -- descriptor access --
 
@@ -633,6 +662,35 @@ class KernelContext:
             t[new_idx, F_HROW] = t[self.idx, F_HROW]
             t[self.idx, F_HOME] = jnp.int32(NO_TASK)
 
+    def become(self, fn: int, dep_count) -> None:
+        """Re-arm this task's own row as its continuation: the row turns
+        into a ``fn`` task waiting on ``dep_count`` predecessors (children
+        spawned with ``succ0=ctx.idx``), and keeps its successors, its out
+        slot, its args, its value block and a migrated copy's home-link
+        where they lie - what the reference does when the blocked task
+        itself becomes the continuation (_help_finish_ctx,
+        src/hclib-runtime.c:1032-1065: nothing allocated, no waiter list
+        moved). Two dynamic writes (F_FN, F_DEP) and one static mark; this
+        dispatch's ``complete()`` sees the mark, counts the task executed
+        and leaves the row pending: no hook, no successor walk, no
+        tombstone. ``take_continuation`` is for a continuation that has to
+        be ANOTHER row. ``dep_count`` must be positive: a continuation
+        that is ready at once is a plain ``spawn``."""
+        if isinstance(dep_count, (int, np.integer)) and dep_count <= 0:
+            raise ValueError(
+                f"become() needs dep_count > 0, got {dep_count}: a "
+                "continuation that is ready at once is a plain spawn"
+            )
+        if self._rearm is None:
+            raise ValueError(
+                "this KernelContext was built without the core's re-arm "
+                "words (core_scratch()'s third); become() needs them"
+            )
+        self._tasks[self.idx, F_FN] = jnp.int32(fn)
+        self._tasks[self.idx, F_DEP] = jnp.int32(dep_count)
+        self._rearm.used = True
+        self._rearm.ref[RA_MARK + self._slot] = 1
+
     def spawn(
         self,
         fn: int,
@@ -656,6 +714,16 @@ class KernelContext:
         rows may hold stale words beyond nargs, which a conforming kernel
         never reads (the same contract C lets the reference's task structs
         rely on, inc/hclib-task.h:32-44).
+
+        Dynamically indexed touches a dispatch (by a row, a ring position
+        or a stack top; ``counts[C_*]`` is static), as ISSUE 41 counted
+        them: a fib fork 25 (48 before ``become``: pop, F_FN, arg 3;
+        ``become`` 2; ``set_arg`` x2; two spawns 18; ``complete()`` 0), a
+        leaf 14.5, a SUM 18.5; one ``spawn(nargs=1)`` is 9 (the free
+        stack, six row words, the arg, the ring). A task's time on the
+        v5e did NOT follow that count (PR 41): it follows the bundles of
+        straight-line code its path runs, about 37 a spawn, the five
+        ``pl.when``s below predicated into them.
         """
         if nargs is None:
             nargs = 6
@@ -874,8 +942,10 @@ class BatchContext:
         batch bodies whose per-slot work is scalar-shaped (dynamic spawns,
         continuation transfer) rather than one fused tile op. The returned
         context shares every underlying ref with this batch, so
-        ``spawn``/``take_continuation``/``set_arg``/``row_values`` behave
-        exactly as they would under scalar dispatch of the same row; a
+        ``spawn``/``become``/``take_continuation``/``set_arg``/
+        ``row_values`` behave exactly as they would under scalar dispatch
+        of the same row (a re-arm marks slot ``s``'s own word, which the
+        round reads when it completes that slot after all the bodies); a
         body that unrolls ``range(width)`` under ``pl.when(live(s))`` and
         runs the scalar kernel per live slot computes bit-identical
         results while skipping the per-descriptor ring pop + lax.switch
@@ -885,6 +955,7 @@ class BatchContext:
             self.idx(s), k._tasks, k._succ, k._ready, k._counts, k.ivalues,
             k.data, k.scratch, k._capacity, k._free, k._num_values,
             k._vfree, k._uses_row_values, k._tracks_home,
+            rearm=k._rearm, slot=s,
         )
         if self._ctx_hook is not None:
             self._ctx_hook(ctx)
@@ -1260,8 +1331,7 @@ class Megakernel:
         ]
         one = [(self.succ_capacity,)]  # succ is input-only
         one += [s.shape for s in self.core_scratch(cap)]
-        if self.batch_specs:
-            one.append((TS_WORDS,))
+        one.append((TS_WORDS,))
         if self.checkpoint:
             one += [(8,), (8,)]  # qstat out, qbuf
         if self.trace is not None:
@@ -1316,13 +1386,18 @@ class Megakernel:
     def core_scratch(self, capacity: Optional[int] = None) -> list:
         """The scheduler core's own SMEM scratch at ``capacity`` rows
         (default: this build's), in ``_make_core``'s order: the two free
-        stacks (``free``, ``vfree``), then - for a batch-routed build -
-        the batched tier's ``lanes`` and ``lstate``. The embedders
+        stacks (``free``, ``vfree``), the re-arm words (``RA_*``: a mark
+        a dispatch slot), then - for a batch-routed build - the batched
+        tier's ``lanes`` and ``lstate``. The embedders
         (_build_raw, StreamingMegakernel._build, ResidentKernel._build)
         splice these into their scratch lists and ``smem_footprint``
         charges the same shapes, so the core's layout is declared once."""
         cap = self.capacity if capacity is None else int(capacity)
-        shapes = [(cap + 1,), (self.num_values // VBLOCK + 1,)]
+        widest = max([spec.width for _, spec in self.batch_specs] + [1])
+        shapes = [
+            (cap + 1,), (self.num_values // VBLOCK + 1,),
+            (RA_MARK + widest,),
+        ]
         if self.batch_specs:
             shapes += [
                 (self.lane_scratch_rows, cap),
@@ -1394,6 +1469,7 @@ class Megakernel:
         scratch,
         free,
         vfree,
+        rearm,
         tasks_in,
         ready_in,
         counts_in,
@@ -1501,18 +1577,21 @@ class Megakernel:
         def stage() -> None:
             free[0] = 0
             vfree[0] = 0
+            for w in range(rearm.shape[0]):
+                rearm[w] = 0
             # Trace header resets per entry/rep, so reps > 1 leaves the
             # LAST rep's records - the same per-graph semantics tstats has.
             tr.reset()
             if use_batch:
                 # Lanes/prefetch state are per-entry scratch (sched() spills
                 # unrun entries back to the ready ring before returning, so
-                # nothing lives in a lane across entries); tstats is the
-                # tier's output window - zeroed here so reps report the
-                # last rep's per-graph counters.
+                # nothing lives in a lane across entries).
                 for li in range(nrows):
                     for w in range(LS_WORDS):
                         lstate[li, w] = 0
+            if tstats is not None:
+                # The tier's output window - zeroed here so reps report
+                # the last rep's per-graph counters.
                 for w in range(TS_WORDS):
                     tstats[w] = 0
             for i in range(8):
@@ -1579,10 +1658,33 @@ class Megakernel:
             ready[tail % capacity] = t
             counts[C_TAIL] = tail + 1
 
-        def complete(idx) -> None:
+        ra = _Rearm(rearm)
+
+        def complete(idx, slot: int = 0) -> None:
             """Decrement successors' dep counters; push newly-ready tasks
             (device analogue of hclib_promise_put waking the waiter list,
-            src/hclib-promise.c:203-245)."""
+            src/hclib-promise.c:203-245). A dispatch whose body re-armed
+            its row (``KernelContext.become`` marked ``slot``) is counted
+            executed and nothing else: the row has not retired, so the
+            hook, the successor walk, the tombstone and the free-stack
+            push all wait for the continuation, on the same row."""
+            if not ra.used:
+                retire(idx)
+                return
+            mark = RA_MARK + slot
+
+            def stay() -> None:
+                rearm[mark] = 0
+                rearm[RA_BECAME] = rearm[RA_BECAME] + 1
+                counts[C_EXECUTED] = counts[C_EXECUTED] + 1
+
+            # A branch, not arithmetic folded into the walk: the v5e
+            # compiler predicates what it can, and every task would then
+            # pay for the whole walk (140.9 ns a task against 131.3, my
+            # chip runs, PR 41).
+            jax.lax.cond(rearm[mark] != 0, stay, lambda: retire(idx))
+
+        def retire(idx) -> None:
             if complete_hook is not None:
                 complete_hook(idx)
 
@@ -1626,7 +1728,7 @@ class Megakernel:
             ctx = KernelContext(
                 idx, tasks, succ, ready, counts, ivalues, data, scratch,
                 capacity, free, num_values, vfree,
-                self.uses_row_values, self.tracks_home,
+                self.uses_row_values, self.tracks_home, rearm=ra,
             )
             if ctx_hook is not None:
                 ctx_hook(ctx)
@@ -1643,7 +1745,7 @@ class Megakernel:
             kctx = KernelContext(
                 lanes[li, head % capacity], tasks, succ, ready, counts,
                 ivalues, data, scratch, capacity, free, num_values, vfree,
-                self.uses_row_values, self.tracks_home,
+                self.uses_row_values, self.tracks_home, rearm=ra,
             )
             if ctx_hook is not None:
                 ctx_hook(kctx)
@@ -1740,7 +1842,7 @@ class Megakernel:
                     def _(s=s):
                         if fire_hook is not None:
                             fire_hook(lanes[li, (base + s) % capacity])
-                        complete(lanes[li, (base + s) % capacity])
+                        complete(lanes[li, (base + s) % capacity], s)
                 if fifo:
                     lstate[li, LS_HEAD] = head + take
                     if spec.prefetch:
@@ -2184,6 +2286,8 @@ class Megakernel:
                     lstate[li, LS_HEAD] = t
                     lstate[li, LS_PF_BASE] = 0
                     tstats[TS_SPILLED] = tstats[TS_SPILLED] + (t - h)
+            if tstats is not None:
+                tstats[TS_BECAME] = rearm[RA_BECAME]
             tr.emit(
                 TR_ROUND_END, tr.tick(),
                 counts[C_EXECUTED] - e0, counts[C_PENDING],
@@ -2263,8 +2367,7 @@ class Megakernel:
         nbatch = len(self.batch_specs)
         ntrace = 1 if trace is not None else 0
         n_in = 5 + ndata + (1 if ckpt else 0)  # qctl rides last
-        n_out = (4 + len(written) + (1 if nbatch else 0)
-                 + (1 if ckpt else 0) + ntrace)
+        n_out = 5 + len(written) + (1 if ckpt else 0) + ntrace
         in_refs = refs[:n_in]
         out_refs = refs[n_in : n_in + n_out]
         tail = list(refs[n_in + n_out :])
@@ -2272,6 +2375,7 @@ class Megakernel:
         tail = tail[len(self.scratch_specs) :]
         free = tail.pop(0)  # internal free-stack: [0]=count, [1..]=rows
         vfree = tail.pop(0)  # value-block free-stack, same layout
+        rearm = tail.pop(0)  # re-arm words (RA_*)
         lanes = tail.pop(0) if nbatch else None  # per-kind ready lanes
         lstate = tail.pop(0) if nbatch else None  # lane cursors + prefetch
         qbuf = tail.pop(0) if ckpt else None  # quiesce-word staging
@@ -2285,8 +2389,8 @@ class Megakernel:
         data = dict(zip(self.data_specs, in_refs[5 : 5 + ndata]))
         data.update(zip(written, out_refs[4 : 4 + len(written)]))
         after = 4 + len(written)  # the appended outputs start here
-        tstats = out_refs[after] if nbatch else None
-        qstat = out_refs[after + (1 if nbatch else 0)] if ckpt else None
+        tstats = out_refs[after]
+        qstat = out_refs[after + 1] if ckpt else None
         tracer = (
             Tracer(out_refs[n_out - 1], trace.capacity)
             if ntrace
@@ -2338,8 +2442,9 @@ class Megakernel:
 
         core = self._make_core(
             succ, tasks, ready, counts, ivalues, data, scratch, free, vfree,
-            tasks_in, ready_in, counts_in, ivalues_in, stage_all_values,
-            lanes=lanes, lstate=lstate, tstats=tstats, tracer=tracer,
+            rearm, tasks_in, ready_in, counts_in, ivalues_in,
+            stage_all_values, lanes=lanes, lstate=lstate, tstats=tstats,
+            tracer=tracer,
             quiesce_hook=quiesce_hook,
         )
 
@@ -2406,7 +2511,6 @@ class Megakernel:
         passes ``read_only``; the embedders alias every buffer)."""
         ndata = len(self.data_specs)
         written = [k for k in self.data_specs if k not in inputs]
-        nbatch = len(self.batch_specs)
         ckpt = self.checkpoint
         smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
         anyspace = functools.partial(pl.BlockSpec, memory_space=pl.ANY)
@@ -2420,10 +2524,11 @@ class Megakernel:
         out_specs = tuple(
             [smem(), smem(), smem(), smem()]
             + [anyspace() for _ in written]
-            # Batched-tier counters ride out as one extra SMEM word row
+            # The tier counters (TS_* words; a build with no batch route
+            # writes TS_BECAME alone) ride out as one extra SMEM word row
             # APPENDED after the data outputs, so every existing consumer's
             # positional indexing is untouched.
-            + ([smem()] if nbatch else [])
+            + [smem()]
             # Quiesce status (QS_* words), same appended discipline.
             + ([smem()] if ckpt else [])
             # The flight-recorder ring rides last, same appended-output
@@ -2442,12 +2547,12 @@ class Megakernel:
                 jax.ShapeDtypeStruct((self.num_values,), jnp.int32),
             ]
             + data_shapes
-            + ([jax.ShapeDtypeStruct((TS_WORDS,), jnp.int32)] if nbatch else [])
+            + [jax.ShapeDtypeStruct((TS_WORDS,), jnp.int32)]
             + ([jax.ShapeDtypeStruct((8,), jnp.int32)] if ckpt else [])
             + ([self.trace.out_shape()] if self.trace is not None else [])
         )
         # inputs: tasks(0) succ(1) ready(2) counts(3) ivalues(4) data(5..)
-        # outputs: tasks(0) ready(1) counts(2) ivalues(3) data(4..) [tstats]
+        # outputs: tasks(0) ready(1) counts(2) ivalues(3) data(4..) tstats
         aliases = {0: 0, 2: 1, 3: 2, 4: 3}
         for o, k in enumerate(written):
             aliases[5 + list(self.data_specs).index(k)] = 4 + o
@@ -2517,10 +2622,9 @@ class Megakernel:
             "counts": (8,),
             "ivalues": (self.num_values,),
         }
-        down = {"counts": (8,), "ivalues": (self.num_values,)}
-        if self.batch_specs:
-            outs.append("tstats")
-            down["tstats"] = (TS_WORDS,)
+        down = {"counts": (8,), "ivalues": (self.num_values,),
+                "tstats": (TS_WORDS,)}
+        outs.append("tstats")
         if self.checkpoint:
             ins.append("qctl")
             up["qctl"] = (8,)
@@ -2604,6 +2708,9 @@ class Megakernel:
             "routed": int(t[TS_ROUTED]),
             "prefetch_hits": int(t[TS_PREFETCH]),
             "spilled": int(t[TS_SPILLED]),
+            # Dispatches that ended re-armed (KernelContext.become), on
+            # either tier.
+            "became": int(t[TS_BECAME]),
             # Age-trigger firing policy (lane_max_age; zeros when off):
             # rounds that jumped ring-drain-first, and the worst
             # starved-round age any lane reached - the device-side gauge
@@ -2853,8 +2960,14 @@ class Megakernel:
                 self._pc_stats["build_s"] += (t1_ns - t0_ns) / 1e9
         counts_np = packed[:8]
         ivalues_np = packed[8 : 8 + self.num_values]
+        off = 8 + self.num_values
+        tstats_np = packed[off : off + TS_WORDS]
+        off += TS_WORDS
         info = {
             "executed": int(counts_np[C_EXECUTED]),
+            # Of those, the dispatches that ended re-armed (ctx.become):
+            # the row stayed pending, as its own continuation.
+            "became": int(tstats_np[TS_BECAME]),
             "pending": int(counts_np[C_PENDING]),
             "allocated": int(counts_np[C_ALLOC]),
             "value_alloc": int(counts_np[C_VALLOC]),
@@ -2870,11 +2983,8 @@ class Megakernel:
             # so MetricsRegistry.add_run_info exports it beside
             # lane_occupancy.
             info["program_cache"] = dict(self._pc_stats)
-        off = 8 + self.num_values
         if self.batch_specs:
-            info["tiers"] = self.decode_tier_stats(
-                packed[off : off + TS_WORDS]
-            )
+            info["tiers"] = self.decode_tier_stats(tstats_np)
             if self._pc_stats is not None:
                 # Host-side build-cost gauges ride the tier dict (the
                 # add_run_info export path). Cross-arm tier equality
@@ -2884,7 +2994,6 @@ class Megakernel:
                 info["tiers"]["cache_lookup_s"] = (
                     self._pc_stats["cache_lookup_s"]
                 )
-            off += TS_WORDS
         quiesced = False
         if self.checkpoint:
             qstat = packed[off : off + 8]

@@ -192,6 +192,7 @@ from .megakernel import (
     C_TAIL,
     C_VBASE,
     Megakernel,
+    RA_BECAME,
     TS_WORDS,
     VBLOCK,
 )
@@ -292,6 +293,8 @@ FS_TEN_EXPIRED = 11 # tenant-tagged ring rows I dropped expired (the
                     # TEN_EXPIRED on published rows, the poll skips them)
 FS_EXPORTED = 12    # rows I put on the wire in the steal exchange
 FS_IMPORTED = 13    # rows I installed from the steal exchange's inboxes
+FS_BECAME = 14      # my dispatches that ended re-armed (ctx.become): of
+                    # my executed count, the ones whose row stayed pending
 FS_WORDS = 16
 
 
@@ -317,6 +320,7 @@ def decode_fault_stats(row) -> Dict[str, Any]:
         "tenant_expired": row[FS_TEN_EXPIRED],
         "steal_exported": row[FS_EXPORTED],
         "steal_imported": row[FS_IMPORTED],
+        "became": row[FS_BECAME],
     }
 
 
@@ -699,7 +703,7 @@ class ResidentKernel:
 
         nckpt = 1 if self.checkpoint else 0
         nh = self.nh
-        (free, vfree, candbuf, sendbuf, statacc, statsnd) = take(6)
+        (free, vfree, rearm, candbuf, sendbuf, statacc, statsnd) = take(7)
         statrcv = take(nh)
         inboxes = take(nh) if self.steal else []
         (
@@ -964,7 +968,7 @@ class ResidentKernel:
 
         core = mk._make_core(
             succ, tasks, ready, counts, ivalues, data, scratch, free, vfree,
-            tasks_in, ready_in, counts_in, ivalues_in, True, ctx_hook,
+            rearm, tasks_in, ready_in, counts_in, ivalues_in, True, ctx_hook,
             complete_hook if (self.migratable and self.homed) else None,
             value_limit=RBASE,
             lanes=lanes, lstate=lstate, tstats=tstats,
@@ -2112,6 +2116,7 @@ class ResidentKernel:
                 ctl_out[i] = 0
         if plan is not None:
             fstats[FS_HB] = pstate[PS_HB]
+        fstats[FS_BECAME] = rearm[RA_BECAME]
         # Credit drain: every executed round ran every hop, and the first
         # send of each credited channel never waited - exactly one
         # outstanding credit per used channel once any round ran. Under a
@@ -2193,10 +2198,11 @@ class ResidentKernel:
         aliases = {0: 0, 2: 1, 3: 2, 4: 3}
         for i in range(ndata):
             aliases[5 + i] = 4 + i
-        free, vfree, *lane_scratch = mk.core_scratch()
+        free, vfree, rearm, *lane_scratch = mk.core_scratch()
         scratch = list(mk.scratch_specs.values()) + [
             free,
             vfree,
+            rearm,
             pltpu.SMEM((self.scan,), jnp.int32),  # candbuf
             pltpu.SMEM((W + 1, DESC_WORDS), jnp.int32),  # sendbuf
             pltpu.SMEM((self.S,), jnp.int32),  # statacc
@@ -2689,6 +2695,7 @@ class ResidentKernel:
             "exported": [f["steal_exported"] for f in fs],
             "imported": [f["steal_imported"] for f in fs],
         }
+        info["became"] = sum(f["became"] for f in fs)
         info["aborted"] = any(f["abort_round"] >= 0 for f in fs)
         if self.T:
             # The stacked tctl echo (lane cursors + cumulative install/

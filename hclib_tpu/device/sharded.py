@@ -53,6 +53,7 @@ from .megakernel import (
     C_TAIL,
     C_VALLOC,
     Megakernel,
+    TS_BECAME,
     TS_WORDS,
     ran_on,
 )
@@ -334,7 +335,6 @@ class ShardedMegakernel:
         with self._maybe_untraced():
             inner = self.mk._build_raw(fuel)
         ndata = len(self.mk.data_specs)
-        nbatch = 1 if self.mk.batch_specs else 0
         axis = self.axis
 
         def step(tasks, succ, ring, counts, iv, *data):
@@ -343,8 +343,8 @@ class ShardedMegakernel:
             )
             tasks_o, ready_o, counts_o, iv_o = outs[:4]
             data_o = outs[4 : 4 + ndata]
-            # Batched-tier counters ride last (appended by _build_raw when
-            # any kind is batch-routed): surfaced per device.
+            # The tier counters ride last (appended by _build_raw):
+            # surfaced per device.
             tstats_o = outs[4 + ndata :]
             # Global termination/health: executed/pending/overflow summed
             # across the mesh (the reference's done-flag join becomes a
@@ -363,7 +363,7 @@ class ShardedMegakernel:
             step,
             mesh=self.mesh,
             in_specs=(P(self.axis),) * nin,
-            out_specs=(P(self.axis),) * (3 + ndata + nbatch),
+            out_specs=(P(self.axis),) * (4 + ndata),
             check_vma=False,
         )
         return jax.jit(f)
@@ -393,7 +393,6 @@ class ShardedMegakernel:
         with self._maybe_untraced():
             inner = self.mk._build_raw(quantum, stage_all_values=True)
         ndata = len(self.mk.data_specs)
-        nbatch = 1 if self.mk.batch_specs else 0
         axis = self.axis
         ndev = self.ndev
         cap = self.mk.capacity
@@ -520,13 +519,12 @@ class ShardedMegakernel:
                 outs = inner(tasks, succ0, ring_, counts, iv, *data)
                 tasks, ring_, counts, iv = outs[:4]
                 data = tuple(outs[4 : 4 + ndata])
-                if nbatch:
-                    # tstats resets at every kernel entry (per-entry
-                    # scratch semantics), so the steal loop accumulates
-                    # the rounds' counters into a cumulative per-device
-                    # row - occupancy over the whole run, not the last
-                    # quantum.
-                    tacc = tacc + outs[4 + ndata]
+                # tstats resets at every kernel entry (per-entry
+                # scratch semantics), so the steal loop accumulates
+                # the rounds' counters into a cumulative per-device
+                # row - occupancy over the whole run, not the last
+                # quantum.
+                tacc = tacc + outs[4 + ndata]
                 for d in hop_dists:
                     perm = [(i, (i + d) % ndev) for i in range(ndev)]
                     tasks, ring_, counts = exchange(tasks, ring_, counts, perm)
@@ -547,7 +545,7 @@ class ShardedMegakernel:
                 iv_o[None],
                 gcounts[None],
                 *[d[None] for d in data_o],
-                *([tacc_o[None]] if nbatch else []),
+                tacc_o[None],
             )
 
         nin = 5 + ndata
@@ -555,7 +553,7 @@ class ShardedMegakernel:
             step,
             mesh=self.mesh,
             in_specs=(P(self.axis),) * nin,
-            out_specs=(P(self.axis),) * (3 + ndata + nbatch),
+            out_specs=(P(self.axis),) * (4 + ndata),
             check_vma=False,
         )
         return jax.jit(f)
@@ -630,12 +628,13 @@ class ShardedMegakernel:
             self._pc_stats["build_s"] += (t1_ns - t0_ns) / 1e9
         if self._pc_stats is not None:
             info["program_cache"] = dict(self._pc_stats)
-        tail = info.pop("extra_outputs", None)
-        if self.mk.batch_specs and tail:
-            # Per-device batched-tier counters (cumulative over the steal
-            # rounds on the steal path): info['tiers'][d] mirrors the
-            # single-device decode, so mesh occupancy reads the same way.
-            trows = tail[-1]
+        # Per-device tier counters (cumulative over the steal rounds on
+        # the steal path).
+        trows = info.pop("extra_outputs")[-1]
+        info["became"] = int(trows[:, TS_BECAME].sum())
+        if self.mk.batch_specs:
+            # info['tiers'][d] mirrors the single-device decode, so mesh
+            # occupancy reads the same way.
             info["tiers"] = [
                 self.mk.decode_tier_stats(trows[d])
                 for d in range(self.ndev)

@@ -295,6 +295,28 @@ def test_classification_and_describe():
     assert d["verify"] is True
 
 
+def test_a_row_rearmed_in_place_is_a_continuation_to_the_shim():
+    """``ctx.become`` is the fork-join ``take_continuation`` is, on the
+    task's own row: a kind that re-arms and spawns only link-free
+    children still leaves its links behind for a continuation, so it is
+    home-linked, not link-free."""
+    from hclib_tpu.analysis.classify import trace_class
+    from hclib_tpu.analysis.shim import run_scalar_kernel
+
+    def rearm_over_link_free_children(ctx):
+        ctx.become(1, 1)
+        ctx.spawn(2, [ctx.arg(0)], nargs=1)
+
+    def only_link_free_children(ctx):
+        ctx.spawn(2, [ctx.arg(0)], nargs=1)
+
+    t = run_scalar_kernel(rearm_over_link_free_children, {}, {})
+    assert t.continuations == 1
+    assert trace_class(t) == "home-linked"
+    t0 = run_scalar_kernel(only_link_free_children, {}, {})
+    assert t0.continuations == 0 and trace_class(t0) == "link-free"
+
+
 def test_migratable_audit_and_suppression():
     mk = make_fib_megakernel(128, interpret=True)
     rep = check_migratable(mk, [FIB], "test")

@@ -430,18 +430,22 @@ def test_bucket_gauges_ride_metrics():
 
 def test_smem_model_charges_the_core_scratch_it_declares(monkeypatch):
     """``core_scratch`` is the one declaration of the scheduler core's
-    scratch (free stacks; lanes and lstate over kinds x buckets):
+    scratch (free stacks; the re-arm words, a mark a batch slot; lanes
+    and lstate over kinds x buckets):
     ``smem_footprint`` charges exactly those words for it, at this
     build's capacity and at another."""
-    from hclib_tpu.device.megakernel import LS_WORDS, VBLOCK, smem_bytes
+    from hclib_tpu.device.megakernel import (
+        LS_WORDS, RA_MARK, VBLOCK, smem_bytes,
+    )
 
     mk = _seq_mk(4, lambda arg: arg(0) // 2)
     assert mk.lane_scratch_rows == 4  # one routed kind x four buckets
     for cap in (mk.capacity, 40):
         shapes = [s.shape for s in mk.core_scratch(cap)]
         assert shapes == [
-            (cap + 1,), (mk.num_values // VBLOCK + 1,), (4, cap),
-            (4, LS_WORDS),
+            (cap + 1,), (mk.num_values // VBLOCK + 1,),
+            (RA_MARK + max(sp.width for _, sp in mk.batch_specs),),
+            (4, cap), (4, LS_WORDS),
         ]
         whole = mk.smem_footprint(cap)
         with monkeypatch.context() as m:

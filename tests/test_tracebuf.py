@@ -274,6 +274,10 @@ def test_streaming_megakernel_traces_injection():
     sm = StreamingMegakernel(mk, ring_capacity=16)
     b = TaskGraphBuilder()
     b.add(FIB, args=[8], out=0)
+    # The injected row's out slot is the host's: undeclared it lies in
+    # row 0's own value block, where the root keeps its children's
+    # results since it re-arms in place (ctx.become).
+    b.reserve_values(2)
     sm.inject(FIB, [6], out=1)
     sm.close()
     iv, info = sm.run_stream(b, quantum=64, max_rounds=8)
