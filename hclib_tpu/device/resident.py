@@ -168,6 +168,7 @@ from .descriptor import (
     TaskGraphBuilder,
 )
 from ..runtime.resilience import DeviceFaultPlan, StallError
+from .inject import region_slot
 from .tenants import (
     TC_CONSUMED,
     TC_DROPPED,
@@ -1510,7 +1511,9 @@ class ResidentKernel:
                 regions. Per lane visit it installs at most ``weight``
                 rows, never more than the scheduler's live
                 ``headroom()`` (a full task table turns into ring
-                backpressure the host reads off the cursor echo), drops
+                backpressure the host reads off the cursor echo; row
+                ``c`` of a lane lies in slot ``c`` modulo the region,
+                ``region_slot``, so a region recycles), drops
                 rows the host marked expired (counted: FS_TEN_EXPIRED +
                 the tctl echo + a TR_TENANT record), and sweeps paused
                 lanes. Quiescing rounds freeze the scan entirely -
@@ -1537,8 +1540,10 @@ class ResidentKernel:
                         c, inst, exp = carry
                         base = (c // 8) * 8
                         rp = pltpu.make_async_copy(
-                            iring.at[pl.ds(lane * region + base, 8)],
-                            rowbuf, isem.at[1],
+                            iring.at[pl.ds(
+                                lane * region
+                                + region_slot(c // 8, region // 8) * 8, 8,
+                            )], rowbuf, isem.at[1],
                         )
                         rp.start()
                         rp.wait()

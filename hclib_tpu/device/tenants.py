@@ -15,6 +15,9 @@ a traffic-shaped, fault-isolated front door:
 - **Ring regions + WRR poll** (device side, device/inject.py): the
   injection ring is partitioned into per-tenant contiguous regions, each
   with its own tail/consumed cursor in a per-tenant ``tctl`` control row.
+  The cursors count rows for the stream's whole life and a row's slot is
+  its index modulo the region, so a region recycles: it bounds what a
+  lane holds at once, not what a stream serves.
   The in-kernel poll visits lanes weighted-round-robin INSIDE the device
   round loop - up to ``weight`` rows per lane per poll, rotating the
   start lane every round - and consumes at most the scheduler's live
@@ -26,11 +29,13 @@ a traffic-shaped, fault-isolated front door:
   ``Admission`` verdict - ``ACCEPTED`` (within the tenant's in-flight
   budget; publishes at the next entry), ``QUEUED`` (over budget but the
   host backlog has room), or ``REJECTED(reason)`` (rate / backlog / ring
-  budget / expired / quarantined / cancelled / closed). Quotas are a
+  occupancy / expired / quarantined / cancelled / closed). Quotas are a
   per-tenant in-flight task budget plus an enqueue-rate ``TokenBucket``
   (injectable clock, so rate decisions are deterministic under test).
-  ``submit(wait=True)`` converts rate/backlog rejections into a blocking
-  wait with bounded exponential backoff.
+  ``submit(wait=True)`` converts rate/backlog/ring rejections into a
+  blocking wait with bounded exponential backoff: all three clear by
+  themselves (the bucket refills, the pump drains the backlog, the
+  consume cursor frees the region's slots).
 
 - **Deadline admission** (resilience.CancelScope deadlines): a
   submission carries a deadline from ``deadline_s=``, the nearest
@@ -148,8 +153,10 @@ __all__ = [
 # ---- tctl ABI: one 8-word int32 control row per tenant lane, published
 # by the host at every entry and echoed back (cumulative counters are
 # host-seeded so they survive entries, resumes, and reshards).
-TC_TAIL = 0       # rows published into this lane's ring region
-TC_CONSUMED = 1   # device consume cursor (region-relative; echo)
+TC_TAIL = 0       # rows ever published into this lane's region (all-time)
+TC_CONSUMED = 1   # device consume cursor (all-time; echo). A row's slot in
+                  # the region is its index modulo region_rows: both sides
+                  # wrap the same way (wrr_poll_reference is the spec)
 TC_WEIGHT = 2     # WRR credit: rows this lane may install per poll
 TC_PAUSE = 3      # nonzero = poll skips the lane (throttle/quarantine)
 TC_EXPIRED = 4    # cumulative rows dropped expired at the poll (echo)
@@ -396,7 +403,8 @@ class _Pending:
         self.row = row
         self.deadline_at = deadline_at
         self.t_submit = t_submit
-        self.index = -1     # region-relative publish index (once published)
+        self.index = -1     # all-time publish index in its lane (once
+                            # published); its slot is index % region_rows
         self.marked = False  # host marked TEN_EXPIRED on the ring
         # Submit token of a tracked request (rides the row's TEN_TOKEN
         # word; 0 = untracked). Zeroed once its future reached a
@@ -431,6 +439,7 @@ class _Lane:
         "published", "consumed", "dev_expired", "dev_dropped", "installed",
         "accepted", "rejected", "expired_host", "poisoned", "dropped",
         "throttled", "quarantined", "latencies", "timed",
+        "latency_sum", "latency_n",
     )
 
     def __init__(self, spec: TenantSpec, idx: int, parent_scope,
@@ -462,6 +471,11 @@ class _Lane:
         self.throttled = False
         self.quarantined: Optional[str] = None
         self.latencies: deque = deque(maxlen=2048)
+        # Every admission-to-install sample ever taken, as a sum and a
+        # count: the reservoir above forgets, a long stream's mean
+        # must not.
+        self.latency_sum = 0.0
+        self.latency_n = 0
 
     @property
     def in_flight(self) -> int:
@@ -482,8 +496,14 @@ class _Lane:
 class TenantTable:
     """The host half of the front door: N lanes over one injection ring
     partitioned into ``region_rows``-row regions (lane i owns ring rows
-    ``[i * region_rows, (i + 1) * region_rows)``). Thread-safe: any
-    thread admits while the stream driver pumps/absorbs."""
+    ``[i * region_rows, (i + 1) * region_rows)``). A region RECYCLES:
+    the lane's cursors (``published``, ``consumed``, TC_TAIL,
+    TC_CONSUMED) count rows for the stream's whole life and a row lives
+    in slot ``index % region_rows``, so a slot is written again once the
+    consume cursor has passed it, and a region bounds what a lane holds
+    at once (published and unconsumed, plus its host backlog), not what
+    it serves. Thread-safe: any thread admits while the stream driver
+    pumps/absorbs."""
 
     def __init__(self, specs: Sequence[TenantSpec], region_rows: int,
                  clock: Callable[[], float] = time.monotonic,
@@ -504,6 +524,11 @@ class TenantTable:
         self.clock = clock
         self.scope = CancelScope()
         self._lock = threading.Lock()
+        # Room appears where a pump takes rows off a backlog and where
+        # an absorb moves a consume cursor: both tell it here, and a
+        # submit(wait=True) held back by "backlog" or "ring" sleeps on
+        # it (wait_room) instead of guessing how long.
+        self._room = threading.Condition(self._lock)
         # Set under the lock by export_state (quiesce cut) and
         # close_if_drained (normal drain exit): a submit racing either
         # stream exit lands before it (its row rides along in the
@@ -516,10 +541,6 @@ class TenantTable:
         # gauge and the next pump stamps it onto newly published rows'
         # TEN_ADMIT_ROUND word. 0 = telemetry off / first entry.
         self._admit_round = 0
-        # Rows of the host ring pump() has written so far (published or
-        # marked expired): the stream driver re-uploads the ring only
-        # when this moved since the copy the chip holds.
-        self.ring_writes = 0
         self._lanes: List[_Lane] = [
             _Lane(s, i, self.scope, clock) for i, s in enumerate(specs)
         ]
@@ -654,12 +675,14 @@ class TenantTable:
                 queued = len(lane.queue)
                 if self._closed:
                     reason = "closed"
-                # Ring lifetime budget: the region is a linear append
-                # log per stream (device/inject.py), so published +
-                # queued rows may never exceed it - rejecting here keeps
-                # QUEUED an eventual-service promise instead of a silent
-                # wedge.
-                elif lane.published + queued >= self.region_rows:
+                # Ring occupancy: what the lane holds at once (published
+                # and unconsumed, plus its backlog) never exceeds the
+                # region, so a published row is never overwritten
+                # before the device consumed it. Backpressure, not a
+                # lifetime budget: it clears as the consume cursor
+                # echoes forward (a region nothing consumes stays full).
+                elif (lane.published - lane.consumed + queued
+                        >= self.region_rows):
                     reason = "ring"
                 elif queued >= spec.queue_capacity:
                     reason = "backlog"
@@ -836,12 +859,23 @@ class TenantTable:
         with self._lock:
             self._admit_round = int(r)
 
-    def pump(self, ring: np.ndarray) -> np.ndarray:
+    def _wrote(self, dirty: Optional[list], lo: int, n: int) -> None:
+        """``pump`` stored ring rows ``[lo, lo + n)`` (published or
+        marked expired): named to a caller that asked which."""
+        if dirty is not None:
+            dirty.append((lo, n))
+
+    def pump(self, ring: np.ndarray,
+             dirty: Optional[list] = None) -> np.ndarray:
         """Expire, publish, and build the tctl block for one entry:
         drops expired host-queued rows, marks expired published rows for
-        the device poll to drop, publishes backlog into each lane's ring
-        region up to its in-flight budget, and returns the (T, 8) tctl
-        array the entry uploads. What it does it decides from what the
+        the device poll to drop, publishes backlog into the free slots of
+        each lane's ring region (the slot of row ``i`` is ``i %
+        region_rows``) up to its in-flight budget, and returns the (T, 8)
+        tctl array the entry uploads. ``dirty``, a list, gets one ``(first
+        ring row, rows)`` run appended for every store into ``ring``, so
+        a driver that keeps a copy of the ring elsewhere can send just
+        those rows after it. What it does it decides from what the
         lane holds: published rows are walked for lapsed deadlines only
         in a lane that has a deadline-bearing one (``_Lane.timed``),
         and a backlog's head run that needs no look at each row (no
@@ -888,31 +922,29 @@ class TenantTable:
                         and p.deadline_at is not None
                         and now >= p.deadline_at
                     ):
-                        ring[base + p.index, TEN_EXPIRED] = 1
-                        self.ring_writes += 1
+                        slot = base + p.index % self.region_rows
+                        ring[slot, TEN_EXPIRED] = 1
+                        self._wrote(dirty, slot, 1)
                         p.marked = True
                         # The client learns EXPIRED the moment the host
                         # knows, not when the device sweeps the row.
                         self._expire_token_locked(p, "deadline (on ring)")
-                # Publish backlog into the region, respecting the
-                # in-flight budget (budget freed as the consume cursor
+                # Publish backlog into the region's free slots, respecting
+                # the in-flight budget (both freed as the consume cursor
                 # echoes forward).
                 cap = (
                     self.region_rows if spec.max_in_flight is None
-                    else spec.max_in_flight
+                    else min(spec.max_in_flight, self.region_rows)
                 )
                 if (
                     spec.validator is None
                     and ring.flags.c_contiguous
                     and not lane.paused()
                 ):
-                    self._publish_run_locked(lane, ring, min(
-                        self.region_rows - lane.published,
-                        cap - lane.in_flight,
-                    ))
+                    self._publish_run_locked(
+                        lane, ring, cap - lane.in_flight, dirty)
                 while (
                     lane.queue
-                    and lane.published < self.region_rows
                     and lane.in_flight < cap
                     and not lane.paused()
                 ):
@@ -925,7 +957,8 @@ class TenantTable:
                         lane, p
                     ):
                         continue
-                    ring[base + lane.published] = p.row
+                    slot = base + lane.published % self.region_rows
+                    ring[slot] = p.row
                     # Telemetry admit stamp - PRESERVE a nonzero word:
                     # residue re-published after a checkpoint cut keeps
                     # its ORIGINAL admission round (the round gauge is
@@ -933,17 +966,14 @@ class TenantTable:
                     # spans the preemption, not just the resumed tail.
                     if (
                         self._admit_round
-                        and ring[base + lane.published,
-                                 TEN_ADMIT_ROUND] == 0
+                        and ring[slot, TEN_ADMIT_ROUND] == 0
                     ):
-                        ring[base + lane.published, TEN_ADMIT_ROUND] = (
-                            self._admit_round
-                        )
+                        ring[slot, TEN_ADMIT_ROUND] = self._admit_round
                     p.index = lane.published
                     lane.pub_meta.append(p)
                     lane.timed += p.deadline_at is not None
                     lane.published += 1
-                    self.ring_writes += 1
+                    self._wrote(dirty, slot, 1)
                 tctl[lane.idx, TC_TAIL] = lane.published
                 tctl[lane.idx, TC_CONSUMED] = lane.consumed
                 tctl[lane.idx, TC_WEIGHT] = (
@@ -952,38 +982,45 @@ class TenantTable:
                 tctl[lane.idx, TC_PAUSE] = 1 if lane.paused() else 0
                 tctl[lane.idx, TC_EXPIRED] = lane.dev_expired
                 tctl[lane.idx, TC_INSTALLED] = lane.installed
+            self._room.notify_all()
         return tctl
 
     def _publish_run_locked(self, lane: _Lane, ring: np.ndarray,
-                            room: int) -> None:
+                            room: int, dirty: Optional[list]) -> None:
         """Publish the head run of the lane's backlog that needs no
         look at each row - no deadline (the lane has no validator, the
         caller saw) - up to ``room`` rows, by ONE store of the rows,
         joined, into the lane's region (a view of a ring that is
-        contiguous, the caller saw too). The admit-round stamp goes
-        onto the slice's zero words only, as row by row: residue
-        re-published after a checkpoint cut keeps its original
-        admission round. A row with a deadline ends the run; ``pump``'s
-        row-by-row loop takes the backlog from there."""
+        contiguous, the caller saw too); a run that crosses the
+        region's end is two stores, up to the end and from slot 0. The
+        admit-round stamp goes onto the slice's zero words only, as row
+        by row: residue re-published after a checkpoint cut keeps its
+        original admission round. A row with a deadline ends the run;
+        ``pump``'s row-by-row loop takes the backlog from there."""
         run = list(itertools.takewhile(
             lambda p: p.deadline_at is None,
             itertools.islice(lane.queue, max(0, room)),
         ))
         if not run:
             return
-        lo = lane.idx * self.region_rows + lane.published
-        block = ring[lo:lo + len(run)]
-        np.concatenate([p.row for p in run], out=block.reshape(-1))
-        if self._admit_round:
-            stamps = block[:, TEN_ADMIT_ROUND]
-            stamps[stamps == 0] = self._admit_round
+        base = lane.idx * self.region_rows
+        slot = lane.published % self.region_rows
+        head = min(len(run), self.region_rows - slot)
+        for part, lo in ((run[:head], base + slot), (run[head:], base)):
+            if not part:
+                continue
+            block = ring[lo:lo + len(part)]
+            np.concatenate([p.row for p in part], out=block.reshape(-1))
+            if self._admit_round:
+                stamps = block[:, TEN_ADMIT_ROUND]
+                stamps[stamps == 0] = self._admit_round
+            self._wrote(dirty, lo, len(part))
         for _ in run:
             lane.queue.popleft()
         for index, p in enumerate(run, lane.published):
             p.index = index
         lane.pub_meta.extend(run)
         lane.published += len(run)
-        self.ring_writes += len(run)
 
     def _validate(self, lane: _Lane, p: _Pending) -> bool:
         """Run the lane's validator with IMMEDIATE retries per its
@@ -1045,9 +1082,12 @@ class TenantTable:
                     # and none of them can be marked (no row of the
                     # lane has a deadline): one pass, one extend.
                     gone = min(new_consumed - lane.consumed, len(pub))
-                    lane.latencies.extend([
+                    waited = [
                         now - pub.popleft().t_submit for _ in range(gone)
-                    ])
+                    ]
+                    lane.latencies.extend(waited)
+                    lane.latency_sum += sum(waited)
+                    lane.latency_n += gone
                 # Row by row where a row may be marked or was swept
                 # (after the pass above nothing is left to take).
                 while pub and pub[0].index < new_consumed:
@@ -1055,6 +1095,8 @@ class TenantTable:
                     lane.timed -= p.deadline_at is not None
                     if not p.marked and not swept:
                         lane.latencies.append(now - p.t_submit)
+                        lane.latency_sum += now - p.t_submit
+                        lane.latency_n += 1
                     elif swept and self.futures is not None and p.token:
                         # Device SWEEP of a paused lane: the row was
                         # consumed without installing - resolve its
@@ -1071,10 +1113,24 @@ class TenantTable:
                 d = int(tctl_out[lane.idx, TC_DROPPED])
                 lane.dev_dropped += d
                 lane.dropped += d
+            self._room.notify_all()
+
+    def wait_room(self, timeout: float) -> None:
+        """Sleep until the next ``pump`` or ``absorb`` (where a backlog
+        shrinks and a region's slots come free), ``timeout`` seconds at
+        most: what a producer held back by backpressure waits for."""
+        with self._room:
+            self._room.wait(timeout)
 
     def total_published(self) -> int:
         with self._lock:
             return sum(lane.published for lane in self._lanes)
+
+    def queued(self) -> int:
+        """Rows admitted and not yet published, over all lanes: what the
+        next pump has to look at."""
+        with self._lock:
+            return sum(len(lane.queue) for lane in self._lanes)
 
     def _drained_locked(self) -> bool:
         return all(
@@ -1108,7 +1164,9 @@ class TenantTable:
         """The per-tenant half of a quiesce export: residue rows (host
         backlog + published-but-unconsumed, tenant-tagged; rows already
         expired - host-marked on the ring OR past their deadline at the
-        cut - are folded into the expired count rather than carried),
+        cut - are folded into the expired count rather than carried;
+        the published ones are rows ``[consumed, published)`` of the
+        lane, read from their slots modulo the region),
         plus the cumulative tctl/tstats counter blocks. Deadlines
         SURVIVE the cut as remaining budget: each live residue row is
         stamped with ``TEN_DEADLINE_MS`` (milliseconds left at export;
@@ -1138,7 +1196,7 @@ class TenantTable:
             for lane in self._lanes:
                 base = lane.idx * self.region_rows
                 for p in lane.pub_meta:
-                    carry(lane, p, ring[base + p.index])
+                    carry(lane, p, ring[base + p.index % self.region_rows])
                 lane.pub_meta.clear()
                 lane.timed = 0
                 for p in lane.queue:
@@ -1182,9 +1240,10 @@ class TenantTable:
     def resume_from(self, state: Dict[str, Any]) -> None:
         """Seed the lanes from a checkpointed state: cumulative counters
         restore from tctl/tstats and residue rows re-enter their lanes'
-        host backlogs (re-published by the next pump from region slot 0,
-        so per-tenant accepted/completed/expired/backlog counts are
-        conserved exactly across the cut). Rows carrying a stamped
+        host backlogs (the lanes' cursors restart at 0, so the next pump
+        re-publishes them from region slot 0, and per-tenant
+        accepted/completed/expired/backlog counts are conserved exactly
+        across the cut). Rows carrying a stamped
         ``TEN_DEADLINE_MS`` remaining budget re-arm their deadlines
         against THIS table's clock."""
         if "tctl" not in state or "tstats" not in state:
@@ -1251,9 +1310,9 @@ class TenantTable:
                 self._adopt_row_locked(self._lanes[t], r)
             for lane in self._lanes:
                 # The same residue-vs-capacity guard the plain stream
-                # raises: a lane's re-published residue must fit its
-                # ring region, or the pump could never drain the queue
-                # and a closed stream would re-enter forever.
+                # raises: a lane never holds more than its region (the
+                # occupancy gate of ``_admit``), so residue that does
+                # was cut from a larger stream than this one.
                 if len(lane.queue) > self.region_rows:
                     raise ValueError(
                         f"tenant {lane.spec.id!r}: resume residue "
@@ -1287,7 +1346,10 @@ class TenantTable:
     def stats(self) -> Dict[str, Dict[str, Any]]:
         """Per-tenant counter snapshot keyed by tenant id (numbers plus
         the quarantine reason string; MetricsRegistry flattening drops
-        strings by design). ``completed`` counts INSTALLS - rows the
+        strings by design). ``latency_sum_s`` over ``latency_n`` is the
+        mean admission-to-install time over every row the lane ever
+        installed (``latency_stats`` reads a bounded reservoir of the
+        newest). ``completed`` counts INSTALLS - rows the
         device poll handed to the scheduler, which a non-aborted stream
         runs to completion before returning (the megakernel executes
         every installed task or the run errors); the same install event
@@ -1305,6 +1367,11 @@ class TenantTable:
                     "in_flight": lane.in_flight,
                     "published": lane.published,
                     "consumed": lane.consumed,
+                    # times the lane has filled its region and started
+                    # over at slot 0
+                    "wraps": lane.published // self.region_rows,
+                    "latency_sum_s": lane.latency_sum,
+                    "latency_n": lane.latency_n,
                     "poisoned": lane.poisoned,
                     "dropped": lane.dropped,
                     "throttled": int(lane.throttled),
@@ -1575,7 +1642,8 @@ class MeshTenantTable:
             # pass over a full device before any quota is charged (the
             # probe is advisory - the routed admit re-checks under its
             # own lock).
-            if lane.published + len(lane.queue) >= self.region_rows:
+            if (lane.published - lane.consumed + len(lane.queue)
+                    >= self.region_rows):
                 last_reason = "ring"
                 continue
             if len(lane.queue) >= lane.spec.queue_capacity:
@@ -2225,8 +2293,10 @@ def wrr_poll_reference(ring: np.ndarray, tctl: np.ndarray,
     (and mean the same thing) without Mosaic interpret. Semantics
     mirrored exactly: visit lane ``(round_idx + k) % T`` for k in
     [0, T), install at most ``min(weight, avail, headroom-left)`` rows
-    from the lane's ring region, drop host-marked TEN_EXPIRED rows
-    (counted, not installed), and sweep paused lanes - cursor jumps to
+    from the lane's ring region - the cursors count rows for the
+    stream's whole life and row ``c`` lies in slot ``c % region_rows``
+    of its region, so a region recycles - drop host-marked TEN_EXPIRED
+    rows (counted, not installed), and sweep paused lanes - cursor jumps to
     tail, swept rows counted in TC_DROPPED, nothing installed. Mutates
     ``tctl`` in place exactly like the device echo (feed it back through
     ``TenantTable.absorb``); returns the installed rows in install
@@ -2248,7 +2318,7 @@ def wrr_poll_reference(ring: np.ndarray, tctl: np.ndarray,
         )
         inst = exp = 0
         for c in range(cons, cons + take):
-            row = ring[lane * region_rows + c]
+            row = ring[lane * region_rows + c % region_rows]
             if int(row[TEN_EXPIRED]) != 0:
                 exp += 1
             else:

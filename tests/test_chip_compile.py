@@ -237,8 +237,9 @@ def _forasync_hbm(sh):
         tk, width=8, interpret=False, space=(bounds, tile)), sh)
 
 
-def _serve_stream(sh):
-    """chip_smoke's serve phase: three tenants, egress mailbox, telemetry."""
+def _serve_stream(sh, delta=False):
+    """chip_smoke's serve phase: three tenants, egress mailbox, telemetry
+    (the tenant poll fetches row ``c`` from slot ``c`` modulo the region)."""
     from hclib_tpu.device.descriptor import RING_ROW, TaskGraphBuilder
     from hclib_tpu.device.egress import EGR_WORDS, EgressSpec
     from hclib_tpu.device.inject import StreamingMegakernel
@@ -272,9 +273,19 @@ def _serve_stream(sh):
     ]))
     # What the chip runs is the entry program: the kernel between the
     # slab's split and join (one transfer each way an entry).
-    slab = z(sum(blocks[n].size for n in lay.up))
+    up = {**lay.up, **(sm._delta_blocks() if delta else {})}
+    slab = z(sum(int(np.prod(shape)) for shape in up.values()))
     args = [slab] + [blocks[n] for n in lay.kept]
-    sm._build_entry(1 << 10, 64).lower(*_shapes(args, sh)).compile()
+    text = sm._build_entry(1 << 10, 64, delta).lower(
+        *_shapes(args, sh)).compile().as_text()
+    assert "tpu_custom_call" in text and ("scatter" in text) == delta
+
+
+def _serve_stream_delta(sh):
+    """The same stream's other entry program (serve-open-steady runs both):
+    the slab also carries up to RING_DELTA_ROWS ring rows, which a scatter
+    stores into the resident, donated ring in front of the kernel."""
+    _serve_stream(sh, delta=True)
 
 
 def _frontier(sh):
@@ -314,7 +325,7 @@ KERNELS = {
     f.__name__.lstrip("_"): f
     for f in (_fib_scalar, _fib_batch, _uts_t1l, _cholesky_8192, _sw_fused,
               _sw_wave, _forasync_1d, _forasync_2d, _forasync_hbm,
-              _serve_stream,
+              _serve_stream, _serve_stream_delta,
               _frontier, _dyngraph, _bnb)
 }
 
@@ -324,14 +335,19 @@ def test_v5e_compiler_accepts(kernel, one_chip):
     KERNELS[kernel](one_chip)
 
 
-def test_v5e_compiler_accepts_resident_kernel_on_four_devices(topo):
+@pytest.mark.parametrize("tenants", [False, True], ids=["steal", "tenants"])
+def test_v5e_compiler_accepts_resident_kernel_on_four_devices(topo, tenants):
     """chip_smoke.py --four-chips' program: the shard_mapped resident
     kernel over a mesh of the four described devices, every input sharded
     over all of them, with the kernel and the termination collective in
-    the compiled module."""
+    the compiled module. ``tenants``: the same mesh with an injection
+    ring cut into three tenant regions of 1,024 rows a device, so the
+    mesh's tenant poll (its 8-row chunk fetched through ``region_slot``,
+    the wrap Mosaic refused as first written) is in the kernel; no cell
+    runs that build on the chip."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from hclib_tpu.device.descriptor import TaskGraphBuilder
+    from hclib_tpu.device.descriptor import RING_ROW, TaskGraphBuilder
     from hclib_tpu.device.megakernel import VBLOCK
     from hclib_tpu.device.resident import ResidentKernel
     from hclib_tpu.device.sharded import abort_words, partition_builders
@@ -343,16 +359,27 @@ def test_v5e_compiler_accepts_resident_kernel_on_four_devices(topo):
         capacity=cap, interpret=False,
         num_values=VBLOCK * cap + max(64, roots),
     )
+    lanes = dict(
+        inject=True, tenants=["gold", "silver", "bronze"],
+        ring_capacity=3 * 1024,
+    ) if tenants else {}
     rk = ResidentKernel(mk, mesh, migratable_fns=[FIB], homed=False,
-                        window=16)
+                        window=16, **lanes)
     tasks, succ, ring, counts = partition_builders(
         mk, ndev, [TaskGraphBuilder() for _ in range(ndev)]
     )
     args = [  # ResidentKernel.run's argument order, steal-only build
         tasks, succ, ring, counts, np.zeros((ndev, mk.num_values), np.int32),
         np.zeros((ndev, rk.max_waits + 1, 3), np.int32),
-        abort_words(None, ndev),
     ]
+    if tenants:  # iring, ictl, the stacked tctl block
+        assert rk.region_rows == 1024
+        args += [
+            np.zeros((ndev, rk.ring_capacity, RING_ROW), np.int32),
+            np.zeros((ndev, 8), np.int32),
+            np.zeros((ndev, rk.T, 8), np.int32),
+        ]
+    args.append(abort_words(None, ndev))
     compiled = rk._build(256, 1 << 14, None).lower(
         *_shapes(args, NamedSharding(mesh, P("q")))
     ).compile()

@@ -270,7 +270,9 @@ def test_an_open_stream_names_its_idle_sleep(recorded):
 # ------------------------------------------------- the metrics' readers
 
 FRONT = {"source": "program_span", "layer": "front door",
-         "moves": "req_per_s", "workloads": [SERVE]}
+         "moves": "req_per_s",
+         # the open-loop cell of PR 44 joined the lists
+         "workloads": [SERVE, "serve-open-steady"]}
 STAGING = {"layer": "host staging", "moves": "solve_ms",
            "workloads": [CHOL, SW, FA]}
 # name: (reducer, args, unit, the rest of the entry, value on HOST below)

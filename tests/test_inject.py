@@ -114,18 +114,19 @@ def test_streaming_on_tpu():
 
 # ---- the entry boundary (ISSUE 32): what crosses the host link an entry
 
-LINK_KEYS = {"entries", "uploads", "downloads", "ring_uploads", "idle_sleeps",
-             "settled", "settle_batches"}
+LINK_KEYS = {"entries", "uploads", "downloads", "ring_uploads", "ring_deltas",
+             "ring_rows_up", "idle_sleeps", "settled", "settle_batches"}
 
 
 def counting_pumps(table):
     """Count the pumps that wrote the host ring."""
     wrote, pump = [], table.pump
 
-    def counted(ring):
-        before = table.ring_writes
-        out = pump(ring)
-        wrote.append(table.ring_writes != before)
+    def counted(ring, dirty=None):
+        dirty = [] if dirty is None else dirty
+        before = len(dirty)
+        out = pump(ring, dirty)
+        wrote.append(len(dirty) != before)
         return out
 
     table.pump = counted
@@ -134,11 +135,12 @@ def counting_pumps(table):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_closed_burst_one_upload_one_download_an_entry(kind):
-    """A closed burst over several entries: the ring goes up once for
-    each pump that published (a lane budget of 4 in flight makes that
-    several), the loop never sleeps, and the host link is crossed at
-    most twice an entry each way - with the totals a burst has always
-    given."""
+    """A closed burst over several entries: the ring goes up whole once,
+    with the first entry, and every later pump that published (a lane
+    budget of 4 in flight makes that several) sends just its rows in
+    that entry's slab; the loop never sleeps, and the host link is
+    crossed at most twice an entry each way - with the totals a burst
+    has always given."""
     n = 24
     sm, table = front_door(kind, max_in_flight=4)
     wrote = counting_pumps(table) if table is not None else None
@@ -161,10 +163,15 @@ def test_closed_burst_one_upload_one_download_an_entry(kind):
     assert link["settled"] == (n if kind in ("egress", "telemetry") else 0)
     assert link["settle_batches"] <= link["entries"]
     assert (link["settle_batches"] >= 3) == (link["settled"] > 0)
+    assert link["ring_uploads"] == 1
     if table is None:
-        assert link["ring_uploads"] == 1
+        assert link["ring_deltas"] == 0
+        assert link["ring_rows_up"] == sm.ring_capacity
         return
-    assert link["ring_uploads"] == sum(wrote) >= 3
+    assert 1 + link["ring_deltas"] == sum(wrote) >= 3
+    # Ring rows refreshed on the chip follow the rows published: all but
+    # the first pump's, which went up inside the whole ring.
+    assert link["ring_rows_up"] == sm.ring_capacity + n - 2 * 4
     for tid in "ab":
         s = table.stats()[tid]
         assert s["accepted"] == s["completed"] == n // 2, s
@@ -202,9 +209,12 @@ def test_open_stream_sleeps_while_idle_and_picks_late_rows_up(kind):
     assert int(iv[0]) == 1000 + 21 and info["executed"] == 7
     link = info["stream"]
     assert link["idle_sleeps"] >= 2
-    # Once with the first entry, and again for the late rows (more than
-    # once more only if the loop woke while the producer was sending).
-    assert 2 <= link["ring_uploads"] <= 1 + 6
+    # Whole once, with the first entry; the late rows ride the slabs of
+    # the entries after them (more than one only if the loop woke while
+    # the producer was sending), row for row.
+    assert link["ring_uploads"] == 1
+    assert 1 <= link["ring_deltas"] <= 6
+    assert link["ring_rows_up"] == sm.ring_capacity + 6
     assert [f.state for f in late] == ["RESULT"] * len(late)
 
 

@@ -42,7 +42,9 @@ def test_metric_file_reads_its_span_through_span_mean(bench, name):
     assert entry == {
         "name": name, "unit": "us", "better": "lower",
         "source": "program_span", "layer": "front door",
-        "moves": "req_per_s", "workloads": [CELL],
+        "moves": "req_per_s",
+        # the open-loop cell of PR 44 joined the list
+        "workloads": [CELL, "serve-open-steady"],
     }
     # appended to the 25 entries PR 35 left, and nothing moved since
     names = [m["name"] for m in bench["per_layer"]]

@@ -274,7 +274,6 @@ def _twins(**lane):
 def _lanes_alike(bulk, rows):
     """Every word a later pump, absorb, export or client can see."""
     assert bulk.stats() == rows.stats()
-    assert bulk.ring_writes == rows.ring_writes
     assert bulk.futures.conservation() == rows.futures.conservation()
     for x, y in zip(bulk._lanes, rows._lanes):
         assert [p.index for p in x.pub_meta] == [p.index for p in y.pub_meta]
@@ -298,7 +297,7 @@ def _script_run(clock, t, ring):
 def _script_deadline_on_some(clock, t, ring):
     """A deadline on rows 4 and 6 of lane a: the run ends at row 4;
     once the deadline lapses the published rows are marked on the ring,
-    their tokens expire once, each mark counts a ring write, and a
+    their tokens expire once, each mark is named in ``dirty``, and a
     later pump marks nothing again."""
     futs = []
     for i in range(8):
@@ -309,15 +308,15 @@ def _script_deadline_on_some(clock, t, ring):
     assert t.submit("b", BUMP, args=[99])
     out = [t.pump(ring)]
     assert t._lanes[0].timed == 2 and t._lanes[1].timed == 0
-    wrote = t.ring_writes
     clock.advance(10.0)
-    out.append(t.pump(ring))
-    assert t.ring_writes == wrote + 2
+    marked = []
+    out.append(t.pump(ring, marked))
+    assert sorted(marked) == [(4, 1), (6, 1)]
     assert [i for i in range(16) if ring[i, TEN_EXPIRED]] == [4, 6]
     assert [f.state for f in futs] == [
         "EXPIRED" if i in (4, 6) else "PENDING" for i in range(8)]
-    out.append(t.pump(ring))
-    assert t.ring_writes == wrote + 2 and t.stats()["a"]["expired"] == 0
+    out.append(t.pump(ring, marked))
+    assert len(marked) == 2 and t.stats()["a"]["expired"] == 0
     echo = out[-1].copy()   # the device drops the marked two, takes all
     echo[0, TC_CONSUMED], echo[0, TC_INSTALLED] = 8, 6
     echo[0, TC_EXPIRED] = 2
@@ -431,8 +430,9 @@ def test_pump_with_a_validator_publishes_what_it_passes():
     )
     futs = [t.submit("a", BUMP, args=[i]).future for i in range(1, 9)]
     ring = np.zeros((16, RING_ROW), np.int32)
-    tctl = t.pump(ring)
-    assert tctl[0, TC_TAIL] == 4 and t.ring_writes == 4
+    wrote = []
+    tctl = t.pump(ring, wrote)
+    assert tctl[0, TC_TAIL] == 4 and sum(n for _, n in wrote) == 4
     assert ring[:5, F_A0].tolist() == [1, 3, 5, 7, 0]
     assert [f.state for f in futs] == ["PENDING", "POISONED"] * 4
     s = t.stats()["a"]
