@@ -124,6 +124,10 @@ __all__ = [
     "TEN_TOKEN",
     "TEN_ADMIT_ROUND",
     "TaskGraphBuilder",
+    "ring_len",
+    "ring_slot",
+    "ring_window",
+    "relay_ring",
 ]
 
 DESC_WORDS = 16
@@ -156,6 +160,63 @@ TEN_EXPIRED = 17
 TEN_DEADLINE_MS = 18
 TEN_TOKEN = 19
 TEN_ADMIT_ROUND = 20
+
+
+def ring_len(capacity: int) -> int:
+    """Words of the ready ring (and of each batch lane's ring) of a table
+    of ``capacity`` rows: the next power of two, so that a ring index is
+    a mask (``ring_slot``) where ``% capacity`` is a divide on the scalar
+    core (``sdivrem``, two pops and the sign fix-ups, five times a task:
+    PR 41's listing). The TABLE keeps ``capacity`` rows, and a ring never
+    holds more than ``capacity`` live entries (each is a table row), so
+    no overflow rule reads this length."""
+    return 1 << (int(capacity) - 1).bit_length()
+
+
+def ring_slot(x, ring: int):
+    """Where the all-time counter ``x`` (C_HEAD, C_TAIL, a lane's LS_*; a
+    traced int32, a numpy array or a Python int) lies in a ring of
+    ``ring`` words: ``x`` modulo ``ring``, as a mask. It is the FLOOR
+    modulus for a negative ``x`` too (two's complement), which lane
+    spills need: they walk C_HEAD below zero."""
+    if ring <= 0 or ring & (ring - 1):
+        raise ValueError(f"a ring is a power of two long, not {ring}")
+    return x & (ring - 1)
+
+
+def ring_window(ready, head: int, tail: int) -> np.ndarray:
+    """The live entries ``[head, tail)`` of one ready ring on the host,
+    the steal side (head) first: the one place that reads a ring's window
+    out of a state dict. The ring's own length is its modulus, so it
+    reads a ring of ``ring_len(capacity)`` words and one of ``capacity``
+    (a snapshot written before PR 45) alike."""
+    ready = np.asarray(ready)
+    return ready[np.arange(int(head), int(tail)) % ready.shape[-1]]
+
+
+def relay_ring(ready, counts, ring: int) -> np.ndarray:
+    """``ready`` re-laid into rings of ``ring`` words: the live window
+    ``[C_HEAD, C_TAIL)`` of each ring (one, or one a device on a leading
+    axis, with ``counts`` stacked alike) lands where ``ring_slot`` will
+    look for it, every other word is NO_TASK. A ring that is ``ring``
+    long already is returned as it is; what arrives shorter is a snapshot
+    written when the ring was ``capacity`` long."""
+    ready = np.asarray(ready)
+    if ready.shape[-1] == ring:
+        return ready
+    flat = ready.reshape(-1, ready.shape[-1])
+    heads_tails = np.asarray(counts).reshape(len(flat), -1)[:, :2]
+    out = np.full((len(flat), ring), NO_TASK, np.int32)
+    for row, old, (head, tail) in zip(out, flat, heads_tails):
+        live = ring_window(old, head, tail)
+        if len(live) > ring:
+            raise ValueError(
+                f"a ready ring of {ring} words cannot hold the "
+                f"{len(live)} live entries of the state's "
+                f"[{int(head)}, {int(tail)})"
+            )
+        row[ring_slot(np.arange(int(head), int(tail)), ring)] = live
+    return out.reshape(ready.shape[:-1] + (ring,))
 
 
 class TaskGraphBuilder:
@@ -209,7 +270,8 @@ class TaskGraphBuilder:
 
     def finalize(self, capacity: Optional[int] = None, succ_capacity: Optional[int] = None):
         """Returns (tasks, succ_csr, ready, counts0) numpy arrays sized to
-        ``capacity`` tasks (extra rows are free slots for on-device spawns).
+        ``capacity`` tasks (extra rows are free slots for on-device spawns);
+        the ready ring is ``ring_len(capacity)`` words.
 
         counts0 = [head, tail, alloc, pending, value_alloc, 0, 0, 0].
         """
@@ -239,7 +301,7 @@ class TaskGraphBuilder:
             succ_arr[: len(csr)] = csr
         # Ready ring: initially-runnable tasks in index order.
         ready0 = [i for i, row in enumerate(self._rows) if row[F_DEP] == 0]
-        ring = np.full(capacity, NO_TASK, dtype=np.int32)
+        ring = np.full(ring_len(capacity), NO_TASK, dtype=np.int32)
         ring[: len(ready0)] = ready0
         counts = np.zeros(8, dtype=np.int32)
         counts[0] = 0  # head

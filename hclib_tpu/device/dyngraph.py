@@ -1234,7 +1234,7 @@ def reshard_dyngraph(bundle, ndev_new: int):
     from ..runtime.checkpoint import CheckpointBundle, CheckpointError
     from .descriptor import (
         DESC_WORDS, F_A0, F_CSR_N, F_DEP, F_FN, F_HOME, F_SUCC0,
-        F_SUCC1, NO_TASK,
+        F_SUCC1, NO_TASK, ring_len,
     )
     from .megakernel import C_ALLOC, C_EXECUTED, C_PENDING, C_VALLOC
 
@@ -1436,7 +1436,7 @@ def reshard_dyngraph(bundle, ndev_new: int):
     va = int(counts[:, C_VALLOC].max())
     V = ivalues.shape[1]
     tasks_new = np.zeros((ndev_new, cap, DESC_WORDS), np.int32)
-    ready_new = np.full((ndev_new, cap), NO_TASK, np.int32)
+    ready_new = np.full((ndev_new, ring_len(cap)), NO_TASK, np.int32)
     counts_new = np.zeros((ndev_new, 8), np.int32)
     parts: List[List[np.ndarray]] = [list(pend_upd)
                                      for _ in range(ndev_new)]

@@ -116,6 +116,7 @@ from .descriptor import (
     TEN_ID,
     TEN_TOKEN,
     TaskGraphBuilder,
+    relay_ring,
 )
 from .egress import (
     EC_CONSUMED,
@@ -1156,7 +1157,7 @@ class StreamingMegakernel:
         out_shape = tuple(
             [
                 jax.ShapeDtypeStruct((mk.capacity, DESC_WORDS), jnp.int32),
-                jax.ShapeDtypeStruct((mk.capacity,), jnp.int32),
+                jax.ShapeDtypeStruct((mk.ring_len,), jnp.int32),
                 jax.ShapeDtypeStruct((8,), jnp.int32),
                 jax.ShapeDtypeStruct((mk.num_values,), jnp.int32),
                 jax.ShapeDtypeStruct((8,), jnp.int32),  # ctl out
@@ -1546,7 +1547,10 @@ class StreamingMegakernel:
             st = resume_state
             succ = np.asarray(st["succ"])
             state = [
-                np.asarray(st["tasks"]), np.asarray(st["ready"]),
+                np.asarray(st["tasks"]),
+                # (a snapshot from before the ring grew to ring_len is
+                # told by its shape and re-laid)
+                relay_ring(st["ready"], st["counts"], mk.ring_len),
                 np.asarray(st["counts"]), np.asarray(st["ivalues"]),
             ]
             data = dict(st.get("data") or {})

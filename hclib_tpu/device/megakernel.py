@@ -49,6 +49,9 @@ from .descriptor import (
     F_SUCC1,
     NO_TASK,
     TaskGraphBuilder,
+    relay_ring,
+    ring_len,
+    ring_slot,
 )
 from .tracebuf import (
     NullTracer,
@@ -360,7 +363,7 @@ BK_MAX = 8
 
 # Per-lane scheduler state words (SMEM (nbatch, LS_WORDS) scratch): the
 # lane's FIFO cursors plus the cross-round prefetch handshake.
-LS_HEAD = 0     # pop cursor (monotonic; ring-indexed mod capacity)
+LS_HEAD = 0     # pop cursor (monotonic; ring-indexed by ring_slot)
 LS_TAIL = 1     # push cursor
 LS_PF_BASE = 2  # head-at-issue + 1 of the outstanding prefetch (0 = none)
 LS_PF_N = 3     # descriptors the outstanding prefetch covers
@@ -624,7 +627,7 @@ class KernelContext:
 
     def push_ready(self, t) -> None:
         tail = self._counts[C_TAIL]
-        self._ready[tail % self._capacity] = t
+        self._ready[ring_slot(tail, self._ready.shape[0])] = t
         self._counts[C_TAIL] = tail + 1
 
     def add_executed(self, n) -> None:
@@ -914,9 +917,7 @@ class BatchContext:
         table: dead-slot reads (callers guard semantics with ``live``) must
         still be IN-BOUNDS SMEM accesses, and uninitialized lane words must
         never index past the task table."""
-        row = self._lanes[
-            self._li, jnp.maximum(pos, 0) % self._capacity
-        ]
+        row = self._lanes[self._li, ring_slot(pos, self._lanes.shape[1])]
         return jnp.clip(row, 0, self._capacity - 1)
 
     def idx(self, s):
@@ -1327,7 +1328,7 @@ class Megakernel:
         aliased SMEM operand costs an input AND an output window."""
         cap = self.capacity if capacity is None else int(capacity)
         io = [  # windows in + out
-            (cap, DESC_WORDS), (cap,), (8,), (self.num_values,),
+            (cap, DESC_WORDS), (ring_len(cap),), (8,), (self.num_values,),
         ]
         one = [(self.succ_capacity,)]  # succ is input-only
         one += [s.shape for s in self.core_scratch(cap)]
@@ -1374,6 +1375,14 @@ class Megakernel:
         )
 
     @property
+    def ring_len(self) -> int:
+        """Words of this build's ready ring and of each lane ring:
+        ``descriptor.ring_len(capacity)``, the next power of two. What
+        ``state['ready']`` is long, and what every runner that reads or
+        writes a ring from outside (steal, export, checkpoint) wraps by."""
+        return ring_len(self.capacity)
+
+    @property
     def lane_scratch_rows(self) -> int:
         """Rows of the batched-tier lane/lstate SMEM scratch: one ring
         per routed kind, times ``priority_buckets`` bucket rings per
@@ -1400,7 +1409,7 @@ class Megakernel:
         ]
         if self.batch_specs:
             shapes += [
-                (self.lane_scratch_rows, cap),
+                (self.lane_scratch_rows, ring_len(cap)),
                 (self.lane_scratch_rows, LS_WORDS),
             ]
         return [pltpu.SMEM(s, jnp.int32) for s in shapes]
@@ -1517,6 +1526,10 @@ class Megakernel:
         nothing - the telemetry-off path is byte-identical.
         """
         capacity = self.capacity
+        # The ready ring and every lane ring are ``ring_len`` words, a
+        # power of two: an index is a mask, not a divide (ring_slot).
+        ring = self.ring_len
+        assert ready.shape == (ring,), (ready.shape, ring)
         num_values = value_limit if value_limit is not None else self.num_values
         # Batched same-kind dispatch tier: requires the per-kind lane
         # scratch. Megakernel's own build (which the sharded steal loop
@@ -1617,18 +1630,18 @@ class Megakernel:
                 ready[i] = ready_in[i]
                 return 0
 
-            # C_TAIL is the all-time push counter; once it passes capacity
-            # the whole ring may be live (entries wrap), and raw C_TAIL as
-            # a bound would walk out of the ring. A NEGATIVE head (lane
-            # spills insert at the cold end, walking head below zero) also
-            # wraps the live window - positions [capacity+head, capacity)
-            # hold live entries a [0, tail) copy would drop.
+            # C_TAIL is the all-time push counter; once it passes the
+            # ring's length the whole ring may be live (entries wrap), and
+            # raw C_TAIL as a bound would walk out of the ring. A NEGATIVE
+            # head (lane spills insert at the cold end, walking head below
+            # zero) also wraps the live window - positions [ring+head,
+            # ring) hold live entries a [0, tail) copy would drop.
             jax.lax.fori_loop(
                 0,
                 jnp.where(
                     counts_in[C_HEAD] < 0,
-                    capacity,
-                    jnp.minimum(counts_in[C_TAIL], capacity),
+                    ring,
+                    jnp.minimum(counts_in[C_TAIL], ring),
                 ),
                 copy_ready,
                 0,
@@ -1655,7 +1668,7 @@ class Megakernel:
 
         def push_ready(t) -> None:
             tail = counts[C_TAIL]
-            ready[tail % capacity] = t
+            ready[ring_slot(tail, ring)] = t
             counts[C_TAIL] = tail + 1
 
         ra = _Rearm(rearm)
@@ -1738,12 +1751,12 @@ class Megakernel:
 
         def _lane_push(li, t) -> None:
             tail = lstate[li, LS_TAIL]
-            lanes[li, tail % capacity] = t
+            lanes[li, ring_slot(tail, ring)] = t
             lstate[li, LS_TAIL] = tail + 1
 
         def _make_bctx(li, spec, head, take, pre, buf, nxt):
             kctx = KernelContext(
-                lanes[li, head % capacity], tasks, succ, ready, counts,
+                lanes[li, ring_slot(head, ring)], tasks, succ, ready, counts,
                 ivalues, data, scratch, capacity, free, num_values, vfree,
                 self.uses_row_values, self.tracks_home, rearm=ra,
             )
@@ -1841,8 +1854,8 @@ class Megakernel:
                     @pl.when(jnp.int32(s) < take)
                     def _(s=s):
                         if fire_hook is not None:
-                            fire_hook(lanes[li, (base + s) % capacity])
-                        complete(lanes[li, (base + s) % capacity], s)
+                            fire_hook(lanes[li, ring_slot(base + s, ring)])
+                        complete(lanes[li, ring_slot(base + s, ring)], s)
                 if fifo:
                     lstate[li, LS_HEAD] = head + take
                     if spec.prefetch:
@@ -1905,7 +1918,7 @@ class Megakernel:
                         # steal/export side (device/sharded.py,
                         # device/resident.py) - the Chase-Lev split of the
                         # reference deque (src/hclib-deque.c).
-                        idx = ready[(tail - 1) % capacity]
+                        idx = ready[ring_slot(tail - 1, ring)]
                         counts[C_TAIL] = tail - 1
                         tr.emit(TR_FIRE_SCALAR, rt, tasks[idx, F_FN], idx)
                         if fire_hook is not None:
@@ -2147,7 +2160,7 @@ class Megakernel:
 
                 @pl.when(jnp.logical_not(fired) & ring_work)
                 def _():
-                    idx = ready[(tail - 1) % capacity]
+                    idx = ready[ring_slot(tail - 1, ring)]
                     counts[C_TAIL] = tail - 1
                     # Pop-time partitioning: batch-routed kinds divert into
                     # their lane (one compare per routed kind) no matter
@@ -2250,7 +2263,7 @@ class Megakernel:
                 # candidate behind the hot end and starve the steal
                 # exchange (observed: a batch-routed forest never
                 # spread). C_HEAD may go negative; every reader indexes
-                # the ring mod capacity, and stage() widens its copy to
+                # the ring by ring_slot, and stage() widens its copy to
                 # the whole ring when the window wraps below zero.
                 rt_x = tr.now()
                 for li, fid, spec in lane_rows:
@@ -2271,8 +2284,8 @@ class Megakernel:
                     head0 = counts[C_HEAD]
 
                     def spill(s, _, li=li, h=h, head0=head0):
-                        ready[(head0 - 1 - s) % capacity] = lanes[
-                            li, (h + s) % capacity
+                        ready[ring_slot(head0 - 1 - s, ring)] = lanes[
+                            li, ring_slot(h + s, ring)
                         ]
                         return 0
 
@@ -2542,7 +2555,7 @@ class Megakernel:
         out_shape = tuple(
             [
                 jax.ShapeDtypeStruct((self.capacity, DESC_WORDS), jnp.int32),
-                jax.ShapeDtypeStruct((self.capacity,), jnp.int32),
+                jax.ShapeDtypeStruct((self.ring_len,), jnp.int32),
                 jax.ShapeDtypeStruct((8,), jnp.int32),
                 jax.ShapeDtypeStruct((self.num_values,), jnp.int32),
             ]
@@ -2618,7 +2631,7 @@ class Megakernel:
         up = {
             "tasks": (self.capacity, DESC_WORDS),
             "succ": (self.succ_capacity,),
-            "ready": (self.capacity,),
+            "ready": (self.ring_len,),
             "counts": (8,),
             "ivalues": (self.num_values,),
         }
@@ -2864,8 +2877,11 @@ class Megakernel:
         # large or non-int32 host buffer gets an upload of its own
         # (``_rides`` reads the rule off each buffer).
         host = {
-            "tasks": tasks, "succ": succ, "ready": ring, "counts": counts,
-            "ivalues": ivalues,
+            "tasks": tasks, "succ": succ,
+            # A snapshot written while the ring was ``capacity`` long is
+            # told by its shape and its live window re-laid.
+            "ready": relay_ring(ring, counts, self.ring_len),
+            "counts": counts, "ivalues": ivalues,
         }
         if self.checkpoint:
             host["qctl"] = self.quiesce_words(quiesce)

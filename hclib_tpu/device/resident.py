@@ -166,6 +166,7 @@ from .descriptor import (
     TEN_EXPIRED,
     TEN_ID,
     TaskGraphBuilder,
+    ring_slot,
 )
 from ..runtime.resilience import DeviceFaultPlan, StallError
 from .inject import region_slot
@@ -776,7 +777,7 @@ class ResidentKernel:
         MAXW = self.max_waits
         W = self.window
         SCAN = self.scan
-        cap = mk.capacity
+        rlen = mk.ring_len  # the ready ring's modulus (a power of two)
         RBASE = self.rbase
         SF_PEND, SF_RECV, SF_OUTB, SF_SENT, SF_INJ = (
             self.SF_PEND, self.SF_RECV, self.SF_OUTB, self.SF_SENT,
@@ -1119,7 +1120,7 @@ class ResidentKernel:
             Sn = jnp.minimum(backlog, SCAN)
 
             def copy_cand(j, _):
-                candbuf[j] = ready[(head + j) % cap]
+                candbuf[j] = ready[ring_slot(head + j, rlen)]
                 return 0
 
             jax.lax.fori_loop(0, Sn, copy_cand, 0)
@@ -1198,7 +1199,7 @@ class ResidentKernel:
 
                 @pl.when(jnp.logical_not(tk))
                 def _():
-                    ready[(head + nsend + kp) % cap] = cand
+                    ready[ring_slot(head + nsend + kp, rlen)] = cand
 
                 # Safe to re-evaluate after the mutation above: homed
                 # export leaves tasks[cand] untouched, and whole-row
@@ -2168,7 +2169,7 @@ class ResidentKernel:
         ]
         out_shape = [
             jax.ShapeDtypeStruct((mk.capacity, DESC_WORDS), jnp.int32),
-            jax.ShapeDtypeStruct((mk.capacity,), jnp.int32),
+            jax.ShapeDtypeStruct((mk.ring_len,), jnp.int32),
             jax.ShapeDtypeStruct((8,), jnp.int32),
             jax.ShapeDtypeStruct((mk.num_values,), jnp.int32),
         ] + data_shapes

@@ -42,6 +42,8 @@ from .descriptor import (
     F_SUCC1,
     NO_TASK,
     TaskGraphBuilder,
+    relay_ring,
+    ring_slot,
 )
 from .megakernel import (
     C_ALLOC,
@@ -145,8 +147,10 @@ def execute_partitions(
         if state is not None:
             tasks = np.asarray(state["tasks"]).copy()
             succ = np.asarray(state["succ"]).copy()
-            ring = np.asarray(state["ready"]).copy()
             counts = np.asarray(state["counts"]).copy()
+            # (a snapshot from before the rings grew to ring_len is
+            # told by its shape and re-laid)
+            ring = relay_ring(state["ready"], counts, mk.ring_len).copy()
             ivalues = np.asarray(state["ivalues"]).copy()
         else:
             tasks, succ, ring, counts = partition_builders(
@@ -396,6 +400,7 @@ class ShardedMegakernel:
         axis = self.axis
         ndev = self.ndev
         cap = self.mk.capacity
+        rlen = self.mk.ring_len  # the ring's modulus; cap is the table's
         K = window
         wl_host = np.zeros(max(1, len(self.mk.kernel_fns)), bool)
         for f in self.migratable_fns:
@@ -436,7 +441,7 @@ class ShardedMegakernel:
                 gavg = jax.lax.psum(backlog, axis) // ndev
                 quota = jnp.clip(backlog - gavg, 0, K)
                 scanned = j < jnp.minimum(backlog, K)
-                ring_idx = (head + j) % cap
+                ring_idx = ring_slot(head + j, rlen)
                 cand = ring_[ring_idx]
                 desc = tasks[jnp.clip(cand, 0, cap - 1)]
                 elig = (
@@ -462,7 +467,9 @@ class ShardedMegakernel:
                 keep = scanned & jnp.logical_not(send)
                 rank_k = jnp.cumsum(keep.astype(jnp.int32)) - 1
                 ring_ = ring_.at[
-                    jnp.where(keep, (head + nsend + rank_k) % cap, cap)
+                    jnp.where(
+                        keep, ring_slot(head + nsend + rank_k, rlen), rlen
+                    )
                 ].set(cand, mode="drop")
                 # Tombstone the exported rows (F_DEP=-1): the task now lives
                 # on the neighbor, so the victim's row is dead and stage()
@@ -496,7 +503,7 @@ class ShardedMegakernel:
                 # OOB indices on untaken lanes: scatter drops them, avoiding
                 # duplicate-index races with the taken lanes' writes.
                 tasks = tasks.at[jnp.where(take, rows, cap)].set(recvbuf)
-                slot = jnp.where(take, (tail + j) % cap, cap)
+                slot = jnp.where(take, ring_slot(tail + j, rlen), rlen)
                 ring_ = ring_.at[slot].set(rows)
                 counts = (
                     counts.at[C_ALLOC].add(can - nre)

@@ -137,6 +137,7 @@ from ..device.descriptor import (  # noqa: E402
     F_SUCC0,
     F_SUCC1,
     NO_TASK,
+    ring_len,
 )
 
 
@@ -693,7 +694,7 @@ class CheckpointBundle:
                     "devices) or rebuild with a larger capacity"
                 )
         tasks_new = np.zeros((ndev_new, cap, DESC_WORDS), np.int32)
-        ready_new = np.full((ndev_new, cap), NO_TASK, np.int32)
+        ready_new = np.full((ndev_new, ring_len(cap)), NO_TASK, np.int32)
         counts_new = np.zeros((ndev_new, 8), np.int32)
         ivalues_new = np.zeros((ndev_new, V), np.int32)
         waits_new = None

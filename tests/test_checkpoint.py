@@ -22,9 +22,13 @@ import pytest
 from conftest import KINDS, bump_mk, front_door, seed_builder, send
 
 import hclib_tpu as hc
-from hclib_tpu.device.descriptor import TaskGraphBuilder
+from hclib_tpu.device.descriptor import (
+    NO_TASK,
+    TaskGraphBuilder,
+    ring_window,
+)
 from hclib_tpu.device.inject import StreamingMegakernel
-from hclib_tpu.device.megakernel import Megakernel
+from hclib_tpu.device.megakernel import C_HEAD, C_TAIL, Megakernel
 from hclib_tpu.device.workloads import (
     UTS_NODE,
     device_uts_mk,
@@ -239,6 +243,58 @@ def test_bundle_save_load_restore_and_metrics(
     assert int(iv[0]) == nodes and info["pending"] == 0
 
 
+def _ring_at(state, start, length):
+    """``state`` with its ready window moved to begin at the all-time
+    position ``start`` of a ring ``length`` words long, indexed by
+    ``% length``: with ``length`` the capacity, the layout every
+    snapshot written before PR 45 has."""
+    counts = state["counts"].copy()
+    live = ring_window(state["ready"], counts[C_HEAD], counts[C_TAIL])
+    ring = np.full(length, NO_TASK, np.int32)
+    ring[(start + np.arange(len(live))) % length] = live
+    counts[C_HEAD], counts[C_TAIL] = start, start + len(live)
+    return dict(state, ready=ring, counts=counts)
+
+
+@pytest.mark.parametrize("capacity", [96, 1000])
+def test_old_layout_snapshot_resumes_like_a_new_one(
+    capacity, tmp_path, uts_ref,
+):
+    """A snapshot whose ``ready`` is ``capacity`` long (built by hand: a
+    window that wraps the old ring's end) is told by its shape, re-laid
+    into the ``ring_len`` ring and resumed to the same count as the new
+    layout's, straight from a state dict and from a bundle on disk."""
+    nodes, info_full = uts_ref
+    mk = make_uts_megakernel(checkpoint=True, capacity=capacity, **UTS_KW)
+    assert capacity < mk.ring_len
+    _, _, q = mk.run(_uts_builder(), quiesce=nodes // 3)
+    new = q["state"]
+    assert new["ready"].shape == (mk.ring_len,) and q["pending"] > 2
+    old = _ring_at(new, capacity - 2, capacity)
+    assert old["ready"].shape == (capacity,)
+    # the same window lies elsewhere in the two layouts
+    moved = _ring_at(new, capacity - 2, mk.ring_len)
+    assert not np.array_equal(
+        np.flatnonzero(old["ready"] != NO_TASK),
+        np.flatnonzero(moved["ready"] != NO_TASK),
+    )
+    results = [mk.resume(st) for st in (new, moved, old)]
+    bundle = snapshot_megakernel(mk, dict(q, state=old))
+    assert bundle.arrays["ready"].shape == (capacity,)
+    bundle.save(str(tmp_path / "old"))
+    results.append(restore_megakernel(
+        str(tmp_path / "old"),
+        make_uts_megakernel(checkpoint=True, capacity=capacity, **UTS_KW),
+    ))
+    for iv, _, info in results:
+        assert int(iv[0]) == nodes
+        assert info["executed"] == info_full["executed"] == nodes
+        assert info["pending"] == 0
+    # a chained checkpoint of the old one comes out in the new layout
+    _, _, q2 = mk.resume(old, quiesce=nodes // 2)
+    assert q2["quiesced"] and q2["state"]["ready"].shape == (mk.ring_len,)
+
+
 def test_bundle_corruption_and_version_rejected(
     tmp_path, uts_ckpt_mk, uts_ref,
 ):
@@ -393,7 +449,7 @@ def test_stream_cut_pulls_resident_state_and_resumes_fresh(kind):
     mk, st = sm.mk, info["state"]
     shapes = {
         "tasks": (mk.capacity, DESC_WORDS), "succ": (mk.succ_capacity,),
-        "ready": (mk.capacity,), "counts": (8,),
+        "ready": (mk.ring_len,), "counts": (8,),
         "ivalues": (mk.num_values,),
     }
     if table is not None:

@@ -178,8 +178,10 @@ def test_resident_inject_cursor_survives_reshard():
         def bump(ctx):
             ctx.set_value(0, ctx.value(0) + ctx.arg(0))
 
+        # 200 rows, not a power of two: the reshard lays the new rings
+        # ``ring_len`` (256) long round tables of 200 (ISSUE 45).
         mk = Megakernel(
-            kernels=[("bump", bump)], capacity=256, num_values=1024,
+            kernels=[("bump", bump)], capacity=200, num_values=1024,
             succ_capacity=8, interpret=True, checkpoint=True,
         )
         return ResidentKernel(
@@ -216,6 +218,9 @@ def test_resident_inject_cursor_survives_reshard():
     bundle = snapshot_resident(rk, info_q)
     small = bundle.reshard(1)
     assert int(np.asarray(small.arrays["ictl"])[:, 0].sum()) == ndev * 6
+    assert bundle.arrays["ready"].shape == (2, 256)
+    assert small.arrays["ready"].shape == (1, 256)
+    assert small.arrays["tasks"].shape[:2] == (1, 200)
     rk2 = make_rk(1)
     iv, _, info = rk2.run(
         resume_state=small.state(), quantum=8, max_rounds=1 << 14,

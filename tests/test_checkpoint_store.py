@@ -107,6 +107,36 @@ def _fake_resident_bundle(ndev=2, cap=8, live_per_dev=1, extra=None):
     return CheckpointBundle("resident", {"ndev": ndev}, arrays)
 
 
+@pytest.mark.parametrize("cap", [6, 8, 12, 200])
+def test_reshard_lays_rings_ring_len_long(cap):
+    """The re-homed ready rings are ``ring_len(capacity)`` words round
+    tables of ``capacity`` rows, whatever the source's layout (these
+    hand-built bundles carry ``ready`` of length ``capacity``, what every
+    snapshot written before PR 45 has); a table may be filled to its last
+    row, and the live window reads back through ``ring_window``."""
+    from hclib_tpu.device.descriptor import NO_TASK, ring_len, ring_window
+
+    src = _fake_resident_bundle(ndev=2, cap=cap, live_per_dev=3)
+    assert src.arrays["ready"].shape == (2, cap)
+    for m, per_dev in ((1, 6), (2, 3), (4, None)):
+        out = src.reshard(m)
+        assert out.arrays["tasks"].shape[:2] == (m, cap)
+        assert out.arrays["ready"].shape == (m, ring_len(cap))
+        counts = out.arrays["counts"]
+        assert int(counts[:, 3].sum()) == 6
+        for d in range(m):
+            n = int(counts[d][1] - counts[d][0])
+            assert per_dev is None or n == per_dev
+            assert ring_window(
+                out.arrays["ready"][d], counts[d][0], counts[d][1]
+            ).tolist() == list(range(n))
+            assert (out.arrays["ready"][d][n:] == NO_TASK).all()
+        # and again from the new layout
+        again = out.reshard(1)
+        assert again.arrays["ready"].shape == (1, ring_len(cap))
+        assert int(again.arrays["counts"][0][3]) == 6
+
+
 def test_reshard_m_edge_cases_diagnosed():
     """SATELLITE: M=1 and M>N re-home cleanly (totals conserved, empty
     new devices legal); illegal/overfull targets get diagnostics naming

@@ -15,7 +15,7 @@ import pytest
 from jax.experimental import pallas as pl
 
 import hclib_tpu as hc
-from hclib_tpu.device.descriptor import F_A0, TaskGraphBuilder
+from hclib_tpu.device.descriptor import F_A0, TaskGraphBuilder, ring_window
 from hclib_tpu.device.forasync_tier import (
     FA_SPLIT,
     FA_TILE,
@@ -355,9 +355,10 @@ def test_checkpoint_mid_loop_resume_bit_identical():
     # pending tile sits in the exported ready window (a lane-resident
     # descriptor here would be invisible to restore and lose a tile).
     counts = state["counts"]
-    head, tail = int(counts[C_HEAD]), int(counts[C_TAIL])
-    cap = mk.capacity
-    rows = [int(state["ready"][i % cap]) for i in range(head, tail)]
+    assert state["ready"].shape == (mk.ring_len,)
+    rows = ring_window(
+        state["ready"], counts[C_HEAD], counts[C_TAIL]
+    ).tolist()
     flats = sorted(int(state["tasks"][r][F_A0]) for r in rows)
     assert len(flats) == q["pending"] == len(set(flats))
     assert set(flats) <= set(range(TOTAL))
@@ -648,8 +649,9 @@ def test_recursive_checkpoint_mid_loop_resume_bit_identical():
     assert q["quiesced"] and q["pending"] > 0
     state = q["state"]
     counts = state["counts"]
-    head, tail = int(counts[C_HEAD]), int(counts[C_TAIL])
-    rows = [int(state["ready"][i % mk.capacity]) for i in range(head, tail)]
+    rows = ring_window(
+        state["ready"], counts[C_HEAD], counts[C_TAIL]
+    ).tolist()
     kinds = sorted(int(state["tasks"][r][0]) for r in rows)
     # Everything pending is on the ring (the lane spilled), both kinds.
     assert len(rows) == q["pending"] and set(kinds) == {FA_TILE, FA_SPLIT}

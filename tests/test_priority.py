@@ -430,10 +430,12 @@ def test_bucket_gauges_ride_metrics():
 
 def test_smem_model_charges_the_core_scratch_it_declares(monkeypatch):
     """``core_scratch`` is the one declaration of the scheduler core's
-    scratch (free stacks; the re-arm words, a mark a batch slot; lanes
-    and lstate over kinds x buckets):
-    ``smem_footprint`` charges exactly those words for it, at this
-    build's capacity and at another."""
+    scratch (free stacks; the re-arm words, a mark a batch slot; lanes,
+    each a ring ``ring_len(capacity)`` long, and lstate over kinds x
+    buckets): ``smem_footprint`` charges exactly those words for it, at
+    this build's capacity and at another, where the ring is longer than
+    the table (40 rows, rings of 64)."""
+    from hclib_tpu.device.descriptor import ring_len
     from hclib_tpu.device.megakernel import (
         LS_WORDS, RA_MARK, VBLOCK, smem_bytes,
     )
@@ -445,7 +447,7 @@ def test_smem_model_charges_the_core_scratch_it_declares(monkeypatch):
         assert shapes == [
             (cap + 1,), (mk.num_values // VBLOCK + 1,),
             (RA_MARK + max(sp.width for _, sp in mk.batch_specs),),
-            (4, cap), (4, LS_WORDS),
+            (4, ring_len(cap)), (4, LS_WORDS),
         ]
         whole = mk.smem_footprint(cap)
         with monkeypatch.context() as m:

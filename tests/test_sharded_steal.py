@@ -172,17 +172,22 @@ def _spawner_kernel(ctx):
         ctx.spawn(0, [n - 1])
 
 
-def test_steal_heavy_run_reuses_rows_everywhere():
+@pytest.mark.parametrize("capacity", [64, 48])
+def test_steal_heavy_run_reuses_rows_everywhere(capacity):
     """A generator on device 0 emits 600 migratable tasks through 64-row
     tables: victims reclaim exported rows (tombstoned at export) and
     importers reuse freed rows instead of ratcheting the bump cursor -
-    without either, cumulative traffic overflows 64 rows quickly."""
+    without either, cumulative traffic overflows 64 rows quickly. At 48
+    rows the table is not a power of two long and the ring (64 words,
+    ``ring_len``) is: the export's head and the import's tail pass both
+    lengths many times over."""
     ndev, ntasks = 8, 600
     mesh = cpu_mesh(ndev, axis_name="queues")
     mk = Megakernel(
         kernels=[("spawner", _spawner_kernel), ("bump", bump_kernel)],
-        capacity=64, num_values=4, succ_capacity=8, interpret=True,
+        capacity=capacity, num_values=4, succ_capacity=8, interpret=True,
     )
+    assert mk.ring_len == 64
     smk = ShardedMegakernel(mk, mesh, migratable_fns=[1])
     builders = [TaskGraphBuilder() for _ in range(ndev)]
     builders[0].add(0, args=[ntasks])

@@ -54,6 +54,10 @@ _ENV_NAME = re.compile(r"HCLIB_TPU_[A-Z][A-Z0-9_]*")
 SKIP_DIRS = {
     ".git", ".jax_cache", "__pycache__", ".pytest_cache", ".hypothesis",
     "perf-logs", ".claude", "build", "dist", ".eggs",
+    # git-ignored: builders' chip scripts, their unpacked copies of the
+    # parent and of the staged tree, traces, and what chiprun brings back
+    ".bench_scratch", ".bench_parent", ".bench_tree", ".bench_trace",
+    "chiprun_out",
 }
 
 
