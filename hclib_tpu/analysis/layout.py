@@ -100,12 +100,15 @@ LAYOUT = {
     "TS_THROTTLED": (5, ("hclib_tpu.device.tenants",)),
     "TS_QUARANTINED": (6, ("hclib_tpu.device.tenants",)),
     # batch-tier counter/state rows (device/megakernel.py)
-    "TS_WORDS": (13, ("hclib_tpu.device.megakernel",)),
-    # re-armed dispatches (ctx.become): the one tier word every build
-    # writes, and the re-arm scratch's two fixed words behind it.
+    "TS_WORDS": (14, ("hclib_tpu.device.megakernel",)),
+    # re-armed dispatches (ctx.become) and retirements that took
+    # retire()'s slow region: the two tier words every build writes, and
+    # the re-arm scratch's three fixed words behind them.
     "TS_BECAME": (12, ("hclib_tpu.device.megakernel",)),
+    "TS_WALKED": (13, ("hclib_tpu.device.megakernel",)),
     "RA_BECAME": (0, ("hclib_tpu.device.megakernel",)),
-    "RA_MARK": (1, ("hclib_tpu.device.megakernel",)),
+    "RA_WALKED": (1, ("hclib_tpu.device.megakernel",)),
+    "RA_MARK": (2, ("hclib_tpu.device.megakernel",)),
     "LS_WORDS": (8, ("hclib_tpu.device.megakernel",)),
     "LS_AGE": (5, ("hclib_tpu.device.megakernel",)),
     # priority-bucket tier words (ISSUE 15): the static bucket-ring
@@ -242,7 +245,7 @@ def check_layout(report: Optional[AnalysisReport] = None,
         )
     if not (m.LS_AGE < m.LS_WORDS
             and m.TS_MAX_AGE < m.TS_BUCKET_FIRES
-            < m.TS_INVERSIONS < m.TS_BECAME < m.TS_WORDS):
+            < m.TS_INVERSIONS < m.TS_BECAME < m.TS_WALKED < m.TS_WORDS):
         report.add(
             "layout", ERROR, None,
             "lane/tier state words exceed their declared row widths "

@@ -14,10 +14,17 @@ Word layout (all int32):
 
     0  F_FN       kernel-table index (what to run)
     1  F_DEP      remaining unsatisfied dependencies (runnable at 0)
-    2  F_SUCC0    inline successor task index, or NO_TASK
-    3  F_SUCC1    inline successor task index, or NO_TASK
+    2  F_SUCC0    inline successor task index, or NO_TASK. Released
+                  inline by every retiring row: a row whose ONLY link is
+                  this one (a fork-join child, an injected request with one
+                  completion future) is the cheap shape
+    3  F_SUCC1    inline successor task index, or NO_TASK. A row that
+                  holds one, or a CSR list, pays one branch more when it
+                  retires (``retire()``'s slow region, counted in
+                  ``info['walked']``); either slot may be filled alone
     4  F_CSR_OFF  offset into the successor-CSR array (extra successors)
-    5  F_CSR_N    number of CSR successors
+    5  F_CSR_N    number of CSR successors (released after the two inline
+                  ones, in list order)
     6..11 F_A0+i  six argument words (meaning defined by the kernel)
     12 F_OUT      output value slot (index into the int32 value buffer)
     13 F_HOME     home device (flat mesh index) of a migrated task, or -1.

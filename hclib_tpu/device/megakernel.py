@@ -310,10 +310,10 @@ OVF_PROMISE = 64  # on-device promise wait spun out its bounded budget
 
 # Dispatch tier statistics (the TS_WORDS-word tstats output a megakernel
 # appends after its data outputs; a batch-routed build's are surfaced as
-# info['tiers'] / Megakernel.stats_dict(), TS_BECAME by every build as
-# info['became']). All counters reset at every kernel entry, so with
-# reps > 1 they describe the LAST rep - per-graph numbers, which is what
-# occupancy tracking wants.
+# info['tiers'] / Megakernel.stats_dict(), TS_BECAME and TS_WALKED by every
+# build as info['became'] / info['walked']). All counters reset at every
+# kernel entry, so with reps > 1 they describe the LAST rep - per-graph
+# numbers, which is what occupancy tracking wants.
 TS_BATCH_ROUNDS = 0   # batch rounds fired
 TS_BATCH_TASKS = 1    # descriptors dispatched through batch bodies
 TS_SCALAR_ROUNDS = 2  # descriptors dispatched through lax.switch
@@ -340,7 +340,11 @@ TS_INVERSIONS = 11    # bucket-order inversions: age-guard fires that
 TS_BECAME = 12        # dispatches that ended RE-ARMED (ctx.become: the row
                       # stayed, as its own continuation); written by every
                       # build, batch-routed or not
-TS_WORDS = 13
+TS_WALKED = 13        # retirements that walked more than F_SUCC0 (a second
+                      # inline successor or a CSR list: retire()'s slow
+                      # region); written by every build. The fast path's
+                      # share is 1 - walked / (executed - became)
+TS_WORDS = 14
 
 # Re-arm words (SMEM scratch of RA_MARK + widest-batch words, the third of
 # ``core_scratch``): what ``KernelContext.become`` leaves for the
@@ -348,7 +352,10 @@ TS_WORDS = 13
 # cleared inside one dispatch, so no round boundary, export or checkpoint
 # cut ever sees one set.
 RA_BECAME = 0  # re-armed dispatches since stage() (rides out as TS_BECAME)
-RA_MARK = 1    # + the dispatch's slot (0 on the scalar tier): re-armed
+RA_WALKED = 1  # retirements since stage() that took retire()'s slow
+               # region, a second inline successor or a CSR list (rides
+               # out as TS_WALKED)
+RA_MARK = 2    # + the dispatch's slot (0 on the scalar tier): re-armed
 
 # Priority-bucket dispatch tier (ISSUE 15): ``priority_buckets=B`` layers
 # B bucket rings over every per-kind batch lane - pop lowest-nonempty-
@@ -725,8 +732,17 @@ class KernelContext:
         leaf 14.5, a SUM 18.5; one ``spawn(nargs=1)`` is 9 (the free
         stack, six row words, the arg, the ring). A task's time on the
         v5e did NOT follow that count (PR 41): it follows the bundles of
-        straight-line code its path runs, about 37 a spawn, the five
-        ``pl.when``s below predicated into them.
+        straight-line code its path runs, about 30 a spawn since the
+        rings are masked (PR 45), the five ``pl.when``s below predicated
+        into them.
+
+        What the new row's links cost when it RETIRES (PR 46, the v5e
+        listing of the fib kernel): one successor in ``succ0`` is the
+        cheap shape; a ``succ1`` (with or without a ``succ0``) or a CSR
+        list (host-built rows only) takes ``retire()``'s slow region:
+        one branch, 13 bundles more for the second inline slot, then 17
+        a listed successor. A fork-join child names its parent in
+        ``succ0``.
         """
         if nargs is None:
             nargs = 6
@@ -1711,16 +1727,31 @@ class Megakernel:
                     def _():
                         push_ready(s)
 
-            dec(tasks[idx, F_SUCC0])
-            dec(tasks[idx, F_SUCC1])
+            # The row's three link words are read together, before the
+            # first dec's chain, so the test below does not wait behind it.
+            s0 = tasks[idx, F_SUCC0]
+            s1 = tasks[idx, F_SUCC1]
             n = tasks[idx, F_CSR_N]
-            off = tasks[idx, F_CSR_OFF]
+            dec(s0)
 
-            def body(i, _):
-                dec(succ[off + i])
-                return 0
+            def walk() -> None:
+                rearm[RA_WALKED] = rearm[RA_WALKED] + 1
+                dec(s1)
+                off = tasks[idx, F_CSR_OFF]
 
-            jax.lax.fori_loop(0, n, body, 0)
+                def body(i, _):
+                    dec(succ[off + i])
+                    return 0
+
+                jax.lax.fori_loop(0, n, body, 0)
+
+            # One successor in F_SUCC0 is the shape a fork-join task has:
+            # the second inline successor and the CSR list sit behind ONE
+            # branch that such a row jumps (the loop inside keeps the v5e
+            # compiler from predicating the region; PR 46: 21 bundles of
+            # every retiring task went to links that were NO_TASK). The
+            # order of pushes is F_SUCC0, F_SUCC1, the list, as it was.
+            jax.lax.cond((s1 != NO_TASK) | (n > 0), walk, lambda: None)
             counts[C_PENDING] = counts[C_PENDING] - 1
             counts[C_EXECUTED] = counts[C_EXECUTED] + 1
             # Reclaim the completed row: nothing references it anymore
@@ -2301,6 +2332,7 @@ class Megakernel:
                     tstats[TS_SPILLED] = tstats[TS_SPILLED] + (t - h)
             if tstats is not None:
                 tstats[TS_BECAME] = rearm[RA_BECAME]
+                tstats[TS_WALKED] = rearm[RA_WALKED]
             tr.emit(
                 TR_ROUND_END, tr.tick(),
                 counts[C_EXECUTED] - e0, counts[C_PENDING],
@@ -2538,9 +2570,9 @@ class Megakernel:
             [smem(), smem(), smem(), smem()]
             + [anyspace() for _ in written]
             # The tier counters (TS_* words; a build with no batch route
-            # writes TS_BECAME alone) ride out as one extra SMEM word row
-            # APPENDED after the data outputs, so every existing consumer's
-            # positional indexing is untouched.
+            # writes TS_BECAME and TS_WALKED alone) ride out as one extra
+            # SMEM word row APPENDED after the data outputs, so every
+            # existing consumer's positional indexing is untouched.
             + [smem()]
             # Quiesce status (QS_* words), same appended discipline.
             + ([smem()] if ckpt else [])
@@ -2724,6 +2756,9 @@ class Megakernel:
             # Dispatches that ended re-armed (KernelContext.become), on
             # either tier.
             "became": int(t[TS_BECAME]),
+            # Retirements that walked more than F_SUCC0 (retire()'s slow
+            # region), on either tier.
+            "walked": int(t[TS_WALKED]),
             # Age-trigger firing policy (lane_max_age; zeros when off):
             # rounds that jumped ring-drain-first, and the worst
             # starved-round age any lane reached - the device-side gauge
@@ -2984,6 +3019,10 @@ class Megakernel:
             # Of those, the dispatches that ended re-armed (ctx.become):
             # the row stayed pending, as its own continuation.
             "became": int(tstats_np[TS_BECAME]),
+            # Retirements that took retire()'s slow region: the row held a
+            # second inline successor or a CSR list. The fast path's
+            # share is 1 - walked / (executed - became).
+            "walked": int(tstats_np[TS_WALKED]),
             "pending": int(counts_np[C_PENDING]),
             "allocated": int(counts_np[C_ALLOC]),
             "value_alloc": int(counts_np[C_VALLOC]),

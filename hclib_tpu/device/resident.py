@@ -195,6 +195,7 @@ from .megakernel import (
     C_VBASE,
     Megakernel,
     RA_BECAME,
+    RA_WALKED,
     TS_WORDS,
     VBLOCK,
 )
@@ -297,6 +298,8 @@ FS_EXPORTED = 12    # rows I put on the wire in the steal exchange
 FS_IMPORTED = 13    # rows I installed from the steal exchange's inboxes
 FS_BECAME = 14      # my dispatches that ended re-armed (ctx.become): of
                     # my executed count, the ones whose row stayed pending
+FS_WALKED = 15      # my retirements that walked more than F_SUCC0 (a
+                    # second inline successor or a CSR list)
 FS_WORDS = 16
 
 
@@ -323,6 +326,7 @@ def decode_fault_stats(row) -> Dict[str, Any]:
         "steal_exported": row[FS_EXPORTED],
         "steal_imported": row[FS_IMPORTED],
         "became": row[FS_BECAME],
+        "walked": row[FS_WALKED],
     }
 
 
@@ -2123,6 +2127,7 @@ class ResidentKernel:
         if plan is not None:
             fstats[FS_HB] = pstate[PS_HB]
         fstats[FS_BECAME] = rearm[RA_BECAME]
+        fstats[FS_WALKED] = rearm[RA_WALKED]
         # Credit drain: every executed round ran every hop, and the first
         # send of each credited channel never waited - exactly one
         # outstanding credit per used channel once any round ran. Under a
@@ -2702,6 +2707,7 @@ class ResidentKernel:
             "imported": [f["steal_imported"] for f in fs],
         }
         info["became"] = sum(f["became"] for f in fs)
+        info["walked"] = sum(f["walked"] for f in fs)
         info["aborted"] = any(f["abort_round"] >= 0 for f in fs)
         if self.T:
             # The stacked tctl echo (lane cursors + cumulative install/

@@ -56,6 +56,7 @@ from .megakernel import (
     C_VALLOC,
     Megakernel,
     TS_BECAME,
+    TS_WALKED,
     TS_WORDS,
     ran_on,
 )
@@ -639,6 +640,7 @@ class ShardedMegakernel:
         # the steal path).
         trows = info.pop("extra_outputs")[-1]
         info["became"] = int(trows[:, TS_BECAME].sum())
+        info["walked"] = int(trows[:, TS_WALKED].sum())
         if self.mk.batch_specs:
             # info['tiers'][d] mirrors the single-device decode, so mesh
             # occupancy reads the same way.

@@ -1,0 +1,205 @@
+#!/usr/bin/env python3
+"""Dump the v5e compiler's final-bundle listing of a scheduler kernel.
+
+No chip: the kernel is compiled for a DESCRIBED ``v5e:2x2`` (as
+``tests/test_chip_compile.py`` does) with libtpu's LLO dump on, in a child
+process of its own: libtpu reads its flags once, when it loads, and the
+child aborts after the listing is written (on a report template this
+install lacks), so its exit code says nothing. A scalar-tier task costs
+the straight-line length of the code its path runs (``PERF.md`` section 6,
+PR 41 / 45 / 46): read the listing before and after a change to the
+scheduler, and count its paths with ``tools/listing_paths.py``.
+
+    python tools/kernel_listing.py <outdir> [--tree DIR] [--kernel fib|forest]
+                                   [--capacity N] [--if-conversion]
+
+``--tree`` is the checkout to compile (default: this one; give a copy of
+the parent commit to compare). ``fib`` is ``fib30-scalar``'s kernel
+(``make_fib_megakernel(768)`` through ``Megakernel._build_exec``);
+``forest`` is ``forest-steal-4chip``'s resident mesh kernel (capacity 640,
+four described chips), which inlines the same scheduler core.
+The child's output goes to ``<outdir>/compile.log``; with
+``--if-conversion`` the compiler's if-conversion pass logs into it which
+``pl.when`` / ``lax.cond`` regions it predicated and which it kept as
+branches (``grep if_conversion <outdir>/compile.log``), and the regions
+are summed up after the listing's path, which is always printed: a region
+"kept" is a branch (the listing's ``sbr.rel ... region = N`` is numbered one
+above the log's), a region "predicated" is code every path runs.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import os
+import re
+import subprocess
+import sys
+from typing import Dict, List
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+
+# The trace names of the two kernels (PERF.md section 3): the jit round a
+# Megakernel's pallas_call is named tpu_custom_call, the mesh kernel
+# resident_mesh.
+KERNELS = {"fib": "tpu_custom_call", "forest": "resident_mesh"}
+
+
+def find_listing(outdir: str, kernel: str) -> str:
+    """The final-bundle listing of ``kernel`` under ``outdir`` (the
+    schedule analysis beside it has the same suffix and is not it)."""
+    found = [
+        f for f in glob.glob(os.path.join(outdir, "*-final_bundles.txt"))
+        if KERNELS[kernel] in os.path.basename(f)
+        and "schedule-analysis" not in os.path.basename(f)
+    ]
+    if not found:
+        raise FileNotFoundError(
+            f"no *{KERNELS[kernel]}*-final_bundles.txt under {outdir}: "
+            f"the child did not reach the compiler (read {outdir}/compile.log)"
+        )
+    return max(found, key=os.path.getsize)
+
+
+def if_conversion_summary(log_path: str) -> Dict[str, List[int]]:
+    """``{"kept": [...], "predicated": [...]}``: the regions the
+    if-conversion pass considered, by its last word on each (a region it
+    rejects stays a branch; one it un-predicates becomes straight-line
+    code that every path pays for)."""
+    last: Dict[int, str] = {}
+    pat = re.compile(
+        r"if_conversion\.cc:\d+\] (Rejecting|Un-predicating region:) "
+        r"\$region(\d+)"
+    )
+    with open(log_path, errors="replace") as f:
+        for line in f:
+            m = pat.search(line)
+            if m:
+                last[int(m.group(2))] = (
+                    "kept" if m.group(1) == "Rejecting" else "predicated"
+                )
+    return {
+        k: sorted(r for r, v in last.items() if v == k)
+        for k in ("kept", "predicated")
+    }
+
+
+def _compile_fib(capacity: int) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from hclib_tpu.device.megakernel import SLAB_RIDE_BYTES
+    from hclib_tpu.device.workloads import make_fib_megakernel
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    )
+    sh = SingleDeviceSharding(topo.devices[0])
+    mk = make_fib_megakernel(capacity, interpret=False)
+    lay = mk._exec_layout(
+        [
+            "data:" + k for k, s in mk.data_specs.items()
+            if s.dtype == jnp.int32
+            and 4 * np.prod(s.shape) < SLAB_RIDE_BYTES
+        ],
+        [],
+    )
+    words = sum(int(np.prod(s)) for s in lay.up.values())
+    args = [jax.ShapeDtypeStruct((words,), jnp.int32, sharding=sh)]
+    args += [
+        jax.ShapeDtypeStruct(
+            mk.data_specs[n[5:]].shape, mk.data_specs[n[5:]].dtype,
+            sharding=sh,
+        )
+        for n in lay.alone
+    ]
+    mk._build_exec(1 << 22, False, lay).lower(*args).compile()
+
+
+def _compile_forest(capacity: int) -> None:
+    import jax
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from hclib_tpu.device.descriptor import TaskGraphBuilder
+    from hclib_tpu.device.megakernel import VBLOCK
+    from hclib_tpu.device.resident import ResidentKernel
+    from hclib_tpu.device.sharded import abort_words, partition_builders
+    from hclib_tpu.device.workloads import FIB, make_fib_megakernel
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    )
+    ndev, roots = 4, 160
+    mesh = Mesh(np.array(topo.devices).reshape(ndev), ("q",))
+    mk = make_fib_megakernel(
+        capacity=capacity, interpret=False,
+        num_values=VBLOCK * capacity + max(64, roots),
+    )
+    rk = ResidentKernel(
+        mk, mesh, migratable_fns=[FIB], homed=False, window=16
+    )
+    tasks, succ, ring, counts = partition_builders(
+        mk, ndev, [TaskGraphBuilder() for _ in range(ndev)]
+    )
+    args = [
+        tasks, succ, ring, counts,
+        np.zeros((ndev, mk.num_values), np.int32),
+        np.zeros((ndev, rk.max_waits + 1, 3), np.int32),
+        abort_words(None, ndev),
+    ]
+    sh = NamedSharding(mesh, PartitionSpec("q"))
+    shapes = [
+        jax.ShapeDtypeStruct(tuple(a.shape), a.dtype, sharding=sh)
+        for a in args
+    ]
+    rk._build(256, 1 << 14, None).lower(*shapes).compile()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("outdir")
+    ap.add_argument("--tree", default=os.path.dirname(_HERE))
+    ap.add_argument("--kernel", choices=sorted(KERNELS), default="fib")
+    ap.add_argument("--capacity", type=int, default=None,
+                    help="table rows (default: the cell's, 768 / 640)")
+    ap.add_argument("--if-conversion", action="store_true")
+    ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
+    a = ap.parse_args(argv)
+    capacity = a.capacity or {"fib": 768, "forest": 640}[a.kernel]
+    if a.child:
+        sys.path.insert(0, a.tree)
+        {"fib": _compile_fib, "forest": _compile_forest}[a.kernel](capacity)
+        return 0
+    outdir = os.path.abspath(a.outdir)
+    os.makedirs(outdir, exist_ok=True)
+    env = dict(os.environ)
+    env["LIBTPU_INIT_ARGS"] = (
+        f"--xla_jf_dump_to={outdir} --xla_jf_dump_llo_text=true"
+    )
+    env["JAX_PLATFORMS"] = "cpu"
+    env["ALLOW_MULTIPLE_LIBTPU_LOAD"] = "1"
+    if a.if_conversion:
+        env.update(TPU_STDERR_LOG_LEVEL="0", TPU_MIN_LOG_LEVEL="0",
+                   TPU_VMODULE="if_conversion=5")
+    cmd = [sys.executable, os.path.abspath(__file__), outdir, "--child",
+           "--tree", os.path.abspath(a.tree), "--kernel", a.kernel,
+           "--capacity", str(capacity)]
+    log_path = os.path.join(outdir, "compile.log")
+    with open(log_path, "w") as log:
+        subprocess.run(cmd, env=env, stdout=log, stderr=log, check=False)
+    print(find_listing(outdir, a.kernel))
+    if a.if_conversion:
+        for k, regions in if_conversion_summary(log_path).items():
+            print(f"{k} {len(regions)}: {' '.join(map(str, regions))}")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
