@@ -27,7 +27,7 @@ import time
 import numpy as np
 
 T1L_NODES = 102_181_082
-CHOLESKY_RESIDUAL_BOUND = 1e-6  # bench_device_cholesky's bound at n=8192
+CHOLESKY_RESIDUAL_BOUND = 1e-6  # at n=8192; the cell cholesky-8192 allows 2e-6
 
 
 def emit(phase: str, dev: dict, **facts) -> None:
@@ -140,7 +140,7 @@ def phase_cholesky(dev: dict, seed: int) -> None:
     )
     assert L.shape == (n, n) and np.isfinite(L).all()
     # Residual on the device at HIGHEST precision (the default bf16
-    # matmul's own error would drown it), as bench_device_cholesky.
+    # matmul's own error would drown it), as the cell cholesky-8192.
     La, Aa = jnp.asarray(L), jnp.asarray(a)
     m = jnp.matmul(La, La.T, precision=jax.lax.Precision.HIGHEST)
     rel = float(jnp.max(jnp.abs(m - Aa)) / jnp.max(jnp.abs(Aa)))
@@ -222,7 +222,7 @@ def phase_forasync(dev: dict, seed: int) -> None:
         MAP_ADD, MAP_MUL, map_data, map_loop, stencil_data, stencil_loop,
     )
 
-    # 1D: the map-style batched-apply loop, bench_forasync's size.
+    # 1D: the map-style batched-apply loop.
     T = 64
     tk, bounds, tile = map_loop(T)
     vin, vout = map_data(T, seed=seed)

@@ -501,13 +501,9 @@ def _make_recording_contexts():
             }))
             return row
 
-        def take_continuation(self, new_idx) -> None:
-            self._shim_trace.continuations += 1
-            super().take_continuation(new_idx)
-
         def become(self, fn, dep_count) -> None:
-            # The same fork-join as take_continuation, on the task's own
-            # row: its links stay behind for the continuation.
+            # A fork-join on the task's own row: its links stay behind
+            # for the continuation.
             self._shim_trace.continuations += 1
             super().become(fn, dep_count)
 

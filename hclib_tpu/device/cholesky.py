@@ -26,7 +26,8 @@ Why 3 passes: f32 data, MXU matmuls at ~f32 accuracy via the bf16 hi/lo
 split (ops/tiles.mm_nt_split). This sets the physics of the benchmark: a
 3-pass f32-accurate GEMM can never exceed 1/3 of the chip's bf16 matmul
 clock, so the meaningful utilization number is (achieved f32-effective
-FLOP/s) / (probe/3) - bench.py prints both.
+FLOP/s) / (peak/3) - the cell ``cholesky-8192`` reports the share of
+the whole bf16 peak as ``chol_roofline``, whose ceiling is so 33 %.
 """
 
 from __future__ import annotations

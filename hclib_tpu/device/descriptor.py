@@ -54,10 +54,7 @@ pass to the continuation without a word moved. From the end of that
 dispatch it is an ordinary pending row: off the ring until its children
 count F_DEP down, exported by a checkpoint cut and kept home by the steal
 filters like any dependent row, retired (hook, successors, tombstone) when
-the continuation completes. ``take_continuation`` is still the call when
-the continuation must be ANOTHER row: one that outlives this task's
-completion hook firing now (an egress token retired at the fork, not at
-the join), or a join spawned by someone else.
+the continuation completes.
 
 Injection-ring row extension (multi-tenant ingress, device/tenants.py):
 ring rows are padded to 256 words (``RING_ROW``, device/inject.py) so any

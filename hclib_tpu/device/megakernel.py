@@ -43,7 +43,6 @@ from .descriptor import (
     F_DEP,
     F_FN,
     F_HOME,
-    F_HROW,
     F_OUT,
     F_SUCC0,
     F_SUCC1,
@@ -312,8 +311,7 @@ OVF_PROMISE = 64  # on-device promise wait spun out its bounded budget
 # appends after its data outputs; a batch-routed build's are surfaced as
 # info['tiers'] / Megakernel.stats_dict(), TS_BECAME and TS_WALKED by every
 # build as info['became'] / info['walked']). All counters reset at every
-# kernel entry, so with reps > 1 they describe the LAST rep - per-graph
-# numbers, which is what occupancy tracking wants.
+# kernel entry: per-graph numbers, which is what occupancy tracking wants.
 TS_BATCH_ROUNDS = 0   # batch rounds fired
 TS_BATCH_TASKS = 1    # descriptors dispatched through batch bodies
 TS_SCALAR_ROUNDS = 2  # descriptors dispatched through lax.switch
@@ -453,7 +451,7 @@ class KernelContext:
         self._vfree = vfree
         self._uses_row_values = uses_row_values
         # Whether this kernel composition can host migrated (homed) rows:
-        # only then do spawn/take_continuation maintain the F_HOME words
+        # only then does spawn maintain the F_HOME words
         # (ResidentKernel sets Megakernel.tracks_home; plain megakernels
         # skip the dead scalar writes - the cost unit on this tier).
         self._tracks_home = tracks_home
@@ -651,27 +649,6 @@ class KernelContext:
             self._counts[C_OVERFLOW],
         )
 
-    def take_continuation(self, new_idx) -> None:
-        """Transfer this task's successors to ``new_idx`` - the descriptor
-        equivalent of the reference turning a blocked stack into a
-        continuation task (_help_finish_ctx, src/hclib-runtime.c:1032-1065):
-        the spawned task becomes the continuation that fires our successors."""
-        t = self._tasks
-        t[new_idx, F_SUCC0] = t[self.idx, F_SUCC0]
-        t[new_idx, F_SUCC1] = t[self.idx, F_SUCC1]
-        t[new_idx, F_CSR_OFF] = t[self.idx, F_CSR_OFF]
-        t[new_idx, F_CSR_N] = t[self.idx, F_CSR_N]
-        t[self.idx, F_SUCC0] = jnp.int32(NO_TASK)
-        t[self.idx, F_SUCC1] = jnp.int32(NO_TASK)
-        t[self.idx, F_CSR_N] = 0
-        if self._tracks_home:
-            # A migrated copy's continuation inherits the home-link as
-            # well: whoever ends the chain forwards the result to the home
-            # proxy (device/resident.py's remote-completion protocol).
-            t[new_idx, F_HOME] = t[self.idx, F_HOME]
-            t[new_idx, F_HROW] = t[self.idx, F_HROW]
-            t[self.idx, F_HOME] = jnp.int32(NO_TASK)
-
     def become(self, fn: int, dep_count) -> None:
         """Re-arm this task's own row as its continuation: the row turns
         into a ``fn`` task waiting on ``dep_count`` predecessors (children
@@ -683,9 +660,8 @@ class KernelContext:
         moved). Two dynamic writes (F_FN, F_DEP) and one static mark; this
         dispatch's ``complete()`` sees the mark, counts the task executed
         and leaves the row pending: no hook, no successor walk, no
-        tombstone. ``take_continuation`` is for a continuation that has to
-        be ANOTHER row. ``dep_count`` must be positive: a continuation
-        that is ready at once is a plain ``spawn``."""
+        tombstone. ``dep_count`` must be positive: a continuation that is
+        ready at once is a plain ``spawn``."""
         if isinstance(dep_count, (int, np.integer)) and dep_count <= 0:
             raise ValueError(
                 f"become() needs dep_count > 0, got {dep_count}: a "
@@ -957,12 +933,12 @@ class BatchContext:
     def slot_ctx(self, s):
         """A KernelContext focused on slot ``s``'s descriptor row - for
         batch bodies whose per-slot work is scalar-shaped (dynamic spawns,
-        continuation transfer) rather than one fused tile op. The returned
+        re-arming in place) rather than one fused tile op. The returned
         context shares every underlying ref with this batch, so
-        ``spawn``/``become``/``take_continuation``/``set_arg``/
-        ``row_values`` behave exactly as they would under scalar dispatch
-        of the same row (a re-arm marks slot ``s``'s own word, which the
-        round reads when it completes that slot after all the bodies); a
+        ``spawn``/``become``/``set_arg``/``row_values`` behave exactly as
+        they would under scalar dispatch of the same row (a re-arm marks
+        slot ``s``'s own word, which the round reads when it completes
+        that slot after all the bodies); a
         body that unrolls ``range(width)`` under ``pl.when(live(s))`` and
         runs the scalar kernel per live slot computes bit-identical
         results while skipping the per-descriptor ring pop + lax.switch
@@ -1097,7 +1073,6 @@ class Megakernel:
         uses_row_values: bool = False,
         vmem_limit_bytes: Optional[int] = None,
         route: Optional[Dict[str, Any]] = None,
-        auto_route: Optional[Dict[str, Any]] = None,
         trace: Optional[Any] = None,
         checkpoint: Optional[bool] = None,
         quiesce_stride: Optional[int] = None,
@@ -1222,16 +1197,12 @@ class Megakernel:
         # result lands where the scalar kernel's would and its successors
         # fire on completion, so irregular DAGs mix routed and scalar
         # tasks freely. A spec must compute the same values as the scalar
-        # kernel it replaces. ``auto_route`` is the legacy vector-tier-only
-        # spelling, kept as an alias.
+        # kernel it replaces.
         self.route = dict(route or {})
-        if auto_route:
-            self.route.update(auto_route)
-        self.auto_route = self.route  # legacy alias
         unknown = set(self.route) - {name for name, _ in kernels}
         if unknown:
             raise ValueError(
-                f"route/auto_route names unknown kernels: {sorted(unknown)}"
+                f"route names unknown kernels: {sorted(unknown)}"
             )
         not_specs = [
             n for n, s in self.route.items()
@@ -1608,8 +1579,8 @@ class Megakernel:
             vfree[0] = 0
             for w in range(rearm.shape[0]):
                 rearm[w] = 0
-            # Trace header resets per entry/rep, so reps > 1 leaves the
-            # LAST rep's records - the same per-graph semantics tstats has.
+            # Trace header resets per entry - the same per-graph
+            # semantics tstats has.
             tr.reset()
             if use_batch:
                 # Lanes/prefetch state are per-entry scratch (sched() spills
@@ -1619,8 +1590,7 @@ class Megakernel:
                     for w in range(LS_WORDS):
                         lstate[li, w] = 0
             if tstats is not None:
-                # The tier's output window - zeroed here so reps report
-                # the last rep's per-graph counters.
+                # The tier's output window - zeroed per entry.
                 for w in range(TS_WORDS):
                     tstats[w] = 0
             for i in range(8):
@@ -2398,8 +2368,8 @@ class Megakernel:
         )
 
     def _kernel(
-        self, fuel: int, reps: int, stage_all_values: bool, trace, ckpt,
-        qstride, inputs, *refs
+        self, fuel: int, stage_all_values: bool, trace, ckpt, qstride,
+        inputs, *refs
     ) -> None:
         # ``trace``/``ckpt``/``qstride``/``inputs`` are the TraceRing /
         # checkpoint flag / quiesce poll stride / input-only data buffers
@@ -2493,17 +2463,8 @@ class Megakernel:
             quiesce_hook=quiesce_hook,
         )
 
-        def one_rep(r, total_executed) -> jnp.int32:
-            core.stage()
-            core.sched(fuel)
-            return total_executed + counts[C_EXECUTED]
-
-        # reps > 1 re-runs the staged graph as a steady-state throughput
-        # harness (the resident scheduler never exits between graphs); the
-        # final state is that of the last rep, with C_EXECUTED accumulated
-        # across reps.
-        total = jax.lax.fori_loop(0, reps, one_rep, jnp.int32(0))
-        counts[C_EXECUTED] = total
+        core.stage()
+        core.sched(fuel)
         if ckpt:
             # State-export record: one TR_CKPT at exit when this entry
             # quiesced (pending rows exported, ready backlog) - the device
@@ -2546,7 +2507,7 @@ class Megakernel:
             )
 
     def _build_raw(
-        self, fuel: int, reps: int = 1, stage_all_values: bool = False,
+        self, fuel: int, stage_all_values: bool = False,
         inputs: Sequence[str] = (),
     ):
         """The bare pallas_call (for embedding under shard_map; re-entrant
@@ -2603,8 +2564,8 @@ class Megakernel:
             aliases[5 + list(self.data_specs).index(k)] = 4 + o
         return pl.pallas_call(
             functools.partial(
-                self._kernel, fuel, reps, stage_all_values, self.trace,
-                ckpt, self.quiesce_stride, tuple(inputs),
+                self._kernel, fuel, stage_all_values, self.trace, ckpt,
+                self.quiesce_stride, tuple(inputs),
             ),
             out_shape=out_shape,
             in_specs=in_specs,
@@ -2720,15 +2681,6 @@ class Megakernel:
         # may keep a small one in faster memory meanwhile, which is why a
         # buffer this call uploaded itself is left to XLA, as it was).
         return jax.jit(tpu_custom_call, donate_argnums=lay.donated)
-
-    def _build(self, fuel: int, reps: int = 1):
-        from ..runtime.progcache import shared_build
-
-        fn, self._pc_stats = shared_build(
-            self, ("megakernel-build", fuel, reps),
-            lambda: jax.jit(self._build_raw(fuel, reps)),
-        )
-        return fn
 
     def decode_tier_stats(self, tstats) -> Dict[str, Any]:
         """Decode the raw TS_WORDS counter row into the per-tier stats dict
@@ -2981,7 +2933,12 @@ class Megakernel:
             # share one Perfetto timeline.
             t0_ns = _time.monotonic_ns()
             with span("mk.launch"):
-                packed_dev, tasks_out, ready_out, *rest = jitted(*args)
+                launch = jitted
+                if first_build:  # this call traces: see first_call
+                    from ..runtime.progcache import first_call
+
+                    launch = functools.partial(first_call, jitted)
+                packed_dev, tasks_out, ready_out, *rest = launch(*args)
                 # The host's copy of what it reads starts behind the
                 # launch; mk.wait only waits for it.
                 packed_dev.copy_to_host_async()

@@ -24,9 +24,9 @@ re-designed as a **home-link protocol**:
 - Exporting a ready row WITH successor links keeps the row at home as a
   *proxy* (off the ready ring, still pending, links intact) and ships a
   copy whose F_HOME/F_HROW words name the proxy.
-- The copy executes on the thief like any local task; continuations
-  spawned there inherit the home-link (``take_continuation`` moves
-  F_HOME/F_HROW with the successor words).
+- The copy executes on the thief like any local task; a copy that
+  re-arms as its own continuation (``ctx.become``) keeps the home-link
+  where it lies, on its row.
 - Whoever ends the chain forwards its out-slot value home in a
   **remote-completion active message**; the home device writes the value
   into the proxy's out slot and completes the proxy - firing the real

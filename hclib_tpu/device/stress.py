@@ -38,10 +38,9 @@ import numpy as np
 __all__ = ["forest_steal", "forest_resident", "unified_load",
            "FOREST_STEAL_BENCH", "FOREST_STEAL_QUICK"]
 
-# The benchmark-of-record forest-steal configuration, shared by the
-# scalar and batched arms in tools/perf_regression.py --multichip AND
-# bench.py's multichip headline: the mesh-batch-dispatch guard compares
-# the two arms' tasks/s, which is only meaningful while they run the
+# The forest-steal configuration shared by the scalar and batched arms
+# in tools/perf_regression.py --multichip: the mesh-batch-dispatch guard
+# compares the two arms' tasks/s, which is only meaningful while they run the
 # SAME workload - tune these here, not at a call site.
 FOREST_STEAL_BENCH = dict(ndev=8, roots=160, n=12, capacity=4096)
 FOREST_STEAL_QUICK = dict(ndev=8, roots=24, n=9, capacity=1024)

@@ -193,8 +193,8 @@ class ScaleEvent:
     ``kind``: ``scale_out`` | ``scale_in`` | ``evacuate`` | ``hold`` |
     ``checkpoint`` (preemption cut) | ``finish`` (workload drained).
     ``resize_latency_s`` is the full quiesced-state -> resumable-state
-    cost of a resize (snapshot + reshard + state rebuild), the number
-    ``bench.py --autoscale`` reports. ``cache_hit`` (resizes only):
+    cost of a resize (snapshot + reshard + state rebuild;
+    tests/test_autoscaler.py holds it to the event). ``cache_hit`` (resizes only):
     whether the target shape's program was already warm in the
     process-wide program cache (runtime/progcache.py), i.e. the resume
     pays zero trace/lower/compile work.

@@ -214,8 +214,6 @@ REGISTRY = {
            "native worker CPU pinning: strided | chunked | none",
            legacy=("HCLIB_AFFINITY",)),
         # -- harnesses --
-        _v("HCLIB_TPU_BENCH_BUDGET_S", "float", "780",
-           "bench.py wall budget for budget-gated sections, seconds"),
         _v("HCLIB_TPU_BIG_TESTS", "flag", "off",
            "opt into hardware-scale test variants (any nonempty value)"),
     ]
@@ -317,7 +315,7 @@ def use_compile_cache() -> str:
     directory and nothing is changed; where it is not, the cache goes to
     ``<checkout>/.jax_cache`` - a fixed path, because the path is part
     of the cache key and a directory that moves never hits. Entry points
-    (``chip_smoke.py``, ``bench.py``, ``__graft_entry__``,
+    (``chip_smoke.py``, ``__graft_entry__``,
     ``tools/perf_regression.py``) and ``tests/conftest.py`` call this
     first; no other code names a cache directory."""
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -54,6 +54,7 @@ scalar ones without letting any entry pin the cache forever.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 import time
@@ -72,6 +73,7 @@ __all__ = [
     "megakernel_fingerprint",
     "mesh_key",
     "shared_build",
+    "first_call",
     "probe",
     "cache_stats",
     "reset",
@@ -471,3 +473,32 @@ def shared_build(mk, variant, build: Callable[[], Any]):
         "cache_lookup_s": lookup_s,
         "build_s": build_s,
     }
+
+
+@functools.cache
+def _roomy_frame() -> Callable:
+    # Locals after the return are never assigned; they only size the
+    # frame: 1 << 15 of them make it 256 KiB and its chunk 512 KiB.
+    source = "def roomy(fn, args):\n    out = fn(*args)\n    return out\n    "
+    source += " = ".join(f"_{i}" for i in range(1 << 15)) + " = None\n"
+    scope: Dict[str, Any] = {}
+    exec(compile(source, "<progcache.first_call>", "exec"), scope)
+    return scope["roomy"]
+
+
+def first_call(fn: Callable, *args):
+    """``fn(*args)`` for a jitted program's FIRST call, the one that
+    traces its kernel, made from inside one large frame.
+
+    CPython carves interpreter frames out of 16 KiB chunks: a call that
+    runs past a chunk's end maps a new chunk, and the return of that
+    call unmaps it. A trace is ten thousand short calls at one depth,
+    and where that depth straddles a chunk's end each of them maps,
+    faults in and unmaps four pages: one trace of the Cholesky kernel
+    took 315,000 page faults against 10,000, and on the chip's host,
+    where a fresh page costs 12 us (``PERF.md`` section 6, PR 29 and
+    PR 47), 33 s against 8. Which side a trace falls on depends on how
+    many frames its caller happens to stand on, so an edit that moves a
+    call moves set-up by tens of seconds. A frame too large for a chunk
+    gets a chunk of its own, with room behind it for the whole trace."""
+    return _roomy_frame()(fn, args)

@@ -296,10 +296,9 @@ def test_classification_and_describe():
 
 
 def test_a_row_rearmed_in_place_is_a_continuation_to_the_shim():
-    """``ctx.become`` is the fork-join ``take_continuation`` is, on the
-    task's own row: a kind that re-arms and spawns only link-free
-    children still leaves its links behind for a continuation, so it is
-    home-linked, not link-free."""
+    """``ctx.become`` is a fork-join on the task's own row: a kind that
+    re-arms and spawns only link-free children still leaves its links
+    behind for a continuation, so it is home-linked, not link-free."""
     from hclib_tpu.analysis.classify import trace_class
     from hclib_tpu.analysis.shim import run_scalar_kernel
 

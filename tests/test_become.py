@@ -69,9 +69,9 @@ def test_one_batch_round_of_two_leaves_and_two_forks():
 
 def test_traveling_copy_rearms_on_the_thief_and_forwards_home_once():
     """Two devices, ``homed=True``: a stolen FIB re-arms ON THE THIEF
-    with its home-link still on the row (``take_continuation`` used to
-    move it to a second row), and the continuation that ends the chain
-    forwards the result home: exact value, every dispatch counted once."""
+    with its home-link still on the row, and the continuation that ends
+    the chain forwards the result home: exact value, every dispatch
+    counted once."""
     ndev, n, cap = 2, 8, 96
     mk = make_fib_megakernel(
         capacity=cap, interpret=True,

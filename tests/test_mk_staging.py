@@ -238,3 +238,16 @@ def test_one_split_and_no_packer():
         if "def _split(" in f.read_text()
     ]
     assert defs == ["device/megakernel.py"]
+
+
+def test_megakernel_has_one_host_program():
+    """``run`` / ``resume`` go through ``_build_exec``; the bare
+    ``jax.jit(_build_raw)`` of the slope harness, its in-kernel ``reps``
+    loop and the continuation call nothing used are gone (ISSUE 47)."""
+    import inspect
+
+    assert not hasattr(Megakernel, "_build")
+    for fn in (Megakernel._build_raw, Megakernel._kernel):
+        assert "reps" not in inspect.signature(fn).parameters, fn
+    assert not hasattr(megakernel.KernelContext, "take_continuation")
+    assert "auto_route" not in inspect.signature(Megakernel).parameters

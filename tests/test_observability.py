@@ -130,21 +130,41 @@ def test_stats_format_contains_steals():
     assert executed >= 51
 
 
-def test_bench_trials_drop_sheared_values():
-    """Slope-based trials can land on the -1.0 sheared sentinel
-    (bench._slope_or_sheared); bench.trials_of must keep them out of the
-    median but count them in n_trials, and a run whose every trial
-    sheared is a failure, not a 0.0 headline."""
-    import bench
+def _repo_sources(root):
+    """The source files git would commit under ``root``: ``git
+    ls-files``, or where there is no git the walk ``tools/lint.py``
+    makes, which skips what building and running leave behind."""
+    import subprocess
 
-    vals = iter((5.0, -1.0, 7.0))
-    s = bench.trials_of("mixed", lambda: next(vals), 3)
-    assert s["median"] == 6.0 and s["best"] == 7.0
-    assert s["n_trials"] == 3 and s["n_used"] == 2 and s["spread"] == 1.4
-    with pytest.raises(RuntimeError, match="sheared"):
-        bench.trials_of("sheared", lambda: -1.0, 2)
-    assert bench._slope_or_sheared(1e-4, 10.0) == -1.0
-    assert bench._slope_or_sheared(0.5, 10.0) == 20.0
+    from tools import lint
+
+    try:
+        out = subprocess.run(
+            ["git", "ls-files"], cwd=root, capture_output=True, text=True,
+            check=True, timeout=30,
+        ).stdout.split()
+    except (OSError, subprocess.SubprocessError):
+        out = []
+    return out or list(lint._files([str(root)]))
+
+
+@pytest.mark.parametrize("doc", ["README.md", "tutorial/README.md"])
+def test_documents_name_files_that_exist(doc):
+    """A document must not outlive the file it sends the reader to:
+    every ``*.py`` it names is the path, or the end of the path, of a
+    file of the repo (``my_run.py`` is the reader's own script)."""
+    import pathlib
+    import re
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    files = ["/" + f for f in _repo_sources(root)]
+    named = set(re.findall(r"[\w./-]+\.py\b", (root / doc).read_text()))
+    assert len(named) > 20, named
+    missing = sorted(
+        n for n in named - {"my_run.py"}
+        if not any(f.endswith("/" + n.lstrip("./")) for f in files)
+    )
+    assert not missing, missing
 
 
 def test_event_log_external_lane_counts_non_worker_records(tmp_path):

@@ -11,6 +11,8 @@ semantics (malformed or non-positive raises, cap=1 evicts and the
 rebuild is bit-identical); fail-open on unfingerprintable input.
 """
 
+import inspect
+
 import numpy as np
 import pytest
 
@@ -464,3 +466,16 @@ def test_metrics_exports_program_cache_gauges():
     assert m["program_cache.evictions"] == 0.0
     assert "fib.program_cache.build_s" in m
     assert "fib.program_cache.cache_lookup_s" in m
+
+
+def test_first_call_is_the_call_from_one_large_frame():
+    """``first_call(fn, *args)`` is ``fn(*args)``: the value comes back,
+    an exception passes through, and the frame it calls from is larger
+    than the 16 KiB chunks CPython carves frames from, so the trace
+    behind it never straddles a chunk's end (ISSUE 47)."""
+    assert progcache.first_call(lambda a, b: (b, a), 1, 2) == (2, 1)
+    with pytest.raises(KeyError, match="gone"):
+        progcache.first_call({}.__getitem__, "gone")
+    depth = progcache.first_call(lambda: len(inspect.stack()))
+    assert depth == len(inspect.stack()) + 3  # first_call, roomy, the lambda
+    assert progcache._roomy_frame().__code__.co_nlocals * 8 > 4 * 16384

@@ -444,3 +444,43 @@ def test_run_on_main_from_escaping_task_at_finalize():
 
     hc.launch(body, nworkers=2)
     assert got == [main_ident]
+
+
+def test_every_registered_env_name_has_a_reader():
+    """An option must not outlive its reader: every row of
+    ``runtime/env.py:REGISTRY`` is named, by its canonical or a legacy
+    spelling, somewhere in the program outside the registry's own rows
+    (``HCLIB_TPU_BIG_TESTS`` is the tests' own switch and is read there)."""
+    import pathlib
+    import re
+
+    from hclib_tpu.runtime import env
+    from tools import lint
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    texts = {
+        f: pathlib.Path(f).read_text(errors="replace")
+        for f in lint._files([
+            str(root / p) for p in
+            ("hclib_tpu", "tools", "chip_smoke.py", "__graft_entry__.py")
+        ])
+    }
+    own = texts[str(root / lint.ENV_MODULE)]
+    start = own.index("REGISTRY = {")
+    texts[str(root / lint.ENV_MODULE)] = (
+        own[:start] + own[own.index("\n}\n", start):]
+    )
+    program = "\n".join(texts.values())
+    tests = "\n".join(
+        f.read_text() for f in (root / "tests").glob("test_*.py")
+        if f != pathlib.Path(__file__).resolve()
+    )
+    unread = [
+        var.name for var in env.REGISTRY.values()
+        if not any(
+            re.search(rf"\b{s}\b",
+                      tests if var.name == "HCLIB_TPU_BIG_TESTS" else program)
+            for s in (var.name,) + var.legacy
+        )
+    ]
+    assert not unread, unread

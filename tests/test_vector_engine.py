@@ -140,8 +140,8 @@ def test_device_nqueens_tpu():
     assert v == 724
 
 
-def test_auto_route_irregular_dag_gets_fast_path():
-    """auto_route: a scalar fib kernel's family is routed to the
+def test_route_irregular_dag_gets_fast_path():
+    """``route=``: a scalar fib kernel's family is routed to the
     batch-dispatch tier by NAME (VERDICT r4 #3) - an irregular DAG mixing
     scalar tasks and a routed recursive family runs the family's whole
     subtree on the VPU lanes (executed counts the expanded tree, not one
@@ -162,7 +162,7 @@ def test_auto_route_irregular_dag_gets_fast_path():
             ("sum", _sum_kernel),
             ("consume", consume),
         ],
-        auto_route={"fib": fib_spec(max_n=14, lanes=(1, 8))},
+        route={"fib": fib_spec(max_n=14, lanes=(1, 8))},
         capacity=32,
         num_values=16,
         succ_capacity=16,
@@ -184,10 +184,10 @@ def test_auto_route_irregular_dag_gets_fast_path():
     assert info["pending"] == 0
 
 
-def test_auto_route_unknown_name_rejected():
-    with pytest.raises(ValueError, match="auto_route"):
+def test_route_unknown_name_rejected():
+    with pytest.raises(ValueError, match="route names unknown"):
         Megakernel(
             kernels=[("a", lambda ctx: None)],
-            auto_route={"b": fib_spec(max_n=4, lanes=(1, 8))},
+            route={"b": fib_spec(max_n=4, lanes=(1, 8))},
             interpret=True,
         )

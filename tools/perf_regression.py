@@ -31,16 +31,8 @@ Usage:
   python tools/perf_regression.py               # full sizes, 3 trials
   python tools/perf_regression.py --quick       # tiny sizes (CI/smoke)
   python tools/perf_regression.py --trials 5 --tolerance 0.2
-  python tools/perf_regression.py --device      # + TPU device suite
   python tools/perf_regression.py --multichip   # 8-device mesh at scale
 Exit code 1 if any app regressed beyond tolerance vs the previous log.
-
-``--device`` adds the TPU engines (megakernel fib scalar + batch tiers,
-Cholesky GFLOP/s, Smith-Waterman GCUPS - fused sweep AND the wave-DAG
-batched-dispatch engine with its batch-occupancy counter, UTS nodes/s) -
-the numbers of record bench.py reports, guarded here so no TPU claim
-floats free of a harness. Device entries record a RATE (higher is
-better); host entries record wall time.
 
 ``--multichip`` runs the benchmark-scale multi-device acceptance
 workloads (hclib_tpu/device/stress.py) on a virtual 8-device CPU mesh:
@@ -90,43 +82,6 @@ def _suite(quick: bool) -> List[Tuple[str, Callable[[], dict]]]:
         ("uts", lambda: uts.run(uts.T1)),
         ("cholesky", lambda: cholesky.run(n=512, tile=64)),
         ("smithwaterman", lambda: smithwaterman.run(m=2048, n=2048, tile=256)),
-    ]
-
-
-def _device_suite(trials: int) -> List[Tuple[str, Callable[[], float], str]]:
-    """TPU device engines: (name, fn -> rate, unit). Each fn measures its
-    own steady-state rate (slope harness, bench.py), compiled
-    (interpret=False stated in bench.py); --trials is the trial count."""
-    import bench as b
-
-    return [
-        ("device-fib-scalar", b.bench_device_fib, "tasks/s"),
-        ("device-fib-batch", b.bench_device_vfib, "tasks/s"),
-        (
-            "device-cholesky",
-            lambda: b.bench_device_cholesky(trials=max(1, trials)) * 1e9,
-            "FLOP/s",
-        ),
-        ("device-sw", lambda: b.bench_device_sw() * 1e9, "CUPS"),
-        (
-            # The batched same-kind dispatch tier's flagship workload: the
-            # wave-DAG SW chunks grouped + prefetched by the scheduler.
-            "device-sw-wave",
-            lambda: b.bench_device_sw_wave(trials=max(1, trials)) * 1e9,
-            "CUPS",
-        ),
-        (
-            # Occupancy of the batch rounds behind that number (fraction
-            # of offered batch slots filled, higher is better; populated
-            # by device-sw-wave, so it reads None - recorded as a SKIP,
-            # not a failure - when that entry didn't run or failed). A
-            # collapse here means the DAG stopped exposing same-kind
-            # parallelism to the tier even if GCUPS weather hides it.
-            "device-sw-wave-occupancy",
-            lambda: b.LAST_SW_WAVE_TIERS.get("batch_occupancy"),
-            "fraction",
-        ),
-        ("device-uts", lambda: b.bench_device_uts()[0], "nodes/s"),
     ]
 
 
@@ -1012,8 +967,6 @@ def _latest_log(log_dir: str, quick: bool) -> Dict[str, dict]:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="tiny inputs (smoke)")
-    ap.add_argument("--device", action="store_true",
-                    help="also run the TPU device suite (rates)")
     ap.add_argument("--multichip", action="store_true",
                     help="also run the 8-device mesh acceptance workloads")
     ap.add_argument("--trials", type=int, default=3)
@@ -1427,38 +1380,6 @@ def main(argv=None) -> int:
                     "incremental recompute stopped paying for itself"
                 )
                 line += "  REGRESSED"
-            print(line, flush=True)
-
-    if args.device:
-        from hclib_tpu.device.megakernel import require_tpu
-        from hclib_tpu.runtime.env import use_compile_cache
-
-        use_compile_cache()
-        print(f"--device: {require_tpu()}", file=sys.stderr)  # or raise
-        for name, fn, unit in _device_suite(args.trials):
-            if wanted and name not in wanted:
-                continue
-            try:
-                val = fn()
-                if val is None:  # dependent entry whose producer
-                    print(f"{name:20s} SKIPPED (no data)",  # didn't run
-                          file=sys.stderr)
-                    continue
-                rate = float(val)
-            except Exception as e:  # one engine must not sink the log
-                print(f"{name:20s} FAILED: {e}", file=sys.stderr)
-                failures.append(f"{name}: failed ({e})")
-                continue
-            results[name] = {"rate": rate, "unit": unit}
-            line = f"{name:20s} rate {rate:14.3e} {unit}"
-            if name in prev and "rate" in prev[name]:
-                ratio = rate / prev[name]["rate"]
-                line += f"  vs prev {ratio:5.2f}x"
-                if ratio < 1 - args.tolerance:
-                    failures.append(
-                        f"{name}: {1/ratio:.2f}x slower than previous log"
-                    )
-                    line += "  REGRESSED"
             print(line, flush=True)
 
     ts = int(time.time())

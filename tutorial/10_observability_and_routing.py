@@ -13,7 +13,7 @@ Three production-facing features close the tour:
 
 2. **Auto-routing to the batch-dispatch tier.** A recursive,
    reduction-shaped task family (lesson 7) can be named in
-   ``Megakernel(auto_route=...)``: tasks of that kernel NAME then run as
+   ``Megakernel(route=...)``: tasks of that kernel NAME then run as
    whole subtrees across the VPU lanes instead of one ~100 ns descriptor
    at a time, while the rest of the DAG stays on the scalar tier -
    dependencies, value slots, and counts all behave identically.
@@ -62,7 +62,7 @@ def part_one_tracing(tmpdir: str) -> None:
     print(f"traced {executed} tasks across {stats['nworkers']} workers\n")
 
 
-def part_two_auto_route() -> None:
+def part_two_routing() -> None:
     from hclib_tpu.device.descriptor import TaskGraphBuilder
     from hclib_tpu.device.megakernel import Megakernel
     from hclib_tpu.device.vector_engine import fib_spec
@@ -79,7 +79,7 @@ def part_two_auto_route() -> None:
         ],
         # Route the 'fib' FAMILY to the vector tier: its whole recursion
         # tree expands across the lanes from one descriptor.
-        auto_route={"fib": fib_spec(max_n=16, lanes=(1, 8))},
+        route={"fib": fib_spec(max_n=16, lanes=(1, 8))},
         capacity=32,
         num_values=16,
         succ_capacity=16,
@@ -138,7 +138,7 @@ def main() -> None:
     with tempfile.TemporaryDirectory() as d:
         part_one_tracing(d)
         part_three_flight_recorder(d)
-    part_two_auto_route()
+    part_two_routing()
     print("lesson 10 OK")
 
 

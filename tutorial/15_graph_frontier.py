@@ -25,8 +25,8 @@ adjacency kept in HBM, walked by dynamically-spawned EXPAND tasks
   the bounded ``tiers['max_starved_age']`` gauge.
 
 The headline metric is TEPS (traversed edges/s): ``info['edges']``
-counts every edge each EXPAND relaxed - ``bench.py --graph`` reports it
-beside the UTS nodes/s number.
+counts every edge each EXPAND relaxed; no benchmark cell times it yet
+(ROADMAP.md, Reach 2).
 """
 
 import os

@@ -26,7 +26,8 @@ wedging.
 - **Conservation**: the ledger's identity
   ``submitted == resolved + expired + poisoned (+ pending)`` closes
   exactly - across checkpoint cuts, resumes, and mesh reshards
-  (tools/chaos_soak.py --serve soaks it; bench.py --serve prices it).
+  (tools/chaos_soak.py --serve soaks it; the cells serve-burst-3072
+  and serve-open-steady check it every run).
 
 Ordering rule worth memorizing: after a preemption cut, ``reattach``
 a resume token only AFTER the resumed stream has re-adopted the
