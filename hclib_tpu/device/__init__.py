@@ -32,6 +32,7 @@ from .forasync_tier import (
 )
 from .frontier import (
     Graph,
+    GraphSearch,
     host_bfs,
     host_pagerank,
     host_sssp,
@@ -46,6 +47,7 @@ from .tracebuf import TraceRing, decode_ring, trace_to_jsonable
 __all__ = [
     "Admission",
     "Graph",
+    "GraphSearch",
     "host_bfs",
     "host_pagerank",
     "host_sssp",

@@ -64,6 +64,16 @@ once >= width entries accumulated - keeping ``lane_partial_age`` and the
 device-side ``max_starved_age`` gauge bounded (the frontier-batch perf
 guard pins both).
 
+**Search with the vertices in HBM.** The three traversals above read a
+word of per-vertex state once an EDGE, so that word (and the vertex table)
+is staged into SMEM value slots, which bounds them to small graphs.
+Breadth-first search to a parent array (Graph500's kernel 2) needs one
+BIT a vertex to refuse an edge: the search kind (``search_kernel``,
+``GraphSearch``; "the search kind" below) keeps only that filter on the
+scalar core and puts the vertex table, the frontier and the answer in HBM,
+with EXPAND descriptors made on the device from the frontier as the task
+table has room.
+
 **TEPS.** Every EXPAND counts its ``cnt`` live edges into value slot
 ``V_EDGES``; traversed-edges/s = edges / wall over a run - the headline
 the graph bench reports beside UTS nodes/s. Improving relaxations (or
@@ -72,6 +82,7 @@ PageRank deliveries) count into ``V_RELAX``.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -81,6 +92,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..runtime.locality import MeshPlacement, resolve_placement
+from ..runtime.spans import span
 from .descriptor import TaskGraphBuilder
 from .megakernel import BK_MAX, BatchSpec, Megakernel, _batch_stub
 
@@ -95,6 +107,9 @@ __all__ = [
     "pagerank_kernel",
     "make_frontier_megakernel",
     "run_frontier",
+    "GraphSearch",
+    "SearchKernel",
+    "search_kernel",
     "seed_frontier",
     "host_bfs",
     "host_sssp",
@@ -109,6 +124,7 @@ __all__ = [
 # Edge-block width: one 128-lane row of int32, and the blocked-CSR
 # alignment unit (every vertex's edge run starts on a block boundary).
 EBLOCK = 128
+EBLOCK_SHIFT = 7  # log2(EBLOCK)
 
 # Unreached distance sentinel (fits int32 with relax headroom: INF + any
 # edge weight stays positive and still compares greater than any real
@@ -126,19 +142,39 @@ FR_EXPAND = 0
 PR_NUM = 13
 PR_DEN = 16
 
-# Value-slot layout: two counters, then the vertex table (3 words per
-# vertex: block start / block count / out-degree), then per-vertex state
-# (distance or rank). All host-preset, so the whole layout stages into
-# SMEM and the device reads it with plain dynamic indexing.
+# Value-slot layout of the SMEM-staged kinds (bfs / sssp / pagerank): two
+# counters, then the vertex table (3 words per vertex: block start / block
+# count / out-degree), then per-vertex state (distance or rank). All
+# host-preset, so the whole layout stages into SMEM and the device reads
+# it with plain dynamic indexing. The search kind has its own (S_* below).
 V_EDGES = 0   # traversed edges (the TEPS numerator; combines by sum)
 V_RELAX = 1   # improving relaxations / PR deliveries (combines by sum)
 VT_BASE = 8
 
 
+_CHUNK = 1 << 22  # directed entries a thread of ``undirected`` takes at once
+
+
+def _threaded(fn: Callable[[int, int], None], cuts: np.ndarray) -> None:
+    """``fn(cuts[i], cuts[i + 1])`` for every i, on a few threads."""
+    import concurrent.futures
+    import os
+
+    spans = [(int(a), int(b)) for a, b in zip(cuts[:-1], cuts[1:]) if b > a]
+    with concurrent.futures.ThreadPoolExecutor(
+        min(16, os.cpu_count() or 1)
+    ) as pool:
+        list(pool.map(lambda ab: fn(*ab), spans))
+
+
 class Graph:
     """Host-side blocked-CSR adjacency (module docstring): dense int32
-    arrays shaped for the device tier plus python adjacency for the host
-    reference arms."""
+    arrays shaped for the device tier. Construction (Graph500's kernel 1)
+    is array code throughout: one 64-bit sort that keeps the input order
+    inside a vertex, one prefix sum, one scatter into the blocks. The edge
+    list may hold self-loops and duplicates; they stay. The per-vertex
+    python lists of the host reference arms (``adj``, ``adj_w``) are made
+    from the blocks on first use."""
 
     def __init__(
         self,
@@ -147,9 +183,9 @@ class Graph:
         dst: np.ndarray,
         weights: Optional[np.ndarray] = None,
     ) -> None:
-        src = np.asarray(src, np.int64)
-        dst = np.asarray(dst, np.int64)
-        if src.shape != dst.shape:
+        src = np.asarray(src)
+        dst = np.asarray(dst)
+        if src.shape != dst.shape or src.ndim != 1:
             raise ValueError("src/dst must be the same length")
         if len(src) and (
             src.min() < 0 or src.max() >= n or dst.min() < 0 or dst.max() >= n
@@ -157,45 +193,148 @@ class Graph:
             raise ValueError(f"edge endpoints out of range [0, {n})")
         self.n = int(n)
         self.m = int(len(src))
-        w = (
-            np.asarray(weights, np.int64)
-            if weights is not None
-            else np.ones(self.m, np.int64)
+        if weights is not None:
+            weights = np.asarray(weights)
+            if weights.shape != src.shape:
+                raise ValueError("weights must match the edge count")
+            if len(weights) and weights.min() < 0:
+                raise ValueError("weights must be >= 0")
+        # Stable order by source in one sort of (src << 32 | position).
+        key = (src.astype(np.uint64) << np.uint64(32)) | np.arange(
+            self.m, dtype=np.uint64
         )
-        if w.shape != src.shape:
-            raise ValueError("weights must match the edge count")
-        if len(w) and w.min() < 0:
-            raise ValueError("weights must be >= 0")
-        order = np.argsort(src, kind="stable")
-        src, dst, w = src[order], dst[order], w[order]
-        self.deg = np.bincount(src, minlength=n).astype(np.int32)
+        key.sort()
+        order = (key & np.uint64(0xFFFFFFFF)).astype(np.int64)
+        key >>= np.uint64(32)  # the sources, ascending
+        splits = np.searchsorted(key, np.arange(n + 1, dtype=np.uint64))
+        del key
+        self._layout(splits)
+        # Edge k of the sorted list lands at its vertex's first block
+        # plus its rank in the vertex.
+        flat = np.arange(self.m, dtype=np.int64) + np.repeat(
+            self.blk_start.astype(np.int64) * EBLOCK - splits[:-1], self.deg
+        )
+        self.indices = np.full((self.nblocks, EBLOCK), -1, np.int32)
+        self.indices.reshape(-1)[flat] = dst[order]
+        # The weights' blocks wait for their first reader (all ones where
+        # the caller gave none): an unweighted search never pays for them.
+        self._weights: Optional[np.ndarray] = None
+        self._weights_src = (
+            flat, None if weights is None else weights[order], self.nblocks
+        )
+
+    @classmethod
+    def undirected(cls, n: int, u: np.ndarray, v: np.ndarray) -> "Graph":
+        """The graph of the undirected edge tuples ``(u[i], v[i])``, each
+        as both its directed entries, self-loops and duplicates as given;
+        a vertex's targets ascend. The same construction as ``__init__``
+        (sort, prefix sum, scatter) with nothing held a directed entry but
+        its 64-bit sort key, and the key's fill and the blocks' scatter
+        spread over a few threads a range of vertices each: at 2^27
+        entries what a fresh host page costs decides the time, and
+        threads take those faults side by side."""
+        u, v = np.asarray(u), np.asarray(v)
+        if u.shape != v.shape or u.ndim != 1:
+            raise ValueError("u/v must be the same length")
+        m = len(u)
+        if m and (min(u.min(), v.min()) < 0 or max(u.max(), v.max()) >= n):
+            raise ValueError(f"edge endpoints out of range [0, {n})")
+        self = cls.__new__(cls)
+        self.n, self.m = int(n), 2 * m
+        with span("g500.kernel1"):
+            self._build_undirected(u, v)
+        return self
+
+    def _build_undirected(self, u: np.ndarray, v: np.ndarray) -> None:
+        n, m = self.n, len(u)
+        key = np.empty(2 * m, np.uint64)
+
+        def fill(lo, hi):
+            a = u[lo:hi].astype(np.uint64)
+            b = v[lo:hi].astype(np.uint64)
+            key[lo:hi] = (a << np.uint64(32)) | b
+            key[m + lo : m + hi] = (b << np.uint64(32)) | a
+
+        _threaded(fill, np.arange(0, m + _CHUNK, _CHUNK).clip(max=m))
+        key.sort()
+        splits = np.searchsorted(
+            key, np.arange(n + 1, dtype=np.uint64) << np.uint64(32)
+        )
+        self._layout(splits)
+        self.indices = np.empty((self.nblocks, EBLOCK), np.int32)
+        ends = np.append(self.blk_start, self.nblocks).astype(np.int64)
+
+        def scatter(v0, v1):
+            e0, b0 = splits[v0], ends[v0]
+            out = self.indices[b0 : ends[v1]].reshape(-1)
+            out[:] = -1
+            at = np.arange(splits[v1] - e0) + np.repeat(
+                (ends[v0:v1] - b0) * EBLOCK - (splits[v0:v1] - e0),
+                self.deg[v0:v1],
+            )
+            out[at] = key[e0 : splits[v1]].astype(np.uint32)  # low: target
+
+        # Ranges of vertices with about _CHUNK entries each.
+        cuts = np.searchsorted(splits, np.arange(0, 2 * m, _CHUNK))
+        _threaded(scatter, np.unique(np.append(cuts, n)))
+        if not self.m:
+            self.indices[:] = -1
+        self._weights = self._weights_src = None
+
+    def _layout(self, splits: np.ndarray) -> None:
+        """``deg`` / ``blk_count`` / ``blk_start`` / ``nblocks`` from the
+        sorted list's per-vertex boundaries."""
+        self.deg = np.diff(splits).astype(np.int32)
         self.blk_count = ((self.deg + EBLOCK - 1) // EBLOCK).astype(np.int32)
-        self.blk_start = np.zeros(n, np.int32)
-        if n > 1:
+        self.blk_start = np.zeros(self.n, np.int32)
+        if self.n > 1:
             self.blk_start[1:] = np.cumsum(self.blk_count)[:-1].astype(
                 np.int32
             )
-        self.nblocks = max(1, int(self.blk_count.sum()))
-        self.indices = np.full((self.nblocks, EBLOCK), -1, np.int32)
-        self.weights = np.zeros((self.nblocks, EBLOCK), np.int32)
-        # Per-vertex adjacency (python lists) for the host references.
-        splits = np.searchsorted(src, np.arange(n + 1))
-        self.adj: List[np.ndarray] = []
-        self.adj_w: List[np.ndarray] = []
-        for v in range(n):
-            lo, hi = int(splits[v]), int(splits[v + 1])
-            self.adj.append(dst[lo:hi].astype(np.int32))
-            self.adj_w.append(w[lo:hi].astype(np.int32))
-            d = hi - lo
-            b0 = int(self.blk_start[v])
-            flat = self.indices[
-                b0 : b0 + int(self.blk_count[v])
-            ].reshape(-1)
-            flat[:d] = dst[lo:hi]
-            wflat = self.weights[
-                b0 : b0 + int(self.blk_count[v])
-            ].reshape(-1)
-            wflat[:d] = w[lo:hi]
+        self.nblocks = max(1, int(self.blk_count.sum(dtype=np.int64)))
+
+    @property
+    def weights(self) -> np.ndarray:
+        if self._weights is None:
+            if self._weights_src is None:  # undirected(): all ones
+                self._weights = (self.indices >= 0).astype(np.int32)
+            else:
+                flat, w, nblocks = self._weights_src
+                self._weights = np.zeros((nblocks, EBLOCK), np.int32)
+                self._weights.reshape(-1)[flat] = 1 if w is None else w
+                self._weights_src = None
+        return self._weights
+
+    @weights.setter
+    def weights(self, value: np.ndarray) -> None:
+        self._weights = value
+
+    def _per_vertex(self, blocks: np.ndarray) -> List[np.ndarray]:
+        return [
+            blocks[b0 : b0 + bc].reshape(-1)[:d]
+            for b0, bc, d in zip(
+                self.blk_start.tolist(), self.blk_count.tolist(),
+                self.deg.tolist(),
+            )
+        ]
+
+    @functools.cached_property
+    def adj(self) -> List[np.ndarray]:
+        """Per-vertex targets, for the host reference arms."""
+        return self._per_vertex(self.indices)
+
+    @functools.cached_property
+    def adj_w(self) -> List[np.ndarray]:
+        return self._per_vertex(self.weights)
+
+    def vtab(self) -> np.ndarray:
+        """The vertex table as the search kind reads it from HBM: ``(first
+        block, degree)`` pairs, 64 vertices a 128-word row."""
+        rows = -(-self.n // 64)
+        t = np.zeros((rows * 64, 2), np.int32)
+        t[: self.n, 0] = self.blk_start
+        t[: self.n, 1] = self.deg
+        return t.reshape(rows, EBLOCK)
 
     def block_cnt(self, v: int, i: int) -> int:
         """Live edges in block ``i`` of vertex ``v`` (the descriptor's
@@ -617,6 +756,321 @@ def pagerank_kernel(reps: int = 64,
     return fk
 
 
+# ------------------------------------------------- the search kind (HBM)
+#
+# Graph500's kernel 2: breadth-first search from one key, the answer a
+# parent array. Nothing a vertex owns is staged into SMEM: the vertex
+# table (``vtab``: first block and degree, 64 vertices a 128-word row),
+# the frontier and the answer live in HBM. What stays on the scalar core
+# is a FILTER of one bit a vertex (``sr_bits``), so that an edge whose far
+# end is reached costs no HBM access at all, and HBM is touched once a
+# NEWLY reached vertex: its ``(vertex, parent)`` pair is appended to the
+# QUEUE (``queue``: 64 pairs a row, a row DMA'd out when it fills), and
+# its table words are read when the queue's reader gets to it.
+#
+# The queue IS the frontier, in discovery order, so the task table holds
+# no frontier at all: a MAKE task (scalar tier) reads the queue, gathers
+# ``SR_GROUP`` vertices' table rows at a time, and makes EXPAND
+# descriptors while the table has room (``capacity - SR_SPARE`` of them),
+# each naming the maker as its successor; then it re-arms itself
+# (``ctx.become``) on as many predecessors as it made, and runs again
+# when the last of them has retired. It never crosses the end of a level
+# with an EXPAND of that level outstanding, so the order is exactly
+# level-synchronous: the first pair the queue holds for a vertex carries
+# its true level, nothing is relaxed twice (``edges`` is the component's
+# directed entries exactly), and the queue's level boundaries
+# (``S_LSTART``) are the levels. The host reads the queue back and
+# unrolls it into the parent array with one numpy scatter.
+
+SR_MAKE = 1  # the maker's table index, beside FR_EXPAND
+
+SR_GROUP = 16  # vertices whose table rows one gather fetches
+SR_SPARE = 8   # table rows a maker leaves free (itself, and slack)
+SR_LEVELS = 64  # level boundaries the value slots keep
+SR_TEST_SHIFT = 2
+SR_TEST = 1 << SR_TEST_SHIFT  # entries whose filter bits one branch tests
+
+# A search build's value slots: V_EDGES, then the maker's state.
+S_EXPANDS = 1   # EXPAND descriptors made
+S_QHEAD = 2     # queue position the reader is at
+S_QTAIL = 3     # queue length: the vertices reached
+S_LEVEL_END = 4  # queue position at which the level in hand ends
+S_LEVEL = 5     # its number
+S_FRONT_MAX = 6  # the longest level
+S_GRP_N = 7     # vertices in the gathered group
+S_GRP_I = 8     # the one in hand
+S_CUR_BLK = 9   # its next block
+S_CUR_END = 10  # one past its last
+S_CUR_LEFT = 11  # its entries not yet given to an EXPAND
+S_RD_ROW = 12   # queue row held in sr_qrd, + 1 (0: none)
+S_HBM_RD = 13   # state words read from HBM (table rows, queue rows)
+S_HBM_WR = 14   # state words written to HBM (queue rows)
+S_MADE = 15     # EXPANDs this maker call made
+S_STOP = 16     # this maker call is over
+S_LSTART = 24   # + level: the queue position its level starts at
+S_WORDS = S_LSTART + SR_LEVELS
+
+
+def _bits_rows(n: int) -> int:
+    return -(-n // (32 * EBLOCK))
+
+
+class SearchKernel(FrontierKernel):
+    """The EXPAND of a search: the frontier tier's edge-slab pipeline
+    (same descriptor, same batched body, same prefetch) with a relax that
+    asks the bit filter and appends to the queue."""
+
+    def __init__(self) -> None:
+        super().__init__("fr_search", None, weighted=False, state0=0)
+        self.st_base = 0  # no per-vertex value slots
+
+    def data_specs(self, graph: Graph) -> Dict[str, jax.ShapeDtypeStruct]:
+        specs = super().data_specs(graph)
+        rows = -(-graph.n // 64)
+        specs["vtab"] = jax.ShapeDtypeStruct((rows, EBLOCK), jnp.int32)
+        specs["queue"] = jax.ShapeDtypeStruct((rows, EBLOCK), jnp.int32)
+        return specs
+
+    def search_scratch(self, graph: Graph) -> Dict[str, Any]:
+        return {
+            "sr_bits": pltpu.SMEM((_bits_rows(graph.n) * EBLOCK,), jnp.int32),
+            "sr_qst": pltpu.SMEM((EBLOCK,), jnp.int32),
+            "sr_qrd": pltpu.SMEM((EBLOCK,), jnp.int32),
+            "sr_vt": pltpu.SMEM((SR_GROUP, EBLOCK), jnp.int32),
+            "sr_grp": pltpu.SMEM((4 * SR_GROUP,), jnp.int32),
+            "sr_sem": pltpu.SemaphoreType.DMA((SR_GROUP + 1,)),
+        }
+
+    def relax(self, kctx, u, w, carry) -> None:
+        bits = kctx.scratch["sr_bits"]
+        word = bits[u >> 5]
+        bit = jnp.int32(1) << (u & 31)
+
+        @pl.when((word & bit) == 0)
+        def _():
+            bits[u >> 5] = word | bit
+            _queue_append(kctx, u, carry)
+
+    def _relax_block(self, kctx, eslab, wslab, carry, cnt) -> None:
+        """The shared loop's spelling for a filter: nearly every entry's
+        far end is reached already, so the entries are TESTED
+        ``SR_TEST`` at a time as straight-line code and only a group with
+        an unreached one takes the branch into the per-entry relax (which
+        tests again: two entries of a group may name one vertex). A
+        block's rows are padded with -1 past ``cnt``."""
+        kctx.ivalues[V_EDGES] = kctx.ivalues[V_EDGES] + cnt
+        bits = kctx.scratch["sr_bits"]
+
+        def unreached(e):
+            u = jnp.maximum(eslab(e), 0)
+            return (e < cnt) & (((bits[u >> 5] >> (u & 31)) & 1) == 0)
+
+        def group(g, _):
+            e0 = g * SR_TEST
+            hit = functools.reduce(
+                jnp.logical_or, [unreached(e0 + k) for k in range(SR_TEST)]
+            )
+
+            @pl.when(hit)
+            def _():
+                for k in range(SR_TEST):
+                    @pl.when(e0 + k < cnt)
+                    def _(k=k):
+                        self.relax(kctx, eslab(e0 + k), None, carry)
+
+            return 0
+
+        jax.lax.fori_loop(
+            0, (cnt + SR_TEST - 1) >> SR_TEST_SHIFT, group, 0
+        )
+
+
+def _queue_row_copy(kctx, row, out: bool):
+    """The DMA between queue row ``row`` and its SMEM row: the staging
+    row out, or the reader's row in."""
+    q = kctx.data["queue"].at[row]
+    sem = kctx.scratch["sr_sem"].at[SR_GROUP]
+    if out:
+        return pltpu.make_async_copy(kctx.scratch["sr_qst"], q, sem)
+    return pltpu.make_async_copy(q, kctx.scratch["sr_qrd"], sem)
+
+
+def _queue_append(kctx, u, parent) -> None:
+    iv = kctx.ivalues
+    qt = iv[S_QTAIL]
+    lane = (qt & 63) * 2
+    kctx.scratch["sr_qst"][lane] = u
+    kctx.scratch["sr_qst"][lane + 1] = parent
+    iv[S_QTAIL] = qt + 1
+
+    @pl.when((qt & 63) == 63)
+    def _():
+        cp = _queue_row_copy(kctx, qt >> 6, out=True)
+        cp.start()
+        cp.wait()
+        iv[S_HBM_WR] = iv[S_HBM_WR] + EBLOCK
+
+
+def _queue_vertex(kctx, p):
+    """The vertex at queue position ``p`` (< S_QTAIL): from the staging
+    row while its row has not been written out, else from the reader's
+    row, fetched when ``p`` enters a new one."""
+    iv = kctx.ivalues
+    row = p >> 6
+    staged = row == (iv[S_QTAIL] >> 6)
+
+    @pl.when(jnp.logical_not(staged) & (iv[S_RD_ROW] != row + 1))
+    def _():
+        cp = _queue_row_copy(kctx, row, out=False)
+        cp.start()
+        cp.wait()
+        iv[S_RD_ROW] = row + 1
+        iv[S_HBM_RD] = iv[S_HBM_RD] + EBLOCK
+
+    lane = (p & 63) * 2
+    return jnp.where(
+        staged, kctx.scratch["sr_qst"][lane], kctx.scratch["sr_qrd"][lane]
+    )
+
+
+def _make_kernel(graph_n: int, budget: int) -> Callable:
+    """The maker of one graph size (module comment above). Its descriptor:
+    ``[first, key]``; ``first`` is 1 on the host's seed, which clears the
+    filter and enqueues the key, and 0 ever after."""
+    nwords = _bits_rows(graph_n) * EBLOCK
+
+    def take(ctx, i) -> None:
+        """Vertex ``i`` of the gathered group becomes the one in hand."""
+        iv, grp = ctx.ivalues, ctx.scratch["sr_grp"]
+        first, deg = grp[4 * i + 1], grp[4 * i + 2]
+        iv[S_GRP_I] = i
+        iv[S_CUR_BLK] = first
+        # A shift, not a divide: the scalar core has no cheap one.
+        iv[S_CUR_END] = first + ((deg + EBLOCK - 1) >> EBLOCK_SHIFT)
+        iv[S_CUR_LEFT] = deg
+
+    def gather(ctx, qh, g) -> None:
+        """The next ``g`` queue vertices and their table words into
+        ``sr_grp``: every row DMA in flight before the first wait."""
+        iv, grp = ctx.ivalues, ctx.scratch["sr_grp"]
+        vt, sems = ctx.scratch["sr_vt"], ctx.scratch["sr_sem"]
+
+        def copy(i):
+            return pltpu.make_async_copy(
+                ctx.data["vtab"].at[grp[4 * i] >> 6], vt.at[i], sems.at[i]
+            )
+
+        for i in range(SR_GROUP):
+            @pl.when(i < g)
+            def _(i=i):
+                grp[4 * i] = _queue_vertex(ctx, qh + i)
+                copy(i).start()
+
+        for i in range(SR_GROUP):
+            @pl.when(i < g)
+            def _(i=i):
+                copy(i).wait()
+                lane = (grp[4 * i] & 63) * 2
+                grp[4 * i + 1] = vt[i, lane]
+                grp[4 * i + 2] = vt[i, lane + 1]
+
+        iv[S_HBM_RD] = iv[S_HBM_RD] + g * EBLOCK
+        iv[S_QHEAD] = qh + g
+        iv[S_GRP_N] = g
+        take(ctx, 0)
+
+    def kernel(ctx) -> None:
+        iv = ctx.ivalues
+        bits = ctx.scratch["sr_bits"]
+
+        @pl.when(ctx.arg(0) != 0)
+        def _():
+            def clear(i, _):
+                bits[i] = 0
+                return 0
+
+            jax.lax.fori_loop(0, nwords, clear, 0)
+            key = ctx.arg(1)
+            bits[key >> 5] = jnp.int32(1) << (key & 31)
+            ctx.scratch["sr_qst"][0] = key
+            ctx.scratch["sr_qst"][1] = key
+            iv[S_QTAIL] = 1
+            iv[S_LEVEL_END] = 1
+            iv[S_FRONT_MAX] = 1
+            ctx.set_arg(ctx.idx, 0, 0)
+
+        iv[S_MADE] = 0
+        iv[S_STOP] = 0
+
+        def step(_):
+            in_hand = iv[S_CUR_BLK] < iv[S_CUR_END]
+            more = iv[S_GRP_I] + 1 < iv[S_GRP_N]
+            qh, end, qt = iv[S_QHEAD], iv[S_LEVEL_END], iv[S_QTAIL]
+
+            @pl.when(in_hand)
+            def _():
+                blk, left = iv[S_CUR_BLK], iv[S_CUR_LEFT]
+                cnt = jnp.minimum(left, EBLOCK)
+                v = ctx.scratch["sr_grp"][4 * iv[S_GRP_I]]
+                ctx.spawn(FR_EXPAND, [v, blk, v, cnt], succ0=ctx.idx,
+                          nargs=4)
+                iv[S_CUR_BLK] = blk + 1
+                iv[S_CUR_LEFT] = left - cnt
+                iv[S_MADE] = iv[S_MADE] + 1
+
+            @pl.when(jnp.logical_not(in_hand) & more)
+            def _():
+                take(ctx, iv[S_GRP_I] + 1)
+
+            idle = jnp.logical_not(in_hand | more)
+
+            @pl.when(idle & (qh < end))
+            def _():
+                gather(ctx, qh, jnp.minimum(end - qh, SR_GROUP))
+
+            # The level's queue is read out. With EXPANDs of it still to
+            # run, the next level's end is not known: stop here. With none,
+            # the level is whole: open the next, or end the search.
+            whole = idle & (qh == end) & (iv[S_MADE] == 0)
+
+            @pl.when(whole & (qt > end))
+            def _():
+                lvl = iv[S_LEVEL] + 1
+                iv[S_LEVEL] = lvl
+                iv[S_LSTART + jnp.minimum(lvl, SR_LEVELS - 1)] = end
+                iv[S_LEVEL_END] = qt
+                iv[S_FRONT_MAX] = jnp.maximum(iv[S_FRONT_MAX], qt - end)
+
+            @pl.when(idle & (qh == end) & ((iv[S_MADE] > 0) | (qt == end)))
+            def _():
+                iv[S_STOP] = 1
+
+            return (iv[S_MADE] < budget) & (iv[S_STOP] == 0)
+
+        jax.lax.while_loop(lambda go: go, step, jnp.bool_(True))
+        made = iv[S_MADE]
+        iv[S_EXPANDS] = iv[S_EXPANDS] + made
+
+        @pl.when(made > 0)
+        def _():
+            ctx.become(SR_MAKE, made)
+
+        @pl.when((made == 0) & ((iv[S_QTAIL] & 63) != 0))
+        def _():  # the search is over: the queue's last, part-filled row
+            cp = _queue_row_copy(ctx, iv[S_QTAIL] >> 6, out=True)
+            cp.start()
+            cp.wait()
+            iv[S_HBM_WR] = iv[S_HBM_WR] + EBLOCK
+
+    return kernel
+
+
+def search_kernel() -> SearchKernel:
+    """Breadth-first search to a parent array, per-vertex state in HBM
+    (``GraphSearch`` runs it)."""
+    return SearchKernel()
+
+
 # ------------------------------------------------------------ host side
 
 _KINDS: Dict[str, Callable[..., FrontierKernel]] = {
@@ -821,8 +1275,9 @@ def make_frontier_megakernel(
     fold first, bounding the live frontier). Exactness never depends on
     it: the result is schedule-independent (certified via ``si_claim``)
     and bit-identical to the unordered arm."""
+    search = isinstance(fk, SearchKernel)
     if num_values is None:
-        num_values = graph.num_value_slots + 8
+        num_values = S_WORDS + 8 if search else graph.num_value_slots + 8
     if priority_buckets is None:
         # The process-wide spelling reaches the builder too (the
         # builder must know: it disables the cross-round prefetch and
@@ -836,7 +1291,7 @@ def make_frontier_megakernel(
             "priority_buckets needs the batched arm (width > 0): the "
             "bucket rings layer over the per-kind batch lanes"
         )
-    if delta is None:
+    if delta is None and not search:
         delta = default_delta(graph)
     if width:
         # Bucketed builds genuinely run WITHOUT the cross-round
@@ -889,14 +1344,27 @@ def make_frontier_megakernel(
         route = None
         scratch = fk.scalar_scratch()
         lane_max_age = 0 if lane_max_age is None else lane_max_age
-    if fk.st_base is not None and fk.st_base != graph.st_base:
+    if search:
+        if priority_buckets:
+            raise ValueError(
+                "a search is level-synchronous by its maker: it takes no "
+                "priority_buckets"
+            )
+        if capacity <= SR_SPARE:
+            raise ValueError(f"a search needs capacity > {SR_SPARE}")
+        kernels.append(
+            ("sr_make", _make_kernel(graph.n, capacity - SR_SPARE))
+        )
+        scratch.update(fk.search_scratch(graph))
+    if not search and fk.st_base is not None and fk.st_base != graph.st_base:
         raise ValueError(
             "FrontierKernel is already bound to a different graph layout "
             f"(st_base {fk.st_base} vs {graph.st_base}): build a fresh "
             "kernel per graph - megakernels trace lazily, so rebinding "
             "would silently retarget an earlier build's state region"
         )
-    fk.st_base = graph.st_base
+    if not search:
+        fk.st_base = graph.st_base
     mk = Megakernel(
         kernels=kernels,
         route=route,
@@ -910,12 +1378,17 @@ def make_frontier_megakernel(
         checkpoint=checkpoint,
         lane_max_age=lane_max_age,
         priority_buckets=priority_buckets,
+        # A search writes its queue alone; the adjacency and the vertex
+        # table are read where they lie.
+        read_only=["indices", "vtab"] if search else (),
     )
     # Stamp the graph layout the traced kernel is bound to: the relax
     # closures bake st_base (and the data specs bake nblocks) into the
     # trace, so running this build over a DIFFERENT graph layout would
     # silently read the wrong state region - run_frontier refuses it.
-    mk._frontier_layout = (fk.name, graph.n, graph.nblocks, graph.st_base)
+    mk._frontier_layout = (
+        fk.name, graph.n, graph.nblocks, 0 if search else graph.st_base
+    )
     # Schedule-independence claim (the exactness model this module's
     # docstring promises): certified lazily by analysis/model.py - K
     # permuted pop orders to the fixpoint - and surfaced in describe()
@@ -1110,3 +1583,99 @@ def run_frontier(
     info["hop_order"] = list(hop_order) if hop_order else None
     result, info = finish(iv_o, info)
     return result, info
+
+
+# ---------------------------------------------------------------- search
+
+
+class GraphSearch:
+    """Breadth-first searches over one resident graph (Graph500's kernel
+    2): built once a graph - the ``Megakernel``, and the adjacency and
+    vertex table uploaded to HBM, where they stay - and called once a
+    search key.
+
+    ``fuel`` bounds the tasks (EXPANDs and maker calls) of one search; a
+    search that needs more stalls (``StallError``)."""
+
+    def __init__(
+        self,
+        graph: Graph,
+        *,
+        width: int = 8,
+        capacity: int = 128,
+        interpret: Optional[bool] = None,
+        fuel: int = 1 << 30,
+    ) -> None:
+        self.graph = graph
+        self.fuel = int(fuel)
+        self.mk = make_frontier_megakernel(
+            search_kernel(), graph, width=width, capacity=capacity,
+            interpret=interpret,
+        )
+        qspec = self.mk.data_specs["queue"]
+        with self._device():
+            self._data = {
+                "indices": jnp.asarray(graph.indices),
+                "vtab": jnp.asarray(graph.vtab()),
+                "queue": jnp.zeros(qspec.shape, qspec.dtype),
+            }
+
+    def _device(self):
+        """Where the graph lives: the default device, or - an interpreter
+        build - the host CPU, as ``Megakernel`` pins its own program."""
+        import contextlib
+
+        if self.mk.interpret:
+            return jax.default_device(jax.devices("cpu")[0])
+        return contextlib.nullcontext()
+
+    def bfs(self, key: int) -> Tuple[np.ndarray, Dict]:
+        """One search from ``key``: ``(parent, info)``. ``parent`` is
+        int32, -1 where the search did not reach, ``parent[key] == key``.
+        ``info`` is ``Megakernel.run``'s with the search's own counters
+        under ``info["search"]``."""
+        key = int(key)
+        if not 0 <= key < self.graph.n:
+            raise ValueError(f"key {key} out of range [0, {self.graph.n})")
+        with span("g500.seed"):
+            b = TaskGraphBuilder()
+            b.reserve_values(S_WORDS)
+            b.add(SR_MAKE, args=[1, key])
+            iv = np.zeros(self.mk.num_values, np.int32)
+        with span("g500.search"):
+            iv, out, info = self.mk.run(
+                b, data=self._data, ivalues=iv, fuel=self.fuel
+            )
+        self._data = out  # the queue was consumed and comes back
+        with span("g500.readback"):
+            # The queue unrolled: its first S_QTAIL pairs, each vertex once.
+            pairs = np.asarray(out["queue"]).reshape(-1, 2)[: iv[S_QTAIL]]
+            parent = np.full(self.graph.n, -1, np.int32)
+            parent[pairs[:, 0]] = pairs[:, 1]
+        levels = int(iv[S_LEVEL]) + 1
+        tiers = info.get("tiers", {})
+        info["search"] = {
+            "edges": int(iv[V_EDGES]),
+            "reached": int(iv[S_QTAIL]),
+            "levels": levels,
+            "level_starts": [
+                int(x) for x in iv[S_LSTART : S_LSTART + min(levels,
+                                                             SR_LEVELS)]
+            ],
+            "expands": int(iv[S_EXPANDS]),
+            "frontier_max": int(iv[S_FRONT_MAX]),
+            "live_rows_max": info["allocated"],
+            "capacity": self.mk.capacity,
+            "batch_rounds": tiers.get("batch_rounds", 0),
+            "batch_slots": tiers.get("batch_tasks", 0),
+            "hbm_words_read": int(iv[S_HBM_RD]),
+            "hbm_words_written": int(iv[S_HBM_WR]),
+        }
+        return parent, info
+
+    def blocks_of(self, parent: np.ndarray) -> int:
+        """Adjacency blocks of the vertices ``parent`` reaches: what
+        ``expands`` is when every reached vertex is expanded once."""
+        return int(
+            self.graph.blk_count[np.asarray(parent) >= 0].sum(dtype=np.int64)
+        )

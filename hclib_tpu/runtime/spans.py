@@ -58,6 +58,11 @@ STAGES = (
     "uts.stage",  # the engine's shape, thresholds table, scalars
     "uts.run",  # the one launch, waited for
     "uts.readback",  # the counts read and summed
+    # Graph.undirected / GraphSearch.bfs (device/frontier.py)
+    "g500.kernel1",  # the tuples sorted and scattered into blocks
+    "g500.seed",  # a search's builder, its one maker row, the zero values
+    "g500.search",  # Megakernel.run: the four mk.* stages nest inside
+    "g500.readback",  # the queue read and unrolled into the parent array
 )
 
 

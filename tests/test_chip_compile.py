@@ -300,6 +300,23 @@ def _frontier(sh):
     ), sh)
 
 
+def _search(sh):
+    """The cell g500-bfs-search's build: Graph500's scale 22 by the
+    shapes alone (a graph of that size is seconds of kernel 1), the
+    adjacency, vertex table and queue handed in on the chip as
+    ``GraphSearch`` does."""
+    import types
+
+    from hclib_tpu.device.frontier import (
+        make_frontier_megakernel, search_kernel,
+    )
+
+    g = types.SimpleNamespace(n=1 << 22, nblocks=3_200_000)
+    _compile_mk(make_frontier_megakernel(
+        search_kernel(), g, width=8, capacity=128, interpret=False,
+    ), sh, fuel=1 << 30, on_device=("indices", "vtab", "queue"))
+
+
 def _dyngraph(sh):
     from hclib_tpu.device.dyngraph import DynGraph, make_dyngraph_megakernel
     from hclib_tpu.device.workloads import rmat_edges
@@ -326,7 +343,7 @@ KERNELS = {
     for f in (_fib_scalar, _fib_batch, _uts_t1l, _cholesky_8192, _sw_fused,
               _sw_wave, _forasync_1d, _forasync_2d, _forasync_hbm,
               _serve_stream, _serve_stream_delta,
-              _frontier, _dyngraph, _bnb)
+              _frontier, _search, _dyngraph, _bnb)
 }
 
 

@@ -105,8 +105,10 @@ def test_spans_land_nested_in_a_profile_the_benchmark_reads(tmp_path):
 
 
 def test_the_table_names_every_stage_once():
-    assert len(set(spans.STAGES)) == len(spans.STAGES) == 29
-    assert all(re.fullmatch(r"[a-z]+(\.[a-z]+)+", s) for s in spans.STAGES)
+    assert len(set(spans.STAGES)) == len(spans.STAGES) == 33
+    assert all(
+        re.fullmatch(r"[a-z0-9]+(\.[a-z0-9]+)+", s) for s in spans.STAGES
+    )
 
 
 def test_only_the_helper_imports_the_annotation_and_spells_the_prefix():
@@ -127,7 +129,7 @@ def test_the_spans_the_source_opens_are_the_helpers_table():
             continue
         assert "from ..runtime.spans import span\n" in text, path
         for arg in calls:  # a literal stage and nothing else
-            assert re.fullmatch(r'"[a-z.]+"', arg), (path, arg)
+            assert re.fullmatch(r'"[a-z0-9.]+"', arg), (path, arg)
             opened.add(arg.strip('"'))
     assert opened == set(spans.STAGES)
 
@@ -274,7 +276,7 @@ FRONT = {"source": "program_span", "layer": "front door",
          # the open-loop cell of PR 44 joined the lists
          "workloads": [SERVE, "serve-open-steady"]}
 STAGING = {"layer": "host staging", "moves": "solve_ms",
-           "workloads": [CHOL, SW, FA]}
+           "workloads": [CHOL, SW, FA, "g500-bfs-search"]}
 # name: (reducer, args, unit, the rest of the entry, value on HOST below)
 METRICS = {
     "launch_us": ("span_mean",

@@ -70,7 +70,7 @@ def _programs() -> List[Tuple[str, "callable"]]:
         make_forasync_megakernel
     from hclib_tpu.device.frontier import (
         Graph, bfs_kernel, make_frontier_megakernel, pagerank_kernel,
-        sssp_kernel,
+        search_kernel, sssp_kernel,
     )
     from hclib_tpu.device.smithwaterman import (
         make_sw_batched_megakernel, make_sw_megakernel,
@@ -102,7 +102,7 @@ def _programs() -> List[Tuple[str, "callable"]]:
         n, rng.integers(0, n, m), rng.integers(0, n, m),
         rng.integers(1, 9, m),
     )
-    for kf in (bfs_kernel, sssp_kernel, pagerank_kernel):
+    for kf in (bfs_kernel, sssp_kernel, pagerank_kernel, search_kernel):
         progs.append((
             f"frontier:{kf().name}",
             lambda kf=kf: make_frontier_megakernel(
