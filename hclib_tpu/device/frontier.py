@@ -72,7 +72,10 @@ BIT a vertex to refuse an edge: the search kind (``search_kernel``,
 ``GraphSearch``; "the search kind" below) keeps only that filter on the
 scalar core and puts the vertex table, the frontier and the answer in HBM,
 with EXPAND descriptors made on the device from the frontier as the task
-table has room.
+table has room. The filter has a LEAD row of all ones in front of vertex
+0's word, where vertex -1's bit lies: a block's padding reads as reached
+by the same test as a vertex, so the loop over a block's entries compares
+nothing with ``cnt``.
 
 **TEPS.** Every EXPAND counts its ``cnt`` live edges into value slot
 ``V_EDGES``; traversed-edges/s = edges / wall over a run - the headline
@@ -476,6 +479,11 @@ class FrontierKernel:
 
         jax.lax.fori_loop(0, cnt, e_body, 0)
 
+    def _slab(self, ref, *row) -> Callable:
+        """The reader ``f(e) -> scalar`` of the slab row ``ref[*row]``
+        that ``_relax_block`` is handed."""
+        return lambda e: ref[(*row, e)]
+
     # -- scalar-tier spelling --
 
     def scalar_scratch(self) -> Dict[str, Any]:
@@ -508,8 +516,8 @@ class FrontierKernel:
         cnt = self._eff_cnt(ctx, v, blk, cnt)
         self._relax_block(
             ctx,
-            lambda e: ctx.scratch["fr_idx"][e],
-            (lambda e: ctx.scratch["fr_wgt"][e]) if self.weighted else None,
+            self._slab(ctx.scratch["fr_idx"]),
+            self._slab(ctx.scratch["fr_wgt"]) if self.weighted else None,
             carry,
             cnt,
         )
@@ -583,8 +591,8 @@ class FrontierKernel:
                 kctx = ctx.slot_ctx(b)
                 self._relax_block(
                     kctx,
-                    lambda e, b=b: ctx.scratch["fr_idx"][buf, b, e],
-                    (lambda e, b=b: ctx.scratch["fr_wgt"][buf, b, e])
+                    self._slab(ctx.scratch["fr_idx"], buf, b),
+                    self._slab(ctx.scratch["fr_wgt"], buf, b)
                     if self.weighted
                     else None,
                     ctx.arg(b, 2),
@@ -768,6 +776,16 @@ def pagerank_kernel(reps: int = 64,
 # QUEUE (``queue``: 64 pairs a row, a row DMA'd out when it fills), and
 # its table words are read when the queue's reader gets to it.
 #
+# Nearly every entry a search examines names a reached vertex, so what a
+# search costs is the straight-line length of the test that finds nothing
+# (ISSUE 49 read it off the compiler's listing: ``tools/kernel_listing.py
+# --kernel search``). Vertex ``u``'s bit is bit ``u & 31`` of word
+# ``SR_LEAD + (u >> 5)``: the ``SR_LEAD`` words in front are all ones, and
+# a block's padding, -1, is bit 31 of the last of them (``-1 >> 5`` is -1,
+# ``-1 & 31`` is 31), so padding reads as REACHED and needs neither a
+# compare with ``cnt`` nor a clamp to vertex 0. The maker's first call
+# sets the lead and clears the rest.
+#
 # The queue IS the frontier, in discovery order, so the task table holds
 # no frontier at all: a MAKE task (scalar tier) reads the queue, gathers
 # ``SR_GROUP`` vertices' table rows at a time, and makes EXPAND
@@ -787,8 +805,10 @@ SR_MAKE = 1  # the maker's table index, beside FR_EXPAND
 SR_GROUP = 16  # vertices whose table rows one gather fetches
 SR_SPARE = 8   # table rows a maker leaves free (itself, and slack)
 SR_LEVELS = 64  # level boundaries the value slots keep
-SR_TEST_SHIFT = 2
+SR_TEST_SHIFT = 4
 SR_TEST = 1 << SR_TEST_SHIFT  # entries whose filter bits one branch tests
+SR_SUB = 4  # of them a sub-group, which a hit walks through relax or jumps
+SR_LEAD = EBLOCK  # filter words in front of vertex 0's (a row), all ones
 
 # A search build's value slots: V_EDGES, then the maker's state.
 S_EXPANDS = 1   # EXPAND descriptors made
@@ -807,6 +827,7 @@ S_HBM_RD = 13   # state words read from HBM (table rows, queue rows)
 S_HBM_WR = 14   # state words written to HBM (queue rows)
 S_MADE = 15     # EXPANDs this maker call made
 S_STOP = 16     # this maker call is over
+S_HITS = 17     # sub-groups of entries that went into relax
 S_LSTART = 24   # + level: the queue position its level starts at
 S_WORDS = S_LSTART + SR_LEVELS
 
@@ -833,7 +854,9 @@ class SearchKernel(FrontierKernel):
 
     def search_scratch(self, graph: Graph) -> Dict[str, Any]:
         return {
-            "sr_bits": pltpu.SMEM((_bits_rows(graph.n) * EBLOCK,), jnp.int32),
+            "sr_bits": pltpu.SMEM(
+                (SR_LEAD + _bits_rows(graph.n) * EBLOCK,), jnp.int32
+            ),
             "sr_qst": pltpu.SMEM((EBLOCK,), jnp.int32),
             "sr_qrd": pltpu.SMEM((EBLOCK,), jnp.int32),
             "sr_vt": pltpu.SMEM((SR_GROUP, EBLOCK), jnp.int32),
@@ -841,42 +864,82 @@ class SearchKernel(FrontierKernel):
             "sr_sem": pltpu.SemaphoreType.DMA((SR_GROUP + 1,)),
         }
 
+    def _slab(self, ref, *row) -> Callable:
+        """``f(e, k=0)``: entry ``e + k`` of the row, ``k`` static, read
+        through a view of the row taken here, once a slot, so an entry is
+        one add away. (Of ``ref[buf, b, e]`` the compiler, which is not
+        told ``e < 128``, makes ``((buf * width + b) + (e >> 7)) * 128 +
+        (e & 127)``, five operations an entry.)"""
+        view = ref.at[row] if row else ref
+        return lambda e, k=0: view[e + k]
+
     def relax(self, kctx, u, w, carry) -> None:
         bits = kctx.scratch["sr_bits"]
-        word = bits[u >> 5]
+        at = SR_LEAD + (u >> 5)
+        word = bits[at]
         bit = jnp.int32(1) << (u & 31)
 
         @pl.when((word & bit) == 0)
         def _():
-            bits[u >> 5] = word | bit
+            bits[at] = word | bit
             _queue_append(kctx, u, carry)
 
     def _relax_block(self, kctx, eslab, wslab, carry, cnt) -> None:
         """The shared loop's spelling for a filter: nearly every entry's
-        far end is reached already, so the entries are TESTED
-        ``SR_TEST`` at a time as straight-line code and only a group with
-        an unreached one takes the branch into the per-entry relax (which
-        tests again: two entries of a group may name one vertex). A
-        block's rows are padded with -1 past ``cnt``."""
-        kctx.ivalues[V_EDGES] = kctx.ivalues[V_EDGES] + cnt
+        far end is reached already, so the entries are TESTED ``SR_TEST``
+        at a time as straight-line integer code (a slab word, the filter
+        word it names, a shift, an AND into its sub-group's word) and one
+        branch is taken only by a group that holds an unreached one. Such
+        a group walks, in block order, those of its sub-groups of
+        ``SR_SUB`` whose own word says so through the per-entry relax
+        (which tests again: two entries of a group may name one vertex).
+        Padding needs no test of its own (``SR_LEAD``), so a group may
+        reach past ``cnt``."""
+        iv = kctx.ivalues
+        iv[V_EDGES] = iv[V_EDGES] + cnt
         bits = kctx.scratch["sr_bits"]
+        nsubs = SR_TEST // SR_SUB
 
-        def unreached(e):
-            u = jnp.maximum(eslab(e), 0)
-            return (e < cnt) & (((bits[u >> 5] >> (u & 31)) & 1) == 0)
+        def reached(e0, k):
+            u = eslab(e0, k)
+            return bits[SR_LEAD + (u >> 5)] >> (u & 31)  # bit 0 answers
 
         def group(g, _):
             e0 = g * SR_TEST
-            hit = functools.reduce(
-                jnp.logical_or, [unreached(e0 + k) for k in range(SR_TEST)]
-            )
+            subs = [
+                functools.reduce(
+                    jnp.bitwise_and,
+                    [reached(e0, k) for k in range(k0, k0 + SR_SUB)],
+                )
+                for k0 in range(0, SR_TEST, SR_SUB)
+            ]
 
-            @pl.when(hit)
+            @pl.when((functools.reduce(jnp.bitwise_and, subs) & 1) == 0)
             def _():
-                for k in range(SR_TEST):
-                    @pl.when(e0 + k < cnt)
-                    def _(k=k):
-                        self.relax(kctx, eslab(e0 + k), None, carry)
+                # Bit i of ``done``: sub-group i holds nothing unreached,
+                # or has been walked. The lowest clear bit is walked and
+                # set till none is clear, so the relax is traced once an
+                # entry of a sub-group, not once an entry of a group.
+                done = functools.reduce(
+                    jnp.bitwise_or,
+                    [(sub & 1) << i for i, sub in enumerate(subs)],
+                )
+
+                def walk(done):
+                    low = i = done & 1  # i: the trailing ones of ``done``
+                    for j in range(1, nsubs - 1):
+                        low = low & (done >> j)
+                        i = i + low
+                    iv[S_HITS] = iv[S_HITS] + 1
+                    for k in range(SR_SUB):
+                        self.relax(
+                            kctx, eslab(e0 + i * SR_SUB, k), None, carry
+                        )
+                    return done | (jnp.int32(1) << i)
+
+                jax.lax.while_loop(
+                    lambda done: done != (1 << nsubs) - 1, walk, done
+                )
 
             return 0
 
@@ -937,7 +1000,7 @@ def _make_kernel(graph_n: int, budget: int) -> Callable:
     """The maker of one graph size (module comment above). Its descriptor:
     ``[first, key]``; ``first`` is 1 on the host's seed, which clears the
     filter and enqueues the key, and 0 ever after."""
-    nwords = _bits_rows(graph_n) * EBLOCK
+    nwords = SR_LEAD + _bits_rows(graph_n) * EBLOCK
 
     def take(ctx, i) -> None:
         """Vertex ``i`` of the gathered group becomes the one in hand."""
@@ -986,12 +1049,12 @@ def _make_kernel(graph_n: int, budget: int) -> Callable:
         @pl.when(ctx.arg(0) != 0)
         def _():
             def clear(i, _):
-                bits[i] = 0
+                bits[i] = jnp.where(i < SR_LEAD, -1, 0)  # the lead stays set
                 return 0
 
             jax.lax.fori_loop(0, nwords, clear, 0)
             key = ctx.arg(1)
-            bits[key >> 5] = jnp.int32(1) << (key & 31)
+            bits[SR_LEAD + (key >> 5)] = jnp.int32(1) << (key & 31)
             ctx.scratch["sr_qst"][0] = key
             ctx.scratch["sr_qst"][1] = key
             iv[S_QTAIL] = 1
@@ -1670,6 +1733,7 @@ class GraphSearch:
             "batch_slots": tiers.get("batch_tasks", 0),
             "hbm_words_read": int(iv[S_HBM_RD]),
             "hbm_words_written": int(iv[S_HBM_WR]),
+            "hit_groups": int(iv[S_HITS]),
         }
         return parent, info
 

@@ -21,6 +21,8 @@ from hclib_tpu.device.frontier import (  # noqa: E402
     EBLOCK,
     INF,
     SR_SPARE,
+    SR_SUB,
+    SR_TEST,
     Graph,
     GraphSearch,
     host_bfs,
@@ -266,3 +268,262 @@ def test_the_search_build_passes_the_verifier_and_describes_itself():
     with pytest.raises(ValueError):
         make_frontier_megakernel(search_kernel(), g, width=4, capacity=32,
                                  interpret=True, priority_buckets=4)
+
+
+# ------------------------------------- the filter test's block shapes
+#
+# ISSUE 49: the search tests its entries 16 at a time and lets the filter
+# answer for a block's padding. One graph of small components, each a
+# block shape that loop must get right, searched from the component's own
+# key; what each search returns is held to the reference AND to what the
+# tree before ISSUE 49 (commit 60b1b73) returned on the same input, parent
+# by parent, so the order in which a block's entries are relaxed is pinned.
+
+SHAPE_N = 4096  # one filter row: vertex 4095 is the filter's last bit
+
+
+def _shape_graph():
+    """``(u, v, keys)``: the tuples and each case's search key."""
+    rng = np.random.default_rng(49)
+    pool = iter(rng.permutation(np.arange(1, SHAPE_N - 1)).tolist())
+    u, v, keys = [], [], {}
+
+    def take(k):
+        return [next(pool) for _ in range(k)]
+
+    def edge(a, b):
+        u.append(a)
+        v.append(b)
+
+    # A hub whose one block has ``cnt`` live entries, every one unreached
+    # when it is tested; its leaves in a path; and a second level hung on
+    # two leaves each, whose parent is the leaf the queue holds first.
+    for cnt in (1, 3, 4, 15, 16, 17, 127, 128):
+        hub, *leaves = take(cnt + 1)
+        for x in leaves:
+            edge(hub, x)
+        for a, b in zip(leaves, leaves[1:]):
+            edge(a, b)
+        for j, s in enumerate(take(min(cnt, 8))):
+            edge(leaves[(3 * j) % cnt], s)
+            edge(leaves[(3 * j + 1) % cnt], s)
+        keys[f"cnt_{cnt}"] = hub
+    # A block (x's) whose only unreached vertex is its last live entry,
+    # at index ``at``: the key and the others are reached a level before.
+    for at in (15, 16, 20):
+        *rest, z = sorted(take(at + 2))
+        key, x, *others = rest
+        for a in others:
+            edge(key, a)
+            edge(x, a)
+        edge(key, x)
+        edge(x, z)
+        keys[f"last_live_at_{at}"] = key
+    # Two entries of one sub-group name one unreached vertex, twice over.
+    key, t1, t2, w = take(4)
+    for t in (t1, t1, t2, t2):
+        edge(key, t)
+    edge(t2, w)
+    keys["twice_in_a_subgroup"] = key
+    # One unreached vertex named at entries 3 and 4: the second sub-group
+    # goes into relax for nothing.
+    key, *abct = take(5)
+    for t in sorted(abct) + [max(abct)]:
+        edge(key, t)
+    keys["twice_across_subgroups"] = key
+    keys["no_edge"] = take(1)[0]
+    # Vertex 0 stays unreached while a block's padding is tested ...
+    key, a = take(2)
+    edge(key, a)
+    keys["padding_beside_vertex_0"] = key
+    # ... and is reached, with the filter's last bit, beside padding.
+    key = take(1)[0]
+    edge(key, 0)
+    edge(key, SHAPE_N - 1)
+    edge(0, SHAPE_N - 1)
+    keys["vertex_0_and_the_last_bit"] = key
+    return np.array(u, np.int32), np.array(v, np.int32), keys
+
+
+SHAPE_U, SHAPE_V, SHAPE_KEYS = _shape_graph()
+
+# What GraphSearch(width=4, capacity=32).bfs(key) returned at commit
+# 60b1b73, the tree before ISSUE 49, case by case: the books, and the
+# parent array as the reached vertices, ascending, beside their parents.
+BEFORE_49 = {
+    "cnt_1": dict(
+        edges=6, expands=3, reached=3, level_starts=[0, 1, 2],
+        vertices=[2109, 3385, 3467], parents=[2109, 2109, 3385]
+    ),
+    "cnt_3": dict(
+        edges=22, expands=7, reached=7, level_starts=[0, 1, 4],
+        vertices=[1009, 1311, 1939, 2668, 3224, 3383, 3657],
+        parents=[2668, 2668, 3657, 2668, 3657, 3657, 2668]
+    ),
+    "cnt_4": dict(
+        edges=30, expands=9, reached=9, level_starts=[0, 1, 5],
+        vertices=[358, 1550, 1578, 1595, 1833, 2732, 3468, 3585, 4021],
+        parents=[1595, 3585, 1595, 1595, 2732, 1595, 2732, 1595, 3585]
+    ),
+    "cnt_15": dict(
+        edges=90, expands=24, reached=24, level_starts=[0, 1, 16],
+        vertices=[161, 379, 413, 661, 1438, 1747, 1878, 1907, 1982,
+        2057, 2116, 2152, 2444, 2492, 2515, 2609, 2632, 2642, 2666,
+        3222, 3499, 3756, 3775, 3996], parents=[3222, 3499, 2632, 2632,
+        2632, 2492, 2632, 2632, 2632, 2632, 3499, 2632, 2492, 2632,
+        2666, 3775, 2632, 2632, 2632, 2632, 2632, 3775, 2632, 2632]
+    ),
+    "cnt_16": dict(
+        edges=94, expands=25, reached=25, level_starts=[0, 1, 17],
+        vertices=[24, 158, 308, 641, 742, 760, 856, 994, 1109, 1300,
+        1369, 1546, 1609, 1692, 2004, 2305, 2690, 3148, 3162, 3283,
+        3373, 3432, 3693, 3725, 4014], parents=[2305, 2305, 856, 2305,
+        2305, 2305, 2305, 3373, 2690, 2305, 2305, 3725, 3432, 2004,
+        2305, 2305, 2305, 2305, 2305, 158, 2305, 2305, 2305, 2305, 3693]
+    ),
+    "cnt_17": dict(
+        edges=98, expands=26, reached=26, level_starts=[0, 1, 18],
+        vertices=[12, 275, 302, 307, 531, 800, 1104, 1501, 1656, 1671,
+        1934, 2134, 2325, 2357, 2379, 2510, 2667, 2729, 3030, 3249,
+        3340, 3371, 3596, 3898, 3913, 3971], parents=[1656, 1104, 1656,
+        1656, 1656, 3030, 1656, 2379, 1656, 1656, 3249, 1656, 1656,
+        1656, 1656, 2325, 1656, 3971, 1656, 1656, 1656, 1656, 1656,
+        2667, 2357, 1656]
+    ),
+    "cnt_127": dict(
+        edges=538, expands=136, reached=136, level_starts=[0, 1, 128],
+        vertices=[16, 33, 44, 53, 65, 86, 90, 91, 223, 230, 284, 315,
+        325, 352, 444, 479, 493, 501, 524, 548, 565, 593, 616, 683, 697,
+        703, 780, 797, 816, 855, 951, 974, 1000, 1024, 1115, 1122, 1138,
+        1143, 1163, 1168, 1171, 1203, 1208, 1226, 1233, 1256, 1274,
+        1306, 1308, 1349, 1368, 1413, 1456, 1496, 1500, 1528, 1580,
+        1645, 1649, 1698, 1706, 1720, 1798, 1819, 1837, 1917, 1927,
+        1986, 2044, 2051, 2090, 2093, 2095, 2105, 2140, 2178, 2242,
+        2264, 2291, 2294, 2344, 2427, 2453, 2469, 2545, 2553, 2597,
+        2618, 2627, 2724, 2731, 2748, 2772, 2807, 2908, 2921, 2968,
+        2993, 3000, 3004, 3014, 3018, 3073, 3105, 3110, 3147, 3173,
+        3177, 3186, 3240, 3297, 3348, 3388, 3400, 3426, 3441, 3472,
+        3498, 3511, 3532, 3558, 3583, 3680, 3795, 3812, 3820, 3865,
+        3912, 3955, 3983, 3993, 4023, 4067, 4082, 4085, 4093],
+        parents=[1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 2968, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 2095, 2242, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 2090, 1163, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 855, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1456, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 3955, 1024, 1024, 1024, 1024, 1024, 1024, 1024,
+        1024, 1024, 1024, 1024, 1024, 1024]
+    ),
+    "cnt_128": dict(
+        edges=542, expands=137, reached=137, level_starts=[0, 1, 129],
+        vertices=[11, 13, 15, 37, 51, 69, 71, 100, 101, 174, 186, 249,
+        292, 339, 348, 362, 384, 433, 455, 471, 505, 559, 592, 642, 658,
+        698, 746, 798, 901, 907, 944, 946, 983, 1073, 1091, 1108, 1117,
+        1119, 1139, 1140, 1142, 1231, 1292, 1324, 1327, 1338, 1359,
+        1402, 1412, 1439, 1457, 1466, 1470, 1479, 1492, 1506, 1523,
+        1593, 1607, 1651, 1695, 1727, 1739, 1748, 1755, 1855, 1857,
+        1909, 1911, 1912, 1985, 1991, 1999, 2031, 2037, 2039, 2071,
+        2084, 2164, 2185, 2213, 2353, 2367, 2373, 2389, 2447, 2448,
+        2462, 2486, 2518, 2559, 2670, 2722, 2774, 2859, 2872, 2912,
+        2926, 2971, 2977, 2998, 3017, 3022, 3034, 3096, 3135, 3151,
+        3196, 3210, 3225, 3259, 3305, 3365, 3372, 3412, 3422, 3429,
+        3447, 3460, 3480, 3494, 3544, 3573, 3649, 3742, 3767, 3827,
+        3846, 3871, 3911, 3934, 3964, 3976, 3988, 3998, 4034, 4094],
+        parents=[642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 505,
+        1402, 642, 642, 642, 642, 642, 1695, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 37, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642,
+        1142, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 2670, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642, 642,
+        642, 642, 642, 642, 642, 642, 1231, 642, 642, 642, 642, 71]
+    ),
+    "last_live_at_15": dict(
+        edges=60, expands=17, reached=17, level_starts=[0, 1, 16],
+        vertices=[19, 297, 567, 707, 1759, 1844, 2042, 2197, 2259, 2369,
+        2771, 2867, 2920, 3430, 3608, 3868, 3968], parents=[19, 19, 19,
+        19, 19, 19, 19, 19, 19, 19, 19, 19, 19, 19, 19, 19, 297]
+    ),
+    "last_live_at_16": dict(
+        edges=64, expands=18, reached=18, level_starts=[0, 1, 17],
+        vertices=[99, 146, 180, 573, 758, 792, 985, 990, 1085, 1195,
+        1223, 1366, 1530, 2991, 3144, 3661, 3744, 4022], parents=[99,
+        99, 99, 99, 99, 99, 99, 99, 99, 99, 99, 99, 99, 99, 99, 99, 99,
+        146]
+    ),
+    "last_live_at_20": dict(
+        edges=80, expands=22, reached=22, level_starts=[0, 1, 21],
+        vertices=[26, 228, 600, 636, 866, 1175, 1813, 2340, 2542, 2677,
+        2817, 2878, 2931, 3221, 3226, 3437, 3457, 3547, 3556, 3670,
+        3864, 3915], parents=[26, 26, 26, 26, 26, 26, 26, 26, 26, 26,
+        26, 26, 26, 26, 26, 26, 26, 26, 26, 26, 26, 228]
+    ),
+    "twice_in_a_subgroup": dict(
+        edges=10, expands=4, reached=4, level_starts=[0, 1, 3],
+        vertices=[453, 1395, 2906, 3831], parents=[453, 453, 3831, 453]
+    ),
+    "twice_across_subgroups": dict(
+        edges=10, expands=5, reached=5, level_starts=[0, 1],
+        vertices=[672, 1684, 2142, 2769, 3316], parents=[672, 672, 672,
+        672, 672]
+    ),
+    "no_edge": dict(
+        edges=0, expands=0, reached=1, level_starts=[0],
+        vertices=[3071], parents=[3071]
+    ),
+    "padding_beside_vertex_0": dict(
+        edges=2, expands=2, reached=2, level_starts=[0, 1],
+        vertices=[934, 1018], parents=[934, 934]
+    ),
+    "vertex_0_and_the_last_bit": dict(
+        edges=6, expands=3, reached=3, level_starts=[0, 1], vertices=[0,
+        3496, 4095], parents=[3496, 3496, 3496]
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def shapes():
+    g = Graph.undirected(SHAPE_N, SHAPE_U, SHAPE_V)
+    return GraphSearch(g, width=4, capacity=32, interpret=True)
+
+
+@pytest.mark.parametrize("case", sorted(SHAPE_KEYS))
+def test_a_block_shape_against_the_reference_and_the_tree_before(shapes, case):
+    key, g = SHAPE_KEYS[case], shapes.graph
+    parent, info = shapes.bfs(key)
+    held = ref.search_and_validate(SHAPE_N, SHAPE_U, SHAPE_V, key, parent)
+    assert not any(held[r] for r in ref.RULES), held
+    assert held["levels_differ"] == 0
+    depth, unrooted = ref.levels_of_tree(parent, key)
+    want = host_bfs(g, key).astype(np.int64)
+    want[want == INF] = -1
+    assert unrooted == 0 and np.array_equal(depth, want)
+    assert info["pending"] == 0 and not info["overflow"]
+    # Vertex 0 is reached by the one case that names it: padding, which
+    # the test before ISSUE 49 read as vertex 0 and masked, never is it.
+    assert (parent[0] >= 0) == (case == "vertex_0_and_the_last_bit")
+    books, before = info["search"], BEFORE_49[case]
+    for k in ("edges", "expands", "reached", "level_starts"):
+        assert books[k] == before[k], k
+    reached = np.flatnonzero(parent >= 0)
+    assert reached.tolist() == before["vertices"]
+    assert parent[reached].tolist() == before["parents"]
+    # Sub-groups that went into relax: every vertex but the key was
+    # appended inside one, at most SR_SUB to each; and none goes in that
+    # was not tested, SR_TEST entries to a group, a vertex's last rounded
+    # up (a block is a whole number of groups).
+    groups = -(-g.deg[reached].astype(np.int64) // SR_TEST)
+    tested = int(groups.sum()) * (SR_TEST // SR_SUB)
+    hits = books["hit_groups"]
+    assert -(-(books["reached"] - 1) // SR_SUB) <= hits <= tested
+    assert (hits > 0) == (books["reached"] > 1)

@@ -10,14 +10,27 @@ the straight-line length of the code its path runs (``PERF.md`` section 6,
 PR 41 / 45 / 46): read the listing before and after a change to the
 scheduler, and count its paths with ``tools/listing_paths.py``.
 
-    python tools/kernel_listing.py <outdir> [--tree DIR] [--kernel fib|forest]
+    python tools/kernel_listing.py <outdir> [--tree DIR]
+                                   [--kernel fib|forest|search]
                                    [--capacity N] [--if-conversion]
 
 ``--tree`` is the checkout to compile (default: this one; give a copy of
 the parent commit to compare). ``fib`` is ``fib30-scalar``'s kernel
 (``make_fib_megakernel(768)`` through ``Megakernel._build_exec``);
 ``forest`` is ``forest-steal-4chip``'s resident mesh kernel (capacity 640,
-four described chips), which inlines the same scheduler core.
+four described chips), which inlines the same scheduler core; ``search``
+is ``g500-bfs-search``'s build as ``tests/test_chip_compile.py:_search``
+compiles it (Graph500's scale 22 by the shapes alone, width 8, capacity
+128, fuel ``1 << 30``, the adjacency, vertex table and queue on the
+device; ten seconds). In its listing the loop of
+``SearchKernel._relax_block`` stands once a batch slot of each copy of the
+batch body (16 times at width 8). It is the ``LB:`` whose first bundle
+shifts the loop's counter by the group's log2 (``sshll.u32 ..., 4``: ``e0
+= g * SR_TEST``; the trip count, ``cnt + 15`` shifted right by 4, is made
+in the bundles in front of it). A group that finds nothing runs from there
+to the first ``sbr.rel`` and its four delay slots, then from that branch's
+target (the ``PF:`` that reloads the counter) to the back-branch and its
+four: 77 + 9 bundles for 16 entries (PR 49; 55 + 7 for 4 before it).
 The child's output goes to ``<outdir>/compile.log``; with
 ``--if-conversion`` the compiler's if-conversion pass logs into it which
 ``pl.when`` / ``lax.cond`` regions it predicated and which it kept as
@@ -39,23 +52,18 @@ from typing import Dict, List
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
-# The trace names of the two kernels (PERF.md section 3): the jit round a
-# Megakernel's pallas_call is named tpu_custom_call, the mesh kernel
-# resident_mesh.
-KERNELS = {"fib": "tpu_custom_call", "forest": "resident_mesh"}
-
-
 def find_listing(outdir: str, kernel: str) -> str:
     """The final-bundle listing of ``kernel`` under ``outdir`` (the
     schedule analysis beside it has the same suffix and is not it)."""
+    name = KERNELS[kernel][0]
     found = [
         f for f in glob.glob(os.path.join(outdir, "*-final_bundles.txt"))
-        if KERNELS[kernel] in os.path.basename(f)
+        if name in os.path.basename(f)
         and "schedule-analysis" not in os.path.basename(f)
     ]
     if not found:
         raise FileNotFoundError(
-            f"no *{KERNELS[kernel]}*-final_bundles.txt under {outdir}: "
+            f"no *{name}*-final_bundles.txt under {outdir}: "
             f"the child did not reach the compiler (read {outdir}/compile.log)"
         )
     return max(found, key=os.path.getsize)
@@ -84,7 +92,11 @@ def if_conversion_summary(log_path: str) -> Dict[str, List[int]]:
     }
 
 
-def _compile_fib(capacity: int) -> None:
+def _compile_mk(mk, fuel: int, on_device=()) -> None:
+    """Compile ``Megakernel.run``'s program for one described v5e chip,
+    laid out as ``run`` lays it out (``tests/test_chip_compile.py:
+    _compile_mk``): the small int32 buffers ride the slab, those named in
+    ``on_device`` are handed in as ``jax.Array``s."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -92,21 +104,20 @@ def _compile_fib(capacity: int) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from hclib_tpu.device.megakernel import SLAB_RIDE_BYTES
-    from hclib_tpu.device.workloads import make_fib_megakernel
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2"
     )
     sh = SingleDeviceSharding(topo.devices[0])
-    mk = make_fib_megakernel(capacity, interpret=False)
     lay = mk._exec_layout(
         [
             "data:" + k for k, s in mk.data_specs.items()
             if s.dtype == jnp.int32
             and 4 * np.prod(s.shape) < SLAB_RIDE_BYTES
+            and k not in on_device
         ],
-        [],
+        ["data:" + k for k in on_device],
     )
     words = sum(int(np.prod(s)) for s in lay.up.values())
     args = [jax.ShapeDtypeStruct((words,), jnp.int32, sharding=sh)]
@@ -117,7 +128,13 @@ def _compile_fib(capacity: int) -> None:
         )
         for n in lay.alone
     ]
-    mk._build_exec(1 << 22, False, lay).lower(*args).compile()
+    mk._build_exec(fuel, False, lay).lower(*args).compile()
+
+
+def _compile_fib(capacity: int) -> None:
+    from hclib_tpu.device.workloads import make_fib_megakernel
+
+    _compile_mk(make_fib_megakernel(capacity, interpret=False), 1 << 22)
 
 
 def _compile_forest(capacity: int) -> None:
@@ -162,20 +179,48 @@ def _compile_forest(capacity: int) -> None:
     rk._build(256, 1 << 14, None).lower(*shapes).compile()
 
 
+def _compile_search(capacity: int) -> None:
+    import types
+
+    from hclib_tpu.device.frontier import (
+        make_frontier_megakernel, search_kernel,
+    )
+
+    g = types.SimpleNamespace(n=1 << 22, nblocks=3_200_000)
+    _compile_mk(
+        make_frontier_megakernel(
+            search_kernel(), g, width=8, capacity=capacity, interpret=False,
+        ),
+        1 << 30, on_device=("indices", "vtab", "queue"),
+    )
+
+
+# kernel -> (its name in the trace and in the dump's file names (PERF.md
+# section 3: the jit round a Megakernel's pallas_call is named
+# tpu_custom_call, the mesh kernel resident_mesh), its compile, the
+# cell's table rows)
+KERNELS = {
+    "fib": ("tpu_custom_call", _compile_fib, 768),
+    "forest": ("resident_mesh", _compile_forest, 640),
+    "search": ("tpu_custom_call", _compile_search, 128),
+}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("outdir")
     ap.add_argument("--tree", default=os.path.dirname(_HERE))
     ap.add_argument("--kernel", choices=sorted(KERNELS), default="fib")
     ap.add_argument("--capacity", type=int, default=None,
-                    help="table rows (default: the cell's, 768 / 640)")
+                    help="table rows (default: the cell's, 768 / 640 / 128)")
     ap.add_argument("--if-conversion", action="store_true")
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     a = ap.parse_args(argv)
-    capacity = a.capacity or {"fib": 768, "forest": 640}[a.kernel]
+    _, compile_kernel, cell_capacity = KERNELS[a.kernel]
+    capacity = a.capacity or cell_capacity
     if a.child:
         sys.path.insert(0, a.tree)
-        {"fib": _compile_fib, "forest": _compile_forest}[a.kernel](capacity)
+        compile_kernel(capacity)
         return 0
     outdir = os.path.abspath(a.outdir)
     os.makedirs(outdir, exist_ok=True)
