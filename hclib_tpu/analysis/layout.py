@@ -100,7 +100,9 @@ LAYOUT = {
     "TS_THROTTLED": (5, ("hclib_tpu.device.tenants",)),
     "TS_QUARANTINED": (6, ("hclib_tpu.device.tenants",)),
     # batch-tier counter/state rows (device/megakernel.py)
-    "TS_WORDS": (14, ("hclib_tpu.device.megakernel",)),
+    "TS_WORDS": (15, ("hclib_tpu.device.megakernel",)),
+    # rows spawned straight onto a lane (spawn-time routing)
+    "TS_DIRECT": (14, ("hclib_tpu.device.megakernel",)),
     # re-armed dispatches (ctx.become) and retirements that took
     # retire()'s slow region: the two tier words every build writes, and
     # the re-arm scratch's three fixed words behind them.
@@ -245,7 +247,8 @@ def check_layout(report: Optional[AnalysisReport] = None,
         )
     if not (m.LS_AGE < m.LS_WORDS
             and m.TS_MAX_AGE < m.TS_BUCKET_FIRES
-            < m.TS_INVERSIONS < m.TS_BECAME < m.TS_WALKED < m.TS_WORDS):
+            < m.TS_INVERSIONS < m.TS_BECAME < m.TS_WALKED < m.TS_DIRECT
+            < m.TS_WORDS):
         report.add(
             "layout", ERROR, None,
             "lane/tier state words exceed their declared row widths "

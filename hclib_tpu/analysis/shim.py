@@ -553,7 +553,7 @@ def _make_recording_contexts():
                 ctx.ivalues, ctx.data, ctx.scratch, ctx._capacity,
                 ctx._free, ctx._num_values, ctx._vfree,
                 ctx._uses_row_values, ctx._tracks_home,
-                rearm=ctx._rearm, slot=ctx._slot,
+                rearm=ctx._rearm, slot=ctx._slot, direct=ctx._direct,
             )
             rec._shim_trace = self._shim_trace
             rec._shim_slot = int(s)

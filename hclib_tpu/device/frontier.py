@@ -55,12 +55,22 @@ ranks combine by sum), and it approximates the float PageRank series
 
 **Firing policy.** Frontier expansion is exactly the chained-spawner
 shape the lane-policy watch item predicted: every batch deposits a
-fan-out of same-kind children on the ready ring, so under pure
-ring-drain-first firing the lane sits starved for the whole routing
-drain. The frontier megakernels therefore default the ISSUE 10 age
-trigger ON (``lane_max_age = 4 * width``): a lane that has held entries
-for that many rounds jumps the ring and fires - full batches mid-drain
-once >= width entries accumulated - keeping ``lane_partial_age`` and the
+fan-out of same-kind children. With the prefetch on (the default) the
+EXPAND lane pops FIFO off one ring, and a child that ``spawn`` makes on
+the device - by a relax inside a batch slot, by the search kind's maker -
+is pushed STRAIGHT onto that lane (``KernelContext.spawn``'s spawn-time
+routing, ISSUE 50; ``info['tiers']['direct']``): the ready ring never
+holds it, no scheduler round is spent routing it, and the lane fires at
+the next round. What still reaches the lane through the ring, a routing
+round each (``tiers['routed']``): the host's seeds, rows stolen or
+restored, lane entries an exit spilled, and every child of a
+``prefetch=False`` or ``priority_buckets`` build (a LIFO round rewrites
+its lane's tail, a bucket is chosen at the routing pop). For those the
+lane sits starved for the whole routing drain under pure ring-drain-first
+firing, so the frontier megakernels default the ISSUE 10 age trigger ON
+(``lane_max_age = 4 * width``): a lane that has held entries for that
+many rounds jumps the ring and fires - full batches mid-drain once
+>= width entries accumulated - keeping ``lane_partial_age`` and the
 device-side ``max_starved_age`` gauge bounded (the frontier-batch perf
 guard pins both).
 
@@ -790,7 +800,10 @@ def pagerank_kernel(reps: int = 64,
 # no frontier at all: a MAKE task (scalar tier) reads the queue, gathers
 # ``SR_GROUP`` vertices' table rows at a time, and makes EXPAND
 # descriptors while the table has room (``capacity - SR_SPARE`` of them),
-# each naming the maker as its successor; then it re-arms itself
+# each naming the maker as its successor and pushed straight onto the
+# EXPAND lane, which runs them in the order they were made (so the tree is
+# the queue-order tree: a vertex's parent is the first of the queue to
+# name it); then it re-arms itself
 # (``ctx.become``) on as many predecessors as it made, and runs again
 # when the last of them has retired. It never crosses the end of a level
 # with an EXPAND of that level outstanding, so the order is exactly

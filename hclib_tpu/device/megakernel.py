@@ -342,7 +342,10 @@ TS_WALKED = 13        # retirements that walked more than F_SUCC0 (a second
                       # inline successor or a CSR list: retire()'s slow
                       # region); written by every build. The fast path's
                       # share is 1 - walked / (executed - became)
-TS_WORDS = 14
+TS_DIRECT = 14        # rows a device-side spawn pushed straight onto their
+                      # kind's lane (``_DirectLanes``): direct + routed is
+                      # what routed alone was
+TS_WORDS = 15
 
 # Re-arm words (SMEM scratch of RA_MARK + widest-batch words, the third of
 # ``core_scratch``): what ``KernelContext.become`` leaves for the
@@ -414,6 +417,29 @@ C_ROUNDS = 7
 C_VBASE = 7
 
 
+def _lane_push(lanes, lstate, li, t) -> None:
+    """Append row ``t`` to lane-state row ``li``'s ring."""
+    tail = lstate[li, LS_TAIL]
+    lanes[li, ring_slot(tail, lanes.shape[1])] = t
+    lstate[li, LS_TAIL] = tail + 1
+
+
+class _DirectLanes(NamedTuple):
+    """The lane scratch of one scheduler core and the kinds a device-side
+    ``spawn`` may push straight onto it: ``rows`` maps the F_FN of every
+    batch-routed kind whose lane pops FIFO off a single ring
+    (``spec.prefetch``, no priority buckets) to its lane-state row. Such a
+    round moves only ``LS_HEAD``, so a push at the tail is safe at any
+    time, from inside the kind's own batch body too; a LIFO round rewrites
+    ``LS_TAIL`` after its body and a bucketed build chooses the ring at the
+    routing pop, so those kinds keep the ready ring."""
+
+    lanes: Any
+    lstate: Any
+    tstats: Any
+    rows: Dict[int, int]
+
+
 class _Rearm:
     """One scheduler core's re-arm words (``RA_*``) and, while the core is
     traced, whether a handler traced so far calls ``become``: each
@@ -432,7 +458,7 @@ class KernelContext:
     def __init__(self, idx, tasks, succ, ready, counts, ivalues, data,
                  scratch, capacity, free, num_values, vfree,
                  uses_row_values=False, tracks_home=False,
-                 rearm=None, slot=0):
+                 rearm=None, slot=0, direct=None):
         self.idx = idx  # this task's descriptor index
         self._tasks = tasks
         self._succ = succ
@@ -460,6 +486,9 @@ class KernelContext:
         # ``BatchContext.slot_ctx`` (``become``).
         self._rearm = rearm
         self._slot = slot
+        # The core's ``_DirectLanes`` (None where no kind qualifies):
+        # ``spawn`` of a kind it names skips the ready ring.
+        self._direct = direct
 
     # -- descriptor access --
 
@@ -689,6 +718,15 @@ class KernelContext:
     ):
         """Allocate + enqueue a new task descriptor; returns its index.
 
+        A ready child lands on the ready ring, from where the scheduler
+        pops it, or - spawn-time routing - straight on its kind's batch
+        lane where ``fn`` is a Python int that the core's ``_DirectLanes``
+        names (a FIFO single-ring batch kind): the scheduler round that
+        would pop the row only to read its ``F_FN`` and push the lane is
+        decided here, at trace time (90 bundles an EXPAND of a search on
+        the v5e, PR 50). A traced ``fn`` keeps the ring; so does a child
+        with ``dep_count > 0``, which ``retire()`` releases onto it.
+
         On table overflow the task is dropped and counts[C_OVERFLOW] is set
         (the reference asserts on deque overflow, src/hclib-runtime.c:520-524;
         here the host checks the flag after the kernel returns).
@@ -764,9 +802,18 @@ class KernelContext:
                 # rehydration.)
                 self._tasks[a_clamped, F_HOME] = jnp.int32(NO_TASK)
 
+        d = self._direct
+        li = None
+        if d is not None and isinstance(fn, (int, np.integer)):
+            li = d.rows.get(int(fn))
+
         @pl.when(ok & (jnp.int32(dep_count) == 0))
         def _():
-            self.push_ready(a_clamped)
+            if li is None:
+                self.push_ready(a_clamped)
+            else:
+                _lane_push(d.lanes, d.lstate, li, a_clamped)
+                d.tstats[TS_DIRECT] = d.tstats[TS_DIRECT] + 1
 
         @pl.when(jnp.logical_not(ok))
         def _():
@@ -779,10 +826,14 @@ class BatchSpec:
     """Describes the batched-dispatch form of one kernel-table entry.
 
     A kind routed through a BatchSpec is never dispatched through the
-    ``lax.switch`` table: the scheduler diverts its ready descriptors into a
-    per-kind SMEM lane and, each batch round, pops up to ``width`` of them
-    and invokes ``body(ctx: BatchContext)`` ONCE for the whole group - one
-    tiled kernel body instead of ``width`` sequential switch dispatches.
+    ``lax.switch`` table: its ready descriptors wait in a per-kind SMEM
+    lane - diverted there off the ready ring at the pop, or, where the lane
+    pops FIFO off a single ring (``prefetch=True``, no priority buckets),
+    pushed there by the device-side ``spawn`` that made them
+    (``KernelContext.spawn``'s spawn-time routing) - and, each batch round,
+    the scheduler pops up to ``width`` of them and invokes
+    ``body(ctx: BatchContext)`` ONCE for the whole group - one tiled
+    kernel body instead of ``width`` sequential switch dispatches.
     Ready descriptors of one kind are mutually independent by construction
     (neither's completion has run, so neither can be the other's
     predecessor), which is what makes same-kind group execution safe for
@@ -795,7 +846,10 @@ class BatchSpec:
     oldest entries stay cold for the multi-device steal exchanges.
     ``prefetch=True`` switches the lane to FIFO pops, which the prefetch
     pipeline requires (see below); the static tile DAGs that use prefetch
-    are order-insensitive.
+    are order-insensitive. A FIFO round moves only the lane's head, so
+    rows may join at the tail at any time: such a kind's children, spawned
+    under a constant kind with no predecessor to wait for, skip the ring
+    and run in the order they were spawned.
 
     ``priority`` opts the kind into the priority-bucket tier (armed only
     when the megakernel is built with ``priority_buckets=B``): a callable
@@ -948,7 +1002,7 @@ class BatchContext:
             self.idx(s), k._tasks, k._succ, k._ready, k._counts, k.ivalues,
             k.data, k.scratch, k._capacity, k._free, k._num_values,
             k._vfree, k._uses_row_values, k._tracks_home,
-            rearm=k._rearm, slot=s,
+            rearm=k._rearm, slot=s, direct=k._direct,
         )
         if self._ctx_hook is not None:
             self._ctx_hook(ctx)
@@ -1590,8 +1644,10 @@ class Megakernel:
                     for w in range(LS_WORDS):
                         lstate[li, w] = 0
             if tstats is not None:
-                # The tier's output window - zeroed per entry.
-                for w in range(TS_WORDS):
+                # The tier's output window - zeroed per entry. TS_DIRECT,
+                # the last word, is the batch tier's alone: a build
+                # without lanes neither writes nor reads it.
+                for w in range(TS_WORDS if use_batch else TS_DIRECT):
                     tstats[w] = 0
             for i in range(8):
                 counts[i] = counts_in[i]
@@ -1658,6 +1714,17 @@ class Megakernel:
             counts[C_TAIL] = tail + 1
 
         ra = _Rearm(rearm)
+        # Spawn-time routing (``KernelContext.spawn``): the kinds a spawn
+        # may push straight onto their lane. None where none qualifies, and
+        # such a build traces what it always traced.
+        direct = None
+        if use_batch and nbk == 1:
+            fifo_rows = {
+                fid: li for li, (fid, spec) in enumerate(self.batch_specs)
+                if spec.prefetch
+            }
+            if fifo_rows:
+                direct = _DirectLanes(lanes, lstate, tstats, fifo_rows)
 
         def complete(idx, slot: int = 0) -> None:
             """Decrement successors' dep counters; push newly-ready tasks
@@ -1743,6 +1810,7 @@ class Megakernel:
                 idx, tasks, succ, ready, counts, ivalues, data, scratch,
                 capacity, free, num_values, vfree,
                 self.uses_row_values, self.tracks_home, rearm=ra,
+                direct=direct,
             )
             if ctx_hook is not None:
                 ctx_hook(ctx)
@@ -1750,16 +1818,12 @@ class Megakernel:
             jax.lax.switch(tasks[idx, F_FN], branches)
             complete(idx)
 
-        def _lane_push(li, t) -> None:
-            tail = lstate[li, LS_TAIL]
-            lanes[li, ring_slot(tail, ring)] = t
-            lstate[li, LS_TAIL] = tail + 1
-
         def _make_bctx(li, spec, head, take, pre, buf, nxt):
             kctx = KernelContext(
                 lanes[li, ring_slot(head, ring)], tasks, succ, ready, counts,
                 ivalues, data, scratch, capacity, free, num_values, vfree,
                 self.uses_row_values, self.tracks_home, rearm=ra,
+                direct=direct,
             )
             if ctx_hook is not None:
                 ctx_hook(kctx)
@@ -2164,9 +2228,10 @@ class Megakernel:
                     idx = ready[ring_slot(tail - 1, ring)]
                     counts[C_TAIL] = tail - 1
                     # Pop-time partitioning: batch-routed kinds divert into
-                    # their lane (one compare per routed kind) no matter
-                    # who pushed them - stage, spawn, install_descriptor,
-                    # and completion all funnel through the ring, so the
+                    # their lane (one compare per routed kind) whoever
+                    # pushed them - stage, install_descriptor, completion,
+                    # and every spawn that spawn-time routing does not
+                    # take (``direct``) funnel through the ring, so the
                     # ring stays the single persistent structure and the
                     # lanes never survive a kernel exit.
                     fn = tasks[idx, F_FN]
@@ -2190,9 +2255,12 @@ class Megakernel:
                                     ),
                                     0, nbk - 1,
                                 ).astype(jnp.int32)
-                                _lane_push(jnp.int32(li * nbk) + bk, idx)
+                                _lane_push(
+                                    lanes, lstate,
+                                    jnp.int32(li * nbk) + bk, idx,
+                                )
                             else:
-                                _lane_push(li * nbk, idx)
+                                _lane_push(lanes, lstate, li * nbk, idx)
 
                         routed = routed | hit
 
@@ -2703,6 +2771,9 @@ class Megakernel:
             "full_rounds": int(t[TS_FULL_ROUNDS]),
             "scalar_tasks": int(t[TS_SCALAR_ROUNDS]),
             "routed": int(t[TS_ROUTED]),
+            # Rows a device-side spawn pushed straight onto their lane
+            # (spawn-time routing): direct + routed reached a lane.
+            "direct": int(t[TS_DIRECT]),
             "prefetch_hits": int(t[TS_PREFETCH]),
             "spilled": int(t[TS_SPILLED]),
             # Dispatches that ended re-armed (KernelContext.become), on

@@ -63,6 +63,7 @@ def test_lane_partitioning_results_and_counters():
     # Every 'double' went through the batch tier, every 'neg' scalar.
     assert t["batch_tasks"] == 11
     assert t["routed"] == 11
+    assert t["direct"] == 0  # host-built rows: every one through the ring
     assert t["scalar_tasks"] == 3
     assert t["spilled"] == 0
     assert 0 < t["batch_occupancy"] <= 1.0

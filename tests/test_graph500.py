@@ -169,6 +169,11 @@ def test_search_from_every_key_against_the_reference(search, i):
     # component examined once, every reached vertex expanded once.
     assert books["edges"] == 2 * held["component_edges"]
     assert books["expands"] == search.blocks_of(parent)
+    # Every EXPAND went straight to its lane and ran in the order it was
+    # made (ISSUE 50): the tree is the queue-order tree.
+    assert info["tiers"]["direct"] == books["expands"]
+    assert info["tiers"]["routed"] == 0
+    assert np.array_equal(parent, _queue_order_tree(search.graph, key))
     assert books["live_rows_max"] < books["capacity"] == 32
     starts = books["level_starts"]
     assert starts[0] == 0 and starts == sorted(starts)
@@ -278,6 +283,15 @@ def test_the_search_build_passes_the_verifier_and_describes_itself():
 # key; what each search returns is held to the reference AND to what the
 # tree before ISSUE 49 (commit 60b1b73) returned on the same input, parent
 # by parent, so the order in which a block's entries are relaxed is pinned.
+#
+# ISSUE 50: the maker's EXPANDs go straight to their lane, which pops
+# FIFO, so they run in the order they were made (the ring, popped newest
+# first, reversed each maker call's). The tree is now THE queue-order tree
+# (``_queue_order_tree``: a vertex's parent is the first vertex of the
+# queue that names it, a level's vertices queued in the order the blocks
+# name them), which pins the same order with no recorded table; the
+# recorded parents still hold where a level has one parent to offer, and
+# differ in the cases ``REORDERED_BY_50`` names.
 
 SHAPE_N = 4096  # one filter row: vertex 4095 is the filter's last bit
 
@@ -491,6 +505,27 @@ BEFORE_49 = {
 }
 
 
+REORDERED_BY_50 = {
+    "cnt_3", "cnt_4", "cnt_15", "cnt_16", "cnt_17", "cnt_127", "cnt_128",
+}
+
+
+def _queue_order_tree(g, key):
+    """The parent array of the plain search that takes vertices off a
+    queue in order and walks each one's adjacency in block order: the
+    first to name a vertex is its parent."""
+    adj = g.adj
+    parent = np.full(g.n, -1, np.int32)
+    parent[key] = key
+    queue = [key]
+    for v in queue:
+        for u in adj[v].tolist():
+            if parent[u] < 0:
+                parent[u] = v
+                queue.append(u)
+    return parent
+
+
 @pytest.fixture(scope="module")
 def shapes():
     g = Graph.undirected(SHAPE_N, SHAPE_U, SHAPE_V)
@@ -517,7 +552,9 @@ def test_a_block_shape_against_the_reference_and_the_tree_before(shapes, case):
         assert books[k] == before[k], k
     reached = np.flatnonzero(parent >= 0)
     assert reached.tolist() == before["vertices"]
-    assert parent[reached].tolist() == before["parents"]
+    assert np.array_equal(parent, _queue_order_tree(g, key))
+    same = parent[reached].tolist() == before["parents"]
+    assert same == (case not in REORDERED_BY_50)
     # Sub-groups that went into relax: every vertex but the key was
     # appended inside one, at most SR_SUB to each; and none goes in that
     # was not tested, SR_TEST entries to a group, a vertex's last rounded

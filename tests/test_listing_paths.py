@@ -140,3 +140,88 @@ def test_the_command_line_prints_the_loop_the_counts_and_the_paths(
     assert out[1:3] == ["sdivrem 1", "sand 2"]
     assert out[3] == "19 0x4:%p5_p0=T 0x11:!%p6_p1=N 0x17:!%p7_p2=T"
     assert len(out) == 3 + 6
+
+
+# ISSUE 50: the ``search`` kernel's listing has a 10-bundle loop BEHIND the
+# scheduler's (the exit's spill of the lanes), so "the last back-branch"
+# named the wrong loop: the scheduler's is the widest. The same listing
+# with such a loop at 0x1c .. 0x1d behind the scheduler's.
+TRAILED = "\n".join(
+    LISTING.splitlines()[:-1]
+    + [
+        "  0x1c LB: > { %s50 = sld [smem:[#allocation2]] }",
+        "  0x1d   : > { %60 = sbr.rel (!%p9_p3) target bundleno = 90 "
+        "(0x5a), region = 40 }",
+        "  0x1e   :  {}",
+        "  0x1f   :  {}",
+        "  0x20   :  {}",
+        "  0x21   :  {}",
+    ]
+)
+
+
+@pytest.fixture(scope="module")
+def trailed():
+    return lp.parse(TRAILED.splitlines())
+
+
+@pytest.mark.parametrize(
+    "head,loop",
+    [
+        (None, (0x3, 0x17)),   # the widest, not the last
+        (0x1c, (0x1c, 0x1d)),  # --loop names another by its head
+        (0x10, (0x10, 0x11)),
+    ],
+)
+def test_the_scheduler_loop_is_the_widest_or_the_one_named(
+    trailed, head, loop
+):
+    br = lp.branches(trailed)
+    assert max(a for a, (_, t) in br.items() if t < a) == 0x1d
+    assert lp.scheduler_loop(br, head) == loop
+    assert lp.loop_paths(trailed, head=head)[:2] == loop
+
+
+def test_a_head_that_no_loop_has_is_refused(trailed):
+    with pytest.raises(ValueError, match="no loop has its head at 0x5"):
+        lp.scheduler_loop(lp.branches(trailed), 0x5)
+
+
+@pytest.mark.parametrize(
+    "take,decisions,length",
+    [
+        ([], "0x4=N 0x11=N 0x17=T", 25),
+        ([0x4], "0x4=T 0x11=N 0x17=T", 19),
+        ([0x11, 0x4, 0x11], "0x4=T 0x11=T 0x11=T 0x11=N 0x17=T", 31),
+    ],
+)
+def test_one_path_followed_by_the_branches_it_takes(
+    trailed, take, decisions, length
+):
+    p = lp.follow(trailed, take)
+    assert p.bundles == length
+    assert " ".join(f"{b:#x}={d}" for b, _, d in p.decisions) == decisions
+    # the walk of every path finds the same one
+    assert p in lp.loop_paths(trailed)[2]
+
+
+def test_a_branch_named_that_the_path_never_meets_is_refused(trailed):
+    with pytest.raises(ValueError, match="not on the path: 0x1d"):
+        lp.follow(trailed, [0x4, 0x1d])
+
+
+def test_the_command_line_follows_one_path_of_a_named_loop(tmp_path, capsys):
+    f = tmp_path / "k-71-final_bundles.txt"
+    f.write_text(TRAILED + "\n")
+    assert lp.main([str(f), "--count", "", "--take", "0x4"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out == [
+        "kernel 34 bundles; loop 0x3 .. 0x17, 25 bundles",
+        "19 0x4:%p5_p0=T 0x11:!%p6_p1=N 0x17:!%p7_p2=T",
+    ]
+    assert lp.main([str(f), "--count", "", "--loop", "0x1c"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out == [
+        "kernel 34 bundles; loop 0x1c .. 0x1d, 6 bundles",
+        "6 0x1d:!%p9_p3=T",
+    ]

@@ -187,6 +187,7 @@ def test_same_answers_however_the_buffers_cross(build, residency):
     if build == "batch":
         t = info["tiers"]
         assert (t["batch_tasks"], t["routed"]) == (FIB_TASKS, FIB_TASKS)
+        assert t["direct"] == 0  # a LIFO lane: its spawns keep the ring
         assert t["scalar_tasks"] == SUM_TASKS + NT
     else:
         assert "tiers" not in info

@@ -31,6 +31,24 @@ in the bundles in front of it). A group that finds nothing runs from there
 to the first ``sbr.rel`` and its four delay slots, then from that branch's
 target (the ``PF:`` that reloads the counter) to the back-branch and its
 four: 77 + 9 bundles for 16 entries (PR 49; 55 + 7 for 4 before it).
+The scheduler's loop is the widest one (``0xaf .. 0x21bf`` at PR 50, of
+8,916 bundles; a 10-bundle loop that spills the lanes at the exit follows
+it, which is why ``tools/listing_paths.py`` takes the widest and not the
+last). It has too many paths to list: follow one with ``listing_paths.py
+<listing> --take 0x<branch>,...``. Its head tests the starved phase and
+jumps that phase's copy of the batch body (the first ``sbr.rel`` of the
+loop, ``0xbe``), then the drain phase's (``0xe67``), then the pop
+(``0x1c18``): all three taken is the round that does nothing, 74 bundles.
+The ROUTING path is the pop not jumped and the next branch (``0x1c25``,
+over the scalar ``step``) taken: pop, ``F_FN``, the compare and the
+predicated lane push, ``TS_ROUTED``, the age clock, 90 bundles a row; a
+row the maker spawns no longer runs it (PR 50: ``spawn`` pushes the lane),
+a row the host staged or ``retire()`` released does. Behind that branch
+stands the maker: the ``LB:`` at ``0x1c4f`` is ``_make_kernel.step``'s
+``while_loop`` (``--loop 0x1c4f --take 0x1c8b``: 98 bundles an iteration,
+``spawn`` and ``take`` predicated into every one, the gather jumped). The
+addresses move with every change to the kernel: find them again by the
+order of the branches, not by their numbers.
 The child's output goes to ``<outdir>/compile.log``; with
 ``--if-conversion`` the compiler's if-conversion pass logs into it which
 ``pl.when`` / ``lax.cond`` regions it predicated and which it kept as

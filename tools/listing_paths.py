@@ -10,7 +10,12 @@ flow. What the count rests on (PR 45; ``PERF.md`` section 6):
 - its printed ``target bundleno`` is from an EARLIER numbering: the
   distinct targets, sorted, are matched in order to the listing's
   labelled lines (``LH:`` ``LB:`` ``LE:`` ``PB:`` ``PF:`` ``CT:``);
-- the scheduler's loop is the ``LB:`` whose back-branch comes last.
+- the scheduler's loop is the WIDEST one: the loop whose back-branch
+  lies farthest from its head holds every other loop of the kernel but
+  those in front of and behind it (in ``--kernel search``'s listing a
+  10-bundle loop that spills the lanes follows it, so "the last
+  back-branch" named the wrong one; PR 50). ``--loop 0x<head>`` names
+  another by its head.
 
 Every syntactic path from the loop's head to its back-branch is listed
 with its length and the way each branch went (``T`` taken, ``N`` not); an
@@ -18,7 +23,16 @@ inner loop is followed for at most three trips. Which of them a task
 kind runs is read off the decisions (in the fib kernel: the first branch
 after the pop tells SUM from FIB, the next a leaf from a fork, and so on).
 
+A loop with batch bodies in it (``search``: sixteen copies of the relax
+loop) has too many paths to list. ``--take 0x<branch>,...`` follows ONE:
+from the loop's head, each named branch taken at its first visit (name it
+twice to take it twice), every other one not, to the back-branch, and
+prints that path's length and decisions. So the round that does nothing
+in the ``search`` listing is ``--take`` of the three branches that jump
+the starved phase's batch body, the drain phase's and the pop.
+
     python tools/listing_paths.py <listing> [--count sdivrem,sand,spop]
+                                  [--loop 0x<head>] [--take 0x<b>,0x<b>]
 """
 
 from __future__ import annotations
@@ -26,7 +40,7 @@ from __future__ import annotations
 import argparse
 import re
 import sys
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 DELAY_SLOTS = 4
 MAX_TRIPS = 3
@@ -89,23 +103,64 @@ def branches(bundles: List[Bundle]) -> Dict[int, Tuple[str, int]]:
     return out
 
 
-def scheduler_loop(br: Dict[int, Tuple[str, int]]) -> Tuple[int, int]:
-    """``(head, back-branch)`` of the last loop in the listing."""
+def scheduler_loop(
+    br: Dict[int, Tuple[str, int]], head: Optional[int] = None
+) -> Tuple[int, int]:
+    """``(head, back-branch)`` of the widest loop in the listing (the last
+    of them where two are as wide), or of the loop whose head is
+    ``head``, by its farthest back-branch."""
     backs = [a for a, (_, t) in br.items() if t < a]
     if not backs:
         raise ValueError("no backward branch: the listing has no loop")
-    back = max(backs)
+    if head is not None:
+        backs = [a for a in backs if br[a][1] == head]
+        if not backs:
+            raise ValueError(f"no loop has its head at {head:#x}")
+    back = max(backs, key=lambda a: (a - br[a][1], a))
     return br[back][1], back
 
 
+def follow(
+    bundles: List[Bundle], take: Sequence[int], head: Optional[int] = None
+) -> Path:
+    """The one path from the loop's head to its back-branch on which each
+    branch of ``take`` is taken, once for each time it is named, at its
+    first visits, and every other branch is not."""
+    br = branches(bundles)
+    head, back = scheduler_loop(br, head)
+    left = list(take)
+    pc, n, dec = head, 0, ()
+    while pc != back:
+        if pc >= len(bundles) or n > len(bundles) * MAX_TRIPS:
+            raise ValueError(
+                f"the path left the loop {head:#x} .. {back:#x} at {pc:#x}"
+            )
+        if pc not in br:
+            pc, n = pc + 1, n + 1
+            continue
+        pred, tgt = br[pc]
+        taken = pc in left
+        if taken:
+            left.remove(pc)
+        dec += ((pc, pred, "T" if taken else "N"),)
+        n += 1 + DELAY_SLOTS
+        pc = tgt if taken else pc + 1 + DELAY_SLOTS
+    if left:
+        raise ValueError(
+            "not on the path: " + ", ".join(f"{a:#x}" for a in left)
+        )
+    return Path(n + 1 + DELAY_SLOTS, dec + ((back, br[back][0], "T"),))
+
+
 def loop_paths(
-    bundles: List[Bundle], max_visits: int = MAX_VISITS
+    bundles: List[Bundle], max_visits: int = MAX_VISITS,
+    head: Optional[int] = None,
 ) -> Tuple[int, int, List[Path], bool]:
     """``(head, back, paths, whole)``: every path from the scheduler
     loop's head round to its back-branch, shortest first; ``whole`` is
     False where the walk gave up after ``max_visits`` branch visits."""
     br = branches(bundles)
-    head, back = scheduler_loop(br)
+    head, back = scheduler_loop(br, head)
     end = len(bundles)
     paths: List[Path] = []
     visits = 0
@@ -149,14 +204,26 @@ def main(argv=None) -> int:
     ap.add_argument("listing")
     ap.add_argument("--count", default="sdivrem,sand,spop",
                     help="mnemonics to count, comma-separated")
+    ap.add_argument("--loop", type=lambda x: int(x, 0), default=None,
+                    help="head bundle of the loop to walk (default: the "
+                    "widest loop)")
+    ap.add_argument("--take", default=None,
+                    help="follow one path: these branches taken, "
+                    "comma-separated, every other one not")
     a = ap.parse_args(argv)
     with open(a.listing) as f:
         bundles = parse(f)
-    head, back, paths, whole = loop_paths(bundles)
+    head, back = scheduler_loop(branches(bundles), a.loop)
     print(f"kernel {len(bundles)} bundles; loop {head:#x} .. {back:#x}, "
           f"{back + 1 + DELAY_SLOTS - head} bundles")
     for name in filter(None, a.count.split(",")):
         print(f"{name} {count_ops(bundles, name)}")
+    if a.take is not None:
+        paths, whole = [follow(
+            bundles, [int(x, 0) for x in a.take.split(",") if x], a.loop
+        )], True
+    else:
+        *_, paths, whole = loop_paths(bundles, head=a.loop)
     for p in paths:
         print(p.bundles,
               " ".join(f"{b:#x}:{pred}={d}" for b, pred, d in p.decisions))
