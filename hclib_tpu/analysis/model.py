@@ -101,8 +101,13 @@ def certify_tile_schedule(tk, bounds, tile, *,
     K permuted orders; identical final buffers = certified. A tile
     whose LOADS overlap another tile's STORES is order-dependent (the
     in-place-stencil bug class) and diverges concretely - refused with
-    the two schedules."""
-    from ..device.forasync_tier import tile_args, tile_grid
+    the two schedules.
+
+    A loop of several steps (``tk.steps`` > 1) is certified over its
+    (step, tile) nodes in K random orders that honour what it DECLARES
+    it awaits, and no more: a tile that reads or overwrites what a tile
+    it does not await stores or reads diverges between two of them."""
+    from ..device.forasync_tier import StepPlan, tile_args, tile_grid
 
     perms = _perms() if perms is None else int(perms)
     key = (repr(tuple(bounds)),
@@ -112,12 +117,14 @@ def certify_tile_schedule(tk, bounds, tile, *,
     if cached is not None and key in cached:
         return cached[key]
     dims, tile_dims, counts, total = tile_grid(bounds, tile)
+    steps = tk.steps
+    plan = StepPlan(tk, dims, tile_dims, counts) if steps > 1 else None
     cert: Dict[str, Any] = {
-        "claim": "forasync-tiles", "kernel": tk.name, "tiles": total,
-        "orders": perms,
+        "claim": "forasync-tiles", "kernel": tk.name,
+        "tiles": total * steps, "orders": perms,
     }
-    if total > TILE_SPACE_CAP:
-        cert["status"] = f"unverified (tile space {total} > cap)"
+    if total * steps > TILE_SPACE_CAP:
+        cert["status"] = f"unverified (tile space {total * steps} > cap)"
         return cert
 
     def run_order(order) -> Dict[str, np.ndarray]:
@@ -127,12 +134,20 @@ def certify_tile_schedule(tk, bounds, tile, *,
                 tk.data_specs.items()
             ))
         }
-        for flat in order:
-            args = tuple(tile_args(dims, tile_dims, counts, int(flat)))
+        for node in order:
+            step, flat = divmod(int(node), total)
+            args = tuple(tile_args(dims, tile_dims, counts, flat)
+                         + [step] * (steps > 1))
             ins = {}
             for s in tk.loads:
                 box = _norm_box(bufs[s.data].shape, s.index(args))
-                ins[s.name] = bufs[s.data][_np_index(box)].copy()
+                got = bufs[s.data][_np_index(box)].copy()
+                if s.into is None:
+                    ins[s.name] = got
+                else:  # a window of a shared staging buffer
+                    stage = ins.setdefault(s.into, np.zeros(
+                        tk.staging[s.into], got.dtype))
+                    stage[_np_index(_norm_box(stage.shape, s.at))] = got
             outs = tk.compute(ins)
             for s in tk.stores:
                 box = _norm_box(bufs[s.data].shape, s.index(args))
@@ -140,9 +155,10 @@ def certify_tile_schedule(tk, bounds, tile, *,
         return bufs
 
     rng = np.random.default_rng(seed)
-    orders = [list(range(total))]
+    orders = [list(range(total * steps))]  # step by step: always legal
     for _ in range(perms - 1):
-        orders.append(list(rng.permutation(total)))
+        orders.append(list(rng.permutation(total)) if steps == 1
+                      else _awaiting_order(plan, rng))
     ref = run_order(orders[0])
     for k in range(1, perms):
         got = run_order(orders[k])
@@ -180,6 +196,27 @@ def certify_tile_schedule(tk, bounds, tile, *,
     else:
         cached[key] = cert
     return cert
+
+
+def _awaiting_order(plan, rng) -> List[int]:
+    """One random order of a stepped loop's nodes (``step * total +
+    flat``) in which every tile comes after the tiles of the step before
+    that it awaits (``StepPlan.near``), drawn a ready node at a time."""
+    total, steps = plan.total, plan.steps
+    near = [plan.near(c) for c in np.ndindex(*plan.counts)]
+    left = {(s, f): len(near[f])
+            for s in range(1, steps) for f in range(total)}
+    ready = [(0, f) for f in range(total)]
+    order = []
+    while ready:
+        step, flat = ready.pop(int(rng.integers(len(ready))))
+        order.append(step * total + flat)
+        if step + 1 < steps:
+            for n in near[flat]:  # symmetric: the tiles that await me
+                left[step + 1, n] -= 1
+                if not left[step + 1, n]:
+                    ready.append((step + 1, n))
+    return order
 
 
 # --------------------------------------------------------- frontier

@@ -67,7 +67,16 @@ def check_tile_windows(tk, bounds, tile,
                        suppress: Sequence[str] = ()) -> AnalysisReport:
     """Prove every pair of tiles of one forasync loop stores disjoint
     windows (per store slab/buffer) by concrete evaluation over the
-    whole tile space. Witness: the two colliding tile coordinates."""
+    whole tile space. Witness: the two colliding tile coordinates.
+
+    A loop of several steps (``tk.steps`` > 1) is also held to the other
+    half, between every step and the next (``_check_step_windows``): a
+    tile of step t whose window overlaps a window of a tile of step t+1
+    on one buffer, one of the two a store, is among the tiles that tile
+    awaits. That is read-before-overwrite (a load of step t under a store
+    of step t+1) and written-before-read (a store of step t under a load
+    of step t+1) at once. Witness: the two tiles, their steps and the
+    two windows."""
     from ..device.forasync_tier import tile_args, tile_grid
 
     report = report or AnalysisReport(suppress)
@@ -83,8 +92,9 @@ def check_tile_windows(tk, bounds, tile,
     per_buffer: Dict[str, List[Tuple[Any, int, Tuple[int, ...]]]] = {}
     from .shim import _norm_box
 
+    stepped = tk.steps > 1
     for flat in range(total):
-        args = tile_args(dims, tile_dims, counts, flat)
+        args = tile_args(dims, tile_dims, counts, flat) + [0] * stepped
         for s in tk.stores:
             try:
                 idx = s.index(tuple(args))
@@ -120,11 +130,113 @@ def check_tile_windows(tk, bounds, tile,
                     )
                     return report  # one witness is enough
             active.append((box, flat, los))
+    if stepped and not _check_step_windows(
+            tk, dims, tile_dims, counts, report):
+        return report
     try:
         _tile_clean.setdefault(tk, set()).add(key)
     except TypeError:
         pass
     return report
+
+
+def _check_step_windows(tk, dims, tile_dims, counts, report) -> bool:
+    """``check_tile_windows``' rule between consecutive steps; False
+    where it added a finding. Every window of every tile of every step
+    is evaluated; two steps whose windows repeat an earlier pair's (a
+    two-plane layout alternates) are not compared again."""
+    import numpy as np
+
+    from ..device.forasync_tier import tile_args
+    from .shim import _norm_box
+
+    total = int(np.prod(counts))
+    coords = np.array(list(np.ndindex(*counts)))
+    awaited = {tuple(o) for o in tk.awaits}
+
+    def windows(step: int):
+        # buffer -> (lo[n, k], hi[n, k], flat[n], is_store[n], slab name)
+        per: Dict[str, List] = {}
+        for flat in range(total):
+            args = tuple(tile_args(dims, tile_dims, counts, flat) + [step])
+            for s, st in [(s, False) for s in tk.loads] + [
+                    (s, True) for s in tk.stores]:
+                box = _norm_box(tuple(tk.data_specs[s.data].shape),
+                                s.index(args))
+                per.setdefault(s.data, []).append((box, flat, st, s.name))
+        return {
+            buf: (np.array([[a for a, _ in w[0]] for w in ws]),
+                  np.array([[b for _, b in w[0]] for w in ws]),
+                  np.array([w[1] for w in ws]),
+                  np.array([w[2] for w in ws]),
+                  [w[3] for w in ws])
+            for buf, ws in per.items()
+        }
+
+    def sig(wins) -> int:
+        return hash(tuple(
+            (buf, lo.tobytes(), hi.tobytes()) for buf, (lo, hi, *_)
+            in sorted(wins.items())
+        ))
+
+    seen = set()
+    try:
+        prev = windows(0)
+    except Exception as e:  # noqa: BLE001
+        report.add("shim-unsupported", INFO, tk.name,
+                   f"slab index not concretely evaluable: {e}")
+        return False
+    for step in range(1, tk.steps):
+        cur = windows(step)
+        pair = (sig(prev), sig(cur))
+        if pair not in seen:
+            seen.add(pair)
+            for buf in set(prev) & set(cur):
+                alo, ahi, aflat, ast, aname = prev[buf]
+                blo, bhi, bflat, bst, bname = cur[buf]
+                # Sweep the axis along which step t's windows start at
+                # the most places: a window of step t+1 can only meet
+                # those that start inside its own extent widened by the
+                # longest of them, a short run of the sorted starts.
+                ax = max(range(alo.shape[1]),
+                         key=lambda d: len(np.unique(alo[:, d])))
+                order = np.argsort(alo[:, ax], kind="stable")
+                starts = alo[order, ax]
+                reach = int((ahi[:, ax] - alo[:, ax]).max())
+                for j in range(len(bflat)):
+                    cand = order[
+                        np.searchsorted(starts, blo[j, ax] - reach, "right"):
+                        np.searchsorted(starts, bhi[j, ax], "left")]
+                    hit = np.all((alo[cand] < bhi[j])
+                                 & (blo[j] < ahi[cand]), axis=1)
+                    hit &= ast[cand] | bst[j]
+                    for i in cand[hit]:
+                        x, y = coords[aflat[i]], coords[bflat[j]]
+                        if tuple(int(v) for v in x - y) in awaited:
+                            continue
+                        what = ("stores over what" if bst[j] and not ast[i]
+                                else "touches what")
+                        verb = "stores" if ast[i] else "loads"
+                        report.add(
+                            "tile-race", ERROR, tk.name,
+                            f"tile {tuple(int(v) for v in y)} of step "
+                            f"{step} {what} tile "
+                            f"{tuple(int(v) for v in x)} of step "
+                            f"{step - 1} {verb} in buffer {buf!r} (slabs "
+                            f"{bname[j]!r} / {aname[i]!r}) and does not "
+                            f"await it (awaits {sorted(awaited)})",
+                            buffer=buf,
+                            tile_a=tuple(int(v) for v in x),
+                            tile_b=tuple(int(v) for v in y),
+                            step_a=step - 1, step_b=step,
+                            window_a=tuple(zip(alo[i].tolist(),
+                                               ahi[i].tolist())),
+                            window_b=tuple(zip(blo[j].tolist(),
+                                               bhi[j].tolist())),
+                        )
+                        return False  # one witness is enough
+        prev = cur
+    return True
 
 
 # -------------------------------------------------------- batch bodies

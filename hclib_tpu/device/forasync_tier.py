@@ -35,12 +35,12 @@ graph additionally orders the steal scan near-neighbors-first
 (``steal_hop_order``), so a skewed or stale placement degrades into
 recoverable work stealing instead of a wrong or wedged run.
 
-Tiles are successor-free descriptors whose kernels only read their args
-and write disjoint output slabs, so they are migratable by construction;
-on the mesh every device runs the loop over a replicated input and
-writes its executed tiles into its own output copy, and the host sums
-the per-device outputs (each tile executes exactly once mesh-wide, and
-output buffers are required to start zero).
+Tiles of ONE step are successor-free descriptors whose kernels only
+read their args and write disjoint output slabs, so they are migratable
+by construction; on the mesh every device runs the loop over a
+replicated input and writes its executed tiles into its own output copy,
+and the host sums the per-device outputs (each tile executes exactly once
+mesh-wide, and output buffers are required to start zero).
 
 Two modes, the reference's two (src/hclib.c:158-416). FLAT stages one
 descriptor a tile on the host, so the task table holds every tile.
@@ -56,6 +56,24 @@ splitter is paced by the tiles' consumer and the table holds the LIVE
 set - two batches of tiles and a split a level of the recursion - however
 many tiles the loop has (``info["forasync"]["live_rows_max"]``).
 
+Time steps (PR 51). ``TileKernel(steps=, awaits=)`` makes the loop
+``steps`` time steps in ONE launch, a tile of step t+1 awaiting the tiles
+of step t at the offsets ``awaits`` names (its own among them): tasks
+made on the device that wait for each other. Step 0 is the splitter's. A
+tile of a later step does not exist until the last tile it awaits has
+stored: a finishing tile counts down, in the kernel's value slots, every
+tile of the next step that awaits it and ``spawn``s the one that reaches
+zero, straight onto the lane (``StepPlan``; the descriptor carries its
+step as a fifth word, by which a slab picks its plane). There is no
+barrier between steps: the lane pops in the order tiles were released,
+the splitter is held back while the lane holds two batches, and the
+front runs skewed through several steps at once
+(``info["forasync"]``: ``released``, ``decrements``, ``mixed_rounds``,
+``step_skew_max``). The table is sized from the schedule replayed on the
+host (``StepPlan.simulate``), and ``analysis.check_tile_windows`` proves
+over the concrete tile space that every tile whose windows meet a store
+of the next step is among the tiles that store's tile awaits.
+
 Device-path constraints (explicit ``ValueError``\\ s):
 
 - bounds must divide exactly by the tile (slab shapes are static; the
@@ -63,7 +81,11 @@ Device-path constraints (explicit ``ValueError``\\ s):
 - a FLAT loop needs a table row a tile: one with more tiles than the
   table takes is refused, naming RECURSIVE;
 - RECURSIVE runs on one device (a placement seeds per-device rings from
-  flat tiles).
+  flat tiles);
+- a loop of several steps runs RECURSIVE on one device, its lane FIFO
+  (``prefetch=True``); what it awaits is symmetric (with every offset its
+  opposite) and includes its own tile, which is what lets two parities
+  of countdowns serve any number of steps.
 """
 
 from __future__ import annotations
@@ -96,6 +118,7 @@ __all__ = [
     "run_forasync_device",
     "seed_root",
     "split_plan",
+    "StepPlan",
     "FA_TILE",
     "FA_SPLIT",
 ]
@@ -312,6 +335,242 @@ def seed_root(builder: TaskGraphBuilder, bounds: Sequence,
     return total
 
 
+# ----------------------------------------------------------- time steps
+#
+# A loop of ``steps`` time steps (``TileKernel(steps=, awaits=)``) keeps a
+# countdown a (step parity, tile) in the kernel's value slots. A tile that
+# has stored decrements the countdown of every tile of the NEXT step that
+# awaits it, and ``spawn``s the one whose countdown reaches zero, straight
+# onto the lane; step 0 is the splitter's. Two parities are enough: tile y
+# of step t+2 awaits tile x of step t+1 for every neighbour y of x, so
+# nobody touches x's countdown for step t+3 before (t+1, x) was released
+# and its countdown re-armed. A countdown word holds what it re-arms to
+# above what is left: ``need << AW_SHIFT | left``.
+
+AW_SHIFT = 8
+AW_MASK = (1 << AW_SHIFT) - 1
+# Value slots: the loop's counters, then the countdowns from AW_BASE.
+V_RELEASED, V_DECREMENTS, V_MIXED, V_SKEW = 0, 1, 2, 3
+AW_BASE = 8
+
+
+class StepPlan:
+    """What the steps of one tile space need on the device and on the
+    host: the release a finished tile runs (``release`` / ``round`` /
+    ``count``, traced into the tile kind's bodies), the countdowns'
+    presets, and the schedule itself replayed on the host
+    (``simulate``), which sizes the table."""
+
+    def __init__(self, tk: "TileKernel", dims, tile_dims, counts) -> None:
+        nd = len(dims)
+        self.tk = tk
+        self.nd = nd
+        self.steps = tk.steps
+        self.counts = list(counts)
+        self.total = math.prod(counts)
+        self.lo = [lo for lo, _ in dims]
+        self.hi = [hi for _, hi in dims]
+        self.td = list(tile_dims)
+        stride = [math.prod(counts[d + 1:]) for d in range(nd)]
+        if any(len(o) != nd for o in tk.awaits):
+            raise ValueError(
+                f"awaits {tk.awaits} are not offsets of a {nd}-D tile space"
+            )
+        # (offset, the same in flat tile indices), in the awaits' order.
+        self.succ = [
+            (o, sum(x * st for x, st in zip(o, stride))) for o in tk.awaits
+        ]
+        self.num_values = AW_BASE + 2 * self.total
+        self._presets: Optional[np.ndarray] = None
+
+    # -- host side --
+
+    def near(self, idx: Sequence[int]) -> List[int]:
+        """Flat indices of the tiles of the step before that tile ``idx``
+        awaits, which by symmetry are also the tiles of the step after
+        that await it: the offsets that stay inside the grid, in the
+        awaits' order."""
+        flat = 0
+        for i, c in zip(idx, self.counts):
+            flat = flat * c + i
+        return [
+            flat + dflat for o, dflat in self.succ
+            if all(0 <= i + x < c for i, x, c in zip(idx, o, self.counts))
+        ]
+
+    def presets(self) -> np.ndarray:
+        """The value slots a launch starts from: counters zero, every
+        countdown of both parities armed. One array, made once: a run
+        copies it into its upload and never writes it."""
+        if self._presets is None:
+            need = [len(self.near(idx))
+                    for idx in np.ndindex(*self.counts)]
+            vals = np.zeros(self.num_values, np.int32)
+            vals[AW_BASE:] = np.tile(
+                np.array(need, np.int32) * (AW_MASK + 2), 2)
+            vals.setflags(write=False)
+            self._presets = vals
+        return self._presets
+
+    def simulate(self, width: int) -> Dict[str, int]:
+        """The launch replayed on the host, descriptor by descriptor, as
+        the scheduler runs it: the ready ring popped newest first, the
+        splitter's halves (upper first), a tile routed to its lane at the
+        pop, the lane fired FIFO at an empty ring or at ``2 * width``
+        entries, a released tile pushed on the lane's tail by the batch
+        that released it while that batch's rows are still live
+        (``width=0``: everything through the ring). Returns the loop's
+        counters as ``info["forasync"]`` reports them, ``live_rows_max``
+        among them: the table is sized from it, and the tests hold the
+        kernel to all of them."""
+        nd, counts, steps = self.nd, self.counts, self.steps
+        coords = list(np.ndindex(*counts))
+        need = [len(self.near(i)) for i in coords]
+        left = [list(need), list(need)]
+        ring: List[Any] = []  # ("s", extents lo/hi) or ("t", step, flat)
+        lane: List[Tuple[int, int]] = []
+        out = dict(released=0, decrements=0, mixed_rounds=0,
+                   step_skew_max=0, live_rows_max=0, batch_rounds=0,
+                   batch_tasks=0, splits=0)
+        live = 1
+        hw = 1
+        if self.total == 1:
+            ring.append(("t", 0, 0))
+        else:
+            ring.append(("s", [0] * nd, list(counts)))
+
+        def spawn(entry, direct: bool) -> None:
+            nonlocal live, hw
+            live += 1
+            hw = max(hw, live)
+            (lane if direct else ring).append(
+                entry[1:] if direct else entry
+            )
+
+        def finish(step: int, flat: int) -> None:
+            if step + 1 >= steps:
+                return
+            cnt = left[(step + 1) & 1]
+            for n in self.near(coords[flat]):
+                out["decrements"] += 1
+                cnt[n] -= 1
+                if cnt[n] == 0:
+                    cnt[n] = need[n]
+                    out["released"] += 1
+                    spawn(("t", step + 1, n), bool(width))
+
+        def split(lo, hi) -> None:
+            ext = [h - l for l, h in zip(lo, hi)]
+            d = _widest(ext, self.td)
+            mid = lo[d] + ext[d] // 2
+            for upper in (True, False):
+                plo, phi = list(lo), list(hi)
+                if upper:
+                    plo[d] = mid
+                else:
+                    phi[d] = mid
+                if all(h - l == 1 for l, h in zip(plo, phi)):
+                    flat = 0
+                    for i, c in zip(plo, counts):
+                        flat = flat * c + i
+                    spawn(("t", 0, flat), False)
+                else:
+                    spawn(("s", plo, phi), False)
+            out["splits"] += 1
+
+        while ring or lane:
+            if lane and (not ring or len(lane) >= 2 * width):
+                take = lane[:width]
+                del lane[:width]
+                for step, flat in take:
+                    finish(step, flat)
+                live -= len(take)
+                st = [s for s, _ in take]
+                out["batch_rounds"] += 1
+                out["batch_tasks"] += len(take)
+                out["mixed_rounds"] += max(st) != min(st)
+                out["step_skew_max"] = max(
+                    out["step_skew_max"], max(st) - min(st))
+                continue
+            e = ring.pop()
+            if e[0] == "s":
+                split(e[1], e[2])
+                live -= 1
+            elif width:
+                lane.append(e[1:])
+            else:
+                finish(e[1], e[2])
+                live -= 1
+        out["live_rows_max"] = hw
+        return out
+
+    # -- device side --
+
+    def release(self, k, a, live):
+        """Tile ``a`` (its five arg words) has stored: count down every
+        tile of the next step that awaits it and spawn those that reach
+        zero, through ``k`` (a ``KernelContext``). Nothing happens where
+        ``live`` is false or ``a`` is of the last step. Returns the
+        decrements and the releases made, traced."""
+        nxt = a[4] + 1
+        go = live & (nxt < self.steps)
+        plane = AW_BASE + (nxt & 1) * self.total
+        dec = rel = jnp.int32(0)
+        for o, dflat in self.succ:
+            nlo = [a[1 + d] + o[d] * self.td[d] for d in range(self.nd)]
+            ok = go
+            for d in range(self.nd):
+                if o[d] < 0:
+                    ok = ok & (nlo[d] >= self.lo[d])
+                elif o[d] > 0:
+                    ok = ok & (nlo[d] < self.hi[d])
+            nflat = a[0] + dflat
+            slot = plane + jnp.where(ok, nflat, 0)
+            w = k.value(slot) - 1
+            fire = ok & ((w & AW_MASK) == 0)
+
+            @pl.when(ok)
+            def _(slot=slot, w=w, fire=fire):
+                k.set_value(
+                    slot, jnp.where(fire, (w >> AW_SHIFT) * (AW_MASK + 2), w)
+                )
+
+            @pl.when(fire)
+            def _(nflat=nflat, nlo=nlo):
+                k.spawn(
+                    FA_TILE,
+                    [nflat] + nlo + [0] * (3 - self.nd) + [nxt],
+                    nargs=5,
+                )
+
+            dec = dec + ok.astype(jnp.int32)
+            rel = rel + fire.astype(jnp.int32)
+        return dec, rel
+
+    @staticmethod
+    def count(ctx, dec, rel, mixed=None, skew=None) -> None:
+        """Add a dispatch's releases to the loop's counters."""
+        ctx.set_value(V_DECREMENTS, ctx.value(V_DECREMENTS) + dec)
+        ctx.set_value(V_RELEASED, ctx.value(V_RELEASED) + rel)
+        if mixed is not None:
+            ctx.set_value(V_MIXED, ctx.value(V_MIXED) + mixed)
+            ctx.set_value(V_SKEW, jnp.maximum(ctx.value(V_SKEW), skew))
+
+    def round(self, ctx, args_of) -> None:
+        """A batch round's releases, slot by slot in lane order, and what
+        the round says of the front: whether its live slots held tiles of
+        more than one step, and how many steps apart."""
+        dec = rel = jnp.int32(0)
+        lo = hi = args_of(0)[4]  # slot 0 is live in every fired round
+        for b in range(ctx.width):
+            a = args_of(b)
+            d, r = self.release(ctx.slot_ctx(b), a, ctx.live(b))
+            dec, rel = dec + d, rel + r
+            lo = jnp.where(ctx.live(b), jnp.minimum(lo, a[4]), lo)
+            hi = jnp.where(ctx.live(b), jnp.maximum(hi, a[4]), hi)
+        self.count(ctx, dec, rel, (hi > lo).astype(jnp.int32), hi - lo)
+
+
 # ---------------------------------------------------------- slab pipeline
 
 
@@ -330,7 +589,14 @@ class Slab:
     ``memref_slice``, which the interpreter never notices. A loop that
     needs an unaligned neighbourhood loads the aligned superset and
     slices the loaded value in ``compute`` (``workloads.stencil_loop``
-    does)."""
+    does).
+
+    A load may land in a window of a STAGING buffer several loads share
+    (``into`` names one of the ``TileKernel``'s ``staging`` buffers, ``at``
+    the window inside it, static and aligned like any other): the pieces
+    of a neighbourhood that is no box - a tile and four halo strips
+    without the corners, ``workloads.jacobi_loop`` - arrive by a DMA each
+    and ``compute`` reads them as one value under the buffer's name."""
 
     def __init__(
         self,
@@ -338,25 +604,43 @@ class Slab:
         data: str,
         index: Callable[[Sequence], Tuple],
         shape: Tuple[int, ...],
+        into: Optional[str] = None,
+        at: Optional[Tuple] = None,
     ) -> None:
+        if (into is None) != (at is None):
+            raise ValueError(
+                f"slab {name!r}: into= and at= come together (a staging "
+                "buffer and the window of it this slab fills)"
+            )
         self.name = name
         self.data = data
         self.index = index
         self.shape = tuple(int(s) for s in shape)
+        self.into = into
+        self.at = None if at is None else tuple(at)
 
 
 class TileKernel:
     """The device body of a forasync tile loop, as a slab pipeline:
-    ``compute`` maps loaded input-slab VALUES (dict keyed by slab name)
-    to output-slab values - pure jnp, no refs - and the tier derives the
-    scalar kernel, the batched body, and its prefetch drain from the
-    slab declarations. One ``TileKernel`` therefore has ONE arithmetic
-    trace, which is what makes the scalar-vs-tile-tier bit-identity of
-    the acceptance runs hold by construction.
+    ``compute`` maps loaded input-slab VALUES (dict keyed by slab name, or
+    by staging buffer for the loads that share one) to output-slab values
+    - pure jnp, no refs - and the tier derives the scalar kernel, the
+    batched body, and its prefetch drain from the slab declarations. One
+    ``TileKernel`` therefore has ONE arithmetic trace, which is what makes
+    the scalar-vs-tile-tier bit-identity of the acceptance runs hold by
+    construction.
 
     ``data_specs`` declares every named buffer the slabs touch (the
     megakernel's ``data_specs``); output buffers must be disjointly
     written across tiles (the same contract batch bodies always carry).
+
+    ``steps`` > 1 makes the loop ``steps`` time steps in one launch (module
+    docstring, "Time steps"): ``awaits`` lists, as offsets in TILE units,
+    the tiles of the step before that a tile waits for - its own (the zero
+    offset) among them, and with every offset its opposite, so the tiles
+    a finishing tile releases are the ones at the same offsets. A slab's
+    ``index`` then receives a fifth word, the tile's step, and picks the
+    plane it reads or writes by it.
     """
 
     def __init__(
@@ -366,6 +650,9 @@ class TileKernel:
         compute: Callable[[Dict[str, Any]], Dict[str, Any]],
         data_specs: Dict[str, jax.ShapeDtypeStruct],
         name: str = "fa_tile",
+        staging: Optional[Dict[str, Tuple[int, ...]]] = None,
+        steps: int = 1,
+        awaits: Sequence[Sequence[int]] = (),
     ) -> None:
         names = [s.name for s in list(loads) + list(stores)]
         if len(set(names)) != len(names):
@@ -375,30 +662,83 @@ class TileKernel:
                 raise ValueError(
                     f"slab {s.name!r} targets undeclared buffer {s.data!r}"
                 )
+        self.staging = {
+            k: tuple(int(n) for n in v) for k, v in (staging or {}).items()
+        }
+        for s in loads:
+            if s.into is not None and s.into not in self.staging:
+                raise ValueError(
+                    f"slab {s.name!r} lands in undeclared staging buffer "
+                    f"{s.into!r}"
+                )
+        if any(s.into is not None for s in stores):
+            raise ValueError("only loads land in a staging buffer")
         self.loads = list(loads)
         self.stores = list(stores)
         self.compute = compute
         self.data_specs = dict(data_specs)
         self.name = name
+        self.steps = int(steps)
+        self.awaits = [tuple(int(x) for x in o) for o in awaits]
+        if self.steps < 1:
+            raise ValueError(f"steps must be >= 1, got {steps}")
+        if self.steps > 1:
+            offs = set(self.awaits)
+            zero = {o for o in offs if not any(o)}
+            if not zero or len(offs) != len(self.awaits) or any(
+                tuple(-x for x in o) not in offs for o in offs
+            ):
+                raise ValueError(
+                    "a loop of several steps awaits its own tile (the zero "
+                    "offset) and, with every offset, its opposite, each "
+                    f"once: got {self.awaits}"
+                )
+            if len(offs) > AW_MASK:
+                raise ValueError(
+                    f"at most {AW_MASK} awaited tiles, got {len(offs)}"
+                )
         # Output buffers (store targets): the mesh path requires them to
         # start zero so per-device copies combine by sum.
         self.out_names = sorted({s.data for s in self.stores})
+        # Arg words a tile descriptor carries: a stepped one its step too.
+        self.nargs = 5 if self.steps > 1 else 4
 
     def _dtype(self, slab: Slab):
         return self.data_specs[slab.data].dtype
+
+    def _load_bufs(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """VMEM buffers the loads fill, name -> (shape, dtype): a load's
+        own, or the staging buffer it shares."""
+        bufs: Dict[str, Tuple[Tuple[int, ...], Any]] = {}
+        for s in self.loads:
+            if s.into is None:
+                bufs[s.name] = (s.shape, self._dtype(s))
+            else:
+                bufs[s.into] = (self.staging[s.into], self._dtype(s))
+        return bufs
+
+    @staticmethod
+    def _load_dst(scratch, s: Slab, lead: Tuple = ()):
+        """Where load ``s`` lands: its buffer (or its window of a staging
+        buffer) behind the leading (half, slot) index ``lead``."""
+        ref = scratch[f"fa_{s.into or s.name}"]
+        idx = tuple(lead) + (s.at or ())
+        return ref.at[idx] if idx else ref
 
     # -- scalar-tier spelling --
 
     def scalar_scratch(self) -> Dict[str, Any]:
         sc: Dict[str, Any] = {}
-        for s in self.loads + self.stores:
+        for n, (shape, dt) in self._load_bufs().items():
+            sc[f"fa_{n}"] = pltpu.VMEM(shape, dt)
+        for s in self.stores:
             sc[f"fa_{s.name}"] = pltpu.VMEM(s.shape, self._dtype(s))
         sc["fa_lsem"] = pltpu.SemaphoreType.DMA((1,))
         sc["fa_ssem"] = pltpu.SemaphoreType.DMA((1,))
         return sc
 
-    def scalar_kernel(self, ctx) -> None:
-        a = tuple(ctx.arg(i) for i in range(4))
+    def scalar_kernel(self, ctx, release=None) -> None:
+        a = tuple(ctx.arg(i) for i in range(self.nargs))
         lsem = ctx.scratch["fa_lsem"]
         ssem = ctx.scratch["fa_ssem"]
         # All loads in flight before the first wait (one sem counts all
@@ -407,11 +747,11 @@ class TileKernel:
             for s in self.loads:
                 cp = pltpu.make_async_copy(
                     ctx.data[s.data].at[s.index(a)],
-                    ctx.scratch[f"fa_{s.name}"],
+                    self._load_dst(ctx.scratch, s),
                     lsem.at[0],
                 )
                 (cp.wait if wait else cp.start)()
-        ins = {s.name: ctx.scratch[f"fa_{s.name}"][...] for s in self.loads}
+        ins = {n: ctx.scratch[f"fa_{n}"][...] for n in self._load_bufs()}
         outs = self.compute(ins)
         for s in self.stores:
             ctx.scratch[f"fa_{s.name}"][...] = outs[s.name]
@@ -423,17 +763,17 @@ class TileKernel:
                     ssem.at[0],
                 )
                 (cp.wait if wait else cp.start)()
+        if release is not None:
+            release.count(ctx, *release.release(ctx, a, jnp.bool_(True)))
 
     # -- batch-tier spelling --
 
     def batch_scratch(self, width: int) -> Dict[str, Any]:
         sc: Dict[str, Any] = {}
-        for s in self.loads:
+        for n, (shape, dt) in self._load_bufs().items():
             # Double-buffered (leading 2): one half computes while the
             # tier's cross-round prefetch fills the other.
-            sc[f"fa_{s.name}"] = pltpu.VMEM(
-                (2, width) + s.shape, self._dtype(s)
-            )
+            sc[f"fa_{n}"] = pltpu.VMEM((2, width) + shape, dt)
         for s in self.stores:
             sc[f"fa_{s.name}"] = pltpu.VMEM((width,) + s.shape, self._dtype(s))
         # One DMA semaphore per (half, slot) counting every load stream
@@ -451,17 +791,18 @@ class TileKernel:
         for s in self.loads:
             cp = pltpu.make_async_copy(
                 ctx.data[s.data].at[s.index(a)],
-                ctx.scratch[f"fa_{s.name}"].at[buf, slot],
+                self._load_dst(ctx.scratch, s, (buf, slot)),
                 sem,
             )
             (cp.wait if wait else cp.start)()
 
-    def batch_body(self, ctx) -> None:
+    def batch_body(self, ctx, release=None) -> None:
         width = ctx.width
         buf = ctx.buf
+        nargs = self.nargs
 
         def args_of(s):
-            return tuple(ctx.arg(s, i) for i in range(4))
+            return tuple(ctx.arg(s, i) for i in range(nargs))
 
         # Phase 1: start operand copies for live slots the prefetch
         # didn't already cover.
@@ -477,7 +818,7 @@ class TileKernel:
         for b in range(width):
             @pl.when(jnp.int32(b) < ctx.prefetch_count)
             def _(b=b):
-                nxt = tuple(ctx.next_arg(b, i) for i in range(4))
+                nxt = tuple(ctx.next_arg(b, i) for i in range(nargs))
                 self._slot_loads(ctx, obuf, b, nxt, wait=False)
 
         # Phase 3: retire this round's loads (prefetched slots wait the
@@ -492,8 +833,8 @@ class TileKernel:
             @pl.when(ctx.live(b))
             def _(b=b):
                 ins = {
-                    s.name: ctx.scratch[f"fa_{s.name}"][buf, b]
-                    for s in self.loads
+                    n: ctx.scratch[f"fa_{n}"][buf, b]
+                    for n in self._load_bufs()
                 }
                 outs = self.compute(ins)
                 for s in self.stores:
@@ -502,10 +843,10 @@ class TileKernel:
         # Phase 5: one store wave - all starts, then all waits, so
         # nothing is still in flight toward the output buffers when the
         # batch's completions run.
-        for wait in (False, True):
+        def store_wave(wait: bool) -> None:
             for b in range(width):
                 @pl.when(ctx.live(b))
-                def _(b=b, wait=wait):
+                def _(b=b):
                     a = args_of(b)
                     sem = ctx.scratch["fa_ssem"].at[b]
                     for s in self.stores:
@@ -516,6 +857,15 @@ class TileKernel:
                         )
                         (cp.wait if wait else cp.start)()
 
+        store_wave(False)
+        if release is not None:
+            # A stepped loop's dependence releases, under the store wave:
+            # they write SMEM only (countdowns, new rows, the lane's
+            # tail), and no tile they make starts a DMA before the next
+            # round, which opens after the waits below.
+            release.round(ctx, args_of)
+        store_wave(True)
+
     def batch_drain(self, ctx) -> None:
         """Retire an in-flight prefetch whose target entries will be
         spilled instead of batched (scheduler exit - fuel, quiesce): wait
@@ -524,7 +874,7 @@ class TileKernel:
         for b in range(ctx.width):
             @pl.when(jnp.int32(b) < ctx.prefetched)
             def _(b=b):
-                a = tuple(ctx.arg(b, i) for i in range(4))
+                a = tuple(ctx.arg(b, i) for i in range(self.nargs))
                 self._slot_loads(ctx, ctx.buf, b, a, wait=True)
 
 
@@ -547,8 +897,9 @@ def _vmem_bytes(tk: TileKernel, scratch: Dict[str, Any]) -> int:
         if getattr(sp, "memory_space", None) == pltpu.VMEM
     )
     one = sum(
-        math.prod(sl.shape) * jnp.dtype(tk._dtype(sl)).itemsize
-        for sl in tk.loads + tk.stores
+        math.prod(shape) * jnp.dtype(dt).itemsize
+        for shape, dt in list(tk._load_bufs().values())
+        + [(sl.shape, tk._dtype(sl)) for sl in tk.stores]
     )
     return max(VMEM_DEFAULT_BYTES, held + 4 * one + (4 << 20))
 
@@ -574,14 +925,42 @@ def make_forasync_megakernel(
     kind beside the tile kind, a lane that fires at two batches, and by
     default a table of the live set (``split_plan``: a pending half a
     level, the piece in hand with its halves, the lane), at least 64
-    rows; a FLAT build's default is 256."""
+    rows; a FLAT build's default is 256. A loop of several steps
+    (``tk.steps`` > 1; RECURSIVE only) also gets its ``StepPlan``
+    (``mk.fa_plan``): the countdowns in the value slots, and a table of
+    the live set its replayed schedule reaches, with an eighth to spare."""
+    plan = None
+    if tk.steps > 1:
+        if space is None:
+            raise ValueError(
+                f"a loop of {tk.steps} steps runs mode=RECURSIVE: step 0 is "
+                "the splitter's, and the countdowns are sized by the tile "
+                "space the build names (space=)"
+            )
+        if width and not prefetch:
+            raise ValueError(
+                "a loop of several steps runs its tiles in the order they "
+                "were released: its lane pops FIFO (prefetch=True)"
+            )
+    if space is not None:
+        dims, tile_dims, counts, _ = tile_grid(*space)
+        if tk.steps > 1:
+            plan = StepPlan(tk, dims, tile_dims, counts)
     if capacity is None:
-        capacity = 256 if space is None else max(
-            64, split_plan(*space)["depth"] + 2 * width + 8
-        )
+        if plan is not None:
+            live = plan.simulate(width)["live_rows_max"]
+            capacity = max(64, live + max(8, live // 8))
+        else:
+            capacity = 256 if space is None else max(
+                64, split_plan(*space)["depth"] + 2 * width + 8
+            )
+    body, scalar = tk.batch_body, tk.scalar_kernel
+    if plan is not None:
+        body = functools.partial(body, release=plan)
+        scalar = functools.partial(scalar, release=plan)
     if width:
         spec = BatchSpec(
-            tk.batch_body,
+            body,
             width=width,
             prefetch=prefetch,
             drain=tk.batch_drain if prefetch else None,
@@ -591,11 +970,10 @@ def make_forasync_megakernel(
         route = {tk.name: spec}
         scratch = tk.batch_scratch(width)
     else:
-        kernels = [(tk.name, tk.scalar_kernel)]
+        kernels = [(tk.name, scalar)]
         route = None
         scratch = tk.scalar_scratch()
     if space is not None:
-        dims, tile_dims, counts, _ = tile_grid(*space)
         kernels.append(("fa_split", _split_kernel(dims, tile_dims, counts)))
     mk = Megakernel(
         kernels=kernels,
@@ -603,7 +981,7 @@ def make_forasync_megakernel(
         data_specs=tk.data_specs,
         scratch_specs=scratch,
         capacity=capacity,
-        num_values=16,
+        num_values=16 if plan is None else plan.num_values,
         succ_capacity=8,
         interpret=interpret,
         vmem_limit_bytes=_vmem_bytes(tk, scratch),
@@ -616,6 +994,7 @@ def make_forasync_megakernel(
     # The tile space a RECURSIVE build splits, as tile_grid normalises
     # it; None for a FLAT build, which takes any.
     mk.fa_space = None if space is None else (dims, tile_dims)
+    mk.fa_plan = plan
     # Schedule-independence claim: tiles write disjoint slabs, so any
     # pop order yields one output state. The tile SPACE isn't known
     # until a run names (bounds, tile) - run_forasync_device completes
@@ -700,6 +1079,12 @@ def run_forasync_device(
             "mode=RECURSIVE runs on one device: a placement seeds the "
             "per-device rings from flat tiles (use mode=FLAT on a mesh)"
         )
+    if tk.steps > 1 and not recursive:
+        raise ValueError(
+            f"a loop of {tk.steps} steps runs mode=RECURSIVE on one device: "
+            "its tiles of step 1 and later are made on the device, behind "
+            "the splitter's step 0"
+        )
     # A RECURSIVE table is sized by the build, to the live set.
     cap = capacity or (None if recursive else max(64, total + 8))
     if mk is not None and getattr(mk, "verify", False) or (
@@ -737,6 +1122,12 @@ def run_forasync_device(
                 f"mode={mode!r} over {want} disagrees with the prebuilt "
                 f"megakernel, built for {getattr(mk, 'fa_space', None)} "
                 "(make_forasync_megakernel's space=; None is a FLAT build)"
+            )
+        plan = getattr(mk, "fa_plan", None)
+        if (plan.tk if plan else None) is not (tk if tk.steps > 1 else None):
+            raise ValueError(
+                f"the prebuilt megakernel was built for another loop's "
+                f"steps than this TileKernel's ({tk.steps})"
             )
         kernel = mk
     else:
@@ -778,17 +1169,32 @@ def run_forasync_device(
         with span("fa.seed"):
             b = TaskGraphBuilder()
             (seed_root if recursive else seed_tiles)(b, bounds, tile)
+        plan = kernel.fa_plan
         with span("fa.run"):
-            _, data_out, info = kernel.run(b, data=dict(data), fuel=fuel)
+            vals, data_out, info = kernel.run(
+                b, data=dict(data), fuel=fuel,
+                ivalues=None if plan is None else plan.presets(),
+            )
         # info is the dict stats_dict() copies: the loop's own counters
         # ride both.
         info["forasync"] = {
             "mode": mode,
-            "tiles": total,
+            "tiles": total * tk.steps,
             "splits": total - 1 if recursive else 0,
             "capacity": kernel.capacity,
             "live_rows_max": info["allocated"],
         }
+        if plan is not None:
+            # Tiles a dependence made, the decrements that led there,
+            # batch rounds that held tiles of several steps and the most
+            # steps one held apart: the kernel's own words (StepPlan).
+            info["forasync"].update(
+                steps=tk.steps,
+                released=int(vals[V_RELEASED]),
+                decrements=int(vals[V_DECREMENTS]),
+                mixed_rounds=int(vals[V_MIXED]),
+                step_skew_max=int(vals[V_SKEW]),
+            )
         return data_out, info
 
     p = resolve_placement(placement)

@@ -448,6 +448,130 @@ def stencil_data(H: int, W: int, seed: int = 0):
     return gin, np.zeros((H, W), np.int32)
 
 
+# Halo of the time-stepped layout: a whole (8, 128) vector tile a side.
+JAC_HR, JAC_HC = 8, 128
+# What a tile of a step awaits of the step before: itself and the four
+# tiles it shares an edge with, as offsets in tile units.
+JAC_AWAITS = ((0, 0), (-1, 0), (1, 0), (0, -1), (0, 1))
+
+
+def jacobi_loop(H: int, W: int, th: int = 8, tw: int = 128, steps: int = 1,
+                awaits=JAC_AWAITS):
+    """``steps`` time steps of ``stencil_loop``'s 5-point sum (int32,
+    wrapping) in ONE layout, read and written in place: the buffer
+    ``grid`` is ``(2, H + 16, W + 256)`` int32, two planes that each carry
+    the (H, W) interior at ``[8:H+8, 128:W+128]`` inside a zero halo of one
+    (8, 128) vector tile a side. Step s reads plane ``s & 1`` and writes
+    the interior of plane ``(s + 1) & 1``; the halo is never stored to, so
+    it stays zero. The caller hands step 0's grid in plane 0 and finds the
+    grid after the last step in plane ``steps & 1``
+    (``jacobi_result``). Returns ``(tile_kernel, bounds, tile)``.
+
+    A tile loads FIVE aligned pieces into one VMEM staging buffer of
+    ``(th + 16, tw + 256)``: itself, an 8-row strip above and below, a
+    128-column strip left and right (31 % over the tile at (256, 1024)),
+    and slices its neighbourhood out of the staged value. No corners: the
+    aligned superset's corners lie in the diagonal neighbours' tiles, which
+    a 5-point tile does not await, so loading them would read what a tile
+    of the next step may be overwriting (``check_tile_windows`` refuses
+    it), and awaiting nine tiles instead of five to load 2 % more that
+    ``compute`` never reads would tie the front tighter for nothing.
+
+    ``awaits`` is the declaration ``TileKernel`` takes; the default is what
+    the five pieces need. Tests hand in a smaller set to see a missed
+    dependence caught."""
+    from .forasync_tier import Slab, TileKernel
+
+    if th % 8 or tw % 128:
+        raise ValueError(
+            f"stencil tiles must be whole (8, 128) tiles, got ({th}, {tw})"
+        )
+    R, C = JAC_HR, JAC_HC
+    grid = jax.ShapeDtypeStruct((2, H + 2 * R, W + 2 * C), jnp.int32)
+
+    def compute(ins):
+        v = ins["v"]  # rows [lo0 - 8, lo0 + th + 8) x cols [lo1 - 128, ...)
+        return {
+            "vout": (
+                v[R:R + th, C:C + tw] + v[R - 1:R + th - 1, C:C + tw]
+                + v[R + 1:R + th + 1, C:C + tw]
+                + v[R:R + th, C - 1:C + tw - 1]
+                + v[R:R + th, C + 1:C + tw + 1]
+            )
+        }
+
+    def step(a):
+        # A loop of one step carries no step word: its tiles are step 0's.
+        return a[4] if len(a) > 4 else 0
+
+    def piece(name, r0, nr, c0, nc):
+        # The piece of the tile's padded neighbourhood that starts (r0, c0)
+        # from the neighbourhood's corner, which in buffer coordinates is
+        # the tile's own loop corner (the halo shifts both by one tile).
+        def index(a):
+            row = pl.multiple_of(a[1], 8) + r0
+            col = pl.multiple_of(a[2], 128) + c0
+            return step(a) & 1, pl.ds(row, nr), pl.ds(col, nc)
+
+        return Slab(name, "grid", index, (nr, nc), into="v",
+                    at=(pl.ds(r0, nr), pl.ds(c0, nc)))
+
+    def out_index(a):
+        row = pl.multiple_of(a[1], 8) + R
+        col = pl.multiple_of(a[2], 128) + C
+        return (step(a) + 1) & 1, pl.ds(row, th), pl.ds(col, tw)
+
+    tk = TileKernel(
+        loads=[
+            piece("c", R, th, C, tw),
+            piece("n", 0, R, C, tw),
+            piece("s", R + th, R, C, tw),
+            piece("w", R, th, 0, C),
+            piece("e", R, th, C + tw, C),
+        ],
+        stores=[Slab("vout", "grid", out_index, (th, tw))],
+        compute=compute,
+        data_specs={"grid": grid},
+        staging={"v": (th + 2 * R, tw + 2 * C)},
+        name="fa_jacobi",
+        steps=steps,
+        awaits=awaits if steps > 1 else (),
+    )
+    return tk, [H, W], [th, tw]
+
+
+def jacobi_data(H: int, W: int, seed: int = 0) -> np.ndarray:
+    """``jacobi_loop``'s ``grid``: plane 0's interior uniform in
+    [0, 2^20) from the seed (``stencil_data``'s values), plane 1's full of
+    -1 (no value a step leaves behind by accident), both halos zero."""
+    R, C = JAC_HR, JAC_HC
+    rng = np.random.default_rng(seed)
+    g = np.zeros((2, H + 2 * R, W + 2 * C), np.int32)
+    g[0, R:R + H, C:C + W] = rng.integers(
+        0, 1 << 20, size=(H, W), dtype=np.int32
+    )
+    g[1, R:R + H, C:C + W] = -1
+    return g
+
+
+def jacobi_result(grid, steps: int):
+    """The (H, W) interior after the last step, out of ``grid`` as the
+    loop left it."""
+    R, C = JAC_HR, JAC_HC
+    return grid[steps & 1, R:grid.shape[1] - R, C:grid.shape[2] - C]
+
+
+def jacobi_reference(interior: np.ndarray, steps: int) -> np.ndarray:
+    """Numpy oracle: the 5-point sum with a zero halo applied ``steps``
+    times to an (H, W) int32 interior, wrapping as the device does."""
+    cur = np.asarray(interior, np.int32)
+    for _ in range(steps):
+        p = np.pad(cur, 1)
+        cur = (p[1:-1, 1:-1] + p[:-2, 1:-1] + p[2:, 1:-1]
+               + p[1:-1, :-2] + p[1:-1, 2:])
+    return cur
+
+
 def map_loop(T: int, th: int = 8, tw: int = 128):
     """Map-style batched-apply loop (the batched-inference shape): block
     t of the (T, th, tw) int32 input maps elementwise through

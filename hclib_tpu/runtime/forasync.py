@@ -27,8 +27,11 @@ returns ``(data_out, info)``. Both modes run there: FLAT stages one
 descriptor a tile from the host; RECURSIVE stages one range descriptor and
 a split kind on the device halves it, at tile boundaries, down to the same
 tiles, so a loop of more tiles than the task table has rows still runs.
-The device tier requires tiles that divide the bounds exactly (slab shapes
-are static).
+A ``TileKernel`` that declares ``steps=`` and ``awaits=`` runs that many
+time steps in the one call (RECURSIVE only): a tile of a later step is
+made on the device when the last tile it awaits of the step before has
+stored, and there is no barrier between steps. The device tier requires
+tiles that divide the bounds exactly (slab shapes are static).
 """
 
 from __future__ import annotations
@@ -205,7 +208,9 @@ def forasync(
     ``dist_func`` doubles as the mesh placement, ``mode=RECURSIVE`` makes
     the tiles on the device from one range descriptor, and extra keywords
     (``data=``, ``width=``, ``mesh=``, ...) forward to
-    ``run_forasync_device``, whose ``(data_out, info)`` is returned.
+    ``run_forasync_device``, whose ``(data_out, info)`` is returned. The
+    number of time steps and what a tile awaits are the ``TileKernel``'s
+    own (``steps=``, ``awaits=``): several steps need ``mode=RECURSIVE``.
     """
     if mode not in (FLAT, RECURSIVE):
         raise ValueError(f"unknown forasync mode {mode!r}")
@@ -216,6 +221,12 @@ def forasync(
             raise ValueError(
                 "place='device' needs an explicit tile= (auto-tile is a "
                 "host-worker-count policy; device tiles size the slabs)"
+            )
+        if getattr(fn, "steps", 1) > 1 and mode != RECURSIVE:
+            raise ValueError(
+                f"a TileKernel of {fn.steps} steps needs mode=RECURSIVE: "
+                "step 0 is the splitter's, later steps are made on the "
+                "device behind it"
             )
         if not blocking:
             raise ValueError(

@@ -237,6 +237,33 @@ def _forasync_hbm(sh):
         tk, width=8, interpret=False, space=(bounds, tile)), sh)
 
 
+def _jacobi_steps(sh):
+    """The cell jacobi-dep-hbm's program (benchmarks/configs/
+    jacobi-taskdep.json): eight time steps of 32768 x 32768 in (256, 1024)
+    tiles at width 8, both planes of the grid (8.66 GB) in ONE buffer on
+    the chip, donated and written in place; the table sized from the
+    replayed schedule, 8,192 countdowns in the value slots."""
+    from hclib_tpu.device.forasync_tier import make_forasync_megakernel
+    from hclib_tpu.device.workloads import jacobi_loop
+
+    tk, bounds, tile = jacobi_loop(32768, 32768, 256, 1024, steps=8)
+    mk = make_forasync_megakernel(tk, width=8, interpret=False,
+                                  space=(bounds, tile))
+    live = mk.fa_plan.simulate(8)["live_rows_max"]
+    assert live < mk.capacity < 128 and mk.read_only == ()
+    assert mk.num_values == 8 + 2 * 4096
+    compiled = _compile_mk(mk, sh, on_device=["grid"])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * 2 * 32784 * 33024
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert _whole_copies(compiled, mk) == []
+    # the scalar arm and the tutorial's size
+    tk, bounds, tile = jacobi_loop(64, 1024, steps=3)
+    for width in (8, 0):
+        _compile_mk(make_forasync_megakernel(
+            tk, width=width, interpret=False, space=(bounds, tile)), sh)
+
+
 def _serve_stream(sh, delta=False):
     """chip_smoke's serve phase: three tenants, egress mailbox, telemetry
     (the tenant poll fetches row ``c`` from slot ``c`` modulo the region)."""
@@ -342,6 +369,7 @@ KERNELS = {
     f.__name__.lstrip("_"): f
     for f in (_fib_scalar, _fib_batch, _uts_t1l, _cholesky_8192, _sw_fused,
               _sw_wave, _forasync_1d, _forasync_2d, _forasync_hbm,
+              _jacobi_steps,
               _serve_stream, _serve_stream_delta,
               _frontier, _search, _dyngraph, _bnb)
 }

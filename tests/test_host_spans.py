@@ -276,7 +276,8 @@ FRONT = {"source": "program_span", "layer": "front door",
          # the open-loop cell of PR 44 joined the lists
          "workloads": [SERVE, "serve-open-steady"]}
 STAGING = {"layer": "host staging", "moves": "solve_ms",
-           "workloads": [CHOL, SW, FA, "g500-bfs-search"]}
+           "workloads": [CHOL, SW, FA, "g500-bfs-search",
+                         "jacobi-dep-hbm"]}
 # name: (reducer, args, unit, the rest of the entry, value on HOST below)
 METRICS = {
     "launch_us": ("span_mean",

@@ -168,6 +168,26 @@ def _programs() -> List[Tuple[str, "callable"]]:
 
     progs.append(("forasync:jacobi2d", jacobi))
 
+    # The same sweep advanced three time steps in one launch, tiles
+    # awaiting their five neighbours of the step before: the build, the
+    # store windows, read-before-overwrite between steps, and K orders
+    # that honour the declared awaits and no more.
+    def jacobi_steps() -> AnalysisReport:
+        from hclib_tpu.analysis import certify_tile_schedule
+        from hclib_tpu.device.workloads import jacobi_loop
+
+        tk, bounds, tile = jacobi_loop(32, 512, 8, 128, steps=3)
+        mk = make_forasync_megakernel(
+            tk, width=4, interpret=True, space=(bounds, tile))
+        rep = verify_megakernel(mk, raise_on_error=False)
+        check_tile_windows(tk, bounds, tile, report=rep)
+        rep.certificates = {tk.name: certify_tile_schedule(
+            tk, bounds, tile, report=rep, raise_on_error=False,
+        )}
+        return rep
+
+    progs.append(("forasync:jacobi-steps", jacobi_steps))
+
     # The mesh stress configuration's migratability claim (stress.
     # forest_steal: fib on the sharded exchange) - audited, with the
     # workload's own suppression annotation honored.

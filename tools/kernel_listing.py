@@ -11,7 +11,7 @@ PR 41 / 45 / 46): read the listing before and after a change to the
 scheduler, and count its paths with ``tools/listing_paths.py``.
 
     python tools/kernel_listing.py <outdir> [--tree DIR]
-                                   [--kernel fib|forest|search]
+                                   [--kernel fib|forest|search|forasync|jacobi]
                                    [--capacity N] [--if-conversion]
 
 ``--tree`` is the checkout to compile (default: this one; give a copy of
@@ -48,7 +48,11 @@ stands the maker: the ``LB:`` at ``0x1c4f`` is ``_make_kernel.step``'s
 ``while_loop`` (``--loop 0x1c4f --take 0x1c8b``: 98 bundles an iteration,
 ``spawn`` and ``take`` predicated into every one, the gather jumped). The
 addresses move with every change to the kernel: find them again by the
-order of the branches, not by their numbers.
+order of the branches, not by their numbers. ``forasync`` is
+``forasync-2d-hbm``'s build and ``jacobi`` ``jacobi-dep-hbm``'s (PR 51:
+eight steps, the release of a finished tile under the store wave of each
+copy of the batch body), both as ``tests/test_chip_compile.py`` compiles
+them, grids on the device.
 The child's output goes to ``<outdir>/compile.log``; with
 ``--if-conversion`` the compiler's if-conversion pass logs into it which
 ``pl.when`` / ``lax.cond`` regions it predicated and which it kept as
@@ -213,6 +217,29 @@ def _compile_search(capacity: int) -> None:
     )
 
 
+def _compile_loop(loop: str, capacity: int, on_device, **kw) -> None:
+    """A RECURSIVE forasync build of ``workloads.<loop>`` over the cells'
+    32768 x 32768 grid in (256, 1024) tiles at width 8."""
+    from hclib_tpu.device import workloads
+    from hclib_tpu.device.forasync_tier import make_forasync_megakernel
+
+    tk, bounds, tile = getattr(workloads, loop)(
+        32768, 32768, 256, 1024, **kw)
+    _compile_mk(
+        make_forasync_megakernel(tk, width=8, capacity=capacity,
+                                 interpret=False, space=(bounds, tile)),
+        1 << 22, on_device=on_device,
+    )
+
+
+def _compile_forasync(capacity: int) -> None:
+    _compile_loop("stencil_loop", capacity, ("gin", "gout"))
+
+
+def _compile_jacobi(capacity: int) -> None:
+    _compile_loop("jacobi_loop", capacity, ("grid",), steps=8)
+
+
 # kernel -> (its name in the trace and in the dump's file names (PERF.md
 # section 3: the jit round a Megakernel's pallas_call is named
 # tpu_custom_call, the mesh kernel resident_mesh), its compile, the
@@ -221,6 +248,8 @@ KERNELS = {
     "fib": ("tpu_custom_call", _compile_fib, 768),
     "forest": ("resident_mesh", _compile_forest, 640),
     "search": ("tpu_custom_call", _compile_search, 128),
+    "forasync": ("tpu_custom_call", _compile_forasync, 64),
+    "jacobi": ("tpu_custom_call", _compile_jacobi, 99),
 }
 
 
@@ -230,7 +259,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tree", default=os.path.dirname(_HERE))
     ap.add_argument("--kernel", choices=sorted(KERNELS), default="fib")
     ap.add_argument("--capacity", type=int, default=None,
-                    help="table rows (default: the cell's, 768 / 640 / 128)")
+                    help="table rows (default: the cell's, 768 / 640 / 128 / 64 / 99)")
     ap.add_argument("--if-conversion", action="store_true")
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     a = ap.parse_args(argv)

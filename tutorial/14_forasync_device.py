@@ -25,6 +25,12 @@ lowers the same construct onto the DEVICE (device/forasync_tier.py):
   so the splitter is paced by its consumer and the table holds the live
   set (two batches of tiles, a range a level of the recursion) however
   many tiles the loop has: 256 tiles run through 64 rows below.
+- **Time steps are dependences, not barriers.** A ``TileKernel`` that
+  declares ``steps=`` and ``awaits=`` advances its grid several time
+  steps in ONE call: a tile of step t+1 is made on the device when the
+  last tile it awaits of step t has stored (a countdown a tile in SMEM
+  that a finishing tile decrements), and goes straight onto the lane, so
+  a tile of step t+1 may run while tiles of step t have not.
 - **Placement is data, not code.** On a mesh, a JSON placement
   descriptor (or a classic dist func) resolved against
   ``locality_graphs/*.json`` maps each flat tile to a device, seeding
@@ -53,6 +59,10 @@ import hclib_tpu as hc  # noqa: E402
 from hclib_tpu.device.forasync_tier import run_forasync_device  # noqa: E402
 from hclib_tpu.device.megakernel import C_EXECUTED  # noqa: E402
 from hclib_tpu.device.workloads import (  # noqa: E402
+    jacobi_data,
+    jacobi_loop,
+    jacobi_reference,
+    jacobi_result,
     map_body,
     map_data,
     map_loop,
@@ -151,6 +161,31 @@ def part_two_b_recursive():
           f"{t['batch_occupancy']:.2f}, {t['prefetch_hits']} prefetch hits")
 
 
+def part_two_c_time_steps():
+    """The same stencil advanced four time steps in one call: two planes
+    in one buffer, each tile awaiting its own and its four edge
+    neighbours' tiles of the step before, no barrier between steps;
+    checked against the numpy oracle applied as many times."""
+    HS, WS, steps = 128, 256, 4  # 16 x 2 tiles: tall enough to overlap
+    tk, bounds, tile = jacobi_loop(HS, WS, steps=steps)
+    grid = jacobi_data(HS, WS, seed=14)
+    d, info = hc.forasync(
+        tk, bounds, tile=tile, mode=hc.RECURSIVE, place="device",
+        data={"grid": grid}, width=2,
+    )
+    got = jacobi_result(np.asarray(d["grid"]), steps)
+    want = jacobi_reference(jacobi_result(grid, 0), steps)
+    assert np.array_equal(got, want)
+    fa = info["forasync"]
+    assert fa["released"] == (steps - 1) * 32 and fa["mixed_rounds"] > 0
+    assert fa["live_rows_max"] < fa["capacity"] < fa["tiles"]
+    print(f"  {steps} steps: {fa['tiles']} tiles, {fa['released']} made by "
+          f"the last of their {fa['decrements']} awaited stores, "
+          f"{fa['mixed_rounds']} of {info['tiers']['batch_rounds']} rounds "
+          f"held tiles of several steps (at most {fa['step_skew_max']} "
+          f"apart), {fa['live_rows_max']} rows live at most")
+
+
 def part_three_mesh_placement():
     """Placement as data: a JSON descriptor seeds the per-device ready
     rings; the machine graph orders the steal scan; a deliberately
@@ -192,6 +227,8 @@ if __name__ == "__main__":
     part_two_map_loop()
     print("recursive splitting on the device:")
     part_two_b_recursive()
+    print("time steps with tile dependences, one call:")
+    part_two_c_time_steps()
     print("mesh placement + stealing:")
     part_three_mesh_placement()
     print("lesson 14 OK")
