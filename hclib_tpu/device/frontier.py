@@ -803,7 +803,13 @@ def pagerank_kernel(reps: int = 64,
 # each naming the maker as its successor and pushed straight onto the
 # EXPAND lane, which runs them in the order they were made (so the tree is
 # the queue-order tree: a vertex's parent is the first of the queue to
-# name it); then it re-arms itself
+# name it). A trip of its loop is a VERTEX: the trip that takes a vertex
+# makes its blocks in an inner loop whose body is the ``spawn`` and nothing
+# else, the loop's state rides in registers and is stored once a call, and
+# the gather and the level logic stand behind one branch a group takes
+# once (ISSUE 52 read the loop off the compiler's listing: a trip a thing,
+# with everything predicated into it, was 98 bundles an EXPAND and 98 a
+# vertex; this is 38 and 43). Then it re-arms itself
 # (``ctx.become``) on as many predecessors as it made, and runs again
 # when the last of them has retired. It never crosses the end of a level
 # with an EXPAND of that level outstanding, so the order is exactly
@@ -831,16 +837,13 @@ S_LEVEL_END = 4  # queue position at which the level in hand ends
 S_LEVEL = 5     # its number
 S_FRONT_MAX = 6  # the longest level
 S_GRP_N = 7     # vertices in the gathered group
-S_GRP_I = 8     # the one in hand
-S_CUR_BLK = 9   # its next block
-S_CUR_END = 10  # one past its last
-S_CUR_LEFT = 11  # its entries not yet given to an EXPAND
+S_GRP_K = 8     # those of them the maker is done with
+S_CUR_DONE = 9  # blocks of the next one already given to EXPANDs
 S_RD_ROW = 12   # queue row held in sr_qrd, + 1 (0: none)
 S_HBM_RD = 13   # state words read from HBM (table rows, queue rows)
 S_HBM_WR = 14   # state words written to HBM (queue rows)
-S_MADE = 15     # EXPANDs this maker call made
-S_STOP = 16     # this maker call is over
 S_HITS = 17     # sub-groups of entries that went into relax
+S_TRIPS = 18    # trips of the maker's loop: a vertex each
 S_LSTART = 24   # + level: the queue position its level starts at
 S_WORDS = S_LSTART + SR_LEVELS
 
@@ -1015,16 +1018,6 @@ def _make_kernel(graph_n: int, budget: int) -> Callable:
     filter and enqueues the key, and 0 ever after."""
     nwords = SR_LEAD + _bits_rows(graph_n) * EBLOCK
 
-    def take(ctx, i) -> None:
-        """Vertex ``i`` of the gathered group becomes the one in hand."""
-        iv, grp = ctx.ivalues, ctx.scratch["sr_grp"]
-        first, deg = grp[4 * i + 1], grp[4 * i + 2]
-        iv[S_GRP_I] = i
-        iv[S_CUR_BLK] = first
-        # A shift, not a divide: the scalar core has no cheap one.
-        iv[S_CUR_END] = first + ((deg + EBLOCK - 1) >> EBLOCK_SHIFT)
-        iv[S_CUR_LEFT] = deg
-
     def gather(ctx, qh, g) -> None:
         """The next ``g`` queue vertices and their table words into
         ``sr_grp``: every row DMA in flight before the first wait."""
@@ -1052,12 +1045,11 @@ def _make_kernel(graph_n: int, budget: int) -> Callable:
 
         iv[S_HBM_RD] = iv[S_HBM_RD] + g * EBLOCK
         iv[S_QHEAD] = qh + g
-        iv[S_GRP_N] = g
-        take(ctx, 0)
 
     def kernel(ctx) -> None:
         iv = ctx.ivalues
         bits = ctx.scratch["sr_bits"]
+        grp = ctx.scratch["sr_grp"]
 
         @pl.when(ctx.arg(0) != 0)
         def _():
@@ -1075,56 +1067,77 @@ def _make_kernel(graph_n: int, budget: int) -> Callable:
             iv[S_FRONT_MAX] = 1
             ctx.set_arg(ctx.idx, 0, 0)
 
-        iv[S_MADE] = 0
-        iv[S_STOP] = 0
+        def refill(made):
+            """The group is read out: ``(k, n, over)`` of the next one,
+            gathered, or of none and the call over. Where the level's
+            queue is read out too and EXPANDs of it are still to run, the
+            next level's end is not known: the call is over. With none the
+            level is whole: open the next, or end the search."""
+            qh, lend, qt = iv[S_QHEAD], iv[S_LEVEL_END], iv[S_QTAIL]
 
-        def step(_):
-            in_hand = iv[S_CUR_BLK] < iv[S_CUR_END]
-            more = iv[S_GRP_I] + 1 < iv[S_GRP_N]
-            qh, end, qt = iv[S_QHEAD], iv[S_LEVEL_END], iv[S_QTAIL]
-
-            @pl.when(in_hand)
-            def _():
-                blk, left = iv[S_CUR_BLK], iv[S_CUR_LEFT]
-                cnt = jnp.minimum(left, EBLOCK)
-                v = ctx.scratch["sr_grp"][4 * iv[S_GRP_I]]
-                ctx.spawn(FR_EXPAND, [v, blk, v, cnt], succ0=ctx.idx,
-                          nargs=4)
-                iv[S_CUR_BLK] = blk + 1
-                iv[S_CUR_LEFT] = left - cnt
-                iv[S_MADE] = iv[S_MADE] + 1
-
-            @pl.when(jnp.logical_not(in_hand) & more)
-            def _():
-                take(ctx, iv[S_GRP_I] + 1)
-
-            idle = jnp.logical_not(in_hand | more)
-
-            @pl.when(idle & (qh < end))
-            def _():
-                gather(ctx, qh, jnp.minimum(end - qh, SR_GROUP))
-
-            # The level's queue is read out. With EXPANDs of it still to
-            # run, the next level's end is not known: stop here. With none,
-            # the level is whole: open the next, or end the search.
-            whole = idle & (qh == end) & (iv[S_MADE] == 0)
-
-            @pl.when(whole & (qt > end))
+            @pl.when((qh == lend) & (made == 0) & (qt > lend))
             def _():
                 lvl = iv[S_LEVEL] + 1
                 iv[S_LEVEL] = lvl
-                iv[S_LSTART + jnp.minimum(lvl, SR_LEVELS - 1)] = end
+                iv[S_LSTART + jnp.minimum(lvl, SR_LEVELS - 1)] = lend
                 iv[S_LEVEL_END] = qt
-                iv[S_FRONT_MAX] = jnp.maximum(iv[S_FRONT_MAX], qt - end)
+                iv[S_FRONT_MAX] = jnp.maximum(iv[S_FRONT_MAX], qt - lend)
 
-            @pl.when(idle & (qh == end) & ((iv[S_MADE] > 0) | (qt == end)))
+            g = jnp.minimum(iv[S_LEVEL_END] - qh, SR_GROUP)
+
+            @pl.when(g > 0)
             def _():
-                iv[S_STOP] = 1
+                gather(ctx, qh, g)
 
-            return (iv[S_MADE] < budget) & (iv[S_STOP] == 0)
+            return jnp.int32(0), g, g == 0
 
-        jax.lax.while_loop(lambda go: go, step, jnp.bool_(True))
-        made = iv[S_MADE]
+        def trip(s):
+            """One vertex, the group's ``k``-th: its blocks from the
+            ``done``-th on become EXPANDs, in block order, while the table
+            has room. A vertex cut short stays the ``k``-th."""
+            k, n, done, made, trips, _ = s
+            k, n, over = jax.lax.cond(
+                k >= n, lambda: refill(made),
+                lambda: (k, n, jnp.bool_(False)),
+            )
+            v, first, deg = grp[4 * k], grp[4 * k + 1], grp[4 * k + 2]
+            # A shift, not a divide: the scalar core has no cheap one.
+            end = first + ((deg + EBLOCK - 1) >> EBLOCK_SHIFT)
+            blk = first + done
+            upto = jnp.where(
+                over, blk, jnp.minimum(end, blk + (budget - made))
+            )
+
+            def expand(c):
+                b, left = c
+                cnt = jnp.minimum(left, EBLOCK)
+                ctx.spawn(FR_EXPAND, [v, b, v, cnt], succ0=ctx.idx, nargs=4)
+                return b + 1, left - cnt
+
+            jax.lax.while_loop(
+                lambda c: c[0] < upto, expand,
+                (blk, deg - (done << EBLOCK_SHIFT)),
+            )
+            made = made + (upto - blk)
+            whole = (upto == end) & jnp.logical_not(over)
+            return (
+                k + whole.astype(jnp.int32), n,
+                jnp.where(whole, 0, upto - first), made, trips + 1,
+                (made < budget) & jnp.logical_not(over),
+            )
+
+        # The loop's state rides in its carry: loaded here and stored
+        # below, once a call, so a call that ``budget`` cuts in the middle
+        # of a vertex goes on there.
+        slots = (S_GRP_K, S_GRP_N, S_CUR_DONE)
+        *hand, made, trips, _ = jax.lax.while_loop(
+            lambda s: s[-1], trip,
+            (*(iv[x] for x in slots), jnp.int32(0), iv[S_TRIPS],
+             jnp.bool_(True)),
+        )
+        for slot, x in zip(slots, hand):
+            iv[slot] = x
+        iv[S_TRIPS] = trips
         iv[S_EXPANDS] = iv[S_EXPANDS] + made
 
         @pl.when(made > 0)
@@ -1739,6 +1752,7 @@ class GraphSearch:
                                                              SR_LEVELS)]
             ],
             "expands": int(iv[S_EXPANDS]),
+            "maker_trips": int(iv[S_TRIPS]),
             "frontier_max": int(iv[S_FRONT_MAX]),
             "live_rows_max": info["allocated"],
             "capacity": self.mk.capacity,

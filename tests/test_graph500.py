@@ -208,6 +208,62 @@ def test_a_frontier_many_times_the_table_never_overflows():
     assert info2["search"] == books
 
 
+# ISSUE 52: a trip of the maker's loop is a VERTEX (its blocks are made in
+# an inner loop of the trip that takes it), counted in ``maker_trips``; a
+# loop that spends a trip a thing (an EXPAND, a take, a gather) would count
+# ``expands + reached`` and more.
+
+
+@pytest.mark.parametrize("seed,scale,capacity", [(48, 7, 32), (9, 9, 64)])
+def test_a_trip_of_the_maker_is_a_vertex(seed, scale, capacity):
+    n = 1 << scale
+    u, v = ref.edge_list(seed, scale)
+    s = GraphSearch(Graph.undirected(n, u, v), width=4, capacity=capacity,
+                    interpret=True)
+    parent, info = s.bfs(int(ref.search_keys(seed, n, u, v)[0]))
+    books = info["search"]
+    assert books["expands"] == s.blocks_of(parent) > books["reached"] > 64
+    # The maker's calls are the search's scalar-tier tasks; each opens
+    # with the trip that finds where the one before stopped.
+    calls = info["tiers"]["scalar_tasks"]
+    assert books["reached"] <= books["maker_trips"] <= books["reached"] + calls
+    assert books["maker_trips"] < books["expands"] + books["reached"]
+
+
+def _star(leaves):
+    """``(u, v)``: a hub (vertex 1), its leaves, a path through them."""
+    leaf = np.arange(2, 2 + leaves, dtype=np.int32)
+    u = np.concatenate([np.full(leaves, 1, np.int32), leaf[:-1]])
+    return u, np.concatenate([leaf, leaf[1:]])
+
+
+@pytest.mark.parametrize("capacity,key", [(16, 1), (16, 2), (12, 700)])
+def test_a_call_cut_in_the_middle_of_a_vertex_goes_on_there(capacity, key):
+    # The hub's blocks are many times what one maker call may make
+    # (``capacity - SR_SPARE``): call after call is cut in the middle of
+    # the hub and the next one goes on at the block it stopped at.
+    budget = capacity - SR_SPARE
+    n = 4096
+    u, v = _star(3 * EBLOCK * budget + 100)
+    g = Graph.undirected(n, u, v)
+    s = GraphSearch(g, width=4, capacity=capacity, interpret=True)
+    parent, info = s.bfs(key)
+    books = info["search"]
+    assert not info["overflow"] and info["pending"] == 0
+    assert books["live_rows_max"] < capacity
+    held = ref.search_and_validate(n, u, v, key, parent)
+    assert not any(held[r] for r in ref.RULES), held
+    assert held["levels_differ"] == 0
+    assert np.array_equal(parent, _queue_order_tree(g, key))
+    assert books["expands"] == s.blocks_of(parent)
+    assert books["edges"] == 2 * held["component_edges"]
+    hub_blocks = int(g.blk_count[1])
+    assert hub_blocks > 3 * budget
+    calls = info["tiers"]["scalar_tasks"]
+    assert calls >= -(-books["expands"] // budget)
+    assert books["maker_trips"] <= books["reached"] + calls
+
+
 def test_a_task_budget_below_the_search_stalls():
     s = GraphSearch(Graph.undirected(N, U, V), width=4, capacity=32,
                     interpret=True, fuel=16)

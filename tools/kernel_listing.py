@@ -31,22 +31,32 @@ in the bundles in front of it). A group that finds nothing runs from there
 to the first ``sbr.rel`` and its four delay slots, then from that branch's
 target (the ``PF:`` that reloads the counter) to the back-branch and its
 four: 77 + 9 bundles for 16 entries (PR 49; 55 + 7 for 4 before it).
-The scheduler's loop is the widest one (``0xaf .. 0x21bf`` at PR 50, of
-8,916 bundles; a 10-bundle loop that spills the lanes at the exit follows
+The scheduler's loop is the widest one (``0xaf .. 0x21b2`` at PR 52, of
+8,903 bundles; a 10-bundle loop that spills the lanes at the exit follows
 it, which is why ``tools/listing_paths.py`` takes the widest and not the
 last). It has too many paths to list: follow one with ``listing_paths.py
 <listing> --take 0x<branch>,...``. Its head tests the starved phase and
 jumps that phase's copy of the batch body (the first ``sbr.rel`` of the
 loop, ``0xbe``), then the drain phase's (``0xe67``), then the pop
-(``0x1c18``): all three taken is the round that does nothing, 74 bundles.
-The ROUTING path is the pop not jumped and the next branch (``0x1c25``,
+(``0x1c1a``): all three taken is the round that does nothing, 76 bundles.
+The ROUTING path is the pop not jumped and the next branch (``0x1c27``,
 over the scalar ``step``) taken: pop, ``F_FN``, the compare and the
-predicated lane push, ``TS_ROUTED``, the age clock, 90 bundles a row; a
+predicated lane push, ``TS_ROUTED``, the age clock, 92 bundles a row; a
 row the maker spawns no longer runs it (PR 50: ``spawn`` pushes the lane),
 a row the host staged or ``retire()`` released does. Behind that branch
-stands the maker: the ``LB:`` at ``0x1c4f`` is ``_make_kernel.step``'s
-``while_loop`` (``--loop 0x1c4f --take 0x1c8b``: 98 bundles an iteration,
-``spawn`` and ``take`` predicated into every one, the gather jumped). The
+stands the maker (PR 52: a trip of its loop is a VERTEX). The ``LB:`` at
+``0x1c52`` is ``_make_kernel``'s outer ``while_loop``; its first branch
+(``0x1c55``) is taken by a trip whose group still holds a vertex and jumps
+the refill (the level logic and the gather, some 1,200 bundles, once a
+group of sixteen); the next on that path (``0x20f9``) jumps the inner
+loop where the vertex has no block to make. ``--loop 0x1c52 --take
+0x1c55,0x20f9``: 43 bundles a vertex. The inner ``LB:`` at ``0x20fe``, with
+a back-branch of its own at ``0x211f``, is the spawn loop: ``--loop
+0x20fe``: 38 bundles an EXPAND, ``spawn``'s own and two register updates;
+``--loop 0x1c52 --take 0x1c55`` is a one-block vertex, 81. (The parent's
+loop did one thing an iteration, ``spawn``, ``take`` and the level logic
+predicated into every one: 98 bundles an EXPAND and 98 a vertex, ``--loop
+0x1c4f --take 0x1c8b`` on its listing.) The
 addresses move with every change to the kernel: find them again by the
 order of the branches, not by their numbers. ``forasync`` is
 ``forasync-2d-hbm``'s build and ``jacobi`` ``jacobi-dep-hbm``'s (PR 51:
