@@ -104,6 +104,7 @@ from ..runtime import resilience
 from ..runtime.resilience import CancelledError, StallError
 from ..runtime.clockprobe import EpochBracket
 from ..runtime.env import env_bool
+from ..runtime.progcache import building
 from ..runtime.spans import span
 from .descriptor import (
     DESC_WORDS,
@@ -453,6 +454,8 @@ class StreamingMegakernel:
         with self._lock:
             d = dict(self._stats)
         d["stream"] = dict(d["stream"])
+        if self._pc_stats is not None:
+            d["program_cache"] = dict(self._pc_stats)
         if self.tenants is not None:
             d["tenants"] = self.tenants.stats()
             if self.tenants.futures is not None:
@@ -1663,10 +1666,15 @@ class StreamingMegakernel:
                     mk, variant,
                     lambda: self._build_entry(quantum, max_rounds, delta),
                 )
+                fresh[delta] = pc_stats
                 if not delta:
                     self._pc_stats = pc_stats
             return self._jitted[key]
 
+        # The programs built for this stream and not called yet, by
+        # ``delta``: each one's first entry is its build (jax.jit is
+        # lazy), and the ledger's bracket goes around it.
+        fresh: Dict[bool, Dict[str, Any]] = {}
         entry = program(False)
         lay = self._entry_layout()
         delta_pad = np.zeros((RING_DELTA_ROWS, RING_ROW), np.int32)
@@ -1725,32 +1733,37 @@ class StreamingMegakernel:
                     )
             link["entries"] += 1
             link["uploads"] += 1
-            with span("stream.launch"):
-                parts = [host[n].reshape(-1) for n in lay.up]
-                if delta:
-                    link["ring_deltas"] += 1
-                    link["ring_rows_up"] += nrows
-                    # ring_idx: the rows' numbers, then distinct numbers
-                    # past the ring's end (dropped); ring_rows: the rows
-                    # out of the host ring, then the padding.
-                    parts.append(np.concatenate(
-                        [np.arange(lo, lo + k, dtype=np.int32)
-                         for lo, k in wrote]
-                        + [np.arange(self.ring_capacity, self.ring_capacity
-                                     + RING_DELTA_ROWS - nrows,
-                                     dtype=np.int32)]
-                    ))
-                    parts += [ring[lo:lo + k].reshape(-1) for lo, k in wrote]
-                    parts.append(delta_pad[nrows:].reshape(-1))
-                slab, *fed_back = (program(True) if delta else entry)(
-                    np.concatenate(parts), *dev.values(),
-                )
-                if delta:
-                    dev["ring"] = fed_back.pop()
-            dev.update(zip(lay.stays, fed_back))
-            link["downloads"] += 1
-            with span("stream.wait"):  # blocked on the kernel, slab down
-                down = np.array(slab)
+            fn = program(True) if delta else entry
+            with building("stream", fn, fresh.pop(delta, None)):
+                with span("stream.launch"):
+                    parts = [host[n].reshape(-1) for n in lay.up]
+                    if delta:
+                        link["ring_deltas"] += 1
+                        link["ring_rows_up"] += nrows
+                        # ring_idx: the rows' numbers, then distinct
+                        # numbers past the ring's end (dropped);
+                        # ring_rows: the rows out of the host ring, then
+                        # the padding.
+                        parts.append(np.concatenate(
+                            [np.arange(lo, lo + k, dtype=np.int32)
+                             for lo, k in wrote]
+                            + [np.arange(self.ring_capacity,
+                                         self.ring_capacity
+                                         + RING_DELTA_ROWS - nrows,
+                                         dtype=np.int32)]
+                        ))
+                        parts += [ring[lo:lo + k].reshape(-1)
+                                  for lo, k in wrote]
+                        parts.append(delta_pad[nrows:].reshape(-1))
+                    slab, *fed_back = fn(
+                        np.concatenate(parts), *dev.values(),
+                    )
+                    if delta:
+                        dev["ring"] = fed_back.pop()
+                dev.update(zip(lay.stays, fed_back))
+                link["downloads"] += 1
+                with span("stream.wait"):  # blocked on the kernel, slab down
+                    down = np.array(slab)
             return _split(down, lay.down)
 
         def pull(names) -> List[np.ndarray]:

@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..runtime.env import env_bool, env_int, env_raw
+from ..runtime.progcache import building
 from ..runtime.spans import span
 from .descriptor import (
     DESC_WORDS,
@@ -1325,10 +1326,10 @@ class Megakernel:
         # (fuel, stage_all_values, the data buffers that ride the slab)
         # -> the program _build_exec made for them
         self._jitted: Dict[Any, Any] = {}
-        # Last shared_build stats ({hit, build_s, cache_lookup_s}) for
-        # this instance's most recent program build - surfaced as
-        # info['program_cache'] (and the tiers timing gauges) so every
-        # run reports what its program cost to obtain.
+        # shared_build's stats of this instance's most recent program
+        # build, filled by progcache.building at the program's first
+        # call - surfaced as info['program_cache'] so every run reports
+        # what its program cost to obtain.
         self._pc_stats: Optional[Dict[str, Any]] = None
         # Last run()'s info dict (incl. the batched-tier counters), for
         # stats_dict() consumers that don't thread the return value.
@@ -2966,7 +2967,7 @@ class Megakernel:
             # same jitted object, so its first call skips trace/lower
             # entirely. The per-instance dict stays as the L1 (repeat
             # runs on one instance never pay fingerprinting).
-            from ..runtime.progcache import shared_build
+            from ..runtime.progcache import first_call, shared_build
 
             lay = self._exec_layout(riding, given)
             fn, self._pc_stats = shared_build(
@@ -3003,24 +3004,31 @@ class Megakernel:
             # stamps host events with, so device rounds and host spans
             # share one Perfetto timeline.
             t0_ns = _time.monotonic_ns()
-            with span("mk.launch"):
-                launch = jitted
-                if first_build:  # this call traces: see first_call
-                    from ..runtime.progcache import first_call
-
-                    launch = functools.partial(first_call, jitted)
-                packed_dev, tasks_out, ready_out, *rest = launch(*args)
-                # The host's copy of what it reads starts behind the
-                # launch; mk.wait only waits for it.
-                packed_dev.copy_to_host_async()
+            # jax.jit is lazy: the trace, the lowering and the compile
+            # the program cache exists to skip are paid inside a
+            # program's first call, so that call, launch to readback, is
+            # the build the ledger's bracket times (and the cache's
+            # eviction weight).
+            with building(
+                "megakernel", jitted,
+                self._pc_stats if first_build else None,
+            ):
+                with span("mk.launch"):
+                    launch = jitted
+                    if first_build:  # this call traces: see first_call
+                        launch = functools.partial(first_call, jitted)
+                    packed_dev, tasks_out, ready_out, *rest = launch(*args)
+                    # The host's copy of what it reads starts behind the
+                    # launch; mk.wait only waits for it.
+                    packed_dev.copy_to_host_async()
+                with span("mk.wait"):  # the kernel runs inside this span
+                    packed = np.asarray(packed_dev)
+            t1_ns = _time.monotonic_ns()
         # A read-only buffer that crossed alone is the array that went
         # in; every other one is what the program returned.
         held = {**dict(zip(lay.alone, args[1:])),
                 **dict(zip(lay.stays[2:], rest))}
         data_out = {k: held["data:" + k] for k in self.data_specs}
-        with span("mk.wait"):  # the kernel runs inside this span
-            packed = np.asarray(packed_dev)
-        t1_ns = _time.monotonic_ns()
         staging = {
             "uploads": 1 + sum(
                 not isinstance(d, jax.Array) for d in alone.values()
@@ -3029,14 +3037,6 @@ class Megakernel:
             "slab_blocks": list(lay.up),
             "downloads": 1,
         }
-        if first_build and self._pc_stats is not None:
-            if not self._pc_stats["hit"]:
-                # jax.jit is lazy: the trace/lower/compile this cache
-                # exists to skip is paid inside the first entry, so a
-                # MISS folds that first wall (compile + one execution)
-                # into build_s; a hit's first entry rides the already-
-                # traced callable and keeps build_s = 0.
-                self._pc_stats["build_s"] += (t1_ns - t0_ns) / 1e9
         counts_np = packed[:8]
         ivalues_np = packed[8 : 8 + self.num_values]
         off = 8 + self.num_values
@@ -3060,23 +3060,12 @@ class Megakernel:
         }
         if self._pc_stats is not None:
             # How this run's program was obtained (the build that
-            # produced the executable, not this entry): cache hit flag
-            # plus build_s vs cache_lookup_s - the trade the program
-            # cache exists to win. Mirrored into the tier gauges below
-            # so MetricsRegistry.add_run_info exports it beside
-            # lane_occupancy.
+            # produced the executable, not this entry): the ledger's
+            # row of it, cache hit flag and build_s vs cache_lookup_s -
+            # the trade the program cache exists to win.
             info["program_cache"] = dict(self._pc_stats)
         if self.batch_specs:
             info["tiers"] = self.decode_tier_stats(tstats_np)
-            if self._pc_stats is not None:
-                # Host-side build-cost gauges ride the tier dict (the
-                # add_run_info export path). Cross-arm tier equality
-                # tests compare device counters only - these two keys
-                # are wall-clock noise by nature.
-                info["tiers"]["build_s"] = self._pc_stats["build_s"]
-                info["tiers"]["cache_lookup_s"] = (
-                    self._pc_stats["cache_lookup_s"]
-                )
         quiesced = False
         if self.checkpoint:
             qstat = packed[off : off + 8]

@@ -168,6 +168,7 @@ from .descriptor import (
     TaskGraphBuilder,
     ring_slot,
 )
+from ..runtime.progcache import building
 from ..runtime.resilience import DeviceFaultPlan, StallError
 from .inject import region_slot
 from .tenants import (
@@ -2663,23 +2664,21 @@ class ResidentKernel:
                 lambda: self._build(quantum, max_rounds, hop_bits),
             )
         t0_ns = time.monotonic_ns()
-        iv_o, data_o, info = execute_partitions(
-            mk, self.mesh, ndev, self._jitted[key], builders, data, ivalues,
-            with_rounds=True,
-            mutate=bump_waits if resume_state is None else None,
-            extra_inputs=extra, state=resume_state,
-            keep_inputs=self.checkpoint,
-        )
-        t1_ns = time.monotonic_ns()
-        if (
-            first_build and self._pc_stats is not None
-            and not self._pc_stats["hit"]
+        # jax.jit is lazy: the first call of a program pays its trace,
+        # lowering and compile (the Megakernel._execute discipline), so
+        # the ledger's bracket goes around it.
+        with building(
+            "resident", self._jitted[key],
+            self._pc_stats if first_build else None,
         ):
-            # jax.jit is lazy: a cache MISS pays trace/lower/compile
-            # inside this first entry (the Megakernel._execute
-            # discipline), so fold the first wall into build_s before
-            # it is reported.
-            self._pc_stats["build_s"] += (t1_ns - t0_ns) / 1e9
+            iv_o, data_o, info = execute_partitions(
+                mk, self.mesh, ndev, self._jitted[key], builders, data,
+                ivalues, with_rounds=True,
+                mutate=bump_waits if resume_state is None else None,
+                extra_inputs=extra, state=resume_state,
+                keep_inputs=self.checkpoint,
+            )
+        t1_ns = time.monotonic_ns()
         if self._pc_stats is not None:
             info["program_cache"] = dict(self._pc_stats)
         info["rounds"] = info.pop("steal_rounds")

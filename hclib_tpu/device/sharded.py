@@ -24,7 +24,6 @@ termination becomes a collective.
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 import jax
@@ -32,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..runtime.progcache import building
 from ..runtime.spans import span
 from .descriptor import (
     DESC_WORDS,
@@ -619,21 +619,17 @@ class ShardedMegakernel:
                     else self._build(fuel)
                 ),
             )
-        t0_ns = time.monotonic_ns()
-        iv_o, data_o, info = execute_partitions(
-            self.mk, self.mesh, self.ndev, self._jitted[key], builders,
-            data, ivalues, with_rounds=steal,
-        )
-        t1_ns = time.monotonic_ns()
-        if (
-            first_build and self._pc_stats is not None
-            and not self._pc_stats["hit"]
+        # jax.jit is lazy: the first call of a program pays its trace,
+        # lowering and compile (the Megakernel._execute discipline), so
+        # the ledger's bracket goes around it.
+        with building(
+            "sharded", self._jitted[key],
+            self._pc_stats if first_build else None,
         ):
-            # jax.jit is lazy: a cache MISS pays trace/lower/compile
-            # inside this first entry (the Megakernel._execute
-            # discipline), so fold the first wall into build_s before
-            # it is reported.
-            self._pc_stats["build_s"] += (t1_ns - t0_ns) / 1e9
+            iv_o, data_o, info = execute_partitions(
+                self.mk, self.mesh, self.ndev, self._jitted[key], builders,
+                data, ivalues, with_rounds=steal,
+            )
         if self._pc_stats is not None:
             info["program_cache"] = dict(self._pc_stats)
         # Per-device tier counters (cumulative over the steal rounds on

@@ -4,6 +4,7 @@ Pins the reference's finish/async/promise/forasync semantics on the host
 before the TPU device path re-implements them on-chip (see ../device/).
 """
 
+from . import progcache  # noqa: F401  registers the build ledger's listeners
 from .deque import WSDeque
 from .finish import Finish
 from .forasync import FLAT, RECURSIVE, forasync, forasync_future, register_dist_func

@@ -339,10 +339,13 @@ class MetricsRegistry:
             # <name>.program_cache.*, while the canonical
             # program_cache.{hits,misses,evictions,entries} series
             # reflects the whole process cache - one series regardless
-            # of which run name the build landed under.
-            from .progcache import cache_stats
+            # of which run name the build landed under. Beside them the
+            # build ledger's sums over every jit of the process:
+            # program_cache.{trace_s,lower_s,compile_s,
+            # cache_retrieval_s,traces,programs}.
+            from .progcache import build_totals, cache_stats
 
-            self.record("program_cache", cache_stats())
+            self.record("program_cache", {**cache_stats(), **build_totals()})
         self.record(name, keep)
 
     # -- snapshots --

@@ -49,11 +49,19 @@ pytest and in serving alike) and ``HCLIB_TPU_PROGRAM_CACHE_CAP``
 Eviction is cost-weighted LRU: on overflow the victim is the entry
 with the smallest measured ``build_s`` among the quarter of entries
 least recently used, so expensive mesh builds outlive bursts of cheap
-scalar ones without letting any entry pin the cache forever.
+scalar ones without letting any entry pin the cache forever. The cost
+is the wall of the program's first call (``building`` writes it): the
+trace, the lowering and the compile it would cost to lose.
+
+The build ledger (ISSUE 53) is the same module's second half: what
+JAX's own monitoring says each jit of the process cost to obtain, by
+program (``build_ledger``; the class ``BuildLedger`` says how), and
+the two profiler spans that put a build on the device trace's clock.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 import threading
@@ -61,8 +69,13 @@ import time
 import types
 from collections import OrderedDict
 from itertools import islice
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax import monitoring
+
+# spelled as every module that opens a span spells it:
+# tests/test_host_spans.py holds the source to this one line
+from ..runtime.spans import span
 from .env import env_int, env_raw
 
 __all__ = [
@@ -74,6 +87,9 @@ __all__ = [
     "mesh_key",
     "shared_build",
     "first_call",
+    "building",
+    "build_ledger",
+    "build_totals",
     "probe",
     "cache_stats",
     "reset",
@@ -385,6 +401,14 @@ class ProgramCache:
                 self.evictions += 1
             return kept[0]
 
+    def set_cost(self, key, build_s: float) -> None:
+        """The entry's eviction weight, once its first call has shown
+        what the build cost; recency is left as it is."""
+        with self._lock:
+            ent = self._entries.get(key)
+            if ent is not None:
+                self._entries[key] = (ent[0], float(build_s))
+
     def stats(self) -> Dict[str, int]:
         with self._lock:
             return {
@@ -411,8 +435,10 @@ def cache_stats() -> Dict[str, int]:
 
 
 def reset() -> None:
-    """Drop every entry and zero the counters (test isolation)."""
+    """Drop every entry, zero the counters and empty the build ledger
+    (test isolation)."""
     _CACHE.reset()
+    _LEDGER.reset()
 
 
 def _key(mk, variant) -> Optional[Tuple[str, str]]:
@@ -438,6 +464,18 @@ def probe(mk, variant) -> bool:
     return key is not None and _CACHE.contains(key)
 
 
+def _obtained(hit: bool, key, lookup_s: float) -> Dict[str, Any]:
+    """How a runner got its program: the dict ``shared_build`` returns
+    and ``building`` fills at the program's first call."""
+    return {
+        "hit": hit,
+        "key": None if key is None else ":".join(key),
+        "cache_lookup_s": lookup_s,
+        "build_s": 0.0, "wall_s": 0.0, "first_run_s": 0.0,
+        **_folded(()),
+    }
+
+
 def shared_build(mk, variant, build: Callable[[], Any]):
     """The one integration point every runner threads its jit through:
 
@@ -446,10 +484,13 @@ def shared_build(mk, variant, build: Callable[[], Any]):
     ``variant`` is any content-reducible object naming the runner's own
     static build parameters (fuel/quantum/windows/mesh/hop order...);
     the megakernel fingerprint plus the variant digest is the cache
-    key. Returns the shared callable and a stats dict: ``hit``,
-    ``cache_lookup_s`` (fingerprint + registry probe), ``build_s``
-    (0.0 on a hit). Cache off / uncacheable input degrade to a plain
-    timed build with ``hit=False``."""
+    key. Returns the shared callable and a stats dict: ``hit``, ``key``
+    (the two digests, ``None`` uncached), ``cache_lookup_s``
+    (fingerprint + registry probe) and the build's seconds, all 0.0
+    here: ``jax.jit(...)`` is lazy, so what the build cost shows at the
+    program's first call, and ``building`` around that call writes it
+    in. Cache off / uncacheable input degrade to a plain build with
+    ``hit=False``."""
     t0 = time.perf_counter()
     key = None
     if enabled():
@@ -457,22 +498,232 @@ def shared_build(mk, variant, build: Callable[[], Any]):
         if key is not None:
             fn = _CACHE.get(key)
             if fn is not None:
-                return fn, {
-                    "hit": True,
-                    "cache_lookup_s": time.perf_counter() - t0,
-                    "build_s": 0.0,
-                }
+                return fn, _obtained(True, key, time.perf_counter() - t0)
     lookup_s = time.perf_counter() - t0
-    t1 = time.perf_counter()
     fn = build()
-    build_s = time.perf_counter() - t1
     if key is not None:
-        fn = _CACHE.put(key, fn, cache_cap(), build_s=build_s)
-    return fn, {
-        "hit": False,
-        "cache_lookup_s": lookup_s,
-        "build_s": build_s,
+        fn = _CACHE.put(key, fn, cache_cap())
+    return fn, _obtained(False, key, lookup_s)
+
+
+# -------------------------------------------------------- build ledger
+
+_SPAN_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _folded(spans) -> Dict[str, Any]:
+    """The seconds of ``(kind, name, start, end, cache_retrieval_s,
+    persistent_hit)`` spans by kind, and how many of them were traces."""
+    by = {"trace": 0.0, "lower": 0.0, "compile": 0.0}
+    for kind, _, start, end, _, _ in spans:
+        by[kind] += end - start
+    compiles = [sp for sp in spans if sp[0] == "compile"]
+    return {
+        "trace_s": by["trace"], "lower_s": by["lower"],
+        "compile_s": by["compile"],
+        "cache_retrieval_s": sum(sp[4] for sp in compiles),
+        # every executable of the row came out of the persistent cache
+        "persistent_hit": bool(compiles) and all(sp[5] for sp in compiles),
+        "traces": sum(sp[0] == "trace" for sp in spans),
     }
+
+
+class BuildLedger:
+    """What each program of the process cost to obtain, as JAX stamps it.
+
+    JAX records a time span (``jax.monitoring``, on ``time.time()``) around
+    every trace of a jit (``jaxpr_trace_duration``, named ``f``), every
+    lowering (``jaxpr_to_mlir_module_duration``, named ``jit(f)``) and
+    every backend compile (``backend_compile_duration``, named
+    ``jit(f)``; with the persistent cache warm that span is the load,
+    and ``cache_hits`` / ``cache_retrieval_time_sec`` fire inside it on
+    the same thread). A pallas kernel's body is traced inside its jit's
+    trace and lowered to Mosaic inside its jit's lowering. None of these
+    fires on a cached dispatch, so the three listeners cost a steady
+    call nothing.
+
+    Spans nest: a jit called while another is traced fires its own
+    trace span inside the outer one, and a lowering rule that calls
+    ``jnp`` fires trace spans inside the lowering. A thread's spans are
+    context managers, so the inner one always ends first; when a span
+    ends, the spans of its thread that started after it did are the
+    ones it encloses, and they are dropped there and then (a kernel's
+    trace fires thousands of them). What is kept is each thread's
+    outermost spans, of whatever kind: their seconds add up to no more
+    than the wall they lie in.
+
+    ``bracket`` takes the spans that end on its thread while it is open
+    for its own row; every other span is found by its ``fun_name``."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        # thread -> its outermost spans, in the order they ended
+        self._spans: Dict[int, List[tuple]] = {}
+        # thread -> [persistent_hit, cache_retrieval_s] of the backend
+        # compile open on it
+        self._loading: Dict[int, list] = {}
+        self._rows: List[Dict[str, Any]] = []
+        self.heard = 0  # calls of the three listeners
+
+    def listen(self) -> None:
+        """Register the three listeners: once a process (JAX has no way
+        to take one back)."""
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_span(self, event, start, end, fun_name="", **_) -> None:
+        kind = _SPAN_KINDS.get(event)
+        tid = threading.get_ident()
+        with self._lock:
+            self.heard += 1
+            if kind is None:
+                return
+            mine = self._spans.setdefault(tid, [])
+            while mine and mine[-1][2] >= start:
+                mine.pop()
+            hit, retrieval_s = (
+                self._loading.pop(tid, (False, 0.0))
+                if kind == "compile" else (False, 0.0))
+            name = str(fun_name)
+            if name.startswith("jit(") and name.endswith(")"):
+                name = name[4:-1]
+            mine.append((kind, name, start, end, retrieval_s, hit))
+        if kind == "compile":
+            # An instant in a profile: an executable was obtained now.
+            with span("prog.compiled"):
+                pass
+
+    def _load(self, slot: int, value) -> None:
+        self._loading.setdefault(
+            threading.get_ident(), [False, 0.0])[slot] = value
+
+    def _on_event(self, event, **_) -> None:
+        with self._lock:
+            self.heard += 1
+            if event == _CACHE_HIT:
+                self._load(0, True)
+
+    def _on_duration(self, event, secs, **_) -> None:
+        with self._lock:
+            self.heard += 1
+            if event == _CACHE_RETRIEVAL:
+                self._load(1, secs)
+
+    @contextlib.contextmanager
+    def bracket(self, runner: str, name: str, stats: Dict[str, Any]):
+        """Around a program's first call: the spans that end on this
+        thread meanwhile are the program's, and ``stats`` (what
+        ``shared_build`` returned) becomes its row."""
+        tid = threading.get_ident()
+        with self._lock:
+            first = len(self._spans.get(tid, ()))
+        t0 = time.time()
+        with span("prog.first_call"):
+            yield
+        t1 = time.time()
+        with self._lock:
+            mine = self._spans.get(tid, [])
+            inside = mine[first:]
+            del mine[first:]
+            stats.update(_folded(inside))
+            built = (
+                stats["trace_s"] + stats["lower_s"] + stats["compile_s"])
+            # the first execution, its upload and its read
+            stats.update(wall_s=t1 - t0, build_s=t1 - t0,
+                         first_run_s=t1 - t0 - built)
+            self._rows.append({"name": name, "runner": runner,
+                               "first": t0, "last": t1, **stats})
+
+    def rows(self) -> List[Dict[str, Any]]:
+        """The bracketed programs in the order they were built, then one
+        row a ``fun_name`` for every jit no bracket took."""
+        with self._lock:
+            out = [dict(r) for r in self._rows]
+            loose: Dict[str, list] = {}
+            for mine in self._spans.values():
+                for sp in mine:
+                    loose.setdefault(sp[1], []).append(sp)
+        for name, spans in loose.items():
+            out.append({
+                "name": name, "runner": None,
+                "first": min(sp[2] for sp in spans),
+                "last": max(sp[3] for sp in spans),
+                **_folded(spans),
+            })
+        return out
+
+    def reset(self) -> None:
+        with self._lock:
+            self._spans.clear()
+            self._loading.clear()
+            del self._rows[:]
+            self.heard = 0
+
+
+_LEDGER = BuildLedger()
+_LEDGER.listen()
+
+
+def build_ledger() -> List[Dict[str, Any]]:
+    """The process's builds so far, one row a program: ``name`` (the
+    jit's ``fun_name``; ``jit(f)`` and ``f`` are one name), ``trace_s``
+    / ``lower_s`` / ``compile_s`` (each thread's outermost spans only,
+    so nothing nested is counted twice), ``cache_retrieval_s`` and
+    ``persistent_hit`` (the compile was a load from JAX's persistent
+    cache), ``traces`` (outermost trace spans: a jit traced again for a
+    new shape or a new layout reads one more), ``first`` / ``last``
+    (``time.time()`` stamps). A program a runner built through
+    ``building`` has a row of its own with ``runner``, ``key``, ``hit``,
+    ``cache_lookup_s``, ``wall_s`` (``build_s`` is its older name) and
+    ``first_run_s``; every other row has ``runner`` None."""
+    return _LEDGER.rows()
+
+
+def build_totals() -> Dict[str, float]:
+    """The ledger summed over its rows (MetricsRegistry's
+    ``program_cache.*`` gauges beside ``cache_stats``)."""
+    rows = build_ledger()
+    out = {
+        k: sum(r[k] for r in rows)
+        for k in ("trace_s", "lower_s", "compile_s", "cache_retrieval_s",
+                  "traces")
+    }
+    out["programs"] = len(rows)
+    return out
+
+
+_NOT_BUILDING = contextlib.nullcontext()
+
+
+def building(runner: str, fn, stats: Optional[Dict[str, Any]]):
+    """The context manager a runner puts around a call of its program:
+    ``stats`` is what ``shared_build`` returned where this call is the
+    program's FIRST, and ``None`` on every later one. ``None``, or an
+    in-process hit (another instance already called the callable),
+    opens nothing. A first call opens the profiler span
+    ``prog.first_call``, takes the trace, lowering and compile spans
+    that end inside it for the program's ledger row, records the call's
+    wall (``wall_s``, and ``first_run_s``: the wall less those spans)
+    into ``stats``, and makes that wall the cache entry's eviction
+    weight."""
+    if stats is None or stats["hit"]:
+        return _NOT_BUILDING
+    return _first_call_of(runner, getattr(fn, "__name__", runner), stats)
+
+
+@contextlib.contextmanager
+def _first_call_of(runner: str, name: str, stats: Dict[str, Any]):
+    with _LEDGER.bracket(runner, name, stats):
+        yield
+    if stats["key"] is not None:
+        _CACHE.set_cost(tuple(stats["key"].split(":")), stats["wall_s"])
 
 
 @functools.cache

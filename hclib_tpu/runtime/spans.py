@@ -63,6 +63,12 @@ STAGES = (
     "g500.seed",  # a search's builder, its one maker row, the zero values
     "g500.search",  # Megakernel.run: the four mk.* stages nest inside
     "g500.readback",  # the queue read and unrolled into the parent array
+    # the build ledger (runtime/progcache.py)
+    "prog.first_call",  # building(): a runner's first call of a program it
+                        # built: trace, lowering, compile or cache load, one
+                        # execution; the runner's launch and wait nest inside
+    "prog.compiled",  # an instant: a backend compile or a load from the
+                      # persistent cache just ended, for any jit of the process
 )
 
 

@@ -95,19 +95,31 @@ def test_spans_land_nested_in_a_profile_the_benchmark_reads(tmp_path):
     try:
         with spans.span("sw.run"):
             with spans.span("mk.upload"):
-                jax.block_until_ready(jax.numpy.zeros(8) + 1)
+                # a jit of a new function compiles, whatever ran before
+                jax.block_until_ready(
+                    jax.jit(lambda x: x * 3 + 1)(jax.numpy.zeros(8)))
     finally:
         jax.profiler.stop_trace()
-    host = {name: (s, e) for name, s, e in trace.read(str(tmp_path))["host"]}
-    assert set(host) == {"bench:sw.run", "bench:mk.upload"}
+    events = trace.read(str(tmp_path))["host"]
+    host = {name: (s, e) for name, s, e in events}
+    # the build ledger marks the instant each executable was obtained
+    # (ISSUE 53), on the same clock, for a jit no runner knows of
+    assert set(host) == {"bench:sw.run", "bench:mk.upload",
+                         "bench:prog.compiled"}
     (a, b), (s, e) = host["bench:sw.run"], host["bench:mk.upload"]
     assert a <= s < e <= b
+    marks = [ev for ev in events if ev[0] == "bench:prog.compiled"]
+    assert marks and all(s <= m0 <= m1 <= e for _, m0, m1 in marks)
+    assert reduce.reducer("span_count_per_span")(
+        a_run(events, []), count="bench:prog.compiled",
+        span="bench:mk.upload") == len(marks)
 
 
 def test_the_table_names_every_stage_once():
-    assert len(set(spans.STAGES)) == len(spans.STAGES) == 33
+    assert len(set(spans.STAGES)) == len(spans.STAGES) == 35
+    assert {"prog.first_call", "prog.compiled"} < set(spans.STAGES)
     assert all(
-        re.fullmatch(r"[a-z0-9]+(\.[a-z0-9]+)+", s) for s in spans.STAGES
+        re.fullmatch(r"[a-z0-9]+(\.[a-z0-9_]+)+", s) for s in spans.STAGES
     )
 
 
@@ -129,7 +141,7 @@ def test_the_spans_the_source_opens_are_the_helpers_table():
             continue
         assert "from ..runtime.spans import span\n" in text, path
         for arg in calls:  # a literal stage and nothing else
-            assert re.fullmatch(r'"[a-z0-9.]+"', arg), (path, arg)
+            assert re.fullmatch(r'"[a-z0-9._]+"', arg), (path, arg)
             opened.add(arg.strip('"'))
     assert opened == set(spans.STAGES)
 
@@ -147,7 +159,12 @@ def test_a_run_opens_its_four_spans_in_order_and_resume_three(recorded):
 
     iv, _, info = fib10()
     assert int(iv[0]) == 55 and info["pending"] == 0
-    assert recorded == MK
+    # the first call is the build: the ledger's bracket opens around
+    # the launch and the wait, a mark falls where the compile ended
+    assert [n for n in recorded if n != "bench:prog.compiled"] == (
+        MK[:2] + ["bench:prog.first_call"] + MK[2:])
+    at = recorded.index("bench:prog.first_call")
+    assert "bench:prog.compiled" in recorded[at:recorded.index(MK[3])]
     del recorded[:]
     _, _, cut = fib10(quiesce=40)
     assert cut["quiesced"] and cut["pending"] > 0
@@ -155,7 +172,13 @@ def test_a_run_opens_its_four_spans_in_order_and_resume_three(recorded):
     del recorded[:]
     iv, _, done = mk.resume(cut["state"])
     assert int(iv[0]) == 55 and done["executed"] == info["executed"]
-    assert recorded == MK[1:]  # the state is final: nothing to finalize
+    # the state is final: nothing to finalize; it comes with a live ring,
+    # which is another layout and so another program, built here
+    assert [n for n in recorded if n != "bench:prog.compiled"] == (
+        MK[1:2] + ["bench:prog.first_call"] + MK[2:])
+    del recorded[:]
+    mk.resume(cut["state"])
+    assert recorded == MK[1:]
 
 
 def test_a_device_forasync_opens_its_two_spans_around_the_run(recorded):
@@ -175,7 +198,8 @@ def test_a_device_forasync_opens_its_two_spans_around_the_run(recorded):
         out, info = hc.forasync(
             tk, bounds, tile=tile, mode=mode, place="device", width=2,
             interpret=True, data={"gin": gin, "gout": gout.copy()})
-        assert recorded == ["bench:fa.seed", "bench:fa.run"] + MK
+        assert [n for n in recorded if not n.startswith("bench:prog.")
+                ] == ["bench:fa.seed", "bench:fa.run"] + MK
         assert np.array_equal(np.asarray(out["gout"]),
                               stencil_reference(gin))
         assert info["forasync"]["mode"] == mode
@@ -224,7 +248,9 @@ def test_every_entry_opens_pump_launch_wait_settle_in_order(bursts):
     per_burst = " ".join(opened).split("run_stream")[1:]
     assert len(per_burst) == 2
     for names, link in zip(per_burst, streams):
-        names = names.split()
+        # the first burst's first entry builds the program: the build
+        # ledger's spans are held in tests/test_progcache.py
+        names = [n for n in names.split() if not n.startswith("bench:prog.")]
         # the stream's state goes up once, inside the first entry
         assert names[:2] == [ENTRY[0], "bench:stream.upload"]
         names.remove("bench:stream.upload")
@@ -361,3 +387,80 @@ def test_span_count_per_span_reads_nothing_without_an_enclosing_span():
     host = [("bench:stream.upload", 0, 50), ("bench:burst", 0, 100)]
     assert read(a_run(host, []), count="bench:stream.upload",
                 span="bench:run_stream") is None
+
+
+# ------------------------- the program build layer's five (ISSUE 53)
+
+CELLS = ["fib30-scalar", "cholesky-8192", "serve-burst-3072", "uts-t1l",
+         "forest-steal-4chip", "sw-wave-8192", "forasync-2d-hbm",
+         "serve-open-steady", "g500-bfs-search", "jacobi-dep-hbm"]
+# name: (reducer, args, unit, source)
+BUILD = {
+    "build_trace_s": ("build_ledger", {"field": "trace_s"}, "s",
+                      "program_counter"),
+    "build_lower_s": ("build_ledger", {"field": "lower_s"}, "s",
+                      "program_counter"),
+    "build_compile_s": ("build_ledger", {"field": "compile_s"}, "s",
+                        "program_counter"),
+    "build_traces": ("build_ledger", {"field": "traces"}, "count",
+                     "program_counter"),
+    "window_builds": ("span_count_per_span",
+                      {"count": "bench:prog.compiled",
+                       "span": "bench:window"}, "count", "program_span"),
+}
+BEFORE_BUILD = 66  # per-layer entries of the parent's BENCHMARK.json
+
+
+@pytest.mark.parametrize("name", sorted(BUILD))
+def test_build_metric_file_and_entry_are_the_issues(bench, name):
+    reducer, args, unit, source = BUILD[name]
+    spec = run.load_json("benchmarks", "metrics", name + ".json")
+    assert spec["name"] == name and spec["reducer"] == reducer
+    assert spec["args"] == args and set(spec) == {
+        "name", "what", "reducer", "args"}
+    assert run.find(bench["per_layer"], name, "metric") == {
+        "name": name, "unit": unit, "better": "lower", "source": source,
+        "layer": "program build", "moves": "setup_s", "workloads": CELLS}
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[BEFORE_BUILD:BEFORE_BUILD + 5] == list(BUILD)
+    assert CELLS == [w["name"] for w in bench["workloads"]][:10]
+
+
+@pytest.mark.parametrize("marks", [0, 1, 3])
+def test_window_builds_counts_the_marks_inside_a_window(marks):
+    spec = run.load_json("benchmarks", "metrics", "window_builds.json")
+    read = reduce.reducer(spec["reducer"])
+    host = [("bench:window", 1_000, 2_000_000),
+            ("bench:window", 3_000_000, 4_000_000),
+            # a mark is an instant; one between two windows is set-up's
+            ("bench:prog.compiled", 2_500_000, 2_500_000)]
+    host += [("bench:prog.compiled", 5_000 + i, 5_000 + i)
+             for i in range(marks)]
+    assert read(a_run(host, []), **spec["args"]) == marks / 2
+    assert read(a_run(host[2:], []), **spec["args"]) is None
+
+
+def test_build_ledger_reads_the_ledger_a_run_leaves(monkeypatch):
+    """The four ``build_*`` through their files on the ledger one tiny
+    ``Megakernel.run`` leaves; a program from before the ledger is
+    nothing to read, and nothing raises."""
+    from hclib_tpu.runtime import progcache
+
+    progcache.reset()
+    b = TaskGraphBuilder()
+    b.add(FIB, args=[6], out=0)
+    make_fib_megakernel(64, interpret=True).run(b)
+    rows = progcache.build_ledger()
+    (mine,) = [r for r in rows if r["runner"] == "megakernel"]
+    read = {}
+    for name in sorted(BUILD)[:4]:
+        spec = run.load_json("benchmarks", "metrics", name + ".json")
+        read[name] = reduce.reducer(spec["reducer"])(
+            a_run([], []), **spec["args"])
+        assert read[name] == sum(r[spec["args"]["field"]] for r in rows)
+    assert read["build_traces"] >= mine["traces"] == 1
+    for part in ("trace", "lower", "compile"):
+        assert read[f"build_{part}_s"] >= mine[f"{part}_s"] > 0
+    monkeypatch.delattr(progcache, "build_ledger")
+    assert reduce.reducer("build_ledger")(a_run([], []), "trace_s") is None
+    progcache.reset()

@@ -119,7 +119,16 @@ def test_a_mesh_of_one_device_steals_nothing(mk):
 
 
 def test_the_host_half_opens_its_four_spans_in_order(stolen):
-    assert stolen[2] == SPANS
+    """The call is the mesh program's first: the build ledger's bracket
+    stands around the four, and marks where a compile ended."""
+    opened = stolen[2]
+    assert opened[0] == "bench:prog.first_call"
+    assert "bench:prog.compiled" in opened
+    assert [n for n in opened if not n.startswith("bench:prog.")] == SPANS
+    row = stolen[1]["program_cache"]
+    assert not row["hit"] and row["trace_s"] > 0 and row["compile_s"] > 0
+    assert row["trace_s"] + row["lower_s"] + row["compile_s"] <= (
+        row["wall_s"]) == row["build_s"]
 
 
 def test_fault_stats_row_decodes_the_two_counters():

@@ -12,11 +12,15 @@ rebuild is bit-identical); fail-open on unfingerprintable input.
 """
 
 import inspect
+import threading
+import time
 
 import numpy as np
 import pytest
+from conftest import front_door, send
 
 import jax
+import jax.numpy as jnp
 
 from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.frontier import _KINDS, Graph, make_frontier_megakernel
@@ -32,7 +36,7 @@ from hclib_tpu.device.workloads import (
     rmat_edges,
     stencil_loop,
 )
-from hclib_tpu.runtime import progcache
+from hclib_tpu.runtime import progcache, spans
 from hclib_tpu.runtime.progcache import (
     Uncacheable,
     cache_cap,
@@ -289,6 +293,15 @@ def test_cache_on_off_lowered_text_byte_identical(name, monkeypatch):
     # implies program-equal for the builder), so sharing is sound.
     monkeypatch.delenv("HCLIB_TPU_PROGRAM_CACHE", raising=False)
     assert _lowered_text(factory()) == on_text
+    # The build ledger listens and changes nothing (ISSUE 53): it saw
+    # the three lowerings, outside any bracket, and the text is what a
+    # process that never asks for it lowers to.
+    rows = [r for r in progcache.build_ledger() if r["lower_s"] > 0]
+    assert rows and all(r["runner"] is None for r in rows)
+    assert sum(r["traces"] for r in progcache.build_ledger()) >= 3
+    progcache.reset()
+    assert progcache.build_ledger() == []
+    assert _lowered_text(factory()) == on_text
 
 
 def test_second_identical_fib_instance_hits_and_matches():
@@ -479,3 +492,243 @@ def test_first_call_is_the_call_from_one_large_frame():
     depth = progcache.first_call(lambda: len(inspect.stack()))
     assert depth == len(inspect.stack()) + 3  # first_call, roomy, the lambda
     assert progcache._roomy_frame().__code__.co_nlocals * 8 > 4 * 16384
+
+
+# ------------------------------------------- the build ledger (ISSUE 53)
+
+
+@pytest.fixture()
+def opened(monkeypatch):
+    """The profiler spans the program opens, in order (as
+    tests/test_host_spans.py records them)."""
+    names = []
+
+    class Span:
+        def __init__(self, name):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Span)
+    return names
+
+
+def _fib(mk=None, n=8):
+    b = TaskGraphBuilder()
+    b.add(FIB, args=[n], out=0)
+    return (mk or make_fib_megakernel(interpret=True)).run(b)
+
+
+def _bracketed():
+    return [r for r in progcache.build_ledger() if r["runner"] is not None]
+
+
+def _built(row):
+    return row["trace_s"] + row["lower_s"] + row["compile_s"]
+
+
+def test_first_run_leaves_one_bracketed_row_and_a_steady_call_nothing(
+        opened):
+    mk = make_fib_megakernel(interpret=True)
+    _, _, info = _fib(mk)
+    (row,) = _bracketed()
+    assert (row["name"], row["runner"]) == ("tpu_custom_call", "megakernel")
+    assert row["hit"] is False and len(row["key"]) == 65
+    assert min(row["trace_s"], row["lower_s"], row["compile_s"]) > 0
+    assert _built(row) <= row["wall_s"] == row["build_s"]
+    assert row["first_run_s"] == pytest.approx(row["wall_s"] - _built(row))
+    assert row["traces"] == 1 and row["first"] < row["last"]
+    # a run reports the row, and stats_dict carries it
+    assert info["program_cache"] == {k: row[k] for k in info["program_cache"]}
+    assert set(info["program_cache"]) == {
+        "hit", "key", "cache_lookup_s", "build_s", "wall_s", "first_run_s",
+        "trace_s", "lower_s", "compile_s", "cache_retrieval_s",
+        "persistent_hit", "traces"}
+    assert mk.stats_dict()["program_cache"] == info["program_cache"]
+    assert opened.count("bench:prog.first_call") == 1
+    assert "bench:prog.compiled" in opened
+    # a second run, and a second instance's first (an in-process hit):
+    # no row, neither span, and JAX told the listeners nothing
+    heard = progcache._LEDGER.heard
+    ledger = progcache.build_ledger()
+    del opened[:]
+    _fib(mk)
+    _, _, again = _fib()
+    assert again["program_cache"]["hit"] is True
+    assert again["program_cache"]["wall_s"] == 0.0 == _built(
+        again["program_cache"])
+    assert progcache._LEDGER.heard == heard > 0
+    assert not [n for n in opened if n.startswith("bench:prog.")]
+    assert progcache.build_ledger() == ledger
+
+
+def test_a_jit_that_calls_a_jit_is_counted_once_and_found_by_name():
+    @jax.jit
+    def ledger_inner(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def ledger_outer(x):
+        return ledger_inner(jnp.cos(x)) + 1
+
+    t0 = time.time()
+    ledger_outer(np.ones(4, np.float32)).block_until_ready()
+    wall = time.time() - t0
+    rows = {r["name"]: r for r in progcache.build_ledger()}
+    # sin, multiply, ledger_inner, cos and add were traced inside
+    # ledger_outer's trace: one row, one trace, under the outer's name
+    # (the trace calls it ledger_outer, the lowering jit(ledger_outer))
+    assert set(rows) == {"ledger_outer"}
+    row = rows["ledger_outer"]
+    assert row["runner"] is None and row["traces"] == 1
+    assert min(row["trace_s"], row["lower_s"], row["compile_s"]) > 0
+    assert _built(row) <= wall
+    assert t0 <= row["first"] < row["last"] <= t0 + wall
+    heard = progcache._LEDGER.heard
+    ledger_outer(np.ones(4, np.float32)).block_until_ready()
+    assert progcache._LEDGER.heard == heard
+    assert progcache.build_totals() == {
+        "trace_s": row["trace_s"], "lower_s": row["lower_s"],
+        "compile_s": row["compile_s"],
+        "cache_retrieval_s": row["cache_retrieval_s"], "traces": 1,
+        "programs": 1}
+    progcache.reset()
+    assert progcache.build_ledger() == []
+    assert progcache.build_totals()["programs"] == 0
+
+
+def test_a_stream_leaves_a_row_an_entry_program(opened):
+    """The plain entry program at the first entry; the delta program
+    once a publish wants it, not before."""
+    sm, _ = front_door("plain")
+    send(sm, None, 6)
+    sm.close()
+    b = TaskGraphBuilder()
+    b.add(0, args=[1000])
+    _, info = sm.run_stream(b)
+    assert info["stream"]["ring_deltas"] == 0
+    (plain,) = _bracketed()
+    assert (plain["name"], plain["runner"]) == ("tpu_custom_call", "stream")
+    assert min(plain["trace_s"], plain["lower_s"], plain["compile_s"]) > 0
+    assert _built(plain) <= plain["wall_s"]
+    assert info["program_cache"] == sm.stats_dict()["program_cache"] == {
+        k: plain[k] for k in info["program_cache"]}
+    assert opened.count("bench:prog.first_call") == 1
+    # the bracket stands around the first entry's launch and its wait
+    at = opened.index("bench:prog.first_call")
+    assert opened[at + 1] == "bench:stream.launch"
+
+    progcache.reset()
+    del opened[:]
+    sm, table = front_door("tenants", max_in_flight=4)
+    send(sm, table, 24)
+    sm.close()
+    _, info = sm.run_stream(b, quantum=4, max_rounds=2)
+    assert info["stream"]["ring_deltas"] >= 2
+    first, delta = _bracketed()
+    assert first["runner"] == delta["runner"] == "stream"
+    assert first["key"] != delta["key"] and first["last"] <= delta["first"]
+    assert delta["trace_s"] > 0 and _built(delta) <= delta["wall_s"]
+    # info reports the plain program's row; each was bracketed once
+    assert info["program_cache"]["key"] == first["key"]
+    assert opened.count("bench:prog.first_call") == 2
+    assert opened.count("bench:stream.launch") == info["stream"]["entries"]
+
+
+def test_two_threads_builds_do_not_take_each_others_spans():
+    """One thread's bracket is open while another traces and compiles
+    a jit of its own: the row holds the first thread's spans only, and
+    the other's jit is found by its name."""
+    def ledger_mine(x):
+        return x * 3 + 1
+
+    def ledger_theirs(x):
+        return x * 5 - 1
+
+    fn, stats = shared_build(
+        _bump_mk(), ("ledger-thread",), lambda: jax.jit(ledger_mine))
+    assert stats["hit"] is False and stats["wall_s"] == 0.0
+    x = np.ones(4, np.float32)
+    theirs = threading.Thread(
+        target=lambda: jax.jit(ledger_theirs)(x).block_until_ready())
+    with progcache.building("test", fn, stats):
+        theirs.start()
+        theirs.join(timeout=120)
+        assert not theirs.is_alive()
+        fn(x).block_until_ready()
+    rows = {r["name"]: r for r in progcache.build_ledger()}
+    assert set(rows) == {"ledger_mine", "ledger_theirs"}
+    mine, other = rows["ledger_mine"], rows["ledger_theirs"]
+    assert mine["runner"] == "test" and other["runner"] is None
+    assert mine["traces"] == other["traces"] == 1
+    assert min(other["trace_s"], other["lower_s"], other["compile_s"]) > 0
+    # the other thread's spans lie inside the bracket's wall and are
+    # not in its row: built and first_run_s stay this thread's
+    assert mine["first"] <= other["first"] < other["last"] <= mine["last"]
+    assert _built(mine) + _built(other) <= mine["wall_s"]
+    assert stats == {k: mine[k] for k in stats}
+    # a hit, or no stats at all, opens nothing
+    hit_fn, hit = shared_build(
+        _bump_mk(), ("ledger-thread",), lambda: jax.jit(ledger_mine))
+    assert hit["hit"] and hit_fn is fn
+    for nothing in (hit, None):
+        with progcache.building("test", fn, nothing):
+            pass
+    assert len(progcache.build_ledger()) == 2
+
+
+def test_eviction_weighs_the_first_calls_wall(monkeypatch):
+    """Two real programs, one slow to trace, oldest in a full cache:
+    the victim is the one whose first call was the shorter, not the
+    one whose ``jax.jit(...)`` constructor happened to return sooner."""
+    monkeypatch.setenv("HCLIB_TPU_PROGRAM_CACHE_CAP", "7")
+
+    def run(body):
+        mk = Megakernel(kernels=[("bump", body)], capacity=128,
+                        num_values=4, succ_capacity=8, interpret=True)
+        b = TaskGraphBuilder()
+        b.add(0, args=[7])
+        iv, _, info = mk.run(b)
+        assert int(iv[0]) == 7
+        return mk, info["program_cache"]
+
+    def slow(ctx):  # the body runs while the kernel is traced
+        time.sleep(2.0)
+        ctx.set_value(0, ctx.value(0) + ctx.arg(0))
+
+    def cheap(ctx):
+        ctx.set_value(0, ctx.arg(0) + ctx.value(0))
+
+    (mk_slow, pc_slow), (mk_cheap, pc_cheap) = run(slow), run(cheap)
+    assert pc_slow["trace_s"] >= 2.0 > pc_cheap["trace_s"]
+    assert pc_slow["wall_s"] > pc_cheap["wall_s"] > 0
+    variant = ("megakernel-exec", 1 << 22, False, (), ())
+    assert probe(mk_slow, variant) and probe(mk_cheap, variant)
+    for i in range(5):  # never called: they weigh nothing, and are newer
+        shared_build(mk_slow, ("filler", i), object)
+    assert cache_stats() == {"hits": 0, "misses": 7, "evictions": 0,
+                             "entries": 7}
+    shared_build(mk_slow, ("filler", 5), object)  # overflow
+    assert cache_stats()["evictions"] == 1
+    # the window is the two least recently used: the slow build, oldest
+    # of all, stays; the cheap one goes
+    assert probe(mk_slow, variant) and not probe(mk_cheap, variant)
+
+
+def test_metrics_exports_the_ledgers_sums():
+    from hclib_tpu.runtime.metrics import MetricsRegistry
+
+    _, _, info = _fib()
+    reg = MetricsRegistry()
+    reg.add_run_info("fib", info)
+    m = reg.snapshot()["metrics"]
+    totals = progcache.build_totals()
+    for k in ("trace_s", "lower_s", "compile_s", "cache_retrieval_s",
+              "traces", "programs"):
+        assert m[f"program_cache.{k}"] == float(totals[k])
+    for k in ("trace_s", "lower_s", "compile_s", "first_run_s", "wall_s"):
+        assert m[f"fib.program_cache.{k}"] == info["program_cache"][k] > 0

@@ -92,6 +92,9 @@ def test_the_four_spans_are_entered_once_a_call(mk_square, monkeypatch):
     a, b = ref.make_pair(SEEDS[1], 512, 512)
     for _ in range(2):
         sw.device_sw_wave(a, b, interpret=True, mk=mk_square, with_h=False)
+    # the build ledger's two (the first call builds; a jnp pass may
+    # compile) are told apart in tests/test_progcache.py
+    opened = [n for n in opened if not n.startswith("bench:prog.")]
     assert [n for n in opened if n.startswith("bench:sw.")] == SPANS * 2
     # Megakernel.run's own four nest inside bench:sw.run
     at = opened.index("bench:sw.run")

@@ -116,14 +116,14 @@ def test_trace_off_is_bit_identical_with_no_ring_output():
     # Tracing adds the trace key plus the trace-DERIVED tier gauges
     # (lane_partial_age, ISSUE 9); every device-computed number is
     # identical.
-    # (program_cache and the tiers build_s/cache_lookup_s keys are
-    # host-side program-cache facts - different per build, not device
-    # output - so they are excluded from the cross-arm identity.)
+    # (program_cache is a host-side fact of the build - different per
+    # build, not device output - so it is excluded from the cross-arm
+    # identity; the tiers hold no copy of it since ISSUE 53.)
     on = {k: v for k, v in info_on.items()
           if k not in ("trace", "program_cache")}
     off = {k: v for k, v in info_off.items() if k != "program_cache"}
-    host_keys = ("lane_partial_age", "lane_partial_ages",
-                 "build_s", "cache_lookup_s")
+    host_keys = ("lane_partial_age", "lane_partial_ages")
+    assert not {"build_s", "cache_lookup_s"} & set(info_off["tiers"])
     on["tiers"] = {
         k: v for k, v in on["tiers"].items() if k not in host_keys
     }
