@@ -8,8 +8,10 @@ re-designed for the VPU instead of translated from the scalar DP loop:
   dependency H[i,j] = max(0, cand[i,j], H[i,j-1] - G) is solved *exactly* as
   a max-plus prefix scan: H = max(0, cummax(cand + j*G) - j*G), where the
   0-truncation can be applied once at the end because a truncation point
-  only ever contributes negative values downstream. cummax is 7 log-step
-  shift+max ops over the 128 lanes.
+  only ever contributes negative values downstream. cummax is a shift+max
+  ladder over the 128 lanes whose windows overlap (``SCAN_STAGES``): a row
+  waits on the ladder's STAGES, each a trip through the cross-lane unit,
+  not on its rolls, so it has few stages of many rolls.
 - Inter-tile boundaries travel through dedicated HBM buffers (bottom row,
   right column, corner per tile) instead of overlapping tile reads, keeping
   every DMA aligned. The right column and the per-row left boundary live in
@@ -46,15 +48,53 @@ TILE_FN = 0
 NEG = -(1 << 30)  # plain int: a jnp constant here would be captured by the trace
 
 
-def _cummax_lanes(x):
+# The shifts of _cummax_lanes, stage by stage. max is idempotent, so the
+# windows of a stage may overlap: the rolls of one stage all read the
+# stage's input and are pushed to the cross-lane unit back to back, and
+# only the stages wait for each other. A stage of k rolls widens the
+# window k + 1 times: 8, 64, 128 lanes. On the v5e a stage in series costs
+# a row 76 ns and a roll more 2-4 ns (PERF.md section 6, PR 54), which is
+# why radix 8 and not 4 (four stages of 3, 3, 3, 1: 9.7 us a round more)
+# or 16 (two of 15 and 7: no faster by the wall clock, the 26 pushes a
+# row more catch up with the trip saved).
+SCAN_STAGES = (
+    (1, 2, 3, 4, 5, 6, 7),
+    (8, 16, 24, 32, 40, 48, 56),
+    (64,),
+)
+
+
+def _max_tree(terms):
+    """Maximum of the planes in ``terms`` as a balanced tree (the VALU
+    chain stays log-deep)."""
+    while len(terms) > 1:
+        terms = [
+            jnp.maximum(*terms[k : k + 2]) if k + 1 < len(terms)
+            else terms[k]
+            for k in range(0, len(terms), 2)
+        ]
+    return terms[0]
+
+
+def _cummax_lanes(x, shifted: bool = False):
     """Inclusive running max along the 128 lanes of an (R, T) plane (each
-    sublane row scans independently)."""
+    sublane row scans independently), exactly: ``SCAN_STAGES`` dependent
+    trips through the cross-lane unit, not the seven of a radix-2 ladder.
+
+    ``shifted``: also the EXCLUSIVE running max (lane j holds the maximum
+    of lanes < j, lane 0 ``NEG``): the scan rolled one lane up, made by the
+    last stage's own trip from rolls one lane longer, so a caller that
+    wants its result's left neighbour pays no trip of its own for it."""
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    for sh in (1, 2, 4, 8, 16, 32, 64):
-        shifted = pltpu.roll(x, sh, axis=1)
-        shifted = jnp.where(lane >= sh, shifted, NEG)
-        x = jnp.maximum(x, shifted)
-    return x
+
+    def rolled(sh):  # of the stage's input: x as it stands when called
+        return jnp.where(lane >= sh, pltpu.roll(x, sh, axis=1), NEG)
+
+    for k, shifts in enumerate(SCAN_STAGES):
+        if shifted and k == len(SCAN_STAGES) - 1:
+            left = _max_tree([rolled(sh + 1) for sh in (0,) + shifts])
+        x = _max_tree([x] + [rolled(sh) for sh in shifts])
+    return (x, left) if shifted else x
 
 
 def _sw_tile_kernel(ctx: KernelContext, with_h: bool = True) -> None:
@@ -312,39 +352,55 @@ def _sw_wave_batch_kernel(ctx, chunk: int, with_h: bool = True) -> None:
     corner = vcorn[buf][:, T - 1 :]  # (S, 1)
 
     def col(plane, i):
-        """Column i of an (S, T) plane as (S, 1): mask + lane-reduce
-        (Mosaic has no dynamic_slice on values; this is 2 plane ops)."""
-        return jnp.sum(
-            jnp.where(lane == i, plane, 0), axis=1, keepdims=True
+        """Column i of an (S, T) plane on every lane of an (S, T) plane: a
+        lane gather, one cross-lane push a vreg (Mosaic has no
+        dynamic_slice on values; a masked lane sum of int32 is four)."""
+        return jnp.take_along_axis(
+            plane, jnp.full((S, T), i, jnp.int32), axis=1
         )
 
-    def row(i, carry):
-        hprev, rout, _mpl = carry
-        achar = col(aplane, i)
-        prev_left = jnp.where(i == 0, corner, col(leftp, i - 1))
-        this_left = col(leftp, i)
+    def ahead(i):
+        """What row ``i`` takes from the operands alone: its substitution
+        scores and its left boundary H[i, j0-1] on every lane. No row
+        computes either, so row ``i - 1`` makes them and hands them on in
+        the carry: their gathers pop under that row's scan and row ``i``
+        starts with them in registers."""
         sub = jnp.where(
-            bplane == achar, jnp.int32(MATCH), jnp.int32(MISMATCH)
+            bplane == col(aplane, i), jnp.int32(MATCH), jnp.int32(MISMATCH)
         )
-        diag = pltpu.roll(hprev, 1, axis=1)
+        return sub, col(leftp, i)
+
+    def row(i, carry):
+        # diag: H[i-1, j-1] on lanes j >= 1, the row before's own making.
+        hprev, rout, _mpl, sub, prev_left, this_left, diag = carry
+        # Row i-1's right column (lane T-1) into column i-1 of rout - pure
+        # plane ops, and a row late, so its lane broadcast too pops under
+        # this row's scan (row 0 finds no lane -1 and leaves rout alone).
+        rout = jnp.where(lane == i - 1, hprev[:, T - 1 :], rout)
+        sub_next, left_next = ahead(jnp.minimum(i + 1, T - 1))
         diag = jnp.where(lane == 0, prev_left, diag)
         cand = jnp.maximum(diag + sub, hprev - GAP)
         cand = jnp.maximum(cand, jnp.where(lane == 0, this_left - GAP, NEG))
-        scan = _cummax_lanes(cand + lane * GAP) - lane * GAP
-        hrow = jnp.maximum(scan, 0)
+        # The scan's last stage makes the next row's diagonal with it: the
+        # row waits on the scan's trips alone, none for a roll of hrow.
+        scan, left = _cummax_lanes(cand + lane * GAP, shifted=True)
+        hrow = jnp.maximum(scan - lane * GAP, 0)
+        diag = jnp.maximum(left - (lane - 1) * GAP, 0)
         if with_h:
             vh[:, pl.ds(i, 1), :] = hrow[:, None, :]
-        # Accumulate the right column (lane T-1 of each row) into column i
-        # of rout - pure plane ops, no scalar extracts in the hot loop.
-        rcol = hrow[:, T - 1 :]
-        rout = jnp.where(lane == i, rcol, rout)
         mplane = jnp.maximum(_mpl, hrow)
-        return hrow, rout, mplane
+        # This row's left boundary is the next row's H[i-1, j0-1].
+        return hrow, rout, mplane, sub_next, this_left, left_next, diag
 
     zero_st = jnp.zeros((S, T), jnp.int32)
-    hlast, rout, mplane = jax.lax.fori_loop(
-        0, T, row, (vtop[buf], zero_st, zero_st)
+    sub0, left0 = ahead(0)
+    hlast, rout, mplane, *_ = jax.lax.fori_loop(
+        0, T, row,
+        (vtop[buf], zero_st, zero_st, sub0,
+         jnp.broadcast_to(corner, (S, T)), left0,
+         pltpu.roll(vtop[buf], 1, axis=1)),
     )
+    rout = jnp.where(lane == T - 1, hlast[:, T - 1 :], rout)
     # Reuse this half as store staging (the prefetch lives in the other
     # half, and these stores drain before this body returns).
     vtop[buf] = hlast

@@ -380,6 +380,25 @@ def test_v5e_compiler_accepts(kernel, one_chip):
     KERNELS[kernel](one_chip)
 
 
+def test_kernel_listing_compiles_its_wave_entry(topo):
+    """``tools/kernel_listing.py --kernel wave`` (PR 54) is the build of
+    ``_sw_wave`` above, the table as the engine sizes it: its child runs
+    this very call with the compiler's dump on."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "tools", "kernel_listing.py")
+    spec = importlib.util.spec_from_file_location("kernel_listing", path)
+    kl = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kl)
+    name, compile_kernel, capacity = kl.KERNELS["wave"]
+    assert (name, capacity) == ("tpu_custom_call", 568)
+    compile_kernel(capacity)
+    with pytest.raises(SystemExit, match="568"):
+        compile_kernel(capacity + 1)
+
+
 @pytest.mark.parametrize("tenants", [False, True], ids=["steal", "tenants"])
 def test_v5e_compiler_accepts_resident_kernel_on_four_devices(topo, tenants):
     """chip_smoke.py --four-chips' program: the shard_mapped resident

@@ -225,3 +225,46 @@ def test_the_command_line_follows_one_path_of_a_named_loop(tmp_path, capsys):
         "kernel 34 bundles; loop 0x1c .. 0x1d, 6 bundles",
         "6 0x1d:!%p9_p3=T",
     ]
+
+
+# A sweep-like loop 0x1..0xa (back-branch at 0x6, its delay slots to 0xa):
+# two rolls pushed side by side on a value the loop carries round, a third
+# on what both made, a lane sum that depends on none of them, and a roll
+# in the delay slots on the third's result. Comments may name values: they
+# are not uses.
+CHAIN = """\
+     0   :  { %v1_v0 = vld [vmem:[#allocation2] sm:$0xff] }
+   0x1 LB: > { %10 = vrot.lane.b32.xlu0 %v9_v1, %s2  ;;  %11 = vrot.lane.b32.xlu1 %v9_v1, %s3 }
+   0x2   : > { %v12_v2 = vpop.permute.xlu0 %10  ;;  %v13_v3 = vpop.permute.xlu1 %11 }
+   0x3   : > { %20 = vadd.xlane.f32.xlu2 %v1_v0  ;;  %v9_v1 = vphi %v1_v0, %v30_v5 /* phi */ }
+   0x4   : > { %v14_v4 = vsel /*vm=*/%vm5_vm0, %v12_v2, /*x=*/%v13_v3 /* not %v31_v6 */ }
+   0x5   : > { %15 = vrot.lane.b32.xlu0 %v14_v4, %s2_s0 }
+   0x6   : > { %40 = sbr.rel (!%p6_p1) target bundleno = 9 (0x9), region = 3 }
+   0x7   : > { %v16_v5 = vpop.permute.xlu0 %15  ;;  %v21_v7 = vpop.xlane.xlu2 %20 }
+   0x8   : > { %v30_v5 = vmax.s32 %v16_v5, %v21_v7 }
+   0x9   : > { %31 = vrot.lane.b32.xlu1 (%p6_p1), %v30_v5, %s2_s0 }
+   0xa   : > { %v31_v6 = vpop.permute.xlu1 %31 }
+   0xb   :  { %50 = vst [vmem:[#allocation3] sm:$0xff] %v31_v6 }
+"""
+
+
+@pytest.mark.parametrize("mnemonics,want", [
+    (["vrot.lane"], (4, 3)),  # 10 | 11 -> 15 -> 31
+    (["vadd.xlane"], (1, 1)),
+    (["vrot.lane", "vadd.xlane"], (5, 3)),  # the lane sum joins at depth 1
+    (["vrot", "vadd"], (5, 3)),  # a mnemonic is a prefix
+    (["vperm"], (0, 0)),
+])
+def test_chain_depth_follows_definitions_and_uses(mnemonics, want):
+    bundles = lp.parse(CHAIN.splitlines())
+    head, back = lp.scheduler_loop(lp.branches(bundles))
+    assert (head, back) == (1, 6)
+    assert lp.chain_depth(bundles, head, back, mnemonics) == want
+
+
+def test_chain_option_prints_the_count_and_the_chain(tmp_path, capsys):
+    path = tmp_path / "chain-final_bundles.txt"
+    path.write_text(CHAIN)
+    assert lp.main([str(path), "--chain", "vrot.lane"]) == 0
+    assert capsys.readouterr().out.splitlines()[-1] == (
+        "vrot.lane: 4 in the loop, 3 in series")

@@ -7,7 +7,10 @@ recurrence, and the four ``bench:sw.*`` spans of a call. The kernel at the
 cell's size is asked of the real compiler in tests/test_chip_compile.py.
 
 One ``Megakernel`` a shape: a square 512 x 512 (4 x 4 tiles, 7 waves) and
-a rectangular 384 x 640 (3 x 5 tiles, 7 waves) with the matrix kept."""
+a rectangular 384 x 640 (3 x 5 tiles, 7 waves) with the matrix kept; the
+other half of each (the square with the matrix, the rectangle without) and
+pairs one tile high and one tile wide build their own (PR 54: the sweep's
+row takes its columns from the row before it, in the loop's carry)."""
 
 import os
 import sys
@@ -73,6 +76,30 @@ def test_rectangular_pair_equals_the_plain_reference(mk_rect, seed):
     assert np.array_equal(info["last_row"], h[-1, :])
     assert np.array_equal(info["last_col"], h[:, -1])
     assert np.array_equal(h, ref.sw_naive(a, b))
+
+
+SHAPES = {  # (n, m): tiles high x tiles wide
+    "square": (512, 512),
+    "rect": (384, 640),
+    "one_tile_high": (128, 384),  # every tile's row 0 meets no tile above
+    "one_tile_wide": (384, 128),  # every tile's left boundary is the zero one
+}
+OTHER_HALF = [("square", True), ("rect", False)] + [
+    (shape, with_h) for shape in ("one_tile_high", "one_tile_wide")
+    for with_h in (False, True)]
+
+
+@pytest.mark.parametrize("shape,with_h", OTHER_HALF)
+def test_pair_equals_the_plain_reference_with_and_without_h(shape, with_h):
+    n, m = SHAPES[shape]
+    a, b = ref.make_pair(SEEDS[1] + n, n, m)
+    score, h, info = sw.device_sw_wave(a, b, interpret=True, with_h=with_h)
+    held_to_reference(a, b, score, info)
+    assert info["executed"] == (n // sw.T) * (m // sw.T)
+    if with_h:
+        assert np.array_equal(h, ref.sw_naive(a, b))
+    else:
+        assert h is None
 
 
 def test_the_four_spans_are_entered_once_a_call(mk_square, monkeypatch):

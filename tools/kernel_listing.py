@@ -11,7 +11,7 @@ PR 41 / 45 / 46): read the listing before and after a change to the
 scheduler, and count its paths with ``tools/listing_paths.py``.
 
     python tools/kernel_listing.py <outdir> [--tree DIR]
-                                   [--kernel fib|forest|search|forasync|jacobi]
+                                   [--kernel fib|forest|search|forasync|jacobi|wave]
                                    [--capacity N] [--if-conversion]
 
 ``--tree`` is the checkout to compile (default: this one; give a copy of
@@ -62,7 +62,32 @@ order of the branches, not by their numbers. ``forasync`` is
 ``forasync-2d-hbm``'s build and ``jacobi`` ``jacobi-dep-hbm``'s (PR 51:
 eight steps, the release of a finished tile under the store wave of each
 copy of the batch body), both as ``tests/test_chip_compile.py`` compiles
-them, grids on the device.
+them, grids on the device. ``wave`` is ``sw-wave-8192``'s build as
+``tests/test_chip_compile.py:_sw_wave`` compiles it (``with_h=False``, the
+table as the engine sizes it: 568 rows, and ``--capacity`` may name no
+other). Its listing holds ONE copy of the batch body, and the sweep
+(``smithwaterman.py:_sw_wave_batch_kernel``, phase 4) is the ``LB:`` whose
+body holds the kernel's ``vrot.lane.b32``: from it to the first ``sbr.rel``
+and its four delay slots is a ROW, 128 of them a round. A row costs what it
+waits for, not its length: every ``vrot.lane`` / ``vadd.xlane`` / ``vperm``
+is pushed to the cross-lane unit and popped a latency later, so count the
+pushes in series, ``tools/listing_paths.py <listing> --loop 0x<that LB>
+--chain vrot.lane`` (PR 54). The parent of PR 54 (4,885 bundles, the loop
+at ``0xc9a``): 155 bundles a row, 16 ``vrot.lane`` (the diagonal's roll and
+a seven-stage radix-2 scan, two vregs each), 12 ``vadd.xlane.f32`` (three
+columns taken by a masked int32 lane sum, four each) and 4 ``vperm``, 32
+pushes; EIGHT ``vrot`` in series, TEN pushes in series with the column in
+front of them and the right column's ``vperm`` behind. PR 54 (4,899
+bundles, the loop at ``0xcac``): 146 bundles a row, 34 ``vrot.lane`` (a
+three-stage radix-8 scan of 7, 7 and 1 rolls whose last stage makes the
+next row's diagonal too, two more rolls; two vregs each), no
+``vadd.xlane`` (a column is one lane gather a vreg, a ``vperm`` with the
+row's number as its pattern, taken a row ahead), 6 ``vperm`` (two more
+stand in the loop's tail under the exit's predicate: the last row's right
+column), 40 pushes; THREE ``vrot`` in series and nothing else in a row's
+chain. On the chip a push in series costs 76 ns and a push more 2-4 ns
+(``PERF.md`` section 6, PR 54): the parent's row took 0.81 us for its 155
+bundles, this one takes 0.31.
 The child's output goes to ``<outdir>/compile.log``; with
 ``--if-conversion`` the compiler's if-conversion pass logs into it which
 ``pl.when`` / ``lax.cond`` regions it predicated and which it kept as
@@ -242,6 +267,19 @@ def _compile_loop(loop: str, capacity: int, on_device, **kw) -> None:
     )
 
 
+def _compile_wave(capacity: int) -> None:
+    """``sw-wave-8192``'s build (``tests/test_chip_compile.py:_sw_wave``);
+    the engine sizes the table itself (568 descriptors)."""
+    from hclib_tpu.device.smithwaterman import T, make_sw_wave_megakernel
+
+    nt = 8192 // T
+    mk = make_sw_wave_megakernel(nt, nt, interpret=False, with_h=False)
+    if capacity != mk.capacity:
+        raise SystemExit(f"the wave engine sizes its own table: "
+                         f"{mk.capacity} rows, not --capacity {capacity}")
+    _compile_mk(mk, 1 << 22)
+
+
 def _compile_forasync(capacity: int) -> None:
     _compile_loop("stencil_loop", capacity, ("gin", "gout"))
 
@@ -260,6 +298,7 @@ KERNELS = {
     "search": ("tpu_custom_call", _compile_search, 128),
     "forasync": ("tpu_custom_call", _compile_forasync, 64),
     "jacobi": ("tpu_custom_call", _compile_jacobi, 99),
+    "wave": ("tpu_custom_call", _compile_wave, 568),
 }
 
 
@@ -269,7 +308,8 @@ def main(argv=None) -> int:
     ap.add_argument("--tree", default=os.path.dirname(_HERE))
     ap.add_argument("--kernel", choices=sorted(KERNELS), default="fib")
     ap.add_argument("--capacity", type=int, default=None,
-                    help="table rows (default: the cell's, 768 / 640 / 128 / 64 / 99)")
+                    help="table rows (default: the cell's, 768 / 640 / 128 / 64 / 99 "
+                    "/ 568)")
     ap.add_argument("--if-conversion", action="store_true")
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     a = ap.parse_args(argv)

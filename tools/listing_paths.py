@@ -31,8 +31,19 @@ prints that path's length and decisions. So the round that does nothing
 in the ``search`` listing is ``--take`` of the three branches that jump
 the starved phase's batch body, the drain phase's and the pop.
 
+``--chain vrot.lane,...`` reads a loop whose cost is not its length but
+what it WAITS for (the wavefront's sweep, ``kernel_listing.py --kernel
+wave``; PR 54): it counts those operations in the loop named by ``--loop``
+and the longest chain of them in which each takes a value the one before
+it made, by definition and use through the loop's bundles in order (a
+value the loop carries round counts from 0, memory is not followed). A
+push to the cross-lane unit (``vrot.lane``, ``vadd.xlane``, ``vperm``) is
+popped a latency later, so that chain times the latency is the least a
+trip of the loop can take however short its listing.
+
     python tools/listing_paths.py <listing> [--count sdivrem,sand,spop]
                                   [--loop 0x<head>] [--take 0x<b>,0x<b>]
+                                  [--chain vrot.lane,vadd.xlane]
 """
 
 from __future__ import annotations
@@ -199,6 +210,35 @@ def count_ops(bundles: List[Bundle], mnemonic: str) -> int:
     return sum(len(pat.findall(b.text)) for b in bundles)
 
 
+_COMMENT = re.compile(r"/\*.*?\*/")
+_OP = re.compile(r"^(%\w+) = ([\w.]+)(.*)$")
+_VALUE = re.compile(r"%\w+")
+
+
+def chain_depth(
+    bundles: List[Bundle], head: int, back: int, mnemonics: Sequence[str]
+) -> Tuple[int, int]:
+    """``(operations, longest dependent chain)`` of the operations whose
+    name starts with one of ``mnemonics`` in the loop ``head .. back`` and
+    its delay slots."""
+    depth: Dict[str, int] = {}
+    ops = 0
+    for b in bundles[head:back + 1 + DELAY_SLOTS]:
+        made = {}
+        for op in _COMMENT.sub("", b.text).split(";;"):
+            m = _OP.match(op.strip().rstrip("}").strip())
+            if not m:
+                continue
+            counted = m.group(2).startswith(tuple(mnemonics))
+            ops += counted
+            made[m.group(1)] = counted + max(
+                (depth.get(v, 0) for v in _VALUE.findall(m.group(3))),
+                default=0,
+            )
+        depth.update(made)  # a bundle's operations read what stood before it
+    return ops, max(depth.values(), default=0)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("listing")
@@ -210,6 +250,9 @@ def main(argv=None) -> int:
     ap.add_argument("--take", default=None,
                     help="follow one path: these branches taken, "
                     "comma-separated, every other one not")
+    ap.add_argument("--chain", default=None,
+                    help="count these operations in the loop and the "
+                    "longest dependent chain of them, comma-separated")
     a = ap.parse_args(argv)
     with open(a.listing) as f:
         bundles = parse(f)
@@ -218,6 +261,10 @@ def main(argv=None) -> int:
           f"{back + 1 + DELAY_SLOTS - head} bundles")
     for name in filter(None, a.count.split(",")):
         print(f"{name} {count_ops(bundles, name)}")
+    if a.chain is not None:
+        ops, chain = chain_depth(bundles, head, back, a.chain.split(","))
+        print(f"{a.chain}: {ops} in the loop, {chain} in series")
+        return 0
     if a.take is not None:
         paths, whole = [follow(
             bundles, [int(x, 0) for x in a.take.split(",") if x], a.loop
