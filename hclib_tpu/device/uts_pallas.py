@@ -40,18 +40,23 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..models.uts import UTSParams
+from ..models.uts import BIN, UTSParams
 from ..runtime.spans import span
 from .megakernel import resolve_interpret
 from .uts_vec import (
+    FRAME_WORDS,
     LANES,
+    _call_bin,
     _engine_shape,
+    _geo_only,
     _launch_once,
     _seeded,
     apply_claim,
     inrow_threshold_table,
+    make_bin_traversal,
     make_traversal,
     padded_threshold_table,
+    pool_of,
 )
 
 __all__ = ["uts_pallas"]
@@ -276,9 +281,133 @@ def _uts_dfs_pallas(
     )
 
 
+def _bin_kernel(
+    S: int,
+    lanes: tuple,
+    every: int,
+    slabs0: int,
+    pool_slabs: int,
+    unroll: bool,
+    # refs
+    scal_ref,  # SMEM (4,): R (roots), below, m, max_steps
+    pool_in_ref,  # ANY (pool_slabs, FRAME_WORDS, rows, 128) i32: the pool,
+    # its first slabs0 slabs the roots (aliased to pool_ref)
+    nodes_ref, leaves_ref, maxd_ref, spmax_ref,  # VMEM lanes, outputs
+    ctl_ref,  # SMEM (9,): steps, unfinished, rounds, then the pool's six
+    pool_ref,  # ANY: the pool, in place
+    ebuf, sem,  # scratch: one slab in VMEM (FRAME_WORDS, rows, 128), DMA
+) -> None:
+    """The binomial traversal, whole, in one resident kernel: the step,
+    the balance round and the driver are uts_vec's (``make_bin_traversal``,
+    its steps written out if ``unroll``); what is the kernel's own is the
+    pool's far end, whole slabs between the exchange buffer and HBM by one
+    DMA each."""
+    del pool_in_ref  # the same buffer as pool_ref
+
+    def spill(_, do, k, planes):
+        @pl.when(do)
+        def _():
+            for w in range(FRAME_WORDS):
+                ebuf[w] = planes[w]
+            cp = pltpu.make_async_copy(ebuf, pool_ref.at[k], sem.at[0])
+            cp.start()
+            cp.wait()
+
+    def fetch(_, do, k):
+        @pl.when(do)
+        def _():
+            cp = pltpu.make_async_copy(
+                pool_ref.at[jnp.maximum(k, 0)], ebuf, sem.at[0])
+            cp.start()
+            cp.wait()
+
+        return tuple(ebuf[w] for w in range(FRAME_WORDS))
+
+    run = make_bin_traversal(
+        S, lanes, scal_ref[3], scal_ref[0], below=scal_ref[1],
+        m=scal_ref[2], every=every, slabs0=jnp.int32(slabs0),
+        pool_slabs=pool_slabs, pstate=None, spill=spill, fetch=fetch,
+        roll_rows=lambda x: pltpu.roll(x, 1, 0), unroll=unroll,
+    )
+    nodes, leaves, maxd, spmax, steps, unfinished, rounds, counters = run()
+    nodes_ref[...] = nodes
+    leaves_ref[...] = leaves
+    maxd_ref[...] = maxd
+    spmax_ref[...] = spmax
+    ctl_ref[0] = steps
+    ctl_ref[1] = unfinished.astype(jnp.int32)
+    ctl_ref[2] = rounds
+    for i, c in enumerate(counters):
+        ctl_ref[3 + i] = c
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "stack_size", "lanes", "every", "pool_slabs", "interpret",
+        "unroll", "vmem_limit_bytes",
+    ),
+)
+def _uts_bin_pallas(
+    slabs,  # (n0, FRAME_WORDS, rows, 128) i32 - the roots, as slabs
+    scal,  # (4,) i32 - [R, below, m, max_steps]
+    stack_size: int,
+    lanes: tuple,
+    every: int,
+    pool_slabs: int,
+    interpret: bool = False,
+    unroll: Optional[bool] = None,
+    vmem_limit_bytes: int = 100 * 2**20,
+):
+    # The compiled kernel writes its steps out; the interpreter keeps them
+    # in a loop (written out, XLA's CPU backend compiles a ring of 2 in
+    # 14 s for 5 and a ring of 8 in 53) unless a test asks for the driver
+    # the chip runs.
+    unroll = not interpret if unroll is None else unroll
+    pool = pool_of(slabs, pool_slabs)
+    i32 = jnp.int32
+    plane = jax.ShapeDtypeStruct(lanes, i32)
+    kernel = pl.pallas_call(
+        functools.partial(
+            _bin_kernel, stack_size, lanes, every, slabs.shape[0],
+            pool.shape[0], unroll,
+        ),
+        out_shape=(
+            plane, plane, plane, plane,  # nodes, leaves, maxd, spmax
+            jax.ShapeDtypeStruct((9,), i32),
+            jax.ShapeDtypeStruct(pool.shape, i32),
+        ),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=tuple(
+            [pl.BlockSpec(memory_space=pltpu.VMEM)] * 4
+            + [pl.BlockSpec(memory_space=pltpu.SMEM),
+               pl.BlockSpec(memory_space=pl.ANY)]
+        ),
+        scratch_shapes=[
+            pltpu.VMEM(slabs.shape[1:], i32),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        input_output_aliases={1: 5},
+        # a name that starts uts_dfs: the trace patterns of the UTS
+        # metrics find both kernels
+        name="uts_dfs_bin",
+        interpret=interpret,
+        compiler_params=(
+            None
+            if interpret
+            else pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
+        ),
+    )
+    nodes, leaves, maxd, spmax, ctl, _ = kernel(scal, pool)
+    return (nodes, leaves, maxd, ctl[0], ctl[1] != 0, ctl[2], spmax, ctl[3:])
+
+
 def uts_pallas(
     params: UTSParams,
-    target_roots: int = 16 * LANES[0] * LANES[1],
+    target_roots: Optional[int] = None,
     max_steps: Optional[int] = None,
     device=None,
     lanes: Tuple[int, int] = LANES,
@@ -288,6 +417,7 @@ def uts_pallas(
     vmem_limit_bytes: int = 100 * 2**20,
     stack_pad: Optional[int] = None,
     table_cols: Optional[int] = None,
+    stack_size: Optional[int] = None,
 ) -> dict:
     """uts_vec with the whole traversal fused into one Pallas kernel; same
     exact counts, same seeding (``uts_vec._seeded``: the tree's top on the
@@ -307,7 +437,13 @@ def uts_pallas(
 
     One call is one traversal: one seeding, one launch of the kernel, one
     readback. ``device_seconds`` is that launch, and the first launch of a
-    shape compiles: a caller that wants a rate calls twice."""
+    shape compiles: a caller that wants a rate calls twice.
+
+    A BINOMIAL tree runs as ``uts_vec`` says (its keyword ``stack_size``),
+    fused likewise:
+    the lanes' rings and the exchange buffer in VMEM, the pool's slabs in
+    HBM, moved by one DMA each, and no return to the host before the tree
+    is counted."""
     if lanes[1] != 128:
         raise ValueError("uts_pallas lanes must be (rows, 128)")
     interpret = resolve_interpret(interpret)
@@ -315,6 +451,17 @@ def uts_pallas(
         max_steps = (1 << 31) - 1
     rows, cols = lanes
     nlanes = rows * cols
+    if params.tree == BIN:
+        return _call_bin(
+            "uts_pallas", _uts_bin_pallas, params, lanes, device, max_steps,
+            stack_size,
+            dict(target_roots=target_roots, depth_bound=depth_bound,
+                 stack_pad=stack_pad, table_cols=table_cols),
+            interpret=interpret, vmem_limit_bytes=vmem_limit_bytes,
+        )
+    _geo_only(stack_size)
+    if target_roots is None:
+        target_roots = 16 * LANES[0] * LANES[1]
     # Padded so any aligned window [align_down(next_root), +nlanes+ALIGN)
     # is in bounds (next_root <= R), laid out as (Rrows, 128) for row-block
     # DMA. PAD_QUANTUM (a multiple of ALIGN) keeps trees with different
@@ -369,8 +516,9 @@ def uts_pallas(
 if __name__ == "__main__":  # pragma: no cover
     import sys
 
-    from ..models.uts import T1, T1L, T3
+    from ..models.uts import T1, T1L, T3, T3L, T_TINY
 
-    name = sys.argv[1] if len(sys.argv) > 1 else "T3"
-    params = {"T1": T1, "T1L": T1L, "T3": T3}[name]
+    name = sys.argv[1] if len(sys.argv) > 1 else "T_TINY"
+    params = {"T1": T1, "T1L": T1L, "T3": T3, "T3L": T3L,
+              "T_TINY": T_TINY}[name]
     print(uts_pallas(params))

@@ -35,7 +35,20 @@ operation vectorized across (rows, 128) VPU planes:
   pads and lays out the roots (uts_seed_roots), so they never visit the
   host: T1L's 239,628 roots are ready 25 ms after the call (PERF.md).
 
-Supports every GEO shape: FIXED (canonical T1/T1L/T1XL/T3) on the
+Binomial trees (``-t 0``; canonical T3/T3L, 17,844 levels deep on levels
+narrower than the lanes are many) take another road through the same entry
+points, because nothing above holds for them: no level is wide enough to
+seed from, so the seeding is the root's children and no more; no static
+stack is tall enough, so a lane's stack is a ring of a few frames that
+gives its BOTTOM frame away (the owner keeps the top, the oldest work
+goes: src/hclib-deque.c's discipline); and no list of roots made in
+advance can balance subtrees whose sizes no root betrays, so every lane
+that holds two frames or more gives, and starved lanes take what was
+given, through an exchange buffer beside the lanes whose overflow is a
+pool in HBM, inside the one launch (make_balance). A geometric tree's
+engine holds none of this: the two have their own step and driver.
+
+Supports every GEO shape: FIXED (canonical T1/T1L/T1XL) on the
 depth-independent threshold fast path, LINEAR/CYCLIC (canonical T5/T2) and
 EXPDEC via exact per-depth threshold tables (one row of integer thresholds
 per depth from the f64 shape function, -1 padded; the device gathers its
@@ -48,7 +61,9 @@ hand-written kernel; it also runs on the CPU backend for tests.
 from __future__ import annotations
 
 import functools
+import hashlib
 import math
+import struct
 import time
 from typing import List, NamedTuple, Optional, Tuple
 
@@ -57,7 +72,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.uts import (
-    CYCLIC, FIXED, LINEAR, UTSParams, _branching, root_state,
+    BIN, CYCLIC, FIXED, LINEAR, UTSParams, _branching, bin_threshold,
+    root_state,
 )
 from ..ops.sha1 import sha1_child as _sha1_child, sha1_children_np
 from ..runtime.spans import span
@@ -68,7 +84,7 @@ __all__ = [
     "inrow_threshold_table", "padded_threshold_table", "MAX_CHILDREN",
     "PAD_QUANTUM",
     "LANES", "NLANES", "make_count_children", "make_dfs_step",
-    "make_refill",
+    "make_refill", "make_bin_step", "make_balance", "make_bin_traversal",
 ]
 
 LANES = (8, 128)
@@ -316,6 +332,207 @@ def make_dfs_step(
     return step
 
 
+def make_bin_step(S: int, lanes: tuple, below, m):
+    """``make_dfs_step`` for a binomial tree, shared by both engines like
+    it; ``below`` and ``m`` are runtime scalars. A binomial node's count is
+    one compare (``m`` children iff r < below, whatever its depth), so a
+    frame carries no count and no table is looked up; the stack is a RING
+    of S planes (S a power of two) indexed by a per-lane ``top``, so the
+    balance round can take the BOTTOM frame away without moving the
+    others; and a push that finds the ring full stalls (the lane repeats
+    the hash) until the next balance round has taken its bottom frame.
+    Signature:
+    (sp, top, nodes, leaves, maxd, spmax, st, ch, dp) -> same tuple."""
+    assert S >= 2 and S & (S - 1) == 0, S
+
+    def step(sp, top, nodes, leaves, maxd, spmax, st, ch, dp):
+        active = sp >= 0
+        child = _level_select(ch, top)
+        depth = _level_select(dp, top)
+        state = [
+            _level_select(tuple(st[L][i] for L in range(S)), top)
+            for i in range(5)
+        ]
+        cstate = _sha1_child(state, child, jnp)
+        r = (cstate[4] & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+        nonleaf = r < below
+        last = child + 1 >= m
+        # Tail-call scheduling as in the geometric step: every frame on
+        # the ring has a child left, so every active lane hashes one. A
+        # push onto a full ring waits: nothing of the lane changes, and
+        # the balance round takes its bottom frame (sp >= its threshold).
+        expand = active & ~(nonleaf & ~last & (sp >= S - 1))
+        cdepth = depth + 1
+        nodes = nodes + expand.astype(jnp.int32)
+        leaves = leaves + (expand & ~nonleaf).astype(jnp.int32)
+        maxd = jnp.maximum(maxd, jnp.where(expand, cdepth, 0))
+        push = expand & nonleaf & ~last
+        tail = expand & nonleaf & last
+        pop = expand & ~nonleaf & last
+        ch = _level_store(ch, top, child + 1, expand & ~last)
+        nxt = (top + 1) & (S - 1)
+        lvl = jnp.where(push, nxt, top)
+        newf = push | tail
+        st = tuple(
+            tuple(
+                jnp.where(newf & (lvl == L), cstate[i], st[L][i])
+                for i in range(5)
+            )
+            for L in range(S)
+        )
+        ch = _level_store(ch, lvl, jnp.zeros(lanes, jnp.int32), newf)
+        dp = _level_store(dp, lvl, cdepth, newf)
+        sp = jnp.where(push, sp + 1, jnp.where(pop, sp - 1, sp))
+        top = jnp.where(push, nxt, jnp.where(pop, (top - 1) & (S - 1), top))
+        return (sp, top, nodes, leaves, maxd, jnp.maximum(spmax, sp),
+                st, ch, dp)
+
+    return step
+
+
+FRAME_WORDS = 7  # a pooled frame: five state words, next child, depth
+
+
+def row_cumsum(mask, lanes: tuple):
+    """Inclusive prefix sum of a 0/1 mask along each row of 128 lanes, as
+    one product with a triangular matrix (exact: sums <= 128), and the
+    rows' totals broadcast over their rows."""
+    cols = lanes[1]
+    upper = (
+        jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 0)
+        <= jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 1)
+    ).astype(jnp.float32)
+    ranks = jnp.dot(
+        mask.astype(jnp.float32), upper, preferred_element_type=jnp.float32
+    ).astype(jnp.int32)
+    return ranks, jnp.broadcast_to(ranks[:, cols - 1:cols], lanes)
+
+
+def row_total(mask, lanes: tuple):
+    """A 0/1 mask's row sums broadcast over their rows (a lane reduce;
+    exact in f32: sums <= 128)."""
+    total = jnp.sum(mask.astype(jnp.float32), axis=1, keepdims=True)
+    return jnp.broadcast_to(total.astype(jnp.int32), lanes)
+
+
+def _as_i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _as_u32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+
+def make_balance(S: int, lanes: tuple, pool_slabs: int, spill, fetch,
+                 roll_rows):
+    """The balance round of a binomial traversal: the work-stealing half of
+    the reference (src/hclib-deque.c:75-106: the owner works at the top of
+    its deque, what leaves it leaves from the BOTTOM), recast for lanes
+    that cannot address each other.
+
+    The pool's near end is an exchange buffer ``E`` of FRAME_WORDS planes
+    beside the lanes: row i of it holds ``e[i]`` frames at its front. A
+    round, in order: an empty ``E`` is filled with the pool's newest slab
+    from HBM (``fetch``); every lane that holds two frames or more gives
+    (three lanes in four are starved on a critical tree, so nobody waits
+    to be asked, and a ring that gives a frame a round is never full for
+    longer than a round): it appends its BOTTOM frame to row i by its rank
+    among the givers, the inverse gather (a binary search of the ranks)
+    and one in-row gather a word; if a row cannot hold what it is given, ``E``
+    first leaves for HBM whole, as the pool's next slab (``spill``);
+    starved lanes (sp < 0) of lane row i take the LAST frames of ``E``'s
+    row i, this round's gifts first, by their rank in the row, one in-row
+    gather a word (gifts before claims: a frame on the tree's critical
+    path changes hands often, and each round it waits in ``E`` is
+    ``every`` steps of the whole call; PERF.md, PR 56); then ``E`` turns
+    by one row, so that what row i could not use feeds row i + 1 next
+    round and every row in 64. Only in-row gathers, one-row turns and whole-slab copies: no
+    compaction across rows, nothing Mosaic lacks. No frame is dropped or
+    handed out twice: a slot of ``E`` is live iff its column is below
+    ``e``, claims shorten ``e`` by what they took, gifts lengthen it by
+    what they put. A full pool sets ``err`` and the traversal stops.
+
+    ``spill(pstate, do, k, planes) -> pstate`` and ``fetch(pstate, do, k)
+    -> planes`` are the engine's (a DMA in the kernel, a slice of a carried
+    array in XLA), ``roll_rows`` its one-row turn. Returns
+    ``balance(lane, pool) -> (lane, pool)``, lane = (sp, top, st, ch, dp),
+    pool = (E, e, slabs, pstate, donated, claimed, moved_rounds, err,
+    spills)."""
+    rows, cols = lanes
+    col = jax.lax.broadcasted_iota(jnp.int32, lanes, 1)
+    search_steps = (cols - 1).bit_length()
+
+    def first_at_least(ranks, target):
+        """Per slot, the first column whose rank reaches ``target``."""
+        lo = jnp.zeros(lanes, jnp.int32)
+        hi = jnp.full(lanes, cols - 1, jnp.int32)
+        for _ in range(search_steps):
+            mid = (lo + hi) >> 1
+            ge = jnp.take_along_axis(ranks, mid, axis=1) >= target
+            hi = jnp.where(ge, mid, hi)
+            lo = jnp.where(ge, lo, mid + 1)
+        return lo
+
+    def balance(lane, pool):
+        sp, top, st, ch, dp = lane
+        E, e, slabs, pstate, donated, claimed, moved, err, spills = pool
+        # 0. an empty exchange takes the newest slab
+        load = (jnp.sum(e) == 0) & (slabs > 0)
+        slab = fetch(pstate, load, slabs - 1)
+        E = tuple(jnp.where(load, s, x) for s, x in zip(slab, E))
+        e = jnp.where(load, row_total(slab[6] > 0, lanes), e)
+        slabs = slabs - load.astype(jnp.int32)
+        # 1. givers append their bottom frames
+        giver = sp >= 1
+        rank, given = row_cumsum(giver, lanes)
+        flush = jnp.any(e + given > cols)
+        err = err | (flush & (slabs >= pool_slabs)).astype(jnp.int32)
+        pstate = spill(
+            pstate, flush, jnp.minimum(slabs, pool_slabs - 1),
+            E[:6] + (jnp.where(col < e, E[6], 0),),
+        )
+        e = jnp.where(flush, 0, e)
+        slabs = slabs + flush.astype(jnp.int32)
+        bottom = (top - sp) & (S - 1)
+        frame = [
+            _as_i32(_level_select(tuple(st[L][i] for L in range(S)), bottom))
+            for i in range(5)
+        ] + [_level_select(ch, bottom), _level_select(dp, bottom)]
+        source = first_at_least(rank, col - e + 1)
+        put = (col >= e) & (col < e + given)
+        E = tuple(
+            jnp.where(put, jnp.take_along_axis(w, source, axis=1), x)
+            for w, x in zip(frame, E)
+        )
+        e = e + given
+        sp = jnp.where(giver, sp - 1, sp)
+        # 2. starved lanes take the row's last frames, this round's gifts
+        # first: a frame that changes hands waits for no second round
+        starved = sp < 0
+        rank, want = row_cumsum(starved, lanes)
+        claim = starved & (rank <= e)
+        at = jnp.clip(e - rank, 0, cols - 1)
+        got = [jnp.take_along_axis(x, at, axis=1) for x in E]
+        st = (tuple(jnp.where(claim, _as_u32(got[i]), st[0][i])
+                    for i in range(5)),) + st[1:]
+        ch = (jnp.where(claim, got[5], ch[0]),) + ch[1:]
+        dp = (jnp.where(claim, got[6], dp[0]),) + dp[1:]
+        sp = jnp.where(claim, 0, sp)
+        top = jnp.where(claim, 0, top)
+        e = e - jnp.minimum(e, want)
+        # 3. the exchange turns by one row
+        E = tuple(roll_rows(x) for x in E)
+        e = roll_rows(e)
+        gave = jnp.sum(giver.astype(jnp.int32))
+        took = jnp.sum(claim.astype(jnp.int32))
+        pool = (E, e, slabs, pstate, donated + gave, claimed + took,
+                moved + (gave + took > 0).astype(jnp.int32), err,
+                spills + flush.astype(jnp.int32))
+        return (sp, top, st, ch, dp), pool
+
+    return balance
+
+
 def apply_claim(claim, rst, rcn, d0, sp, st0, ch0, cn0, dp0):
     """Install gathered roots into level 0 of claiming lanes (the shared
     tail of every refill implementation)."""
@@ -444,6 +661,77 @@ def make_traversal(
     return run
 
 
+def make_bin_traversal(S, lanes, max_steps, R, *, below, m, every, slabs0,
+                       pool_slabs, pstate, spill, fetch, roll_rows,
+                       unroll=False):
+    """``make_traversal`` for a binomial tree, shared by both engines like
+    it: a balance round (``make_balance``) in the refill's place, then
+    ``every`` steps, until no lane holds a frame and the pool none; the
+    last steps of a traversal may find no lane active, and ``steps`` counts
+    them too. ``unroll`` writes the steps out (the compiled kernel: a loop
+    of their own costs a copy of every loop-carried plane a trip, a fifth
+    of a step's bundles by the v5e compiler's listing, PERF.md, PR 56;
+    XLA's CPU backend, which fuses one hash into the next and is three to
+    ten times as long over the compile, keeps the loop). ``max_steps`` is a runtime scalar here.
+    The pool starts as ``slabs0`` slabs holding the ``R`` roots (the root's
+    non-leaf children) and every lane starved. Returns run() -> (nodes,
+    leaves, maxd, spmax, steps, unfinished, rounds, (donated, claimed, rounds
+    in which a frame moved, the pool's high-water mark, pool full, slabs
+    spilled to HBM))."""
+    step = make_bin_step(S, lanes, below, m)
+    balance = make_balance(S, lanes, pool_slabs, spill, fetch, roll_rows)
+
+    def pooled(pool):
+        return R + pool[4] - pool[5]  # frames neither in a lane nor done
+
+    def work_left(sp, pool):
+        # by what is there, not by the counters: a frame lost or doubled
+        # must end the loop and fail the conservation check, not spin
+        return jnp.any(sp >= 0) | (jnp.sum(pool[1]) > 0) | (pool[2] > 0)
+
+    def outer_cond(carry):
+        sp, pool, steps = carry[0], carry[-4], carry[-3]
+        return (work_left(sp, pool) & (steps < max_steps) & (pool[7] == 0))
+
+    def outer_body(carry):
+        (sp, top, nodes, leaves, maxd, spmax, st, ch, dp, pool, steps,
+         rounds, pool_max) = carry
+        pool_max = jnp.maximum(pool_max, pooled(pool))
+        (sp, top, st, ch, dp), pool = balance((sp, top, st, ch, dp), pool)
+        lane = (sp, top, nodes, leaves, maxd, spmax, st, ch, dp)
+        if unroll:
+            for _ in range(every):
+                lane = step(*lane)
+        else:
+            lane = jax.lax.fori_loop(
+                0, every, lambda _, lane: step(*lane), lane)
+        sp, top, nodes, leaves, maxd, spmax, st, ch, dp = lane
+        steps = steps + every
+        return (sp, top, nodes, leaves, maxd, spmax, st, ch, dp, pool, steps,
+                rounds + 1, jnp.maximum(pool_max, pooled(pool)))
+
+    def run():
+        zeros = jnp.zeros(lanes, jnp.int32)
+        uzeros = jnp.zeros(lanes, jnp.uint32)
+        zero = jnp.int32(0)
+        pool = (tuple(zeros for _ in range(FRAME_WORDS)), zeros, slabs0,
+                pstate, zero, zero, zero, zero, zero)
+        carry = (
+            jnp.full(lanes, -1, jnp.int32), zeros, zeros, zeros, zeros,
+            jnp.full(lanes, -1, jnp.int32),
+            tuple(tuple(uzeros for _ in range(5)) for _ in range(S)),
+            tuple(zeros for _ in range(S)), tuple(zeros for _ in range(S)),
+            pool, zero, zero, zero,
+        )
+        (sp, _, nodes, leaves, maxd, spmax, _, _, _, pool, steps, rounds,
+         pool_max) = jax.lax.while_loop(outer_cond, outer_body, carry)
+        unfinished = work_left(sp, pool)
+        return (nodes, leaves, maxd, spmax, steps, unfinished, rounds,
+                (pool[4], pool[5], pool[6], pool_max, pool[7], pool[8]))
+
+    return run
+
+
 def _engine_shape(params: UTSParams, d0: int, depth_bound, stack_pad):
     """Tree shape -> (thresholds, stack height, depth cap, bounded), for
     both engines. ``thresholds`` is the static tuple of the FIXED fast
@@ -521,16 +809,41 @@ def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
     wants a rate warms first (the first launch of a shape compiles) and
     times a second call. Totals are summed here in int64 (see the engines'
     return comment); ``cap`` is the depth bound to validate, None where
-    the shape's own cap is exact."""
+    the shape's own cap is exact. A binomial traversal returns two outputs
+    more, the lanes' deepest stack pointers and the pool's counters
+    (``make_bin_traversal``), which are checked and reported here too."""
     with span("uts.run"):
         t0 = time.perf_counter()
         outs = jax.block_until_ready(run())
         dt = time.perf_counter() - t0
     host_nodes, host_leaves, d0 = seed
-    nodes, leaves, maxd, steps, unfinished, refills = outs
+    nodes, leaves, maxd, steps, unfinished, refills, *pooled = outs
+    on_device = nodes
     with span("uts.readback"):
+        if pooled:
+            # eight outputs in one transfer, not one each
+            (nodes, leaves, maxd, steps, unfinished, refills, spmax,
+             counters) = jax.device_get(outs)
+            donated, claimed, moved, pool_max, full, spills = (
+                int(x) for x in np.asarray(counters)
+            )
+            if full:
+                raise RuntimeError(
+                    f"{who}: the pool is full ({result['pool_capacity']} "
+                    "frames): nowhere to spill the exchange buffer - "
+                    "uts_vec.BIN_POOL_SLABS is too small for this tree"
+                )
         if bool(unfinished):
             raise RuntimeError(f"{who} ran out of steps ({max_steps})")
+        if pooled:
+            # every frame that left a lane, and every root, was taken once
+            assert donated + result["roots"] == claimed, (
+                donated, result["roots"], claimed)
+            result.update(
+                donated=donated, claimed=claimed, balance_rounds=moved,
+                pool_max=pool_max, spills=spills,
+                stack_max=int(np.asarray(spmax).max()) + 1,
+            )
         deepest = int(np.asarray(maxd).max())
         if cap is not None and deepest >= cap:
             raise RuntimeError(
@@ -549,7 +862,7 @@ def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
             device_seconds=dt,
             nodes_per_sec=dev_nodes / dt if dt > 0 else float("inf"),
             lane_efficiency=dev_nodes / (steps * nlanes) if steps else 0.0,
-            **ran_on(nodes, interpret),
+            **ran_on(on_device, interpret),
         )
     return result
 
@@ -911,9 +1224,175 @@ def _seed_top(params: UTSParams, target_roots: int, device):
         chip_nodes += level.n
 
 
+# A binomial traversal's shape. BIN_STACK is the default of the keyword
+# ``stack_size`` (the ring's height); the other two are the engine's own:
+# the steps between two balance rounds, and the pool's capacity in HBM in
+# slabs of one frame a lane. PERF.md (PR 56) has the chip's readings of the
+# alternatives (rings of 2-8, rounds every 1-4 steps) and of the pool: T3L
+# whole on 8,192 lanes never held more than 3 slabs (the roots' one and 2
+# spilled a call), so 8 is that and as much again and a bit; a tree that
+# fills them raises.
+BIN_STACK = 2
+BIN_EVERY = 2
+BIN_POOL_SLABS = 8
+
+
+def _geo_only(stack_size):
+    if stack_size is not None:
+        raise ValueError(
+            "stack_size is a binomial tree's keyword (-t 0); this tree is "
+            "geometric"
+        )
+
+
+def _seeded_bin(params: UTSParams, lanes: tuple):
+    """A binomial tree's whole seeding inside its span: the root and its
+    floor(b0) children, hashed on the host (hashlib: a call's host time
+    is all of its spread between processes, PERF.md, PR 56), and the
+    non-leaf ones laid out as the pool's first slabs, still on the host:
+    ``(seed, slabs, result)`` as ``_seeded`` gives them. A slab is
+    (FRAME_WORDS, rows, 128) int32 (state words as u32 bits, next child 0,
+    depth 1; depth 0 marks an empty slot), its frames dealt round-robin
+    over the rows and each row filled from its front, which is how the
+    balance round reads it."""
+    rows, cols = lanes
+    nlanes = rows * cols
+    with span("uts.seed"):
+        t0 = time.perf_counter()
+        b0 = int(math.floor(params.b0))
+        root = hashlib.sha1(root_state(params.root_seed))
+        digests = []
+        for i in range(b0):  # SHA1(root's state || BE32(i))
+            child = root.copy()
+            child.update(struct.pack(">i", i))
+            digests.append(child.digest())
+        kids = np.frombuffer(b"".join(digests), ">u4").reshape(
+            b0, 5).T.astype(np.uint32)
+        r = (kids[4] & np.uint32(0x7FFFFFFF)).astype(np.int64)
+        keep = kids[:, r < bin_threshold(params.q)]
+        R = keep.shape[1]
+        slabs = None
+        if R:
+            n0 = -(-R // nlanes)
+            flat = np.zeros((FRAME_WORDS, n0 * nlanes), np.uint32)
+            flat[:5, :R] = keep
+            flat[6, :R] = 1
+            # frame f of a slab -> row f % rows, column f // rows
+            slabs = np.ascontiguousarray(
+                flat.reshape(FRAME_WORDS, n0, cols, rows)
+                .transpose(1, 0, 3, 2)
+            ).view(np.int32)
+        seed_seconds = time.perf_counter() - t0
+    result = {
+        "host_seed_nodes": 1 + b0,
+        "roots": R,
+        "seed_seconds": seed_seconds,
+        "seed_levels_on_chip": 0,
+        "seed_nodes_on_chip": 0,
+    }
+    seed = (1 + b0, max(b0, 1) - R, 1 if b0 else 0)
+    if slabs is None:
+        result.update(nodes=seed[0], leaves=seed[1], max_depth=seed[2],
+                      steps=0)
+    return seed, slabs, result
+
+
+def pool_of(slabs, pool_slabs: int):
+    """The pool in HBM at the start of a launch: ``pool_slabs`` slabs (as
+    many as the roots fill, if those are more), the first ones the roots',
+    the others empty."""
+    pool = jnp.zeros(
+        (max(pool_slabs, slabs.shape[0]),) + slabs.shape[1:], jnp.int32)
+    return jax.lax.dynamic_update_slice(pool, slabs, (0, 0, 0, 0))
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("stack_size", "lanes", "every", "pool_slabs"),
+)
+def _uts_bin(
+    slabs,  # (n0, FRAME_WORDS, rows, 128) i32 - the roots, as slabs
+    scal,  # (4,) i32 - [R, below, m, max_steps]
+    stack_size: int,
+    lanes: tuple,
+    every: int,
+    pool_slabs: int,
+):
+    pool = pool_of(slabs, pool_slabs)
+
+    def spill(pool, do, k, planes):
+        return jax.lax.cond(
+            do,
+            lambda p: jax.lax.dynamic_update_slice(
+                p, jnp.stack(planes)[None], (k, 0, 0, 0)),
+            lambda p: p,
+            pool,
+        )
+
+    def fetch(pool, do, k):
+        slab = jax.lax.dynamic_slice(
+            pool, (k, 0, 0, 0), (1,) + pool.shape[1:])[0]
+        return tuple(slab[w] for w in range(FRAME_WORDS))
+
+    run = make_bin_traversal(
+        stack_size, lanes, scal[3], scal[0], below=scal[1], m=scal[2],
+        every=every, slabs0=jnp.int32(slabs.shape[0]),
+        pool_slabs=pool.shape[0], pstate=pool, spill=spill, fetch=fetch,
+        roll_rows=lambda x: jnp.roll(x, 1, 0),
+    )
+    nodes, leaves, maxd, spmax, steps, unfinished, rounds, counters = run()
+    return (nodes, leaves, maxd, steps, unfinished, rounds, spmax,
+            jnp.stack(counters))
+
+
+def _call_bin(who, engine, params, lanes, device, max_steps, stack_size,
+              geometric: dict, **engine_kw):
+    """One binomial traversal through ``engine`` (``_uts_bin`` or
+    uts_pallas's jitted kernel): the keywords checked, the seeding, the
+    slabs and the runtime scalars sent up inside ``uts.stage``, one launch,
+    one readback. ``geometric`` holds the call's keywords that mean nothing to
+    a binomial tree (its seeding is the root's children and no more; its
+    node's count does not depend on its depth, so no table and no depth
+    cap) and raise if given; ``engine_kw`` goes to ``engine`` as it is."""
+    for name, value in geometric.items():
+        if value is not None:
+            raise ValueError(
+                f"{name} has no meaning for a binomial tree (-t 0): its "
+                "seeding is the root's children, its stack a ring of "
+                "stack_size frames that spills to the pool"
+            )
+    S = BIN_STACK if stack_size is None else int(stack_size)
+    if S < 2 or S & (S - 1):
+        raise ValueError(f"stack_size must be a power of two >= 2, got {S}")
+    seed, slabs, result = _seeded_bin(params, tuple(lanes))
+    if slabs is None:
+        return result
+    with span("uts.stage"):
+        result.update(
+            stack_size=S,
+            pool_capacity=max(BIN_POOL_SLABS, slabs.shape[0])
+            * lanes[0] * lanes[1],
+        )
+        # One upload, not waited for: the launch waits for it, and every
+        # wait of the host is a millisecond of a call that differs between
+        # processes (PERF.md, PR 56).
+        slabs, scal = jax.device_put(
+            (slabs, np.array([result["roots"], bin_threshold(params.q),
+                              params.m, max_steps], np.int32)),
+            device,
+        )
+        kw = dict(stack_size=S, lanes=tuple(lanes), every=BIN_EVERY,
+                  pool_slabs=BIN_POOL_SLABS, **engine_kw)
+    return _launch_once(
+        who, lambda: engine(slabs, scal, **kw), result, seed,
+        lanes[0] * lanes[1], max_steps, None,
+        engine_kw.get("interpret", False),
+    )
+
+
 def uts_vec(
     params: UTSParams,
-    target_roots: int = 16 * NLANES,
+    target_roots: Optional[int] = None,
     max_steps: Optional[int] = None,
     device=None,
     lanes: Tuple[int, int] = LANES,
@@ -921,6 +1400,7 @@ def uts_vec(
     depth_bound: Optional[int] = None,
     stack_pad: Optional[int] = None,
     table_cols: Optional[int] = None,
+    stack_size: Optional[int] = None,
 ) -> dict:
     """Run UTS with the vectorized DFS engine; returns counts + timing info.
 
@@ -940,10 +1420,31 @@ def uts_vec(
     compiles (as the seeding's expansions do): a caller that wants a rate
     calls twice. ``host_seed_nodes`` counts levels 0 to d0 whole, wherever
     they were hashed; ``seed_levels_on_chip`` / ``seed_nodes_on_chip`` say
-    how much of that the device did."""
+    how much of that the device did.
+
+    A BINOMIAL tree (``params.tree == BIN``) takes the same road with other
+    stations: the seeding is the root and its floor(b0) children, on the
+    host, and the non-leaf ones are the pool's first frames
+    (``target_roots`` and the depth keywords mean nothing and raise); a
+    lane's stack is a ring of ``stack_size`` frames (default BIN_STACK)
+    whose bottom frame leaves for the exchange buffer, and from there for
+    a starved lane or the pool in HBM, in the balance round that comes
+    every BIN_EVERY steps (``make_balance``); the result dict gains
+    ``donated``, ``claimed``, ``pool_max``, ``spills``, ``stack_max`` and
+    ``balance_rounds``, and ``refills`` counts the balance rounds."""
     if max_steps is None:
         max_steps = (1 << 31) - 1
     nlanes = lanes[0] * lanes[1]
+    if params.tree == BIN:
+        return _call_bin(
+            "uts_vec", _uts_bin, params, lanes, device, max_steps,
+            stack_size,
+            dict(target_roots=target_roots, depth_bound=depth_bound,
+                 stack_pad=stack_pad, table_cols=table_cols),
+        )
+    _geo_only(stack_size)
+    if target_roots is None:
+        target_roots = 16 * NLANES
     # Padded to PAD_QUANTUM (>= R + nlanes): the refill window
     # dynamic_slice never runs off the end, and trees with different root
     # counts land on the SAME padded shape, sharing one compiled engine.
@@ -982,8 +1483,9 @@ def uts_vec(
 if __name__ == "__main__":  # pragma: no cover
     import sys
 
-    from ..models.uts import T1, T1L, T3
+    from ..models.uts import T1, T1L, T3, T3L, T_TINY
 
-    name = sys.argv[1] if len(sys.argv) > 1 else "T3"
-    params = {"T1": T1, "T1L": T1L, "T3": T3}[name]
+    name = sys.argv[1] if len(sys.argv) > 1 else "T_TINY"
+    params = {"T1": T1, "T1L": T1L, "T3": T3, "T3L": T3L,
+              "T_TINY": T_TINY}[name]
     print(uts_vec(params))

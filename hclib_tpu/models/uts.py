@@ -12,9 +12,18 @@ test/uts/uts.c, test/uts/rng/brg_sha1.c) from its published algorithm:
   shape function - LINEAR: b0*(1 - d/gen_mx); EXPDEC: b0*d^(-ln b0/ln gen_mx);
   CYCLIC; FIXED: b0 while d < gen_mx else 0 - then p = 1/(1+b_i) and
   numChildren = floor(log(1-u)/log(1-p)), capped at 100 (uts.h:31).
+- BIN child count (``-t 0``, uts_numChildren_bin): the root has floor(b0)
+  children ("only a BIN root can have more than MAXNUMCHILDREN": the cap
+  does not apply to it); every other node has ``m`` children if
+  toProb(rng_rand(state)) < q and none otherwise. With m*q a hair over 1
+  every subtree is a critical branching process: nearly all die within a
+  few nodes, a few hold millions, and no look at a root says which.
 
 Canonical trees (test/uts/sample_trees.sh): T1 = GEO/FIXED d=10 b=4 r=19
-(4,130,071 nodes); T1L = GEO/FIXED d=13 b=4 r=29 (102,181,082 nodes).
+(4,130,071 nodes); T1L = GEO/FIXED d=13 b=4 r=29 (102,181,082 nodes);
+binomial T3 = -t 0 -b 2000 -q 0.124875 -m 8 -r 42 (4,112,897 nodes, depth
+1,572) and T3L = -t 0 -b 2000 -q 0.200014 -m 5 -r 7 (111,345,631 nodes,
+depth 17,844).
 
 The parallel traversal spawns one task per node (work-stealing stress). The
 device path (device/) runs the same tree with an on-chip SHA-1 in the
@@ -33,21 +42,26 @@ from typing import List, Tuple
 import hclib_tpu as hc
 
 __all__ = [
-    "UTSParams", "T1", "T1L", "T1XL", "T1XXL", "T2", "T3", "T5",
+    "UTSParams", "T1", "T1L", "T1XL", "T1XXL", "T2", "T3", "T3L", "T5",
+    "T_TINY", "BIN", "GEO", "bin_threshold",
     "count_seq", "count_parallel", "run",
 ]
 
 MAX_CHILDREN = 100  # MAXNUMCHILDREN (reference: test/uts/uts.h:31)
 
 LINEAR, EXPDEC, CYCLIC, FIXED = 0, 1, 2, 3  # geoshape enum (uts.h:65)
+BIN, GEO = 0, 1  # tree type, -t (uts.h: enum uts_trees_e)
 
 
 @dataclass(frozen=True)
 class UTSParams:
     shape: int = FIXED  # -a
     gen_mx: int = 10  # -d (tree depth)
-    b0: float = 4.0  # -b (branching factor)
+    b0: float = 4.0  # -b (branching factor; a BIN root's child count)
     root_seed: int = 19  # -r
+    tree: int = GEO  # -t
+    q: float = 15.0 / 64.0  # -q (BIN: probability of a non-leaf node)
+    m: int = 4  # -m (BIN: children of a non-leaf node)
 
 
 # Canonical trees (reference: test/uts/sample_trees.sh:18,37)
@@ -61,7 +75,10 @@ T2 = UTSParams(shape=CYCLIC, gen_mx=16, b0=6.0, root_seed=502)  # 4,117,769
 # why engine totals are summed in int64 on the host.
 T1XL = UTSParams(shape=FIXED, gen_mx=15, b0=4.0, root_seed=29)  # 1,635,119,272
 T1XXL = UTSParams(shape=FIXED, gen_mx=15, b0=4.0, root_seed=19)  # 4,230,646,601
-T3 = UTSParams(shape=FIXED, gen_mx=5, b0=4.0, root_seed=42)  # small, for tests
+T_TINY = UTSParams(shape=FIXED, gen_mx=5, b0=4.0, root_seed=42)  # for tests
+# The binomial sample trees (test/uts/sample_trees.sh, -t 0):
+T3 = UTSParams(tree=BIN, b0=2000.0, q=0.124875, m=8, root_seed=42)  # 4,112,897
+T3L = UTSParams(tree=BIN, b0=2000.0, q=0.200014, m=5, root_seed=7)  # 111,345,631
 
 
 def root_state(seed: int) -> bytes:
@@ -92,7 +109,25 @@ def _branching(params: UTSParams, depth: int) -> float:
     raise ValueError(f"unknown shape {params.shape}")
 
 
+def bin_threshold(q: float) -> int:
+    """The least r in [0, 2^31] with r / 2^31 >= q in float64: a binomial
+    node is a non-leaf iff rng_rand(state) < bin_threshold(q), one integer
+    compare (the vector engines' form of toProb(r) < q)."""
+    lo, hi = 0, 1 << 31  # invariant: hi / 2^31 >= q (q <= 1)
+    while lo < hi:
+        mid = (lo + hi) // 2
+        if mid / 2147483648.0 >= q:
+            hi = mid
+        else:
+            lo = mid + 1
+    return lo
+
+
 def num_children(params: UTSParams, state: bytes, depth: int) -> int:
+    if params.tree == BIN:
+        if depth == 0:
+            return int(math.floor(params.b0))  # uncapped (uts.c)
+        return params.m if rng_rand(state) / 2147483648.0 < params.q else 0
     b_i = _branching(params, depth)
     if b_i <= 0.0:
         return 0
@@ -159,7 +194,7 @@ def count_parallel(params: UTSParams, nworkers=None, grain: int = 1,
     return hc.launch(main, nworkers=nworkers, **launch_kwargs)
 
 
-def run(params: UTSParams = T3, nworkers=None, **launch_kwargs) -> dict:
+def run(params: UTSParams = T_TINY, nworkers=None, **launch_kwargs) -> dict:
     t0 = time.perf_counter()
     nodes, leaves, max_depth = count_parallel(params, nworkers=nworkers,
                                               **launch_kwargs)
@@ -176,6 +211,7 @@ def run(params: UTSParams = T3, nworkers=None, **launch_kwargs) -> dict:
 if __name__ == "__main__":  # pragma: no cover
     import sys
 
-    name = sys.argv[1] if len(sys.argv) > 1 else "T3"
-    params = {"T1": T1, "T1L": T1L, "T2": T2, "T3": T3, "T5": T5}[name]
+    name = sys.argv[1] if len(sys.argv) > 1 else "T_TINY"
+    params = {"T1": T1, "T1L": T1L, "T2": T2, "T3": T3, "T3L": T3L, "T5": T5,
+              "T_TINY": T_TINY}[name]
     print(run(params))

@@ -165,6 +165,40 @@ def _uts_t1l(sh):
     assert seeding[-1][1] >= 300_125  # level 9, whole
 
 
+def _uts_t3l(sh):
+    """The binomial kernel of the cell uts-t3l, through uts_pallas itself
+    at the cell's lanes and the engine's defaults: the seeding (the root's
+    2,000 children, on the host) runs here, the jitted kernel is swapped
+    for one that compiles the real kernel for the described chip and
+    stops there. What the interpreter cannot say: whether Mosaic takes the
+    balance round's in-row gathers, its one-row turn, the slab DMAs at a
+    dynamic index and a ring of this height as loop-carried planes."""
+    import hclib_tpu.device.uts_pallas as up
+    from hclib_tpu.models.uts import T3L
+
+    class Compiled(Exception):
+        pass
+
+    real = up._uts_bin_pallas
+    seen = {}
+
+    def compile_only(*args, **kw):
+        assert kw["interpret"] is False
+        seen.update(kw, slabs=args[0].shape)
+        real.lower(*_shapes(args, sh), **kw).compile()
+        raise Compiled
+
+    up._uts_bin_pallas = compile_only
+    try:
+        with pytest.raises(Compiled):
+            up.uts_pallas(T3L, lanes=(64, 128), interpret=False)
+    finally:
+        up._uts_bin_pallas = real
+    # the root's non-leaf children fit one slab of 8,192 frames
+    assert seen["slabs"] == (1, 7, 64, 128), seen
+    assert seen["lanes"] == (64, 128) and seen["stack_size"] >= 2, seen
+
+
 def _cholesky_8192(sh):
     from hclib_tpu.device.cholesky import make_cholesky_megakernel
 
@@ -367,7 +401,8 @@ def _bnb(sh):
 
 KERNELS = {
     f.__name__.lstrip("_"): f
-    for f in (_fib_scalar, _fib_batch, _uts_t1l, _cholesky_8192, _sw_fused,
+    for f in (_fib_scalar, _fib_batch, _uts_t1l, _uts_t3l, _cholesky_8192,
+              _sw_fused,
               _sw_wave, _forasync_1d, _forasync_2d, _forasync_hbm,
               _jacobi_steps,
               _serve_stream, _serve_stream_delta,

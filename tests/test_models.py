@@ -23,15 +23,15 @@ def test_fib_ddf():
 
 
 def test_uts_t3_parallel_matches_sequential():
-    seq = uts.count_seq(uts.T3)
-    par = uts.count_parallel(uts.T3, nworkers=4)
+    seq = uts.count_seq(uts.T_TINY)
+    par = uts.count_parallel(uts.T_TINY, nworkers=4)
     assert par == seq
     assert seq[0] == 1279  # pinned: detects any RNG/shape drift
 
 
 def test_uts_grain_batching():
-    seq = uts.count_seq(uts.T3)
-    assert uts.count_parallel(uts.T3, nworkers=4, grain=32) == seq
+    seq = uts.count_seq(uts.T_TINY)
+    assert uts.count_parallel(uts.T_TINY, nworkers=4, grain=32) == seq
 
 
 def test_uts_canonical_root_children():

@@ -29,7 +29,7 @@ def test_native_fib(rt):
 
 
 def test_native_uts_t3(rt):
-    # T3: FIXED shape, depth 5, b0=4, seed 42 (pinned in models/uts.py)
+    # T_TINY: FIXED shape, depth 5, b0=4, seed 42 (pinned in models/uts.py)
     assert rt.uts(3, 5, 4.0, 42) == (1279, 1018, 5)
 
 

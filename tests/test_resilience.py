@@ -337,7 +337,7 @@ def test_seeded_chaos_worker_kill_and_peer_crash():
     try:
         with w1._heap_lock:
             w1._heap["x"] = np.zeros(2, np.int32)
-        expect = uts.count_seq(uts.T3)[0]
+        expect = uts.count_seq(uts.T_TINY)[0]
         t0 = time.monotonic()
         # On a loaded 1-vCPU host the whole (50-100 ms) traversal can
         # finish before the doomed worker's OS thread is ever scheduled,
@@ -352,12 +352,12 @@ def test_seeded_chaos_worker_kill_and_peer_crash():
 
                 def visit(state, depth):
                     n.add(1)
-                    for i in range(uts.num_children(uts.T3, state, depth)):
+                    for i in range(uts.num_children(uts.T_TINY, state, depth)):
                         hc.async_(visit, uts.spawn_state(state, i),
                                   depth + 1)
 
                 with hc.finish():
-                    hc.async_(visit, uts.root_state(uts.T3.root_seed), 0)
+                    hc.async_(visit, uts.root_state(uts.T_TINY.root_seed), 0)
                 return n.gather()
 
             assert rt.run(main, deadline_s=120) == expect

@@ -12,7 +12,7 @@ import pytest
 from hclib_tpu.device.uts_pallas import uts_pallas
 from hclib_tpu.runtime.env import env_flag
 from hclib_tpu.device.uts_vec import uts_vec
-from hclib_tpu.models.uts import FIXED, T3, UTSParams, count_seq
+from hclib_tpu.models.uts import FIXED, T_TINY, UTSParams, count_seq
 
 
 def _cpu():
@@ -20,9 +20,9 @@ def _cpu():
 
 
 def test_uts_pallas_t3_exact():
-    r = uts_pallas(T3, target_roots=64, device=_cpu(), interpret=True,
+    r = uts_pallas(T_TINY, target_roots=64, device=_cpu(), interpret=True,
                    stack_pad=8)
-    assert (r["nodes"], r["leaves"], r["max_depth"]) == count_seq(T3)
+    assert (r["nodes"], r["leaves"], r["max_depth"]) == count_seq(T_TINY)
 
 
 def test_uts_pallas_deeper_tree_exact():
@@ -47,7 +47,7 @@ def test_uts_pallas_matches_xla_engine_steps():
 
 def test_uts_pallas_requires_128_lanes():
     with pytest.raises(ValueError, match="128"):
-        uts_pallas(T3, lanes=(8, 64), device=_cpu(), interpret=True)
+        uts_pallas(T_TINY, lanes=(8, 64), device=_cpu(), interpret=True)
 
 
 @pytest.mark.skipif(

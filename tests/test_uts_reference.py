@@ -29,7 +29,7 @@ from hclib_tpu.ops.sha1 import sha1_child, sha1_children_np  # noqa: E402
 
 # name -> (the reference's tree, the engines' target_roots)
 TREES = {
-    "T3": ({"shape": "FIXED", "gen_mx": 5, "b0": 4, "root_seed": 42}, 64),
+    "T_TINY": ({"shape": "FIXED", "gen_mx": 5, "b0": 4, "root_seed": 42}, 64),
     "deep7": ({"shape": "FIXED", "gen_mx": 7, "b0": 4, "root_seed": 19}, 256),
 }
 NLANES = uv.NLANES
@@ -96,13 +96,13 @@ def test_reference_hashes_in_blocks_with_jax_numpy(monkeypatch):
     jitted jax.numpy), at a block the CPU compiles quickly."""
     import jax.numpy as jnp
 
-    monkeypatch.setattr(ref, "BLOCK", 128)  # T3's widest hashed level: 243
-    assert ref.count_tree(TREES["T3"][0], jnp) == _reference("T3")
+    monkeypatch.setattr(ref, "BLOCK", 128)  # T_TINY's widest hashed level: 243
+    assert ref.count_tree(TREES["T_TINY"][0], jnp) == _reference("T_TINY")
 
 
 def test_reference_refuses_a_shape_it_does_not_write():
     with pytest.raises(NotImplementedError):
-        ref.count_tree({**TREES["T3"][0], "shape": "LINEAR"})
+        ref.count_tree({**TREES["T_TINY"][0], "shape": "LINEAR"})
 
 
 @pytest.mark.parametrize("n", [1, 7, 1000])
@@ -183,13 +183,13 @@ def test_one_call_launches_the_kernel_exactly_once(
         return real(*args, **kw)
 
     monkeypatch.setattr(module, attr, counted)
-    r = _call(engine, "T3")
+    r = _call(engine, "T_TINY")
     assert len(launches) == 1
-    assert r["nodes"] == _reference("T3")["nodes"]
+    assert r["nodes"] == _reference("T_TINY")["nodes"]
 
 
 @pytest.mark.parametrize("engine", ["pallas", "vec"])
 def test_a_call_takes_no_timing_reps(engine):
     fn = up.uts_pallas if engine == "pallas" else uv.uts_vec
     with pytest.raises(TypeError, match="timing_reps"):
-        fn(_params("T3"), timing_reps=1)
+        fn(_params("T_TINY"), timing_reps=1)

@@ -15,15 +15,15 @@ from hclib_tpu.device import uts_vec as uv
 from hclib_tpu.device.uts_pallas import ALIGN, uts_pallas
 from hclib_tpu.ops.sha1 import sha1_block
 from hclib_tpu.models.uts import (
-    CYCLIC, EXPDEC, FIXED, LINEAR, T1L, T3, UTSParams, count_seq,
+    CYCLIC, EXPDEC, FIXED, LINEAR, T1L, T_TINY, UTSParams, count_seq,
 )
 
 NEVER = 1 << 62
 
-# name -> (tree, target_roots): T3, the trees of tests/test_uts_pallas.py
+# name -> (tree, target_roots): T_TINY, the trees of tests/test_uts_pallas.py
 # and T1L's own top, to level 6 (4,562 nodes).
 TREES = {
-    "T3": (T3, 64),
+    "T_TINY": (T_TINY, 64),
     "fixed7": (UTSParams(shape=FIXED, gen_mx=7, b0=4.0, root_seed=19), 256),
     "linear": (UTSParams(shape=LINEAR, gen_mx=6, b0=4.0, root_seed=34), 64),
     "cyclic": (UTSParams(shape=CYCLIC, gen_mx=1, b0=6.0, root_seed=7), 8),
@@ -170,10 +170,10 @@ def test_a_tree_the_seeding_consumes_whole_on_the_device(monkeypatch, engine):
     counts from the device's levels alone."""
     monkeypatch.setattr(uv, "SEED_CHIP_FROM", 0)
     if engine == "vec":
-        r = uv.uts_vec(T3, target_roots=10**9, device=_cpu())
+        r = uv.uts_vec(T_TINY, target_roots=10**9, device=_cpu())
     else:
-        r = uts_pallas(T3, target_roots=10**9, device=_cpu(), interpret=True)
-    nodes, leaves, depth = count_seq(T3)
+        r = uts_pallas(T_TINY, target_roots=10**9, device=_cpu(), interpret=True)
+    nodes, leaves, depth = count_seq(T_TINY)
     assert (r["nodes"], r["leaves"], r["max_depth"]) == (nodes, leaves, depth)
     assert (r["roots"], r["steps"], r["host_seed_nodes"]) == (0, 0, nodes)
     assert r["seed_nodes_on_chip"] == nodes - 1

@@ -14,7 +14,7 @@ from hclib_tpu.models.uts import (
     EXPDEC,
     FIXED,
     LINEAR,
-    T3,
+    T_TINY,
     UTSParams,
     count_seq,
     num_children,
@@ -41,8 +41,8 @@ def test_thresholds_exact_against_scalar_formula():
 
 
 def test_uts_vec_t3_exact():
-    r = uts_vec(T3, target_roots=64, device=_cpu(), stack_pad=8)
-    assert (r["nodes"], r["leaves"], r["max_depth"]) == count_seq(T3)
+    r = uts_vec(T_TINY, target_roots=64, device=_cpu(), stack_pad=8)
+    assert (r["nodes"], r["leaves"], r["max_depth"]) == count_seq(T_TINY)
 
 
 def test_uts_vec_deeper_tree_exact():

@@ -200,7 +200,7 @@ def scenario_uts_kill_worker(seed: int, scale: str) -> dict:
     1-vCPU host the short tree can drain before that thread is ever
     scheduled, so the kill is raced over a few attempts - every attempt
     must stay exact, and the kill must land within the attempt budget."""
-    params = uts.T3
+    params = uts.T_TINY
     plan = hc.FaultPlan(
         seed=seed, kill_worker=1, kill_worker_after=1,
         steal_delay_rate=0.05, steal_delay_s=0.001,

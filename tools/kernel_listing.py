@@ -11,7 +11,7 @@ PR 41 / 45 / 46): read the listing before and after a change to the
 scheduler, and count its paths with ``tools/listing_paths.py``.
 
     python tools/kernel_listing.py <outdir> [--tree DIR]
-                                   [--kernel fib|forest|search|forasync|jacobi|wave]
+                                   [--kernel fib|forest|search|forasync|jacobi|wave|uts_bin]
                                    [--capacity N] [--if-conversion]
 
 ``--tree`` is the checkout to compile (default: this one; give a copy of
@@ -292,7 +292,39 @@ def _compile_jacobi(capacity: int) -> None:
 # section 3: the jit round a Megakernel's pallas_call is named
 # tpu_custom_call, the mesh kernel resident_mesh), its compile, the
 # cell's table rows)
+def _compile_uts_bin(stack_size: int) -> None:
+    """``uts-t3l``'s kernel (PR 56) as ``tests/test_chip_compile.py:_uts_t3l``
+    compiles it: the binomial traversal on 64 x 128 lanes; ``--capacity`` is
+    the ring's height. Its listing holds one loop: the balance round,
+    then ``BIN_EVERY`` steps unrolled (one SHA-1 and the ring's selects
+    each). PR 56 read 17.9 k bundles a trip with the steps in a loop of
+    their own and 14.7 k unrolled, at a ring of 4 and 4 steps a round."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from hclib_tpu.device import uts_pallas as up
+    from hclib_tpu.device import uts_vec as uv
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    sh = SingleDeviceSharding(topo.devices[0])
+    lanes = (64, 128)
+    args = (
+        jax.ShapeDtypeStruct((1, uv.FRAME_WORDS) + lanes, jnp.int32,
+                             sharding=sh),
+        jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sh),
+    )
+    up._uts_bin_pallas.lower(
+        *args, stack_size=stack_size, lanes=lanes,
+        every=uv.BIN_EVERY, pool_slabs=uv.BIN_POOL_SLABS, interpret=False,
+    ).compile()
+
+
 KERNELS = {
+    "uts_bin": ("uts_dfs_bin", _compile_uts_bin, 2),
     "fib": ("tpu_custom_call", _compile_fib, 768),
     "forest": ("resident_mesh", _compile_forest, 640),
     "search": ("tpu_custom_call", _compile_search, 128),

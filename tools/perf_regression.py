@@ -68,7 +68,7 @@ def _suite(quick: bool) -> List[Tuple[str, Callable[[], dict]]]:
             ("qsort", lambda: sort.run(1 << 14, "qsort")),
             ("cilksort", lambda: sort.run(1 << 14, "cilksort")),
             ("fft", lambda: fft.run(1 << 12, threshold=1 << 10)),
-            ("uts", lambda: uts.run(uts.T3)),
+            ("uts", lambda: uts.run(uts.T_TINY)),
             ("cholesky", lambda: cholesky.run(n=64, tile=32)),
             ("smithwaterman", lambda: smithwaterman.run(m=128, n=128, tile=64)),
         ]

@@ -31,14 +31,14 @@ import jax
 def vectorized_uts() -> None:
     """Exact UTS tree count, thousands of DFS lanes + shared root queue."""
     from hclib_tpu.device.uts_vec import NLANES, uts_vec
-    from hclib_tpu.models.uts import T3, count_seq
+    from hclib_tpu.models.uts import T_TINY, count_seq
 
-    r = uts_vec(T3, target_roots=64, device=jax.devices("cpu")[0])
-    want_nodes, want_leaves, want_depth = count_seq(T3)
+    r = uts_vec(T_TINY, target_roots=64, device=jax.devices("cpu")[0])
+    want_nodes, want_leaves, want_depth = count_seq(T_TINY)
     assert (r["nodes"], r["leaves"], r["max_depth"]) == (
         want_nodes, want_leaves, want_depth,
     )
-    print(f"UTS T3: {r['nodes']} nodes counted exactly by {NLANES} lanes")
+    print(f"UTS T_TINY: {r['nodes']} nodes counted exactly by {NLANES} lanes")
 
 
 def fused_smith_waterman() -> None:
