@@ -293,7 +293,7 @@ def _bin_kernel(
     pool_in_ref,  # ANY (pool_slabs, FRAME_WORDS, rows, 128) i32: the pool,
     # its first slabs0 slabs the roots (aliased to pool_ref)
     nodes_ref, leaves_ref, maxd_ref, spmax_ref,  # VMEM lanes, outputs
-    ctl_ref,  # SMEM (9,): steps, unfinished, rounds, then the pool's six
+    ctl_ref,  # SMEM (10,): steps, unfinished, rounds, then the pool's seven
     pool_ref,  # ANY: the pool, in place
     ebuf, sem,  # scratch: one slab in VMEM (FRAME_WORDS, rows, 128), DMA
 ) -> None:
@@ -374,7 +374,7 @@ def _uts_bin_pallas(
         ),
         out_shape=(
             plane, plane, plane, plane,  # nodes, leaves, maxd, spmax
-            jax.ShapeDtypeStruct((9,), i32),
+            jax.ShapeDtypeStruct((10,), i32),
             jax.ShapeDtypeStruct(pool.shape, i32),
         ),
         in_specs=[

@@ -45,8 +45,14 @@ goes: src/hclib-deque.c's discipline); and no list of roots made in
 advance can balance subtrees whose sizes no root betrays, so every lane
 that holds two frames or more gives, and starved lanes take what was
 given, through an exchange buffer beside the lanes whose overflow is a
-pool in HBM, inside the one launch (make_balance). A geometric tree's
-engine holds none of this: the two have their own step and driver.
+pool in HBM, inside the one launch (make_balance). A frame there is a
+node and the RANGE of its children still to hash, and it is split when it
+changes hands: a lane keeps the next child of its top frame and gives the
+others, and a starved lane is dealt one child, so the children of a node
+on the tree's deepest path are hashed by as many lanes in one step, and
+that path costs two steps a level where a frame that moved whole cost
+three and a half (PERF.md, PR 57). A geometric tree's engine holds none of
+this: the two have their own step and driver.
 
 Supports every GEO shape: FIXED (canonical T1/T1L/T1XL) on the
 depth-independent threshold fast path, LINEAR/CYCLIC (canonical T5/T2) and
@@ -332,22 +338,43 @@ def make_dfs_step(
     return step
 
 
+# A frame's children word: the children still to hash are [lo, hi), packed
+# as lo | hi << 16. A whole frame is [0, m); a frame that changed hands is
+# a piece of one (make_balance). The widths of a row's frames are summed as
+# one product on the MXU, whose one pass holds 8 bits of an operand, so a
+# width, and with it a binomial tree's ``m``, is at most BIN_MAX_M.
+BIN_MAX_M = 255
+
+
+def _children(word):
+    """A children word's (lo, hi)."""
+    return word & 0xFFFF, word >> 16
+
+
+def _children_word(lo, hi):
+    return lo | (hi << 16)
+
+
 def make_bin_step(S: int, lanes: tuple, below, m):
     """``make_dfs_step`` for a binomial tree, shared by both engines like
     it; ``below`` and ``m`` are runtime scalars. A binomial node's count is
     one compare (``m`` children iff r < below, whatever its depth), so a
-    frame carries no count and no table is looked up; the stack is a RING
-    of S planes (S a power of two) indexed by a per-lane ``top``, so the
-    balance round can take the BOTTOM frame away without moving the
-    others; and a push that finds the ring full stalls (the lane repeats
-    the hash) until the next balance round has taken its bottom frame.
-    Signature:
+    frame carries no count and no table is looked up: it is (state, the
+    children ``[lo, hi)`` of that node still to hash, depth), and ``lo`` is
+    the next one. A node pushed here is whole, ``[0, m)``; one that came
+    from the balance round is one child, ``[c, c + 1)``, and what the round
+    left of a frame it split is ``[lo, lo + 1)``. The stack is a RING of S
+    planes (S a power of two) indexed by a per-lane ``top``, so the balance
+    round can take the BOTTOM frame away without moving the others; and a
+    push that finds the ring full stalls (the lane repeats the hash) until
+    the next balance round has taken its bottom frame. Signature:
     (sp, top, nodes, leaves, maxd, spmax, st, ch, dp) -> same tuple."""
     assert S >= 2 and S & (S - 1) == 0, S
 
     def step(sp, top, nodes, leaves, maxd, spmax, st, ch, dp):
         active = sp >= 0
-        child = _level_select(ch, top)
+        word = _level_select(ch, top)
+        child, hi = _children(word)
         depth = _level_select(dp, top)
         state = [
             _level_select(tuple(st[L][i] for L in range(S)), top)
@@ -356,7 +383,7 @@ def make_bin_step(S: int, lanes: tuple, below, m):
         cstate = _sha1_child(state, child, jnp)
         r = (cstate[4] & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
         nonleaf = r < below
-        last = child + 1 >= m
+        last = child + 1 >= hi
         # Tail-call scheduling as in the geometric step: every frame on
         # the ring has a child left, so every active lane hashes one. A
         # push onto a full ring waits: nothing of the lane changes, and
@@ -369,7 +396,7 @@ def make_bin_step(S: int, lanes: tuple, below, m):
         push = expand & nonleaf & ~last
         tail = expand & nonleaf & last
         pop = expand & ~nonleaf & last
-        ch = _level_store(ch, top, child + 1, expand & ~last)
+        ch = _level_store(ch, top, word + 1, expand & ~last)
         nxt = (top + 1) & (S - 1)
         lvl = jnp.where(push, nxt, top)
         newf = push | tail
@@ -380,7 +407,7 @@ def make_bin_step(S: int, lanes: tuple, below, m):
             )
             for L in range(S)
         )
-        ch = _level_store(ch, lvl, jnp.zeros(lanes, jnp.int32), newf)
+        ch = _level_store(ch, lvl, _children_word(0, m), newf)
         dp = _level_store(dp, lvl, cdepth, newf)
         sp = jnp.where(push, sp + 1, jnp.where(pop, sp - 1, sp))
         top = jnp.where(push, nxt, jnp.where(pop, (top - 1) & (S - 1), top))
@@ -390,28 +417,29 @@ def make_bin_step(S: int, lanes: tuple, below, m):
     return step
 
 
-FRAME_WORDS = 7  # a pooled frame: five state words, next child, depth
+FRAME_WORDS = 7  # a pooled frame: five state words, children word, depth
 
 
-def row_cumsum(mask, lanes: tuple):
-    """Inclusive prefix sum of a 0/1 mask along each row of 128 lanes, as
-    one product with a triangular matrix (exact: sums <= 128), and the
-    rows' totals broadcast over their rows."""
+def row_cumsum(x, lanes: tuple):
+    """Inclusive prefix sum along each row of 128 lanes of a 0/1 mask, or
+    of whole numbers up to BIN_MAX_M, as one product with a triangular
+    matrix (exact: an operand fits the MXU's 8 bits, a sum f32's 24), and
+    the rows' totals broadcast over their rows."""
     cols = lanes[1]
     upper = (
         jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 0)
         <= jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 1)
     ).astype(jnp.float32)
     ranks = jnp.dot(
-        mask.astype(jnp.float32), upper, preferred_element_type=jnp.float32
+        x.astype(jnp.float32), upper, preferred_element_type=jnp.float32
     ).astype(jnp.int32)
     return ranks, jnp.broadcast_to(ranks[:, cols - 1:cols], lanes)
 
 
-def row_total(mask, lanes: tuple):
-    """A 0/1 mask's row sums broadcast over their rows (a lane reduce;
-    exact in f32: sums <= 128)."""
-    total = jnp.sum(mask.astype(jnp.float32), axis=1, keepdims=True)
+def row_total(x, lanes: tuple):
+    """The row sums of a 0/1 mask, or of a lane's 0 to 2 gifts, broadcast
+    over their rows (a lane reduce; exact in f32: sums <= 256)."""
+    total = jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
     return jnp.broadcast_to(total.astype(jnp.int32), lanes)
 
 
@@ -428,64 +456,114 @@ def make_balance(S: int, lanes: tuple, pool_slabs: int, spill, fetch,
     """The balance round of a binomial traversal: the work-stealing half of
     the reference (src/hclib-deque.c:75-106: the owner works at the top of
     its deque, what leaves it leaves from the BOTTOM), recast for lanes
-    that cannot address each other.
+    that cannot address each other, and with one thing the reference
+    cannot do: **a frame that changes hands is split**. A node's children
+    are ``SHA1(node || i)``: they depend on the node alone, so as many
+    lanes as it has children left can hash them in the same step. A frame
+    is (state, the children ``[lo, hi)`` still to hash, depth)
+    (``make_bin_step``); what is given is such a range, and what a starved
+    lane takes is ONE child of one.
 
     The pool's near end is an exchange buffer ``E`` of FRAME_WORDS planes
     beside the lanes: row i of it holds ``e[i]`` frames at its front. A
-    round, in order: an empty ``E`` is filled with the pool's newest slab
-    from HBM (``fetch``); every lane that holds two frames or more gives
-    (three lanes in four are starved on a critical tree, so nobody waits
-    to be asked, and a ring that gives a frame a round is never full for
-    longer than a round): it appends its BOTTOM frame to row i by its rank
-    among the givers, the inverse gather (a binary search of the ranks)
-    and one in-row gather a word; if a row cannot hold what it is given, ``E``
-    first leaves for HBM whole, as the pool's next slab (``spill``);
-    starved lanes (sp < 0) of lane row i take the LAST frames of ``E``'s
-    row i, this round's gifts first, by their rank in the row, one in-row
-    gather a word (gifts before claims: a frame on the tree's critical
-    path changes hands often, and each round it waits in ``E`` is
-    ``every`` steps of the whole call; PERF.md, PR 56); then ``E`` turns
-    by one row, so that what row i could not use feeds row i + 1 next
-    round and every row in 64. Only in-row gathers, one-row turns and whole-slab copies: no
-    compaction across rows, nothing Mosaic lacks. No frame is dropped or
-    handed out twice: a slot of ``E`` is live iff its column is below
-    ``e``, claims shorten ``e`` by what they took, gifts lengthen it by
-    what they put. A full pool sets ``err`` and the traversal stops.
+    round, in order:
+
+    0. An empty ``E`` is filled with the pool's newest slab from HBM
+       (``fetch``).
+    1. *Who gives.* Every lane that holds two frames or more gives its
+       BOTTOM frame, whole (three lanes in five are starved on a critical
+       tree, so nobody waits to be asked, and a ring that gives a frame a
+       round is never full for longer than a round). And every lane, in a
+       row where some lane is starved, whose TOP frame has two children or
+       more left keeps the next one, ``[lo, lo + 1)``, and gives the
+       others, ``[lo + 1, hi)``: its only frame, or the one above the
+       bottom frame it gives in the same round (a lane may so give twice:
+       a node pushed in the step before the round is split in that round,
+       not two rounds later). Where no lane of the row is starved nobody
+       could take the children now, and a bushy tree gives as it did
+       before frames were split. A giver appends its one or two ranges to
+       row i by its rank among the gifts (a product with a triangular
+       matrix), the inverse gather (a binary search of the ranks) and one
+       in-row gather a word and gift. A lane whose gifts the row has no
+       room for keeps them for a round, unless ``E`` is over half full all
+       in all: then it first leaves for HBM, whole, as the pool's next
+       slab (``spill``). (A row that a burst fills does not send the 63
+       others away: what leaves comes back only when ``E`` is empty, and
+       the subtrees in it fall that far behind; PERF.md, PR 57.)
+    2. *Who takes.* The starved lanes (sp < 0) of lane row i are dealt the
+       LAST children of ``E``'s row i, a child a lane, this round's gifts
+       first: the frames' widths summed from the row's front (one more
+       product), the same search finds the frame of a lane's child and its
+       place in the sum the child, one in-row gather a word. A frame dealt
+       out whole leaves ``E``, the one the dealing stopped in keeps its
+       first children (its ``hi`` is cut). Gifts before claims: a frame on
+       the tree's critical path changes hands every level, and each round
+       it waits in ``E`` is ``every`` steps of the whole call (PERF.md, PR
+       56 and PR 57).
+    3. ``E`` turns by one row, so that what row i could not use feeds row
+       i + 1 next round and every row in 64.
+
+    Only in-row gathers, one-row turns and whole-slab copies: no
+    compaction across rows, nothing Mosaic lacks.
+
+    The books are in PIECES, a child a piece: ``donated`` grows by a gift's
+    width, ``claimed`` by one a lane that was dealt a child, and the two
+    sides count apart (the width as it left the lane, the lanes that took),
+    so a child lost or dealt twice shows in ``donated + roots == claimed``
+    to the unit; ``split_gifts`` counts the gifts that split a frame (its
+    holder kept the next child). A slot of ``E`` is live iff its column is
+    below ``e``. A full pool sets ``err`` and the traversal stops.
 
     ``spill(pstate, do, k, planes) -> pstate`` and ``fetch(pstate, do, k)
     -> planes`` are the engine's (a DMA in the kernel, a slice of a carried
     array in XLA), ``roll_rows`` its one-row turn. Returns
     ``balance(lane, pool) -> (lane, pool)``, lane = (sp, top, st, ch, dp),
     pool = (E, e, slabs, pstate, donated, claimed, moved_rounds, err,
-    spills)."""
+    spills, split_gifts)."""
     rows, cols = lanes
     col = jax.lax.broadcasted_iota(jnp.int32, lanes, 1)
     search_steps = (cols - 1).bit_length()
 
-    def first_at_least(ranks, target):
-        """Per slot, the first column whose rank reaches ``target``."""
+    def first_at_least(ranks, total, target):
+        """Per slot, the first column whose rank (non-decreasing along a
+        row, ``total`` at its end) reaches ``target``, and that rank."""
         lo = jnp.zeros(lanes, jnp.int32)
         hi = jnp.full(lanes, cols - 1, jnp.int32)
+        there = total
         for _ in range(search_steps):
             mid = (lo + hi) >> 1
-            ge = jnp.take_along_axis(ranks, mid, axis=1) >= target
+            rank = jnp.take_along_axis(ranks, mid, axis=1)
+            ge = rank >= target
             hi = jnp.where(ge, mid, hi)
+            there = jnp.where(ge, rank, there)
             lo = jnp.where(ge, lo, mid + 1)
-        return lo
+        return lo, there
 
     def balance(lane, pool):
         sp, top, st, ch, dp = lane
-        E, e, slabs, pstate, donated, claimed, moved, err, spills = pool
+        (E, e, slabs, pstate, donated, claimed, moved, err, spills,
+         splits) = pool
         # 0. an empty exchange takes the newest slab
         load = (jnp.sum(e) == 0) & (slabs > 0)
         slab = fetch(pstate, load, slabs - 1)
         E = tuple(jnp.where(load, s, x) for s, x in zip(slab, E))
         e = jnp.where(load, row_total(slab[6] > 0, lanes), e)
         slabs = slabs - load.astype(jnp.int32)
-        # 1. givers append their bottom frames
-        giver = sp >= 1
-        rank, given = row_cumsum(giver, lanes)
-        flush = jnp.any(e + given > cols)
+        # 1. givers append their ranges: a bottom frame whole, a top
+        # frame's children but the next (no giver is starved, so the
+        # starved lanes' ranks are the same before the gifts and after)
+        starved = sp < 0
+        want_rank, want = row_cumsum(starved, lanes)
+        bottom = (top - sp) & (S - 1)
+        bword, tword = _level_select(ch, bottom), _level_select(ch, top)
+        (blo, bhi), (tlo, thi) = _children(bword), _children(tword)
+        whole = sp >= 1
+        split = (sp >= 0) & (thi - tlo >= 2) & (want > 0)
+        gifts = whole.astype(jnp.int32) + split.astype(jnp.int32)
+        rank, offered = row_cumsum(gifts, lanes)
+        # (e is a plane: a row's count stands in each of its columns)
+        flush = (jnp.any(e + offered > cols)
+                 & (jnp.sum(e) > rows * cols * cols // 2))
         err = err | (flush & (slabs >= pool_slabs)).astype(jnp.int32)
         pstate = spill(
             pstate, flush, jnp.minimum(slabs, pool_slabs - 1),
@@ -493,41 +571,73 @@ def make_balance(S: int, lanes: tuple, pool_slabs: int, spill, fetch,
         )
         e = jnp.where(flush, 0, e)
         slabs = slabs + flush.astype(jnp.int32)
-        bottom = (top - sp) & (S - 1)
-        frame = [
-            _as_i32(_level_select(tuple(st[L][i] for L in range(S)), bottom))
-            for i in range(5)
-        ] + [_level_select(ch, bottom), _level_select(dp, bottom)]
-        source = first_at_least(rank, col - e + 1)
+        fits = rank <= cols - e
+        whole, split = whole & fits, split & fits
+        given = row_total(jnp.where(fits, gifts, 0), lanes)
+
+        def frame(level, word):
+            return [
+                _as_i32(_level_select(
+                    tuple(st[L][i] for L in range(S)), level))
+                for i in range(5)
+            ] + [word, _level_select(dp, level)]
+
+        # a lane's last gift is its top frame's tail if it gives one, and
+        # the gift before that, if there is one, its bottom frame
+        first = frame(bottom, bword)
+        last = [jnp.where(split, t, b)
+                for t, b in zip(frame(top, tword + 1), first)]
+        slot = col - e + 1
+        source, there = first_at_least(rank, offered, slot)
         put = (col >= e) & (col < e + given)
         E = tuple(
-            jnp.where(put, jnp.take_along_axis(w, source, axis=1), x)
-            for w, x in zip(frame, E)
+            jnp.where(put, jnp.where(
+                there == slot, jnp.take_along_axis(t, source, axis=1),
+                jnp.take_along_axis(b, source, axis=1)), x)
+            for b, t, x in zip(first, last, E)
         )
         e = e + given
-        sp = jnp.where(giver, sp - 1, sp)
-        # 2. starved lanes take the row's last frames, this round's gifts
-        # first: a frame that changes hands waits for no second round
-        starved = sp < 0
-        rank, want = row_cumsum(starved, lanes)
-        claim = starved & (rank <= e)
-        at = jnp.clip(e - rank, 0, cols - 1)
+        sp = jnp.where(whole, sp - 1, sp)
+        ch = _level_store(ch, top, _children_word(tlo, tlo + 1), split)
+        # 2. starved lanes are dealt the row's last children, a child a
+        # lane, this round's gifts first: a frame that changes hands waits
+        # for no second round, and its children for no second step
+        elo, ehi = _children(E[5])
+        width = jnp.where(col < e, ehi - elo, 0)
+        upto, avail = row_cumsum(width, lanes)  # children, from the front
+        claim = starved & (want_rank <= avail)
+        nth = avail - want_rank + 1
+        at, there = first_at_least(upto, avail, nth)
         got = [jnp.take_along_axis(x, at, axis=1) for x in E]
+        child = (got[5] >> 16) - 1 - (there - nth)
         st = (tuple(jnp.where(claim, _as_u32(got[i]), st[0][i])
                     for i in range(5)),) + st[1:]
-        ch = (jnp.where(claim, got[5], ch[0]),) + ch[1:]
+        ch = (jnp.where(claim, _children_word(child, child + 1), ch[0]),
+              ) + ch[1:]
         dp = (jnp.where(claim, got[6], dp[0]),) + dp[1:]
         sp = jnp.where(claim, 0, sp)
         top = jnp.where(claim, 0, top)
-        e = e - jnp.minimum(e, want)
+        # what is left: the frames that start below the children left, the
+        # last of them cut where the dealing stopped
+        left = avail - jnp.minimum(avail, want)
+        before = upto - width
+        keep = (col < e) & (before < left)
+        E = E[:5] + (
+            jnp.where(keep, _children_word(
+                elo, jnp.minimum(ehi, elo + left - before)), E[5]),
+            E[6],
+        )
+        e = row_total(keep, lanes)
         # 3. the exchange turns by one row
         E = tuple(roll_rows(x) for x in E)
         e = roll_rows(e)
-        gave = jnp.sum(giver.astype(jnp.int32))
+        gave = jnp.sum(jnp.where(whole, bhi - blo, 0)
+                       + jnp.where(split, thi - tlo - 1, 0))
         took = jnp.sum(claim.astype(jnp.int32))
         pool = (E, e, slabs, pstate, donated + gave, claimed + took,
                 moved + (gave + took > 0).astype(jnp.int32), err,
-                spills + flush.astype(jnp.int32))
+                spills + flush.astype(jnp.int32),
+                splits + jnp.sum(split.astype(jnp.int32)))
         return (sp, top, st, ch, dp), pool
 
     return balance
@@ -674,15 +784,18 @@ def make_bin_traversal(S, lanes, max_steps, R, *, below, m, every, slabs0,
     XLA's CPU backend, which fuses one hash into the next and is three to
     ten times as long over the compile, keeps the loop). ``max_steps`` is a runtime scalar here.
     The pool starts as ``slabs0`` slabs holding the ``R`` roots (the root's
-    non-leaf children) and every lane starved. Returns run() -> (nodes,
-    leaves, maxd, spmax, steps, unfinished, rounds, (donated, claimed, rounds
-    in which a frame moved, the pool's high-water mark, pool full, slabs
-    spilled to HBM))."""
+    non-leaf children, whole frames) and every lane starved; the books are
+    in pieces (``make_balance``), and a root is dealt a child a lane like
+    any frame, so ``donated`` starts at the ``R (m - 1)`` children the
+    roots hold beyond one each. Returns run() -> (nodes, leaves, maxd,
+    spmax, steps, unfinished, rounds, (donated, claimed, rounds in which a
+    frame moved, the pool's high-water mark, pool full, slabs spilled to
+    HBM, gifts that split a frame))."""
     step = make_bin_step(S, lanes, below, m)
     balance = make_balance(S, lanes, pool_slabs, spill, fetch, roll_rows)
 
     def pooled(pool):
-        return R + pool[4] - pool[5]  # frames neither in a lane nor done
+        return R + pool[4] - pool[5]  # children neither in a lane nor done
 
     def work_left(sp, pool):
         # by what is there, not by the counters: a frame lost or doubled
@@ -715,7 +828,7 @@ def make_bin_traversal(S, lanes, max_steps, R, *, below, m, every, slabs0,
         uzeros = jnp.zeros(lanes, jnp.uint32)
         zero = jnp.int32(0)
         pool = (tuple(zeros for _ in range(FRAME_WORDS)), zeros, slabs0,
-                pstate, zero, zero, zero, zero, zero)
+                pstate, R * (m - 1), zero, zero, zero, zero, zero)
         carry = (
             jnp.full(lanes, -1, jnp.int32), zeros, zeros, zeros, zeros,
             jnp.full(lanes, -1, jnp.int32),
@@ -727,7 +840,8 @@ def make_bin_traversal(S, lanes, max_steps, R, *, below, m, every, slabs0,
          pool_max) = jax.lax.while_loop(outer_cond, outer_body, carry)
         unfinished = work_left(sp, pool)
         return (nodes, leaves, maxd, spmax, steps, unfinished, rounds,
-                (pool[4], pool[5], pool[6], pool_max, pool[7], pool[8]))
+                (pool[4], pool[5], pool[6], pool_max, pool[7], pool[8],
+                 pool[9]))
 
     return run
 
@@ -824,7 +938,7 @@ def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
             # eight outputs in one transfer, not one each
             (nodes, leaves, maxd, steps, unfinished, refills, spmax,
              counters) = jax.device_get(outs)
-            donated, claimed, moved, pool_max, full, spills = (
+            donated, claimed, moved, pool_max, full, spills, splits = (
                 int(x) for x in np.asarray(counters)
             )
             if full:
@@ -836,12 +950,13 @@ def _launch_once(who, run, result, seed, nlanes, max_steps, cap, interpret):
         if bool(unfinished):
             raise RuntimeError(f"{who} ran out of steps ({max_steps})")
         if pooled:
-            # every frame that left a lane, and every root, was taken once
+            # every child that left a lane, or came with a root, was
+            # taken once
             assert donated + result["roots"] == claimed, (
                 donated, result["roots"], claimed)
             result.update(
                 donated=donated, claimed=claimed, balance_rounds=moved,
-                pool_max=pool_max, spills=spills,
+                pool_max=pool_max, spills=spills, split_gifts=splits,
                 stack_max=int(np.asarray(spmax).max()) + 1,
             )
         deepest = int(np.asarray(maxd).max())
@@ -1230,8 +1345,9 @@ def _seed_top(params: UTSParams, target_roots: int, device):
 # slabs of one frame a lane. PERF.md (PR 56) has the chip's readings of the
 # alternatives (rings of 2-8, rounds every 1-4 steps) and of the pool: T3L
 # whole on 8,192 lanes never held more than 3 slabs (the roots' one and 2
-# spilled a call), so 8 is that and as much again and a bit; a tree that
-# fills them raises.
+# spilled a call), so 8 is that and as much again and a bit; since PR 57
+# the exchange leaves only when it is over half full, and T3L spills
+# none. A tree that fills the slabs raises.
 BIN_STACK = 2
 BIN_EVERY = 2
 BIN_POOL_SLABS = 8
@@ -1251,8 +1367,9 @@ def _seeded_bin(params: UTSParams, lanes: tuple):
     is all of its spread between processes, PERF.md, PR 56), and the
     non-leaf ones laid out as the pool's first slabs, still on the host:
     ``(seed, slabs, result)`` as ``_seeded`` gives them. A slab is
-    (FRAME_WORDS, rows, 128) int32 (state words as u32 bits, next child 0,
-    depth 1; depth 0 marks an empty slot), its frames dealt round-robin
+    (FRAME_WORDS, rows, 128) int32 (state words as u32 bits, the children
+    [0, m) as ``m << 16``, depth 1; depth 0 marks an empty slot), its
+    frames dealt round-robin
     over the rows and each row filled from its front, which is how the
     balance round reads it."""
     rows, cols = lanes
@@ -1276,6 +1393,7 @@ def _seeded_bin(params: UTSParams, lanes: tuple):
             n0 = -(-R // nlanes)
             flat = np.zeros((FRAME_WORDS, n0 * nlanes), np.uint32)
             flat[:5, :R] = keep
+            flat[5, :R] = _children_word(0, params.m)
             flat[6, :R] = 1
             # frame f of a slab -> row f % rows, column f // rows
             slabs = np.ascontiguousarray(
@@ -1364,6 +1482,10 @@ def _call_bin(who, engine, params, lanes, device, max_steps, stack_size,
     S = BIN_STACK if stack_size is None else int(stack_size)
     if S < 2 or S & (S - 1):
         raise ValueError(f"stack_size must be a power of two >= 2, got {S}")
+    if params.m > BIN_MAX_M:
+        raise ValueError(
+            f"a binomial tree's m is at most {BIN_MAX_M} here (the balance "
+            f"round sums a row's widths in one MXU pass), got {params.m}")
     seed, slabs, result = _seeded_bin(params, tuple(lanes))
     if slabs is None:
         return result
@@ -1427,10 +1549,12 @@ def uts_vec(
     host, and the non-leaf ones are the pool's first frames
     (``target_roots`` and the depth keywords mean nothing and raise); a
     lane's stack is a ring of ``stack_size`` frames (default BIN_STACK)
-    whose bottom frame leaves for the exchange buffer, and from there for
-    a starved lane or the pool in HBM, in the balance round that comes
-    every BIN_EVERY steps (``make_balance``); the result dict gains
-    ``donated``, ``claimed``, ``pool_max``, ``spills``, ``stack_max`` and
+    whose bottom frame, and the children of its top frame but the next,
+    leave for the exchange buffer, and from there a child a starved lane,
+    or for the pool in HBM, in the balance round that comes every
+    BIN_EVERY steps (``make_balance``); the result dict gains ``donated``
+    and ``claimed`` (children: a frame dealt to k lanes is k of each),
+    ``split_gifts``, ``pool_max``, ``spills``, ``stack_max`` and
     ``balance_rounds``, and ``refills`` counts the balance rounds."""
     if max_steps is None:
         max_steps = (1 << 31) - 1

@@ -298,7 +298,11 @@ def _compile_uts_bin(stack_size: int) -> None:
     the ring's height. Its listing holds one loop: the balance round,
     then ``BIN_EVERY`` steps unrolled (one SHA-1 and the ring's selects
     each). PR 56 read 17.9 k bundles a trip with the steps in a loop of
-    their own and 14.7 k unrolled, at a ring of 4 and 4 steps a round."""
+    their own and 14.7 k unrolled, at a ring of 4 and 4 steps a round; at
+    the cell's ring of 2 and 2 steps a round ``listing_paths.py`` counts
+    7,874 bundles on the trip that neither fetches nor spills a slab, and
+    8,782 since PR 57 (frames split as they change hands: a third rank
+    product, 35 in-row gathers a round for 21)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
