@@ -19,6 +19,11 @@ test/cholesky/cholesky.cpp):
   3-pass bf16 hi/lo split (2x the throughput of HIGHEST's 6 passes).
 - ``dma_copy``: start+wait of a Pallas async copy (HBM<->VMEM staging in
   task kernels).
+- ``lu_tile`` / ``lu_and_inv``: LU WITHOUT pivoting of a tile (L unit
+  lower, U upper) and both inverses, for device/sparselu.py's ``lu0``: the
+  same panel blocking as ``factor_tile`` with the symmetry gone - the tile
+  and its transpose are swept together, so the L columns of a panel are
+  rows of the transpose and nothing is sliced along lanes.
 """
 
 from __future__ import annotations
@@ -30,7 +35,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "factor_tile", "tri_inverse", "factor_and_inv", "mm_nt", "dma_copy",
-    "split_bf16", "mm_nt_split", "mm_nt_rsplit",
+    "split_bf16", "mm_nt_split", "mm_nt_rsplit", "lu_tile", "lu_and_inv",
+    "mm_nn_lsplit", "mm_nn_rsplit",
 ]
 
 
@@ -264,3 +270,121 @@ def dma_copy(src, dst, sem):
     cp = pltpu.make_async_copy(src, dst, sem)
     cp.start()
     cp.wait()
+
+
+def mm_nn_lsplit(ah, al, b):
+    """a @ b with only the LEFT operand pre-split (b is split here): the
+    three MXU passes of ``mm_nn``, for a kernel that keeps ``a`` split
+    (device/sparselu.py: ``inv(L) @ A_kj``)."""
+    bh, bl = split_bf16(b)
+    return _d_nn(ah, bh) + _d_nn(ah, bl) + _d_nn(al, bh)
+
+
+def mm_nn_rsplit(a, bh, bl):
+    """a @ b with only the RIGHT operand pre-split (``A_ik @ inv(U)``)."""
+    ah, al = split_bf16(a)
+    return _d_nn(ah, bh) + _d_nn(ah, bl) + _d_nn(al, bh)
+
+
+def _lu8_and_inv(d8):
+    """Serial LU without pivoting of an (8, 8) block and both inverses,
+    fully unrolled with static slices, as ``_chol8_and_inv``. Returns
+    ``(L8, U8, inv(L8), inv(U8))``, L8 unit lower with its ones."""
+    rows8 = jax.lax.broadcasted_iota(jnp.int32, (PANEL, PANEL), 0)
+    cols8 = jax.lax.broadcasted_iota(jnp.int32, (PANEL, PANEL), 1)
+    s8 = d8
+    lcols, urows = [], []
+    for q in range(PANEL):
+        dq = jax.lax.slice(s8, (q, q), (q + 1, q + 1))
+        colq = jax.lax.slice(s8, (0, q), (PANEL, q + 1))
+        rowq = jax.lax.slice(s8, (q, 0), (q + 1, PANEL))
+        c = jnp.where(rows8[:, :1] > q, colq / dq, 0.0)
+        r = jnp.where(cols8[:1] >= q, rowq, 0.0)
+        lcols.append(c + (rows8[:, :1] == q).astype(d8.dtype))
+        urows.append(r)
+        s8 = jnp.where((rows8 > q) & (cols8 > q), s8 - c * r, s8)
+    l8 = jnp.concatenate(lcols, axis=1)
+    u8 = jnp.concatenate(urows, axis=0)
+    # inv(L8) by forward substitution (unit diagonal), inv(U8) by back
+    # substitution from the last row up: row i of X solves T X = I.
+    lrows = []
+    for i in range(PANEL):
+        acc = (cols8[:1] == i).astype(d8.dtype)
+        for j in range(i):
+            acc = acc - jax.lax.slice(l8, (i, j), (i + 1, j + 1)) * lrows[j]
+        lrows.append(acc)
+    xrows = [None] * PANEL
+    for i in reversed(range(PANEL)):
+        acc = (cols8[:1] == i).astype(d8.dtype)
+        for j in range(i + 1, PANEL):
+            acc = acc - jax.lax.slice(u8, (i, j), (i + 1, j + 1)) * xrows[j]
+        xrows[i] = acc / jax.lax.slice(u8, (i, i), (i + 1, i + 1))
+    return (l8, u8, jnp.concatenate(lrows, axis=0),
+            jnp.concatenate(xrows, axis=0))
+
+
+def lu_tile(t, ts: int):
+    """Panel-blocked LU without pivoting of a (ts, ts) tile: ``(L, U)``, L
+    unit lower with its ones, U upper, ``L U = t``.
+
+    ``factor_tile`` reads a panel's columns off its rows by symmetry; here
+    the tile ``s`` and its transpose ``st`` are carried together, so the
+    panel's U rows come from rows of ``s`` (``inv(L8) @ s[J, :]``) and its
+    L columns, transposed, from rows of ``st`` (``inv(U8)^T @ st[J, :]``):
+    every slice is a sublane block. Each panel ends in one rank-8 MXU
+    update of ``s`` and one of ``st`` (3-pass bf16 split, ~f32 exact); the
+    serial math stays on the 8x8 diagonal block."""
+    assert ts % PANEL == 0, ts
+    rows = jax.lax.broadcasted_iota(jnp.int32, (ts, ts), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (ts, ts), 1)
+    lanep = jax.lax.broadcasted_iota(jnp.int32, (PANEL, ts), 1)
+    s, st = t, jnp.transpose(t)
+    upans, lpans = [], []
+    npanels = ts // PANEL
+
+    def splice(x, blk8, j0):
+        """``x`` with ``blk8`` in its diagonal-block window and zero left
+        of it (static concatenate + mask, as ``factor_tile``)."""
+        parts = []
+        if j0:
+            parts.append(jnp.zeros((PANEL, j0), t.dtype))
+        parts.append(blk8)
+        if ts - j0 - PANEL:
+            parts.append(jnp.zeros((PANEL, ts - j0 - PANEL), t.dtype))
+        w = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+        x = jnp.where((lanep >= j0) & (lanep < j0 + PANEL), w, x)
+        return jnp.where(lanep >= j0, x, 0.0)
+
+    for p in range(npanels):
+        j0 = p * PANEL
+        pan = jax.lax.slice(s, (j0, 0), (j0 + PANEL, ts))
+        pant = jax.lax.slice(st, (j0, 0), (j0 + PANEL, ts))
+        d8 = jax.lax.slice(pan, (0, j0), (PANEL, j0 + PANEL))
+        l8, u8, il8, iu8 = _lu8_and_inv(d8)
+        u = splice(mm_nn(il8, pan), u8, j0)
+        lt = splice(mm_nn(jnp.transpose(iu8), pant), jnp.transpose(l8), j0)
+        upans.append(u)
+        lpans.append(lt)
+        if p + 1 < npanels:
+            # s[a, b] -= sum_q L[a, j0+q] U[j0+q, b] = (lt^T u)[a, b], and
+            # the same of the transpose.
+            edge = j0 + PANEL - 1
+            trail = (rows > edge) & (cols > edge)
+            s = jnp.where(trail, s - _mm_tn(lt, u), s)
+            st = jnp.where(trail, st - _mm_tn(u, lt), st)
+    return (jnp.transpose(jnp.concatenate(lpans, axis=0)),
+            jnp.concatenate(upans, axis=0))
+
+
+def lu_and_inv(t, ts: int):
+    """``(LU, inv(L), inv(U))`` of a (ts, ts) tile factored without
+    pivoting: LU packed (L below the diagonal, its ones implied; U on and
+    above it), the inverses by ``tri_inverse``'s Newton-Schulz, which is
+    as exact for an upper triangle as for a lower one. With them a block's
+    two triangular solves are one 3-pass product each, as Cholesky's TRSM
+    is."""
+    l, u = lu_tile(t, ts)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (ts, ts), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (ts, ts), 1)
+    return (jnp.where(rows > cols, l, u), tri_inverse(l, ts),
+            tri_inverse(u, ts))

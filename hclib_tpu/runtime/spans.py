@@ -63,6 +63,9 @@ STAGES = (
     "g500.seed",  # a search's builder, its one maker row, the zero values
     "g500.search",  # Megakernel.run: the four mk.* stages nest inside
     "g500.readback",  # the queue read and unrolled into the parent array
+    # device_sparselu (device/sparselu.py), one of each a call
+    "slu.seed",  # the builder's root descriptor, the output buffers made
+    "slu.run",  # Megakernel.run: the four mk.* stages nest inside
     # the build ledger (runtime/progcache.py)
     "prog.first_call",  # building(): a runner's first call of a program it
                         # built: trace, lowering, compile or cache load, one

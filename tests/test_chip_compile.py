@@ -399,12 +399,35 @@ def _bnb(sh):
         ), sh)
 
 
+def _sparselu(sh):
+    """The cell sparselu-dep-128's program (benchmarks/configs/
+    sparselu-taskdep.json): 128 x 128 blocks of 128 x 128, the 1,768
+    present blocks read where they lie, the factor's 8,320 (545 MB) and
+    the inverses donated and written in place; the table sized from the
+    replayed schedule, the masks and a word a block in the value slots,
+    all of it inside a v5e's SMEM."""
+    from hclib_tpu.device.megakernel import DEVICE_TABLE
+    from hclib_tpu.device.sparselu import make_sparselu_megakernel
+
+    mk = make_sparselu_megakernel(128, 128, interpret=False)
+    assert mk.slu_replay["live_rows_max"] < mk.capacity < 160
+    assert mk.read_only == ("a",)
+    assert mk.num_values == 16 + 4 * 128 * 4 + 128 * 128
+    assert mk.smem_footprint() < DEVICE_TABLE["TPU v5 lite"]["smem_bytes"] / 2
+    assert [spec.width for _, spec in mk.batch_specs] == [8, 16]
+    compiled = _compile_mk(mk, sh, on_device=["a", "blocks", "linv"])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * 8320 * 128 * 128 + 2 * 4 * 128**3
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert _whole_copies(compiled, mk) == []
+
+
 KERNELS = {
     f.__name__.lstrip("_"): f
     for f in (_fib_scalar, _fib_batch, _uts_t1l, _uts_t3l, _cholesky_8192,
               _sw_fused,
               _sw_wave, _forasync_1d, _forasync_2d, _forasync_hbm,
-              _jacobi_steps,
+              _jacobi_steps, _sparselu,
               _serve_stream, _serve_stream_delta,
               _frontier, _search, _dyngraph, _bnb)
 }

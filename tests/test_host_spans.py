@@ -116,8 +116,9 @@ def test_spans_land_nested_in_a_profile_the_benchmark_reads(tmp_path):
 
 
 def test_the_table_names_every_stage_once():
-    assert len(set(spans.STAGES)) == len(spans.STAGES) == 35
+    assert len(set(spans.STAGES)) == len(spans.STAGES) == 37
     assert {"prog.first_call", "prog.compiled"} < set(spans.STAGES)
+    assert {"slu.seed", "slu.run"} < set(spans.STAGES)
     assert all(
         re.fullmatch(r"[a-z0-9]+(\.[a-z0-9_]+)+", s) for s in spans.STAGES
     )
@@ -302,8 +303,9 @@ FRONT = {"source": "program_span", "layer": "front door",
          # the open-loop cell of PR 44 joined the lists
          "workloads": [SERVE, "serve-open-steady"]}
 STAGING = {"layer": "host staging", "moves": "solve_ms",
+           # the SparseLU cell of PR 58 joined the lists
            "workloads": [CHOL, SW, FA, "g500-bfs-search",
-                         "jacobi-dep-hbm"]}
+                         "jacobi-dep-hbm", "sparselu-dep-128"]}
 # name: (reducer, args, unit, the rest of the entry, value on HOST below)
 METRICS = {
     "launch_us": ("span_mean",

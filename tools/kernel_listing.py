@@ -11,7 +11,8 @@ PR 41 / 45 / 46): read the listing before and after a change to the
 scheduler, and count its paths with ``tools/listing_paths.py``.
 
     python tools/kernel_listing.py <outdir> [--tree DIR]
-                                   [--kernel fib|forest|search|forasync|jacobi|wave|uts_bin]
+                                   [--kernel fib|forest|search|forasync|jacobi|wave|
+                                             uts_bin|sparselu]
                                    [--capacity N] [--if-conversion]
 
 ``--tree`` is the checkout to compile (default: this one; give a copy of
@@ -327,6 +328,24 @@ def _compile_uts_bin(stack_size: int) -> None:
     ).compile()
 
 
+def _compile_sparselu(capacity: int) -> None:
+    """``sparselu-dep-128``'s build (PR 58) as ``tests/test_chip_compile.py:
+    _sparselu`` compiles it: 128 x 128 blocks of 128 x 128, the present
+    blocks and the factor on the device; the build sizes its own table from
+    the replayed schedule (``--capacity`` may name no other). Its listing
+    holds the scalar ``lu0`` and the two range kinds behind the switch, and
+    two copies (starved phase off: one, the drain phase's) of each batch
+    body, the panel's at width 8 and the update's at width 16, each with
+    its releases under the store wave."""
+    from hclib_tpu.device.sparselu import make_sparselu_megakernel
+
+    mk = make_sparselu_megakernel(128, 128, interpret=False)
+    if capacity != mk.capacity:
+        raise SystemExit(f"the sparselu build sizes its own table: "
+                         f"{mk.capacity} rows, not --capacity {capacity}")
+    _compile_mk(mk, 1 << 22, on_device=("a", "blocks", "linv"))
+
+
 KERNELS = {
     "uts_bin": ("uts_dfs_bin", _compile_uts_bin, 2),
     "fib": ("tpu_custom_call", _compile_fib, 768),
@@ -335,6 +354,7 @@ KERNELS = {
     "forasync": ("tpu_custom_call", _compile_forasync, 64),
     "jacobi": ("tpu_custom_call", _compile_jacobi, 99),
     "wave": ("tpu_custom_call", _compile_wave, 568),
+    "sparselu": ("tpu_custom_call", _compile_sparselu, 129),
 }
 
 
