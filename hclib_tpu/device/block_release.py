@@ -77,6 +77,8 @@ K_DIAG, K_PANEL, K_UPDATE, K_SCANP, K_SCANU = 0, 1, 2, 3, 4
 V_FILL, V_SCANS = 2, 3
 V_DIAG, V_ROW, V_COL, V_UPD = 4, 5, 6, 7  # executed by kind
 V_PANEL_ROUNDS, V_UPD_ROUNDS = 8, 9
+# tasks of a lane whose loads were in flight before their round began
+V_PANEL_PREFETCHED, V_UPD_PREFETCHED = 10, 11
 BASE = 16
 
 ST_MASK = 0xFF
@@ -170,16 +172,22 @@ class BlockPlan:
             self._presets = out
         return self._presets
 
-    def simulate(self, panel_width: int, update_width: int) -> Dict[str, int]:
+    def simulate(self, panel_width: int, update_width: int,
+                 log: Optional[list] = None) -> Dict[str, int]:
         """The launch replayed on the host, descriptor by descriptor, as
         the scheduler runs it: a lane fires (panel before update) at an
         empty ring or at twice its width; else the ring pops newest first;
         a task the release makes goes straight on its lane's tail (the
         diagonal task and the ranges on the ring) while the rows of the
-        batch that made it are still live. Returns the release's counters
-        as ``info["sparselu"]`` reports them, ``live_rows_max`` among
-        them: the table is sized from it, and the tests hold the kernel to
-        all of them."""
+        batch that made it are still live. A round announces the entries
+        queued behind its batch, a batch at most, for the lane's next
+        round to find prefetched (the scheduler's handshake: the count is
+        taken before the round's own releases push). Returns the
+        release's counters as ``info["sparselu"]`` reports them,
+        ``live_rows_max`` among them: the table is sized from it, and the
+        tests hold the kernel to all of them. ``log``, where given, takes
+        the schedule: ``(lane, tasks, prefetched, announced)`` a batch
+        round, the entry a pop of the ring."""
         n = self.n
         step: Dict[Tuple[int, int], int] = {}
         busy, final = set(), set()
@@ -189,7 +197,8 @@ class BlockPlan:
         lanes = {"p": [], "u": []}
         out = dict(released=0, decrements=0, scans=0, fill_blocks=0,
                    lu0=0, fwd=0, bdiv=0, bmod=0, panel_rounds=0,
-                   bmod_rounds=0, live_rows_max=0)
+                   bmod_rounds=0, panel_prefetched=0, bmod_prefetched=0,
+                   live_rows_max=0)
         made = {(int(i), int(j)) for i, j in zip(*np.nonzero(self.present))}
         frow, fcol = [0] * n, [0] * n  # panel blocks final so far, a step
         live = hw = 1
@@ -282,21 +291,29 @@ class BlockPlan:
                 out["released"] += 1
                 spawn("p", (ii, jj))
 
-        fire = (("p", panel_width, after_panel, "panel_rounds"),
-                ("u", update_width, after_update, "bmod_rounds"))
+        fire = (("p", panel_width, after_panel, "panel"),
+                ("u", update_width, after_update, "bmod"))
+        announced = {"p": 0, "u": 0}
         while ring or lanes["p"] or lanes["u"]:
-            for name, width, done, rounds in fire:
+            for name, width, done, key in fire:
                 lane = lanes[name]
                 if lane and (not ring or len(lane) >= 2 * width):
                     take = lane[:width]
                     del lane[:width]
+                    pre = min(announced[name], len(take))
+                    announced[name] = min(len(lane), width)
+                    if log is not None:
+                        log.append((name, take, pre, announced[name]))
                     for t in take:
                         done(*t)
                     live -= len(take)
-                    out[rounds] += 1
+                    out[key + "_rounds"] += 1
+                    out[key + "_prefetched"] += pre
                     break
             else:
                 e = ring.pop()
+                if log is not None:
+                    log.append(e)
                 if e[0] == "d":
                     after_diag(e[1])
                 else:

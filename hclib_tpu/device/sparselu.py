@@ -14,12 +14,23 @@ pair, one descriptor a task of the source. The kinds and their tiers:
   swapped): ``inv(L) A_kj`` or ``A_ik inv(U)``, one 3-pass product each, as
   Cholesky's TRSM is;
 - ``bmod`` (its own batch lane, 95 % of the tasks): ``A_ij -= A_ik A_kj``,
-  three tile loads in flight a slot before the first wait, both operands
-  split in VMEM (a 128-tile's split is 16 vregs a plane; a split cache in
-  HBM would double the factor's bytes to save it), one store. The first
-  update of a fill block loads nothing: it MAKES the block.
+  three tile loads a slot, both operands split in VMEM (a 128-tile's
+  split is 16 vregs a plane; a split cache in HBM would double the
+  factor's bytes to save it), one store. The first update of a fill block
+  loads nothing: it MAKES the block.
 - two range kinds (scalar tier) that deal a finished block's releases out
   as the lanes drain (``BlockPlan.scan``).
+
+Both lanes run the scheduler's cross-round prefetch (``_batch_round``):
+their tiles and load semaphores stand in two halves, and while a round
+computes, stores and releases out of one, the loads of the batch queued
+behind it land in the other, so a round opens on tiles that are there.
+The scheduler announces a round how many queued descriptors to prefetch
+and tells the next how many it finds prefetched; a lane with nothing
+queued is announced nothing and loads on demand. ``info["sparselu"]``
+counts the tasks found prefetched by lane (``bmod_prefetched``,
+``panel_prefetched``), ``info["tiers"]["prefetch_hits"]`` their sum, and
+``BlockPlan.simulate`` replays the handshake to the unit.
 
 Storage is sparse: ``blocks[slot, m, m]``, a slot a block of the FINAL
 pattern, the blocks present before the call first (so the caller's array
@@ -98,29 +109,60 @@ def _block_load(ctx, word, dst, sem, wait: bool, n_present: int) -> None:
         _copies([(ctx.data["blocks"].at[slot], dst)], sem, wait)
 
 
-def _batch_round(ctx, loads, compute, stores, release, rounds) -> None:
-    """One batch round of either lane: every live slot's loads in flight
-    before the first wait, the slots' products, then one store wave with
-    the releases under it - they write SMEM only, and nothing they make
-    starts a DMA before a later round, which opens after the waits."""
-    # The lanes declare ``prefetch`` for its FIFO pop and spawn-time
-    # routing alone and load on demand: what the scheduler announces is
-    # taken out of the body's sight, so an edit that reads it fails to
-    # trace instead of joining a protocol the verifier was told to skip.
-    for announced in ("prefetched", "prefetch_count", "buf"):
-        vars(ctx).pop(announced, None)
+def _batch_round(ctx, loads, compute, stores, release, rounds,
+                 prefetched) -> None:
+    """One batch round of either lane, in the scheduler's cross-round
+    double-buffer protocol (``BatchSpec(prefetch=True)``; the order is
+    ``forasync_tier.TileKernel.batch_body``'s): the live slots the last
+    round did not prefetch start their loads into half ``ctx.buf``; the
+    ``ctx.prefetch_count`` descriptors queued behind this batch start
+    theirs into the other half, to land under this round's products,
+    stores and releases; every live slot waits its loads (a prefetched
+    slot the copies the last round started: the same triples under the
+    same predicates); the products in half ``ctx.buf``, in place; then
+    one store wave from that half with the releases under it - they
+    write SMEM only, and nothing they make starts a DMA before a later
+    round, which opens after the waits. What a round prefetches was
+    queued before the round began, so its blocks were stored and waited
+    in an earlier round and no task between here and its use writes
+    them (``B_BUSY``; its operands are final)."""
+    buf, load = ctx.buf, _ft.partial(loads, ctx)
 
-    def each_live(fn, *args) -> None:
+    def each(pred, fn, *args) -> None:
         for b in range(ctx.width):
-            pl.when(ctx.live(b))(_ft.partial(fn, b, *args))
+            pl.when(pred(b))(_ft.partial(fn, b, *args))
 
-    each_live(loads, False)
-    each_live(loads, True)
-    compute()
-    each_live(stores, False)
-    each_live(release)
+    # The compiler predicates a slot's region and every path runs it: a
+    # wave no slot takes part in (the on-demand one of a round that was
+    # prefetched whole, the prefetch of a lane with nothing queued) is
+    # jumped as one region behind one branch.
+    @pl.when(ctx.prefetched < ctx.count)
+    def _():
+        each(lambda b: ctx.live(b) & (b >= ctx.prefetched),
+             load, ctx.arg, buf, False)
+
+    @pl.when(ctx.prefetch_count > 0)
+    def _():
+        each(lambda b: b < ctx.prefetch_count,
+             load, ctx.next_arg, 1 - buf, False)
+
+    each(ctx.live, load, ctx.arg, buf, True)
+    compute(buf)
+    each(ctx.live, stores, buf, False)
+    each(ctx.live, release)
     ctx.set_value(rounds, ctx.value(rounds) + 1)
-    each_live(stores, True)
+    ctx.set_value(prefetched, ctx.value(prefetched) + ctx.prefetched)
+    each(ctx.live, stores, buf, True)
+
+
+def _batch_drain(ctx, loads) -> None:
+    """Retire the prefetch in flight for ``ctx.prefetched`` descriptors
+    whose round will not run (the scheduler's exit with entries unrun, on
+    ``fuel``): wait the copies ``_batch_round`` started for them into half
+    ``ctx.buf``."""
+    for b in range(ctx.width):
+        pl.when(b < ctx.prefetched)(
+            _ft.partial(loads, ctx, b, ctx.arg, ctx.buf, True))
 
 
 def _lu0_kernel(ctx, plan: BlockPlan, m: int, n_present: int) -> None:
@@ -143,91 +185,103 @@ def _lu0_kernel(ctx, plan: BlockPlan, m: int, n_present: int) -> None:
     plan.after_diag(ctx, kk)
 
 
-def _panel_body(ctx, plan: BlockPlan, m: int, n_present: int) -> None:
+def _panel_loads(ctx, b, arg, half, wait: bool, n_present: int) -> None:
+    """Start (or retire) slot ``b``'s loads into ``half``: the inverse's
+    two halves and the block; ``arg(b, i)`` reads the descriptor."""
+    ii, jj, word = (arg(b, i) for i in range(3))
+    kk, sel = jnp.minimum(ii, jj), (ii > jj).astype(jnp.int32)
+    linv, sem = ctx.data["linv"], ctx.scratch["plsem"].at[half, b]
+    _copies([(linv.at[kk, sel, 0], ctx.scratch["pih"].at[half, b]),
+             (linv.at[kk, sel, 1], ctx.scratch["pil"].at[half, b])],
+            sem, wait)
+    _block_load(ctx, word, ctx.scratch["px"].at[half, b], sem, wait,
+                n_present)
+
+
+def _panel_body(ctx, loads, plan: BlockPlan) -> None:
     """``fwd`` (``ii < jj``: ``inv(L_kk) A_kj``) and ``bdiv`` (``A_ik
     inv(U_kk)``) through one body: the inverse's two halves and the block
     a slot, one product, the block stored back."""
     px, pih, pil = (ctx.scratch[k] for k in ("px", "pih", "pil"))
-    lsem, ssem = ctx.scratch["plsem"], ctx.scratch["pssem"]
-    linv, blocks = ctx.data["linv"], ctx.data["blocks"]
+    ssem, blocks = ctx.scratch["pssem"], ctx.data["blocks"]
 
     def args_of(b):
         return ctx.arg(b, 0), ctx.arg(b, 1), ctx.arg(b, 2)
 
-    def loads(b, wait: bool) -> None:
-        ii, jj, word = args_of(b)
-        kk, sel = jnp.minimum(ii, jj), (ii > jj).astype(jnp.int32)
-        _copies([(linv.at[kk, sel, 0], pih.at[b]),
-                 (linv.at[kk, sel, 1], pil.at[b])], lsem.at[b], wait)
-        _block_load(ctx, word, px.at[b], lsem.at[b], wait, n_present)
-
-    def compute() -> None:
+    def compute(buf) -> None:
         for b in range(ctx.width):
             ii, jj, _ = args_of(b)
 
             @pl.when(ctx.live(b) & (ii < jj))
             def _(b=b):
-                px[b] = mm_nn_lsplit(pih[b], pil[b], px[b])
+                px[buf, b] = mm_nn_lsplit(pih[buf, b], pil[buf, b],
+                                          px[buf, b])
 
             @pl.when(ctx.live(b) & (ii > jj))
             def _(b=b):
-                px[b] = mm_nn_rsplit(px[b], pih[b], pil[b])
+                px[buf, b] = mm_nn_rsplit(px[buf, b], pih[buf, b],
+                                          pil[buf, b])
 
-    def stores(b, wait: bool) -> None:
-        _copies([(px.at[b], blocks.at[BlockPlan.slot(args_of(b)[2])])],
+    def stores(b, buf, wait: bool) -> None:
+        _copies([(px.at[buf, b],
+                  blocks.at[BlockPlan.slot(args_of(b)[2])])],
                 ssem.at[b], wait)
 
     def release(b) -> None:
         ii, jj, _ = args_of(b)
         plan.after_panel(ctx.slot_ctx(b), ii, jj)
 
-    _batch_round(ctx, loads, compute, stores, release, br.V_PANEL_ROUNDS)
+    _batch_round(ctx, loads, compute, stores, release, br.V_PANEL_ROUNDS,
+                 br.V_PANEL_PREFETCHED)
 
 
-def _bmod_body(ctx, plan: BlockPlan, m: int, n_present: int) -> None:
+def _bmod_loads(ctx, b, arg, half, wait: bool, plan: BlockPlan,
+                n_present: int) -> None:
+    """Start (or retire) slot ``b``'s loads into ``half``: the two
+    operands, final blocks of the output, and the block from where it
+    lies; ``arg(b, i)`` reads the descriptor."""
+    ii, jj, kk, word = (arg(b, i) for i in range(4))
+    blocks, sem = ctx.data["blocks"], ctx.scratch["ulsem"].at[half, b]
+    sa = BlockPlan.slot(ctx.value(plan.word_at(ii, kk)))
+    sb = BlockPlan.slot(ctx.value(plan.word_at(kk, jj)))
+    _copies([(blocks.at[sa], ctx.scratch["ua"].at[half, b]),
+             (blocks.at[sb], ctx.scratch["ub"].at[half, b])], sem, wait)
+    _block_load(ctx, word, ctx.scratch["uc"].at[half, b], sem, wait,
+                n_present)
+
+
+def _bmod_body(ctx, loads, plan: BlockPlan, m: int) -> None:
     """``A_ij -= A_ik A_kj`` for every live slot: the two operands and the
     block a slot, one 3-pass product, the block stored back."""
     ua, ub, uc = (ctx.scratch[k] for k in ("ua", "ub", "uc"))
-    lsem, ssem = ctx.scratch["ulsem"], ctx.scratch["ussem"]
-    blocks = ctx.data["blocks"]
+    ssem, blocks = ctx.scratch["ussem"], ctx.data["blocks"]
 
     def args_of(b):
         return tuple(ctx.arg(b, i) for i in range(4))
 
-    def loads(b, wait: bool) -> None:
-        ii, jj, kk, word = args_of(b)
-        sa = BlockPlan.slot(ctx.value(plan.word_at(ii, kk)))
-        sb = BlockPlan.slot(ctx.value(plan.word_at(kk, jj)))
-        _copies([(blocks.at[sa], ua.at[b]), (blocks.at[sb], ub.at[b])],
-                lsem.at[b], wait)
-        _block_load(ctx, word, uc.at[b], lsem.at[b], wait, n_present)
-
-    def compute() -> None:
+    def compute(buf) -> None:
         for b in range(ctx.width):
             word = args_of(b)[3]
 
             @pl.when(ctx.live(b) & jnp.logical_not(_made(word)))
             def _(b=b):  # allocate_clean_block: the update makes the block
-                uc[b] = jnp.zeros((m, m), jnp.float32)
+                uc[buf, b] = jnp.zeros((m, m), jnp.float32)
 
             @pl.when(ctx.live(b))
             def _(b=b):
-                uc[b] = uc[b] - mm_nn(ua[b], ub[b])
+                uc[buf, b] = uc[buf, b] - mm_nn(ua[buf, b], ub[buf, b])
 
-    def stores(b, wait: bool) -> None:
-        _copies([(uc.at[b], blocks.at[BlockPlan.slot(args_of(b)[3])])],
+    def stores(b, buf, wait: bool) -> None:
+        _copies([(uc.at[buf, b],
+                  blocks.at[BlockPlan.slot(args_of(b)[3])])],
                 ssem.at[b], wait)
 
     def release(b) -> None:
         ii, jj, kk, _ = args_of(b)
         plan.after_update(ctx.slot_ctx(b), ii, jj, kk)
 
-    _batch_round(ctx, loads, compute, stores, release, br.V_UPD_ROUNDS)
-
-
-def _nothing_in_flight(ctx) -> None:
-    """The lanes pop FIFO (which spawn-time routing wants) and load on
-    demand: the bodies start no prefetch, so there is none to retire."""
+    _batch_round(ctx, loads, compute, stores, release, br.V_UPD_ROUNDS,
+                 br.V_UPD_PREFETCHED)
 
 
 def make_sparselu_megakernel(
@@ -252,21 +306,27 @@ def make_sparselu_megakernel(
         "dva": pltpu.VMEM(tile, jnp.float32),
         **{f"dinv{i}": pltpu.VMEM(tile, jnp.bfloat16) for i in range(4)},
         "dsem": pltpu.SemaphoreType.DMA((1,)),
-        "px": pltpu.VMEM((pw,) + tile, jnp.float32),
-        "pih": pltpu.VMEM((pw,) + tile, jnp.bfloat16),
-        "pil": pltpu.VMEM((pw,) + tile, jnp.bfloat16),
-        "plsem": pltpu.SemaphoreType.DMA((pw,)),
+        # the lanes' tiles and load semaphores in two halves: a round
+        # computes in one while the next round's loads fill the other
+        "px": pltpu.VMEM((2, pw) + tile, jnp.float32),
+        "pih": pltpu.VMEM((2, pw) + tile, jnp.bfloat16),
+        "pil": pltpu.VMEM((2, pw) + tile, jnp.bfloat16),
+        "plsem": pltpu.SemaphoreType.DMA((2, pw)),
         "pssem": pltpu.SemaphoreType.DMA((pw,)),
-        "ua": pltpu.VMEM((uw,) + tile, jnp.float32),
-        "ub": pltpu.VMEM((uw,) + tile, jnp.float32),
-        "uc": pltpu.VMEM((uw,) + tile, jnp.float32),
-        "ulsem": pltpu.SemaphoreType.DMA((uw,)),
+        "ua": pltpu.VMEM((2, uw) + tile, jnp.float32),
+        "ub": pltpu.VMEM((2, uw) + tile, jnp.float32),
+        "uc": pltpu.VMEM((2, uw) + tile, jnp.float32),
+        "ulsem": pltpu.SemaphoreType.DMA((2, uw)),
         "ussem": pltpu.SemaphoreType.DMA((uw,)),
     }
-    kw = dict(plan=plan, m=m, n_present=sym.n_present)
+    # a lane's loads, started by its rounds and retired by its drain
+    panel_loads = _ft.partial(_panel_loads, n_present=sym.n_present)
+    bmod_loads = _ft.partial(_bmod_loads, plan=plan,
+                             n_present=sym.n_present)
     mk = Megakernel(
         kernels=[
-            ("lu0", _ft.partial(_lu0_kernel, **kw)),
+            ("lu0", _ft.partial(_lu0_kernel, plan=plan, m=m,
+                                n_present=sym.n_present)),
             ("panel", _batch_stub),
             ("bmod", _batch_stub),
             ("scan_panel", _ft.partial(plan.scan, kind=br.K_SCANP)),
@@ -274,15 +334,16 @@ def make_sparselu_megakernel(
         ],
         route={
             # FIFO lanes a spawn pushes straight onto, each firing at two
-            # batches over a hot ring: a range is dealt out as they drain.
+            # batches over a hot ring: a range is dealt out as they drain,
+            # and the batch queued behind a round is prefetched under it.
             "panel": BatchSpec(
-                _ft.partial(_panel_body, **kw), width=pw, prefetch=True,
-                drain=_nothing_in_flight, fire_at=2 * pw,
-                verify_suppress=("prefetch-protocol",)),
+                _ft.partial(_panel_body, loads=panel_loads, plan=plan),
+                width=pw, prefetch=True, fire_at=2 * pw,
+                drain=_ft.partial(_batch_drain, loads=panel_loads)),
             "bmod": BatchSpec(
-                _ft.partial(_bmod_body, **kw), width=uw, prefetch=True,
-                drain=_nothing_in_flight, fire_at=2 * uw,
-                verify_suppress=("prefetch-protocol",)),
+                _ft.partial(_bmod_body, loads=bmod_loads, plan=plan, m=m),
+                width=uw, prefetch=True, fire_at=2 * uw,
+                drain=_ft.partial(_batch_drain, loads=bmod_loads)),
         },
         data_specs={
             "a": jax.ShapeDtypeStruct((sym.n_present,) + tile, jnp.float32),
@@ -326,7 +387,8 @@ def device_sparselu(blocks, mk: Megakernel, out=None
     ``info["sparselu"]``: executed by kind (``lu0`` / ``fwd`` / ``bdiv`` /
     ``bmod``), ``fill_blocks``, ``released`` (tasks the release made: all
     but the root), ``releases`` (tests of a block's readiness that made
-    nothing), ``scans`` (range descriptors run), rounds and tasks by lane,
+    nothing), ``scans`` (range descriptors run), rounds and tasks by lane
+    and how many of a lane's tasks found their tiles prefetched,
     ``live_rows_max`` against ``capacity``. Two spans split the call,
     ``slu.seed`` and ``slu.run`` (``runtime/spans.py:STAGES``)."""
     sym: Symbolic = mk.slu_sym
@@ -352,7 +414,8 @@ def device_sparselu(blocks, mk: Megakernel, out=None
         vals, data, info = mk.run(b, data=data, ivalues=plan.presets())
     v = {k: int(vals[getattr(br, "V_" + k.upper())])
          for k in ("fill", "scans", "diag", "row", "col", "upd",
-                   "panel_rounds", "upd_rounds")}
+                   "panel_rounds", "upd_rounds", "panel_prefetched",
+                   "upd_prefetched")}
     released, tests = int(vals[V_RELEASED]), int(vals[V_DECREMENTS])
     info["sparselu"] = {
         "lu0": v["diag"], "fwd": v["row"], "bdiv": v["col"], "bmod": v["upd"],
@@ -361,6 +424,8 @@ def device_sparselu(blocks, mk: Megakernel, out=None
         "decrements": tests,
         "panel_rounds": v["panel_rounds"], "panel_tasks": v["row"] + v["col"],
         "bmod_rounds": v["upd_rounds"], "bmod_tasks": v["upd"],
+        "panel_prefetched": v["panel_prefetched"],
+        "bmod_prefetched": v["upd_prefetched"],
         "live_rows_max": info["allocated"], "capacity": mk.capacity,
         "rows": sym.rows, "cols": sym.cols,
     }

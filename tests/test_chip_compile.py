@@ -405,11 +405,22 @@ def _sparselu(sh):
     present blocks read where they lie, the factor's 8,320 (545 MB) and
     the inverses donated and written in place; the table sized from the
     replayed schedule, the masks and a word a block in the value slots,
-    all of it inside a v5e's SMEM."""
+    all of it inside a v5e's SMEM; both lanes' tiles in two halves for the
+    cross-round prefetch, 8.2 MiB of VMEM scratch under the build's 32."""
+    from jax.experimental.pallas import tpu as pltpu
+
     from hclib_tpu.device.megakernel import DEVICE_TABLE
     from hclib_tpu.device.sparselu import make_sparselu_megakernel
 
     mk = make_sparselu_megakernel(128, 128, interpret=False)
+    tile = 128 * 128
+    assert sum(
+        int(np.prod(sp.shape)) * jnp.dtype(sp.dtype).itemsize
+        for sp in mk.scratch_specs.values()
+        if getattr(sp, "memory_space", None) == pltpu.VMEM
+    ) == (2 * 16 * 3 * 4 + 2 * 8 * (4 + 2 + 2) + 4 + 4 * 2) * tile
+    assert mk.vmem_limit_bytes == 32 << 20
+    assert all(spec.prefetch for _, spec in mk.batch_specs)
     assert mk.slu_replay["live_rows_max"] < mk.capacity < 160
     assert mk.read_only == ("a",)
     assert mk.num_values == 16 + 4 * 128 * 4 + 128 * 128

@@ -2,11 +2,17 @@
 against BOTS' sequential ``sparselu_seq_call`` in numpy float32
 (``benchmarks/reference/sparselu.py``), element by element, on the CPU
 interpreter; the release's counters against the symbolic factorisation and
-against the schedule replayed on the host; the tile LU against numpy."""
+against the schedule replayed on the host; the tile LU against numpy. The
+lanes' cross-round prefetch (ISSUE 59): its counts against the replay's,
+the verifier's rule, a run cut short with a prefetch in flight. The
+interpreter performs a copy when it is WAITED, so a prefetched tile is
+read a round after its start: what the protocol counts on not to change
+in between is held to the reference here."""
 
 import functools
 import json
 import os
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -16,11 +22,14 @@ import jax.numpy as jnp
 
 from benchmarks.reference import sparselu as ref
 from hclib_tpu.device import block_release as br
+from hclib_tpu.device import tracebuf as tb
+from hclib_tpu.device.descriptor import TaskGraphBuilder
 from hclib_tpu.device.sparselu import (
     PANEL_WIDTH, UPDATE_WIDTH, device_sparselu, make_sparselu_megakernel,
 )
 from hclib_tpu.models import sparselu as model
 from hclib_tpu.ops.tiles import lu_and_inv
+from hclib_tpu.runtime.resilience import StallError
 
 M = 128
 # the cell's limit on the componentwise backward error (PERF.md section 2)
@@ -54,17 +63,54 @@ PATTERNS = {
     "genmat8": lambda: ref.genmat_pattern(8),
     "band5_no_fill": lambda: _band(5),
     "arrow5_fills_completely": lambda: _arrow(5),
+    # 49 updates ready at once: the lanes fire with batches queued behind
+    # them, and most rounds find their tiles prefetched
+    "arrow8_prefetches": lambda: _arrow(8),
 }
+
+
+# This pattern's build carries the flight recorder (a ring of 256 records,
+# the last ones kept): the test that cuts a run short reads the exit's
+# records, and shares the build, and its whole program, with the others.
+TRACED = "arrow8_prefetches"
 
 
 @functools.lru_cache(maxsize=None)
 def _build(name: str):
     p = PATTERNS[name]()
-    return make_sparselu_megakernel(len(p), M, pattern=p, interpret=True)
+    env = {"HCLIB_TPU_TRACE": "256"} if name == TRACED else {}
+    with mock.patch.dict(os.environ, env):
+        return make_sparselu_megakernel(len(p), M, pattern=p,
+                                        interpret=True)
+
+
+@pytest.fixture(scope="module", params=sorted(PATTERNS))
+def name(request):
+    """A pattern of ``PATTERNS``. Of module scope, so that the tests of
+    one pattern run one after the other and share its build and its run
+    (a build takes the interpreter most of a minute to trace)."""
+    return request.param
 
 
 def _blocks(p, seed):
     return np.asarray(ref.make_blocks(seed, p, M))
+
+
+@functools.lru_cache(maxsize=None)
+def _run(name: str):
+    """One call on pattern ``name``'s build: the matrix, the factor and
+    ``info``, shared by the tests that read one run."""
+    mk = _build(name)
+    a = _blocks(mk.slu_sym.present, 58)
+    factor, info = device_sparselu(a, mk=mk)
+    return a, np.asarray(factor), info
+
+
+def _schedule(plan):
+    """The replay's counters and its schedule: ``(lane, tasks, prefetched,
+    announced)`` a batch round, the entry a pop of the ring."""
+    log = []
+    return plan.simulate(PANEL_WIDTH, UPDATE_WIDTH, log), log
 
 
 def _sequential(a, sym):
@@ -77,15 +123,13 @@ def _close(got, want, shift):
     assert np.abs(got - want).max() <= TOL * shift
 
 
-@pytest.mark.parametrize("name", sorted(PATTERNS))
 def test_program_against_the_sequential_reference(name):
     mk = _build(name)
     sym, plan = mk.slu_sym, mk.slu_plan
     want_sym = ref.symbolic(sym.present)
     assert sym.counts == want_sym["counts"]
     assert (sym.final == want_sym["final"]).all()
-    a = _blocks(sym.present, 58)
-    factor, info = device_sparselu(a, mk=mk)
+    a, factor, info = _run(name)
     slu = info["sparselu"]
     # the four counts and the fill count against the symbolic ones
     assert {k: slu[k] for k in sym.counts} == sym.counts
@@ -118,9 +162,13 @@ def test_program_against_the_sequential_reference(name):
 def test_two_calls_on_one_build_leave_no_residue():
     """The input is not consumed; the second call factors another matrix
     into the first call's factor (and a third into a buffer of NaN) and
-    nothing of what the buffer held shows."""
-    mk = _build("genmat6")
+    nothing of what the buffer held shows. The ``bmod`` lane runs an odd
+    number of rounds, so a call ends in the other half of the lanes'
+    tiles than it began in, and the next begins as the first did."""
+    mk = _build(TRACED)
     sym = mk.slu_sym
+    replay = mk.slu_replay
+    assert replay["bmod_rounds"] % 2 == 1 and replay["bmod_prefetched"] > 0
     shift = ref.diag_shift(sym.present, M)
     a1 = jnp.asarray(_blocks(sym.present, 1))
     a2 = jnp.asarray(_blocks(sym.present, 2**31 + 2))
@@ -129,6 +177,7 @@ def test_two_calls_on_one_build_leave_no_residue():
     _close(np.asarray(f1), want1, shift)
     f2, info = device_sparselu(a2, mk=mk, out=f1)
     assert info["sparselu"]["fill_blocks"] == sym.fill_blocks
+    assert info["sparselu"]["bmod_prefetched"] == replay["bmod_prefetched"]
     want2 = _sequential(np.asarray(a2), sym)
     _close(np.asarray(f2), want2, shift)
     poison = jnp.full((sym.slots, M, M), jnp.nan, jnp.float32)
@@ -137,6 +186,60 @@ def test_two_calls_on_one_build_leave_no_residue():
         device_sparselu(a1, mk=mk)[0]))
     _close(np.asarray(f3), want1, shift)
     assert not a1.is_deleted() and not a2.is_deleted()
+
+
+def test_prefetched_tasks_are_the_replays(name):
+    """Tasks whose tiles were in flight before their round began, by lane
+    and as the scheduler counts them: the replay's handshake to the unit
+    (a round announces what was queued behind its batch before its own
+    releases pushed, a batch at most, and the lane's next round finds as
+    many of its tasks prefetched)."""
+    mk = _build(name)
+    _, _, info = _run(name)
+    slu, tiers = info["sparselu"], info["tiers"]
+    replay, log = _schedule(mk.slu_plan)
+    rounds = [e for e in log if e[0] in ("p", "u")]
+    for lane, key, width in (("p", "panel", PANEL_WIDTH),
+                             ("u", "bmod", UPDATE_WIDTH)):
+        mine = [e for e in rounds if e[0] == lane]
+        assert len(mine) == slu[key + "_rounds"]
+        assert all(pre <= len(take) <= width for _, take, pre, _ in mine)
+        assert mine[0][2] == 0  # nothing is in flight before the first
+        assert mine[-1][3] == 0  # and nothing after the last
+        assert slu[key + "_prefetched"] == replay[key + "_prefetched"] == sum(
+            pre for _, _, pre, _ in mine)
+    assert tiers["prefetch_hits"] == (
+        slu["bmod_prefetched"] + slu["panel_prefetched"])
+    assert tiers["batch_rounds"] == len(rounds)
+    if name == "arrow8_prefetches":
+        assert (slu["bmod_prefetched"], slu["panel_prefetched"]) == (75, 7)
+        assert slu["bmod_rounds"] == 11
+
+
+def test_a_fill_blocks_first_update_in_a_prefetched_slot_starts_from_zero():
+    """A fill block's first update loads nothing and zeroes its tile where
+    it computes. In a prefetched slot that is the half the round before
+    last stored from: the block must come out as the reference's, not
+    that tile's leftovers less the product."""
+    mk = _build(TRACED)
+    sym, plan = mk.slu_sym, mk.slu_plan
+    a, factor, _ = _run(TRACED)
+    _, log = _schedule(plan)
+    updates = [e for e in log if e[0] == "u"]
+    firsts = [
+        (ii, jj)
+        for r, (_, take, pre, _) in enumerate(updates) if r >= 2
+        for s, (ii, jj, kk) in enumerate(take)
+        if s < pre and not sym.present[ii, jj]
+        and kk == plan.first_step(ii, jj)
+        # the slot's tile in this half is one an earlier round left there
+        and s < len(updates[r - 2][1])
+    ]
+    assert firsts
+    want = ref.sparselu_seq(a, sym.present)
+    shift = ref.diag_shift(sym.present, M)
+    for ii, jj in firsts:
+        _close(factor[sym.slot_of[ii, jj]], want[ii, jj], shift)
 
 
 @pytest.mark.parametrize("n", [50, 96, 100, 128])
@@ -157,6 +260,9 @@ def test_replay_of_the_sources_classes(n):
         assert (sym.n_present, sym.fill_blocks, sym.slots) == (1768, 6552,
                                                                8320)
         assert sym.widest_step == 4096 and r["live_rows_max"] == 115
+        # all but 134 of the updates find their tiles prefetched
+        assert (r["bmod_rounds"], r["bmod_prefetched"]) == (10931, 174650)
+        assert (r["panel_rounds"], r["panel_prefetched"]) == (1050, 7826)
 
 
 class _Values:
@@ -240,34 +346,86 @@ def test_host_model_against_the_sequential_reference():
         a, sym.rows[:sym.n_present], sym.cols[:sym.n_present], 6))).all()
 
 
-def test_lanes_stand_outside_the_prefetch_protocol_and_nothing_else():
-    """The two lanes declare ``prefetch`` for its FIFO pop and spawn-time
-    routing and load on demand: the one verifier rule they are excused
-    from is the prefetch protocol's, and what the scheduler announces is
-    out of the bodies' sight (a body that read it would not trace)."""
+def _cut_with_a_prefetch_in_flight(log):
+    """A ``fuel`` that stops the scheduler after a pop of the ring that
+    follows a ``bmod`` round with a batch queued behind it, past the
+    lane's third round: the fuel, what each lane has in flight there, and
+    the tasks that had been found prefetched by then."""
+    executed = hits = 0
+    flying = {"p": 0, "u": 0}
+    rounds = {"p": 0, "u": 0}
+    for e, nxt in zip(log, log[1:]):
+        if e[0] in flying:
+            lane, take, pre, announced = e
+            executed += len(take)
+            hits += pre
+            flying[lane] = announced
+            rounds[lane] += 1
+            if (lane == "u" and rounds["u"] >= 3 and announced
+                    and nxt[0] not in flying):
+                return executed + 1, flying, hits
+        else:
+            executed += 1
+    raise AssertionError("no such point in this schedule")
+
+
+def test_a_run_cut_short_drains_its_prefetch_and_the_next_is_whole():
+    """``fuel`` runs out one task after a ``bmod`` round that started the
+    next batch's loads: the scheduler's exit retires them through the
+    lane's ``drain`` (the flight recorder's ``prefetch_drain`` record
+    names the lane and the count), and the same build then factors the
+    matrix whole."""
+    mk = _build(TRACED)
+    sym, plan = mk.slu_sym, mk.slu_plan
+    _, log = _schedule(plan)
+    fuel, flying, hits = _cut_with_a_prefetch_in_flight(log)
+    assert flying["u"] > 0
+    a = _blocks(sym.present, 59)
+    b = TaskGraphBuilder()
+    b.add(br.K_DIAG, args=[0, plan.root_word()])
+    with pytest.raises(StallError) as cut:
+        mk.run(b, fuel=fuel, ivalues=plan.presets(), data={
+            "a": jnp.asarray(a),
+            "blocks": jnp.zeros((sym.slots, M, M), jnp.float32),
+            "linv": jnp.zeros((sym.n, 2, 2, M, M), jnp.bfloat16)})
+    stats = cut.value.stats
+    assert stats["executed"] == fuel and stats["pending"] > 0
+    assert stats["tiers"]["prefetch_hits"] == hits
+    drains = tb.records_of(stats["trace"], tb.TR_PREFETCH_DRAIN)
+    assert {int(fid): int(n) for _, _, fid, n in drains} == {
+        kind: flying[lane]
+        for lane, kind in (("p", br.K_PANEL), ("u", br.K_UPDATE))
+        if flying[lane]}
+    factor, info = device_sparselu(a, mk=mk)
+    assert info["sparselu"]["bmod_prefetched"] == mk.slu_replay[
+        "bmod_prefetched"]
+    _close(np.asarray(factor), _sequential(a, sym),
+           ref.diag_shift(sym.present, M))
+
+
+def test_lanes_run_the_prefetch_protocol_under_the_verifier():
+    """Both lanes declare ``prefetch`` and keep it: no rule of the build's
+    verifier is suppressed, the build passes with the prefetch protocol's
+    rule on and its passes run (no body the shim could not read), and the
+    rule bites: the same lane with a drain that waits nothing is refused,
+    by the copies the body's prefetch left in flight."""
+    from hclib_tpu.analysis import verify_megakernel
+    from hclib_tpu.analysis.races import check_batch_spec
+    from hclib_tpu.device.megakernel import BatchSpec
+
     mk = _build("genmat4")
-    specs = [spec for _, spec in mk.batch_specs]
-    assert len(specs) == 2 and mk.verify_suppress == ()
-    for spec in specs:
-        assert spec.prefetch and spec.verify_suppress == (
-            "prefetch-protocol",)
-    seen = {}
-
-    class Ctx:
-        width = 0
-
-        def __init__(self):
-            self.prefetched = self.buf = self.prefetch_count = 1
-
-        def value(self, i):
-            return 0
-
-        def set_value(self, i, v):
-            seen["rounds"] = i
-
-    ctx = Ctx()
-    from hclib_tpu.device.sparselu import _batch_round
-    _batch_round(ctx, None, lambda: None, None, None, br.V_UPD_ROUNDS)
-    assert seen == {"rounds": br.V_UPD_ROUNDS}
-    assert not any(hasattr(ctx, k)
-                   for k in ("prefetched", "buf", "prefetch_count"))
+    specs = dict(mk.batch_specs)
+    assert sorted(specs) == [br.K_PANEL, br.K_UPDATE]
+    assert mk.verify and mk.verify_suppress == ()
+    for spec in specs.values():
+        assert spec.prefetch and spec.verify_suppress == ()
+        assert spec.fire_at == 2 * spec.width
+    assert [str(f) for f in verify_megakernel(mk).findings] == []
+    for fid, spec in specs.items():
+        idle = BatchSpec(spec.body, width=spec.width, prefetch=True,
+                         drain=lambda ctx: None, fire_at=spec.fire_at)
+        found = check_batch_spec(
+            mk.kernel_names[fid], fid, idle, mk.data_specs,
+            mk.scratch_specs).findings
+        assert [f.rule for f in found] == ["prefetch-protocol"]
+        assert "never drained" in found[0].message
